@@ -25,17 +25,29 @@ CORR_DTYPES = ("fp32", "bf16", "int8")
 CORR_IMPLS = ("allpairs", "local", "pallas", "flash")
 
 
+# Why corr_impl="pallas" (the per-pixel kernels, fused or not) is not
+# supported on the chip: Mosaic's own message for the (k, k, C) window
+# load at the v5 440x1024 shapes. ops/pallas_corr.py raises this on a
+# TPU backend; tests/test_chip_compile.py pins that the compiler's
+# refusal still stands, and a repair re-admits the family there first.
+PALLAS_TPU_REFUSAL = (
+    "corr_impl='pallas' does not compile for TPU v5e under the installed "
+    "JAX (Mosaic: 'cannot statically prove that index in dimension 2 is "
+    "a multiple of 8' on the per-pixel window load); not supported on "
+    "the chip — use 'flash' or 'allpairs'")
+
+
 def resolve_corr_impl(impl: str, platform: str) -> Tuple[str, bool]:
     """Resolve an eval/serve CLI ``--corr_impl`` value to a concrete
     (corr_impl, fused_update) pair.
 
     "auto" is the production default: on TPU it resolves to the
     flash-blocked fused step (corr_impl="flash", fused_update=True) —
-    the O(fmaps)-memory configuration that unlocks 1080p+ and
-    constant-memory video (docs/perf.md "Correlation memory &
-    precision"). Off-TPU it falls back to the materialized volume:
-    Pallas kernels only run off-chip in interpreter mode, which is
-    debug-speed, not serving-speed. Explicit values pass through with
+    the O(fmaps)-memory configuration; chip_smoke.py runs it on the chip
+    against allpairs and tests/test_chip_compile.py compiles it for
+    v5e. Off-TPU it falls back to the materialized volume: Pallas
+    kernels only run off-chip in interpreter mode, which is debug-speed,
+    not serving-speed. Explicit values pass through with
     fused_update=False (the CLI's --fused_update flag overrides).
     """
     if impl == "auto":
@@ -119,18 +131,18 @@ class RAFTConfig:
     #                     point the train_bench HBM columns quantify
     remat_policy: str = "full"
     # rematerialize ONLY the correlation lookup: drops the per-iteration
-    # one-hot hat matrices — the dominant training-memory term (measured
-    # 5x1.57 GB with up to 15x lane padding at batch 6, 368x496; see
-    # docs/perf.md) — at a fraction of full remat's recompute cost.
+    # one-hot hat matrices — the dominant training-memory term (their
+    # (9, W) trailing dims lane-pad ~10x on the chip; the v5e compiler
+    # wants 33.6 GiB at batch 10, 368x496 without remat and 20.2 with
+    # this — docs/perf.md) — at a fraction of full remat's recompute cost.
     # Numerically identical; composes with (and is implied by) remat
     remat_lookup: bool = False
     # transposed-conv implementation inside the embedded DexiNed's
     # upsamplers: "transpose" (lax.conv_transpose) or "subpixel" (the
     # numerically identical phase-decomposed form — dense half-res convs
     # instead of an input-dilated full-res conv; see models/dexined.py).
-    # Default flipped to "subpixel" after the on-chip A/B: end-to-end v5
-    # forward at 440x1024 dropped 175.9 -> 100.0 ms (allpairs path),
-    # prelude ~104 -> ~26 ms (logs/tpu_queue_r4/bench_record.log).
+    # "subpixel" is the default (its speed against "transpose" is not
+    # measured on today's code — ROADMAP D2).
     dexined_upconv: str = "subpixel"
     # unroll factor for the refinement-loop scan (lax.scan unroll): >1
     # lets XLA software-pipeline consecutive iterations (fuse the next

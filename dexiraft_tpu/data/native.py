@@ -2,7 +2,8 @@
 
 Builds the shared object on first use with g++ (cached under
 native/build/), falls back to the pure-Python codecs when the toolchain
-or library is unavailable, and honors DEXIRAFT_NO_NATIVE=1. Batch decodes
+or library is unavailable (status() names which serves, and why), and
+honors DEXIRAFT_NO_NATIVE=1. Batch decodes
 release the GIL for the whole call — C++ threads do the file I/O.
 """
 
@@ -24,11 +25,15 @@ _SO = osp.join(_REPO_ROOT, "native", "build", "libdexiraft_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# why the Python codecs are serving, once get_lib() has given up
+_unavailable: Optional[str] = None
 
 
-def _build() -> Optional[str]:
+def _build() -> str:
+    """Path of an up-to-date shared object, compiling it if needed.
+    Raises OSError / subprocess.SubprocessError with the reason."""
     if not osp.exists(_SRC):
-        return None
+        raise FileNotFoundError(f"no source at {_SRC}")
     os.makedirs(osp.dirname(_SO), exist_ok=True)
     if (osp.exists(_SO)
             and os.stat(_SO).st_mtime >= os.stat(_SRC).st_mtime):
@@ -41,30 +46,29 @@ def _build() -> Optional[str]:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _SO)
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
+    except (subprocess.SubprocessError, OSError):
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return None
+        raise
     return _SO
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """The loaded library, building it on first call; None if unavailable."""
-    global _lib, _tried
+    """The loaded library, building it on first call; None if unavailable
+    (status() says why)."""
+    global _lib, _tried, _unavailable
     if os.environ.get("DEXIRAFT_NO_NATIVE") == "1":
         return None
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        so = _build()
-        if so is None:
-            return None
         try:
-            lib = ctypes.CDLL(so)
-        except OSError:
+            lib = ctypes.CDLL(_build())
+        except (subprocess.SubprocessError, OSError) as e:
+            _unavailable = f"{type(e).__name__}: {e}"
             return None
         i32p = ctypes.POINTER(ctypes.c_int32)
         lib.drn_read_flo.argtypes = [ctypes.c_char_p, ctypes.c_void_p,
@@ -82,6 +86,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
             fn.restype = ctypes.c_int32
         _lib = lib
         return _lib
+
+
+def status() -> str:
+    """Which decoders serve this process, for the entry points' device
+    banner: the Python codecs taking over is allowed, but not silently."""
+    if os.environ.get("DEXIRAFT_NO_NATIVE") == "1":
+        return "python (DEXIRAFT_NO_NATIVE=1)"
+    if get_lib() is not None:
+        return f"native ({_SO})"
+    return f"python (native build unavailable: {_unavailable})"
 
 
 def read_flo_native(path) -> Optional[np.ndarray]:

@@ -120,6 +120,10 @@ def load_variables(args):
 
     impl, fused = resolve_corr_impl_args(args, jax.devices()[0].platform,
                                          "eval")
+    from dexiraft_tpu.profiling import device_banner
+
+    device_banner("eval", corr_impl_arg=args.corr_impl, corr_impl=impl,
+                  fused_update=fused)
     cfg = VARIANTS[args.variant](small=args.small,
                                  mixed_precision=args.mixed_precision,
                                  corr_impl=impl,

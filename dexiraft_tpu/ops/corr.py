@@ -183,10 +183,9 @@ def interp_window(vol: jax.Array, centers: jax.Array, radius: int,
 
     which XLA schedules as streaming passes over the volume (HBM-bandwidth
     bound) instead of the scalar-gather HLO that advanced indexing lowers
-    to (~1000x slower on TPU measured at Sintel eval size). Expressed as
-    ONE three-operand einsum so XLA picks the contraction path itself:
-    measured on-chip (scripts/lookup_ab2.py, RTT-corrected) 1.2 ms/iter
-    vs 2.2 for the hand-split y-then-x pair and 1.5 for x-then-y.
+    to. Expressed as ONE three-operand einsum so XLA picks the
+    contraction path itself (scripts/lookup_ab.py --variant 2 compares
+    the hand-split pairs; not measured on today's code).
 
     The window axis order matches _window_delta: x offset on the SLOW
     axis — the reference's transposed window (core/corr.py:37-43).

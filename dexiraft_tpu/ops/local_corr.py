@@ -13,8 +13,8 @@ windowed with the separable one-hot interpolation matmuls of
 ops.corr.interp_window, and discarded. Transient memory is
 O(chunk · W · H2 · W2) per level (`row_chunk` bounds it; lax.map keeps
 chunks sequential), never the full volume, and there are zero gather
-HLOs — TPU gathers measured 16-30x slower than recomputing the dots on
-the MXU.
+HLOs — the gather formulations lost to recomputing the dots on the MXU
+when this was written (not measured on today's code).
 
 Like the reference's AlternateCorrBlock (core/corr.py:63-91), the pyramid
 pools FMAP2 (not the correlation volume) — since build_corr_pyramid now
@@ -59,8 +59,7 @@ def local_corr_level(
     all-pairs block vol = f1_chunk · f2ᵀ (MXU matmul) is materialized,
     windowed via the separable one-hot interpolation matmuls of
     ops.corr.corr_lookup, and discarded — O(chunk·H2·W2) transient memory,
-    never the full O((HW)²) volume, and zero gather HLOs (TPU gathers
-    measured ~16-30x slower than rebuilding the dots on the MXU).
+    never the full O((HW)²) volume, and zero gather HLOs.
     """
     b, h, w, c = fmap1.shape
     coords = jax.lax.stop_gradient(coords)

@@ -37,6 +37,14 @@ Three kernel generations live here, newest last:
     is a block x blockᵀ MXU matmul windowed in-register by the hat
     matrices, and there is no budget split at any geometry. See the
     "Flash-blocked kernel" section below.
+
+On the chip only the flash generation is supported. Mosaic refuses both
+per-pixel generations at the v5 440x1024 shapes ("cannot statically
+prove that index in dimension 2 is a multiple of 8": the (k, k, C)
+window load starts at an arbitrary sublane), so on a TPU backend they
+raise config.PALLAS_TPU_REFUSAL; they stay for interpret-mode parity
+and as the reference the flash tests compare with.
+tests/test_chip_compile.py compiles what stays reachable for v5e.
 """
 
 from __future__ import annotations
@@ -49,11 +57,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dexiraft_tpu.config import PALLAS_TPU_REFUSAL
 from dexiraft_tpu.ops.local_corr import local_corr_level
 
-# queries per grid step; read through _pixel_block() so on-chip tuning
-# (scripts/tpu_smoke.py sweeps DEXIRAFT_PALLAS_PIXEL_BLOCK) needs no
-# code edit. Resolved at trace time — rebuild the jit to change it.
+# queries per grid step; read through _pixel_block() so tuning
+# (DEXIRAFT_PALLAS_PIXEL_BLOCK) needs no code edit. Resolved at trace
+# time — rebuild the jit to change it.
 _PIXEL_BLOCK = 256
 
 
@@ -70,10 +79,25 @@ def _pixel_block() -> int:
 
 def _interpret_default() -> bool:
     # DEXIRAFT_PALLAS_INTERPRET=1 runs the kernel in interpreter mode
-    # (trace-time switch) — lets the whole-model corr_impl="pallas" path
-    # run off-chip (tests/test_local_corr.py). Never set it on a TPU
-    # host: the interpreter is orders of magnitude slower.
-    return os.environ.get("DEXIRAFT_PALLAS_INTERPRET", "0") == "1"
+    # (trace-time switch) — lets the whole-model kernel paths run
+    # off-chip (tests/test_local_corr.py). On a TPU backend the variable
+    # being set is an error, not a mode: the interpreter would stand in
+    # for the kernel at orders of magnitude less speed, silently.
+    interpret = os.environ.get("DEXIRAFT_PALLAS_INTERPRET", "0") == "1"
+    if interpret and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "DEXIRAFT_PALLAS_INTERPRET=1 on a TPU backend: Pallas kernels "
+            "must compile for the chip, not run in the interpreter — "
+            "unset it")
+    return interpret
+
+
+def _refuse_per_pixel_on_tpu(interpret: bool) -> None:
+    """The per-pixel kernels (corr_impl="pallas", fused or not) do not
+    compile for the chip: fail with Mosaic's reason at trace time, on
+    every path, instead of mid-compile."""
+    if not interpret and jax.default_backend() == "tpu":
+        raise NotImplementedError(PALLAS_TPU_REFUSAL)
 
 
 def _variant() -> str:
@@ -83,8 +107,7 @@ def _variant() -> str:
     # whole block — the shape the VPU pipelines well (the per-pixel
     # (k,k,C) reduce of "loop" is latency-bound, VERDICT r4 weak-6).
     # Costs P*k*k*C*4 B of extra VMEM, so "batched" wants a SMALLER
-    # pixel block (default 32 vs 256). Trace-time switch; the on-chip
-    # A/B lives in scripts/tpu_smoke.py.
+    # pixel block (default 32 vs 256). Trace-time switch.
     v = os.environ.get("DEXIRAFT_PALLAS_VARIANT", "loop")
     return v if v in ("loop", "batched") else "loop"
 
@@ -196,6 +219,7 @@ def _pallas_forward(fmap1: jax.Array, fmap2: jax.Array, coords: jax.Array,
                     radius: int, interpret=None) -> jax.Array:
     if interpret is None:
         interpret = _interpret_default()
+    _refuse_per_pixel_on_tpu(interpret)
     b, h, w, c = fmap1.shape
     h2, w2 = fmap2.shape[1:3]
     r = radius
@@ -423,6 +447,7 @@ def _fused_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
                    interpret=None) -> jax.Array:
     if interpret is None:
         interpret = _interpret_default()
+    _refuse_per_pixel_on_tpu(interpret)
     b, h, w, c = fmap1.shape
     r = radius
     k = 2 * r + 2
@@ -618,6 +643,7 @@ pallas_fused_step.defvjp(_fused_fwd, _fused_bwd)
 # row block + the (P, rows*W2) dots transient).
 _FLASH_PIXEL_BLOCK = 256
 _FLASH_ROWS = 8
+_LANES = 128
 
 
 def _flash_pixel_block() -> int:
@@ -637,12 +663,13 @@ def _hat(taps_center, length, offset, radius, p_block):
     range taps have empty support, reproducing bilinear_sampler's zero
     padding; zero-padded rows/cols get weights but multiply zeros."""
     win = 2 * radius + 1
-    pos = offset + jax.lax.broadcasted_iota(
-        jnp.float32, (p_block, win, length), 2)
-    tap = (taps_center[:, None, None]
-           + jax.lax.broadcasted_iota(jnp.float32, (p_block, win, length), 1)
-           - radius)
-    return jnp.maximum(0.0, 1.0 - jnp.abs(pos - tap))
+    # Mosaic's iota is integer-only: build the indices in int32 and cast
+    shape = (p_block, win, length)
+    pos = offset + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    tap = jax.lax.broadcasted_iota(jnp.int32, shape, 1) - radius
+    return jnp.maximum(
+        0.0, 1.0 - jnp.abs((pos - tap).astype(jnp.float32)
+                           - taps_center[:, None, None]))
 
 
 def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
@@ -774,13 +801,17 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
                                     (b, h, w, weight.shape[1]))
         return jnp.zeros((b, h, w, num_levels * win * win), jnp.float32)
     kept = [fmap2_levels[i] for i in level_ids]
-    level_shapes = tuple(f2.shape[1:3] for f2 in kept)
 
-    # pad each level's rows to the DMA block size in the STORAGE dtype
-    # (fp32/bf16/int8 — the quantized bytes are what stream HBM->VMEM)
+    # pad each level's rows to the DMA block size and its columns to the
+    # lane width, in the STORAGE dtype (fp32/bf16/int8 — the quantized
+    # bytes are what stream HBM->VMEM). Zero rows/columns read as
+    # out-of-frame. The column pad is what Mosaic needs: the kernel
+    # splits the (P, rows*w2) dots into (P, rows, w2), which it only
+    # lays out when w2 is a whole number of 128-lane tiles
     f2p = [jnp.pad(f2, ((0, 0), (0, (-f2.shape[1]) % rows),
-                        (0, 0), (0, 0)))
+                        (0, (-f2.shape[2]) % _LANES), (0, 0)))
            for f2 in kept]
+    level_shapes = tuple(f2.shape[1:3] for f2 in f2p)
     w2_max = max(s[1] for s in level_shapes)
 
     n = h * w
@@ -816,7 +847,7 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
     # the fmap2 levels: full arrays, HBM-resident — the kernel DMAs row
     # blocks on demand
     inputs += f2p
-    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * len(f2p)
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(f2p)
 
     scratch = [pltpu.VMEM((rows, w2_max, c), f2p[0].dtype),
                pltpu.VMEM((pixel_block, win * win), jnp.float32)]

@@ -24,12 +24,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # this container's jax (0.4.x) has it experimental
-    from jax.experimental.shard_map import shard_map
 
 from dexiraft_tpu.ops.corr import build_corr_pyramid, corr_lookup
 from dexiraft_tpu.parallel.layout import LAYOUT, SEQ_AXIS

@@ -68,18 +68,17 @@ def _env_int(name: str) -> int:
 # call. Elastic membership needs the opposite on both counts: a survivor
 # must OUTLIVE a dead peer, then tear the whole runtime down and
 # re-initialize at the new world size. The helpers below mirror
-# jax._src.distributed.State.initialize/shutdown with three deliberate
-# differences, each validated against this container's jax 0.4.37:
+# jax._src.distributed.State.initialize/shutdown (jax 0.9.0: the
+# factories live in jaxlib._jax and take one heartbeat_timeout in
+# seconds) with three deliberate differences:
 #
-#   * service AND client heartbeats are relaxed to effectively-never
-#     (max_missing_heartbeats ~ 1e5): the coordination service never
+#   * service AND client heartbeat timeouts are relaxed to
+#     effectively-never (~1e6 s): the coordination service never
 #     declares a silent peer dead, so it never propagates the fatal
-#     error that the default client answers with process termination
-#     (the custom missed_heartbeat_callback escape hatch is unusable
-#     here — this jaxlib's binding cannot convert the absl::Status
-#     argument and aborts with std::bad_cast). Liveness detection moves
-#     wholesale to the KV-store leases the membership runtime owns,
-#     where a missed lease is a catchable verdict, not a SIGABRT.
+#     error that the default client answers with process termination.
+#     Liveness detection moves wholesale to the KV-store leases the
+#     membership runtime owns, where a missed lease is a catchable
+#     verdict, not a SIGABRT.
 #   * the client is built with shutdown_on_destruction=False and a small
 #     shutdown_timeout, so teardown against DEAD peers is bounded: the
 #     explicit client.shutdown() below stops the client's error-polling
@@ -92,8 +91,7 @@ def _env_int(name: str) -> int:
 #     so the next elastic_initialize presents the new world to
 #     jax.process_count()/jax.devices() consistently on every member.
 
-_ELASTIC_HEARTBEAT_INTERVAL_S = 10
-_ELASTIC_MAX_MISSING_HEARTBEATS = 100_000
+_ELASTIC_HEARTBEAT_TIMEOUT_S = 1_000_000
 
 
 def elastic_initialize(coordinator_address: str, num_processes: int,
@@ -107,7 +105,7 @@ def elastic_initialize(coordinator_address: str, num_processes: int,
     is True on the epoch's rank 0 (the coordinator host).
     """
     from jax._src import distributed
-    from jaxlib import xla_extension
+    from jaxlib import _jax
 
     st = distributed.global_state
     if st.client is not None:
@@ -115,25 +113,22 @@ def elastic_initialize(coordinator_address: str, num_processes: int,
             "elastic_initialize: a distributed runtime is already "
             "installed — elastic_teardown() first (one epoch at a time)")
     if start_service:
-        st.service = xla_extension.get_distributed_runtime_service(
+        st.service = _jax.get_distributed_runtime_service(
             "[::]:" + coordinator_address.rsplit(":", 1)[1],
             int(num_processes),
-            heartbeat_interval=_ELASTIC_HEARTBEAT_INTERVAL_S,
-            max_missing_heartbeats=_ELASTIC_MAX_MISSING_HEARTBEATS)
+            heartbeat_timeout=_ELASTIC_HEARTBEAT_TIMEOUT_S)
     st.coordinator_address = coordinator_address
     st.num_processes = int(num_processes)
     st.process_id = int(process_id)
-    client = xla_extension.get_distributed_runtime_client(
+    client = _jax.get_distributed_runtime_client(
         coordinator_address, int(process_id),
         init_timeout=int(init_timeout_s),
         shutdown_timeout=int(shutdown_timeout_s),
-        heartbeat_interval=_ELASTIC_HEARTBEAT_INTERVAL_S,
-        max_missing_heartbeats=_ELASTIC_MAX_MISSING_HEARTBEATS,
+        heartbeat_timeout=_ELASTIC_HEARTBEAT_TIMEOUT_S,
         shutdown_on_destruction=False, use_compression=True)
     client.connect()
     st.client = client
-    st.preemption_sync_manager = (
-        xla_extension.create_preemption_sync_manager())
+    st.preemption_sync_manager = _jax.create_preemption_sync_manager()
     st.preemption_sync_manager.initialize(client)
     # flight-recorder stamp: the connect above is itself a collective
     # rendezvous (every member of the new world must dial in), so the

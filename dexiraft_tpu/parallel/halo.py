@@ -57,12 +57,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # this container's jax (0.4.x) has it experimental
-    from jax.experimental.shard_map import shard_map
 
 from dexiraft_tpu.config import RAFTConfig, TrainConfig
 from dexiraft_tpu.models.extractor import encoder_conv_chain
@@ -766,7 +762,7 @@ def make_halo_train_fn(cfg: RAFTConfig, tc: TrainConfig, mesh: Mesh,
         body, mesh=mesh,
         in_specs=(param_specs, repl, bsc, bsc, bsc, bsc),
         out_specs=(repl, repl, param_specs),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_halo_eval_fn(cfg: RAFTConfig, mesh: Mesh, abstract_params,
@@ -802,4 +798,4 @@ def make_halo_eval_fn(cfg: RAFTConfig, mesh: Mesh, abstract_params,
         body, mesh=mesh,
         in_specs=(param_specs, repl, bsc, bsc, bsc),
         out_specs=(bsc, bsc),
-        check_rep=False)
+        check_vma=False)

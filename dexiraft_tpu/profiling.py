@@ -7,8 +7,10 @@ Tools:
   * StepTimer: wall-clock per-step timing with warmup exclusion; the
     train Logger separately reports steps/sec and iters/sec (the
     north-star throughput metric).
-  * enable_persistent_cache(dir): persistent XLA compilation cache —
+  * enable_persistent_cache(): persistent XLA compilation cache —
     repeat launches of the same program skip the multi-minute compile.
+  * device_banner(label): the one line every entry point prints about
+    the device, the versions and the cache it runs with.
   * ThroughputReport: steps/s, pixel-iters/s (the tokens/s analog for
     this workload), and MFU from counted FLOPs — the record format
     scripts/train_bench.py emits per config.
@@ -24,34 +26,67 @@ import os
 import time
 from typing import Iterator, Optional
 
-# jax is imported inside the three functions that touch it: this module
+# jax is imported inside the functions that touch it: this module
 # sits on the serve package's import path, and the serve CLI's parser /
 # --workers pool parent must stay jax-free (seconds of import on a TPU
 # host for a process that never runs the model)
 
-# default persistent-cache location (train_cli --compile_cache,
-# scripts/train_bench.py); relative to the process CWD like logs/
-DEFAULT_CACHE_DIR = os.path.join("logs", "xla_cache")
+# persistent-cache location when JAX_COMPILATION_CACHE_DIR is unset: a
+# fixed absolute path inside the checkout. The path is part of the
+# cache's key, and train/serve/the tests chdir — a path relative to the
+# working directory would key the same program differently per directory
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
-    """Turn on XLA's persistent compilation cache at cache_dir.
+def enable_persistent_cache() -> str:
+    """Turn on XLA's persistent compilation cache; returns its directory.
 
-    Every fresh process pays full XLA compile time for the train step
-    (multi-minute at production geometry); with the cache, the second
-    and later launches deserialize the compiled executable from disk in
-    seconds. The thresholds are zeroed so even sub-second compiles cache
-    — this repo's jitted steps are exactly the artifacts worth keeping.
-    Safe to call more than once; returns the directory used.
+    The ONE owner of the cache location. Where JAX_COMPILATION_CACHE_DIR
+    is set the cache is placed from outside: JAX reads the variable
+    itself and no directory is set in code. Unset, the directory is
+    DEFAULT_CACHE_DIR. Either way the thresholds are zeroed so even
+    sub-second compiles cache — this repo's jitted steps are exactly the
+    artifacts worth keeping. Safe to call more than once.
     """
     import jax
 
-    cache_dir = cache_dir or DEFAULT_CACHE_DIR
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return cache_dir
+
+
+def device_banner(label: str, **extra) -> dict:
+    """Print (and return) one line naming what this process runs on:
+    platform, device kind and count as JAX reports them, the
+    JAX/jaxlib/libtpu versions and the compile-cache directory in use
+    (None = no persistent cache). Every entry point prints it before
+    its first compile, so no log can be read as a device run when it
+    was not one; chip_smoke.py parses it. ``extra`` rides along (what
+    --corr_impl auto resolved to, which decoder serves the loader)."""
+    import importlib.metadata
+    import json
+
+    import jax
+    import jaxlib
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    dev = jax.devices()[0]
+    info = {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices()), "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__, "libtpu": libtpu,
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+            **extra}
+    print(f"[{label}] device: {json.dumps(info)}", flush=True)
+    return info
 
 
 class ThroughputReport:

@@ -48,6 +48,7 @@ import time
 from typing import Dict, List, Optional
 
 from dexiraft_tpu.analysis.locks import OrderedLock
+from dexiraft_tpu.chips import one_chip_env, refuse_more_than_chips
 from dexiraft_tpu.serve.router import Router, RouterConfig
 
 
@@ -97,15 +98,18 @@ def build_parser() -> argparse.ArgumentParser:
 # ---- spawn-mode plumbing (shared with serve_bench / chaos_smoke) --------
 
 
-def spawn_replica(port: int, serve_args: List[str], *, host="127.0.0.1",
+def spawn_replica(port: int, serve_args: List[str], *, chip: int,
+                  host="127.0.0.1",
                   env: Optional[dict] = None) -> subprocess.Popen:
-    """One single-worker serve process on an explicit port. Detached
-    into its own session so ^C on the router's terminal reaches it
-    exactly once, through our forwarding (the serve_cli pool's
-    rationale)."""
+    """One single-worker serve process on an explicit port, held to chip
+    ``chip`` of the host (a chip belongs to one process at a time —
+    dexiraft_tpu.chips). Detached into its own session so ^C on the
+    router's terminal reaches it exactly once, through our forwarding
+    (the serve_cli pool's rationale)."""
     argv = [sys.executable, "-m", "dexiraft_tpu", "serve",
             "--host", host, "--port", str(port), *serve_args]
-    return subprocess.Popen(argv, env=env, start_new_session=True)
+    return subprocess.Popen(argv, env=one_chip_env(chip, env),
+                            start_new_session=True)
 
 
 def wait_ready(host: str, port: int, timeout_s: float = 600.0,
@@ -161,10 +165,16 @@ class _Supervisor:
                 port = self.args.port_base + i
                 self.ports[rid] = port
                 self.restarts[rid] = 0
-                self.procs[rid] = spawn_replica(port, self.serve_args,
-                                                host=self.args.host)
+                self.procs[rid] = self._spawn(rid)
                 urls[rid] = f"{self.args.host}:{port}"
         return urls
+
+    def _spawn(self, rid: str) -> subprocess.Popen:
+        # replica i listens on port_base + i and owns chip i
+        port = self.ports[rid]
+        return spawn_replica(port, self.serve_args,
+                             chip=port - self.args.port_base,
+                             host=self.args.host)
 
     def respawn(self, rid: str) -> None:
         """The drain hook: SIGTERM (replica drains itself — zero-drop),
@@ -201,9 +211,7 @@ class _Supervisor:
                     proc.kill()
                     proc.wait()
             with self._lock:
-                self.procs[rid] = spawn_replica(self.ports[rid],
-                                                self.serve_args,
-                                                host=self.args.host)
+                self.procs[rid] = self._spawn(rid)
                 self.restarts[rid] = 0  # deliberate restart, not a crash
                 self._gave_up.discard(rid)   # a drain respawn revives
         finally:
@@ -266,9 +274,7 @@ class _Supervisor:
                         continue
                     self.restarts[rid] += 1
                     self._last_restart[rid] = time.monotonic()
-                    self.procs[rid] = spawn_replica(self.ports[rid],
-                                                    self.serve_args,
-                                                    host=self.args.host)
+                    self.procs[rid] = self._spawn(rid)
 
     def start(self) -> None:
         self._thread = threading.Thread(target=self._watch,
@@ -321,6 +327,7 @@ def main(argv=None) -> None:
         if args.spawn < 1:
             raise SystemExit(f"router: --spawn must be >= 1, got "
                              f"{args.spawn}")
+        refuse_more_than_chips(args.spawn, "router --spawn")
         supervisor = _Supervisor(args, serve_args)
         urls = supervisor.spawn_all()
         print(f"[router] spawned {args.spawn} replica(s) on ports "

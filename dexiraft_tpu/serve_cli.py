@@ -34,6 +34,7 @@ import signal
 import subprocess
 import sys
 
+from dexiraft_tpu.chips import one_chip_env, refuse_more_than_chips
 from dexiraft_tpu.config import VARIANTS
 from dexiraft_tpu.serve.engine import ServeConfig, add_engine_args
 
@@ -136,10 +137,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--warmup", default=None,
                    help="comma-separated HxW geometries to pre-compile "
                         "before accepting traffic (e.g. 440x1024,368x768)")
-    p.add_argument("--compile_cache_dir", default=None,
-                   help="persistent XLA cache dir (default: the repo "
-                        "cache; workers share it for fast scale-out)")
-    p.add_argument("--no_compile_cache", action="store_true")
+    p.add_argument("--no_compile_cache", action="store_true",
+                   help="skip the persistent XLA cache (placed by "
+                        "JAX_COMPILATION_CACHE_DIR, else a fixed path in "
+                        "the checkout; workers share it for fast "
+                        "scale-out)")
     p.add_argument("--strict", action="store_true",
                    help="PR 5 drift watch with teeth: a recompile on an "
                         "already-compiled bucket signature raises "
@@ -166,9 +168,11 @@ def _run_pool(args, argv) -> None:
     # (argparse: the last occurrence of a store option wins)
     child_argv = list(argv) + ["--workers", "1", "--reuse_port",
                                "--session_ttl_s", "0"]
+    refuse_more_than_chips(args.workers, "serve --workers")
     children = []
     for i in range(args.workers):
-        env = dict(os.environ, DEXIRAFT_SERVE_WORKER=str(i))
+        # worker i owns chip i: a chip belongs to one process at a time
+        env = dict(one_chip_env(i), DEXIRAFT_SERVE_WORKER=str(i))
         # own session: a foreground ^C delivers SIGINT to the whole
         # terminal process group, and _forward would deliver it AGAIN —
         # two signals is the children's abort gesture, not a drain.
@@ -220,6 +224,10 @@ def _load(args):
 
     impl, fused = resolve_corr_impl_args(args, jax.devices()[0].platform,
                                          "serve")
+    from dexiraft_tpu.profiling import device_banner
+
+    device_banner("serve", corr_impl_arg=args.corr_impl, corr_impl=impl,
+                  fused_update=fused)
     cfg = VARIANTS[args.variant](small=args.small,
                                  mixed_precision=args.mixed_precision,
                                  corr_impl=impl,
@@ -399,8 +407,7 @@ def _serve_one(args) -> None:
     if not args.no_compile_cache:
         from dexiraft_tpu.profiling import enable_persistent_cache
 
-        cache = enable_persistent_cache(args.compile_cache_dir)
-        print(f"[serve] compile cache: {cache}", flush=True)
+        enable_persistent_cache()
 
     cfg, variables = _load(args)
 

@@ -59,32 +59,35 @@ def create_state(
     """Initialize params (Kaiming/Xavier per module) and optimizer state.
 
     Init runs on small dummy shapes — RAFT is fully convolutional, so
-    parameters are shape-independent of the training resolution.
+    parameters are shape-independent of the training resolution — and
+    as ONE jitted program: eagerly it is a compile per distinct op and
+    shape (some 1,200 of them for v5), minutes of set-up on a cold chip.
     """
     model = RAFT(cfg)
     bs = batch_size if batch_size is not None else 1
     init_size = image_size if image_size is not None else (64, 64)
     img_shape, edge_shape = model_inputs_shape(cfg, bs, init_size)
-
-    init_rng, state_rng = jax.random.split(rng)
-    dummy = jnp.zeros(img_shape, jnp.float32)
-    kwargs = {}
-    if edge_shape is not None:
-        e = jnp.zeros(edge_shape, jnp.float32)
-        kwargs = dict(edges1=e, edges2=e)
-    variables = model.init(init_rng, dummy, dummy, iters=1, train=False, **kwargs)
-
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
     tx = make_optimizer_from(tc)
-    opt_state = tx.init(params)
-    return TrainState(
-        step=jnp.zeros((), jnp.int32),
-        params=params,
-        batch_stats=batch_stats,
-        opt_state=opt_state,
-        rng=state_rng,
-    )
+
+    def init(rng: jax.Array) -> TrainState:
+        init_rng, state_rng = jax.random.split(rng)
+        dummy = jnp.zeros(img_shape, jnp.float32)
+        kwargs = {}
+        if edge_shape is not None:
+            e = jnp.zeros(edge_shape, jnp.float32)
+            kwargs = dict(edges1=e, edges2=e)
+        variables = model.init(init_rng, dummy, dummy, iters=1, train=False,
+                               **kwargs)
+        params = variables["params"]
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=params,
+            batch_stats=variables.get("batch_stats", {}),
+            opt_state=tx.init(params),
+            rng=state_rng,
+        )
+
+    return jax.jit(init)(rng)
 
 
 def make_optimizer_from(tc: TrainConfig) -> optax.GradientTransformation:

@@ -17,6 +17,7 @@ sharded over the data mesh axis, VAL_FREQ checkpoint+validate, final save.
 from __future__ import annotations
 
 import argparse
+import json
 import sys
 from typing import Dict, Optional
 
@@ -116,10 +117,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "current step runs; 0 disables)")
     p.add_argument("--compile_cache", action="store_true",
                    help="persistent XLA compilation cache — repeat "
-                        "launches skip the multi-minute compile")
-    p.add_argument("--compile_cache_dir", default=None,
-                   help="cache location (default logs/xla_cache); "
-                        "implies --compile_cache")
+                        "launches skip the multi-minute compile (placed "
+                        "by JAX_COMPILATION_CACHE_DIR, else a fixed path "
+                        "in the checkout; profiling.enable_persistent_"
+                        "cache)")
     p.add_argument("--validation", nargs="*", default=None,
                    choices=sorted(_VAL_ITERS),
                    help="default: the preset's per-stage validation sets")
@@ -367,11 +368,13 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
     jitted step) is rebuilt here against the new world."""
     import os.path as osp
 
+    from dexiraft_tpu.data import native
     from dexiraft_tpu.data.datasets import fetch_dataset
     from dexiraft_tpu.data.loader import Loader
     from dexiraft_tpu.data.prefetch import prefetch_to_device
     from dexiraft_tpu.parallel import layout
     from dexiraft_tpu.parallel.layout import make_train_mesh
+    from dexiraft_tpu.profiling import device_banner, enable_persistent_cache
     from dexiraft_tpu.resilience import (
         Coordinator,
         HangWatchdog,
@@ -400,30 +403,22 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
         print(f"[mesh] {dict(mesh.shape)} over {len(jax.devices())} "
               f"devices (batch {tc.batch_size})")
 
-    if args.compile_cache or args.compile_cache_dir:
-        if layout.LAYOUT.has_fsdp(mesh):
-            # a persistent-cache HIT of the donated fsdp step crashes
-            # this backend (deserialized executable segfault, jax
-            # 0.4.37 CPU — bisected in the fsdp PR; cold writes are
-            # fine, which makes the crash land on the SECOND launch);
-            # refuse loudly rather than let a relaunch die mid-warmup.
-            # docs/perf.md "Sharded state (fsdp)" has the story.
-            print("[cache] persistent compile cache DISABLED: "
-                  "cache-hit fsdp executables crash this backend "
-                  "(docs/perf.md 'Sharded state (fsdp)')")
-        else:
-            from dexiraft_tpu.profiling import enable_persistent_cache
-
-            print(f"[cache] persistent XLA compile cache: "
-                  f"{enable_persistent_cache(args.compile_cache_dir)}")
+    if args.compile_cache:
+        enable_persistent_cache()
+    device_banner("train", corr_impl=cfg.corr_impl,
+                  fused_update=cfg.fused_update, mesh=dict(mesh.shape),
+                  decoder=native.status())
     state = create_state(jax.random.PRNGKey(tc.seed), cfg, tc)
     print(f"Parameter Count: {param_count(state.params)}")
     fsdp_live = layout.LAYOUT.has_fsdp(mesh)
-    if fsdp_live:
-        # storage layout from step one: params/opt_state land sharded,
-        # so every restore below (resume, rollback, partial) restores
-        # per shard into the template's resolved shardings
-        state = layout.shard_state(state, mesh)
+    # storage layout from step one — the layout the step pins at its
+    # jit boundary: on an fsdp mesh params/opt_state land sharded, so
+    # every restore below (resume, rollback, partial) restores per
+    # shard into the template's resolved shardings; on every mesh the
+    # first call then sees the types the second will (a state that
+    # first arrives unplaced carries no mesh in its types, and the step
+    # would trace and compile twice)
+    state = layout.shard_state(state, mesh)
 
     # last checkpoint that belongs to THIS trajectory — the only valid
     # rollback target. A stale dir from a previous experiment must never
@@ -639,6 +634,7 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
                                 directory=ckpt_dir)
     metrics = None
     preempted = False
+    batch_devices = 0  # devices one batch leaf spans, from the first step
 
     def note_flush(info) -> None:
         """Surface one committed (or failed) async flush in the logger:
@@ -740,6 +736,7 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
                     # steps, not compiles)
                     watch = jaxguards.RecompileWatch(f"train[{tc.name}]")
                     watch.mark_warm()
+                    batch_devices = len(batch["image1"].sharding.device_set)
                     if args.strict:
                         guard_stack.enter_context(
                             jax.transfer_guard("disallow"))
@@ -1003,6 +1000,14 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
               f"this run (EWMA step {wd.ewma_s:.2f}s)")
     logger.close()
     print(f"[prefetch] {batches.summary()}")
+    # where the run lived: how many devices a batch leaf spanned and
+    # what each mesh device holds (the CPU backend reports no memory
+    # statistics) — chip_smoke.py --chips 4 reads this line
+    print("[train] placement: " + json.dumps({
+        "batch_devices": batch_devices,
+        "bytes_in_use": {
+            str(d.id): (d.memory_stats() or {}).get("bytes_in_use")
+            for d in mesh.devices.flat}}))
     if loader.stats.faults:
         print(f"[pipeline] {loader.stats.summary()}")
     # end-of-run sentinel verdict: strict fails the run on any

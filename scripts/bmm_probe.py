@@ -42,14 +42,6 @@ def probe(name, batch, m, k, n, dtype=jnp.float32):
 
 def main():
     print(f"platform={jax.devices()[0].platform}", file=sys.stderr)
-    t = jax.jit(lambda x: jnp.sum(x))
-    float(t(jnp.ones((8, 8))))
-    t0 = time.perf_counter()
-    for _ in range(3):
-        float(t(jnp.ones((8, 8))))
-    rtt = (time.perf_counter() - t0) / 3
-    print(f"rtt {rtt * 1e3:.1f} ms (already amortized /32 below: "
-          f"{rtt / ITERS * 1e3:.2f} ms/call)")
 
     probe("L0 y-einsum b14080 9x55x128", 14080, 9, 55, 128)
     probe("L0 x-einsum b14080 9x128x9 ", 14080, 9, 128, 9)

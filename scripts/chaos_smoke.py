@@ -455,7 +455,7 @@ def phase_router_failover(tmp: str) -> dict:
     env = {**os.environ,
            "PYTHONPATH": repo + os.pathsep + os.environ.get("PYTHONPATH",
                                                             "")}
-    procs = {f"r{i}": spawn_replica(p, serve_args, env=env)
+    procs = {f"r{i}": spawn_replica(p, serve_args, chip=i, env=env)
              for i, p in enumerate(ports)}
     router = None
     try:

@@ -1,11 +1,11 @@
 """On-chip profile of the v5 eval-forward PRELUDE — the part that gates
-the end-to-end headline (VERDICT r1: ~104 ms measured against a ~4 ms
-ideal-MXU floor, i.e. ~4% MXU efficiency, cause unprofiled).
+the end-to-end headline (its time against the ~4 ms ideal-MXU floor is
+not measured on today's code).
 
 Times each prelude component standalone at its production shape
 (B=2: both frames batched through one DexiNed call; 440x1024 input),
-in the production dtype (bf16 under mixed precision), RTT-corrected like
-bench.py. The UpConv stages are timed in BOTH transposed-conv
+in the production dtype (bf16 under mixed precision), around
+block_until_ready like bench.py. The UpConv stages are timed in BOTH transposed-conv
 implementations ("transpose" = lax.conv_transpose on the input-dilated
 signal; "subpixel" = the numerically identical phase decomposition,
 models/dexined.py:SubpixelConvTranspose) — the A/B that decides
@@ -50,54 +50,31 @@ def main():
     from dexiraft_tpu.models.extractor import BasicEncoder
     from dexiraft_tpu.ops.corr import build_corr_pyramid
 
-    trivial = jax.jit(lambda x: jnp.sum(x))
-    float(trivial(jnp.ones((8, 8))))
-
-    def rtt(reps=4):
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            float(trivial(jnp.ones((8, 8))))
-        return (time.perf_counter() - t0) / reps
-
     results = {}
 
     def bench(name, module, shapes, method=None):
-        """Init `module` on random inputs of `shapes`, time jitted apply."""
+        """Init `module` on random inputs of `shapes`, time jitted apply
+        around block_until_ready. A component that throws fails the run."""
         keys = jax.random.split(jax.random.PRNGKey(0), len(shapes))
         xs = [jax.random.normal(k, s, jnp.float32) for k, s in zip(keys, shapes)]
-        try:
-            variables = jax.jit(lambda *a: module.init(
-                jax.random.PRNGKey(1), *a))(*xs)
+        variables = jax.jit(lambda *a: module.init(
+            jax.random.PRNGKey(1), *a))(*xs)
+        fwd = jax.jit(lambda *a: module.apply(variables, *a))
 
-            @jax.jit
-            def fwd(*a):
-                out = module.apply(variables, *a)
-                leaves = jax.tree_util.tree_leaves(out)
-                return sum(jnp.sum(l.astype(jnp.float32)) for l in leaves)
+        gflop = None
+        cost = fwd.lower(*xs).compile().cost_analysis()
+        if cost and cost.get("flops"):
+            gflop = cost["flops"] / 1e9
 
-            gflop = None
-            try:
-                cost = fwd.lower(*xs).compile().cost_analysis()
-                if cost and cost.get("flops"):
-                    gflop = cost["flops"] / 1e9
-            except Exception:
-                pass  # cost model optional; timings are the point
-
-            float(fwd(*xs))  # compile
-            floor = rtt()
-            t0 = time.perf_counter()
-            for _ in range(args.reps):
-                float(fwd(*xs))
-            raw = (time.perf_counter() - t0) / args.reps
-            dtc = raw - floor if raw > floor else raw
-            results[name] = dtc
-            eff = (f"  {gflop:8.1f} GFLOP -> {gflop / dtc / 1e3:6.2f} TFLOP/s"
-                   if gflop else "")
-            print(f"{name:>28s}: {dtc * 1e3:8.2f} ms   "
-                  f"(raw {raw * 1e3:.2f}, rtt {floor * 1e3:.2f}){eff}",
-                  flush=True)
-        except Exception as e:
-            print(f"{name:>28s}: FAILED {type(e).__name__}: {e}", flush=True)
+        jax.block_until_ready(fwd(*xs))  # compile
+        t0 = time.perf_counter()
+        for _ in range(args.reps):
+            jax.block_until_ready(fwd(*xs))
+        dt_s = (time.perf_counter() - t0) / args.reps
+        results[name] = dt_s
+        eff = (f"  {gflop:8.1f} GFLOP -> {gflop / dt_s / 1e3:6.2f} TFLOP/s"
+               if gflop else "")
+        print(f"{name:>28s}: {dt_s * 1e3:8.2f} ms{eff}", flush=True)
 
     B = 2  # both frames in one batched DexiNed call (models/raft.py:190)
     H, W = 440, 1024
@@ -151,14 +128,12 @@ def main():
         return sum(jnp.sum(v) for v in pyr.levels)
 
     f1 = jax.random.normal(jax.random.PRNGKey(2), (1, H // 8, W // 8, 256))
-    float(vol(f1, f1))
-    floor = rtt()
+    jax.block_until_ready(vol(f1, f1))
     t0 = time.perf_counter()
     for _ in range(args.reps):
-        float(vol(f1, f1))
+        jax.block_until_ready(vol(f1, f1))
     raw = (time.perf_counter() - t0) / args.reps
-    print(f"{'corr_pyramid_build':>28s}: "
-          f"{(raw - floor if raw > floor else raw) * 1e3:8.2f} ms", flush=True)
+    print(f"{'corr_pyramid_build':>28s}: {raw * 1e3:8.2f} ms", flush=True)
 
     # --- the refinement-loop components at loop shapes (B=2: the dual
     # streams share one batch; 55x128 = 440x1024 at 1/8) ---
@@ -186,19 +161,13 @@ def main():
             coords = coords_grid(2, h8, w8) + 1.3
             return jnp.sum(pyr(coords))
 
-        try:
-            float(lookup_once(f1, f2))
-            floor = rtt()
-            t0 = time.perf_counter()
-            for _ in range(args.reps):
-                float(lookup_once(f1, f2))
-            raw = (time.perf_counter() - t0) / args.reps
-            dtc = raw - floor if raw > floor else raw
-            print(f"{'build+lookup[' + impl + ']':>28s}: {dtc * 1e3:8.2f} ms",
-                  flush=True)
-        except Exception as e:
-            print(f"{'build+lookup[' + impl + ']':>28s}: FAILED {e}",
-                  flush=True)
+        jax.block_until_ready(lookup_once(f1, f2))
+        t0 = time.perf_counter()
+        for _ in range(args.reps):
+            jax.block_until_ready(lookup_once(f1, f2))
+        raw = (time.perf_counter() - t0) / args.reps
+        print(f"{'build+lookup[' + impl + ']':>28s}: {raw * 1e3:8.2f} ms",
+              flush=True)
 
     ups = [k for k in results if k.startswith("up") and "[" in k]
     t_total = sum(v for k, v in results.items()

@@ -178,7 +178,7 @@ def reap_children(procs, timeout: float = 300.0):
 def patch_orbax_kv_barriers(cap_timeout_s=None) -> None:
     """Reroute orbax's process-sync onto its distributed-client barrier.
 
-    orbax 0.7.0's ``sync_global_processes`` defaults to an XLA allgather
+    orbax's ``sync_global_processes`` defaults to an XLA allgather
     (``multihost_utils.sync_global_devices``) that this container's CPU
     backend cannot run ("Multiprocess computations aren't implemented on
     the CPU backend") — but orbax already ships the non-XLA alternative,
@@ -201,10 +201,10 @@ def patch_orbax_kv_barriers(cap_timeout_s=None) -> None:
     so real flush skew is milliseconds.
     """
     from orbax.checkpoint import multihost as omh_pkg
-    from orbax.checkpoint.multihost import utils as omh
+    from orbax.checkpoint._src.multihost import multihost as omh
 
     def kv_sync(name, *, timeout=None, processes=None,
-                barrier_sync_fn=None):
+                barrier_sync_fn=None, **_unused):
         from jax._src import distributed
 
         if barrier_sync_fn is None and distributed.global_state.client \

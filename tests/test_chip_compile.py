@@ -1,0 +1,130 @@
+"""The kernels of the main path, compiled for the chip without the chip.
+
+The TPU's compiler is installed here and compiles for a described
+topology (`v5e:2x2`): what Mosaic refuses on the chip it refuses here, at
+no chip time. Interpret-mode parity tests cannot see this — all three
+kernel families passed them while none compiled. Each case is the kernel
+alone at the shapes the v5 forward feeds it at 440x1024 (fmaps
+1x55x128x256, 4 levels, radius 4), for every configuration
+`--corr_impl auto` can resolve to on a TPU (flash, fused, at each
+`--corr_dtype`) and the unfused flash lookup. A compile that passes is
+not a chip run: chip_smoke.py runs the same configuration on the chip
+against allpairs.
+
+All cases live in this one file: one process at a time may hold the
+topology plug-in's lock. The file sorts inside the part of the suite the
+tier-1 clock reaches (hence no `test_zz*` name).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dexiraft_tpu.config import resolve_corr_impl
+from dexiraft_tpu.ops import pallas_corr as pc
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+
+B, H, W, C = 1, 55, 128, 256
+LEVELS, RADIUS, FEAT = 4, 4, 256
+DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e chip; skipped where the topology
+    cannot be described. The persistent compilation cache is off around
+    these compiles: an entry written for a described chip cannot be read
+    back without one, and the next run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or the lock is held elsewhere
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(chip, dtype):
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    levels = tuple(sds((B, H >> i, W >> i, C), DTYPES[dtype])
+                   for i in range(LEVELS))
+    win2 = (2 * RADIUS + 1) ** 2
+    return dict(f1=sds((B, H, W, C)), coords=sds((B, H, W, 2)),
+                levels=levels, weight=sds((LEVELS * win2, FEAT)),
+                bias=sds((FEAT,)))
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_auto_on_tpu_names_what_is_compiled_here():
+    assert resolve_corr_impl("auto", "tpu") == ("flash", True)
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8"])
+def test_flash_fused_step_compiles_for_v5e(chip, dtype):
+    """What `auto` serves: one kernel per refinement iteration."""
+    s = _shapes(chip, dtype)
+    text = _compiled_text(
+        lambda f1, lv, co, w, b: pc.flash_fused_step(
+            f1, lv, co, w, b, RADIUS, False),
+        s["f1"], s["levels"], s["coords"], s["weight"], s["bias"])
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype,level", [("fp32", 0), ("int8", 0),
+                                         ("fp32", 3), ("bf16", 3)])
+def test_flash_lookup_compiles_for_v5e(chip, dtype, level):
+    """corr_impl="flash" without fused_update, at the widest level and
+    at the narrowest (16 columns, padded to the lane width)."""
+    s = _shapes(chip, dtype)
+    text = _compiled_text(
+        lambda f1, f2, co: pc.flash_local_corr_level(
+            f1, f2, co, RADIUS, False),
+        s["f1"], s["levels"][level], s["coords"])
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_per_pixel_family_is_still_refused(chip, fused):
+    """corr_impl="pallas" is withdrawn from every on-chip default because
+    Mosaic refuses its window load (config.PALLAS_TPU_REFUSAL). When
+    this starts passing the kernel compiles again: re-admit the family
+    here, in bench.py's legs and in the refusal, deliberately."""
+    s = _shapes(chip, "fp32")
+    if fused:
+        fn = lambda f1, lv, co, w, b: pc.pallas_fused_step(  # noqa: E731
+            f1, lv, co, w, b, RADIUS, False)
+        args = (s["f1"], s["levels"], s["coords"], s["weight"], s["bias"])
+    else:
+        fn = lambda f1, f2, co: pc.pallas_local_corr_level(  # noqa: E731
+            f1, f2, co, RADIUS, False)
+        args = (s["f1"], s["levels"][0], s["coords"])
+    with pytest.raises(Exception, match="multiple of 8"):
+        _compiled_text(fn, *args)
+
+
+def test_per_pixel_family_refuses_a_tpu_backend(monkeypatch):
+    """On a TPU backend the per-pixel kernels fail at trace time with the
+    compiler's reason, and the interpret switch is an error, not a
+    mode."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        pc._refuse_per_pixel_on_tpu(False)
+    monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="DEXIRAFT_PALLAS_INTERPRET"):
+        pc._interpret_default()

@@ -29,8 +29,12 @@ def test_counted_flops_never_raises_on_junk():
 
 
 def test_chip_peak_table_sane():
-    assert all(1e13 < v < 1e16 for v in bench.CHIP_PEAK_BF16_FLOPS.values())
-    # the chip this project benches on must be present under both the
-    # device_kind spellings seen from jax
-    assert "TPU v5 lite" in bench.CHIP_PEAK_BF16_FLOPS
-    assert bench.CHIP_PEAK_BF16_FLOPS["TPU v5 lite"] == 197e12
+    import pytest
+
+    # the table holds the device this project is measured on, under the
+    # device_kind jax reports for it; any other kind is an error where
+    # a utilisation is printed, never a default
+    assert bench.CHIP_PEAK_BF16_FLOPS == {"TPU v5 lite": 197e12}
+    assert bench.chip_peak_bf16_flops("TPU v5 lite") == 197e12
+    with pytest.raises(SystemExit, match="no bf16 peak on record"):
+        bench.chip_peak_bf16_flops("TPU v9")

@@ -98,8 +98,8 @@ def test_custom_vjp_grads():
 
 
 def test_pixel_block_override_identical(monkeypatch):
-    # the tuning knob (DEXIRAFT_PALLAS_PIXEL_BLOCK, swept on-chip by
-    # tpu_smoke) must only change the grid partition, never the values
+    # the tuning knob (DEXIRAFT_PALLAS_PIXEL_BLOCK) must only change
+    # the grid partition, never the values
     monkeypatch.delenv("DEXIRAFT_PALLAS_PIXEL_BLOCK", raising=False)
     f1, f2, coords = _setup(jax.random.PRNGKey(2))
     ref = pallas_local_corr_level(f1, f2, coords, 4, True)
