@@ -1,0 +1,18 @@
+"""Model FLOP utilisation: FLOPs the algorithm needs per unit of work
+(benchmarks/flops.py on the plain form: allpairs, no remat; recomputed
+work not counted) x units per second of the measured window, over
+chips x the bf16 peak of peaks.json. An end-to-end utilisation, not a
+kernel's roofline share.
+
+Eval cells, from pairs a second; `train_model_flops_util_pct` is the train cells' reading,
+from steps a second.
+"""
+
+
+def read(obs):
+    c = obs.counters
+    if obs.peaks is None or "flops_per_unit" not in c:
+        return None
+    batches_per_s = obs.end_to_end["eval_pairs_per_s"] / c["batch"]
+    return (c["flops_per_unit"] * batches_per_s
+            / (obs.chips * obs.peaks["bf16_flops_per_s"]) * 100)
