@@ -21,36 +21,92 @@ window of serve_bench/train_bench. ``RecompileWatch`` observes without
 raising — it powers the one-line drift warning non-strict runs emit.
 
 Monitoring listeners cannot be unregistered (jax.monitoring has no
-per-listener removal), so ONE module-level listener is installed lazily
-on first use and only ever increments a counter; entering/leaving
-strict_mode snapshots it.
+per-listener removal), so ONE module-level listener is installed,
+once, when this module is imported (an entry point's set-up is mostly
+over before it makes its first watch, and the `jax:` spans below are
+that set-up); entering/leaving strict_mode snapshots its compile
+counter.
+
+The same listener keeps what set-up is made of: seconds and count of
+JAX's own compile-path events in the process-wide span table
+(`profiling.snapshot("jax:")`): `jax:trace` (Python tracing to a jaxpr),
+`jax:lower` (jaxpr to an MLIR module), `jax:backend_compile` (XLA's
+compile) and `jax:cache_load` (reading a persistent-cache entry). The
+seconds are SELF times, so the four add up to no more than the wall
+time they were spent in: JAX's events nest (every inner `jit` logs a
+trace of its own inside the outer's, and in jax 0.9
+`backend_compile_duration` is logged around `compile_or_get_cached`,
+so on a cache hit it fires and holds the whole cache read), and an
+event's seconds here leave out the events that ran inside it. So
+`jax:backend_compile` is net of `jax:cache_load`: near zero on a warm
+cache. The drift count keeps counting the raw compile event.
+`jax_at_warm()` is the copy of those totals `RecompileWatch.mark_warm()`
+last put aside: what set-up cost, without what compiles afterwards.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import sys
+import threading
+import time
 from typing import Iterator, Optional
 
 import jax
 
+from dexiraft_tpu import profiling
 from dexiraft_tpu.analysis.locks import OrderedLock
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# jax.monitoring event -> span name in profiling's table
+_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax:lower",
+    _COMPILE_EVENT: "jax:backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax:cache_load",
+}
 
 _lock = OrderedLock("analysis.guards.listener")
 _installed = False
 _count = 0
+_at_warm: dict = {}
+_tls = threading.local()
 
 
 class RecompileBudgetExceeded(RuntimeError):
     """Raised when a strict_mode region compiles past its pinned budget."""
 
 
-def _listener(event: str, durations: float, **_kw) -> None:
+def _listener(event: str, duration: float, **_kw) -> None:
     global _count
     if event == _COMPILE_EVENT:
         _count += 1
+    name = _DURATION_SPANS.get(event)
+    if name is not None:
+        profiling.add(name, _self_seconds(duration))
+
+
+def _self_seconds(duration: float) -> float:
+    """`duration` of the event that just ended on this thread, less the
+    events that ran inside it. The listener is called as an event ends,
+    so the event began `duration` ago; the events already seen whose
+    midpoint is later than that were inside it (midpoints, because this
+    clock read is microseconds after the one JAX ended the event with:
+    a sibling that ended just before this event began must not count,
+    and an error is at most the length of such a sibling). They were
+    each already made net of their own inside, so only the outermost of
+    them remain. Own reads are on the monotonic clock; only lengths
+    come from JAX."""
+    now = time.perf_counter()
+    start = now - duration
+    seen = _tls.__dict__.setdefault(
+        "seen", collections.deque(maxlen=profiling.SPAN_WINDOW))
+    inside = 0.0
+    while seen and seen[-1][0] > start:
+        inside += seen.pop()[1]
+    seen.append((now - duration / 2, duration))
+    return max(duration - inside, 0.0)
 
 
 def _ensure_listener() -> None:
@@ -59,6 +115,16 @@ def _ensure_listener() -> None:
         if not _installed:
             jax.monitoring.register_event_duration_secs_listener(_listener)
             _installed = True
+
+
+_ensure_listener()
+
+
+def jax_at_warm() -> dict:
+    """`profiling.snapshot("jax:")` as the last `mark_warm()` of any
+    watch saw it ({} before the first): a run's set-up, when warm is
+    marked where the timed window starts and not again."""
+    return _at_warm
 
 
 def compile_count() -> int:
@@ -111,8 +177,10 @@ class RecompileWatch:
         # re-baseline — writing the stale count would re-expose the
         # window's own compiles as drift. watch -> listener (via
         # compile_count) is the declared LOCK_ORDER direction.
+        global _at_warm
         with self._slock:
             self._warm_at = compile_count()
+        _at_warm = profiling.snapshot("jax:")
 
     @property
     def drift(self) -> int:

@@ -35,6 +35,7 @@ from typing import Dict, Iterator, Optional, Tuple
 import numpy as np
 
 from dexiraft_tpu.analysis.locks import OrderedLock
+from dexiraft_tpu.profiling import reset as reset_spans, span
 
 Batch = Dict[str, np.ndarray]
 
@@ -345,8 +346,12 @@ class Loader:
         return np.arange(len(self.dataset))
 
     def _decode(self, epoch: int, index: int) -> Batch:
-        rng = np.random.default_rng((self.seed, epoch, index))
-        return self.dataset.sample(int(index), rng)
+        # thread workers only: a process worker runs _process_decode in
+        # another process, whose span table nobody reads, so it reports
+        # nothing
+        with span("loader:decode"):
+            rng = np.random.default_rng((self.seed, epoch, index))
+            return self.dataset.sample(int(index), rng)
 
     def _note_decode_ok(self) -> None:
         """Hook: a sample decoded successfully (RecordLoader counts
@@ -407,6 +412,9 @@ class Loader:
         out: "queue.Queue" = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
         self.positions.clear()  # one live stream per Loader
+        # ... and one account of it: a stream drained earlier (a bench's
+        # warm-up, a rate probe) must not leak into this one's spans
+        reset_spans("loader:")
 
         # a trailing partial global batch cannot be split evenly across
         # hosts — some would yield one more batch than others and the
@@ -459,12 +467,15 @@ class Loader:
         feeder.start()
         try:
             while True:
-                work = out.get()
-                if isinstance(work, _FeederError):
-                    raise work.exc
-                epoch_b, offset_b, pairs = work
-                samples = [self._resolve(pools, epoch_b, i, f)
-                           for i, f in pairs]
+                # the consumer's wait: for the feeder's queue, then for
+                # each sample's decode future (retries included)
+                with span("loader:wait"):
+                    work = out.get()
+                    if isinstance(work, _FeederError):
+                        raise work.exc
+                    epoch_b, offset_b, pairs = work
+                    samples = [self._resolve(pools, epoch_b, i, f)
+                               for i, f in pairs]
                 good = [s for s in samples if s is not None]
                 if not good:
                     # nothing in this batch survived; drop it rather
@@ -478,14 +489,17 @@ class Loader:
                           f"dropped ({self.stats.dropped_batches} so far)",
                           flush=True)
                     continue
-                n_good = len(good)
-                while len(good) < len(pairs):
-                    # backfill skipped slots by replicating survivors —
-                    # batch shape stays stable (one compiled step), and
-                    # a duplicated good sample beats a crashed run
-                    good.append(good[len(good) % n_good])
+                with span("loader:stack"):
+                    n_good = len(good)
+                    while len(good) < len(pairs):
+                        # backfill skipped slots by replicating
+                        # survivors — batch shape stays stable (one
+                        # compiled step), and a duplicated good sample
+                        # beats a crashed run
+                        good.append(good[len(good) % n_good])
+                    batch = _stack(good)
                 self.positions.append((epoch_b, offset_b))
-                yield _stack(good)
+                yield batch
         finally:
             stop.set()
             pools.shutdown()

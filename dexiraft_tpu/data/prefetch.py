@@ -10,11 +10,16 @@ The train step then starts without waiting on PCIe/DCN: its arguments
 are already resident (the classic double-buffering pattern; depth=2 is
 one buffer computing + one filling).
 
-Stall accounting: after warm-fill, any time spent inside `next()` of
-the HOST iterator is chip-starvation time (the host failed to keep
-ahead) — the number `scripts/train_bench.py` reports as
-`prefetch_stall`. The device_put enqueue itself is non-blocking, so it
-is deliberately not counted as stall.
+Stall accounting: `stall_s` is the time spent inside `next()` of the
+HOST iterator after warm-fill (span `prefetch:host_next`), exposed to
+the chips or not — the number `scripts/train_bench.py` reports as
+`prefetch_stall`. For a `Loader` it holds the consumer-side work of
+every batch (`loader:wait` for the decode futures, `loader:stack`), so
+it is above zero when no chip ever waits: with `depth` batches in
+flight it is hidden behind the step, and whether the chips starve is
+read from the device trace's idle share, not from here. The device_put
+only enqueues; it has a span of its own (`prefetch:put`) and is not in
+`stall_s`.
 
 Donation interplay: the jitted step donates only its STATE argument
 (donate_argnums=0), never the batch, so a prefetched batch that is
@@ -24,8 +29,9 @@ still queued for a future step is never invalidated by the current one.
 from __future__ import annotations
 
 import collections
-import time
 from typing import Any, Callable, Iterable, Iterator, Optional
+
+from dexiraft_tpu.profiling import reset as reset_spans, span
 
 # parallel.mesh (and with it jax) is imported lazily: data/__init__ must
 # stay importable without jax so the Loader's SPAWNED process workers
@@ -33,16 +39,18 @@ from typing import Any, Callable, Iterable, Iterator, Optional
 
 
 class PrefetchStats:
-    """Host-side starvation accounting for a DevicePrefetcher."""
+    """Host-iterator time accounting for a DevicePrefetcher."""
 
     def __init__(self) -> None:
         self.reset()
 
     def reset(self) -> None:
-        """Zero the counters (warm_fill_s included) — e.g. to exclude a
-        bench's warmup steps from the steady-state record."""
+        """Zero the counters (warm_fill_s included) and the prefetcher's
+        spans (`prefetch:*`) — e.g. to exclude a bench's warmup steps
+        from the steady-state record."""
+        reset_spans("prefetch:")
         self.batches = 0  # batches yielded (after warm-fill)
-        self.stall_s = 0.0  # time blocked on the HOST iterator
+        self.stall_s = 0.0  # time inside the HOST iterator's next()
         self.stalls = 0  # yields on which the host made us wait
         self.warm_fill_s = 0.0  # initial fill (excluded from stall_s)
 
@@ -109,23 +117,27 @@ class DevicePrefetcher:
     def _pull(self) -> bool:
         """Enqueue one more host batch's transfer; False when exhausted.
         The put only ENQUEUES (async dispatch) — the host-iterator next()
-        is the only blocking part, and that is what gets timed."""
+        is the only blocking part, and that is what stall_s times."""
         if self._exhausted:
             return False
-        t0 = time.perf_counter()
-        try:
-            batch = next(self._it)
-        except StopIteration:
-            self._exhausted = True
+        with span("prefetch:host_next") as host_next:
+            try:
+                batch = next(self._it)
+            except StopIteration:
+                self._exhausted = True
+        if self._exhausted:
+            # the call that finds the end is in the span and, as
+            # before, not in stall_s
             return False
-        dt = time.perf_counter() - t0
+        dt = host_next.seconds
         if self._warm:
             self.stats.stall_s += dt
             if dt > self.STALL_EPS_S:
                 self.stats.stalls += 1
         else:
             self.stats.warm_fill_s += dt
-        self._buf.append(self.put(batch))
+        with span("prefetch:put"):
+            self._buf.append(self.put(batch))
         return True
 
     def __iter__(self) -> Iterator[Any]:

@@ -86,6 +86,7 @@ def avg_pool_2x2(x: jax.Array) -> jax.Array:
     return x.mean(axis=(2, 4))
 
 
+@jax.named_scope("build_corr_pyramid")
 def build_corr_pyramid(
     fmap1: jax.Array, fmap2: jax.Array, num_levels: int = 4, radius: int = 4,
     dtype: str = "fp32",
@@ -204,6 +205,7 @@ def interp_window(vol: jax.Array, centers: jax.Array, radius: int,
     return window.reshape(vol.shape[0], win * win)
 
 
+@jax.named_scope("corr_lookup")
 def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
     """Sample a (2r+1)^2 window around ``coords / 2^i`` at every level.
 

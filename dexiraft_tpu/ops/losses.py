@@ -36,6 +36,7 @@ def flow_metrics(flow_pred: jax.Array, flow_gt: jax.Array, valid: jax.Array) -> 
     }
 
 
+@jax.named_scope("sequence_loss")
 def sequence_loss(
     flow_preds: jax.Array,
     flow_gt: jax.Array,

@@ -280,6 +280,7 @@ def _pallas_forward(fmap1: jax.Array, fmap2: jax.Array, coords: jax.Array,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((pixel_block, k, k, c), jnp.float32)],
             interpret=interpret,
+            name="pallas_corr_batched",
         )(sx_flat, sy_flat, f1_flat, f2p, frac_flat, sx_flat, sy_flat)
     else:
         kernel = functools.partial(_corr_kernel, radius=r, h2=h2, w2=w2)
@@ -291,6 +292,7 @@ def _pallas_forward(fmap1: jax.Array, fmap2: jax.Array, coords: jax.Array,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((pixel_block, k * k), jnp.float32)],
             interpret=interpret,
+            name="pallas_corr",
         )(sx_flat, sy_flat, f1_flat, f2p, frac_flat)
 
     return out[:, :n].reshape(b, h, w, win * win)
@@ -534,6 +536,7 @@ def _fused_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, np_tot, feat), jnp.float32),
         scratch_shapes=[pltpu.VMEM((pixel_block, k * k), jnp.float32)],
         interpret=interpret,
+        name="pallas_fused_step",
     )(*inputs)
     return out[:, :n].reshape(b, h, w, feat)
 
@@ -870,6 +873,9 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, np_tot, out_ch), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        # the kernel's name in the compiled HLO and the device trace
+        # (unnamed it takes whatever Flax scope is open: %Conv_0.6)
+        name="flash_fused_step" if fused else "flash_corr",
     )(*inputs)
     return out[:, :n].reshape(b, h, w, out_ch)
 

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("upsample_flow_convex")
 def upsample_flow_convex(flow: jax.Array, mask: jax.Array) -> jax.Array:
     """Upsample (B, H, W, 2) flow to (B, 8H, 8W, 2) by convex combination.
 

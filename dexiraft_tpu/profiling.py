@@ -4,9 +4,11 @@ only timing is DexiNed's per-image time.time() deltas, main.py:133-147).
 Tools:
   * trace(log_dir): context manager around jax.profiler for a window of
     steps — inspect with TensorBoard's profile plugin or Perfetto.
-  * StepTimer: wall-clock per-step timing with warmup exclusion; the
-    train Logger separately reports steps/sec and iters/sec (the
-    north-star throughput metric).
+  * span(name) / add / reset / snapshot: the program's host spans — one
+    process-wide table of seconds, counts and single durations per
+    name, each span also a TraceAnnotation on the device trace's clock.
+    The engine, the loader, the prefetcher and the compile listener
+    write it; PERF.md section 3 names every span and what reads it.
   * enable_persistent_cache(): persistent XLA compilation cache —
     repeat launches of the same program skip the multi-minute compile.
   * device_banner(label): the one line every entry point prints about
@@ -21,10 +23,12 @@ Tools:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
+import threading
 import time
-from typing import Iterator, Optional
+from typing import Dict, Iterator, Optional
 
 # jax is imported inside the functions that touch it: this module
 # sits on the serve package's import path, and the serve CLI's parser /
@@ -130,11 +134,13 @@ class ServeStats:
     and returns array FUTURES; the only host-blocking operation is the
     np.asarray fetch when a ticket leaves the in-flight window. So:
 
-      * fetch_s       — wall time the host spent BLOCKED inside fetches
-                        (device compute the in-flight window failed to
-                        hide; the serving analog of prefetch_stall)
+      * fetch_s       — wall time the host spent inside fetches: the
+                        wait for the device (`engine:wait`, the host's
+                        slack) plus the device-to-host copy
+                        (`engine:copy_out`)
       * dispatch_s    — host-side pad/stack/put/enqueue time (never
-                        blocks on device compute)
+                        blocks on device compute): `engine:assemble` +
+                        `engine:put` + `engine:enqueue`
       * batch_latency — per-batch dispatch→fetch-complete wall time;
                         p50/p99 come from these samples
       * peak_inflight — max dispatched-unfetched batches observed
@@ -152,8 +158,10 @@ class ServeStats:
         self.reset()
 
     def reset(self) -> None:
-        import collections
-
+        """Zero the counters, and with them the engine's spans in the
+        process-wide table (`engine:*`, which split dispatch_s and
+        fetch_s): the two accounts cover the same batches."""
+        reset("engine:")
         self.batches = 0
         self.frames = 0          # real frame pairs yielded
         self.pad_frames = 0      # partial-batch tail filler (masked out)
@@ -228,40 +236,95 @@ def trace(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-class StepTimer:
-    """Wall-clock step timing; ignores the first `warmup` laps (compile)."""
+# ---- host spans ---------------------------------------------------------------
 
-    def __init__(self, warmup: int = 1):
-        self.warmup = warmup
-        self.times: list = []
-        self._t: Optional[float] = None
-        self._laps = 0
+# single durations kept per span name, as ServeStats.batch_latency_s keeps
+SPAN_WINDOW = 4096
 
-    def __enter__(self):
-        self._t = time.perf_counter()
+
+class _SpanRecord:
+    __slots__ = ("seconds", "count", "durations")
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+        self.count = 0
+        self.durations: "collections.deque" = collections.deque(
+            maxlen=SPAN_WINDOW)
+
+
+# process-wide and always on, like ServeStats: a handful of entries per
+# batch or step, plus one per sample a loader thread decodes. One plain
+# leaf lock (nothing is called while it is held): loader workers share a
+# name, and an engine may dispatch from a scheduler or handler thread.
+# A name is a layer's, not an object's: two engines, prefetchers or
+# Loader streams in one process write the same records, and a reset by
+# one (ServeStats/PrefetchStats.reset, a new Loader stream) restarts the
+# account of both. The stats objects' own fields stay per object.
+_spans: Dict[str, _SpanRecord] = {}
+_spans_lock = threading.Lock()
+
+
+def add(name: str, seconds: float) -> None:
+    """One more duration under `name`: what `span` does on exit, for a
+    duration measured elsewhere (a sum over a batch's rows, a
+    jax.monitoring event)."""
+    with _spans_lock:
+        rec = _spans.get(name)
+        if rec is None:
+            rec = _spans[name] = _SpanRecord()
+        rec.seconds += seconds
+        rec.count += 1
+        rec.durations.append(seconds)
+
+
+def reset(prefix: str = "") -> None:
+    """Forget every name that starts with `prefix` (a layer's spans share
+    one: "engine:", "loader:", "prefetch:", "jax:")."""
+    with _spans_lock:
+        for name in [n for n in _spans if n.startswith(prefix)]:
+            del _spans[name]
+
+
+def snapshot(prefix: str = "") -> Dict[str, dict]:
+    """`{name: {"seconds", "count", "durations"}}` of the names that
+    start with `prefix`, since their last reset. `durations` is the last
+    SPAN_WINDOW single durations in order: while `count` equals its
+    length it is all of them, so a reader can take the first n alone."""
+    with _spans_lock:
+        return {name: {"seconds": rec.seconds, "count": rec.count,
+                       "durations": list(rec.durations)}
+                for name, rec in _spans.items() if name.startswith(prefix)}
+
+
+class span:
+    """`with span(name):` — a host span of the program.
+
+    A `jax.profiler.TraceAnnotation` for the time inside (on the host
+    plane of a profiler trace, on the device events' clock; nanoseconds
+    when no profiler session is active), and on exit one duration on
+    `time.perf_counter` added to the table under `name`. The duration
+    stays on the object as `.seconds`, so a caller that also keeps a
+    total of its own (ServeStats, PrefetchStats) feeds it from the same
+    clock reads. The annotation is made on entry, not when the object
+    is: a TraceAnnotation starts when it is constructed.
+    """
+
+    __slots__ = ("name", "seconds", "_t0", "_annotation")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.seconds = 0.0
+
+    def __enter__(self) -> "span":
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t
-        self._laps += 1
-        if self._laps > self.warmup:
-            self.times.append(dt)
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        add(self.name, self.seconds)
         return False
-
-    @property
-    def mean(self) -> float:
-        return sum(self.times) / len(self.times) if self.times else 0.0
-
-    def summary(self) -> str:
-        if not self.times:
-            return "no timed laps"
-        lo, hi = min(self.times), max(self.times)
-        return (f"{len(self.times)} laps: mean {self.mean * 1e3:.2f} ms "
-                f"(min {lo * 1e3:.2f}, max {hi * 1e3:.2f})")
-
-
-def annotate(name: str):
-    """Named region for profile traces (shows up in the trace viewer)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

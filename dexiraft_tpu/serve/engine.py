@@ -56,7 +56,7 @@ from typing import Any, Callable, Dict, Iterable, Iterator, List, NamedTuple, Op
 import numpy as np
 
 from dexiraft_tpu.data.padder import InputPadder
-from dexiraft_tpu.profiling import ServeStats
+from dexiraft_tpu.profiling import ServeStats, span
 from dexiraft_tpu.serve.buckets import BucketRegistry
 
 EvalFn = Callable[..., Tuple[Any, Any]]
@@ -300,20 +300,21 @@ class InferenceEngine:
                 "it with ServeConfig(adaptive=True) and an adaptive "
                 "eval_fn (make_eval_step(adaptive=True))")
         t0 = time.perf_counter()
-        padders = [InputPadder(it["image1"].shape, mode=mode,
-                               stride=cfg.stride, target=bucket)
-                   for _, it in group]
-        im1 = [p.pad(np.asarray(it["image1"], np.float32))[0]
-               for p, (_, it) in zip(padders, group)]
-        im2 = [p.pad(np.asarray(it["image2"], np.float32))[0]
-               for p, (_, it) in zip(padders, group)]
-        fill = cfg.batch_size - len(group)
-        if fill:  # tail: replicate the last item up to the batch shape
-            im1 += [im1[-1]] * fill
-            im2 += [im2[-1]] * fill
-            self.stats.pad_frames += fill
-        im1 = np.stack(im1)
-        im2 = np.stack(im2)
+        with span("engine:assemble") as assemble:
+            padders = [InputPadder(it["image1"].shape, mode=mode,
+                                   stride=cfg.stride, target=bucket)
+                       for _, it in group]
+            im1 = [p.pad(np.asarray(it["image1"], np.float32))[0]
+                   for p, (_, it) in zip(padders, group)]
+            im2 = [p.pad(np.asarray(it["image2"], np.float32))[0]
+                   for p, (_, it) in zip(padders, group)]
+            fill = cfg.batch_size - len(group)
+            if fill:  # tail: replicate the last item up to the batch shape
+                im1 += [im1[-1]] * fill
+                im2 += [im2[-1]] * fill
+                self.stats.pad_frames += fill
+            im1 = np.stack(im1)
+            im2 = np.stack(im2)
 
         inits = [it.get("flow_init") for _, it in group]
         will_fi = cfg.warm_start or any(x is not None for x in inits)
@@ -328,40 +329,46 @@ class InferenceEngine:
                else contextlib.nullcontext())
         iters_used = final_delta = None
         with win:
-            fi = self._assemble_fi(bucket, inits) if will_fi else None
-            im1, im2, fi = self.put((im1, im2, fi))
-            t1 = time.perf_counter()
-            if cfg.adaptive:
-                # the ONE budget-normalization site (module docstring):
-                # every dispatch — warmup, scheduler-budgeted, default —
-                # presents the same int32 scalar aval, so the signature
-                # stays one executable per bucket
-                ib = None if iter_budget is None else np.int32(iter_budget)
-                flow_low, flow_up, iters_used, final_delta = \
-                    self.eval_fn(im1, im2, fi, ib)
-            else:
-                flow_low, flow_up = self.eval_fn(im1, im2, fi)
-            if (fresh and cfg.device_carry
-                    and not isinstance(flow_low, np.ndarray)):
-                # pre-compile the per-row carry slices: _fetch_one's
-                # low[row] is one executable per STATIC row index, and
-                # warmup batches carry one real item — without this the
-                # first multi-warm batch would compile rows 1.. after
-                # mark_warm and trip a --strict check
-                for row in range(cfg.batch_size):
-                    flow_low[row]
-        t2 = time.perf_counter()
-        if fresh:
+            with span("engine:put") as put:
+                fi = self._assemble_fi(bucket, inits) if will_fi else None
+                im1, im2, fi = self.put((im1, im2, fi))
             # the first call on a fresh signature traces+compiles
-            # synchronously before enqueueing — charge that span to
-            # compile_s ONLY, so dispatch_s stays what ServeStats
+            # synchronously before enqueueing — its seconds go to
+            # compile_s ONLY (a plain clock read, no span), so
+            # dispatch_s and engine:enqueue stay what ServeStats
             # documents (host pad/stack/put/enqueue time)
+            t1 = time.perf_counter()
+            with (contextlib.nullcontext() if fresh
+                  else span("engine:enqueue")) as enqueue:
+                if cfg.adaptive:
+                    # the ONE budget-normalization site (module
+                    # docstring): every dispatch — warmup,
+                    # scheduler-budgeted, default — presents the same
+                    # int32 scalar aval, so the signature stays one
+                    # executable per bucket
+                    ib = (None if iter_budget is None
+                          else np.int32(iter_budget))
+                    flow_low, flow_up, iters_used, final_delta = \
+                        self.eval_fn(im1, im2, fi, ib)
+                else:
+                    flow_low, flow_up = self.eval_fn(im1, im2, fi)
+                if (fresh and cfg.device_carry
+                        and not isinstance(flow_low, np.ndarray)):
+                    # pre-compile the per-row carry slices: _fetch_one's
+                    # low[row] is one executable per STATIC row index,
+                    # and warmup batches carry one real item — without
+                    # this the first multi-warm batch would compile rows
+                    # 1.. after mark_warm and trip a --strict check
+                    for row in range(cfg.batch_size):
+                        flow_low[row]
+            t2 = time.perf_counter()
+        self.stats.dispatch_s += assemble.seconds + put.seconds
+        if fresh:
             self.compile_s += t2 - t1
-            self.stats.dispatch_s += t1 - t0
             # expected compile: move the drift baseline past it
             self.watch.mark_warm()
         else:
-            self.stats.dispatch_s += t2 - t0
+            self.stats.dispatch_s += enqueue.seconds
             # compiled-signature dispatch that still compiled = drift:
             # strict engines fail the run, default engines warn once
             if cfg.strict:
@@ -439,57 +446,74 @@ class InferenceEngine:
 
     def _fetch_one(self) -> Iterator[Result]:
         ticket = self._inflight.popleft()
-        t0 = time.perf_counter()
-        if (isinstance(ticket.flow_low, np.ndarray)
-                and isinstance(ticket.flow_up, np.ndarray)):
-            # stub eval_fns (unit tests, the fleet tests' subprocess
-            # replicas) already returned host arrays — nothing to fetch
-            low, up = ticket.flow_low, ticket.flow_up
-        else:
+        # stub eval_fns (unit tests, the fleet tests' subprocess
+        # replicas) already returned host arrays — nothing to wait for
+        # or to fetch
+        host = (isinstance(ticket.flow_low, np.ndarray)
+                and isinstance(ticket.flow_up, np.ndarray))
+        if not host or ticket.iters_used is not None:
             import jax  # deferred: module stays importable without jax
-
-            # explicit device->host fetch (jaxlint JL007): this sync IS
-            # the fetch side's job, and device_get passes a strict
-            # transfer guard
-            if self.config.device_carry:
-                # the carry consumer (session splat) lives on device —
-                # keep flow_low there; Result.flow_low rows become
-                # device slices and the carry never crosses the bus
-                low = ticket.flow_low
+        with span("engine:wait") as wait:
+            # the device is still computing this ticket: the host's
+            # slack. Blocks on what copy_out is about to fetch, so it
+            # changes no result and no order of transfers
+            if not host:
+                jax.block_until_ready(
+                    ticket.flow_up if self.config.device_carry
+                    else (ticket.flow_low, ticket.flow_up))
+        with span("engine:copy_out") as copy_out:
+            if host:
+                low, up = ticket.flow_low, ticket.flow_up
             else:
-                low = jax.device_get(ticket.flow_low)
-                if self.config.warm_start:
-                    # carry traffic only when the engine is configured
-                    # for session carry (serve sets warm_start with
-                    # sessions); a stateless replica's flow_low fetch is
-                    # plain Result plumbing, not carry bytes
-                    self.stats.carry_d2h_bytes += low.nbytes
-            up = jax.device_get(ticket.flow_up)
-        iu = fd = None
-        if ticket.iters_used is not None:
-            if isinstance(ticket.iters_used, np.ndarray):
-                # stub eval_fns hand host arrays straight through
-                iu, fd = ticket.iters_used, ticket.final_delta
-            else:
-                import jax  # deferred like the flow fetches above
-
-                # explicit D2H (jaxlint JL007): (B,) vectors, a few bytes
-                iu = jax.device_get(ticket.iters_used)
-                fd = jax.device_get(ticket.final_delta)
-        now = time.perf_counter()
-        self.stats.fetch_s += now - t0
+                # explicit device->host fetch (jaxlint JL007): this sync
+                # IS the fetch side's job, and device_get passes a strict
+                # transfer guard
+                if self.config.device_carry:
+                    # the carry consumer (session splat) lives on device
+                    # — keep flow_low there; Result.flow_low rows become
+                    # device slices and the carry never crosses the bus
+                    low = ticket.flow_low
+                else:
+                    low = jax.device_get(ticket.flow_low)
+                    if self.config.warm_start:
+                        # carry traffic only when the engine is
+                        # configured for session carry (serve sets
+                        # warm_start with sessions); a stateless
+                        # replica's flow_low fetch is plain Result
+                        # plumbing, not carry bytes
+                        self.stats.carry_d2h_bytes += low.nbytes
+                up = jax.device_get(ticket.flow_up)
+            iu = fd = None
+            if ticket.iters_used is not None:
+                if isinstance(ticket.iters_used, np.ndarray):
+                    # stub eval_fns hand host arrays straight through
+                    iu, fd = ticket.iters_used, ticket.final_delta
+                else:
+                    # explicit D2H (jaxlint JL007): (B,) vectors, a few
+                    # bytes
+                    iu = jax.device_get(ticket.iters_used)
+                    fd = jax.device_get(ticket.final_delta)
+        self.stats.fetch_s += wait.seconds + copy_out.seconds
         self.stats.fetches += 1
-        self.stats.batch_latency_s.append(now - ticket.t_dispatch)
-        for row, (idx, item, padder) in enumerate(ticket.entries):
-            self.stats.frames += 1
-            if iu is None:
-                yield Result(idx, item, low[row], padder.unpad(up[row]))
-            else:
-                self.stats.iters_used.append(int(iu[row]))
-                self.stats.final_delta.append(float(fd[row]))
-                yield Result(idx, item, low[row], padder.unpad(up[row]),
-                             iters_used=int(iu[row]),
-                             final_delta=float(fd[row]))
+        self.stats.batch_latency_s.append(
+            time.perf_counter() - ticket.t_dispatch)
+        with span("engine:deliver"):
+            results = [
+                Result(idx, item, low[row], padder.unpad(up[row]),
+                       **({} if iu is None else {
+                           "iters_used": int(iu[row]),
+                           "final_delta": float(fd[row])}))
+                for row, (idx, item, padder) in enumerate(ticket.entries)]
+        # the consumer's work between two next() calls runs on this same
+        # serial thread: the generator stays suspended at each yield for
+        # as long as the caller takes with the row
+        with span("engine:caller"):
+            for result in results:
+                self.stats.frames += 1
+                if iu is not None:
+                    self.stats.iters_used.append(result.iters_used)
+                    self.stats.final_delta.append(result.final_delta)
+                yield result
 
     def _drain_to(self, n: int) -> Iterator[Result]:
         while len(self._inflight) > n:

@@ -24,17 +24,6 @@ def test_viz_cli_converts_tree(tmp_path):
     assert img.shape == (16, 24, 3)
 
 
-def test_step_timer_excludes_warmup():
-    from dexiraft_tpu.profiling import StepTimer
-
-    t = StepTimer(warmup=2)
-    for _ in range(5):
-        with t:
-            pass
-    assert len(t.times) == 3
-    assert "3 laps" in t.summary()
-
-
 def test_trace_context(tmp_path):
     import jax
     import jax.numpy as jnp
