@@ -626,8 +626,30 @@ pallas_fused_step.defvjp(_fused_fwd, _fused_bwd)
 # (bilinear blend + out-of-frame zeroing in one expression — no corner
 # blending, no coordinate clipping), and accumulates. Row blocks whose
 # rows cannot intersect any query window in the block (hat support is
-# empty outside [ty - r - 1, ty + r + 1]) are skipped before the DMA,
-# so HBM traffic tracks the windows actually needed, not H2 x W2.
+# empty outside [ty - r - 1, ty + r + 1]) are never copied, so HBM
+# traffic tracks the windows actually needed, not H2 x W2: each level's
+# visited blocks are one contiguous range, known from the block's
+# coords before the grid step's first matmul.
+#
+# The visited blocks of all levels stream through TWO VMEM slots, one
+# DMA semaphore each (ISSUE 25): before computing on a visit the kernel
+# starts the copy of the next one into the other slot — the level's
+# next block or, on a level's last, the first block of the next level
+# that has any — and only then waits for its own. The copy runs behind
+# the matmuls of the visit before it; the first copy of a grid step is
+# the only one waited for with nothing to compute. Visits and their
+# order are those of a plain loop over each level's range, so the sums
+# are too.
+#
+# To read what Mosaic made of it without a chip, compile the kernel for
+# a described topology (tests/test_chip_compile.py's recipe) under
+#   LIBTPU_INIT_ARGS="--xla_jf_dump_to=<dir> --xla_jf_dump_llo_text=true"
+# and open <dir>/*-flash_fused_step.1-*-final_bundles.txt: one line a
+# VLIW bundle, loop bodies between `LB:` marks, the copies as
+# `dma.hbm_to_vmem` / `dma.done.wait`. (The dumper aborts on a missing
+# report template after that file is written.) Give the levels the
+# batch the model gives them (32 and up): at batch 1 the compiler keeps
+# the whole level in VMEM and the "copy" is 256 vector loads and stores.
 #
 # Consequences: VMEM use is O(pixel_block) at ANY geometry (no budget
 # split path), HBM holds only the fmaps (never a volume, never padded
@@ -642,8 +664,9 @@ pallas_fused_step.defvjp(_fused_fwd, _fused_bwd)
 
 # queries per flash grid step / fmap2 rows per DMA block. Trace-time
 # env knobs like DEXIRAFT_PALLAS_PIXEL_BLOCK; the defaults bound the
-# resident set to ~4 MB at C=256 (f1 block 256 KB + one (8, W2, C)
-# row block + the (P, rows*W2) dots transient).
+# resident set to ~5 MB at C=256 and W2=128 (f1 block 256 KB + the two
+# (8, W2, C) row-block slots, 1 MiB each at fp32 + the (P, rows*W2)
+# dots transient).
 _FLASH_PIXEL_BLOCK = 256
 _FLASH_ROWS = 8
 _LANES = 128
@@ -678,8 +701,8 @@ def _hat(taps_center, length, offset, radius, p_block):
 def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
                   num_levels_total: int, rows: int, fused: bool):
     """refs: f1, coords, [w, b], f2 level refs (ANY/HBM), out, then
-    scratch: f2 row-block buffer, window accumulator, [out accumulator],
-    DMA semaphore.
+    scratch: the two f2 row-block slots, window accumulator, [out
+    accumulator], one DMA semaphore a slot.
 
     ``level_ids`` are the ORIGINAL pyramid indices of the staged levels
     (degenerate 0-row tail levels are filtered out on the XLA side —
@@ -717,52 +740,83 @@ def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
         out_ref[0] = jnp.zeros(
             (p_block, num_levels_total * win * win), jnp.float32)
 
-    for f2_ref, lvl, (h2, w2) in zip(lvl_refs, level_ids, level_shapes):
+    # The visited row blocks of every level, before the first copy. Hat
+    # support of tap t is (t-1, t+1) and taps span [ty-r, ty+r]: a row
+    # block outside [min ty - r - 1, max ty + r + 1] cannot contribute.
+    # Block i holds rows [i*rows, i*rows + rows - 1], so the visited
+    # ones are ceil((t_lo - rows + 1) / rows) .. floor(t_hi / rows),
+    # clipped to the level: an empty range where every window lies
+    # outside it. floor/ceil are monotone, so they are taken on the
+    # vector and the reduction yields the integer.
+    tys, first, end = [], [], []
+    for f2_ref, lvl in zip(lvl_refs, level_ids):
         n_blocks = f2_ref.shape[1] // rows
+        ty = coords_ref[0, :, 1].astype(jnp.float32) * (1.0 / (2.0 ** lvl))
+        lo = jnp.min(jnp.ceil((ty - (r + 1) - (rows - 1)) / rows))
+        hi = jnp.max(jnp.floor((ty + (r + 1)) / rows))
+        tys.append(ty)
+        first.append(jnp.clip(lo.astype(jnp.int32), 0, n_blocks))
+        end.append(jnp.clip(hi.astype(jnp.int32) + 1, 0, n_blocks))
+
+    def copy(i, blk_i, slot):
+        w2 = level_shapes[i][1]
+        return pltpu.make_async_copy(
+            lvl_refs[i].at[bi, pl.ds(blk_i * rows, rows)],
+            f2blk_ref.at[slot, :, :w2, :], sem.at[slot])
+
+    def start_first_visit(from_i, slot):
+        """Start the copy of the first visited block of the first level
+        from ``from_i`` on that has any; none where none has."""
+        none_yet = True
+        for j in range(from_i, n_lvls):
+            has = first[j] < end[j]
+            pl.when(none_yet & has)(
+                lambda j=j: copy(j, first[j], slot).start())
+            none_yet = none_yet & ~has
+
+    # the pipeline of the section comment above: a visit computes from
+    # ``slot`` while its successor's copy fills the other; every copy
+    # that is started is waited for by its own visit
+    start_first_visit(0, 0)
+    slot = jnp.int32(0)
+
+    for i, (lvl, (h2, w2)) in enumerate(zip(level_ids, level_shapes)):
         inv = 1.0 / (2.0 ** lvl)
         tx = coords_ref[0, :, 0].astype(jnp.float32) * inv  # (P,)
-        ty = coords_ref[0, :, 1].astype(jnp.float32) * inv
+        ty = tys[i]
         # x hats cover the whole level width (a row of queries spans it);
         # y hats are built per row block inside the loop
         ax = _hat(tx, w2, 0, r, p_block)  # (P, win, w2)
-        # hat support of tap t is (t-1, t+1); taps span [ty-r, ty+r] —
-        # a row block outside [min ty - r - 1, max ty + r + 1] cannot
-        # contribute, so its DMA and matmuls are skipped entirely
-        t_lo = jnp.min(ty) - (r + 1)
-        t_hi = jnp.max(ty) + (r + 1)
         win_ref[...] = jnp.zeros_like(win_ref)
 
-        def body(blk_i, _, f2_ref=f2_ref, ax=ax, ty=ty,
-                 t_lo=t_lo, t_hi=t_hi, w2=w2):
+        def body(blk_i, slot, i=i, ax=ax, ty=ty, w2=w2):
             row0 = blk_i * rows
+            pl.when(blk_i + 1 < end[i])(
+                lambda: copy(i, blk_i + 1, 1 - slot).start())
+            if i + 1 < n_lvls:  # the last level hands nothing on
+                pl.when(blk_i + 1 == end[i])(
+                    lambda: start_first_visit(i + 1, 1 - slot))
+            copy(i, blk_i, slot).wait()
+            blk = (f2blk_ref[slot, :, :w2, :]
+                   .reshape(rows * w2, c).astype(jnp.float32))
+            # partial all-pairs block: (P, C) x (rows*w2, C)ᵀ on the
+            # MXU — the local_corr formulation, never materialized
+            # beyond this row block
+            dots = jax.lax.dot_general(
+                f1, blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dots = dots.reshape(p_block, rows, w2)
+            ay = _hat(ty, rows, row0, r, p_block)  # (P, win, rows)
+            rows_c = jax.lax.dot_general(  # (P, win_y, w2)
+                ay, dots, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            wp = jax.lax.dot_general(  # (P, win_x, win_y) — x slow,
+                ax, rows_c, (((2,), (2,)), ((0,), (0,))),  # ops.corr
+                preferred_element_type=jnp.float32)  # channel order
+            win_ref[...] += wp.reshape(p_block, win * win)
+            return 1 - slot
 
-            @pl.when((row0 <= t_hi) & (row0 + rows - 1 >= t_lo))
-            def _():
-                dma = pltpu.make_async_copy(
-                    f2_ref.at[bi, pl.ds(row0, rows)],
-                    f2blk_ref.at[:, :w2, :], sem)
-                dma.start()
-                dma.wait()
-                blk = (f2blk_ref[:, :w2, :]
-                       .reshape(rows * w2, c).astype(jnp.float32))
-                # partial all-pairs block: (P, C) x (rows*w2, C)ᵀ on the
-                # MXU — the local_corr formulation, never materialized
-                # beyond this row block
-                dots = jax.lax.dot_general(
-                    f1, blk, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                dots = dots.reshape(p_block, rows, w2)
-                ay = _hat(ty, rows, row0, r, p_block)  # (P, win, rows)
-                rows_c = jax.lax.dot_general(  # (P, win_y, w2)
-                    ay, dots, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)
-                wp = jax.lax.dot_general(  # (P, win_x, win_y) — x slow,
-                    ax, rows_c, (((2,), (2,)), ((0,), (0,))),  # ops.corr
-                    preferred_element_type=jnp.float32)  # channel order
-                win_ref[...] += wp.reshape(p_block, win * win)
-            return 0
-
-        jax.lax.fori_loop(0, n_blocks, body, 0)
+        slot = jax.lax.fori_loop(first[i], end[i], body, slot)
 
         if fused:
             w_lvl = w_ref[pl.ds(lvl * win * win, win * win), :]
@@ -852,11 +906,11 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
     inputs += f2p
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(f2p)
 
-    scratch = [pltpu.VMEM((rows, w2_max, c), f2p[0].dtype),
+    scratch = [pltpu.VMEM((2, rows, w2_max, c), f2p[0].dtype),
                pltpu.VMEM((pixel_block, win * win), jnp.float32)]
     if fused:
         scratch.append(pltpu.VMEM((pixel_block, out_ch), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA)
+    scratch.append(pltpu.SemaphoreType.DMA((2,)))
 
     kernel = functools.partial(_flash_kernel, radius=r,
                                level_ids=level_ids,

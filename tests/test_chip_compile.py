@@ -5,7 +5,9 @@ topology (`v5e:2x2`): what Mosaic refuses on the chip it refuses here, at
 no chip time. Interpret-mode parity tests cannot see this — all three
 kernel families passed them while none compiled. Each case is the kernel
 alone at the shapes the v5 forward feeds it at 440x1024 (fmaps
-1x55x128x256, 4 levels, radius 4), for every configuration
+55x128x256 at the eval cells' batch of 32, 4 levels, radius 4; at batch
+1 the compiler keeps a whole level in VMEM and the kernel's row-block
+copies are not DMAs), for every configuration
 `--corr_impl auto` can resolve to on a TPU (flash, fused, at each
 `--corr_dtype`) and the unfused flash lookup. A compile that passes is
 not a chip run: chip_smoke.py runs the same configuration on the chip
@@ -27,7 +29,7 @@ from dexiraft_tpu.ops import pallas_corr as pc
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
 
-B, H, W, C = 1, 55, 128, 256
+B, H, W, C = 32, 55, 128, 256
 LEVELS, RADIUS, FEAT = 4, 4, 256
 DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
@@ -55,14 +57,14 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _shapes(chip, dtype):
+def _shapes(chip, dtype, h=H, w=W, b=B):
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
-    levels = tuple(sds((B, H >> i, W >> i, C), DTYPES[dtype])
+    levels = tuple(sds((b, h >> i, w >> i, C), DTYPES[dtype])
                    for i in range(LEVELS))
     win2 = (2 * RADIUS + 1) ** 2
-    return dict(f1=sds((B, H, W, C)), coords=sds((B, H, W, 2)),
+    return dict(f1=sds((b, h, w, C)), coords=sds((b, h, w, 2)),
                 levels=levels, weight=sds((LEVELS * win2, FEAT)),
                 bias=sds((FEAT,)))
 
@@ -75,10 +77,15 @@ def test_auto_on_tpu_names_what_is_compiled_here():
     assert resolve_corr_impl("auto", "tpu") == ("flash", True)
 
 
-@pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8"])
-def test_flash_fused_step_compiles_for_v5e(chip, dtype):
-    """What `auto` serves: one kernel per refinement iteration."""
-    s = _shapes(chip, dtype)
+@pytest.mark.parametrize("dtype,hw", [("fp32", (H, W)), ("bf16", (H, W)),
+                                      ("int8", (H, W)),
+                                      ("fp32", (136, 240))])
+def test_flash_fused_step_compiles_for_v5e(chip, dtype, hw):
+    """What `auto` serves: one kernel per refinement iteration. The two
+    row-block slots and their semaphore pair are what Mosaic has to
+    accept and what has to fit: 1088x1920 (136x240, level 0 padded to 256
+    columns) has the largest pair any entry point reaches."""
+    s = _shapes(chip, dtype, *hw)
     text = _compiled_text(
         lambda f1, lv, co, w, b: pc.flash_fused_step(
             f1, lv, co, w, b, RADIUS, False),
@@ -104,8 +111,9 @@ def test_per_pixel_family_is_still_refused(chip, fused):
     """corr_impl="pallas" is withdrawn from every on-chip default because
     Mosaic refuses its window load (config.PALLAS_TPU_REFUSAL). When
     this starts passing the kernel compiles again: re-admit the family
-    here, in bench.py's legs and in the refusal, deliberately."""
-    s = _shapes(chip, "fp32")
+    here, in bench.py's legs and in the refusal, deliberately. (Batch 1:
+    the refusal it pins is the window load's.)"""
+    s = _shapes(chip, "fp32", b=1)
     if fused:
         fn = lambda f1, lv, co, w, b: pc.pallas_fused_step(  # noqa: E731
             f1, lv, co, w, b, RADIUS, False)

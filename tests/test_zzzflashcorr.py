@@ -42,13 +42,17 @@ def _small_flash_blocks(monkeypatch):
     monkeypatch.setenv("DEXIRAFT_FLASH_ROWS", "2")
 
 
+def _grid(b, h, w):
+    ys, xs = jnp.meshgrid(jnp.arange(h, dtype=jnp.float32),
+                          jnp.arange(w, dtype=jnp.float32), indexing="ij")
+    return jnp.stack([xs, ys], axis=-1)[None].repeat(b, 0)
+
+
 def _setup(key, b=1, h=6, w=8, c=32, levels=3, radius=2):
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     f1 = jax.random.normal(k1, (b, h, w, c), jnp.float32)
     f2 = jax.random.normal(k2, (b, h, w, c), jnp.float32)
-    ys, xs = jnp.meshgrid(jnp.arange(h, dtype=jnp.float32),
-                          jnp.arange(w, dtype=jnp.float32), indexing="ij")
-    coords = (jnp.stack([xs, ys], axis=-1)[None].repeat(b, 0)
+    coords = (_grid(b, h, w)
               + jax.random.uniform(k3, (b, h, w, 2), jnp.float32, -2, 2))
     win = 2 * radius + 1
     feat = 16
@@ -188,6 +192,184 @@ class TestFlashKernelParity:
                                 w8, bias, radius, True)
         bound = 0.05 * float(jnp.max(jnp.abs(ref)))
         assert float(jnp.max(jnp.abs(out8 - ref))) <= max(bound, 1e-3)
+
+
+# -- the two-slot row-block pipeline (ISSUE 25) -----------------------------
+
+RADIUS, ROWS, PIXEL_BLOCK = 1, 2, 16  # of the visited-range cases
+H, W = 16, 8  # 8 query blocks of two rows; levels of 16, 8, 4 rows
+
+
+def _visited(coords, level_rows, rows, pixel_block, radius):
+    """The kernel's visited row blocks as [level] -> (first, end) arrays
+    over (batch, query block): the rule of _flash_kernel on the host, so
+    a case can assert that it is the case it says it is."""
+    b = coords.shape[0]
+    ty = np.asarray(coords[..., 1], np.float32).reshape(b, -1)
+    ty = np.pad(ty, ((0, 0), (0, (-ty.shape[1]) % pixel_block)))
+    ty = ty.reshape(b, -1, pixel_block)
+    out = []
+    for lvl, h2 in enumerate(level_rows):
+        n_blocks = -(-h2 // rows)
+        t = ty / np.float32(2.0 ** lvl)
+        first = np.ceil((t.min(-1) - (radius + 1) - (rows - 1)) / rows)
+        end = np.floor((t.max(-1) + (radius + 1)) / rows) + 1
+        out.append((np.clip(first, 0, n_blocks).astype(int),
+                    np.clip(end, 0, n_blocks).astype(int)))
+    return out
+
+
+def _n(v):
+    """[level] -> visits of each (batch, query block)."""
+    return [e - f for f, e in v]
+
+
+def _pipeline_case(name):
+    """-> (fmap1, levels, coords, weight, bias, check) for one shape of
+    the visited ranges; ``check`` asserts on _visited's answer that the
+    case is what its name says."""
+    b, h, w, dtype = 1, H, W, None
+    key = jax.random.PRNGKey(sum(map(ord, name)))
+    if name == "batch2_tail":
+        b, h, w = 2, 7, 6  # 42 queries: the third block is 10 real + 6 pad
+    elif name == "degenerate_tail":
+        h = 3  # levels of 3, 1 and 0 rows
+    elif name in ("bf16", "int8"):
+        dtype = name
+    f1, f2, coords, weight, bias = _setup(key, b=b, h=h, w=w, radius=RADIUS)
+    lc = build_local_corr(f1, f2, num_levels=3, radius=RADIUS,
+                          **({"dtype": dtype} if dtype else {}))
+    levels = tuple(lc.fmap2_pyramid)
+    n_blocks = [-(-lv.shape[1] // ROWS) for lv in levels]
+    grid = _grid(b, h, w)
+    xs = coords[..., :1]  # the fixture's x flow stays
+
+    def with_y(y):
+        return jnp.concatenate([xs, jnp.broadcast_to(y, (b, h, w, 1))], -1)
+
+    if name == "small":
+        coords = grid + 0.2 * (coords - grid)  # flow within +-0.4
+
+        def check(v):  # 1-3 blocks at every level, three somewhere
+            assert all(1 <= n.min() and n.max() <= 3 for n in _n(v))
+            assert _n(v)[0].max() == 3
+    elif name == "level0_empty":
+        # flow shifted up past the frame: no window reaches level 0's
+        # first row, levels 1 and 2 (coarser rows) are still in reach
+        coords = with_y(-2.5 + 0.15 * (coords - grid)[..., 1:])
+
+        def check(v):
+            n0, n1, n2 = _n(v)
+            assert (n0 == 0).all() and (n1 == 1).all() and (n2 == 1).all()
+    elif name == "bottom_empty":
+        # flow shifted down: the last query blocks look past the end of
+        # levels 0 and 1 and still into level 2
+        coords = with_y(grid[..., 1:] + 5.25)
+
+        def check(v):
+            n0, n1, n2 = _n(v)
+            past = (n0 == 0) & (n1 == 0)
+            assert past.any() and not past.all() and (n2[past] > 0).all()
+            assert (n0 > 0).any()
+    elif name == "all_empty":
+        coords = coords + 1000.0
+
+        def check(v):
+            assert all((n == 0).all() for n in _n(v))
+    elif name == "one_visit":
+        coords = with_y(-5.0)  # out of reach of levels 0 and 1
+
+        def check(v):
+            assert (sum(_n(v)) == 1).all() and (_n(v)[2] == 1).all()
+    elif name == "clipped_both":
+        # each row of queries looks past both ends of every level
+        ys = jnp.where(jnp.arange(w) % 2 == 0, -2.0, h + 2.0)
+        coords = with_y(ys[None, None, :, None])
+
+        def check(v):
+            for (f, e), nb in zip(v, n_blocks):
+                assert (f == 0).all() and (e == nb).all()
+    elif name == "degenerate_tail":
+        def check(v):
+            assert [lv.shape[1] for lv in levels] == [3, 1, 0]
+            assert (_n(v)[2] == 0).all() and (_n(v)[0] > 0).all()
+    else:
+        def check(v):
+            assert any(n.max() > 1 for n in _n(v))
+    if dtype == "int8":  # dequantization scales ride the weights
+        ww = (2 * RADIUS + 1) ** 2
+        weight = jnp.concatenate(
+            [weight[i * ww:(i + 1) * ww] * lc.scales[i] for i in range(3)])
+    return lc.fmap1, levels, coords, weight, bias, check
+
+
+class TestVisitedRangePipeline:
+    """The row-block loop runs over [first, end) of each level and hands
+    the next copy over level boundaries, through two slots. Interpret
+    mode copies at `start`, so what these pin is the ranges, the slots
+    and the hand-over's choice of block — not the overlap (the chip's)."""
+
+    CASES = ["small", "level0_empty", "bottom_empty", "all_empty",
+             "one_visit", "clipped_both", "degenerate_tail", "batch2_tail",
+             "bf16", "int8"]
+
+    @staticmethod
+    def _case(name):
+        f1, levels, coords, weight, bias, check = _pipeline_case(name)
+        check(_visited(coords, [lv.shape[1] for lv in levels], ROWS,
+                       PIXEL_BLOCK, RADIUS))
+        return f1, levels, coords, weight, bias
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_fused_matches_reference(self, name):
+        f1, levels, coords, weight, bias = self._case(name)
+        out = flash_fused_step(f1, levels, coords, weight, bias, RADIUS, True)
+        ref = fused_reference(f1, levels, coords, weight, bias, RADIUS)
+        assert float(jnp.max(jnp.abs(out - ref))) <= 1e-3
+        if name == "all_empty":  # no visit, no copy: the bias alone
+            np.testing.assert_array_equal(
+                np.asarray(out), np.broadcast_to(np.asarray(bias), out.shape))
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_lookup_matches_reference(self, name):
+        """The unfused kernel, one call a level at that level's coords
+        (one level a call: the ranges and slots without the hand-over)."""
+        f1, levels, coords, _, _ = self._case(name)
+        for lvl, f2 in enumerate(levels):
+            co = coords / (2.0 ** lvl)
+            out = flash_local_corr_level(f1, f2, co, RADIUS, True)
+            if name == "all_empty" or f2.shape[1] == 0:
+                np.testing.assert_array_equal(np.asarray(out), 0.0)
+            else:
+                ref = local_corr_level(f1, f2.astype(jnp.float32), co, RADIUS)
+                assert float(jnp.max(jnp.abs(out - ref))) <= 1e-3
+
+    def test_rows_outside_every_range_are_never_read(self):
+        """Poison: NaN rows that no query block's range reaches leave the
+        output finite and equal; a NaN row inside a range reaches it."""
+        f1, f2, coords, weight, bias = _setup(jax.random.PRNGKey(25), h=H,
+                                              radius=RADIUS)
+        coords = coords.at[..., 1].set(coords[..., 1] % 3.0)  # all look up
+        lc = build_local_corr(f1, f2, num_levels=3, radius=RADIUS)
+        levels = tuple(lc.fmap2_pyramid)
+        clean = flash_fused_step(lc.fmap1, levels, coords, weight, bias,
+                                 RADIUS, True)
+        visited = _visited(coords, [lv.shape[1] for lv in levels], ROWS,
+                           PIXEL_BLOCK, RADIUS)
+        poisoned, n_poisoned = [], 0
+        for lv, (_, end) in zip(levels, visited):
+            reach = int(end.max()) * ROWS  # rows from here on: in no range
+            n_poisoned += max(lv.shape[1] - reach, 0)
+            poisoned.append(lv.at[:, reach:].set(jnp.nan))
+        assert n_poisoned >= 8  # the case poisons something
+        out = flash_fused_step(lc.fmap1, tuple(poisoned), coords, weight,
+                               bias, RADIUS, True)
+        assert bool(jnp.isfinite(out).all())
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+        hit = (levels[0], levels[1].at[:, 0].set(jnp.nan), levels[2])
+        out = flash_fused_step(lc.fmap1, hit, coords, weight, bias, RADIUS,
+                               True)
+        assert bool(jnp.isnan(out).any())
 
 
 class TestBlockedTilingEquivalence:
