@@ -253,6 +253,103 @@ VARIANTS = {
 
 
 @dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """A DeepSeek-V3-style decoder (`model_type: deepseek_v3`): latent
+    attention without a query LoRA, one or more leading dense layers,
+    then sigmoid-routed expert layers with shared experts
+    (models/lm/, docs/lm.md). Keys and defaults are
+    kanana-2-30b-a3b-instruct-2601's published `config.json`.
+
+    The share: a chip of a tensor- and expert-parallel group holds
+    `heads_held` of the `num_attention_heads` heads, `experts_held` of
+    the `n_routed_experts` experts (each a `(first, count)` range) and a
+    `vocab_size`-row slice of the vocabulary. The router keeps its
+    published width and chooses over all experts; what the absent heads
+    and experts would have added is left out. `None` holds everything.
+    """
+
+    vocab_size: int = 128_256
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    n_routed_experts: int = 128
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    routed_scaling_factor: float = 2.448
+    norm_topk_prob: bool = True
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
+    # assumed (the catalog row does not give it): DeepSeek-V3's
+    # `initializer_range`
+    init_std: float = 0.02
+    # rows of the packed dataset and of every batch
+    seq_len: int = 8192
+    heads_held: Optional[Tuple[int, int]] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    # bf16 module compute from the fp32 masters; the train step's bf16
+    # policy forces it, as it forces RAFT's
+    mixed_precision: bool = False
+    # full recomputation of every decoder layer in the backward
+    remat: bool = False
+    # query rows of one attention block (ops/lm_attention.py) and rows
+    # of one dispatch chunk of the expert layer (models/lm/moe.py); the
+    # results do not depend on either
+    attn_block: int = 1024
+    moe_chunk: int = 32_768
+
+    def __post_init__(self):
+        for name, whole in (("heads_held", self.num_attention_heads),
+                            ("experts_held", self.n_routed_experts)):
+            held = getattr(self, name)
+            if held is None:
+                object.__setattr__(self, name, (0, whole))
+            else:
+                first, count = (int(v) for v in held)
+                if first < 0 or count < 1 or first + count > whole:
+                    raise ValueError(f"{name}={held!r} is not a range of "
+                                     f"the {whole} the model has")
+                object.__setattr__(self, name, (first, count))
+        if self.seq_len % self.attn_block and self.seq_len > self.attn_block:
+            raise ValueError(f"seq_len {self.seq_len} is not a multiple of "
+                             f"attn_block {self.attn_block}")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+def kanana2(**kw) -> LMConfig:
+    """kanana-2-30b-a3b-instruct-2601 as published; `heads_held`,
+    `experts_held`, `vocab_size` and `num_hidden_layers` cut it to a
+    chip's share (benchmarks/configs/kanana-2-30b-a3b-share8.json)."""
+    return LMConfig(**kw)
+
+
+def kanana2_toy(**kw) -> LMConfig:
+    """The CPU tests' size: every mechanism, toy widths (8 shares hold 2
+    experts and 1 head each)."""
+    base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=3,
+                intermediate_size=96, moe_intermediate_size=32,
+                n_routed_experts=16, num_experts_per_tok=2,
+                num_attention_heads=8, kv_lora_rank=16, qk_nope_head_dim=8,
+                qk_rope_head_dim=4, v_head_dim=8, seq_len=128,
+                attn_block=32, moe_chunk=64)
+    return LMConfig(**{**base, **kw})
+
+
+# language models `train --variant` takes beside VARIANTS. Not in
+# VARIANTS: eval, serve and video have no path for them (ROADMAP.md).
+LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy}
+
+
+@dataclasses.dataclass(frozen=True)
 class TrainConfig:
     """One training stage. Presets mirror train_standard.sh / train_mixed.sh."""
 

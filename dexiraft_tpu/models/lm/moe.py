@@ -1,0 +1,182 @@
+"""The routed expert layer, told which experts it holds.
+
+Routing is the whole model's (`topk_method: noaux_tc`, one group):
+`s = sigmoid(W_r x)` in fp32 over all `n_routed_experts`, the top
+`num_experts_per_tok` of `s + b`, weights `s` of the chosen (without
+`b`) normalised to sum 1 and scaled by `routed_scaling_factor`. This
+chip then computes `sum_i w_i Expert_i(x)` over the chosen experts it
+holds (`cfg.experts_held`), plus the shared experts, which every chip of
+the group computes alike. What the absent experts would add is left
+out: in a deployment it arrives with the expert-parallel sum. No code
+stands in for that exchange.
+
+Dropless, in one program shape. A (token, choice) pair is a slot;
+`T * top_k` slots exist and any number of them, up to all, may fall on
+held experts. Slots are sorted by held expert (the others last), and
+the sorted order is cut into chunks of `cfg.moe_chunk` rows. A chunk
+gathers its tokens, runs the three grouped products of a SwiGLU with
+the chunk's own group sizes, and scatter-adds the weighted rows into the
+output. Chunk 0 always runs; the later chunks are a scan under one
+`lax.cond` that is taken only if held slots pass chunk 0, each of them
+under a `lax.cond` of its own and a `jax.checkpoint`, so chunks that
+seldom run keep nothing for the backward and cost nothing when skipped. At the published balance
+(`T * top_k * held / experts` slots) chunk 0 is all there is; with every
+token on one held expert every chunk runs. Work follows the slots, the
+shape never changes, and no slot is dropped: `moe_dropped_slots` counts
+held slots less the rows the chunks took, and stays 0.
+
+`b` (`e_score_correction_bias`) is a buffer outside the gradient whose
+update rule the published config does not give; it is carried in the
+`batch_stats` collection and held at zero (docs/lm.md, `assumed`).
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from dexiraft_tpu.config import LMConfig
+from dexiraft_tpu.models.lm.layers import SwiGLU, Weights
+from dexiraft_tpu.ops.grouped import grouped_matmul
+
+
+def route(scores: jax.Array, bias: jax.Array, top_k: int, scale: float,
+          normalise: bool) -> Tuple[jax.Array, jax.Array]:
+    """(expert ids `[T, k]`, weights `[T, k]` fp32) from sigmoid scores
+    `[T, E]` fp32."""
+    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return chosen, weights * scale
+
+
+class RoutedExperts(Weights):
+    cfg: LMConfig = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """x `[T, D]` -> (this chip's part of the layer's output `[T, D]`,
+        counters)."""
+        cfg = self.cfg
+        t, d = x.shape
+        top_k, width = cfg.num_experts_per_tok, cfg.moe_intermediate_size
+        first, held = cfg.experts_held
+
+        with jax.named_scope("lm/moe/router"):
+            w_r = self.param("router", nn.initializers.normal(cfg.init_std),
+                             (d, cfg.n_routed_experts), jnp.float32)
+            bias = self.variable(
+                "batch_stats", "e_score_correction_bias",
+                lambda: jnp.zeros((cfg.n_routed_experts,), jnp.float32)).value
+            scores = jax.nn.sigmoid(jnp.matmul(
+                x.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST))
+            chosen, weights = route(scores, bias, top_k,
+                                    cfg.routed_scaling_factor,
+                                    cfg.norm_topk_prob)
+
+        with jax.named_scope("lm/moe/dispatch"):
+            local = chosen.reshape(-1) - first
+            key = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
+                             axis=0, dtype=jnp.int32)           # [held]
+            n_held = jnp.sum(counts)
+            ends = jnp.cumsum(counts)
+            starts = ends - counts
+            chunk = min(cfg.moe_chunk, t * top_k)
+            n_chunks = -(-t * top_k // chunk)
+            pad = n_chunks * chunk - t * top_k
+            slot_token = jnp.pad(order // top_k, (0, pad)).reshape(
+                n_chunks, chunk)
+            slot_weight = jnp.pad(weights.reshape(-1)[order], (0, pad)
+                                  ).reshape(n_chunks, chunk)
+
+        w_gate = self.w("w_gate", (held, d, width))
+        w_up = self.w("w_up", (held, d, width))
+        w_down = self.w("w_down", (held, width, d))
+
+        def run_chunk(y, c, tokens, wts):
+            lo = c * chunk
+            sizes = (jnp.clip(ends, lo, lo + chunk)
+                     - jnp.clip(starts, lo, lo + chunk))
+            # rows past the held slots are in no group, and the grouped
+            # product leaves such rows unspecified (on the chip: whatever
+            # the buffer held, NaN included). They are zeroed on the way
+            # in and after every product, by a select: every value the
+            # backward multiplies by is then finite, and a zero cotangent
+            # stays zero
+            live = (lo + jnp.arange(chunk) < n_held)[:, None]
+            keep = lambda a: jnp.where(live, a, jnp.zeros((), a.dtype))
+            with jax.named_scope("lm/moe/dispatch"):
+                rows = keep(x[tokens])
+            with jax.named_scope("lm/moe/experts"):
+                gate = keep(grouped_matmul(rows, w_gate, sizes))
+                up = keep(grouped_matmul(rows, w_up, sizes))
+                out = keep(grouped_matmul(jax.nn.silu(gate) * up, w_down,
+                                          sizes))
+            with jax.named_scope("lm/moe/combine"):
+                y = y.at[tokens].add(out.astype(jnp.float32) * wts[:, None])
+            return y, jnp.sum(sizes)
+
+        y = jnp.zeros((t, d), jnp.float32)
+        y, taken = run_chunk(y, 0, slot_token[0], slot_weight[0])
+        if n_chunks > 1:
+            later = jax.checkpoint(run_chunk)
+
+            def body(carry, xs):
+                y, taken = carry
+                c, tokens, wts = xs
+                y, n = jax.lax.cond(
+                    c * chunk < n_held,
+                    lambda y: later(y, c, tokens, wts),
+                    lambda y: (y, jnp.zeros((), jnp.int32)), y)
+                return (y, taken + n), None
+
+            @jax.checkpoint
+            def overflow(y):
+                (y, n), _ = jax.lax.scan(
+                    body, (y, jnp.zeros((), jnp.int32)),
+                    (jnp.arange(1, n_chunks), slot_token[1:],
+                     slot_weight[1:]))
+                return y, n
+
+            # one branch around the whole scan: at the usual balance no
+            # later chunk runs, and then the scan must not cost its carry
+            # copies either (176 ms a step; my chip run, PR 26)
+            y, more = jax.lax.cond(
+                n_held > chunk, overflow,
+                lambda y: (y, jnp.zeros((), jnp.int32)), y)
+            taken = taken + more
+
+        counters = {
+            "moe_slots_held": n_held,
+            "moe_load_max": jnp.max(counts),
+            "moe_load_mean": jnp.mean(counts.astype(jnp.float32)),
+            "moe_dropped_slots": n_held - taken,
+        }
+        return y.astype(x.dtype), counters
+
+
+class MoE(Weights):
+    """Routed experts held here + the shared experts (one SwiGLU of
+    `n_shared_experts * moe_intermediate_size`)."""
+
+    cfg: LMConfig = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        cfg = self.cfg
+        flat = x.reshape(-1, x.shape[-1])
+        routed, counters = RoutedExperts(
+            cfg=cfg, dtype=self.dtype, init_std=self.init_std,
+            name="experts")(flat)
+        with jax.named_scope("lm/moe/shared"):
+            shared = SwiGLU(
+                width=cfg.n_shared_experts * cfg.moe_intermediate_size,
+                dtype=self.dtype, init_std=self.init_std, name="shared")(flat)
+        return (routed + shared).reshape(x.shape), counters

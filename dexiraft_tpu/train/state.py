@@ -15,8 +15,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from dexiraft_tpu.config import RAFTConfig, TrainConfig
-from dexiraft_tpu.models.raft import RAFT
+from dexiraft_tpu.config import TrainConfig
+from dexiraft_tpu.train.family import family_of
 
 
 @flax.struct.dataclass
@@ -39,50 +39,31 @@ class TrainState:
         return {"params": self.params, "batch_stats": self.batch_stats}
 
 
-def model_inputs_shape(
-    cfg: RAFTConfig, batch: int, image_size: Tuple[int, int]
-) -> Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]:
-    """(image shape, edge-image shape or None) for init/dummy batches."""
-    h, w = image_size
-    img = (batch, h, w, 3)
-    edges = (batch, h, w, 3) if (cfg.variant in ("early", "separate") and not cfg.embed_dexined) else None
-    return img, edges
-
-
 def create_state(
     rng: jax.Array,
-    cfg: RAFTConfig,
+    cfg: Any,
     tc: TrainConfig,
     batch_size: Optional[int] = None,
     image_size: Optional[Tuple[int, int]] = None,
 ) -> TrainState:
-    """Initialize params (Kaiming/Xavier per module) and optimizer state.
-
-    Init runs on small dummy shapes — RAFT is fully convolutional, so
-    parameters are shape-independent of the training resolution — and
-    as ONE jitted program: eagerly it is a compile per distinct op and
-    shape (some 1,200 of them for v5), minutes of set-up on a cold chip.
+    """Initialize params (the family's own init, train/family.py) and
+    optimizer state, as ONE jitted program: eagerly it is a compile per
+    distinct op and shape (some 1,200 of them for v5), minutes of set-up
+    on a cold chip. `batch_size` / `image_size` size RAFT's dummy init
+    batch; no parameter's shape depends on them.
     """
-    model = RAFT(cfg)
-    bs = batch_size if batch_size is not None else 1
-    init_size = image_size if image_size is not None else (64, 64)
-    img_shape, edge_shape = model_inputs_shape(cfg, bs, init_size)
+    family = family_of(cfg, tc)
+    sizes = {k: v for k, v in (("batch_size", batch_size),
+                               ("image_size", image_size)) if v is not None}
     tx = make_optimizer_from(tc)
 
     def init(rng: jax.Array) -> TrainState:
         init_rng, state_rng = jax.random.split(rng)
-        dummy = jnp.zeros(img_shape, jnp.float32)
-        kwargs = {}
-        if edge_shape is not None:
-            e = jnp.zeros(edge_shape, jnp.float32)
-            kwargs = dict(edges1=e, edges2=e)
-        variables = model.init(init_rng, dummy, dummy, iters=1, train=False,
-                               **kwargs)
-        params = variables["params"]
+        params, batch_stats = family.init(init_rng, **sizes)
         return TrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,
-            batch_stats=variables.get("batch_stats", {}),
+            batch_stats=batch_stats,
             opt_state=tx.init(params),
             rng=state_rng,
         )

@@ -1,5 +1,17 @@
 """Jitted train / eval steps, single-chip or sharded over a device mesh.
 
+The seam. `make_train_step(cfg, tc)` asks `train/family.py` for the
+model family of `cfg` and takes from it three functions: `init(rng)`
+(through `create_state`), `loss_fn(params, batch_stats, batch, rng) ->
+(loss, (metrics, new_stats))`, `augment(batch, rng)` and
+`grad_metrics(grads)`. What is in this file after that is the same for
+every family: value-and-grad, the
+microbatch scan, clip + AdamW on the OneCycle schedule from fp32
+masters, the `all_finite` verdict, donation, the mesh's shardings and
+the fsdp fences. RAFT v1-v5 (a `RAFTConfig`) is the first family, the
+language model of models/lm (an `LMConfig`) the second; the eval, encode
+and refine steps below are RAFT's alone.
+
 The reference's inner loop (train.py:163-186: forward, sequence loss,
 backward, unscale/clip/step, scheduler) becomes ONE jitted function —
 the 12-iteration refinement loop, loss, and optimizer update all compile
@@ -49,7 +61,6 @@ from jax.sharding import Mesh
 
 from dexiraft_tpu.config import RAFTConfig, TrainConfig
 from dexiraft_tpu.models.raft import RAFT
-from dexiraft_tpu.ops.losses import sequence_loss
 from dexiraft_tpu.parallel import halo
 from dexiraft_tpu.parallel.layout import (
     LAYOUT,
@@ -59,6 +70,7 @@ from dexiraft_tpu.parallel.layout import (
     state_sharding,
     variables_sharding,
 )
+from dexiraft_tpu.train.family import family_of, thread_remat
 from dexiraft_tpu.train.optimizer import training_schedule
 from dexiraft_tpu.train.state import TrainState, create_state, make_optimizer_from
 
@@ -85,21 +97,6 @@ def all_finite(*trees: Any) -> jax.Array:
     return ok
 
 
-def _add_noise(rng: jax.Array, stdv: jax.Array, image: jax.Array) -> jax.Array:
-    """Gaussian noise at the given stdv, clipped to [0,255] (train.py:170-173);
-    the reference draws ONE stdv ~ U(0,5) shared by both frames."""
-    noisy = image + stdv * jax.random.normal(rng, image.shape, jnp.float32)
-    return jnp.clip(noisy, 0.0, 255.0)
-
-
-def cast_floating(tree: Any, dtype: Any) -> Any:
-    """Cast every floating leaf of a pytree to dtype; leave the rest alone."""
-    def cast(x):
-        x = jnp.asarray(x)
-        return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
-    return jax.tree.map(cast, tree)
-
-
 def make_train_step(
     cfg: RAFTConfig,
     tc: TrainConfig,
@@ -123,83 +120,18 @@ def make_train_step(
     if tc.remat not in ("none", "per_iter", "dots_saveable"):
         raise ValueError(f"remat must be none|per_iter|dots_saveable, "
                          f"got {tc.remat!r}")
-    if tc.remat != "none":
-        import dataclasses
-
-        # thread the TrainConfig remat axis into the model config: both
-        # checkpointing modes wrap the scanned iteration; the policy
-        # decides what the checkpoint saves (config.py remat_policy)
-        cfg = dataclasses.replace(
-            cfg, remat=True,
-            remat_policy=("dots_saveable" if tc.remat == "dots_saveable"
-                          else "full"))
     if compute_sharding == "halo":
-        return _make_halo_train_step(cfg, tc, mesh)
-    # bf16 training policy: force the MODEL's mixed-precision path —
-    # module compute dtype becomes bf16, so flax casts each op's params
-    # from the fp32 masters per use (autodiff transposes the casts and
-    # the gradients land back fp32), activations are genuinely bf16, and
-    # the corr volume stays fp32 by the model's own mixed-precision
-    # contract. Everything after the model — loss, metrics, BN running
-    # stats, optimizer — stays fp32. No loss scaling: bf16 shares fp32's
-    # exponent range (README design note). NOTE a hand-cast of params /
-    # inputs here would NOT work: RAFT.__call__ re-casts inputs fp32 and
-    # derives its compute dtype from cfg.mixed_precision alone.
-    bf16 = tc.precision == "bf16"
-    if bf16 and not cfg.mixed_precision:
-        import dataclasses
-
-        cfg = dataclasses.replace(cfg, mixed_precision=True)
-    model = RAFT(cfg)
-    if tc.edge_sum_fusion and (cfg.variant != "raft" or cfg.embed_dexined):
-        raise ValueError(
-            "edge_sum_fusion is the v1 (plain 'raft') training fusion — "
-            "the model itself consumes edges in the other variants")
+        if not isinstance(cfg, RAFTConfig):
+            raise ValueError("compute_sharding='halo' shards image rows: "
+                             "RAFT only")
+        return _make_halo_train_step(thread_remat(cfg, tc), tc, mesh)
+    # the family threads tc's remat and precision axes into its own
+    # model config (train/family.py)
+    family = family_of(cfg, tc)
     tx = make_optimizer_from(tc)
     schedule = training_schedule(tc.lr, tc.num_steps)
 
-    def loss_fn(params: Any, batch_stats: Any, batch: Batch, rng: jax.Array):
-        def fwd(stats, drop_rng, im1, im2, **kw):
-            return model.apply(
-                {"params": params, "batch_stats": stats},
-                im1, im2, iters=tc.iters, train=True,
-                freeze_bn=tc.freeze_bn, mutable=["batch_stats"],
-                rngs={"dropout": drop_rng}, **kw,
-            )
-
-        if tc.edge_sum_fusion:
-            if "edges1" not in batch:
-                raise ValueError("edge_sum_fusion needs edge-pair data "
-                                 "(edge_root)")
-            # v1-lineage summed fusion (alt/train_1.py:173-176): same
-            # model on the image pair and the edge-image pair, per-iter
-            # predictions summed; BN stats update through both passes
-            # sequentially, and each pass draws independent dropout masks
-            # like the reference's two separate forward calls
-            rng_img, rng_edge = jax.random.split(rng)
-            img_flow, mut1 = fwd(batch_stats, rng_img,
-                                 batch["image1"], batch["image2"])
-            edge_flow, mut2 = fwd(mut1.get("batch_stats", batch_stats),
-                                  rng_edge,
-                                  batch["edges1"], batch["edges2"])
-            outputs = img_flow + edge_flow
-            mutated = mut2
-        else:
-            kwargs: Dict[str, Any] = {}
-            if "edges1" in batch:
-                kwargs = dict(edges1=batch["edges1"], edges2=batch["edges2"])
-            outputs, mutated = fwd(batch_stats, rng, batch["image1"],
-                                   batch["image2"], **kwargs)
-        new_stats = mutated.get("batch_stats", batch_stats)
-        if bf16:
-            # fp32 loss/metrics and fp32 carried state, whatever dtype
-            # the bf16 forward emitted
-            outputs = outputs.astype(jnp.float32)
-            new_stats = cast_floating(new_stats, jnp.float32)
-        loss, metrics = sequence_loss(outputs, batch["flow"], batch["valid"], tc.gamma)
-        return loss, (metrics, new_stats)
-
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grad_fn = jax.value_and_grad(family.loss_fn, has_aux=True)
 
     # fsdp fence shardings, filled in below when the mesh has the axis;
     # None on every other path so the step body compiles unchanged
@@ -217,12 +149,7 @@ def make_train_step(
             # Everything below computes exactly the replicated program.
             state = jax.lax.with_sharding_constraint(state, fence_repl)
         rng, noise_rng, dropout_rng = jax.random.split(state.rng, 3)
-        if tc.add_noise:
-            k_stdv, k1, k2 = jax.random.split(noise_rng, 3)
-            stdv = jax.random.uniform(k_stdv, (), jnp.float32, 0.0, 5.0)
-            batch = dict(batch)
-            batch["image1"] = _add_noise(k1, stdv, batch["image1"])
-            batch["image2"] = _add_noise(k2, stdv, batch["image2"])
+        batch = family.augment(batch, noise_rng)
 
         accum = tc.accum_steps
         if accum > 1:
@@ -237,7 +164,7 @@ def make_train_step(
             # every framework's; equivalent to training at the smaller
             # BN batch). Running stats thread sequentially through the
             # scan carry, like sequential steps would
-            b = batch["image1"].shape[0]
+            b = jax.tree.leaves(batch)[0].shape[0]
             if b % accum:
                 raise ValueError(
                     f"batch {b} not divisible by accum_steps {accum}")
@@ -275,8 +202,16 @@ def make_train_step(
             (loss, (metrics, batch_stats)), grads = grad_fn(
                 state.params, state.batch_stats, batch, dropout_rng)
 
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+        with jax.named_scope("optimizer"):
+            # what a family wants to read of the gradients before the
+            # clip (RAFT: nothing, so nothing is traced for it)
+            metrics = dict(metrics, **family.grad_metrics(grads))
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+            # in the scope too: XLA fuses the verdict's pass over the new
+            # state with the update that writes it
+            state_finite = all_finite(params, batch_stats, opt_state)
 
         new_state = TrainState(
             step=state.step + 1,
@@ -286,8 +221,7 @@ def make_train_step(
             rng=rng,
         )
         metrics = dict(metrics, loss=loss, lr=schedule(state.step),
-                       state_finite=all_finite(params, batch_stats,
-                                               opt_state))
+                       state_finite=state_finite)
         if fence_repl is not None:
             # EXIT FENCE (fsdp): pin the finished state replicated so
             # sharding propagation from the sharded out_shardings below

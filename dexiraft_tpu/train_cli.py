@@ -8,6 +8,9 @@ one stage; presets supply the per-stage hyperparameters:
       --variant v1 --validation chairs
   python -m dexiraft_tpu train --preset standard --stage things \
       --restore_ckpt checkpoints/raft-chairs
+  python -m dexiraft_tpu train --variant kanana2 --tokens docs.npz \
+      --layers 6 --heads_held 0 4 --experts_held 0 16 --vocab_size 16032 \
+      --batch_size 4 --precision bf16 --remat        (docs/lm.md)
 
 The loop is the reference's (train.py:163-215) re-shaped for TPU: one
 jitted sharded step (forward + loss + backward + optimizer), batches
@@ -25,7 +28,13 @@ import jax
 import numpy as np
 
 from dexiraft_tpu import config as cfglib
-from dexiraft_tpu.config import VARIANTS, RAFTConfig, TrainConfig
+from dexiraft_tpu.config import (
+    LM_VARIANTS,
+    VARIANTS,
+    LMConfig,
+    RAFTConfig,
+    TrainConfig,
+)
 
 # reference in-training validation iteration counts (evaluate.py:81-210)
 _VAL_ITERS = {"chairs": 24, "sintel": 32, "kitti": 24, "hd1k": 24}
@@ -52,11 +61,34 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--name", default=None,
                    help="experiment name (default: preset's per-stage name, "
                         "else 'raft')")
-    p.add_argument("--stage", required=True,
-                   choices=["chairs", "things", "sintel", "kitti"])
+    p.add_argument("--stage", default=None,
+                   choices=["chairs", "things", "sintel", "kitti"],
+                   help="curriculum stage (required for the RAFT variants)")
     p.add_argument("--preset", choices=["standard", "mixed", "none"],
                    default="none", help="stage hyperparameter preset")
-    p.add_argument("--variant", default="v1", choices=sorted(VARIANTS))
+    p.add_argument("--variant", default="v1",
+                   choices=sorted(VARIANTS) + sorted(LM_VARIANTS),
+                   help="v1..v5: RAFT; kanana2: the language model of "
+                        "models/lm (docs/lm.md), trained on --tokens")
+    # the language model's own flags (refused for the RAFT variants)
+    p.add_argument("--tokens", default=None,
+                   help="kanana2: token file (.npz of `tokens` and "
+                        "`lengths`, data/tokens.py), packed first-fit "
+                        "into rows of --seq_len")
+    p.add_argument("--seq_len", type=int, default=None,
+                   help="kanana2: positions a row (default 8192)")
+    p.add_argument("--layers", type=int, default=None,
+                   help="kanana2: decoder layers held (default: all 48)")
+    p.add_argument("--vocab_size", type=int, default=None,
+                   help="kanana2: rows of the vocabulary held")
+    p.add_argument("--heads_held", type=int, nargs=2, default=None,
+                   metavar=("FIRST", "COUNT"),
+                   help="kanana2: the attention heads this chip holds of "
+                        "a tensor-parallel group (default: all)")
+    p.add_argument("--experts_held", type=int, nargs=2, default=None,
+                   metavar=("FIRST", "COUNT"),
+                   help="kanana2: the routed experts this chip holds of "
+                        "an expert-parallel group (default: all)")
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="allpairs",
@@ -269,7 +301,56 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# flags that mean something for one family only; given for the other,
+# `train` refuses them by name rather than ignoring them
+_RAFT_ONLY = ("stage", "preset", "small", "mixed_precision", "corr_impl",
+              "corr_dtype", "fused_update", "remat_lookup", "dexined_upconv",
+              "dropout", "image_size", "gamma", "iters", "add_noise",
+              "validation", "records_dir", "edge_root", "edge_sum_fusion",
+              "fsdp", "elastic", "join")
+_LM_ONLY = ("tokens", "seq_len", "layers", "vocab_size", "heads_held",
+            "experts_held")
+
+
+def _refuse_given(args, names, why: str) -> None:
+    defaults = build_parser()
+    given = [n for n in names if getattr(args, n) != defaults.get_default(n)]
+    if given:
+        raise SystemExit(f"train: {', '.join('--' + n for n in given)} "
+                         f"{why}")
+
+
+def resolve_lm_configs(args) -> "tuple[LMConfig, TrainConfig]":
+    _refuse_given(args, _RAFT_ONLY,
+                  f"belong(s) to the RAFT variants; --variant "
+                  f"{args.variant} is a language model (docs/lm.md: "
+                  "--tokens, --seq_len, --layers, --vocab_size, "
+                  "--heads_held, --experts_held, --remat for whole layers)")
+    if not args.tokens:
+        raise SystemExit(f"train: --variant {args.variant} needs --tokens")
+    model = {k: v for k, v in (
+        ("seq_len", args.seq_len), ("num_hidden_layers", args.layers),
+        ("vocab_size", args.vocab_size), ("heads_held", args.heads_held),
+        ("experts_held", args.experts_held)) if v is not None}
+    cfg = LM_VARIANTS[args.variant](remat=args.remat, **model)
+    tc = TrainConfig(
+        name=args.name or args.variant, stage="tokens", clip=args.clip,
+        precision=args.precision, accum_steps=args.accum_steps,
+        prefetch_depth=args.prefetch_depth, val_freq=args.val_freq,
+        sum_freq=args.sum_freq, seed=args.seed, validation=(),
+        **{k: v for k, v in (("lr", args.lr), ("num_steps", args.num_steps),
+                             ("batch_size", args.batch_size),
+                             ("wdecay", args.wdecay)) if v is not None})
+    return cfg, tc
+
+
 def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
+    if args.variant in LM_VARIANTS:
+        return resolve_lm_configs(args)
+    _refuse_given(args, _LM_ONLY, "belong(s) to --variant kanana2")
+    if args.stage is None:
+        raise SystemExit("train: --stage is required for --variant "
+                         f"{args.variant}")
     if args.fused_update and args.corr_impl not in ("pallas", "flash"):
         raise SystemExit("train: --fused_update requires --corr_impl "
                          "flash (the blocked HBM-streaming kernel) or "
@@ -405,9 +486,15 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
 
     if args.compile_cache:
         enable_persistent_cache()
-    device_banner("train", corr_impl=cfg.corr_impl,
-                  fused_update=cfg.fused_update, mesh=dict(mesh.shape),
-                  decoder=native.status())
+    is_lm = isinstance(cfg, LMConfig)
+    if is_lm:
+        device_banner("train", model=args.variant, mesh=dict(mesh.shape),
+                      heads_held=cfg.heads_held,
+                      experts_held=cfg.experts_held)
+    else:
+        device_banner("train", corr_impl=cfg.corr_impl,
+                      fused_update=cfg.fused_update, mesh=dict(mesh.shape),
+                      decoder=native.status())
     state = create_state(jax.random.PRNGKey(tc.seed), cfg, tc)
     print(f"Parameter Count: {param_count(state.params)}")
     fsdp_live = layout.LAYOUT.has_fsdp(mesh)
@@ -584,6 +671,13 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
               f"({man.num_records} records in {len(man.shards)} shard(s), "
               f"fingerprint {man.fingerprint[:12]})")
         loader = RecordLoader(records_ds, tc.batch_size, **loader_kwargs)
+    elif is_lm:
+        from dexiraft_tpu.data.tokens import PackedTokens
+
+        dataset = PackedTokens(args.tokens, cfg.seq_len)
+        print(f"Training with {len(dataset)} packed rows of {cfg.seq_len} "
+              f"positions ({dataset.fill:.1%} filled)")
+        loader = Loader(dataset, tc.batch_size, **loader_kwargs)
     else:
         dataset = fetch_dataset(tc.stage, tc.image_size,
                                 edge_root=args.edge_root)
@@ -598,7 +692,8 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
     # gather fences, so it must never see fsdp-sharded params — gather
     # explicitly (sanctioned host window; layout.gather_state is a
     # no-op on replicated leaves / non-fsdp meshes)
-    validate = _make_validators(
+    # (a language model has no validation set here: tc.validation is ())
+    validate = None if is_lm else _make_validators(
         cfg, tc.validation,
         (lambda: layout.gather_state(state.variables, mesh)) if fsdp_live
         else (lambda: state.variables))
@@ -736,7 +831,8 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
                     # steps, not compiles)
                     watch = jaxguards.RecompileWatch(f"train[{tc.name}]")
                     watch.mark_warm()
-                    batch_devices = len(batch["image1"].sharding.device_set)
+                    batch_devices = len(
+                        jax.tree.leaves(batch)[0].sharding.device_set)
                     if args.strict:
                         guard_stack.enter_context(
                             jax.transfer_guard("disallow"))

@@ -1,0 +1,46 @@
+"""Shared by the tests/test_zz_lm_*.py files: the toy configuration, a
+packed batch and seeded weights of O(1) scale."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from dexiraft_tpu.config import TrainConfig, kanana2_toy
+from dexiraft_tpu.train.family import family_of
+
+
+def packed_batch(cfg, rows=2, seed=0):
+    """Rows of `cfg.seq_len`: documents of 50, 40 and 30 tokens, then
+    pad; positions restart, segment ids 1, 2, 3, 0."""
+    rng = np.random.default_rng(seed)
+    s = cfg.seq_len
+    docs = (50, 40, 30)
+    seg = np.zeros((rows, s), np.int32)
+    pos = np.zeros((rows, s), np.int32)
+    at = 0
+    for i, n in enumerate(docs, start=1):
+        seg[:, at:at + n] = i
+        pos[:, at:at + n] = np.arange(n)
+        at += n
+    tokens = rng.integers(0, cfg.vocab_size, (rows, s)).astype(np.int32)
+    tokens[seg == 0] = 0
+    return {k: jnp.asarray(v) for k, v in
+            (("tokens", tokens), ("positions", pos), ("segment_ids", seg))}
+
+
+def seeded(cfg, precision="fp32", remat="none", seed=1, scale=5.0):
+    """(family, params, batch_stats): matrices scaled up from the 0.02
+    init so that every path carries signal at toy widths."""
+    family = family_of(cfg, TrainConfig(precision=precision, remat=remat))
+    params, stats = family.init(jax.random.PRNGKey(seed))
+    params = jax.tree.map(lambda p: p * scale if p.ndim > 1 else p, params)
+    return family, params, stats
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def toy(**kw):
+    return kanana2_toy(**kw)

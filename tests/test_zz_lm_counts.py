@@ -1,0 +1,156 @@
+"""The benchmark's analytic counts for the language-model cell
+(benchmarks/lm_counts.py) and its scope reducer (benchmarks/lm_scopes.py).
+
+`flops.py` walks a jaxpr and knows `dot_general`; the cell's count is
+analytic because the expert layer's work follows the routing. The dense
+parts (projections, shared experts, dense layer, head, router) are held
+to the walk of the plain reference at the toy size, whose other products
+have closed forms of their own: full `S x S` score matrices and every
+held expert on every token. The routed part is slots x 3 x 2 x hidden x
+width by hand.
+"""
+
+import os.path as osp
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+
+from benchmarks import flops, lm_counts, lm_scopes  # noqa: E402
+from dexiraft_tpu.config import kanana2  # noqa: E402
+from dexiraft_tpu.interop import lm_reference as ref  # noqa: E402
+
+from _lm_common import packed_batch, seeded, toy  # noqa: E402
+
+
+def test_dense_parts_equal_the_walk_of_the_reference():
+    cfg = toy(experts_held=(2, 4), heads_held=(1, 2))
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg)
+    rows, s = batch["tokens"].shape
+    walked = flops.count(lambda p: ref.loss(p, batch, cfg), params)
+    per_token = sum(lm_counts.per_token_forward(cfg).values())
+    heads = cfg.heads_held[1]
+    moe_layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
+    # what the reference computes beyond the dense parts, in closed form:
+    # whole score matrices, and each held expert on every token
+    scores = rows * cfg.num_hidden_layers * heads * s * s * 2 * (
+        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim)
+    experts = (rows * s * moe_layers * cfg.experts_held[1]
+               * lm_counts.per_slot_forward(cfg))
+    assert walked == per_token * rows * s + scores + experts
+
+
+def test_routed_part_is_slots_times_three_products_by_hand():
+    cfg = kanana2(num_hidden_layers=6, heads_held=(0, 4),
+                  experts_held=(0, 16), vocab_size=16032)
+    assert lm_counts.per_slot_forward(cfg) == 3 * 2 * 2048 * 768
+    parts = lm_counts.step_flops(cfg, tokens_real=32768, slots_held=5 * 24576,
+                                 pairs_in_document=4 * 14e6)
+    assert parts["routed"] == 3 * (5 * 24576) * 3 * 2 * 2048 * 768
+    assert parts["total"] == pytest.approx(sum(
+        v for k, v in parts.items() if k != "total"))
+    # the issue's arithmetic: an expert layer's routed + shared + router
+    # outweigh its attention projections at the deployment's balance
+    assert 3.0e13 < parts["total"] < 4.5e13
+
+
+def test_pairs_in_document_counts_the_causal_pairs_of_each_document():
+    seg = np.array([1, 1, 1, 2, 2, 0, 0])
+    assert lm_counts.pairs_in_document(seg) == 6 + 3
+
+
+def test_grouped_roofline_is_compute_bound_at_the_deployments_load():
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    slots = 5 * 16 * 1536
+    least = lm_counts.grouped_roofline_seconds(2048, 768, 16, slots, 5, True,
+                                               peaks)
+    assert lm_counts.grouped_calls(True) == 12
+    assert least["flops"] == slots * 12 * 2 * 2048 * 768
+    assert least["bound"] == "compute"
+    assert least["flops"] / least["bytes"] == pytest.approx(409.6, rel=0.01)
+    # a tenth of the rows an expert: the weights' bytes bound it
+    few = lm_counts.grouped_roofline_seconds(2048, 768, 16, slots / 10, 5,
+                                             True, peaks)
+    assert few["bound"] == "memory"
+
+
+COMPILED = """
+HloModule jit_step
+%fused_computation.1 (p: bf16[8,8]) -> bf16[8,8] {
+  %multiply.9 = bf16[8,8]{1,0} multiply(%p, %p), metadata={op_name="jit(step)/jit(main)/lm/mla/mul"}
+}
+ENTRY %main {
+  %fusion.1 = bf16[8,8]{1,0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/jit(main)/transpose(jvp(lm/mla))/dot_general" source_file="x.py"}
+  %ragged-dot.2 = bf16[8,8]{1,0} custom-call(%b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jit(main)/checkpoint/lm/moe/experts/ragged_dot"}
+  %fusion.3 = f32[8]{0} fusion(%c), kind=kInput, calls=%fc, metadata={op_name="jit(step)/jit(main)/optimizer/add"}
+  %while.4 = (s32[]) while(%t), condition=%c, body=%b, metadata={op_name="jit(step)/jit(main)/lm/head_loss/while"}
+  ROOT %copy.5 = f32[8]{0} copy(%fusion.3)
+}
+"""
+
+
+def test_scopes_join_trace_events_with_the_compiled_text():
+    names = lm_scopes.instruction_scopes(COMPILED)
+    assert names["fusion.1"].endswith("transpose(jvp(lm/mla))/dot_general")
+    assert "copy.5" not in names
+    ms = 1_000_000
+    ops = [["%fusion.1 fusion bf16[8,8]", 0, 2 * ms],
+           ["%ragged-dot.2 tpu_custom_call bf16[8,8]", 2 * ms, 3 * ms],
+           ["%fusion.3 fusion f32[8]", 5 * ms, 1 * ms],
+           ["%while.4 while s32[]", 0, 9 * ms],      # a container: skipped
+           ["%copy.5 copy f32[8]", 6 * ms, 1 * ms],  # no op_name
+           ["%fusion.1 fusion bf16[8,8]", 20 * ms, 2 * ms]]  # outside
+    got = lm_scopes.scope_seconds(
+        [{"name": "/device:TPU:0", "ops": ops}], (0, 10 * ms), names,
+        ("lm/moe/experts", "lm/mla", "optimizer"))
+    assert got["lm/mla"] == pytest.approx(0.002)
+    assert got["lm/moe/experts"] == pytest.approx(0.003)
+    assert got["optimizer"] == pytest.approx(0.001)
+    assert got["unattributed"] == pytest.approx(0.001)
+    assert got["leaf_total"] == pytest.approx(0.007)
+    assert got["unattributed_top"][0][0].startswith("%copy.5")
+
+
+def test_new_layer_metrics_read_the_scope_counters_and_give_nothing_without():
+    from benchmarks import harness
+
+    counters = {"scope_s:lm/moe/experts": 0.2, "scope_s:lm/moe/router": 0.01,
+                "scope_s:lm/moe/dispatch": 0.03, "scope_s:lm/moe/combine": 0.02,
+                "scope_s:lm/moe/shared": 0.04, "scope_s:lm/mla": 0.15,
+                "scope_s:lm/head_loss": 0.07, "scope_s:optimizer": 0.02,
+                "traced_slots_held": 5 * 16 * 1536.0, "experts_layers": 5,
+                "remat": 1.0, "hidden_size": 2048,
+                "moe_intermediate_size": 768, "experts_held": 16,
+                "moe_load_max": 1700.0, "moe_load_mean": 1536.0,
+                "tokens_real": 32500.0, "batch": 4, "seq_len": 8192}
+
+    def obs(c, trace):
+        return harness.Observation(
+            spans={}, counters=c, end_to_end={}, trace=trace,
+            peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+            chips=1, memory_peak_bytes=0)
+
+    read = lambda name, o: harness.load_metric(name).read(o)
+    full = obs(counters, {"busy_s": 1.0})
+    assert read("lm_moe_device_ms", full) == pytest.approx(300.0)
+    assert read("lm_moe_experts_device_ms", full) == pytest.approx(200.0)
+    assert read("lm_attn_device_ms", full) == pytest.approx(150.0)
+    assert read("lm_head_loss_device_ms", full) == pytest.approx(70.0)
+    assert read("lm_optimizer_device_ms", full) == pytest.approx(20.0)
+    share = read("lm_moe_experts_roofline_pct", full)
+    assert share == pytest.approx(
+        5 * 16 * 1536 * 12 * 2 * 2048 * 768 / 197e12 / 0.2 * 100)
+    assert 0 < share <= 100
+    assert read("lm_moe_load_max_over_mean", full) == pytest.approx(1700 / 1536)
+    assert read("lm_pack_fill_pct", full) == pytest.approx(32500 / 32768 * 100)
+    # a program without the scopes or counters (the parent): nothing, no raise
+    bare = obs({}, {"busy_s": 1.0})
+    for name in ("lm_moe_device_ms", "lm_moe_experts_device_ms",
+                 "lm_attn_device_ms", "lm_head_loss_device_ms",
+                 "lm_optimizer_device_ms", "lm_moe_experts_roofline_pct",
+                 "lm_moe_load_max_over_mean", "lm_pack_fill_pct"):
+        assert read(name, bare) is None

@@ -1,0 +1,126 @@
+"""The shares test: the parts of a layer's output that all 8 chips of
+the stated deployment give (heads 8 ways, routed experts 8 ways), with
+what every chip computes alike (the shared experts) counted once, add up
+to what the uncut reference gives for the whole layer.
+
+Each share runs the SYSTEM's modules (models/lm) on its slice of the
+whole model's weights; the whole is the plain reference holding every
+head and expert. fp32, so 2e-5 relative (seen 1e-6): float32 sums in
+another order.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dexiraft_tpu.interop import lm_reference as ref
+from dexiraft_tpu.models.lm.attention import LatentAttention
+from dexiraft_tpu.models.lm.layers import SwiGLU
+from dexiraft_tpu.models.lm.moe import RoutedExperts
+
+from _lm_common import packed_batch, rel, seeded, toy
+
+SHARES = 8
+
+
+@pytest.fixture(scope="module")
+def whole():
+    cfg = toy()
+    _, params, stats = seeded(cfg)
+    batch = packed_batch(cfg, rows=1)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, cfg.seq_len,
+                                                  cfg.hidden_size))
+    return cfg, params, batch, x
+
+
+def _share_cfg(i):
+    return toy(heads_held=(i, 1), experts_held=(2 * i, 2))
+
+
+def _attention_part(i, whole):
+    cfg, params, batch, x = whole
+    share = _share_cfg(i)
+    p = ref.take_share(params, cfg, share.heads_held, share.experts_held)
+    return LatentAttention(cfg=share).apply(
+        {"params": p["layers_1"]["attn"]}, x, batch["positions"],
+        batch["segment_ids"])[0]
+
+
+def _routed_part(i, whole):
+    cfg, params, batch, x = whole
+    share = _share_cfg(i)
+    p = ref.take_share(params, cfg, share.heads_held, share.experts_held)
+    out, counters = RoutedExperts(cfg=share).apply(
+        {"params": p["layers_1"]["moe"]["experts"]}, x[0],
+        mutable=["batch_stats"])[0]
+    assert int(counters["moe_dropped_slots"]) == 0
+    return out, counters
+
+
+@pytest.mark.parametrize("i", range(SHARES))
+def test_a_share_equals_the_reference_given_that_share(i, whole):
+    cfg, params, batch, x = whole
+    share = _share_cfg(i)
+    p = ref.take_share(params, cfg, share.heads_held, share.experts_held)
+    lp = p["layers_1"]
+    want_attn = ref.attention(lp["attn"], x[0], batch["positions"][0],
+                              batch["segment_ids"][0], cfg, heads=1)
+    assert rel(_attention_part(i, whole), want_attn) < 2e-5
+    want_moe = ref.moe(lp["moe"], x[0], cfg, share.experts_held)
+    routed, _ = _routed_part(i, whole)
+    shared = SwiGLU(width=cfg.n_shared_experts * cfg.moe_intermediate_size
+                    ).apply({"params": lp["moe"]["shared"]}, x[0])
+    assert rel(routed + shared, want_moe) < 2e-5
+
+
+def test_attention_parts_of_all_shares_add_up_to_the_whole(whole):
+    cfg, params, batch, x = whole
+    total = sum(_attention_part(i, whole) for i in range(SHARES))
+    want = ref.attention(params["layers_1"]["attn"], x[0],
+                         batch["positions"][0], batch["segment_ids"][0], cfg,
+                         heads=cfg.num_attention_heads)
+    assert rel(total, want) < 2e-5
+
+
+def test_routed_parts_add_up_with_the_shared_experts_counted_once(whole):
+    cfg, params, batch, x = whole
+    parts = [_routed_part(i, whole) for i in range(SHARES)]
+    shared = SwiGLU(width=cfg.n_shared_experts * cfg.moe_intermediate_size
+                    ).apply({"params": params["layers_1"]["moe"]["shared"]},
+                            x[0])
+    total = sum(out for out, _ in parts) + shared
+    want = ref.moe(params["layers_1"]["moe"], x[0], cfg,
+                   (0, cfg.n_routed_experts))
+    assert rel(total, want) < 2e-5
+    # every slot of every token lands on exactly one chip
+    slots = sum(int(c["moe_slots_held"]) for _, c in parts)
+    assert slots == cfg.seq_len * cfg.num_experts_per_tok
+
+
+def test_layer_outputs_of_the_shares_add_up_to_the_whole_layer(whole):
+    """x + sum of the attention parts = h; h + routed parts + shared once
+    = the uncut reference's layer output."""
+    cfg, params, batch, x = whole
+    lp = params["layers_1"]
+    want = ref.layer(lp, x[0], batch["positions"][0],
+                     batch["segment_ids"][0], cfg, dense=False)
+    normed = ref._rms_norm(x[0], lp["attn_norm"], cfg.rms_norm_eps)
+    h = x[0]
+    routed = 0.0
+    for i in range(SHARES):
+        share = _share_cfg(i)
+        p = ref.take_share(params, cfg, share.heads_held, share.experts_held)
+        h = h + LatentAttention(cfg=share).apply(
+            {"params": p["layers_1"]["attn"]}, normed[None],
+            batch["positions"], batch["segment_ids"])[0]
+    ffn_in = ref._rms_norm(h, lp["ffn_norm"], cfg.rms_norm_eps)
+    for i in range(SHARES):
+        share = _share_cfg(i)
+        p = ref.take_share(params, cfg, share.heads_held, share.experts_held)
+        routed = routed + RoutedExperts(cfg=share).apply(
+            {"params": p["layers_1"]["moe"]["experts"]}, ffn_in,
+            mutable=["batch_stats"])[0][0]
+    shared = SwiGLU(width=cfg.n_shared_experts * cfg.moe_intermediate_size
+                    ).apply({"params": lp["moe"]["shared"]}, ffn_in)
+    assert rel(h + routed + shared, want) < 2e-5
