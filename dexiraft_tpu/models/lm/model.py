@@ -27,9 +27,11 @@ from dexiraft_tpu.config import LMConfig
 from dexiraft_tpu.models.lm.attention import LatentAttention
 from dexiraft_tpu.models.lm.layers import SwiGLU, Weights, rms_norm
 from dexiraft_tpu.models.lm.moe import MoE
+from dexiraft_tpu.ops.lm_attention import block_pair_counts, kernel_blocks
 
 COUNTERS = ("moe_slots_held", "moe_load_max", "moe_load_mean",
-            "moe_dropped_slots")
+            "moe_dropped_slots", "attn_block_pairs_visited",
+            "attn_block_pairs_causal")
 
 
 def _gain(module: nn.Module, name: str, width: int) -> jax.Array:
@@ -96,7 +98,8 @@ class LM(nn.Module):
         head = self.param("head", nn.initializers.normal(cfg.init_std),
                           (cfg.hidden_size, cfg.vocab_size),
                           jnp.float32).astype(dtype)
-        counters = _reduce_counters(per_layer)
+        counters = dict(_reduce_counters(per_layer),
+                        **_attention_counters(cfg, segment_ids))
         if logits:
             return jnp.matmul(x, head,
                               preferred_element_type=jnp.float32), counters
@@ -108,13 +111,27 @@ def _reduce_counters(per_layer) -> Dict[str, jax.Array]:
     expert's load, the mean load."""
     if not per_layer:
         return {}
-    stack = {k: jnp.stack([c[k] for c in per_layer]) for k in COUNTERS}
+    stack = {k: jnp.stack([c[k] for c in per_layer]) for k in per_layer[0]}
     return {
         "moe_slots_held": jnp.sum(stack["moe_slots_held"]),
         "moe_load_max": jnp.max(stack["moe_load_max"]),
         "moe_load_mean": jnp.mean(stack["moe_load_mean"]),
         "moe_dropped_slots": jnp.sum(stack["moe_dropped_slots"]),
     }
+
+
+def _attention_counters(cfg: LMConfig, segment_ids: jax.Array
+                        ) -> Dict[str, jax.Array]:
+    """Of a layer (every layer sees the same documents): the block pairs
+    the attention kernel's grid computes for this batch and those of its
+    causal triangle, from the table the kernel is handed. Where the
+    kernel does not take the shapes, the one block the XLA path's mask
+    covers."""
+    seq = segment_ids.shape[1]
+    blocks = kernel_blocks(seq, cfg.qk_head_dim, cfg.v_head_dim) or (seq, seq)
+    visited, causal = block_pair_counts(segment_ids, *blocks)
+    return {"attn_block_pairs_visited": visited,
+            "attn_block_pairs_causal": causal}
 
 
 def next_token_targets(tokens: jax.Array, segment_ids: jax.Array

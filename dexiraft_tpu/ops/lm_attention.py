@@ -1,28 +1,62 @@
-"""Causal attention within packed documents, by blocks of query rows.
+"""Causal attention within packed documents.
 
 A row of a packed batch holds several documents; a token attends to the
 tokens of its own document that do not come after it. At 8192 positions
 the whole `[heads, S, S]` score matrix is 1 GiB a head in fp32, so it is
-never made: query block `i` (rows `[i*block, (i+1)*block)`) meets keys
-`[0, (i+1)*block)` only, which also leaves out the causal mask's upper
-blocks (36 of 64 block pairs at 8 blocks). Each block is a
-`jax.checkpoint`: the backward recomputes its scores and keeps none.
+never made. `document_attention` takes one of two paths, chosen from
+what it can observe (the backend and the shapes), not by a flag:
 
-Plain XLA on purpose: two matmuls and a softmax a block. The blocks
-below the diagonal that hold no pair of one document are still computed
-and masked; skipping them takes a kernel that reads the segment table
-(ROADMAP.md).
+**On a TPU, whole lane-aligned blocks: a Pallas flash kernel**
+(`flash_document_attention`). Grid `(rows x groups of up to 4 heads, query
+blocks, key blocks)`, key blocks innermost. A `(bq, bk)` tile of scores
+lives in VMEM in fp32 and nowhere else; the running maximum, the running
+sum and the output accumulator are fp32 VMEM scratch. The backward is two more
+kernels (`dq`; `dk` and `dv`) that recompute a tile's scores from `q`,
+`k` and the row's log-sum-exp, the forward's only residual beside its
+output. A packed row's documents are contiguous, so the key blocks a
+query block needs are a range: from the block that holds the first token
+of the document of the query block's first row, to the diagonal
+(`block_table`; `last_q` is its transpose for `dk`/`dv`). The table is
+made in XLA from `segment_ids`, handed to the kernels by scalar prefetch,
+and does two things: the key block's index map is clamped into the
+range, so a grid step outside it fetches nothing, and the step's compute
+is under `pl.when`. Inside the range, a tile below the diagonal whose
+rows and keys all lie in one document (`full_kv`, `full_q`) skips the
+mask. `block_pair_counts` counts the visited pairs from the same table.
 
-Scores, the mask and the softmax are fp32 whatever the inputs are; the
-probabilities are cast to `v`'s dtype for the second matmul.
+**Elsewhere (the CPU of the tests, shapes that are not whole blocks):
+plain XLA by blocks of query rows**, the oracle of the kernel's tests.
+Query block `i` (rows `[i*block, (i+1)*block)`) meets keys
+`[0, (i+1)*block)`: the causal mask's upper blocks are left out, the
+blocks below the diagonal that hold no pair of one document are computed
+and masked. Each block is a `jax.checkpoint`.
+
+Both: scores, the mask and the softmax statistics are fp32 whatever the
+inputs are; the probabilities are cast to `v`'s dtype for the second
+matmul; pad positions share id 0 and see each other.
 """
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple, Optional, Tuple
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _MASKED = -1e30  # not -inf: a row always holds its own diagonal
+_LANES = 128
+# the kernel's blocks of query rows and of keys: of 512, 1024 and their
+# mixes the fastest on the cell's documents, 13.4 ms a layer against
+# 13.9-14.3 (my chip run, PR 27; CHANGES.md has every reading)
+_BLOCK_Q = 512
+_BLOCK_K = 512
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+
+
+# ---- the XLA path ----------------------------------------------------------
 
 
 def _block(q, k, v, seg_q, seg_k, first_row, scale):
@@ -46,21 +80,26 @@ def _block(q, k, v, seg_q, seg_k, first_row, scale):
     return jnp.einsum("gqk,gkd->gqd", p.astype(v.dtype), v)
 
 
-def document_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                       segment_ids: jax.Array, *, scale: float,
-                       block: int) -> jax.Array:
-    """softmax(q k^T * scale) v over the earlier tokens of the same
-    document. q, k `[B, S, H, D]`, v `[B, S, H, Dv]`, segment_ids
-    `[B, S]` (pad positions share id 0 and see each other: their output
-    is never read). Returns `[B, S, H, Dv]` in v's dtype."""
+def _fold(x):
+    """[B, S, H, D] -> [B*H, S, D]: heads beside the batch, in front: the
+    layout the chip's compiler makes plain batched matmuls of."""
+    b, seq, heads, d = x.shape
+    return jnp.swapaxes(x, 1, 2).reshape(b * heads, seq, d)
+
+
+def _unfold(x, b):
+    g, seq, d = x.shape
+    return jnp.swapaxes(x.reshape(b, g // b, seq, d), 1, 2)
+
+
+def xla_document_attention(q, k, v, segment_ids, *, scale: float,
+                           block: int) -> jax.Array:
+    """`document_attention`'s XLA path (module docstring)."""
     b, seq, heads, _ = q.shape
     block = min(block, seq)
     if seq % block:
         raise ValueError(f"{seq} positions are not whole blocks of {block}")
-    # heads beside the batch, in front: the layout the chip's compiler
-    # makes plain batched matmuls of
-    fold = lambda x: jnp.swapaxes(x, 1, 2).reshape(b * heads, seq, -1)
-    q, k, v = fold(q), fold(k), fold(v)
+    q, k, v = _fold(q), _fold(k), _fold(v)
     seg = jnp.repeat(segment_ids, heads, axis=0)
     run = jax.checkpoint(_block, static_argnums=(5, 6))
     out = []
@@ -68,5 +107,418 @@ def document_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         end = first + block
         out.append(run(q[:, first:end], k[:, :end], v[:, :end],
                        seg[:, first:end], seg[:, :end], first, scale))
-    out = jnp.concatenate(out, axis=1).reshape(b, heads, seq, -1)
-    return jnp.swapaxes(out, 1, 2)
+    return _unfold(jnp.concatenate(out, axis=1), b)
+
+
+# ---- which key blocks a query block needs ----------------------------------
+
+
+def kernel_blocks(seq: int, d_qk: int, d_v: int) -> Optional[Tuple[int, int]]:
+    """(bq, bk) if the kernel takes these shapes: whole blocks, head
+    widths a multiple of 64. None otherwise."""
+    bq, bk = min(_BLOCK_Q, seq), min(_BLOCK_K, seq)
+    if (seq % bq or seq % bk or bq % _LANES or bk % _LANES
+            or d_qk % 64 or d_v % 64):
+        return None
+    return bq, bk
+
+
+class BlockTable(NamedTuple):
+    """int32, a packed row's blocks: for query block `i` the key blocks
+    `first_kv[b, i] .. (i*bq + bq - 1) // bk` hold every key its rows
+    attend to, and of them `full_kv[b, i] ..` up to the last block that
+    ends at or before the query block's first row need no mask. For key
+    block `j` the query blocks `j*bk // bq .. last_q[b, j]`, no mask from
+    the first block that starts at or after the key block's end up to
+    `full_q[b, j]`."""
+    first_kv: jax.Array  # [B, S // bq]
+    full_kv: jax.Array   # [B, S // bq]
+    last_q: jax.Array    # [B, S // bk]
+    full_q: jax.Array    # [B, S // bk]
+
+
+def block_table(segment_ids: jax.Array, bq: int, bk: int) -> BlockTable:
+    """From `segment_ids` `[B, S]`, whose documents are contiguous (a
+    document is a run of one id: data/tokens.py packs them so)."""
+    seq = segment_ids.shape[1]
+    at = jnp.arange(seq, dtype=jnp.int32)
+    differs = segment_ids[:, 1:] != segment_ids[:, :-1]
+    edge = jnp.ones_like(segment_ids[:, :1], bool)
+    # a position's document runs from `start` to `end`, both inside it
+    start = jax.lax.cummax(
+        jnp.where(jnp.concatenate([edge, differs], 1), at, 0), axis=1)
+    end = jax.lax.cummin(
+        jnp.where(jnp.concatenate([differs, edge], 1), at, seq - 1),
+        axis=1, reverse=True)
+    return BlockTable(
+        first_kv=start[:, ::bq] // bk,
+        full_kv=-(-start[:, bq - 1::bq] // bk),
+        last_q=end[:, bk - 1::bk] // bq,
+        full_q=(end[:, ::bk] + 1) // bq - 1)
+
+
+def _diagonal(i, bq: int, bk: int):
+    """The last key block that query block `i` reaches."""
+    return (i * bq + bq - 1) // bk
+
+
+def block_pair_counts(segment_ids: jax.Array, bq: int, bk: int
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """(visited, causal), summed over the rows: the (query block, key
+    block) pairs the kernel's grid computes, and those of the causal
+    triangle."""
+    first = block_table(segment_ids, bq, bk).first_kv
+    diag = _diagonal(jnp.arange(first.shape[1], dtype=jnp.int32), bq, bk)
+    return (jnp.sum(diag[None] - first + 1),
+            jnp.sum(diag + 1) * first.shape[0])
+
+
+# ---- the kernels -----------------------------------------------------------
+#
+# A grid step holds one tile's blocks for `hb` heads of one row: the heads
+# share the row's table and its mask, and a step's fixed cost (0.24 us,
+# beside 1.7 us of work a head and 512 x 512 tile; my chip run, PR 27) is
+# paid once for them. Refs are `[hb, rows, width]`; a loop walks the heads.
+
+
+def _mask(seg_col, seg_row, a_first, b_first, shape, a_is_query):
+    """`[A, B]` bool: the pair is of one document and the key does not
+    come after the query. seg_col `[A, 128]` (the ids of the tile's rows
+    down the sublanes, the same in every lane), seg_row `[1, B]`;
+    a_first, b_first: the tile's first positions."""
+    a_at = a_first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    b_at = b_first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    causal = a_at >= b_at if a_is_query else a_at <= b_at
+    return causal & (_wide(seg_col, shape[1]) == seg_row)
+
+
+def _scores(a, b, mask, scale):
+    """fp32 `a b^T * scale` of `a` `[A, D]` and `b` `[B, D]`, `_MASKED`
+    outside `mask` if there is one."""
+    s = jax.lax.dot_general(a, b, _NT,
+                            preferred_element_type=jnp.float32) * scale
+    return s if mask is None else jnp.where(mask, s, _MASKED)
+
+
+def _wide(col, n: int):
+    """A `[..., rows, 128]` column, the same in every lane, as
+    `[..., rows, n]`."""
+    if n == _LANES:
+        return col
+    reps = (1,) * (col.ndim - 1) + (-(-n // _LANES),)
+    return jnp.tile(col, reps)[..., :n]
+
+
+def _each_head(hb: int, tile, visited, full, make_mask):
+    """`tile(h, mask)` for the step's heads, under `pl.when`: without a
+    mask where the tile is `full`, with the row's on the range's other
+    tiles."""
+    def heads(mask):
+        jax.lax.fori_loop(0, hb, lambda h, _: tile(h, mask), None)
+
+    pl.when(visited & full)(lambda: heads(None))
+    pl.when(visited & jnp.logical_not(full))(lambda: heads(make_mask()))
+
+
+def _key_blocks_of(first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq,
+                   bk):
+    """`_each_head`'s (visited, full, make_mask) for the kernels whose
+    grid is (g, query block i, key block j)."""
+    g, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    at = (g // steps_a_row) * pl.num_programs(1) + i
+    return ((j >= first_ref[at]) & (j <= _diagonal(i, bq, bk)),
+            (j >= full_ref[at]) & (j * bk + bk - 1 <= i * bq),
+            lambda: _mask(segq_ref[...], segk_ref[...], i * bq, j * bk,
+                          (bq, bk), True))
+
+
+def _fwd_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref,
+                o_ref, lse_ref, m_ref, l_ref, acc_ref, *, steps_a_row, scale,
+                bq, bk):
+    j = pl.program_id(2)
+    d_v = acc_ref.shape[-1]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(h, mask):
+        s = _scores(q_ref[h], k_ref[h], mask, scale)
+        m_prev = m_ref[h]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row whose keys in this tile are all masked adds exp(0) = 1 a
+        # key here; the diagonal tile, visited last, has a key of its
+        # own, a maximum above _MASKED, and alpha = 0 wipes what was added
+        p = jnp.exp(s - _wide(m_next, bk))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[h] = m_next
+        acc_ref[h] = _wide(alpha, d_v) * acc_ref[h] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[h],
+            preferred_element_type=jnp.float32)
+
+    _each_head(q_ref.shape[0], tile, *_key_blocks_of(
+        first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq, bk))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / _wide(l, d_v)).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
+
+
+def _dq_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+               delta_ref, segq_ref, segk_ref, dq_ref, acc_ref, *,
+               steps_a_row, scale, bq, bk):
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(h, mask):
+        k = k_ref[h]
+        p = jnp.exp(_scores(q_ref[h], k, mask, scale)
+                    - _wide(lse_ref[h], bk))
+        dp = jax.lax.dot_general(do_ref[h], v_ref[h], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - _wide(delta_ref[h], bk))
+        acc_ref[h] += jnp.dot(ds.astype(k.dtype), k,
+                              preferred_element_type=jnp.float32)
+
+    _each_head(q_ref.shape[0], tile, *_key_blocks_of(
+        first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq, bk))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(last_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, segq_ref, segk_ref, dk_ref, dv_ref, dk_acc,
+                dv_acc, *, steps_a_row, scale, bq, bk):
+    """The transposed tile: keys down the sublanes, queries along the
+    lanes, so the row statistics are rows and no matmul transposes."""
+    g, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    at = (g // steps_a_row) * pl.num_programs(1) + j
+
+    @pl.when(i == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def tile(h, mask):
+        q, do = q_ref[h], do_ref[h]
+        p = jnp.exp(_scores(k_ref[h], q, mask, scale) - lse_ref[h])
+        dv_acc[h] += jnp.dot(p.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[h], do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[h])
+        dk_acc[h] += jnp.dot(ds.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32)
+
+    _each_head(q_ref.shape[0], tile,
+               (i >= (j * bk) // bq) & (i <= last_ref[at]),
+               (i * bq >= j * bk + bk - 1) & (i <= full_ref[at]),
+               lambda: _mask(segk_ref[...], segq_ref[...], j * bk, i * bq,
+                             (bk, bq), False))
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# ---- their calls -----------------------------------------------------------
+
+_HEADS_A_STEP = 4  # at most; VMEM holds their blocks twice over
+# of a v5e's 128 MiB: the backward's blocks for 4 heads pass the 16 MiB a
+# kernel is given unasked
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+class _Static(NamedTuple):
+    heads: int  # of a row: q, k, v are [rows x heads, S, D]
+    scale: float
+    bq: int
+    bk: int
+    interpret: bool
+
+    @property
+    def hb(self) -> int:
+        """Heads a grid step holds: the largest divisor of `heads` up to
+        `_HEADS_A_STEP`."""
+        return max(n for n in range(1, _HEADS_A_STEP + 1)
+                   if self.heads % n == 0)
+
+
+def _call(kernel, st: _Static, name, grid, prefetch, in_specs, inputs,
+          out_specs, out_shape, scratch):
+    return pl.pallas_call(
+        functools.partial(kernel, steps_a_row=st.heads // st.hb,
+                          scale=st.scale, bq=st.bq, bk=st.bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=st.interpret,
+        name=name,  # in the compiled HLO and the device trace
+    )(*prefetch, *inputs)
+
+
+def _query_major_specs(st: _Static, nq, d_qk, d_v):
+    """Block specs of the kernels whose grid is (g, query block i, key
+    block j), g over rows x groups of `hb` heads: q-side blocks follow
+    `i`, key-side blocks follow `j` clamped into the range the table
+    gives."""
+    hb, bq, bk = st.hb, st.bq, st.bk
+    steps_a_row = st.heads // hb
+
+    def kv(g, i, j, first_ref, full_ref):
+        return jnp.clip(j, first_ref[(g // steps_a_row) * nq + i],
+                        _diagonal(i, bq, bk))
+
+    spec = pl.BlockSpec
+    return dict(
+        q=spec((hb, bq, d_qk), lambda g, i, j, *_: (g, i, 0)),
+        k=spec((hb, bk, d_qk), lambda g, i, j, *t: (g, kv(g, i, j, *t), 0)),
+        v=spec((hb, bk, d_v), lambda g, i, j, *t: (g, kv(g, i, j, *t), 0)),
+        o=spec((hb, bq, d_v), lambda g, i, j, *_: (g, i, 0)),
+        stat=spec((hb, bq, _LANES), lambda g, i, j, *_: (g, i, 0)),
+        seg_q=spec((None, bq, _LANES),
+                   lambda g, i, j, *_: (g // steps_a_row, i, 0)),
+        seg_k=spec((None, 1, bk),
+                   lambda g, i, j, *t: (g // steps_a_row, 0,
+                                        kv(g, i, j, *t))))
+
+
+def _lanes(x):
+    """[..., S] -> [..., S, 128]: a column the kernel reads down the
+    sublanes, the same in every lane."""
+    return jnp.broadcast_to(x[..., None], x.shape + (_LANES,))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _forward(st: _Static, q, k, v, segment_ids, table: BlockTable):
+    g, seq, d_qk = q.shape
+    d_v = v.shape[-1]
+    hb = st.hb
+    nq, nk = seq // st.bq, seq // st.bk
+    sp = _query_major_specs(st, nq, d_qk, d_v)
+    o, lse = _call(
+        _fwd_kernel, st, "lm_attention_fwd", (g // hb, nq, nk),
+        (table.first_kv.reshape(-1), table.full_kv.reshape(-1)),
+        [sp["q"], sp["k"], sp["v"], sp["seg_q"], sp["seg_k"]],
+        (q, k, v, _lanes(segment_ids), segment_ids[:, None, :]),
+        [sp["o"], sp["stat"]],
+        [jax.ShapeDtypeStruct((g, seq, d_v), v.dtype),
+         jax.ShapeDtypeStruct((g, seq, _LANES), jnp.float32)],
+        [pltpu.VMEM((hb, st.bq, _LANES), jnp.float32),
+         pltpu.VMEM((hb, st.bq, _LANES), jnp.float32),
+         pltpu.VMEM((hb, st.bq, d_v), jnp.float32)])
+    return o, lse[..., 0]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _backward(st: _Static, q, k, v, segment_ids, table: BlockTable, o, lse,
+              do):
+    g, seq, d_qk = q.shape
+    d_v = v.shape[-1]
+    hb, bq, bk = st.hb, st.bq, st.bk
+    steps_a_row = st.heads // hb
+    nq, nk = seq // bq, seq // bk
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    seg_col, seg_row = _lanes(segment_ids), segment_ids[:, None, :]
+
+    sp = _query_major_specs(st, nq, d_qk, d_v)
+    dq = _call(
+        _dq_kernel, st, "lm_attention_dq", (g // hb, nq, nk),
+        (table.first_kv.reshape(-1), table.full_kv.reshape(-1)),
+        [sp["q"], sp["k"], sp["v"], sp["o"], sp["stat"], sp["stat"],
+         sp["seg_q"], sp["seg_k"]],
+        (q, k, v, do, _lanes(lse), _lanes(delta), seg_col, seg_row),
+        sp["q"], jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((hb, bq, d_qk), jnp.float32)])
+
+    def qi(g_, j, i, last_ref, full_ref):
+        return jnp.clip(i, (j * bk) // bq,
+                        last_ref[(g_ // steps_a_row) * nk + j])
+
+    spec = pl.BlockSpec
+    key_side = lambda d: spec((hb, bk, d), lambda g_, j, i, *_: (g_, j, 0))
+    query_side = lambda d: spec(
+        (hb, bq, d), lambda g_, j, i, *t: (g_, qi(g_, j, i, *t), 0))
+    row = lambda: spec((hb, 1, bq),
+                       lambda g_, j, i, *t: (g_, 0, qi(g_, j, i, *t)))
+    dk, dv = _call(
+        _dkv_kernel, st, "lm_attention_dkv", (g // hb, nk, nq),
+        (table.last_q.reshape(-1), table.full_q.reshape(-1)),
+        [query_side(d_qk), key_side(d_qk), key_side(d_v), query_side(d_v),
+         row(), row(),
+         spec((None, 1, bq),
+              lambda g_, j, i, *t: (g_ // steps_a_row, 0, qi(g_, j, i, *t))),
+         spec((None, bk, _LANES),
+              lambda g_, j, i, *_: (g_ // steps_a_row, j, 0))],
+        (q, k, v, do, lse[:, None, :], delta[:, None, :], seg_row, seg_col),
+        [key_side(d_qk), key_side(d_v)],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((hb, bk, d_qk), jnp.float32),
+         pltpu.VMEM((hb, bk, d_v), jnp.float32)])
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash(st: _Static, q, k, v, segment_ids, table: BlockTable):
+    return _forward(st, q, k, v, segment_ids, table)[0]
+
+
+def _flash_fwd(st, q, k, v, segment_ids, table):
+    o, lse = _forward(st, q, k, v, segment_ids, table)
+    return o, (q, k, v, segment_ids, table, o, lse)
+
+
+def _flash_bwd(st, res, do):
+    q, k, v, segment_ids, table, o, lse = res
+    dq, dk, dv = _backward(st, q, k, v, segment_ids, table, o, lse, do)
+    return dq, dk, dv, None, None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_document_attention(q, k, v, segment_ids, *, scale: float,
+                             interpret: bool = False) -> jax.Array:
+    """`document_attention`'s kernel path (module docstring), for shapes
+    `kernel_blocks` takes. `interpret=True` runs the kernels in Pallas's
+    interpreter: the tests' way to them without a chip."""
+    b, seq, heads, d_qk = q.shape
+    blocks = kernel_blocks(seq, d_qk, v.shape[-1])
+    if blocks is None:
+        raise ValueError(f"no kernel for {seq} positions of widths {d_qk} "
+                         f"and {v.shape[-1]}")
+    st = _Static(heads, float(scale), *blocks, interpret)
+    out = _flash(st, _fold(q), _fold(k), _fold(v), segment_ids,
+                 block_table(segment_ids, *blocks))
+    return _unfold(out, b)
+
+
+def document_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                       segment_ids: jax.Array, *, scale: float,
+                       block: int) -> jax.Array:
+    """softmax(q k^T * scale) v over the earlier tokens of the same
+    document. q, k `[B, S, H, D]`, v `[B, S, H, Dv]`, segment_ids
+    `[B, S]` (pad positions share id 0 and see each other: their output
+    is never read). Returns `[B, S, H, Dv]` in v's dtype. `block` is the
+    XLA path's block of query rows; the kernel's blocks are its own."""
+    if (jax.default_backend() == "tpu"
+            and kernel_blocks(q.shape[1], q.shape[-1], v.shape[-1])):
+        return flash_document_attention(q, k, v, segment_ids, scale=scale)
+    return xla_document_attention(q, k, v, segment_ids, scale=scale,
+                                  block=block)

@@ -136,3 +136,40 @@ def test_per_pixel_family_refuses_a_tpu_backend(monkeypatch):
     monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
     with pytest.raises(RuntimeError, match="DEXIRAFT_PALLAS_INTERPRET"):
         pc._interpret_default()
+
+
+# ---- the language model's attention kernel (ops/lm_attention.py) ----------
+
+LM_G, LM_HEADS, LM_S, LM_DQK, LM_DV = 16, 4, 8192, 192, 128
+
+
+@pytest.mark.parametrize("which", ["forward", "dq", "dkv"])
+def test_lm_attention_kernel_compiles_for_v5e(chip, which):
+    """The three kernels of the packed-document attention alone, at
+    `kanana2-train-pack8k`'s shapes: 4 rows x 4 heads, 8192 positions,
+    widths 192 / 128, bf16. The backward's two calls are one function, so
+    the `dq` and `dkv` cases each find their own kernel in its text."""
+    from dexiraft_tpu.ops import lm_attention as la
+
+    bq, bk = la.kernel_blocks(LM_S, LM_DQK, LM_DV)
+    st = la._Static(LM_HEADS, LM_DQK ** -0.5, bq, bk, False)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    q, k = sds((LM_G, LM_S, LM_DQK)), sds((LM_G, LM_S, LM_DQK))
+    v = sds((LM_G, LM_S, LM_DV))
+    seg = sds((LM_G // LM_HEADS, LM_S), jnp.int32)
+    table = lambda s: la.block_table(s, bq, bk)  # noqa: E731
+    if which == "forward":
+        text = _compiled_text(
+            lambda q, k, v, s: la._forward(st, q, k, v, s, table(s)),
+            q, k, v, seg)
+    else:
+        text = _compiled_text(
+            lambda q, k, v, s, o, lse, do: la._backward(
+                st, q, k, v, s, table(s), o, lse, do),
+            q, k, v, seg, v, sds((LM_G, LM_S), jnp.float32), v)
+    name = {"forward": "lm_attention_fwd", "dq": "lm_attention_dq",
+            "dkv": "lm_attention_dkv"}[which]
+    assert "tpu_custom_call" in text and name in text

@@ -1,0 +1,254 @@
+"""The packed-document attention's Pallas kernels (ops/lm_attention.py) in
+Pallas's interpreter on the CPU: against the XLA path of the same file,
+which the CPU tests of the model run, and against the plain reference's
+attention (interop/lm_reference.py); forward and the gradients of `q`,
+`k`, `v`. And the block table alone, against a count of the pairs.
+
+Sizes: 512 positions in blocks of 128 (the module's constants are the
+chip's, 512; the tests set them), so a row has 4 x 4 block pairs, at the
+cell's head widths, 192 and 128. Interpret-mode parity says nothing
+about Mosaic: tests/test_chip_compile.py compiles the same kernels at
+the cell's shapes.
+
+Tolerances, relative in the 2-norm. fp32 inputs: both paths are float32
+arithmetic of one mathematics in another order (a running maximum
+against a row's, one division at the end against one a key), on the
+CPU's exact fp32 matmuls: 1e-5 is 25x the 2e-7 to 4e-7 seen. bf16
+inputs: both round the probabilities to 8 bits for the second matmul,
+the XLA path after the division, the kernel before it, and the XLA
+path's backward rounds dP where the kernel keeps fp32: 2e-2 is 5x the
+2.9e-3 to 3.9e-3 seen, and a dropped block or a wrong mask is off by 0.1
+to 1.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dexiraft_tpu.ops import lm_attention as la
+
+S, HEADS, D_QK, D_V = 512, 2, 192, 128
+TOL = {"fp32": 1e-5, "bf16": 2e-2}
+
+
+def _segments(*lengths):
+    """One row: documents of these lengths, ids 1, 2, ...; pad (id 0)
+    to S."""
+    seg = np.zeros(S, np.int32)
+    at = 0
+    for i, n in enumerate(lengths, start=1):
+        seg[at:at + n] = i
+        at += n
+    assert at <= S
+    return seg
+
+
+LAYOUTS = {
+    # a row that is one document: the whole triangle, no tile skipped
+    "one_document": [_segments(S)],
+    # documents that start and end inside a block ([0, 40), [40, 100)),
+    # across block edges ([100, 300)), on an edge ([300, 384)), and a
+    # short padded tail
+    "inside_and_across": [_segments(40, 60, 200, 84, 86)],
+    # pad longer than a block: whole blocks of id 0, which see each other
+    "padded_tail": [_segments(200)],
+    # documents that are whole blocks, and two rows with their own tables
+    "block_aligned_two_rows": [_segments(128, 256, 128),
+                               _segments(300, 150)],
+}
+
+
+@pytest.fixture
+def blocks(monkeypatch, request):
+    bq, bk = getattr(request, "param", (128, 128))
+    monkeypatch.setattr(la, "_BLOCK_Q", bq)
+    monkeypatch.setattr(la, "_BLOCK_K", bk)
+    return bq, bk
+
+
+def _inputs(rows, dtype, d_qk=D_QK, d_v=D_V, seed=0):
+    rng = np.random.default_rng(seed)
+    arr = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.normal(size=shape), jnp.float32).astype(dtype)
+    return (arr(rows, S, HEADS, d_qk), arr(rows, S, HEADS, d_qk),
+            arr(rows, S, HEADS, d_v),
+            jnp.asarray(rng.normal(size=(rows, S, HEADS, d_v)), jnp.float32))
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _out_and_grads(fn, q, k, v, w):
+    out = fn(q, k, v)
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                     argnums=(0, 1, 2))(q, k, v)
+    return (out,) + grads
+
+
+def _assert_kernel_matches_the_xla_path(layout, dtype):
+    seg = jnp.asarray(np.stack(LAYOUTS[layout]))
+    q, k, v, w = _inputs(seg.shape[0],
+                         jnp.float32 if dtype == "fp32" else jnp.bfloat16)
+    scale = D_QK ** -0.5
+    got = _out_and_grads(lambda *a: la.flash_document_attention(
+        *a, seg, scale=scale, interpret=True), q, k, v, w)
+    want = _out_and_grads(lambda *a: la.xla_document_attention(
+        *a, seg, scale=scale, block=128), q, k, v, w)
+    # pad rows' outputs are never read, but both paths define them alike
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert _rel(a, b) < TOL[dtype], (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_kernel_matches_the_xla_path(blocks, layout, dtype):
+    _assert_kernel_matches_the_xla_path(layout, dtype)
+
+
+@pytest.mark.parametrize("blocks", [(256, 128), (128, 256)], indirect=True)
+def test_kernel_with_unequal_blocks(blocks):
+    """Query and key blocks of different sizes: the diagonal and both
+    ranges are computed in positions, not in block indices."""
+    _assert_kernel_matches_the_xla_path("inside_and_across", "fp32")
+
+
+def test_latent_attention_on_the_kernel_matches_the_reference(
+        blocks, monkeypatch):
+    """The module that calls the kernel, heads and rotary embedding
+    included, against the plain reference's attention: the output and
+    the gradients of the input and of every weight, fp32."""
+    import dexiraft_tpu.models.lm.attention as attention
+    from dexiraft_tpu.config import kanana2_toy
+    from dexiraft_tpu.interop import lm_reference as ref
+
+    monkeypatch.setattr(
+        attention, "document_attention",
+        lambda q, k, v, seg, *, scale, block: la.flash_document_attention(
+            q, k, v, seg, scale=scale, interpret=True))
+    cfg = kanana2_toy(qk_nope_head_dim=128, qk_rope_head_dim=64,
+                      v_head_dim=128, seq_len=S, attn_block=128,
+                      num_attention_heads=HEADS)
+    seg_row = LAYOUTS["inside_and_across"][0]
+    seg = jnp.asarray(seg_row[None])
+    starts = np.maximum.accumulate(np.where(
+        np.r_[True, seg_row[1:] != seg_row[:-1]], np.arange(S), 0))
+    pos = jnp.asarray((np.arange(S) - starts)[None], jnp.int32)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(1, S, cfg.hidden_size)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    module = attention.LatentAttention(cfg=cfg, dtype=jnp.float32,
+                                       init_std=0.2)
+    params = module.init(jax.random.PRNGKey(0), x, pos, seg)["params"]
+
+    def ours(p, x):
+        return jnp.sum(module.apply({"params": p}, x, pos, seg) * w)
+
+    def plain(p, x):
+        with jax.default_matmul_precision("highest"):
+            return jnp.sum(ref.attention(p, x[0], pos[0], seg[0], cfg,
+                                         HEADS) * w[0])
+
+    got = jax.value_and_grad(ours, argnums=(0, 1))(params, x)
+    want = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
+    assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got[1])[0],
+                            jax.tree.leaves(want[1])):
+        assert _rel(a, b) < 1e-4, (jax.tree_util.keystr(path), _rel(a, b))
+
+
+# ---- the table alone -------------------------------------------------------
+
+
+def _allowed(seg_row):
+    """[S, S] bool: query r may attend to key c."""
+    t = np.arange(S)
+    return (t[:, None] >= t[None, :]) & (seg_row[:, None] == seg_row[None, :])
+
+
+def _by_blocks(allowed, bq, bk, how):
+    """[S // bq, S // bk]: `how` (any, all) over each block pair."""
+    return how(allowed.reshape(S // bq, bq, S // bk, bk), axis=(1, 3))
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256),
+                                   (64, 64)])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_the_table_visits_exactly_the_blocks_that_hold_a_pair(layout, bq, bk):
+    seg = np.stack(LAYOUTS[layout])
+    table = jax.tree.map(np.asarray, la.block_table(jnp.asarray(seg), bq, bk))
+    nq, nk = S // bq, S // bk
+    i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    diag = (i * bq + bq - 1) // bk
+    below = j * bk + bk - 1 <= i * bq  # every key at or before every query
+    visited_total = 0
+    for b, row in enumerate(seg):
+        allowed = _allowed(row)
+        holds_a_pair = _by_blocks(allowed, bq, bk, np.any)
+        all_pairs = _by_blocks(allowed, bq, bk, np.all)
+        # the forward's and dq's range: key blocks of a query block
+        by_query = (j >= table.first_kv[b][:, None]) & (j <= diag)
+        np.testing.assert_array_equal(by_query, holds_a_pair)
+        # dk/dv's range, the transpose: query blocks of a key block
+        by_key = (i >= (j * bk) // bq) & (i <= table.last_q[b][None, :])
+        np.testing.assert_array_equal(by_key, holds_a_pair)
+        # the tiles that skip the mask are those with no masked pair
+        np.testing.assert_array_equal(
+            (j >= table.full_kv[b][:, None]) & below, all_pairs)
+        np.testing.assert_array_equal(
+            (i <= table.full_q[b][None, :]) & below, all_pairs)
+        visited_total += int(holds_a_pair.sum())
+    visited, causal = la.block_pair_counts(jnp.asarray(seg), bq, bk)
+    assert int(visited) == visited_total
+    assert int(causal) == len(seg) * int((diag + 1).sum())
+    if layout == "one_document":
+        assert int(visited) == int(causal)
+    elif bq == bk:  # every other layout leaves a block pair out
+        assert int(visited) < int(causal)
+
+
+def test_the_step_carries_the_counters():
+    """The model's metrics hold both counters, from the kernel's table
+    where the kernel takes the shapes and one block a row where it does
+    not (the toy widths)."""
+    from dexiraft_tpu.config import kanana2_toy
+    from dexiraft_tpu.models.lm.model import COUNTERS, _attention_counters
+
+    assert {"attn_block_pairs_visited",
+            "attn_block_pairs_causal"} <= set(COUNTERS)
+    seg = jnp.asarray(np.stack(LAYOUTS["block_aligned_two_rows"]))
+    toy = _attention_counters(kanana2_toy(seq_len=S), seg)
+    assert {k: int(v) for k, v in toy.items()} == {
+        "attn_block_pairs_visited": 2, "attn_block_pairs_causal": 2}
+    wide = _attention_counters(
+        kanana2_toy(seq_len=S, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                    v_head_dim=128), seg)
+    # S = 512 is one block of the chip's 512
+    assert int(wide["attn_block_pairs_causal"]) == 2
+
+
+@pytest.mark.parametrize("seq,d_qk,d_v,want", [
+    (8192, 192, 128, (512, 512)),   # the cell
+    (512, 192, 128, (512, 512)),
+    (256, 128, 128, (256, 256)),    # a short row is one block
+    (8192 + 512, 192, 128, (512, 512)),
+    (8192 + 128, 192, 128, None),   # not whole blocks
+    (128, 12, 8, None),             # the toy widths
+    (8, 192, 128, None),            # `init`'s dummy row
+    (192, 192, 128, None),          # not a multiple of the lane width
+])
+def test_kernel_blocks_takes_whole_lane_aligned_shapes(seq, d_qk, d_v, want):
+    assert la.kernel_blocks(seq, d_qk, d_v) == want
+
+
+def test_the_cpu_takes_the_xla_path():
+    """Selection is by the backend: here the XLA path, whatever the
+    shapes, so the tier-1 numbers of the model's tests are today's."""
+    seg = jnp.asarray(np.stack(LAYOUTS["padded_tail"]))
+    q, k, v, _ = _inputs(1, jnp.float32)
+    text = jax.jit(lambda q, k, v: la.document_attention(
+        q, k, v, seg, scale=1.0, block=128)).lower(q, k, v).as_text()
+    assert "pallas" not in text and "custom_call" not in text
