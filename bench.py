@@ -411,13 +411,11 @@ def main() -> None:
     # volume, the memory-efficient on-demand path (the alt_cuda_corr
     # analog the north-star metric names, BASELINE.json) and the
     # flash-blocked Pallas kernel; the fastest is the headline. The
-    # per-pixel Pallas kernels have no legs: they do not compile for the
-    # chip (config.PALLAS_TPU_REFUSAL). The DexiNed upconv A/B
-    # (transposed conv vs the identical-map subpixel phase form) is kept
-    # on both non-Pallas corr paths as a diagnostic; the upconv choice
-    # only changes the prelude, so the transpose variants skip the
-    # marginal-loop (1-iter) re-measurement and inherit the loop rate of
-    # their subpixel sibling on the same corr path.
+    # DexiNed upconv A/B (transposed conv vs the identical-map subpixel
+    # phase form) is kept on both non-Pallas corr paths as a diagnostic;
+    # the upconv choice only changes the prelude, so the transpose
+    # variants skip the marginal-loop (1-iter) re-measurement and inherit
+    # the loop rate of their subpixel sibling on the same corr path.
     allpairs_ips, allpairs_loop, ap_diag = measure("allpairs", "subpixel")
     diag = {f"allpairs_{k}": v for k, v in ap_diag.items()}
     # candidate = (corr_impl, upconv, corr_dtype, fused, ips, loop_ips)

@@ -403,7 +403,7 @@ def phase_eval(ctx: Context) -> dict:
                      eval_argv(ctx, "auto", ctx.path("flow_auto")),
                      dict(ctx.env, JAX_DUMP_IR_TO=ir_dir))
     info = banner(text, "eval", ctx)
-    if info["corr_impl"] in ("flash", "pallas"):
+    if info["corr_impl"] == "flash":
         # the lowered eval step must hold the kernel: no interpreter,
         # no reference standing in for it
         held = [p for p in glob.glob(osp.join(ir_dir, "*jit_step*"))

@@ -18,23 +18,12 @@ from typing import Optional, Tuple
 CORR_DTYPES = ("fp32", "bf16", "int8")
 
 # correlation implementations: the materialized MXU volume, the XLA
-# on-demand path, the per-pixel Pallas kernel, and the flash-blocked
-# Pallas kernel (fmap2 streamed from HBM in row blocks — O(fmaps)
-# memory at any geometry; ops/pallas_corr.py). Jax-free for the same
-# CLI-parser reason as CORR_DTYPES.
-CORR_IMPLS = ("allpairs", "local", "pallas", "flash")
-
-
-# Why corr_impl="pallas" (the per-pixel kernels, fused or not) is not
-# supported on the chip: Mosaic's own message for the (k, k, C) window
-# load at the v5 440x1024 shapes. ops/pallas_corr.py raises this on a
-# TPU backend; tests/test_chip_compile.py pins that the compiler's
-# refusal still stands, and a repair re-admits the family there first.
-PALLAS_TPU_REFUSAL = (
-    "corr_impl='pallas' does not compile for TPU v5e under the installed "
-    "JAX (Mosaic: 'cannot statically prove that index in dimension 2 is "
-    "a multiple of 8' on the per-pixel window load); not supported on "
-    "the chip — use 'flash' or 'allpairs'")
+# on-demand path, and the flash-blocked Pallas kernel (fmap2 streamed
+# from HBM in row blocks — O(fmaps) memory at any geometry;
+# ops/pallas_corr.py). The one list every front end's --corr_impl
+# choices are built from. Jax-free for the same CLI-parser reason as
+# CORR_DTYPES.
+CORR_IMPLS = ("allpairs", "local", "flash")
 
 
 def resolve_corr_impl(impl: str, platform: str) -> Tuple[str, bool]:
@@ -62,10 +51,10 @@ def resolve_corr_impl_args(args, platform: str, label: str) -> Tuple[str, bool]:
     "auto" resolved to. ONE copy so the two CLIs cannot drift."""
     impl, fused_auto = resolve_corr_impl(args.corr_impl, platform)
     fused = args.fused_update or fused_auto
-    if fused and impl not in ("pallas", "flash"):
+    if fused and impl != "flash":
         raise SystemExit(f"{label}: --fused_update requires --corr_impl "
-                         "flash or pallas (pass one explicitly — 'auto' "
-                         "resolves to allpairs off-TPU)")
+                         "flash (pass it explicitly — 'auto' resolves to "
+                         "allpairs off-TPU)")
     if args.corr_impl == "auto":
         print(f"[{label}] corr_impl auto -> {impl}"
               f"{' + fused_update' if fused else ''}", flush=True)
@@ -93,12 +82,12 @@ class RAFTConfig:
     corr_radius: Optional[int] = None  # None -> 4 full / 3 small (core/raft.py:37-47)
     dropout: float = 0.0
     mixed_precision: bool = False  # bf16 compute in encoders/update; corr stays fp32
-    # allpairs = materialized MXU volume; local/pallas/flash = on-demand
+    # allpairs = materialized MXU volume; local/flash = on-demand
     # paths (flash is the blocked HBM-streaming kernel — the production
     # eval/serve default on TPU via resolve_corr_impl("auto", ...))
     corr_impl: str = "allpairs"
     # STORAGE precision of the correlation pyramid (allpairs: the
-    # materialized volume levels; local/pallas: the fmap2 pyramid the
+    # materialized volume levels; local/flash: the fmap2 pyramid the
     # lookup streams) — "fp32" | "bf16" | "int8" (per-level scale,
     # dequantized inside the consuming matmul/kernel, ops/quant.py).
     # Correlation math stays fp32-accumulated on every path; this knob
@@ -107,11 +96,10 @@ class RAFTConfig:
     corr_dtype: str = "fp32"
     # fuse each refinement iteration's 4-level window lookup WITH the
     # motion encoder's 1x1 corr conv into ONE Pallas kernel
-    # (ops/pallas_corr.pallas_fused_step / flash_fused_step): the
+    # (ops/pallas_corr.flash_fused_step): the
     # (2r+1)^2-per-level corr features never round-trip HBM — only the
-    # conv's F-channel output does. Requires corr_impl="pallas" or
-    # "flash" (the VMEM-kernel formulations); parameter tree is
-    # IDENTICAL to the unfused path, so checkpoints interchange
+    # conv's F-channel output does. Requires corr_impl="flash";
+    # parameter tree is IDENTICAL to the unfused path, so checkpoints interchange
     # (models/update.py FusedCorrEncoder)
     fused_update: bool = False
     # rows per chunk for the local path's gather (bounds the transient
@@ -173,12 +161,11 @@ class RAFTConfig:
             raise ValueError(
                 f"unknown corr_dtype {self.corr_dtype!r}; expected one "
                 f"of {CORR_DTYPES}")
-        if self.fused_update and self.corr_impl not in ("pallas", "flash"):
+        if self.fused_update and self.corr_impl != "flash":
             raise ValueError(
                 "fused_update=True requires corr_impl='flash' (the "
-                "blocked HBM-streaming kernel — the production default) "
-                "or 'pallas' (the per-pixel VMEM formulation); the "
-                "allpairs volume cannot be tiled per pixel block")
+                "blocked HBM-streaming kernel — the production default); "
+                "the allpairs volume cannot be tiled per pixel block")
         if self.remat_policy not in ("full", "dots_saveable"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; expected "

@@ -12,6 +12,7 @@ import sys
 
 import jax
 
+from dexiraft_tpu.config import CORR_IMPLS
 from dexiraft_tpu.train_cli import VARIANTS, _VAL_ITERS
 
 
@@ -33,8 +34,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="auto",
-                   choices=["auto", "allpairs", "local", "pallas", "flash"],
-                   help="'local'/'pallas'/'flash' = the memory-efficient "
+                   choices=["auto", *CORR_IMPLS],
+                   help="'local'/'flash' = the memory-efficient "
                         "on-demand paths (the reference's "
                         "--alternate_corr); 'auto' (default) = the "
                         "production config: flash-blocked fused step on "
@@ -49,7 +50,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--fused_update", action="store_true",
                    help="fuse lookup + motion-encoder corr conv into one "
                         "Pallas kernel per iteration (requires "
-                        "--corr_impl pallas; same checkpoints)")
+                        "--corr_impl flash; same checkpoints)")
     p.add_argument("--scan_unroll", type=int, default=1,
                    help="refinement-scan unroll factor (XLA pipelining "
                         "knob; numerically identical)")

@@ -9,8 +9,8 @@ The motion encoders own the fused refinement-step seam
 (config.fused_update): their first layer — the 1x1 conv over the
 (2r+1)^2-per-level correlation features — is exactly a per-pixel matmul,
 so it can run INSIDE the Pallas lookup kernel while each pixel block's
-correlation window is still VMEM-resident (ops/pallas_corr.py
-pallas_fused_step). ``FusedCorrEncoder`` declares parameters with the
+correlation window is still VMEM-resident (the pyramid's fused_step,
+ops/local_corr.py). ``FusedCorrEncoder`` declares parameters with the
 same names/shapes/initializers as the ``nn.Conv`` it replaces, under the
 same module name ("Conv_0"), so the parameter tree — and therefore every
 checkpoint and the torch interop name map (interop/torch_convert.py) —
@@ -90,8 +90,9 @@ class SepConvGRU(nn.Module):
 
 
 class FusedCorrEncoder(nn.Module):
-    """The motion encoder's 1x1 corr conv, executed INSIDE the fused
-    Pallas lookup kernel (pre-activation; the relu stays in XLA).
+    """The motion encoder's 1x1 corr conv, executed INSIDE the
+    pyramid's fused lookup kernel (pre-activation; the relu stays in
+    XLA).
 
     Declares ``kernel``/``bias`` with ``nn.Conv``'s exact shapes and
     initializers, so instantiating it under the name the conv would have
@@ -111,11 +112,6 @@ class FusedCorrEncoder(nn.Module):
 
     @nn.compact
     def __call__(self, pyr, coords):
-        from dexiraft_tpu.ops.pallas_corr import (
-            flash_fused_step,
-            pallas_fused_step,
-        )
-
         num_levels = len(pyr.fmap2_pyramid)
         win = 2 * pyr.radius + 1
         in_ch = num_levels * win * win
@@ -129,14 +125,7 @@ class FusedCorrEncoder(nn.Module):
             w = jnp.concatenate(
                 [w[lvl * ww:(lvl + 1) * ww] * pyr.scales[lvl]
                  for lvl in range(num_levels)], axis=0)
-        # flash = the blocked HBM-streaming kernel (ONE call at any
-        # geometry); pallas = the per-pixel VMEM formulation with its
-        # fp32 budget split. Same VJP contract, same param tree.
-        step = (flash_fused_step if pyr.kernel == "flash"
-                else pallas_fused_step)
-        out = step(pyr.fmap1, pyr.fmap2_pyramid, coords,
-                   w, bias.astype(jnp.float32), pyr.radius,
-                   None, pyr.row_chunk)
+        out = pyr.fused_step(coords, w, bias.astype(jnp.float32))
         return out.astype(self.dtype)
 
 

@@ -109,9 +109,8 @@ class LocalCorr:
     wd: int = flax.struct.field(pytree_node=False)
     radius: int = flax.struct.field(pytree_node=False)
     row_chunk: Optional[int] = flax.struct.field(pytree_node=False, default=None)
-    # lookup implementation: "xla" (local_corr_level matmuls), "pallas"
-    # (per-pixel slice kernel), "flash" (blocked HBM-streaming kernel —
-    # ops/pallas_corr.py flash_local_corr_level / flash_fused_step)
+    # lookup implementation: "xla" (local_corr_level matmuls) or "flash"
+    # (blocked HBM-streaming kernel — ops/pallas_corr.py)
     kernel: str = flax.struct.field(pytree_node=False, default="xla")
     # per-level fp32 scalar dequantization scales for int8-stored fmap2
     # levels (ops/quant.py); None for fp32/bf16. Correlation is linear in
@@ -128,20 +127,16 @@ class LocalCorr:
         out: List[jax.Array] = []
         for i, f2 in enumerate(self.fmap2_pyramid):
             coords_i = coords / (2.0 ** i)
-            if self.kernel in ("pallas", "flash"):
-                from dexiraft_tpu.ops.pallas_corr import (
-                    flash_local_corr_level,
-                    pallas_local_corr_level,
-                )
+            if self.kernel == "flash":
+                from dexiraft_tpu.ops.pallas_corr import flash_local_corr_level
 
                 # interpret=None defers to the kernel module's
                 # DEXIRAFT_PALLAS_INTERPRET env knob, which makes these
                 # whole-model paths exercisable off-chip
-                # (tests/test_local_corr.py, tests/test_zzzflashcorr.py)
-                level = (flash_local_corr_level if self.kernel == "flash"
-                         else pallas_local_corr_level)
-                corr = level(self.fmap1, f2, coords_i, self.radius,
-                             None, self.row_chunk)
+                # (tests/test_zzzflashcorr.py)
+                corr = flash_local_corr_level(
+                    self.fmap1, f2, coords_i, self.radius, None,
+                    self.row_chunk)
             else:
                 corr = local_corr_level(
                     self.fmap1, f2, coords_i, self.radius, self.row_chunk)
@@ -151,6 +146,18 @@ class LocalCorr:
             out.append(corr)
         return jnp.concatenate(out, axis=-1).astype(jnp.float32)
 
+    def fused_step(self, coords: jax.Array, weight: jax.Array,
+                   bias: jax.Array) -> jax.Array:
+        """The lookup and the motion encoder's 1x1 corr conv as one
+        kernel (kernel="flash" only; RAFTConfig refuses fused_update
+        elsewhere): coords as above, weight (L*(2r+1)^2, F) with int8
+        level scales already folded in, bias (F,) -> (B, H, W, F)."""
+        from dexiraft_tpu.ops.pallas_corr import flash_fused_step
+
+        return flash_fused_step(self.fmap1, self.fmap2_pyramid, coords,
+                                weight, bias, self.radius, None,
+                                self.row_chunk)
+
 
 def build_local_corr(
     fmap1: jax.Array,
@@ -158,9 +165,8 @@ def build_local_corr(
     num_levels: int = 4,
     radius: int = 4,
     row_chunk: Optional[int] = None,
-    use_pallas: bool = False,
     dtype: str = "fp32",
-    kernel: Optional[str] = None,
+    kernel: str = "xla",
 ) -> LocalCorr:
     """Build the pooled-fmap2 pyramid (no volume materialization).
 
@@ -170,15 +176,11 @@ def build_local_corr(
     level is then stored bf16/int8 with a per-level scale (ops/quant.py)
     and the lookup dequantizes in-register.
 
-    ``kernel`` picks the lookup implementation ("xla" | "pallas" |
-    "flash"); ``use_pallas`` is the legacy boolean spelling of
-    kernel="pallas" and is ignored when ``kernel`` is given.
+    ``kernel`` picks the lookup implementation ("xla" | "flash").
     """
-    if kernel is None:
-        kernel = "pallas" if use_pallas else "xla"
-    if kernel not in ("xla", "pallas", "flash"):
+    if kernel not in ("xla", "flash"):
         raise ValueError(f"unknown local-corr kernel {kernel!r}; "
-                         "expected 'xla', 'pallas', or 'flash'")
+                         "expected 'xla' or 'flash'")
     b, h, w, _ = fmap1.shape
     f1 = fmap1.astype(jnp.float32)
     pooled = [fmap2.astype(jnp.float32)]

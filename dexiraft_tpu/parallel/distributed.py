@@ -201,3 +201,13 @@ def refresh_backend_world() -> None:
     xla_bridge.process_count.cache_clear()
     xla_bridge.local_devices.cache_clear()
     _jax.clear_caches()
+    # orbax keeps its own: the async save's signalling client is cached
+    # with the distributed client of the world it was first asked in,
+    # and every save after a reconfiguration then dials a coordinator
+    # that is gone ("Connection refused"; the step never commits)
+    try:
+        from orbax.checkpoint._src.futures import signaling_client
+    except ImportError:  # another orbax: nothing cached under this name
+        pass
+    else:
+        signaling_client.get_signaling_client.cache_clear()

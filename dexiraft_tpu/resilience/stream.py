@@ -76,7 +76,10 @@ def save_position(directory: str, step: int, pos: StreamPosition,
         record["loader_kind"] = str(loader_kind)
     if fingerprint is not None:
         record["fingerprint"] = str(fingerprint)
-    tmp = path + ".tmp"
+    # the writer's own temp name: hosts that share the checkpoint
+    # directory write the same record, and a shared name lets one rename
+    # take the file the other is about to rename
+    tmp = path + f".tmp{os.getpid()}"
     with open(tmp, "w") as f:
         json.dump(record, f)
     os.replace(tmp, path)

@@ -35,7 +35,7 @@ import subprocess
 import sys
 
 from dexiraft_tpu.chips import one_chip_env, refuse_more_than_chips
-from dexiraft_tpu.config import VARIANTS
+from dexiraft_tpu.config import CORR_IMPLS, VARIANTS
 from dexiraft_tpu.serve.engine import ServeConfig, add_engine_args
 
 
@@ -52,7 +52,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="auto",
-                   choices=["auto", "allpairs", "local", "pallas", "flash"],
+                   choices=["auto", *CORR_IMPLS],
                    help="'auto' (default) = the production config: "
                         "flash-blocked fused step on TPU (O(fmaps) "
                         "correlation memory at any geometry), allpairs "
@@ -64,7 +64,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--fused_update", action="store_true",
                    help="one fused Pallas lookup+update kernel per "
                         "refinement iteration (requires --corr_impl "
-                        "flash or pallas)")
+                        "flash)")
     p.add_argument("--scan_unroll", type=int, default=1)
     p.add_argument("--dexined_upconv", default="subpixel",
                    choices=["transpose", "subpixel"])

@@ -29,6 +29,7 @@ import numpy as np
 
 from dexiraft_tpu import config as cfglib
 from dexiraft_tpu.config import (
+    CORR_IMPLS,
     LM_VARIANTS,
     VARIANTS,
     LMConfig,
@@ -92,7 +93,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="allpairs",
-                   choices=["allpairs", "local", "pallas", "flash"])
+                   choices=CORR_IMPLS)
     p.add_argument("--corr_dtype", default="fp32", choices=["fp32", "bf16"],
                    help="storage precision of the correlation pyramid "
                         "(halves HBM traffic of the refinement loop at "
@@ -100,7 +101,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--fused_update", action="store_true",
                    help="fuse each iteration's 4-level lookup with the "
                         "motion encoder's corr conv into one Pallas "
-                        "kernel (requires --corr_impl flash or pallas; "
+                        "kernel (requires --corr_impl flash; "
                         "identical param tree, checkpoints interchange)")
     p.add_argument("--remat", action="store_true",
                    help="rematerialize refinement iterations in backward "
@@ -351,21 +352,20 @@ def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
     if args.stage is None:
         raise SystemExit("train: --stage is required for --variant "
                          f"{args.variant}")
-    if args.fused_update and args.corr_impl not in ("pallas", "flash"):
-        raise SystemExit("train: --fused_update requires --corr_impl "
-                         "flash (the blocked HBM-streaming kernel) or "
-                         "pallas (the per-pixel VMEM formulation)")
-    cfg = VARIANTS[args.variant](
-        small=args.small,
-        mixed_precision=args.mixed_precision,
-        dropout=args.dropout,
-        corr_impl=args.corr_impl,
-        corr_dtype=args.corr_dtype,
-        fused_update=args.fused_update,
-        remat=args.remat,
-        remat_lookup=args.remat_lookup,
-        dexined_upconv=args.dexined_upconv,
-    )
+    try:
+        cfg = VARIANTS[args.variant](
+            small=args.small,
+            mixed_precision=args.mixed_precision,
+            dropout=args.dropout,
+            corr_impl=args.corr_impl,
+            corr_dtype=args.corr_dtype,
+            fused_update=args.fused_update,
+            remat=args.remat,
+            remat_lookup=args.remat_lookup,
+            dexined_upconv=args.dexined_upconv,
+        )
+    except ValueError as e:  # a combination RAFTConfig refuses
+        raise SystemExit(f"train: {e}") from None
 
     if args.preset != "none":
         stages = (cfglib.STANDARD_STAGES if args.preset == "standard"

@@ -47,6 +47,8 @@ sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+from dexiraft_tpu.ops import pallas_corr
+
 EVAL_GEOMETRY = (440, 1024)
 HIGHRES_GEOMETRY = (1088, 1920)  # 1080p padded to /8
 CHAINED_GEOMETRY = (256, 512)
@@ -279,7 +281,7 @@ def run_record(args) -> dict:
         # interpreter-mode kernels off-chip; a big pixel block keeps the
         # interpret grid (traced per step) small at 1080p
         os.environ.setdefault("DEXIRAFT_PALLAS_INTERPRET", "1")
-        os.environ.setdefault("DEXIRAFT_FLASH_PIXEL_BLOCK", "2048")
+        pallas_corr._FLASH_PIXEL_BLOCK = 2048
     iters = args.iters if args.iters is not None else (8 if on_tpu else 2)
     _log(f"platform={platform} iters={iters}")
     rec = {
@@ -323,10 +325,10 @@ def run_single(args) -> None:
           f"{2 * args.chunk * (w // 8) * (h // 8) * (w // 8) * 4 / 1e9:.2f} GB",
           file=sys.stderr)
 
-    if args.impl in ("pallas", "flash") and platform != "tpu":
-        # either Pallas impl can only lower off-TPU in interpreter mode
+    if args.impl == "flash" and platform != "tpu":
+        # the kernel can only lower off-TPU in interpreter mode
         os.environ.setdefault("DEXIRAFT_PALLAS_INTERPRET", "1")
-        os.environ.setdefault("DEXIRAFT_FLASH_PIXEL_BLOCK", "2048")
+        pallas_corr._FLASH_PIXEL_BLOCK = 2048
     cfg = raft_v5(mixed_precision=(platform == "tpu"), corr_impl=args.impl,
                   corr_row_chunk=args.chunk,
                   fused_update=args.impl == "flash")
@@ -361,6 +363,8 @@ def run_single(args) -> None:
 
 
 def main():
+    from dexiraft_tpu.config import CORR_IMPLS
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="record", choices=["record", "single"],
                     help="record = the pinned strict-mode JSON record "
@@ -373,7 +377,7 @@ def main():
                     help="refinement iterations (record mode default: "
                          "8 on TPU, 2 on the CPU)")
     ap.add_argument("--impl", default="local",
-                    choices=["local", "pallas", "flash", "allpairs"],
+                    choices=CORR_IMPLS,
                     help="corr path for --mode single")
     ap.add_argument("--cpu", action="store_true",
                     help="force the CPU backend (same as JAX_PLATFORMS=cpu)")

@@ -19,13 +19,11 @@ logs/ carries records in them):
   --variant 3   bf16 inputs for the on-demand (local) corr path
     fp32/bf16/bf16_all timing + max|delta| accuracy bound per variant
 
-  --variant 4   the three lookup FORMULATIONS head-to-head (ISSUE 12):
+  --variant 4   the two lookup FORMULATIONS head-to-head (ISSUE 12):
     allpairs   materialized volume + one-hot matmul lookup (corr_lookup)
-    pallas     per-pixel slice kernel (pallas_local_corr_level; CPU
-               interpret runs only — it does not compile for the chip)
     flash      flash-blocked kernel — fmap2 row-block-streamed from HBM,
                partial-volume MXU matmuls, no materialized volume
-    In a CPU run the Pallas legs run in interpreter mode at a reduced
+    In a CPU run the Pallas leg runs in interpreter mode at a reduced
     geometry/iteration count (printed) — code-path proof only.
 
 Each timed run is 32 chained 2-stream lookups inside one scan
@@ -455,11 +453,10 @@ def main_v3():
 
 
 # ---------------------------------------------------------------------------
-# variant 4: the three formulations head-to-head (ISSUE 12)
+# variant 4: the two formulations head-to-head (ISSUE 12)
 # ---------------------------------------------------------------------------
 # allpairs amortizes one volume build over the loop but streams the
-# O(N^2) volume from HBM every lookup; per-pixel pallas avoids the
-# volume but is gather-shaped; flash-blocked recomputes the needed
+# O(N^2) volume from HBM every lookup; flash-blocked recomputes the needed
 # partial-volume blocks as MXU matmuls with only the fmaps in HBM.
 
 def main_v4():
@@ -470,8 +467,7 @@ def main_v4():
     on_tpu = jax.devices()[0].platform == "tpu"
     h8, w8, iters = (H8, W8, ITERS) if on_tpu else (16, 32, 4)
     if not on_tpu:
-        # interpreter-mode kernels at the full geometry are debug-speed
-        # (the per-pixel kernel loops 7040 slices per level per iter) —
+        # interpreter-mode kernels at the full geometry are debug-speed:
         # the CPU leg proves the code paths, not the ordering
         os.environ.setdefault("DEXIRAFT_PALLAS_INTERPRET", "1")
         print(f"cpu run: reduced geometry {h8}x{w8}, {iters} iters "
@@ -511,10 +507,6 @@ def main_v4():
 
     time_leg("allpairs", lambda a, b: (build_corr_pyramid(a, b, 4, RADIUS),
                                        build_corr_pyramid(b, a, 4, RADIUS)))
-    if not on_tpu:  # does not compile for the chip (PALLAS_TPU_REFUSAL)
-        time_leg("pallas", lambda a, b: (
-            build_local_corr(a, b, 4, RADIUS, kernel="pallas"),
-            build_local_corr(b, a, 4, RADIUS, kernel="pallas")))
     time_leg("flash", lambda a, b: (
         build_local_corr(a, b, 4, RADIUS, kernel="flash"),
         build_local_corr(b, a, 4, RADIUS, kernel="flash")))
@@ -526,8 +518,7 @@ def main():
     ap.add_argument("--variant", type=int, choices=[1, 2, 3, 4], default=1,
                     help="1 = formulation A/B, 2 = contraction-order / "
                          "instance-overhead round, 3 = bf16-input round, "
-                         "4 = allpairs vs per-pixel pallas vs "
-                         "flash-blocked")
+                         "4 = allpairs vs flash-blocked")
     args = ap.parse_args()
     print(f"platform={jax.devices()[0].platform}", file=sys.stderr)
     {1: main_v1, 2: main_v2, 3: main_v3, 4: main_v4}[args.variant]()

@@ -14,8 +14,8 @@ Times, around work that ends in block_until_ready:
                its bytes column is the O(fmaps) streaming BOUND, vs the
                O(N^2) volume bytes of lkp32. Interpreter-mode
                (debug-speed) in a CPU run; with lookup_ab
-               --variant 4 the pinned records now cover all three
-               formulations (allpairs / per-pixel pallas / flash)
+               --variant 4 the pinned records cover both
+               formulations (allpairs / flash)
   forward      the full v5 test-mode forward (sanity: ~ sum of the above)
   fwd_iter1    iters=1 forward -> per-iteration + prelude split
   fwd_sp_unr4  candidate config: scan_unroll=4 (XLA software pipelining)

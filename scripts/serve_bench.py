@@ -161,6 +161,8 @@ ADAPTIVE_OVERLOAD_KEYS = OVERLOAD_KEYS | {
 
 
 def build_parser() -> argparse.ArgumentParser:
+    from dexiraft_tpu.config import CORR_IMPLS
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", default="v5")
     ap.add_argument("--small", action="store_true")
@@ -181,8 +183,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--cpu", action="store_true",
                     help="force the CPU backend (same as JAX_PLATFORMS=cpu)")
     ap.add_argument("--corr_impl", default="auto",
-                    choices=["auto", "allpairs", "local", "pallas",
-                             "flash"],
+                    choices=["auto", *CORR_IMPLS],
                     help="'auto' (default) = the production config: "
                          "flash-blocked fused step on TPU, allpairs "
                          "off-chip; the RESOLVED value is stamped into "
@@ -190,7 +191,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "are self-describing")
     ap.add_argument("--fused_update", action="store_true",
                     help="fused Pallas lookup+update kernel (requires "
-                         "--corr_impl flash or pallas)")
+                         "--corr_impl flash)")
     # ---- closed-loop (service) mode ------------------------------------
     ap.add_argument("--closed_loop", action="store_true",
                     help="load-generate against the real FlowService over "

@@ -65,6 +65,7 @@ import numpy as np
 
 
 def main():
+    from dexiraft_tpu.config import CORR_IMPLS
     from dexiraft_tpu.train_cli import fsdp_arg
 
     ap = argparse.ArgumentParser()
@@ -93,13 +94,13 @@ def main():
                          "elementwise chains")
     ap.add_argument("--remat_lookup", action="store_true")
     ap.add_argument("--corr_impl", default="allpairs",
-                    choices=["allpairs", "local", "pallas", "flash"])
+                    choices=CORR_IMPLS)
     ap.add_argument("--corr_dtype", choices=["fp32", "bf16"], default="fp32",
                     help="correlation-pyramid storage precision (int8 is "
                          "inference-only, so not offered here)")
     ap.add_argument("--fused_update", action="store_true",
                     help="fused Pallas lookup+update step kernel "
-                         "(requires --corr_impl flash or pallas)")
+                         "(requires --corr_impl flash)")
     ap.add_argument("--no_compile_cache", action="store_true",
                     help="skip the persistent compile cache (cold "
                          "compile every launch)")
@@ -141,8 +142,8 @@ def main():
                          "first jax-visible setting, handled before "
                          "import")
     args = ap.parse_args()
-    if args.fused_update and args.corr_impl not in ("pallas", "flash"):
-        ap.error("--fused_update requires --corr_impl flash or pallas")
+    if args.fused_update and args.corr_impl != "flash":
+        ap.error("--fused_update requires --corr_impl flash")
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
 

@@ -351,6 +351,8 @@ def measure_carry(args, cfg, variables, h: int, w: int) -> dict:
 
 
 def main():
+    from dexiraft_tpu.config import CORR_IMPLS
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", default="v1")
     ap.add_argument("--small", action="store_true")
@@ -363,8 +365,7 @@ def main():
     ap.add_argument("--seq_lens", type=int, nargs="+", default=(2, 8, 32),
                     help="stream lengths for the flat-footprint leg")
     ap.add_argument("--corr_impl", default="auto",
-                    choices=["auto", "allpairs", "local", "pallas",
-                             "flash"])
+                    choices=["auto", *CORR_IMPLS])
     ap.add_argument("--corr_dtype", default="fp32",
                     choices=["fp32", "bf16", "int8"])
     ap.add_argument("--fused_update", action="store_true")

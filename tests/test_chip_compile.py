@@ -106,33 +106,11 @@ def test_flash_lookup_compiles_for_v5e(chip, dtype, level):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_per_pixel_family_is_still_refused(chip, fused):
-    """corr_impl="pallas" is withdrawn from every on-chip default because
-    Mosaic refuses its window load (config.PALLAS_TPU_REFUSAL). When
-    this starts passing the kernel compiles again: re-admit the family
-    here, in bench.py's legs and in the refusal, deliberately. (Batch 1:
-    the refusal it pins is the window load's.)"""
-    s = _shapes(chip, "fp32", b=1)
-    if fused:
-        fn = lambda f1, lv, co, w, b: pc.pallas_fused_step(  # noqa: E731
-            f1, lv, co, w, b, RADIUS, False)
-        args = (s["f1"], s["levels"], s["coords"], s["weight"], s["bias"])
-    else:
-        fn = lambda f1, f2, co: pc.pallas_local_corr_level(  # noqa: E731
-            f1, f2, co, RADIUS, False)
-        args = (s["f1"], s["levels"][0], s["coords"])
-    with pytest.raises(Exception, match="multiple of 8"):
-        _compiled_text(fn, *args)
-
-
-def test_per_pixel_family_refuses_a_tpu_backend(monkeypatch):
-    """On a TPU backend the per-pixel kernels fail at trace time with the
-    compiler's reason, and the interpret switch is an error, not a
-    mode."""
+def test_interpret_switch_is_an_error_on_a_tpu_backend(monkeypatch):
+    """On a TPU backend the interpret switch is an error, not a mode."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="does not compile"):
-        pc._refuse_per_pixel_on_tpu(False)
+    monkeypatch.delenv("DEXIRAFT_PALLAS_INTERPRET", raising=False)
+    assert pc._interpret_default() is False
     monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
     with pytest.raises(RuntimeError, match="DEXIRAFT_PALLAS_INTERPRET"):
         pc._interpret_default()

@@ -113,27 +113,3 @@ class TestRAFTIntegration:
         preds = model.apply(variables, im1, im1, iters=2, train=False)
         assert preds.shape == (2, 1, 64, 64, 2)
         assert np.isfinite(np.asarray(preds)).all()
-
-    def test_raft_pallas_forward_matches_local(self, monkeypatch):
-        # the corr_impl="pallas" seam through the WHOLE model (init with
-        # local — the param tree is corr-independent — then apply with
-        # the kernel in interpret mode, off-chip)
-        from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
-
-        img = jnp.zeros((1, 32, 32, 3), jnp.float32)
-        rng = jax.random.PRNGKey(1)
-        im1 = jax.random.uniform(rng, (1, 32, 32, 3), jnp.float32, 0, 255)
-        im2 = jax.random.uniform(jax.random.PRNGKey(2),
-                                 (1, 32, 32, 3), jnp.float32, 0, 255)
-
-        cfg_l = raft_v1(small=True, corr_impl="local")
-        variables = RAFT(cfg_l).init(jax.random.PRNGKey(0), img, img,
-                                     iters=1, train=False)
-        ref = RAFT(cfg_l).apply(variables, im1, im2, iters=2, train=False)
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        cfg_p = raft_v1(small=True, corr_impl="pallas")
-        out = RAFT(cfg_p).apply(variables, im1, im2, iters=2, train=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)

@@ -2,19 +2,22 @@
 
 Interpret-mode parity of flash_fused_step / flash_local_corr_level
 against the unfused XLA references (forward AND gradients, including
-through bf16/int8-quantized levels), blocked-tiling vs single-block and
-vs the per-pixel split-path equivalence, the whole-model flash path on
-shared parameters, config-time refusals, and the compile-time
-memory_analysis pin that the flash executable's temp footprint is
+through bf16/int8-quantized levels, at the published width and radii
+and at the frame's edges), blocked-tiling vs single-block equivalence,
+the whole-model flash path on shared parameters (v1 and v5, as the eval
+cells run them), config-time refusals, the one list of --corr_impl
+choices, and the compile-time memory_analysis pin that the flash executable's temp footprint is
 O(fmaps) — not O(volume) — at a geometry where the all-pairs volume
 dominates.
 
-Named to sort last (870s tier-1 budget convention); every fixture is
+Named to sort last (tier-1 budget convention); every fixture is
 tiny because interpret-mode Pallas pays per traced grid step.
 """
 
+import glob
 import importlib.util
 import os.path as osp
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,12 +25,12 @@ import numpy as np
 import pytest
 
 from dexiraft_tpu.ops.corr import build_corr_pyramid, corr_lookup
+from dexiraft_tpu.ops import pallas_corr
 from dexiraft_tpu.ops.local_corr import build_local_corr, local_corr_level
 from dexiraft_tpu.ops.pallas_corr import (
     flash_fused_step,
     flash_local_corr_level,
     fused_reference,
-    pallas_fused_step,
 )
 
 REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
@@ -36,10 +39,10 @@ REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def _small_flash_blocks(monkeypatch):
     """Interpret mode traces the kernel once per grid step and pays per
-    padded pixel: tiny fixtures want tiny blocks (the knobs never change
-    values — test_rows_block_equivalence pins that)."""
-    monkeypatch.setenv("DEXIRAFT_FLASH_PIXEL_BLOCK", "16")
-    monkeypatch.setenv("DEXIRAFT_FLASH_ROWS", "2")
+    padded pixel: tiny fixtures want tiny blocks (the tiling never
+    changes values — test_rows_block_equivalence pins that)."""
+    monkeypatch.setattr(pallas_corr, "_FLASH_PIXEL_BLOCK", 16)
+    monkeypatch.setattr(pallas_corr, "_FLASH_ROWS", 2)
 
 
 def _grid(b, h, w):
@@ -192,6 +195,89 @@ class TestFlashKernelParity:
                                 w8, bias, radius, True)
         bound = 0.05 * float(jnp.max(jnp.abs(ref)))
         assert float(jnp.max(jnp.abs(out8 - ref))) <= max(bound, 1e-3)
+
+
+# -- the model's own width and radii, and the frame's edges -----------------
+
+
+def _geometry_case(name):
+    """-> (fmap1, fmap2, coords in fmap2's pixels, radius) at C=128 on an
+    8x16 query grid: what the fixtures above (C=32, radius 1-2, 6x8) do
+    not reach."""
+    key = jax.random.PRNGKey(sum(map(ord, name)))
+    b, h, w, c = 1, 8, 16, 128
+    k1, k2, k3 = jax.random.split(key, 3)
+    f1 = jax.random.normal(k1, (b, h, w, c), jnp.float32)
+    f2 = jax.random.normal(k2, (b, h, w, c), jnp.float32)
+    noisy = _grid(b, h, w) + jax.random.uniform(k3, (b, h, w, 2),
+                                                jnp.float32, -3.0, 3.0)
+    if name in ("radius3", "radius4"):
+        return f1, f2, noisy, int(name[-1])
+    if name == "frame_edge":  # every window straddles two frame edges
+        edge = jnp.stack([jnp.full((b, h, w), -0.4),
+                          jnp.full((b, h, w), h - 0.6)], axis=-1)
+        return f1, f2, edge, 4
+    if name == "far_out_both_signs":  # half the queries at -500, half +500
+        sign = jnp.where(jnp.arange(w) % 2 == 0, -1.0, 1.0)
+        far = jnp.broadcast_to(500.0 * sign[None, None, :, None],
+                               (b, h, w, 2))
+        return f1, f2, far, 4
+    assert name == "half_size_level"  # a coarser level than the query grid
+    f2 = jax.random.normal(k2, (b, h // 2, w // 2, c), jnp.float32)
+    return f1, f2, noisy / 2.0, 3
+
+
+class TestModelWidthGeometry:
+    CASES = ["radius3", "radius4", "frame_edge", "far_out_both_signs",
+             "half_size_level"]
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_lookup_matches_reference(self, name):
+        f1, f2, coords, radius = _geometry_case(name)
+        out = flash_local_corr_level(f1, f2, coords, radius, True)
+        ref = local_corr_level(f1, f2, coords, radius)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+        if name == "far_out_both_signs":
+            np.testing.assert_array_equal(np.asarray(out), 0.0)
+        else:
+            assert float(jnp.abs(ref).max()) > 0.1
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_fused_matches_reference(self, name):
+        """The same level as the fused step's only one (level 0: its
+        coords are the level's)."""
+        f1, f2, coords, radius = _geometry_case(name)
+        k4, k5 = jax.random.split(jax.random.PRNGKey(28))
+        weight = jax.random.normal(k4, ((2 * radius + 1) ** 2, 16)) * 0.05
+        bias = jax.random.normal(k5, (16,)) * 0.1
+        out = flash_fused_step(f1, (f2,), coords, weight, bias, radius, True)
+        ref = fused_reference(f1, (f2,), coords, weight, bias, radius)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+        if name == "far_out_both_signs":
+            np.testing.assert_array_equal(
+                np.asarray(out), np.broadcast_to(np.asarray(bias), out.shape))
+
+    def test_lookup_vjp_matches_reference(self):
+        """flash_local_corr_level's own VJP (the fused step's is pinned
+        above): local_corr_level's gradients to both fmaps, exactly zero
+        to coords."""
+        f1, f2, coords, _ = _geometry_case("radius3")
+        f1, f2, coords = f1[:, :4, :8], f2[:, :4, :8], coords[:, :4, :8]
+
+        def grads(level):
+            return jax.grad(lambda a, b_, c_: jnp.sum(level(a, b_, c_) ** 2),
+                            argnums=(0, 1, 2))(f1, f2, coords)
+
+        gf = grads(lambda a, b_, c_: flash_local_corr_level(a, b_, c_, 2,
+                                                            True))
+        gr = grads(lambda a, b_, c_: local_corr_level(a, b_, c_, 2))
+        for a, b_ in zip(gf[:2], gr[:2]):
+            assert float(jnp.abs(b_).max()) > 0
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=1e-3, atol=1e-3)
+        np.testing.assert_array_equal(np.asarray(gf[2]), 0.0)
 
 
 # -- the two-slot row-block pipeline (ISSUE 25) -----------------------------
@@ -373,48 +459,53 @@ class TestVisitedRangePipeline:
 
 
 class TestBlockedTilingEquivalence:
-    """The split-path equivalence satellite: one big block vs fine row
-    tiling vs the per-pixel fused kernel's VMEM-budget split — all the
-    same sum, associativity aside."""
+    """One big block vs fine row tiling: the same sum, associativity
+    aside."""
 
     def test_rows_block_equivalence(self, monkeypatch):
         radius = 2
         f1, f2, coords, weight, bias = _setup(jax.random.PRNGKey(7),
                                               radius=radius)
         lc = build_local_corr(f1, f2, num_levels=3, radius=radius)
-        monkeypatch.setenv("DEXIRAFT_FLASH_ROWS", "64")  # single block
+        monkeypatch.setattr(pallas_corr, "_FLASH_ROWS", 64)  # single block
         one = flash_fused_step(lc.fmap1, lc.fmap2_pyramid, coords,
                                weight, bias, radius, True)
-        monkeypatch.setenv("DEXIRAFT_FLASH_ROWS", "1")  # finest tiling
+        monkeypatch.setattr(pallas_corr, "_FLASH_ROWS", 1)  # finest tiling
         many = flash_fused_step(lc.fmap1, lc.fmap2_pyramid, coords,
                                 weight, bias, radius, True)
         assert float(jnp.max(jnp.abs(one - many))) <= 1e-4
-
-    def test_matches_per_pixel_split_path(self, monkeypatch):
-        """flash vs the per-pixel fused kernel forced through ITS
-        VMEM-budget per-level split: identical up to summation order."""
-        radius = 2
-        f1, f2, coords, weight, bias = _setup(jax.random.PRNGKey(8),
-                                              radius=radius)
-        lc = build_local_corr(f1, f2, num_levels=3, radius=radius)
-        flash = flash_fused_step(lc.fmap1, lc.fmap2_pyramid, coords,
-                                 weight, bias, radius, True)
-        from dexiraft_tpu.ops import pallas_corr
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_PIXEL_BLOCK", "16")
-        monkeypatch.setattr(pallas_corr, "_FUSED_LEVELS_VMEM_BYTES", 1)
-        split = pallas_fused_step(lc.fmap1, lc.fmap2_pyramid, coords,
-                                  weight, bias, radius, True)
-        assert float(jnp.max(jnp.abs(flash - split))) <= 1e-3
 
     def test_pixel_block_override_identical(self, monkeypatch):
         radius = 2
         f1, f2, coords, _, _ = _setup(jax.random.PRNGKey(9), radius=radius)
         a = flash_local_corr_level(f1, f2, coords, radius, True)
-        monkeypatch.setenv("DEXIRAFT_FLASH_PIXEL_BLOCK", "64")
+        monkeypatch.setattr(pallas_corr, "_FLASH_PIXEL_BLOCK", 64)
         b = flash_local_corr_level(f1, f2, coords, radius, True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6)
+
+
+def _assert_flash_fused_matches_allpairs(make, mixed, variables, im1, im2):
+    """The whole model as the eval cells run it (flash + fused, which
+    `auto` resolves to on a TPU) against allpairs on the same
+    parameters."""
+    from dexiraft_tpu.models.raft import RAFT
+
+    def flow(**corr):  # one program each: eager, hundreds of compiles
+        cfg = make(small=True, mixed_precision=mixed, **corr)
+        return jax.jit(lambda v, a, b: RAFT(cfg).apply(
+            v, a, b, iters=2, train=False))(variables, im1, im2)
+
+    ref = flow()
+    out = flow(corr_impl="flash", fused_update=True)
+    scale = float(jnp.abs(ref).max())
+    assert out.shape == ref.shape and scale > 1.0  # px: a flow to compare
+    # fp32: reassociation noise. bf16 compute: a last-bit difference in a
+    # window feature can round an activation the other way (measured
+    # 0.6-0.8 % of the largest flow after two iterations)
+    tol = 0.02 * scale if mixed else 1e-4
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=0, atol=tol)
 
 
 class TestFlashModel:
@@ -476,6 +567,15 @@ class TestFlashModel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
 
+    def test_flash_fused_matches_allpairs_mixed_precision(self, fixture,
+                                                          monkeypatch):
+        from dexiraft_tpu.config import raft_v1
+
+        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
+        im1, im2, variables, _ = fixture
+        _assert_flash_fused_matches_allpairs(raft_v1, True, variables,
+                                             im1, im2)
+
     def test_flash_trains(self, fixture, monkeypatch):
         """flash is trainable (what licenses train_cli --corr_impl
         flash): whole-model param grads through the scanned fused step
@@ -509,6 +609,35 @@ class TestFlashModel:
         assert max(float(jnp.abs(a).max()) for a in flat_f) > 0
 
 
+class TestEvalCellModelV5:
+    """v5 small: the dual stream's 2B-batch pyramid through the fused
+    step, behind the embedded DexiNed."""
+
+    @pytest.fixture(scope="class")
+    def fixture(self):
+        from dexiraft_tpu.config import raft_v5
+        from dexiraft_tpu.models.raft import RAFT
+
+        img = jnp.zeros((1, 32, 32, 3), jnp.float32)
+        im1 = jax.random.uniform(jax.random.PRNGKey(1), (1, 32, 32, 3),
+                                 jnp.float32, 0, 255)
+        im2 = jax.random.uniform(jax.random.PRNGKey(2), (1, 32, 32, 3),
+                                 jnp.float32, 0, 255)
+        # one program: eager, v5's init is a thousand small compiles
+        variables = jax.jit(lambda k: RAFT(raft_v5(small=True)).init(
+            k, img, img, iters=1, train=False))(jax.random.PRNGKey(0))
+        return im1, im2, variables
+
+    @pytest.mark.parametrize("mixed", [False, True])
+    def test_flash_fused_matches_allpairs(self, fixture, mixed, monkeypatch):
+        from dexiraft_tpu.config import raft_v5
+
+        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
+        im1, im2, variables = fixture
+        _assert_flash_fused_matches_allpairs(raft_v5, mixed, variables,
+                                             im1, im2)
+
+
 class TestConfigTimeRefusals:
     """ISSUE 12 satellite: unknown combinations die at RAFTConfig
     construction, not deep in build_local_corr mid-trace."""
@@ -525,23 +654,20 @@ class TestConfigTimeRefusals:
         with pytest.raises(ValueError, match="unknown corr_dtype"):
             raft_v1(corr_dtype="fp16")
 
-    def test_fused_requires_flash_or_pallas_names_flash(self):
+    @pytest.mark.parametrize("impl", ["allpairs", "local"])
+    def test_fused_requires_flash_names_flash(self, impl):
         from dexiraft_tpu.config import raft_v1
 
         with pytest.raises(ValueError, match="fused_update.*flash"):
-            raft_v1(fused_update=True)  # default allpairs
-        with pytest.raises(ValueError, match="fused_update.*flash"):
-            raft_v1(corr_impl="local", fused_update=True)
-        # the sanctioned combos construct fine
-        raft_v1(corr_impl="flash", fused_update=True)
-        raft_v1(corr_impl="pallas", fused_update=True)
+            raft_v1(corr_impl=impl, fused_update=True)
+        raft_v1(corr_impl="flash", fused_update=True)  # the one that is
 
     def test_resolve_corr_impl(self):
         from dexiraft_tpu.config import resolve_corr_impl
 
         assert resolve_corr_impl("auto", "tpu") == ("flash", True)
         assert resolve_corr_impl("auto", "cpu") == ("allpairs", False)
-        assert resolve_corr_impl("pallas", "tpu") == ("pallas", False)
+        assert resolve_corr_impl("local", "tpu") == ("local", False)
         assert resolve_corr_impl("flash", "cpu") == ("flash", False)
 
     def test_build_local_corr_unknown_kernel_refused(self):
@@ -549,21 +675,43 @@ class TestConfigTimeRefusals:
         with pytest.raises(ValueError, match="unknown local-corr kernel"):
             build_local_corr(f1, f1, 2, 2, kernel="cuda")
 
-    def test_fused_levels_budget_env_validation(self):
-        from dexiraft_tpu.ops.pallas_corr import _parse_positive_int_env
+    def test_retired_corr_impl_refused_naming_flash(self):
+        """The per-pixel family's name is no configuration any more."""
+        from dexiraft_tpu.config import raft_v1
 
-        assert _parse_positive_int_env("DEXIRAFT_TEST_UNSET_VAR", 7) == 7
-        import os
+        with pytest.raises(ValueError, match="unknown corr_impl.*flash"):
+            raft_v1(corr_impl="pallas")
 
-        os.environ["DEXIRAFT_TEST_BUDGET_VAR"] = "12MB"
-        try:
-            with pytest.raises(ValueError, match="not an integer"):
-                _parse_positive_int_env("DEXIRAFT_TEST_BUDGET_VAR", 7)
-            os.environ["DEXIRAFT_TEST_BUDGET_VAR"] = "-4"
-            with pytest.raises(ValueError, match="positive"):
-                _parse_positive_int_env("DEXIRAFT_TEST_BUDGET_VAR", 7)
-        finally:
-            del os.environ["DEXIRAFT_TEST_BUDGET_VAR"]
+    def test_retired_kernel_refused_naming_flash(self):
+        f1 = jnp.zeros((1, 4, 4, 8), jnp.float32)
+        with pytest.raises(ValueError, match="local-corr kernel.*flash"):
+            build_local_corr(f1, f1, 2, 2, kernel="pallas")
+
+
+class TestOneCorrelationDecision:
+    """config.py names the implementations; every front end offers that
+    list and the kernel module has one environment switch."""
+
+    @pytest.mark.parametrize("cli,auto", [("train_cli", False),
+                                          ("eval_cli", True),
+                                          ("serve_cli", True)])
+    def test_cli_choices_are_the_config_list(self, cli, auto):
+        import importlib
+
+        from dexiraft_tpu.config import CORR_IMPLS
+
+        parser = importlib.import_module(f"dexiraft_tpu.{cli}").build_parser()
+        action, = [a for a in parser._actions if a.dest == "corr_impl"]
+        expected = (["auto"] if auto else []) + list(CORR_IMPLS)
+        assert list(action.choices) == expected
+        assert "pallas" not in action.choices
+
+    def test_ops_read_one_environment_variable(self):
+        reads = []
+        for path in glob.glob(osp.join(REPO, "dexiraft_tpu", "ops", "*.py")):
+            src = open(path).read()
+            reads += re.findall(r"(?:environ|getenv)[^\n]*", src)
+        assert reads and all("DEXIRAFT_PALLAS_INTERPRET" in r for r in reads)
 
 
 class TestMemoryFootprint:
