@@ -148,7 +148,7 @@ class RAFTStep(nn.Module):
 def _upsample(flow: jax.Array, mask: Optional[jax.Array]) -> jax.Array:
     if mask is None:  # small model has no mask head (core/raft.py:187-190)
         return upflow8(flow)
-    return upsample_flow_convex(flow.astype(jnp.float32), mask.astype(jnp.float32))
+    return upsample_flow_convex(flow.astype(jnp.float32), mask)
 
 
 class RAFT(nn.Module):

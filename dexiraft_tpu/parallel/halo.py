@@ -73,6 +73,7 @@ from dexiraft_tpu.models.update import (
 from dexiraft_tpu.ops.corr import build_corr_pyramid, corr_lookup
 from dexiraft_tpu.ops.grid import _resize_matrix, coords_grid
 from dexiraft_tpu.ops.losses import MAX_FLOW
+from dexiraft_tpu.ops.upsample import convex_combine
 from dexiraft_tpu.parallel.layout import (
     DATA_AXIS,
     FSDP_AXIS,
@@ -400,29 +401,19 @@ def _upflow8_halo(flow: jax.Array, n_seq: int) -> jax.Array:
 
 def _upsample_convex_halo(flow: jax.Array, mask: jax.Array,
                           n_seq: int) -> jax.Array:
-    """ops/upsample.upsample_flow_convex on a row slab, bit-exact: the
-    3x3 patch extraction needs one coarse row past each slab edge —
+    """ops/upsample.upsample_flow_convex on a row slab: the 3x3 patch
+    extraction needs one coarse row past each slab edge —
     halo-exchanged where the unsharded path zero-pads (same zeros at
     the global edges, by the non-circular exchange contract)."""
-    b, h, w, _ = flow.shape
-    m = mask.reshape(b, h, w, 9, 8, 8)
-    m = jax.nn.softmax(m, axis=3)
-
     fp = halo_exchange(8.0 * flow, 1, 1, n_seq)  # rows: L + 2
     fp = jnp.pad(fp, ((0, 0), (0, 0), (1, 1), (0, 0)))
-    patches = jnp.stack(
-        [fp[:, dy:dy + h, dx:dx + w, :] for dy in range(3) for dx in range(3)],
-        axis=3,
-    )
-    up = jnp.einsum("bhwkij,bhwkc->bhwijc", m, patches)
-    return up.transpose(0, 1, 3, 2, 4, 5).reshape(b, 8 * h, 8 * w, 2)
+    return convex_combine(fp, mask)
 
 
 def _upsample_halo(flow, mask, n_seq):
     if mask is None:
         return _upflow8_halo(flow, n_seq)
-    return _upsample_convex_halo(flow.astype(jnp.float32),
-                                 mask.astype(jnp.float32), n_seq)
+    return _upsample_convex_halo(flow.astype(jnp.float32), mask, n_seq)
 
 
 def _coords_grid_sharded(b: int, l8: int, w8: int, n_seq: int) -> jax.Array:

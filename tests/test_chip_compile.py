@@ -19,6 +19,7 @@ tier-1 clock reaches (hence no `test_zz*` name).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -151,3 +152,34 @@ def test_lm_attention_kernel_compiles_for_v5e(chip, which):
     name = {"forward": "lm_attention_fwd", "dq": "lm_attention_dq",
             "dkv": "lm_attention_dkv"}[which]
     assert "tpu_custom_call" in text and name in text
+
+
+# ---- the convex upsample (ops/upsample.py) --------------------------------
+
+def test_convex_upsample_is_lane_dense_for_v5e(chip):
+    """The function with its gradient at `v5-train-chairs`' shapes (the
+    image stream's batch of 8, 368x496 / 8, the mask in bf16): until PR 29
+    its arrays were laid out as `f32[8,46,62,9,8,8]{3,5,4,2,1,0:T(8,128)}`,
+    the 9 taps on the 128 lanes, and the compiler counted 1.40 GB of
+    temporaries for a function whose largest array holds 52 MB. The limit
+    is 1.5 x what the lane-dense form reads (0.062 GB)."""
+    from dexiraft_tpu.ops.upsample import upsample_flow_convex
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def weighted(flow, mask, weight):
+        return jnp.sum(upsample_flow_convex(flow, mask) * weight)
+
+    compiled = jax.jit(jax.grad(weighted, (0, 1))).lower(
+        sds((8, 46, 62, 2)), sds((8, 46, 62, 576), jnp.bfloat16),
+        sds((8, 368, 496, 2))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.093e9
+    # no large array with the taps, a sub-pixel 8 or the 2 components as
+    # its minor-most (lane) dimension: `[8,46,62,9,8,8]{3,...` and the like
+    starved = set()
+    for m in re.finditer(r"\w+\[([\d,]+)\]\{(\d+)[,:}]", compiled.as_text()):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if len(dims) >= 4 and max(dims) >= 46 and dims[int(m.group(2))] in (2, 8, 9):
+            starved.add(m.group(0))
+    assert not starved, sorted(starved)
