@@ -286,26 +286,16 @@ class LMConfig:
     # full recomputation of every decoder layer in the backward
     remat: bool = False
     # query rows of one attention block (ops/lm_attention.py) and rows
-    # of one dispatch chunk of the expert layer (models/lm/moe.py); the
-    # results do not depend on either
+    # of one dispatch chunk of the expert layer (None: the layer sizes it
+    # from the slots its held experts expect, models/lm/moe.py
+    # `dispatch_chunk`); the results do not depend on either
     attn_block: int = 1024
-    moe_chunk: int = 32_768
+    moe_chunk: Optional[int] = None
 
     def __post_init__(self):
-        for name, whole in (("heads_held", self.num_attention_heads),
-                            ("experts_held", self.n_routed_experts)):
-            held = getattr(self, name)
-            if held is None:
-                object.__setattr__(self, name, (0, whole))
-            else:
-                first, count = (int(v) for v in held)
-                if first < 0 or count < 1 or first + count > whole:
-                    raise ValueError(f"{name}={held!r} is not a range of "
-                                     f"the {whole} the model has")
-                object.__setattr__(self, name, (first, count))
-        if self.seq_len % self.attn_block and self.seq_len > self.attn_block:
-            raise ValueError(f"seq_len {self.seq_len} is not a multiple of "
-                             f"attn_block {self.attn_block}")
+        _hold(self, "heads_held", self.num_attention_heads)
+        _hold(self, "experts_held", self.n_routed_experts)
+        _whole_attention_blocks(self)
 
     @property
     def qk_head_dim(self) -> int:
@@ -331,9 +321,167 @@ def kanana2_toy(**kw) -> LMConfig:
     return LMConfig(**{**base, **kw})
 
 
+_LAYER_KINDS = ("sliding_attention", "full_attention")
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig:
+    """An AFMoE decoder (`model_type: afmoe`): gated grouped-query
+    attention with QK-norm, sliding-window layers (rotary embedding) and
+    full layers (none) mixed by `layer_types`, four RMSNorms a layer,
+    leading dense layers, then sigmoid-routed expert layers with a shared
+    expert (models/lm/, docs/lm.md). Keys and defaults are Trinity-Mini's
+    published `config.json`.
+
+    The share is `LMConfig`'s, and the key/value heads held beside it: a
+    key/value head serves `num_attention_heads // num_key_value_heads`
+    query heads, so the chips that hold its query heads each hold a copy
+    of it. `kv_heads_held` defaults to the heads that `heads_held` reads.
+
+    The modules both architectures run (the stack, the expert layer)
+    read one vocabulary, `LMConfig`'s: the properties at the end give
+    this configuration's keys under those names.
+    """
+
+    vocab_size: int = 200_192
+    hidden_size: int = 2048
+    num_hidden_layers: int = 32
+    num_dense_layers: int = 2
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_experts: int = 128
+    num_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    route_scale: float = 2.826
+    route_norm: bool = True
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 2048
+    # one of _LAYER_KINDS a layer; None: the published pattern, three
+    # sliding layers and a full one (`global_attn_every_n_layers: 4`)
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope_theta: float = 1e4
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = True
+    # assumed (the catalog row does not give it): afmoe's default
+    # `initializer_range`
+    init_std: float = 0.02
+    seq_len: int = 32_768
+    heads_held: Optional[Tuple[int, int]] = None
+    kv_heads_held: Optional[Tuple[int, int]] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    mixed_precision: bool = False
+    remat: bool = False
+    attn_block: int = 1024
+    moe_chunk: Optional[int] = None
+
+    def __post_init__(self):
+        heads, kv = self.num_attention_heads, self.num_key_value_heads
+        if heads % kv:
+            raise ValueError(f"{heads} query heads do not divide over "
+                             f"{kv} key/value heads")
+        group = heads // kv
+        _hold(self, "heads_held", heads)
+        _hold(self, "experts_held", self.num_experts)
+        first, count = self.heads_held
+        if self.kv_heads_held is None:
+            lo, hi = first // group, (first + count - 1) // group
+            object.__setattr__(self, "kv_heads_held", (lo, hi - lo + 1))
+        _hold(self, "kv_heads_held", kv)
+        kv_first, kv_count = self.kv_heads_held
+        # the op's rule, in local numbers: held query head i reads held
+        # key/value head i // (count // kv_count). True of query heads
+        # inside one key/value head, and of whole groups from a group's
+        # first head
+        per = max(count // kv_count, 1)
+        if count % kv_count or any(
+                (first + i) // group - kv_first != i // per
+                for i in range(count)):
+            raise ValueError(
+                f"heads_held={self.heads_held} with kv_heads_held="
+                f"{self.kv_heads_held}: the query heads a chip holds "
+                f"divide evenly, in order, over the key/value heads it "
+                f"holds ({group} query heads read one key/value head)")
+        kinds = self.layer_types
+        if kinds is None:
+            kinds = tuple(_LAYER_KINDS[(i + 1) % 4 == 0]
+                          for i in range(self.num_hidden_layers))
+        kinds = tuple(kinds)
+        if (len(kinds) != self.num_hidden_layers
+                or any(k not in _LAYER_KINDS for k in kinds)):
+            raise ValueError(f"layer_types={kinds!r}: one of {_LAYER_KINDS} "
+                             f"for each of {self.num_hidden_layers} layers")
+        object.__setattr__(self, "layer_types", kinds)
+        _whole_attention_blocks(self)
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """Layer `i`'s window, None for a full layer."""
+        return (self.sliding_window
+                if self.layer_types[i] == "sliding_attention" else None)
+
+    # ---- LMConfig's names for what the shared modules read ----
+    n_routed_experts = property(lambda self: self.num_experts)
+    n_shared_experts = property(lambda self: self.num_shared_experts)
+    routed_scaling_factor = property(lambda self: self.route_scale)
+    norm_topk_prob = property(lambda self: self.route_norm)
+    first_k_dense_replace = property(lambda self: self.num_dense_layers)
+    qk_head_dim = property(lambda self: self.head_dim)
+    v_head_dim = property(lambda self: self.head_dim)
+
+
+def _hold(cfg, name: str, whole: int) -> None:
+    """A `(first, count)` share of `whole`, or None for all of it."""
+    held = getattr(cfg, name)
+    if held is None:
+        object.__setattr__(cfg, name, (0, whole))
+        return
+    first, count = (int(v) for v in held)
+    if first < 0 or count < 1 or first + count > whole:
+        raise ValueError(f"{name}={held!r} is not a range of "
+                         f"the {whole} the model has")
+    object.__setattr__(cfg, name, (first, count))
+
+
+def _whole_attention_blocks(cfg) -> None:
+    if cfg.seq_len % cfg.attn_block and cfg.seq_len > cfg.attn_block:
+        raise ValueError(f"seq_len {cfg.seq_len} is not a multiple of "
+                         f"attn_block {cfg.attn_block}")
+
+
+def trinity_mini(**kw) -> AfmoeConfig:
+    """Trinity-Mini as published; `heads_held`, `kv_heads_held`,
+    `experts_held`, `vocab_size`, `num_hidden_layers`, `num_dense_layers`
+    and `layer_types` cut it to a chip's share
+    (benchmarks/configs/trinity-mini-share8.json)."""
+    return AfmoeConfig(**kw)
+
+
+def trinity_mini_toy(**kw) -> AfmoeConfig:
+    """The CPU tests' size: every mechanism, toy widths. 8 query heads
+    over 4 key/value heads, so 8 shares hold 1 query head and 2 experts
+    each and a pair of shares holds copies of one key/value head; a dense
+    layer, then one period of expert layers; a window shorter than the
+    row."""
+    base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=5,
+                num_dense_layers=1, intermediate_size=96,
+                moe_intermediate_size=32, num_experts=16,
+                num_experts_per_tok=2, num_attention_heads=8,
+                num_key_value_heads=4, head_dim=8, sliding_window=24,
+                layer_types=("sliding_attention",) * 4 + ("full_attention",),
+                seq_len=128, attn_block=32, moe_chunk=64)
+    return AfmoeConfig(**{**base, **kw})
+
+
+# the configurations of models/lm: what `family_of` and `train` take for a
+# language model
+LM_CONFIGS = (LMConfig, AfmoeConfig)
+
 # language models `train --variant` takes beside VARIANTS. Not in
 # VARIANTS: eval, serve and video have no path for them (ROADMAP.md).
-LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy}
+LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
+               "trinity-mini": trinity_mini,
+               "trinity-mini-toy": trinity_mini_toy}
 
 
 @dataclasses.dataclass(frozen=True)

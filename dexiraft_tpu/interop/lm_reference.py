@@ -1,32 +1,46 @@
-"""The plain reference of the language model: forward, loss and gradients
+"""The plain reference of the language models: forward, loss and gradients
 in `jax.numpy`, float32, under `jax.default_matmul_precision("highest")`.
 
-Written from the published `config.json` of
-kanana-2-30b-a3b-instruct-2601 (`model_type: deepseek_v3`; docs/lm.md has
-the equations), independent of models/lm: no Flax module, no kernel, no
-sorting, no blocks of attention, no recomputation. Every held expert is
-applied to every token and weighted by `w_i` where the router chose it
-and by 0 elsewhere; attention makes the full `[heads, S, S]` score
-matrix of one sequence at a time. It reads the same parameter tree as
-the system (a nested dict of arrays, named as models/lm names them), so
-both run on the same seeded weights.
+Two architectures, picked by the configuration's type. An `LMConfig`:
+written from the published `config.json` of
+kanana-2-30b-a3b-instruct-2601 (`model_type: deepseek_v3`). An
+`AfmoeConfig`: from Trinity-Mini's (`model_type: afmoe`) and, for what
+the config does not carry (the four norms and where they sit, the gate,
+the QK-norm, rotary embedding on sliding layers only, the embedding's
+scale), from the model's published modelling code (`transformers`
+`models/afmoe`; docs/lm.md has both sets of equations and what is
+`assumed`). Independent of models/lm: no Flax module, no kernel, no
+table, no sorting, no recomputation. Every held expert is applied to
+every token and weighted by `w_i` where the router chose it and by 0
+elsewhere; attention makes the dense `[heads, query rows, all keys]`
+score matrix of one sequence with its mask written out, keys and values
+repeated to the query heads. It reads the same parameter tree as the
+system (a nested dict of arrays, named as models/lm names them), so both
+run on the same seeded weights.
 
 Departures from the published model, each also in docs/lm.md:
   * `e_score_correction_bias` b = 0: the config gives no update rule for
     it, so it is held at its initial value (`bias`, if given, is added
     to the scores for the choice only, as the model does).
-  * the share: `experts_held` and `heads_held` name the experts and heads
-    this chip of a tensor- and expert-parallel group holds. The router
-    still scores and chooses over all experts; what the absent experts
-    and heads would add is left out, here as in the system. The
-    vocabulary slice is a smaller vocabulary: the embedding and the head
-    have the rows that are held and the loss is over them.
+  * the share: `experts_held`, `heads_held` and (afmoe) `kv_heads_held`
+    name the experts and heads this chip of a tensor- and
+    expert-parallel group holds. The router still scores and chooses
+    over all experts; what the absent experts and heads would add is
+    left out, here as in the system. The vocabulary slice is a smaller
+    vocabulary: the embedding and the head have the rows that are held
+    and the loss is over them.
+  * afmoe's `expert_bias` is the same buffer under another name, held
+    at 0 alike; its `load_balance_coeff` belongs to the update rule that
+    is not run.
 
 `blocked_loss_and_grads` is the same mathematics walked a sequence and
 a layer at a time (`jax.vjp` of one layer, inputs kept, layers revisited
 in reverse), for sizes at which `jax.grad` of the whole loss does not
-fit the chip. tests/test_zz_lm_reference.py holds it to `jax.grad` of
-`loss`.
+fit the chip. With `block`, an afmoe layer and the head also take a
+block of rows at a time (`_by_blocks`: a sequential map whose backward
+recomputes the block): 4 heads x 32,768 x 32,768 fp32 scores are 17 GB,
+2,048 query rows of them 1 GB. tests/test_zz_lm_reference.py holds both
+to `jax.grad` of `loss`.
 
 `dtype=jnp.bfloat16` computes everything in bf16 (weights, router,
 softmax, norm statistics, loss) at the default matmul precision: the
@@ -41,6 +55,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from dexiraft_tpu.config import AfmoeConfig
 
 Share = Optional[Tuple[int, int]]
 
@@ -69,8 +85,35 @@ def _rope(x, positions, theta):
                      axis=-1).reshape(x.shape)
 
 
+def _rope_half(x, positions, theta):
+    """Pairs (i, i + d/2) of the last axis rotated by
+    position * theta^(-2i/d): `x * cos + rotate_half(x) * sin`.
+    x [S, heads, d]; float32 angles."""
+    d = x.shape[-1]
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    ang = (positions.astype(jnp.float32)[:, None]
+           * theta ** (-2.0 * i / d))[:, None, :]
+    cos, sin = jnp.cos(ang).astype(x.dtype), jnp.sin(ang).astype(x.dtype)
+    lo, hi = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], -1)
+
+
 def _swiglu(x, p):
     return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+
+
+def _by_blocks(f, block: Optional[int], *rows):
+    """`f(*rows)` of arrays with one leading axis of rows, all at once
+    or, given `block`, `block` rows at a time one after another, each
+    recomputed in the backward. `f`'s result has the same leading axis."""
+    n = rows[0].shape[0]
+    if block is None or block >= n:
+        return f(*rows)
+    if n % block:
+        raise ValueError(f"{n} rows are not whole blocks of {block}")
+    cut = tuple(r.reshape((n // block, block) + r.shape[1:]) for r in rows)
+    out = jax.lax.map(jax.checkpoint(lambda xs: f(*xs)), cut)
+    return out.reshape((n,) + out.shape[2:])
 
 
 def attention(p, x, positions, segment_ids, cfg, heads: int):
@@ -94,6 +137,38 @@ def attention(p, x, positions, segment_ids, cfg, heads: int):
     out = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, axis=-1),
                      kv[..., nope:])
     return out.reshape(s, heads * dv) @ p["wo"]
+
+
+def gated_attention(p, x, positions, segment_ids, cfg, heads: int,
+                    kv_heads: int, window: Optional[int],
+                    block: Optional[int] = None):
+    """One sequence of afmoe's mixer: x [S, D]. `p` holds `heads` query
+    heads' columns and the `kv_heads` key/value heads they read, each
+    serving `heads // kv_heads` of them in order. `window` None: a full
+    layer, without a positional embedding."""
+    hd, eps = cfg.head_dim, cfg.rms_norm_eps
+    s = x.shape[0]
+    q = _rms_norm((x @ p["wq"]).reshape(s, heads, hd), p["q_norm"], eps)
+    k = _rms_norm((x @ p["wk"]).reshape(s, kv_heads, hd), p["k_norm"], eps)
+    v = (x @ p["wv"]).reshape(s, kv_heads, hd)
+    if window is not None:
+        q = _rope_half(q, positions, cfg.rope_theta)
+        k = _rope_half(k, positions, cfg.rope_theta)
+    k, v = (jnp.repeat(t, heads // kv_heads, axis=1) for t in (k, v))
+    t = jnp.arange(s)
+
+    def rows(q_rows, at, seg_rows):
+        scores = (jnp.einsum("qhd,khd->hqk", q_rows, k)
+                  / jnp.sqrt(jnp.asarray(hd, x.dtype)))
+        back = at[:, None] - t[None, :]
+        visible = (back >= 0) & (seg_rows[:, None] == segment_ids[None, :])
+        if window is not None:
+            visible &= back < window
+        scores = jnp.where(visible[None], scores, -jnp.inf)
+        return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, axis=-1), v)
+
+    out = _by_blocks(rows, block, q, t, segment_ids).reshape(s, heads * hd)
+    return (out * jax.nn.sigmoid(x @ p["wg"])) @ p["wo"]
 
 
 def routing(p, x, cfg, bias=None):
@@ -134,19 +209,73 @@ def layer(p, x, positions, segment_ids, cfg, dense: bool,
                    bias)
 
 
+def afmoe_layer(p, x, positions, segment_ids, cfg, index: int,
+                heads_held: Share = None, kv_heads_held: Share = None,
+                experts_held: Share = None, bias=None,
+                block: Optional[int] = None):
+    """h = x + N2(Attn(N1(x))); x' = h + N4(FFN(N3(h))); one sequence,
+    layer `index` of the layers held."""
+    eps = cfg.rms_norm_eps
+    a = gated_attention(
+        p["attn"], _rms_norm(x, p["attn_norm"], eps), positions, segment_ids,
+        cfg, (heads_held or cfg.heads_held)[1],
+        (kv_heads_held or cfg.kv_heads_held)[1], cfg.layer_window(index),
+        block)
+    h = x + _rms_norm(a, p["attn_post_norm"], eps)
+    normed = _rms_norm(h, p["ffn_norm"], eps)
+    if index < cfg.num_dense_layers:
+        f = _by_blocks(lambda rows: _swiglu(rows, p["mlp"]), block, normed)
+    else:
+        f = _by_blocks(lambda rows: moe(p["moe"], rows, cfg,
+                                        experts_held or cfg.experts_held,
+                                        bias), block, normed)
+    return h + _rms_norm(f, p["ffn_post_norm"], eps)
+
+
+def _layer_of(cfg, i: int, block: Optional[int] = None, **share):
+    """Layer `i` as `f(p, x, positions, segment_ids)`."""
+    if isinstance(cfg, AfmoeConfig):
+        return lambda p, x, pos, seg: afmoe_layer(p, x, pos, seg, cfg, i,
+                                                  block=block, **share)
+    return lambda p, x, pos, seg: layer(p, x, pos, seg, cfg,
+                                        _is_dense(cfg, i), **share)
+
+
+def _kind(cfg, i: int):
+    """What tells layer `i`'s program from another layer's."""
+    window = (cfg.layer_window(i) if isinstance(cfg, AfmoeConfig) else None)
+    return _is_dense(cfg, i), window
+
+
+def _embed_scale(cfg, dtype):
+    """afmoe's `mup_enabled`: the embedding times sqrt(hidden_size)."""
+    scaled = isinstance(cfg, AfmoeConfig) and cfg.mup_enabled
+    return jnp.asarray(cfg.hidden_size ** 0.5 if scaled else 1.0, dtype)
+
+
 def _targets(tokens, segment_ids):
     """Position t predicts token t+1 where both are in one document."""
     valid = (segment_ids[:-1] == segment_ids[1:]) & (segment_ids[:-1] > 0)
     return tokens[1:], valid
 
 
-def head_loss_sum(p, x, tokens, segment_ids, cfg):
+def head_loss_sum(p, x, tokens, segment_ids, cfg,
+                  block: Optional[int] = None):
     """Sum of the cross-entropies of one sequence's targets."""
-    logits = _rms_norm(x, p["final_norm"], cfg.rms_norm_eps) @ p["head"]
     targets, valid = _targets(tokens, segment_ids)
-    logp = jax.nn.log_softmax(logits[:-1], axis=-1)
-    picked = jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
-    return -jnp.sum(jnp.where(valid, picked, 0.0))
+
+    def rows(x_rows, targets, valid):
+        logits = (_rms_norm(x_rows, p["final_norm"], cfg.rms_norm_eps)
+                  @ p["head"])
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
+        return jnp.where(valid, picked, 0.0)
+
+    # the last position, which predicts nothing, rides along
+    last = jnp.zeros((1,), targets.dtype)
+    return -jnp.sum(_by_blocks(rows, block, x,
+                               jnp.concatenate([targets, last]),
+                               jnp.concatenate([valid, last > 0])))
 
 
 def _cast(tree, dtype):
@@ -159,10 +288,10 @@ def _is_dense(cfg, i):
 
 def hidden_states(params, tokens, positions, segment_ids, cfg, **share):
     """One sequence through the stack: [S, D] before the final norm."""
-    x = params["embed"][tokens]
+    x = params["embed"][tokens] * _embed_scale(cfg, params["embed"].dtype)
     for i in range(cfg.num_hidden_layers):
-        x = layer(params[f"layers_{i}"], x, positions, segment_ids, cfg,
-                  _is_dense(cfg, i), **share)
+        x = _layer_of(cfg, i, **share)(params[f"layers_{i}"], x, positions,
+                                       segment_ids)
     return x
 
 
@@ -202,34 +331,36 @@ def loss_and_grads(params, batch, cfg, dtype=jnp.float32, **share):
     return jax.value_and_grad(loss)(params, batch, cfg, dtype, **share)
 
 
-def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32, **share):
+def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
+                           block: Optional[int] = None, **share):
     """`loss_and_grads`, a sequence and a layer at a time: the forward
     keeps each layer's input, the backward takes `jax.vjp` of one layer
     at a time from the last to the first and adds the sequences'
-    gradients up. Each piece is jitted once and reused."""
+    gradients up. Each kind of layer is jitted once and reused. `block`:
+    rows at a time inside an afmoe layer and the head (module
+    docstring)."""
     params = _cast(params, dtype)
     n_layers = cfg.num_hidden_layers
     denom = jnp.maximum(n_targets(batch), 1).astype(dtype)
+    scale = _embed_scale(cfg, dtype)
 
-    def run_layer(dense):
-        return lambda p, x, pos, seg: layer(p, x, pos, seg, cfg, dense,
-                                            **share)
-
-    def vjp_layer(dense):
+    def vjp_of(run):
         def f(p, x, pos, seg, dy):
-            _, pull = jax.vjp(lambda p, x: run_layer(dense)(p, x, pos, seg),
-                              p, x)
+            _, pull = jax.vjp(lambda p, x: run(p, x, pos, seg), p, x)
             return pull(dy)
         return jax.jit(f)
 
-    fwd = {d: jax.jit(run_layer(d)) for d in (True, False)}
-    bwd = {d: vjp_layer(d) for d in (True, False)}
+    kinds = [_kind(cfg, i) for i in range(n_layers)]
+    runs = {k: _layer_of(cfg, kinds.index(k), block, **share)
+            for k in set(kinds)}
+    fwd = {k: jax.jit(run) for k, run in runs.items()}
+    bwd = {k: vjp_of(run) for k, run in runs.items()}
     top = {k: params[k] for k in ("final_norm", "head")}
     head = jax.jit(jax.value_and_grad(
-        lambda p, x, tok, seg: head_loss_sum(p, x, tok, seg, cfg) / denom,
-        argnums=(0, 1)))
+        lambda p, x, tok, seg: head_loss_sum(p, x, tok, seg, cfg, block)
+        / denom, argnums=(0, 1)))
     embed_grad = jax.jit(lambda tok, dx: jnp.zeros_like(
-        params["embed"]).at[tok].add(dx))
+        params["embed"]).at[tok].add(dx * scale))
 
     add = lambda acc, g: g if acc is None else jax.tree.map(jnp.add, acc, g)
     total = jnp.zeros((), jnp.float32)
@@ -238,45 +369,56 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32, **share):
         for b in range(batch["tokens"].shape[0]):
             tok, pos, seg = (batch[k][b] for k in
                              ("tokens", "positions", "segment_ids"))
-            inputs = [params["embed"][tok]]
+            inputs = [params["embed"][tok] * scale]
             for i in range(n_layers):
-                inputs.append(fwd[_is_dense(cfg, i)](
+                inputs.append(fwd[kinds[i]](
                     params[f"layers_{i}"], inputs[-1], pos, seg))
             value, (g_top, dx) = head(top, inputs.pop(), tok, seg)
             total = total + value.astype(jnp.float32)
             for k in top:
                 grads[k] = add(grads[k], g_top[k])
             for i in reversed(range(n_layers)):
-                g, dx = bwd[_is_dense(cfg, i)](
+                g, dx = bwd[kinds[i]](
                     params[f"layers_{i}"], inputs.pop(), pos, seg, dx)
+                # a layer at a time on the device too: a call's results
+                # are allocated when it is queued, and five layers'
+                # gradients queued beside the kept inputs are 2.4 GB more
+                # than the walk needs (my chip run, PR 31)
+                dx = jax.block_until_ready(dx)
                 grads[f"layers_{i}"] = add(grads[f"layers_{i}"], g)
             grads["embed"] = add(grads["embed"], embed_grad(tok, dx))
     return total, grads
 
 
 def take_share(params, cfg, heads_held: Tuple[int, int],
-               experts_held: Tuple[int, int]):
+               experts_held: Tuple[int, int], kv_heads_held: Share = None):
     """From the parameters of a model that holds everything, the tree of
     the chip that holds `heads_held` and `experts_held`: the held heads'
-    columns of `wq` and `wkvb`, their rows of `wo`, the held experts'
-    matrices. The router, the latent projection, the norms, the shared
-    experts, the embedding and the head are whole on every chip."""
-    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    h0, hn = heads_held
+    columns of `wq` and `wkvb` (afmoe: of `wq` and `wg`, and the held
+    key/value heads' columns of `wk` and `wv`), their rows of `wo`, the
+    held experts' matrices. The router, the latent projection, the
+    norms, the shared experts, the embedding and the head are whole on
+    every chip."""
     e0, en = experts_held
 
-    def heads(mat, per_head, axis):
-        lo, hi = h0 * per_head, (h0 + hn) * per_head
+    def heads(mat, per_head, axis, held=heads_held):
+        lo, hi = held[0] * per_head, (held[0] + held[1]) * per_head
         return mat[:, lo:hi] if axis == 1 else mat[lo:hi]
+
+    if isinstance(cfg, AfmoeConfig):
+        hd = cfg.head_dim
+        cut = {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
+               "wk": (hd, 1, kv_heads_held), "wv": (hd, 1, kv_heads_held)}
+    else:
+        nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        cut = {"wq": (nope + rope, 1), "wkvb": (nope + dv, 1), "wo": (dv, 0)}
 
     out = dict(params)
     for i in range(cfg.num_hidden_layers):
         lp = dict(params[f"layers_{i}"])
-        attn = dict(lp["attn"])
-        attn["wq"] = heads(attn["wq"], nope + rope, 1)
-        attn["wkvb"] = heads(attn["wkvb"], nope + dv, 1)
-        attn["wo"] = heads(attn["wo"], dv, 0)
-        lp["attn"] = attn
+        lp["attn"] = dict(lp["attn"], **{
+            k: heads(lp["attn"][k], *how) for k, how in cut.items()})
         if "moe" in lp:
             experts = dict(lp["moe"]["experts"])
             for k in ("w_gate", "w_up", "w_down"):

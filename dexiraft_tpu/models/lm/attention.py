@@ -1,5 +1,8 @@
-"""Multi-head latent attention without a query LoRA (`q_lora_rank:
-null`), for the heads this chip holds.
+"""The two mixers, for the heads this chip holds: `mixer_of` picks by the
+configuration's type.
+
+**`LatentAttention`** (`LMConfig`): multi-head latent attention without a
+query LoRA (`q_lora_rank: null`).
 
     q            = W_q x            -> per head [q_nope; q_rope]
     [c; k_rope]  = W_kva x          k_rope is shared by every head
@@ -11,6 +14,21 @@ null`), for the heads this chip holds.
 the latent projection `W_kva` and its norm are whole on every chip of
 the group. What the absent heads would add to `out` is left out: in a
 deployment it arrives with the tensor-parallel sum.
+
+**`GatedAttention`** (`AfmoeConfig`): grouped-query attention with a
+sigmoid gate on its output and an RMSNorm on every query and key head.
+
+    q = W_q x -> heads x d;  k = W_k x, v = W_v x -> kv heads x d;  g = W_g x
+    q = RMSNorm_d(q), k = RMSNorm_d(k)     one gain each, shared by the heads
+    sliding layers: q, k = rope(q), rope(k)   (half rotation, over all d)
+    full layers: no positional embedding
+    scores over the same document, earlier keys, and in sliding layers
+    fewer than `sliding_window` back;  out = W_o (softmax(scores) v * sigmoid(g))
+
+`W_q`, `W_g` and `W_o` hold the held query heads' columns (rows), `W_k`
+and `W_v` the columns of the key/value heads those read
+(`cfg.kv_heads_held`): a key/value head serves several query heads, so
+the chips that hold its query heads each hold a copy of it.
 """
 
 from __future__ import annotations
@@ -19,8 +37,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dexiraft_tpu.config import LMConfig
-from dexiraft_tpu.models.lm.layers import Weights, rms_norm, rope_interleaved
+from typing import Optional
+
+from dexiraft_tpu.config import AfmoeConfig, LMConfig
+from dexiraft_tpu.models.lm.layers import (Weights, rms_norm, rope_half,
+                                           rope_interleaved)
 from dexiraft_tpu.ops.lm_attention import document_attention
 
 
@@ -62,3 +83,44 @@ class LatentAttention(Weights):
                 scale=(nope + rope) ** -0.5, block=cfg.attn_block)
             return out.reshape(b, s, heads * dv) @ self.w(
                 "wo", (heads * dv, d))
+
+
+class GatedAttention(Weights):
+    cfg: AfmoeConfig = None
+    window: Optional[int] = None  # None: a full layer
+
+    @nn.compact
+    def __call__(self, x: jax.Array, positions: jax.Array,
+                 segment_ids: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        b, s, d = x.shape
+        heads, kv_heads, hd = (cfg.heads_held[1], cfg.kv_heads_held[1],
+                               cfg.head_dim)
+        kind = "full" if self.window is None else "window"
+        with jax.named_scope("lm/gqa/proj"):
+            q = (x @ self.w("wq", (d, heads * hd))).reshape(b, s, heads, hd)
+            k = (x @ self.w("wk", (d, kv_heads * hd))
+                 ).reshape(b, s, kv_heads, hd)
+            v = (x @ self.w("wv", (d, kv_heads * hd))
+                 ).reshape(b, s, kv_heads, hd)
+            gate = x @ self.w("wg", (d, heads * hd))
+            q, k = (rms_norm(t, self.param(name, nn.initializers.ones, (hd,),
+                                           jnp.float32), cfg.rms_norm_eps)
+                    for t, name in ((q, "q_norm"), (k, "k_norm")))
+            if self.window is not None:
+                q = rope_half(q, positions, cfg.rope_theta)
+                k = rope_half(k, positions, cfg.rope_theta)
+        with jax.named_scope(f"lm/gqa/{kind}/kernel"):
+            out = document_attention(q, k, v, segment_ids, scale=hd ** -0.5,
+                                     block=cfg.attn_block, window=self.window)
+        with jax.named_scope("lm/gqa/proj"):
+            gated = out.reshape(b, s, heads * hd) * jax.nn.sigmoid(gate)
+            return gated @ self.w("wo", (heads * hd, d))
+
+
+def mixer_of(cfg, layer: int, **kw) -> nn.Module:
+    """Layer `layer`'s attention module (named `attn`)."""
+    if isinstance(cfg, AfmoeConfig):
+        return GatedAttention(cfg=cfg, window=cfg.layer_window(layer),
+                              name="attn", **kw)
+    return LatentAttention(cfg=cfg, name="attn", **kw)

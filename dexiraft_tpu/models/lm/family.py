@@ -13,12 +13,13 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from dexiraft_tpu.config import LMConfig, TrainConfig
+from dexiraft_tpu.config import TrainConfig
 from dexiraft_tpu.models.lm.model import LM, next_token_targets
 
 
 class LMFamily:
-    def __init__(self, cfg: LMConfig, tc: TrainConfig):
+    def __init__(self, cfg: Any, tc: TrainConfig):
+        """cfg: one of config.LM_CONFIGS."""
         if tc.remat == "dots_saveable":
             raise ValueError(
                 "remat='dots_saveable' is a policy of RAFT's refinement "

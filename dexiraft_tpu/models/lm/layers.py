@@ -1,5 +1,6 @@
 """What every decoder layer shares: RMSNorm, the rotary embedding on
-interleaved pairs, the SwiGLU MLP and the fp32-master parameter."""
+interleaved pairs and on half-rotated ones, the SwiGLU MLP and the
+fp32-master parameter."""
 
 from __future__ import annotations
 
@@ -32,6 +33,21 @@ def rope_interleaved(x: jax.Array, positions: jax.Array,
     even, odd = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_half(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotate the pairs (i, i + d/2) of the last axis by
+    `position * theta^(-2i/d)`: the half-rotation layout
+    (`x * cos + rotate_half(x) * sin`). x `[B, S, heads, d]`, positions
+    `[B, S]`; the angles are fp32."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, :, None, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    lo, hi = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def normal_init(std: float):

@@ -13,7 +13,7 @@ stands in for that exchange.
 Dropless, in one program shape. A (token, choice) pair is a slot;
 `T * top_k` slots exist and any number of them, up to all, may fall on
 held experts. Slots are sorted by held expert (the others last), and
-the sorted order is cut into chunks of `cfg.moe_chunk` rows. A chunk
+the sorted order is cut into chunks of `dispatch_chunk` rows. A chunk
 gathers its tokens, runs the three grouped products of a SwiGLU with
 the chunk's own group sizes, and scatter-adds the weighted rows into the
 output. Chunk 0 always runs; the later chunks are a scan under one
@@ -25,6 +25,13 @@ token on one held expert every chunk runs. Work follows the slots, the
 shape never changes, and no slot is dropped: `moe_dropped_slots` counts
 held slots less the rows the chunks took, and stays 0.
 
+The chunk is sized from the shapes (`dispatch_chunk`): a load that
+reaches chunk 1 pays for a whole second chunk, so chunk 0 has to clear
+the expected load with room; every row of room costs its gather and its
+scatter-add whether a slot fills it or not. `cfg.moe_chunk` overrides
+the size (the tests' several chunks at toy sizes; a smaller chunk to
+save memory).
+
 `b` (`e_score_correction_bias`) is a buffer outside the gradient whose
 update rule the published config does not give; it is carried in the
 `batch_stats` collection and held at zero (docs/lm.md, `assumed`).
@@ -32,13 +39,12 @@ update rule the published config does not give; it is carried in the
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Any, Dict, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dexiraft_tpu.config import LMConfig
 from dexiraft_tpu.models.lm.layers import SwiGLU, Weights
 from dexiraft_tpu.ops.grouped import grouped_matmul
 
@@ -54,8 +60,26 @@ def route(scores: jax.Array, bias: jax.Array, top_k: int, scale: float,
     return chosen, weights * scale
 
 
+# a dispatch chunk is a whole number of these rows
+_CHUNK_ROWS = 8192
+
+
+def dispatch_chunk(slots: int, held: int, experts: int) -> int:
+    """Rows of one dispatch chunk for `slots` (token, choice) pairs when
+    `held` of `experts` experts are here: the slots the held experts
+    expect at an even balance and a third more, up to the next 8,192
+    rows, and at most every slot. kanana2's share at 4 x 8192 tokens
+    (24,576 expected): 32,768, what it has run at since PR 26 without
+    reaching chunk 1; Trinity-Mini's at 32,768 tokens (32,768 expected,
+    and at random weights a document's tokens route alike): 49,152,
+    where 40,960 still ran the overflow a few times a window and 65,536
+    cost 4.6 % in dead rows (my chip runs, PR 31)."""
+    room = -(-4 * slots * held // (3 * experts))
+    return min(slots, -(-room // _CHUNK_ROWS) * _CHUNK_ROWS)
+
+
 class RoutedExperts(Weights):
-    cfg: LMConfig = None
+    cfg: Any = None  # one of config.LM_CONFIGS
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -88,7 +112,8 @@ class RoutedExperts(Weights):
             n_held = jnp.sum(counts)
             ends = jnp.cumsum(counts)
             starts = ends - counts
-            chunk = min(cfg.moe_chunk, t * top_k)
+            chunk = min(cfg.moe_chunk or dispatch_chunk(
+                t * top_k, held, cfg.n_routed_experts), t * top_k)
             n_chunks = -(-t * top_k // chunk)
             pad = n_chunks * chunk - t * top_k
             slot_token = jnp.pad(order // top_k, (0, pad)).reshape(
@@ -166,7 +191,7 @@ class MoE(Weights):
     """Routed experts held here + the shared experts (one SwiGLU of
     `n_shared_experts * moe_intermediate_size`)."""
 
-    cfg: LMConfig = None
+    cfg: Any = None  # one of config.LM_CONFIGS
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:

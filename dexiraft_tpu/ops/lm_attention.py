@@ -1,35 +1,55 @@
-"""Causal attention within packed documents.
+"""Causal attention within packed documents, over all earlier keys or
+over a sliding window of them, with keys and values that several query
+heads may share.
 
 A row of a packed batch holds several documents; a token attends to the
-tokens of its own document that do not come after it. At 8192 positions
-the whole `[heads, S, S]` score matrix is 1 GiB a head in fp32, so it is
-never made. `document_attention` takes one of two paths, chosen from
-what it can observe (the backend and the shapes), not by a flag:
+tokens of its own document that do not come after it and, where the
+caller gives a `window`, that lie fewer than `window` positions back:
+
+    visible(i, j):  same document,  j <= i,  i - j < window
+
+At 8192 positions the whole `[heads, S, S]` score matrix is 1 GiB a head
+in fp32, at 32,768 it is 4 GiB, so it is never made. `q` has `H` heads,
+`k` and `v` have `H_kv` with `H % H_kv == 0`: query head `h` reads
+key/value head `h // (H // H_kv)`. `document_attention` takes one of two
+paths, chosen from what it can observe (the backend and the shapes), not
+by a flag:
 
 **On a TPU, whole lane-aligned blocks: a Pallas flash kernel**
-(`flash_document_attention`). Grid `(rows x groups of up to 4 heads, query
-blocks, key blocks)`, key blocks innermost. A `(bq, bk)` tile of scores
-lives in VMEM in fp32 and nowhere else; the running maximum, the running
-sum and the output accumulator are fp32 VMEM scratch. The backward is two more
-kernels (`dq`; `dk` and `dv`) that recompute a tile's scores from `q`,
-`k` and the row's log-sum-exp, the forward's only residual beside its
-output. A packed row's documents are contiguous, so the key blocks a
-query block needs are a range: from the block that holds the first token
-of the document of the query block's first row, to the diagonal
-(`block_table`; `last_q` is its transpose for `dk`/`dv`). The table is
-made in XLA from `segment_ids`, handed to the kernels by scalar prefetch,
-and does two things: the key block's index map is clamped into the
-range, so a grid step outside it fetches nothing, and the step's compute
-is under `pl.when`. Inside the range, a tile below the diagonal whose
-rows and keys all lie in one document (`full_kv`, `full_q`) skips the
-mask. `block_pair_counts` counts the visited pairs from the same table.
+(`flash_document_attention`). Grid `(rows x groups of up to 4 query
+heads, query blocks, key blocks)`, key blocks innermost. A `(bq, bk)`
+tile of scores lives in VMEM in fp32 and nowhere else; the running
+maximum, the running sum and the output accumulator are fp32 VMEM
+scratch. The backward is two more kernels (`dq`; `dk` and `dv`) that
+recompute a tile's scores from `q`, `k` and the row's log-sum-exp, the
+forward's only residual beside its output. A grid step loads one K/V
+block for the query heads of its group that share it (it is never copied
+to the query heads), and `dk`/`dv` sum over them in the step's scratch.
+
+A packed row's documents are contiguous, so the key blocks a query block
+needs are a range with two bounds (`block_table`; `last_q` is its
+transpose for `dk`/`dv`). Its first block is the later of two: the block
+that holds the first token of the document of the query block's first
+row, and the block that holds the first key inside the window of that
+row, `max(0, i*bq - window + 1) // bk`. Its last block is the diagonal.
+The table is made in XLA from `segment_ids` and the window, handed to
+the kernels by scalar prefetch, and does two things: the key block's
+index map is clamped into the range, so a grid step outside it fetches
+nothing, and the step's compute is under `pl.when`. Inside the range, a
+tile below the diagonal whose rows and keys all lie in one document and
+wholly inside the window (`full_kv`, `full_q`) skips the mask.
+`block_pair_counts` counts the visited pairs from the same table. With
+no window and `H_kv == H` the table and the kernels are what they were
+before either existed.
 
 **Elsewhere (the CPU of the tests, shapes that are not whole blocks):
 plain XLA by blocks of query rows**, the oracle of the kernel's tests.
 Query block `i` (rows `[i*block, (i+1)*block)`) meets keys
-`[0, (i+1)*block)`: the causal mask's upper blocks are left out, the
-blocks below the diagonal that hold no pair of one document are computed
-and masked. Each block is a `jax.checkpoint`.
+`[0, (i+1)*block)`, or from the block of the window's first key: the
+causal mask's upper blocks and the blocks before the window are left
+out, the blocks between that hold no visible pair are computed and
+masked. Each block is a `jax.checkpoint`. Keys and values are repeated
+to the query heads.
 
 Both: scores, the mask and the softmax statistics are fp32 whatever the
 inputs are; the probabilities are cast to `v`'s dtype for the second
@@ -59,15 +79,18 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 # ---- the XLA path ----------------------------------------------------------
 
 
-def _block(q, k, v, seg_q, seg_k, first_row, scale):
+def _block(q, k, v, seg_q, seg_k, first_row, first_key, scale, window):
     """q [G, Q, D], k [G, K, D], v [G, K, Dv], G = batch x heads; seg_q
     [G, Q], seg_k [G, K]. The block's first query is row `first_row` of
-    the sequence, the keys start at row 0."""
+    the sequence, its first key row `first_key`."""
     s = jnp.einsum("gqd,gkd->gqk", q, k,
                    preferred_element_type=jnp.float32) * scale
-    rows = first_row + jnp.arange(q.shape[1])
-    mask = ((rows[:, None] >= jnp.arange(k.shape[1])[None, :])[None]
-            & (seg_q[:, :, None] == seg_k[:, None, :]))
+    rows = (first_row + jnp.arange(q.shape[1]))[:, None]
+    keys = (first_key + jnp.arange(k.shape[1]))[None, :]
+    near = rows >= keys
+    if window is not None:
+        near &= rows - keys < window
+    mask = near[None] & (seg_q[:, :, None] == seg_k[:, None, :])
     s = jnp.where(mask, s, _MASKED)
     # the softmax written out, its row maximum behind a barrier: fused
     # with the subtraction, the chip's compiler turns the maximum into a
@@ -93,21 +116,36 @@ def _unfold(x, b):
 
 
 def xla_document_attention(q, k, v, segment_ids, *, scale: float,
-                           block: int) -> jax.Array:
+                           block: int, window: Optional[int] = None
+                           ) -> jax.Array:
     """`document_attention`'s XLA path (module docstring)."""
     b, seq, heads, _ = q.shape
     block = min(block, seq)
     if seq % block:
         raise ValueError(f"{seq} positions are not whole blocks of {block}")
+    group = _group(heads, k.shape[2])
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     q, k, v = _fold(q), _fold(k), _fold(v)
     seg = jnp.repeat(segment_ids, heads, axis=0)
-    run = jax.checkpoint(_block, static_argnums=(5, 6))
+    run = jax.checkpoint(_block, static_argnums=(5, 6, 7, 8))
     out = []
     for first in range(0, seq, block):
         end = first + block
-        out.append(run(q[:, first:end], k[:, :end], v[:, :end],
-                       seg[:, first:end], seg[:, :end], first, scale))
+        lo = (0 if window is None
+              else max(0, first - window + 1) // block * block)
+        out.append(run(q[:, first:end], k[:, lo:end], v[:, lo:end],
+                       seg[:, first:end], seg[:, lo:end], first, lo, scale,
+                       window))
     return _unfold(jnp.concatenate(out, axis=1), b)
+
+
+def _group(heads: int, kv_heads: int) -> int:
+    """Query heads that share one key/value head."""
+    if kv_heads < 1 or heads % kv_heads:
+        raise ValueError(f"{heads} query heads do not divide over "
+                         f"{kv_heads} key/value heads")
+    return heads // kv_heads
 
 
 # ---- which key blocks a query block needs ----------------------------------
@@ -130,16 +168,19 @@ class BlockTable(NamedTuple):
     ends at or before the query block's first row need no mask. For key
     block `j` the query blocks `j*bk // bq .. last_q[b, j]`, no mask from
     the first block that starts at or after the key block's end up to
-    `full_q[b, j]`."""
+    `full_q[b, j]`. Each bound is the tighter of the document's and the
+    window's."""
     first_kv: jax.Array  # [B, S // bq]
     full_kv: jax.Array   # [B, S // bq]
     last_q: jax.Array    # [B, S // bk]
     full_q: jax.Array    # [B, S // bk]
 
 
-def block_table(segment_ids: jax.Array, bq: int, bk: int) -> BlockTable:
+def block_table(segment_ids: jax.Array, bq: int, bk: int,
+                window: Optional[int] = None) -> BlockTable:
     """From `segment_ids` `[B, S]`, whose documents are contiguous (a
-    document is a run of one id: data/tokens.py packs them so)."""
+    document is a run of one id: data/tokens.py packs them so), and the
+    window, if there is one."""
     seq = segment_ids.shape[1]
     at = jnp.arange(seq, dtype=jnp.int32)
     differs = segment_ids[:, 1:] != segment_ids[:, :-1]
@@ -150,11 +191,26 @@ def block_table(segment_ids: jax.Array, bq: int, bk: int) -> BlockTable:
     end = jax.lax.cummin(
         jnp.where(jnp.concatenate([differs, edge], 1), at, seq - 1),
         axis=1, reverse=True)
-    return BlockTable(
+    table = BlockTable(
         first_kv=start[:, ::bq] // bk,
         full_kv=-(-start[:, bq - 1::bq] // bk),
         last_q=end[:, bk - 1::bk] // bq,
         full_q=(end[:, ::bk] + 1) // bq - 1)
+    if window is None:
+        return table
+    # a query block's rows are i*bq .. i*bq + bq - 1, a key block's keys
+    # j*bk .. j*bk + bk - 1. The first row's window starts the range; a
+    # tile lies wholly inside the window if its last row still sees its
+    # first key: i*bq + bq - 1 - j*bk < window
+    q0 = at[:seq // bq] * bq
+    k0 = at[:seq // bk] * bk
+    return BlockTable(
+        first_kv=jnp.maximum(table.first_kv,
+                             jnp.maximum(q0 - window + 1, 0) // bk),
+        full_kv=jnp.maximum(table.full_kv,
+                            -(-jnp.maximum(q0 + bq - window, 0) // bk)),
+        last_q=jnp.minimum(table.last_q, (k0 + bk + window - 2) // bq),
+        full_q=jnp.minimum(table.full_q, (k0 + window) // bq - 1))
 
 
 def _diagonal(i, bq: int, bk: int):
@@ -162,12 +218,13 @@ def _diagonal(i, bq: int, bk: int):
     return (i * bq + bq - 1) // bk
 
 
-def block_pair_counts(segment_ids: jax.Array, bq: int, bk: int
+def block_pair_counts(segment_ids: jax.Array, bq: int, bk: int,
+                      window: Optional[int] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """(visited, causal), summed over the rows: the (query block, key
     block) pairs the kernel's grid computes, and those of the causal
     triangle."""
-    first = block_table(segment_ids, bq, bk).first_kv
+    first = block_table(segment_ids, bq, bk, window).first_kv
     diag = _diagonal(jnp.arange(first.shape[1], dtype=jnp.int32), bq, bk)
     return (jnp.sum(diag[None] - first + 1),
             jnp.sum(diag + 1) * first.shape[0])
@@ -175,21 +232,27 @@ def block_pair_counts(segment_ids: jax.Array, bq: int, bk: int
 
 # ---- the kernels -----------------------------------------------------------
 #
-# A grid step holds one tile's blocks for `hb` heads of one row: the heads
-# share the row's table and its mask, and a step's fixed cost (0.24 us,
-# beside 1.7 us of work a head and 512 x 512 tile; my chip run, PR 27) is
-# paid once for them. Refs are `[hb, rows, width]`; a loop walks the heads.
+# A grid step holds one tile's blocks for `hb` query heads of one row: the
+# heads share the row's table and its mask, and a step's fixed cost (0.24
+# us, beside 1.7 us of work a head and 512 x 512 tile; my chip run, PR 27)
+# is paid once for them. Query-side refs are `[hb, rows, width]`, key-side
+# refs `[hb // rep, rows, width]` where `rep` query heads of the step share
+# a key/value head (1: every head has its own); a loop walks the query
+# heads.
 
 
-def _mask(seg_col, seg_row, a_first, b_first, shape, a_is_query):
-    """`[A, B]` bool: the pair is of one document and the key does not
-    come after the query. seg_col `[A, 128]` (the ids of the tile's rows
+def _mask(seg_col, seg_row, a_first, b_first, shape, a_is_query, window):
+    """`[A, B]` bool: the pair is of one document, the key does not come
+    after the query and, under a window, lies fewer than `window`
+    positions before it. seg_col `[A, 128]` (the ids of the tile's rows
     down the sublanes, the same in every lane), seg_row `[1, B]`;
     a_first, b_first: the tile's first positions."""
     a_at = a_first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     b_at = b_first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    causal = a_at >= b_at if a_is_query else a_at <= b_at
-    return causal & (_wide(seg_col, shape[1]) == seg_row)
+    near = a_at >= b_at if a_is_query else a_at <= b_at
+    if window is not None:
+        near &= (a_at - b_at if a_is_query else b_at - a_at) < window
+    return near & (_wide(seg_col, shape[1]) == seg_row)
 
 
 def _scores(a, b, mask, scale):
@@ -209,6 +272,11 @@ def _wide(col, n: int):
     return jnp.tile(col, reps)[..., :n]
 
 
+def _shared(h, rep: int):
+    """The step's key/value head that its query head `h` reads."""
+    return h if rep == 1 else h // rep
+
+
 def _each_head(hb: int, tile, visited, full, make_mask):
     """`tile(h, mask)` for the step's heads, under `pl.when`: without a
     mask where the tile is `full`, with the row's on the range's other
@@ -221,7 +289,7 @@ def _each_head(hb: int, tile, visited, full, make_mask):
 
 
 def _key_blocks_of(first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq,
-                   bk):
+                   bk, window):
     """`_each_head`'s (visited, full, make_mask) for the kernels whose
     grid is (g, query block i, key block j)."""
     g, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -229,12 +297,12 @@ def _key_blocks_of(first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq,
     return ((j >= first_ref[at]) & (j <= _diagonal(i, bq, bk)),
             (j >= full_ref[at]) & (j * bk + bk - 1 <= i * bq),
             lambda: _mask(segq_ref[...], segk_ref[...], i * bq, j * bk,
-                          (bq, bk), True))
+                          (bq, bk), True, window))
 
 
 def _fwd_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref,
                 o_ref, lse_ref, m_ref, l_ref, acc_ref, *, steps_a_row, scale,
-                bq, bk):
+                bq, bk, window, rep):
     j = pl.program_id(2)
     d_v = acc_ref.shape[-1]
 
@@ -245,7 +313,8 @@ def _fwd_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile(h, mask):
-        s = _scores(q_ref[h], k_ref[h], mask, scale)
+        hk = _shared(h, rep)
+        s = _scores(q_ref[h], k_ref[hk], mask, scale)
         m_prev = m_ref[h]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # a row whose keys in this tile are all masked adds exp(0) = 1 a
@@ -256,11 +325,12 @@ def _fwd_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref,
         l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[h] = m_next
         acc_ref[h] = _wide(alpha, d_v) * acc_ref[h] + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[h],
+            p.astype(v_ref.dtype), v_ref[hk],
             preferred_element_type=jnp.float32)
 
     _each_head(q_ref.shape[0], tile, *_key_blocks_of(
-        first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq, bk))
+        first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq, bk,
+        window))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -271,7 +341,7 @@ def _fwd_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, segq_ref, segk_ref,
 
 def _dq_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                delta_ref, segq_ref, segk_ref, dq_ref, acc_ref, *,
-               steps_a_row, scale, bq, bk):
+               steps_a_row, scale, bq, bk, window, rep):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -279,17 +349,19 @@ def _dq_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile(h, mask):
-        k = k_ref[h]
+        hk = _shared(h, rep)
+        k = k_ref[hk]
         p = jnp.exp(_scores(q_ref[h], k, mask, scale)
                     - _wide(lse_ref[h], bk))
-        dp = jax.lax.dot_general(do_ref[h], v_ref[h], _NT,
+        dp = jax.lax.dot_general(do_ref[h], v_ref[hk], _NT,
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - _wide(delta_ref[h], bk))
         acc_ref[h] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32)
 
     _each_head(q_ref.shape[0], tile, *_key_blocks_of(
-        first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq, bk))
+        first_ref, full_ref, segq_ref, segk_ref, steps_a_row, bq, bk,
+        window))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -298,9 +370,11 @@ def _dq_kernel(first_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _dkv_kernel(last_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 delta_ref, segq_ref, segk_ref, dk_ref, dv_ref, dk_acc,
-                dv_acc, *, steps_a_row, scale, bq, bk):
+                dv_acc, *, steps_a_row, scale, bq, bk, window, rep):
     """The transposed tile: keys down the sublanes, queries along the
-    lanes, so the row statistics are rows and no matmul transposes."""
+    lanes, so the row statistics are rows and no matmul transposes. The
+    `rep` query heads that share a key/value head add into its one
+    accumulator."""
     g, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     at = (g // steps_a_row) * pl.num_programs(1) + j
 
@@ -310,21 +384,22 @@ def _dkv_kernel(last_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def tile(h, mask):
+        hk = _shared(h, rep)
         q, do = q_ref[h], do_ref[h]
-        p = jnp.exp(_scores(k_ref[h], q, mask, scale) - lse_ref[h])
-        dv_acc[h] += jnp.dot(p.astype(do.dtype), do,
-                             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v_ref[h], do, _NT,
+        p = jnp.exp(_scores(k_ref[hk], q, mask, scale) - lse_ref[h])
+        dv_acc[hk] += jnp.dot(p.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[hk], do, _NT,
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta_ref[h])
-        dk_acc[h] += jnp.dot(ds.astype(q.dtype), q,
-                             preferred_element_type=jnp.float32)
+        dk_acc[hk] += jnp.dot(ds.astype(q.dtype), q,
+                              preferred_element_type=jnp.float32)
 
     _each_head(q_ref.shape[0], tile,
                (i >= (j * bk) // bq) & (i <= last_ref[at]),
                (i * bq >= j * bk + bk - 1) & (i <= full_ref[at]),
                lambda: _mask(segk_ref[...], segq_ref[...], j * bk, i * bq,
-                             (bk, bq), False))
+                             (bk, bq), False, window))
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _():
@@ -341,25 +416,50 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 
 
 class _Static(NamedTuple):
-    heads: int  # of a row: q, k, v are [rows x heads, S, D]
+    heads: int  # query heads of a row: q is [rows x heads, S, D]
     scale: float
     bq: int
     bk: int
     interpret: bool
+    kv_heads: Optional[int] = None  # of a row; None: as many as `heads`
+    window: Optional[int] = None
+
+    @property
+    def group(self) -> int:
+        """Query heads that share one key/value head."""
+        return _group(self.heads, self.kv_heads or self.heads)
 
     @property
     def hb(self) -> int:
-        """Heads a grid step holds: the largest divisor of `heads` up to
-        `_HEADS_A_STEP`."""
+        """Query heads a grid step holds: the largest divisor of `heads`
+        up to `_HEADS_A_STEP` that holds whole groups or lies inside
+        one."""
         return max(n for n in range(1, _HEADS_A_STEP + 1)
-                   if self.heads % n == 0)
+                   if self.heads % n == 0
+                   and (n % self.group == 0 or self.group % n == 0))
+
+    @property
+    def rep(self) -> int:
+        """Query heads of a step that share a key/value head."""
+        return min(self.group, self.hb)
+
+    @property
+    def hkv(self) -> int:
+        """Key/value heads a grid step holds."""
+        return self.hb // self.rep
+
+    def kv_step(self, g):
+        """Grid step `g`'s block along the folded key/value heads, in
+        blocks of `hkv`."""
+        return g if self.hb % self.group == 0 else g * self.hb // self.group
 
 
 def _call(kernel, st: _Static, name, grid, prefetch, in_specs, inputs,
           out_specs, out_shape, scratch):
     return pl.pallas_call(
         functools.partial(kernel, steps_a_row=st.heads // st.hb,
-                          scale=st.scale, bq=st.bq, bk=st.bk),
+                          scale=st.scale, bq=st.bq, bk=st.bk,
+                          window=st.window, rep=st.rep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
@@ -377,7 +477,7 @@ def _query_major_specs(st: _Static, nq, d_qk, d_v):
     block j), g over rows x groups of `hb` heads: q-side blocks follow
     `i`, key-side blocks follow `j` clamped into the range the table
     gives."""
-    hb, bq, bk = st.hb, st.bq, st.bk
+    hb, hkv, bq, bk = st.hb, st.hkv, st.bq, st.bk
     steps_a_row = st.heads // hb
 
     def kv(g, i, j, first_ref, full_ref):
@@ -387,8 +487,10 @@ def _query_major_specs(st: _Static, nq, d_qk, d_v):
     spec = pl.BlockSpec
     return dict(
         q=spec((hb, bq, d_qk), lambda g, i, j, *_: (g, i, 0)),
-        k=spec((hb, bk, d_qk), lambda g, i, j, *t: (g, kv(g, i, j, *t), 0)),
-        v=spec((hb, bk, d_v), lambda g, i, j, *t: (g, kv(g, i, j, *t), 0)),
+        k=spec((hkv, bk, d_qk),
+               lambda g, i, j, *t: (st.kv_step(g), kv(g, i, j, *t), 0)),
+        v=spec((hkv, bk, d_v),
+               lambda g, i, j, *t: (st.kv_step(g), kv(g, i, j, *t), 0)),
         o=spec((hb, bq, d_v), lambda g, i, j, *_: (g, i, 0)),
         stat=spec((hb, bq, _LANES), lambda g, i, j, *_: (g, i, 0)),
         seg_q=spec((None, bq, _LANES),
@@ -451,7 +553,12 @@ def _backward(st: _Static, q, k, v, segment_ids, table: BlockTable, o, lse,
                         last_ref[(g_ // steps_a_row) * nk + j])
 
     spec = pl.BlockSpec
-    key_side = lambda d: spec((hb, bk, d), lambda g_, j, i, *_: (g_, j, 0))
+    hkv = st.hkv
+    key_side = lambda d: spec(
+        (hkv, bk, d), lambda g_, j, i, *_: (st.kv_step(g_), j, 0))
+    # a step writes the sums over its own query heads: one block a step
+    key_out = lambda d: spec((hkv, bk, d), lambda g_, j, i, *_: (g_, j, 0))
+    steps = g // hb
     query_side = lambda d: spec(
         (hb, bq, d), lambda g_, j, i, *t: (g_, qi(g_, j, i, *t), 0))
     row = lambda: spec((hb, 1, bq),
@@ -466,11 +573,17 @@ def _backward(st: _Static, q, k, v, segment_ids, table: BlockTable, o, lse,
          spec((None, bk, _LANES),
               lambda g_, j, i, *_: (g_ // steps_a_row, j, 0))],
         (q, k, v, do, lse[:, None, :], delta[:, None, :], seg_row, seg_col),
-        [key_side(d_qk), key_side(d_v)],
-        [jax.ShapeDtypeStruct(k.shape, k.dtype),
-         jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [pltpu.VMEM((hb, bk, d_qk), jnp.float32),
-         pltpu.VMEM((hb, bk, d_v), jnp.float32)])
+        [key_out(d_qk), key_out(d_v)],
+        [jax.ShapeDtypeStruct((steps * hkv, seq, d_qk), k.dtype),
+         jax.ShapeDtypeStruct((steps * hkv, seq, d_v), v.dtype)],
+        [pltpu.VMEM((hkv, bk, d_qk), jnp.float32),
+         pltpu.VMEM((hkv, bk, d_v), jnp.float32)])
+    if st.group > hb:
+        # a key/value head's query heads span `group // hb` steps, which
+        # lie side by side: their sums are added here
+        dk, dv = (jnp.sum(x.reshape(k.shape[0], st.group // hb, seq, -1)
+                          .astype(jnp.float32), axis=1).astype(x.dtype)
+                  for x in (dk, dv))
     return dq, dk, dv
 
 
@@ -494,6 +607,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_document_attention(q, k, v, segment_ids, *, scale: float,
+                             window: Optional[int] = None,
                              interpret: bool = False) -> jax.Array:
     """`document_attention`'s kernel path (module docstring), for shapes
     `kernel_blocks` takes. `interpret=True` runs the kernels in Pallas's
@@ -503,22 +617,29 @@ def flash_document_attention(q, k, v, segment_ids, *, scale: float,
     if blocks is None:
         raise ValueError(f"no kernel for {seq} positions of widths {d_qk} "
                          f"and {v.shape[-1]}")
-    st = _Static(heads, float(scale), *blocks, interpret)
+    st = _Static(heads, float(scale), *blocks, interpret, k.shape[2], window)
     out = _flash(st, _fold(q), _fold(k), _fold(v), segment_ids,
-                 block_table(segment_ids, *blocks))
+                 block_table(segment_ids, *blocks, window))
     return _unfold(out, b)
 
 
 def document_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        segment_ids: jax.Array, *, scale: float,
-                       block: int) -> jax.Array:
+                       block: int, window: Optional[int] = None
+                       ) -> jax.Array:
     """softmax(q k^T * scale) v over the earlier tokens of the same
-    document. q, k `[B, S, H, D]`, v `[B, S, H, Dv]`, segment_ids
-    `[B, S]` (pad positions share id 0 and see each other: their output
-    is never read). Returns `[B, S, H, Dv]` in v's dtype. `block` is the
-    XLA path's block of query rows; the kernel's blocks are its own."""
+    document, all of them or those fewer than `window` positions back.
+    q `[B, S, H, D]`, k `[B, S, H_kv, D]`, v `[B, S, H_kv, Dv]` with
+    `H % H_kv == 0` (query head h reads key/value head h // (H // H_kv)),
+    segment_ids `[B, S]` (pad positions share id 0 and see each other:
+    their output is never read). Returns `[B, S, H, Dv]` in v's dtype.
+    `block` is the XLA path's block of query rows; the kernel's blocks
+    are its own."""
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} positions holds no key")
     if (jax.default_backend() == "tpu"
             and kernel_blocks(q.shape[1], q.shape[-1], v.shape[-1])):
-        return flash_document_attention(q, k, v, segment_ids, scale=scale)
+        return flash_document_attention(q, k, v, segment_ids, scale=scale,
+                                        window=window)
     return xla_document_attention(q, k, v, segment_ids, scale=scale,
-                                  block=block)
+                                  block=block, window=window)

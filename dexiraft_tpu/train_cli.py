@@ -11,6 +11,11 @@ one stage; presets supply the per-stage hyperparameters:
   python -m dexiraft_tpu train --variant kanana2 --tokens docs.npz \
       --layers 6 --heads_held 0 4 --experts_held 0 16 --vocab_size 16032 \
       --batch_size 4 --precision bf16 --remat        (docs/lm.md)
+  python -m dexiraft_tpu train --variant trinity-mini --tokens docs.npz \
+      --layers 5 --dense_layers 1 --layer_types sliding_attention \
+      sliding_attention sliding_attention sliding_attention full_attention \
+      --heads_held 0 4 --experts_held 0 16 --vocab_size 25024 \
+      --batch_size 1 --precision bf16 --remat
 
 The loop is the reference's (train.py:163-215) re-shaped for TPU: one
 jitted sharded step (forward + loss + backward + optimizer), batches
@@ -20,9 +25,10 @@ sharded over the data mesh axis, VAL_FREQ checkpoint+validate, final save.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
@@ -32,7 +38,7 @@ from dexiraft_tpu.config import (
     CORR_IMPLS,
     LM_VARIANTS,
     VARIANTS,
-    LMConfig,
+    LM_CONFIGS,
     RAFTConfig,
     TrainConfig,
 )
@@ -69,26 +75,41 @@ def build_parser() -> argparse.ArgumentParser:
                    default="none", help="stage hyperparameter preset")
     p.add_argument("--variant", default="v1",
                    choices=sorted(VARIANTS) + sorted(LM_VARIANTS),
-                   help="v1..v5: RAFT; kanana2: the language model of "
-                        "models/lm (docs/lm.md), trained on --tokens")
+                   help="v1..v5: RAFT; kanana2, trinity-mini: the language "
+                        "models of models/lm (docs/lm.md), trained on "
+                        "--tokens")
     # the language model's own flags (refused for the RAFT variants)
     p.add_argument("--tokens", default=None,
-                   help="kanana2: token file (.npz of `tokens` and "
+                   help="language models: token file (.npz of `tokens` and "
                         "`lengths`, data/tokens.py), packed first-fit "
                         "into rows of --seq_len")
     p.add_argument("--seq_len", type=int, default=None,
-                   help="kanana2: positions a row (default 8192)")
+                   help="language models: positions a row (default: "
+                        "kanana2 8192, trinity-mini 32768)")
     p.add_argument("--layers", type=int, default=None,
-                   help="kanana2: decoder layers held (default: all 48)")
+                   help="language models: decoder layers held (default: "
+                        "all)")
+    p.add_argument("--dense_layers", type=int, default=None,
+                   help="language models: of them, the leading dense "
+                        "layers (default: as published)")
+    p.add_argument("--layer_types", nargs="+", default=None,
+                   choices=["sliding_attention", "full_attention"],
+                   help="trinity-mini: each held layer's attention "
+                        "(default: three sliding, one full, repeated)")
     p.add_argument("--vocab_size", type=int, default=None,
-                   help="kanana2: rows of the vocabulary held")
+                   help="language models: rows of the vocabulary held")
     p.add_argument("--heads_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="kanana2: the attention heads this chip holds of "
-                        "a tensor-parallel group (default: all)")
+                   help="language models: the attention (query) heads this "
+                        "chip holds of a tensor-parallel group (default: "
+                        "all)")
+    p.add_argument("--kv_heads_held", type=int, nargs=2, default=None,
+                   metavar=("FIRST", "COUNT"),
+                   help="trinity-mini: the key/value heads this chip holds "
+                        "(default: those its query heads read)")
     p.add_argument("--experts_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="kanana2: the routed experts this chip holds of "
+                   help="language models: the routed experts this chip holds of "
                         "an expert-parallel group (default: all)")
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
@@ -309,8 +330,13 @@ _RAFT_ONLY = ("stage", "preset", "small", "mixed_precision", "corr_impl",
               "dropout", "image_size", "gamma", "iters", "add_noise",
               "validation", "records_dir", "edge_root", "edge_sum_fusion",
               "fsdp", "elastic", "join")
-_LM_ONLY = ("tokens", "seq_len", "layers", "vocab_size", "heads_held",
-            "experts_held")
+_LM_ONLY = ("tokens", "seq_len", "layers", "dense_layers", "layer_types",
+            "vocab_size", "heads_held", "kv_heads_held", "experts_held")
+# of them, the flags one architecture has and the other has not, by the
+# configuration's field
+_LM_FIELDS = {"dense_layers": ("first_k_dense_replace", "num_dense_layers"),
+              "layer_types": ("layer_types",),
+              "kv_heads_held": ("kv_heads_held",)}
 
 
 def _refuse_given(args, names, why: str) -> None:
@@ -321,19 +347,35 @@ def _refuse_given(args, names, why: str) -> None:
                          f"{why}")
 
 
-def resolve_lm_configs(args) -> "tuple[LMConfig, TrainConfig]":
+def resolve_lm_configs(args) -> "tuple[Any, TrainConfig]":
     _refuse_given(args, _RAFT_ONLY,
                   f"belong(s) to the RAFT variants; --variant "
                   f"{args.variant} is a language model (docs/lm.md: "
-                  "--tokens, --seq_len, --layers, --vocab_size, "
-                  "--heads_held, --experts_held, --remat for whole layers)")
+                  "--tokens, --seq_len, --layers, --dense_layers, "
+                  "--layer_types, --vocab_size, --heads_held, "
+                  "--kv_heads_held, --experts_held, --remat for whole "
+                  "layers)")
     if not args.tokens:
         raise SystemExit(f"train: --variant {args.variant} needs --tokens")
+    make = LM_VARIANTS[args.variant]
+    fields = {f.name for f in dataclasses.fields(make())}
     model = {k: v for k, v in (
         ("seq_len", args.seq_len), ("num_hidden_layers", args.layers),
         ("vocab_size", args.vocab_size), ("heads_held", args.heads_held),
         ("experts_held", args.experts_held)) if v is not None}
-    cfg = LM_VARIANTS[args.variant](remat=args.remat, **model)
+    for flag, names in _LM_FIELDS.items():
+        value = getattr(args, flag)
+        if value is None:
+            continue
+        name = next((n for n in names if n in fields), None)
+        if name is None:
+            raise SystemExit(f"train: --{flag} is not a flag of --variant "
+                             f"{args.variant}")
+        model[name] = tuple(value) if isinstance(value, list) else value
+    try:
+        cfg = make(remat=args.remat, **model)
+    except ValueError as e:
+        raise SystemExit(f"train: {e}")
     tc = TrainConfig(
         name=args.name or args.variant, stage="tokens", clip=args.clip,
         precision=args.precision, accum_steps=args.accum_steps,
@@ -348,7 +390,8 @@ def resolve_lm_configs(args) -> "tuple[LMConfig, TrainConfig]":
 def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
     if args.variant in LM_VARIANTS:
         return resolve_lm_configs(args)
-    _refuse_given(args, _LM_ONLY, "belong(s) to --variant kanana2")
+    _refuse_given(args, _LM_ONLY, "belong(s) to the language models "
+                  "(--variant kanana2, trinity-mini)")
     if args.stage is None:
         raise SystemExit("train: --stage is required for --variant "
                          f"{args.variant}")
@@ -374,7 +417,6 @@ def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
     else:
         base = TrainConfig(stage=args.stage)
 
-    import dataclasses
     overrides: Dict = dict(
         stage=args.stage,
         clip=args.clip,
@@ -486,11 +528,13 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
 
     if args.compile_cache:
         enable_persistent_cache()
-    is_lm = isinstance(cfg, LMConfig)
+    is_lm = isinstance(cfg, LM_CONFIGS)
     if is_lm:
         device_banner("train", model=args.variant, mesh=dict(mesh.shape),
                       heads_held=cfg.heads_held,
-                      experts_held=cfg.experts_held)
+                      experts_held=cfg.experts_held,
+                      **({"kv_heads_held": cfg.kv_heads_held}
+                         if hasattr(cfg, "kv_heads_held") else {}))
     else:
         device_banner("train", corr_impl=cfg.corr_impl,
                       fused_update=cfg.fused_update, mesh=dict(mesh.shape),

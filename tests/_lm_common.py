@@ -1,11 +1,11 @@
-"""Shared by the tests/test_zz_lm_*.py files: the toy configuration, a
-packed batch and seeded weights of O(1) scale."""
+"""Shared by the tests/test_zz_lm_*.py files: the toy configurations of
+both architectures, a packed batch and seeded weights of O(1) scale."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dexiraft_tpu.config import TrainConfig, kanana2_toy
+from dexiraft_tpu.config import TrainConfig, kanana2_toy, trinity_mini_toy
 from dexiraft_tpu.train.family import family_of
 
 
@@ -42,5 +42,12 @@ def rel(a, b):
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-def toy(**kw):
-    return kanana2_toy(**kw)
+ARCHS = {"kanana2": kanana2_toy, "trinity": trinity_mini_toy}
+# a share of each toy: experts 2-5; kanana's heads 1-2, trinity's query
+# heads 2-3, which read key/value head 1
+SHARES = {"kanana2": dict(experts_held=(2, 4), heads_held=(1, 2)),
+          "trinity": dict(experts_held=(2, 4), heads_held=(2, 2))}
+
+
+def toy(arch="kanana2", **kw):
+    return ARCHS[arch](**kw)

@@ -154,6 +154,44 @@ def test_lm_attention_kernel_compiles_for_v5e(chip, which):
     assert "tpu_custom_call" in text and name in text
 
 
+# `trinity-train-pack32k`: 1 row x 4 query heads over 1 key/value head,
+# 32,768 positions, width 128; a sliding-window layer and a full one
+TR_G, TR_KV, TR_S, TR_D, TR_WINDOW = 4, 1, 32768, 128, 2048
+
+
+@pytest.mark.parametrize("window", [TR_WINDOW, None], ids=["window", "full"])
+@pytest.mark.parametrize("which", ["forward", "dq", "dkv"])
+def test_lm_attention_kernel_with_shared_heads_compiles_for_v5e(chip, which,
+                                                                window):
+    """The same three kernels at the second cell's shapes: the step's 4
+    query heads read one K/V block, `dk`/`dv` sum over them in scratch,
+    and the table carries the window's bound."""
+    from dexiraft_tpu.ops import lm_attention as la
+
+    bq, bk = la.kernel_blocks(TR_S, TR_D, TR_D)
+    st = la._Static(TR_G, TR_D ** -0.5, bq, bk, False, TR_KV, window)
+    assert (st.hb, st.rep, st.hkv) == (4, 4, 1)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    q, kv = sds((TR_G, TR_S, TR_D)), sds((TR_KV, TR_S, TR_D))
+    seg = sds((1, TR_S), jnp.int32)
+    table = lambda s: la.block_table(s, bq, bk, window)  # noqa: E731
+    if which == "forward":
+        text = _compiled_text(
+            lambda q, k, v, s: la._forward(st, q, k, v, s, table(s)),
+            q, kv, kv, seg)
+    else:
+        text = _compiled_text(
+            lambda q, k, v, s, o, lse, do: la._backward(
+                st, q, k, v, s, table(s), o, lse, do),
+            q, kv, kv, seg, q, sds((TR_G, TR_S), jnp.float32), q)
+    name = {"forward": "lm_attention_fwd", "dq": "lm_attention_dq",
+            "dkv": "lm_attention_dkv"}[which]
+    assert "tpu_custom_call" in text and name in text
+
+
 # ---- the convex upsample (ops/upsample.py) --------------------------------
 
 def test_convex_upsample_is_lane_dense_for_v5e(chip):

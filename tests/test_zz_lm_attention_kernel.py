@@ -160,6 +160,140 @@ def test_latent_attention_on_the_kernel_matches_the_reference(
         assert _rel(a, b) < 1e-4, (jax.tree_util.keystr(path), _rel(a, b))
 
 
+@pytest.mark.parametrize("kind", ["sliding", "full"])
+def test_gated_attention_on_the_kernel_matches_the_reference(
+        blocks, monkeypatch, kind):
+    """The second mixer on the kernel (4 query heads over 1 key/value
+    head, the cell's share; gate, QK-norm, rotary embedding on the
+    sliding layer only) against the plain reference's: the output and
+    the gradients of the input and of every weight, fp32."""
+    import dexiraft_tpu.models.lm.attention as attention
+    from dexiraft_tpu.config import trinity_mini_toy
+    from dexiraft_tpu.interop import lm_reference as ref
+
+    monkeypatch.setattr(
+        attention, "document_attention",
+        lambda q, k, v, seg, *, scale, block, window:
+        la.flash_document_attention(q, k, v, seg, scale=scale, window=window,
+                                    interpret=True))
+    cfg = trinity_mini_toy(head_dim=128, seq_len=S, attn_block=128,
+                           num_attention_heads=4, num_key_value_heads=1,
+                           sliding_window=200)
+    window = cfg.sliding_window if kind == "sliding" else None
+    seg_row = LAYOUTS["inside_and_across"][0]
+    seg = jnp.asarray(seg_row[None])
+    starts = np.maximum.accumulate(np.where(
+        np.r_[True, seg_row[1:] != seg_row[:-1]], np.arange(S), 0))
+    pos = jnp.asarray((np.arange(S) - starts)[None], jnp.int32)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=(1, S, cfg.hidden_size)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    module = attention.GatedAttention(cfg=cfg, window=window,
+                                      dtype=jnp.float32, init_std=0.2)
+    params = module.init(jax.random.PRNGKey(0), x, pos, seg)["params"]
+
+    def ours(p, x):
+        return jnp.sum(module.apply({"params": p}, x, pos, seg) * w)
+
+    def plain(p, x):
+        with jax.default_matmul_precision("highest"):
+            return jnp.sum(ref.gated_attention(p, x[0], pos[0], seg[0], cfg,
+                                               4, 1, window) * w[0])
+
+    got = jax.value_and_grad(ours, argnums=(0, 1))(params, x)
+    want = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
+    assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got[1])[0],
+                            jax.tree.leaves(want[1])):
+        assert _rel(a, b) < 1e-4, (jax.tree_util.keystr(path), _rel(a, b))
+
+
+# ---- a window, and keys and values that query heads share ------------------
+
+WINDOWS = {"none": None, "under_a_block": 48, "not_a_multiple_of_a_block": 200,
+           "over_the_longest_document": 400}
+GQA_HEADS, GQA_D = 4, 128
+
+
+def _visible(seg_row, window):
+    """[S, S] bool, the three terms written out: query r may attend to
+    key c."""
+    t = np.arange(S)
+    back = t[:, None] - t[None, :]
+    near = back >= 0 if window is None else (back >= 0) & (back < window)
+    return near & (seg_row[:, None] == seg_row[None, :])
+
+
+def _dense_oracle(q, k, v, seg, scale, window):
+    """softmax over the visible keys of the whole `[S, S]` score matrix,
+    keys and values repeated to the query heads; no blocks, no table."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    mask = jnp.asarray(np.stack([_visible(r, window) for r in seg]))
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _gqa_inputs(rows, heads, kv_heads, seed=3):
+    rng = np.random.default_rng(seed)
+    arr = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    return (arr(rows, S, heads, GQA_D), arr(rows, S, kv_heads, GQA_D),
+            arr(rows, S, kv_heads, GQA_D), arr(rows, S, heads, GQA_D))
+
+
+def _assert_matches_the_dense_oracle(path, window, heads, kv_heads,
+                                     layout="inside_and_across"):
+    seg_np = np.stack(LAYOUTS[layout])
+    seg = jnp.asarray(seg_np)
+    q, k, v, w = _gqa_inputs(len(seg_np), heads, kv_heads)
+    scale = GQA_D ** -0.5
+    if path == "kernel":
+        fn = lambda *a: la.flash_document_attention(  # noqa: E731
+            *a, seg, scale=scale, window=window, interpret=True)
+    else:
+        fn = lambda *a: la.document_attention(  # noqa: E731
+            *a, seg, scale=scale, block=128, window=window)
+    got = _out_and_grads(fn, q, k, v, w)
+    want = _out_and_grads(
+        lambda *a: _dense_oracle(*a, seg_np, scale, window), q, k, v, w)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        assert _rel(a, b) < TOL["fp32"], (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("kv_heads", [1, GQA_HEADS])
+@pytest.mark.parametrize("window", list(WINDOWS))
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_window_and_shared_heads_match_the_dense_oracle(blocks, path, window,
+                                                        kv_heads):
+    """Both paths against the whole masked score matrix: out, dq, and
+    dk, dv summed over the query heads that share a key/value head. The
+    longest document of the layout is 200 positions."""
+    _assert_matches_the_dense_oracle(path, WINDOWS[window], GQA_HEADS,
+                                     kv_heads)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1), (2, 1)])
+def test_kernel_steps_hold_whole_groups_or_lie_inside_one(blocks, heads,
+                                                          kv_heads):
+    """2 key/value heads a step; one head's 8 query heads over two steps
+    whose dk, dv are added outside the kernel; a step of 2 heads."""
+    st = la._Static(heads, 1.0, 128, 128, True, kv_heads, None)
+    assert (st.hb, st.rep, st.hkv) == {(4, 2): (4, 2, 2), (8, 1): (4, 4, 1),
+                                       (2, 1): (2, 2, 1)}[heads, kv_heads]
+    _assert_matches_the_dense_oracle("kernel", 200, heads, kv_heads,
+                                     layout="block_aligned_two_rows")
+
+
+def test_heads_that_do_not_divide_are_refused():
+    q, k, v, _ = _gqa_inputs(1, 4, 3)
+    with pytest.raises(ValueError, match="do not divide"):
+        la.document_attention(q, k, v, jnp.ones((1, S), jnp.int32),
+                              scale=1.0, block=128)
+
+
 # ---- the table alone -------------------------------------------------------
 
 
@@ -172,6 +306,71 @@ def _allowed(seg_row):
 def _by_blocks(allowed, bq, bk, how):
     """[S // bq, S // bk]: `how` (any, all) over each block pair."""
     return how(allowed.reshape(S // bq, bq, S // bk, bk), axis=(1, 3))
+
+
+def _table_of_pr27(seg, bq, bk):
+    """`block_table` as it was before the window, in numpy: what
+    `window=None` has to give array for array."""
+    at = np.arange(S)
+    differs = seg[:, 1:] != seg[:, :-1]
+    edge = np.ones_like(seg[:, :1], bool)
+    start = np.maximum.accumulate(
+        np.where(np.concatenate([edge, differs], 1), at, 0), axis=1)
+    end = np.minimum.accumulate(
+        np.where(np.concatenate([differs, edge], 1), at, S - 1)[:, ::-1],
+        axis=1)[:, ::-1]
+    return la.BlockTable(
+        first_kv=start[:, ::bq] // bk, full_kv=-(-start[:, bq - 1::bq] // bk),
+        last_q=end[:, bk - 1::bk] // bq, full_q=(end[:, ::bk] + 1) // bq - 1)
+
+
+@pytest.mark.parametrize("window", [None, 1, 48, 128, 200, 400, 4 * S])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256),
+                                   (64, 64)])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_the_table_under_a_window(layout, bq, bk, window):
+    """No window: yesterday's table, array for array. A window: the
+    visited range holds every block pair with a visible pair (and is a
+    range, so it may hold a block between two that do), the tiles that
+    skip the mask are exactly those with no masked pair, and a window
+    longer than the row changes nothing."""
+    seg = np.stack(LAYOUTS[layout])
+    table = jax.tree.map(np.asarray,
+                         la.block_table(jnp.asarray(seg), bq, bk, window))
+    plain = _table_of_pr27(seg, bq, bk)
+    if window is None or window >= S:
+        for got, want in zip(table, plain):
+            np.testing.assert_array_equal(got, want)
+        return
+    nq, nk = S // bq, S // bk
+    i, j = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    diag = (i * bq + bq - 1) // bk
+    below = j * bk + bk - 1 <= i * bq
+    visited_total = 0
+    for b, row in enumerate(seg):
+        visible = _visible(row, window)
+        holds_a_pair = _by_blocks(visible, bq, bk, np.any)
+        all_pairs = _by_blocks(visible, bq, bk, np.all)
+        by_query = (j >= table.first_kv[b][:, None]) & (j <= diag)
+        by_key = (i >= (j * bk) // bq) & (i <= table.last_q[b][None, :])
+        # every needed pair is inside both ranges, and both are the same
+        # set of tiles: no block the window or the document has left
+        # behind is visited
+        assert not (holds_a_pair & ~by_query).any()
+        np.testing.assert_array_equal(by_query, by_key)
+        first_needed = np.where(holds_a_pair.any(1), holds_a_pair.argmax(1),
+                                nk)
+        np.testing.assert_array_equal(table.first_kv[b], first_needed)
+        np.testing.assert_array_equal(
+            (j >= table.full_kv[b][:, None]) & below, all_pairs)
+        np.testing.assert_array_equal(
+            (i <= table.full_q[b][None, :]) & below, all_pairs)
+        visited_total += int(by_query.sum())
+    visited, causal = la.block_pair_counts(jnp.asarray(seg), bq, bk, window)
+    assert int(visited) == visited_total
+    assert int(visited) <= int(la.block_pair_counts(jnp.asarray(seg), bq,
+                                                    bk)[0])
+    assert int(causal) == len(seg) * int((diag + 1).sum())
 
 
 @pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256),
@@ -228,6 +427,27 @@ def test_the_step_carries_the_counters():
                     v_head_dim=128), seg)
     # S = 512 is one block of the chip's 512
     assert int(wide["attn_block_pairs_causal"]) == 2
+
+
+def test_the_step_carries_the_counters_of_both_kinds_of_layer():
+    """An `AfmoeConfig`: the sliding layers' visited pairs under the
+    window and the full layers' without, each summed over its layers."""
+    from dexiraft_tpu.config import trinity_mini_toy
+    from dexiraft_tpu.models.lm.model import COUNTERS, _attention_counters
+
+    seg = jnp.asarray(np.stack(LAYOUTS["one_document"]))
+    # widths the kernel takes, a row of four of the chip's blocks
+    cfg = trinity_mini_toy(head_dim=128, seq_len=4 * S, sliding_window=8)
+    got = _attention_counters(cfg, jnp.concatenate([seg] * 4, axis=1))
+    assert set(got) <= set(COUNTERS)
+    # a full layer sees the 10 block pairs of the triangle; a sliding
+    # layer its diagonal and, for the 7 keys before a block's first row,
+    # the block before it: 4 + 3
+    assert {k: int(v) for k, v in got.items()} == {
+        "attn_block_pairs_visited_window": 4 * 7,
+        "attn_block_pairs_visited_full": 10, "attn_block_pairs_causal": 10}
+    one = _attention_counters(trinity_mini_toy(seq_len=S), seg)
+    assert int(one["attn_block_pairs_visited_window"]) == 4  # toy widths
 
 
 @pytest.mark.parametrize("seq,d_qk,d_v,want", [

@@ -1,5 +1,6 @@
-"""The new cell's rehearsal (`JAX_PLATFORMS=cpu`, exit 4), its manifest
-entries and its configuration file, and `train_cli`'s refusals."""
+"""The language-model cells' rehearsals (`JAX_PLATFORMS=cpu`, exit 4),
+their manifest entries and configuration files, and `train_cli`'s
+refusals."""
 
 import json
 import os
@@ -36,7 +37,29 @@ NEEDS_A_CHIP = {"train_step_device_ms", "train_device_idle_pct",
 
 # no cut may name a width (the builder's contract)
 WIDTHS = {"hidden_size", "intermediate_size", "moe_intermediate_size",
-          "num_experts_per_tok", "n_shared_experts"}
+          "num_experts_per_tok", "n_shared_experts", "num_shared_experts",
+          "head_dim", "sliding_window"}
+
+# the second language cell (PR 31): what it shares with the first, what
+# it brings, and what of the first's it leaves (`lm_attn_device_ms`
+# reads `lm/mla`)
+CELL2 = "trinity-train-pack32k"
+NEW2 = ["lm_gqa_device_ms", "lm_gqa_window_kernel_device_ms",
+        "lm_gqa_full_kernel_device_ms", "lm_gqa_kernel_roofline_pct",
+        "lm_gqa_window_blocks_visited_pct"]
+SHARED2 = [m for m in NEW if m != "lm_attn_device_ms"]
+NEEDS_A_CHIP2 = NEEDS_A_CHIP | {
+    "lm_gqa_device_ms", "lm_gqa_window_kernel_device_ms",
+    "lm_gqa_full_kernel_device_ms", "lm_gqa_kernel_roofline_pct"}
+CELLS = {
+    CELL: dict(config="kanana-2-30b-a3b-share8", traffic="train-pack8k",
+               model="kanana-2-30b-a3b-instruct-2601", bias="e_score_correction_bias",
+               reports=FED + NEW + SETUP, needs_a_chip=NEEDS_A_CHIP),
+    CELL2: dict(config="trinity-mini-share8", traffic="train-pack32k",
+                model="Trinity-Mini", bias="expert_bias",
+                reports=FED + SHARED2 + NEW2 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP2),
+}
 
 
 @pytest.fixture(scope="module")
@@ -49,7 +72,7 @@ def test_manifest_names_the_cell_and_every_metric_of_section_6(manifest):
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kanana-2-30b-a3b-share8", "train-pack8k", 1)
-    assert len(manifest["workloads"]) == 5
+    assert len(manifest["workloads"]) == 6
     by_name = {m["name"]: m for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + NEW + ["train_samples_per_s"]:
         assert CELL in by_name[name]["workloads"], name
@@ -58,15 +81,35 @@ def test_manifest_names_the_cell_and_every_metric_of_section_6(manifest):
     for name in SETUP:  # no list: every cell that reports setup_s
         assert "workloads" not in by_name[name]
     for name in NEW:
-        assert by_name[name]["workloads"] == [CELL]
+        assert by_name[name]["workloads"][0] == CELL
         assert by_name[name]["moves"] == "train_samples_per_s"
     for name in ("train_loop_device_ms_per_iter", "train_prelude_device_ms"):
         assert CELL not in by_name[name]["workloads"]  # no refinement loop
 
 
-def test_configuration_file_keeps_every_published_width(manifest):
+def test_manifest_names_the_second_cell_and_what_it_reports(manifest):
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL2)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "trinity-mini-share8", "train-pack32k", 1)
+    assert manifest["workloads"][-1] == cell  # entries are added at the end
+    by_name = {m["name"]: m
+               for m in manifest["per_layer"] + manifest["end_to_end"]}
+    for name in FED + SHARED2 + ["train_samples_per_s"]:
+        assert by_name[name]["workloads"][-2:] == [CELL, CELL2], name
+    assert by_name["lm_attn_device_ms"]["workloads"] == [CELL]
+    for name in NEW2:
+        assert by_name[name]["workloads"] == [CELL2]
+        assert by_name[name]["moves"] == "train_samples_per_s"
+        assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
+        assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
+                                   name + ".py"))
+    assert [m["name"] for m in manifest["per_layer"][-5:]] == NEW2
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_configuration_file_keeps_every_published_width(manifest, cell):
     entry = next(c for c in manifest["configs"]
-                 if c["name"] == "kanana-2-30b-a3b-share8")
+                 if c["name"] == CELLS[cell]["config"])
     with open(osp.join(REPO, entry["file"])) as f:
         cfg = json.load(f)
     assert cfg["reduced"] == entry["reduced"]
@@ -76,7 +119,7 @@ def test_configuration_file_keeps_every_published_width(manifest):
         pytest.skip("the catalog is not mounted here")
     with open(catalog) as f:
         row = next(json.loads(l) for l in f
-                   if '"kanana-2-30b-a3b-instruct-2601"' in l)
+                   if '"name": "%s"' % CELLS[cell]["model"] in l)
     assert entry["source"] == row["source_url"]
     for key, value in row["config"].items():
         if key in cfg["reduced"]:
@@ -85,15 +128,17 @@ def test_configuration_file_keeps_every_published_width(manifest):
         else:
             assert cfg[key] == value, key
     assert cfg["deployment"]["chips_sharing_a_layer"] == 8
-    assert any("e_score_correction_bias" in a for a in cfg["assumed"])
+    assert any(CELLS[cell]["bias"] in a for a in cfg["assumed"])
     assert osp.exists(osp.join(REPO, cfg["plain_reference"].split(":")[0]))
 
 
-def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report():
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report(
+        cell):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
+        [sys.executable, "benchmarks/run.py", "--workload", cell, "--seed",
          "2147483659", "--seconds", "1", "--trace", "1"],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == EXIT_REHEARSAL, proc.stderr[-2000:]
@@ -102,7 +147,7 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report():
     out = json.loads(line[len("REHEARSAL "):])
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] >= 3
-    want = set(FED + NEW + SETUP) - NEEDS_A_CHIP
+    want = set(CELLS[cell]["reports"]) - CELLS[cell]["needs_a_chip"]
     assert want <= set(out["would_report"]), want - set(out["would_report"])
     counters = json.loads(next(
         l for l in proc.stdout.splitlines()
@@ -111,6 +156,76 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report():
     assert counters["moe_dropped_slots"] == 0
     assert counters["flops_per_unit"] > 0
     assert "against their limits" in proc.stdout
+    if cell == CELL2:  # both kinds of layer, counted apart
+        assert counters["attn_block_pairs_visited_window"] > 0
+        assert counters["attn_block_pairs_visited_full"] > 0
+
+
+def test_the_second_cells_configuration_states_its_cut_and_builds():
+    """`parameters_held` is the program's own count, the share is the
+    deployment's, and the runner builds the configuration from the file's
+    `program` group alone."""
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+    from dexiraft_tpu.config import TrainConfig
+    from dexiraft_tpu.train.family import family_of
+    from dexiraft_tpu.train.state import param_count
+
+    cell = harness.load_cell(CELL2)
+    cfg, tc = harness.load_runner("lm_train_packed")._configs(cell, 0)
+    held = cell.config["parameters_held"]
+    params, _ = jax.eval_shape(family_of(cfg, TrainConfig()).init,
+                               jax.random.PRNGKey(0))
+    assert param_count(params) == held["total"] == 587_508_992
+    assert held["state_bytes_at_16_a_parameter"] == 16 * held["total"]
+    assert (cfg.heads_held, cfg.kv_heads_held, cfg.experts_held) == (
+        (0, 4), (0, 1), (0, 16))
+    assert (cfg.num_experts, cfg.num_attention_heads,
+            cfg.num_key_value_heads) == (128, 32, 4)  # the router's width
+    assert cfg.layer_types == ("sliding_attention",) * 4 + ("full_attention",)
+    assert (cfg.seq_len, tc.batch_size, cfg.remat) == (32768, 1, True)
+    # the cell times what the program selects: no tuned size of its own
+    assert cfg.moe_chunk is None
+    assert set(cell.traffic["model_flags"]) == {"seq_len", "remat"}
+
+
+def test_a_control_goes_through_the_checks_own_comparison():
+    """The builder's other readings (`LM_CHECK_SECOND_READING`): each
+    control is a fault the configuration can express, and its readings
+    meet the limits the way the program's do."""
+    import dataclasses
+
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+
+    cell = harness.load_cell(CELL2)
+    runner = harness.load_runner("lm_train_packed")
+    cfg, _ = runner._configs(cell, 0)
+    controls = cell.traffic["check"]["controls"]
+    assert set(controls) == {"no_window", "no_route_scale", "no_route_norm",
+                             "no_embedding_scale"}
+    for fault in controls.values():
+        faulty = dataclasses.replace(cfg, **fault)
+        assert faulty != cfg
+    assert dataclasses.replace(cfg, **controls["no_window"]).layer_window(
+        0) == cfg.seq_len
+    tol = {"loss": 1e-3, "wk": 0.1}
+    lines = []
+    for readings in ({"loss": 2e-3, "wk": 0.05}, {"loss": 2e-4, "wk": 0.05},
+                     {"loss": float("nan"), "wk": 0.2}):
+        runner._control(lines.append, "a fault", readings, tol)
+    assert lines[0].endswith("FAILED by loss")
+    assert "FAILED" not in lines[1] and "ok" in lines[1]
+    assert lines[2].endswith("FAILED by loss, wk")
+    assert [runner._within(r, tol) for r in (
+        {"loss": 2e-3, "wk": 0.05}, {"loss": 2e-4, "wk": 0.05})] == [
+            False, True]
+    docs = cell.traffic["documents"]
+    assert (docs["median"], docs["sigma"], docs["shortest"], docs["longest"],
+            docs["count"]) == (8192, 1.0, 1024, 16384, 512)
+    assert cell.config["deployment"]["chips_sharing_a_layer"] == 8
 
 
 def _train(*flags):
@@ -142,7 +257,15 @@ def test_train_cli_refuses_the_language_models_flags_for_raft():
     assert proc.returncode != 0 and "--stage is required" in proc.stderr
 
 
-def test_train_cli_trains_the_toy_model_through_the_normal_path(tmp_path):
+@pytest.mark.parametrize("variant,share", [
+    ("kanana2-toy", ("--heads_held", "0", "2")),
+    # a dense sliding layer, an expert sliding layer, the full layer
+    ("trinity-mini-toy", ("--heads_held", "2", "2", "--kv_heads_held", "1",
+                          "1", "--layers", "3", "--dense_layers", "1",
+                          "--layer_types", "sliding_attention",
+                          "sliding_attention", "full_attention"))])
+def test_train_cli_trains_the_toy_model_through_the_normal_path(
+        tmp_path, variant, share):
     import numpy as np
 
     from dexiraft_tpu.data.tokens import write_token_file
@@ -152,16 +275,25 @@ def test_train_cli_trains_the_toy_model_through_the_normal_path(tmp_path):
                       4, 128)
     tokens = str(tmp_path / "toy.npz")
     write_token_file(tokens, rng.integers(0, 256, lengths.sum()), lengths)
-    proc = _train("--variant", "kanana2-toy", "--tokens", tokens,
+    proc = _train("--variant", variant, "--tokens", tokens,
                   "--batch_size", "2", "--num_steps", "4", "--val_freq", "2",
                   "--sum_freq", "2", "--precision", "bf16", "--remat",
-                  "--experts_held", "0", "4", "--heads_held", "0", "2",
+                  "--experts_held", "0", "4", *share,
                   "--num_workers", "2", "--output", str(tmp_path / "ck"),
                   "--log_dir", str(tmp_path / "runs"))
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "Done: 4 steps" in proc.stdout
     assert "packed rows of 128 positions" in proc.stdout
-    with open(tmp_path / "runs" / "kanana2-toy" / "metrics.jsonl") as f:
+    with open(tmp_path / "runs" / variant / "metrics.jsonl") as f:
         last = [r for r in map(json.loads, f) if "loss" in r][-1]
     assert last["moe_dropped_slots"] == 0 and last["tokens_real"] > 0
-    assert osp.isdir(tmp_path / "ck" / "kanana2-toy")
+    assert osp.isdir(tmp_path / "ck" / variant)
+
+
+def test_train_cli_refuses_a_share_that_splits_a_key_value_head(tmp_path):
+    proc = _train("--variant", "trinity-mini-toy", "--tokens", "x.npz",
+                  "--heads_held", "1", "3")  # heads 1 | 2, 3 of two
+    assert proc.returncode != 0 and "divide evenly" in proc.stderr
+    proc = _train("--variant", "kanana2-toy", "--tokens", "x.npz",
+                  "--kv_heads_held", "0", "1")
+    assert proc.returncode != 0 and "--kv_heads_held" in proc.stderr

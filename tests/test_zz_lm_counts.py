@@ -19,11 +19,11 @@ import pytest
 
 sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
 
-from benchmarks import flops, lm_counts, lm_scopes  # noqa: E402
-from dexiraft_tpu.config import kanana2  # noqa: E402
+from benchmarks import flops, lm_counts, lm_counts_afmoe, lm_scopes  # noqa: E402
+from dexiraft_tpu.config import kanana2, trinity_mini  # noqa: E402
 from dexiraft_tpu.interop import lm_reference as ref  # noqa: E402
 
-from _lm_common import packed_batch, seeded, toy  # noqa: E402
+from _lm_common import SHARES, packed_batch, seeded, toy  # noqa: E402
 
 
 def test_dense_parts_equal_the_walk_of_the_reference():
@@ -154,3 +154,119 @@ def test_new_layer_metrics_read_the_scope_counters_and_give_nothing_without():
                  "lm_optimizer_device_ms", "lm_moe_experts_roofline_pct",
                  "lm_moe_load_max_over_mean", "lm_pack_fill_pct"):
         assert read(name, bare) is None
+
+
+# ---- the second architecture's counts (benchmarks/lm_counts_afmoe.py) ------
+
+
+def test_afmoe_dense_parts_equal_the_walk_of_the_reference():
+    """The reference makes whole `[S, S]` score matrices in every layer
+    of either kind (the window is a mask there) and applies each held
+    expert to every token; the rest of its products are the analytic
+    per-token parts."""
+    cfg = toy("trinity", **SHARES["trinity"])
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg)
+    rows, s = batch["tokens"].shape
+    walked = flops.count(lambda p: ref.loss(p, batch, cfg), params)
+    per_token = sum(lm_counts_afmoe.per_token_forward(cfg).values())
+    moe_layers = cfg.num_hidden_layers - cfg.num_dense_layers
+    scores = (rows * cfg.num_hidden_layers * s * s
+              * lm_counts_afmoe.per_pair_forward(cfg))
+    experts = (rows * s * moe_layers * cfg.experts_held[1]
+               * lm_counts_afmoe.per_slot_forward(cfg))
+    assert walked == per_token * rows * s + scores + experts
+
+
+@pytest.mark.parametrize("window", [1, 2, 3, 50])
+def test_pairs_in_window_counts_the_visible_pairs_of_each_document(window):
+    seg = np.array([1, 1, 1, 1, 2, 2, 0, 0, 3])
+    t = np.arange(len(seg))
+    back = t[:, None] - t[None, :]
+    visible = ((back >= 0) & (back < window) & (seg[:, None] == seg[None, :])
+               & (seg[:, None] > 0))
+    assert lm_counts_afmoe.pairs_in_window(seg, window) == visible.sum()
+    if window >= 4:
+        assert (lm_counts_afmoe.pairs_in_window(seg, window)
+                == lm_counts.pairs_in_document(seg) == 10 + 3 + 1)
+    cfg = toy("trinity", sliding_window=window)
+    assert lm_counts_afmoe.pairs_by_kind(cfg, np.stack([seg, seg])) == {
+        "full": 2 * 14.0, "window": 2.0 * visible.sum()}
+
+
+def test_afmoe_step_flops_at_the_cells_share_by_hand():
+    cfg = trinity_mini(
+        num_hidden_layers=5, num_dense_layers=1, vocab_size=25024,
+        layer_types=("sliding_attention",) * 4 + ("full_attention",),
+        heads_held=(0, 4), experts_held=(0, 16))
+    assert lm_counts_afmoe.layers_by_kind(cfg) == {"window": 4, "full": 1}
+    assert lm_counts_afmoe.per_slot_forward(cfg) == 3 * 2 * 2048 * 1024
+    # W_q, W_g, W_o of 4 heads and W_k, W_v of 1, each 2048 x 128 a head
+    assert lm_counts_afmoe.per_token_forward(cfg)["projections"] == (
+        5 * 2 * 2048 * 128 * (3 * 4 + 2 * 1))
+    parts = lm_counts_afmoe.step_flops(
+        cfg, tokens_real=32200, slots_held=4 * 32200,
+        pairs={"full": 199e6, "window": 58.6e6})
+    assert parts["attention"] == 3 * 4 * 2 * 256 * (199e6 + 4 * 58.6e6)
+    assert parts["total"] == pytest.approx(sum(
+        v for k, v in parts.items() if k != "total"))
+    # the issue's arithmetic: 3.3e13 a step, the head 9.9e12 of it
+    assert 3.0e13 < parts["total"] < 3.6e13
+    assert parts["head"] == pytest.approx(9.9e12, rel=0.01)
+
+
+def test_attention_roofline_counts_eleven_products_a_pair_and_head():
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    assert sum(lm_counts_afmoe.attention_calls(True).values()) == 11
+    assert sum(lm_counts_afmoe.attention_calls(False).values()) == 9
+    least = lm_counts_afmoe.attention_roofline_seconds(
+        58.6e6, 4, 32768, 4, 1, 128, True, peaks)
+    assert least["flops"] == 4 * 58.6e6 * 4 * 11 * 2 * 128
+    # each of the 4 calls moves q, o / dq, do for 4 heads and k, v for 1
+    assert least["bytes"] == 4 * 4 * 32768 * 128 * 2 * (3 * 4 + 2)
+    assert least["seconds"] == pytest.approx(least["flops"] / 197e12)
+    # a handful of pairs a token: the calls' bytes bound them
+    few = lm_counts_afmoe.attention_roofline_seconds(
+        32768 * 8, 4, 32768, 4, 1, 128, True, peaks)
+    assert few["seconds"] == pytest.approx(few["bytes"] / 819e9)
+
+
+def test_gqa_layer_metrics_read_their_counters_and_give_nothing_without():
+    from benchmarks import harness
+
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    counters = {"scope_s:lm/gqa/proj": 0.030,
+                "scope_s:lm/gqa/window/kernel": 0.040,
+                "scope_s:lm/gqa/full/kernel": 0.025,
+                "scope_s:lm/moe/experts": 0.2,
+                "traced_pairs_window": 58.6e6, "traced_pairs_full": 199e6,
+                "attn_layers_window": 4, "attn_layers_full": 1,
+                "attn_heads_held": 4, "attn_kv_heads_held": 1,
+                "attn_head_dim": 128, "remat": 1.0, "batch": 1,
+                "seq_len": 32768,
+                "attn_block_pairs_visited_window": 4 * 300.0,
+                "attn_block_pairs_visited_full": 1000.0}
+
+    def obs(c):
+        return harness.Observation(
+            spans={}, counters=c, end_to_end={}, trace={"busy_s": 1.0},
+            peaks=peaks, chips=1, memory_peak_bytes=0)
+
+    read = lambda name, o: harness.load_metric(name).read(o)  # noqa: E731
+    full = obs(counters)
+    assert read("lm_gqa_device_ms", full) == pytest.approx(95.0)
+    assert read("lm_gqa_window_kernel_device_ms", full) == pytest.approx(40.0)
+    assert read("lm_gqa_full_kernel_device_ms", full) == pytest.approx(25.0)
+    least = (4 * 58.6e6 + 199e6) * 4 * 11 * 256 / 197e12
+    share = read("lm_gqa_kernel_roofline_pct", full)
+    assert share == pytest.approx(least / 0.065 * 100)
+    assert 0 < share < 100
+    assert read("lm_gqa_window_blocks_visited_pct", full) == pytest.approx(30.0)
+    # the parent's program, or kanana's: nothing, and no raise
+    for c in ({}, {"scope_s:lm/mla": 0.15, "batch": 4, "seq_len": 8192,
+                   "attn_block_pairs_visited": 500.0}):
+        for name in ("lm_gqa_device_ms", "lm_gqa_window_kernel_device_ms",
+                     "lm_gqa_full_kernel_device_ms",
+                     "lm_gqa_kernel_roofline_pct",
+                     "lm_gqa_window_blocks_visited_pct"):
+            assert read(name, obs(c)) is None, name

@@ -147,3 +147,54 @@ def test_dropless_with_every_token_forced_onto_one_held_expert():
         for k in path:
             g, w = g[k], w[k]
         assert rel(g, w) < 2e-5
+
+
+@pytest.mark.parametrize("slots,held,experts,rows", [
+    (4 * 8192 * 6, 16, 128, 32_768),   # kanana2-train-pack8k: as PR 26 set it
+    (32_768 * 8, 16, 128, 49_152),     # trinity-train-pack32k
+    (8192 * 6, 16, 128, 8192),         # one row of kanana2's
+    (32_768 * 8, 128, 128, 32_768 * 8),  # every expert here: every slot
+    (256 * 2, 2, 16, 256 * 2)])        # a toy: one chunk
+def test_the_dispatch_chunk_clears_the_expected_load_by_a_third(
+        slots, held, experts, rows):
+    """No field, flag or traffic file picks the chunk: the layer sizes it
+    from its shapes, and for the benchmark's two cells that is the size
+    each was measured at."""
+    from dexiraft_tpu.config import kanana2, trinity_mini
+    from dexiraft_tpu.models.lm.moe import dispatch_chunk
+
+    got = dispatch_chunk(slots, held, experts)
+    assert got == rows
+    assert got == slots or (got % 8192 == 0
+                            and 3 * got * experts >= 4 * slots * held
+                            and 3 * (got - 8192) * experts < 4 * slots * held)
+    assert kanana2().moe_chunk is None and trinity_mini().moe_chunk is None
+
+
+@pytest.mark.parametrize("block", [32, 64, 128, 96])
+def test_head_loss_by_blocks_of_positions_equals_the_whole(block):
+    """The head and the loss walk blocks of positions; the sum and the
+    gradients of the activations and of the head are those of one pass
+    over `[B, S, vocab]` logits. 96 is no divisor of the row: a row at a
+    time."""
+    import jax
+
+    from dexiraft_tpu.models.lm.model import head_loss
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(2, 128, 16)), jnp.float32)
+    head = jnp.asarray(rng.normal(size=(16, 40)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, 40, (2, 128)), jnp.int32)
+    weight = jnp.asarray(rng.integers(0, 2, (2, 128)), jnp.float32)
+
+    def whole(x, head):
+        logp = jax.nn.log_softmax(x @ head, axis=-1)
+        picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.sum(picked * weight)
+
+    want = jax.value_and_grad(whole, argnums=(0, 1))(x, head)
+    got = jax.value_and_grad(
+        lambda x, head: head_loss(x, head, targets, weight, block),
+        argnums=(0, 1))(x, head)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)

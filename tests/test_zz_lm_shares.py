@@ -1,7 +1,10 @@
 """The shares test: the parts of a layer's output that all 8 chips of
 the stated deployment give (heads 8 ways, routed experts 8 ways), with
 what every chip computes alike (the shared experts) counted once, add up
-to what the uncut reference gives for the whole layer.
+to what the uncut reference gives for the whole layer. For both
+architectures: kanana2's latent attention first, then trinity's gated
+grouped-query attention, where two chips hold copies of one key/value
+head.
 
 Each share runs the SYSTEM's modules (models/lm) on its slice of the
 whole model's weights; the whole is the plain reference holding every
@@ -124,3 +127,116 @@ def test_layer_outputs_of_the_shares_add_up_to_the_whole_layer(whole):
     shared = SwiGLU(width=cfg.n_shared_experts * cfg.moe_intermediate_size
                     ).apply({"params": lp["moe"]["shared"]}, ffn_in)
     assert rel(h + routed + shared, want) < 2e-5
+
+
+# ---- the second architecture: gated grouped-query attention ---------------
+#
+# 8 query heads over 4 key/value heads: share i holds query head i and a
+# copy of key/value head i // 2, which share i ^ 1 holds too, and experts
+# 2i, 2i + 1. Layer 1 is a sliding expert layer, layer 4 the full layer.
+
+AFMOE_LAYERS = {"sliding": "layers_1", "full": "layers_4"}
+
+
+@pytest.fixture(scope="module")
+def afmoe_whole():
+    cfg = toy("trinity")
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg, rows=1)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, cfg.seq_len,
+                                                  cfg.hidden_size))
+    return cfg, params, batch, x
+
+
+def _afmoe_share(i, whole):
+    """(the share's configuration, its parameter tree)."""
+    cfg, params, _, _ = whole
+    share = toy("trinity", heads_held=(i, 1), experts_held=(2 * i, 2))
+    assert share.kv_heads_held == (i // 2, 1)
+    return share, ref.take_share(params, cfg, share.heads_held,
+                                 share.experts_held, share.kv_heads_held)
+
+
+def _gated_part(i, whole, layer, x):
+    from dexiraft_tpu.models.lm.attention import mixer_of
+
+    _, _, batch, _ = whole
+    share, p = _afmoe_share(i, whole)
+    index = int(layer.split("_")[1])
+    return mixer_of(share, index).apply(
+        {"params": p[layer]["attn"]}, x, batch["positions"],
+        batch["segment_ids"])[0]
+
+
+def _afmoe_routed_part(i, whole, layer, rows):
+    share, p = _afmoe_share(i, whole)
+    out, counters = RoutedExperts(cfg=share).apply(
+        {"params": p[layer]["moe"]["experts"]}, rows,
+        mutable=["batch_stats"])[0]
+    assert int(counters["moe_dropped_slots"]) == 0
+    return out, counters
+
+
+@pytest.mark.parametrize("kind", list(AFMOE_LAYERS))
+@pytest.mark.parametrize("i", range(SHARES))
+def test_a_gated_share_equals_the_reference_given_that_share(i, kind,
+                                                             afmoe_whole):
+    cfg, _, batch, x = afmoe_whole
+    layer = AFMOE_LAYERS[kind]
+    share, p = _afmoe_share(i, afmoe_whole)
+    index = int(layer.split("_")[1])
+    want = ref.gated_attention(
+        p[layer]["attn"], x[0], batch["positions"][0],
+        batch["segment_ids"][0], cfg, 1, 1, cfg.layer_window(index))
+    assert rel(_gated_part(i, afmoe_whole, layer, x), want) < 2e-5
+    want_moe = ref.moe(p[layer]["moe"], x[0], cfg, share.experts_held)
+    routed, _ = _afmoe_routed_part(i, afmoe_whole, layer, x[0])
+    shared = SwiGLU(width=cfg.moe_intermediate_size).apply(
+        {"params": p[layer]["moe"]["shared"]}, x[0])
+    assert rel(routed + shared, want_moe) < 2e-5
+
+
+@pytest.mark.parametrize("kind", list(AFMOE_LAYERS))
+def test_gated_attention_parts_of_all_shares_add_up_to_the_whole(
+        kind, afmoe_whole):
+    """A key/value head's two copies each serve their own query head:
+    the eight parts are the uncut attention's eight heads."""
+    cfg, params, batch, x = afmoe_whole
+    layer = AFMOE_LAYERS[kind]
+    total = sum(_gated_part(i, afmoe_whole, layer, x) for i in range(SHARES))
+    want = ref.gated_attention(
+        params[layer]["attn"], x[0], batch["positions"][0],
+        batch["segment_ids"][0], cfg, cfg.num_attention_heads,
+        cfg.num_key_value_heads, cfg.layer_window(int(layer.split("_")[1])))
+    assert rel(total, want) < 2e-5
+
+
+@pytest.mark.parametrize("kind", list(AFMOE_LAYERS))
+def test_afmoe_layer_outputs_of_the_shares_add_up_to_the_whole_layer(
+        kind, afmoe_whole):
+    """x + N2(sum of the attention parts) = h; h + N4(routed parts +
+    the shared expert once) = the uncut reference's layer output, for a
+    sliding expert layer and the full layer: the norms of what a half
+    adds come after the sum over the chips."""
+    cfg, params, batch, x = afmoe_whole
+    layer = AFMOE_LAYERS[kind]
+    lp = params[layer]
+    eps = cfg.rms_norm_eps
+    want = ref.afmoe_layer(lp, x[0], batch["positions"][0],
+                           batch["segment_ids"][0], cfg,
+                           int(layer.split("_")[1]))
+    normed = ref._rms_norm(x[0], lp["attn_norm"], eps)
+    attn = sum(_gated_part(i, afmoe_whole, layer, normed[None])
+               for i in range(SHARES))
+    h = x[0] + ref._rms_norm(attn, lp["attn_post_norm"], eps)
+    ffn_in = ref._rms_norm(h, lp["ffn_norm"], eps)
+    parts = [_afmoe_routed_part(i, afmoe_whole, layer, ffn_in)
+             for i in range(SHARES)]
+    shared = SwiGLU(width=cfg.moe_intermediate_size).apply(
+        {"params": lp["moe"]["shared"]}, ffn_in)
+    ffn = sum(out for out, _ in parts) + shared
+    got = h + ref._rms_norm(ffn, lp["ffn_post_norm"], eps)
+    assert rel(got, want) < 2e-5
+    # every slot of every token lands on exactly one chip
+    assert sum(int(c["moe_slots_held"]) for _, c in parts) == (
+        cfg.seq_len * cfg.num_experts_per_tok)
