@@ -119,10 +119,10 @@ class RAFTConfig:
     #                     point the train_bench HBM columns quantify
     remat_policy: str = "full"
     # rematerialize ONLY the correlation lookup: drops the per-iteration
-    # one-hot hat matrices — the dominant training-memory term (their
-    # (9, W) trailing dims lane-pad ~10x on the chip; the v5e compiler
-    # wants 33.6 GiB at batch 10, 368x496 without remat and 20.2 with
-    # this — docs/perf.md) — at a fraction of full remat's recompute cost.
+    # hats and tap rows (until PR 32 their (9, W) trailing dims lane-padded
+    # ~10x: the v5e compiler wanted 33.6 GiB at batch 10, 368x496 without
+    # remat and 20.2 with this — docs/perf.md; not compiled again since)
+    # at a fraction of full remat's recompute cost.
     # Numerically identical; composes with (and is implied by) remat
     remat_lookup: bool = False
     # transposed-conv implementation inside the embedded DexiNed's

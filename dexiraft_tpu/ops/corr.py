@@ -3,17 +3,28 @@
 TPU-native re-design of the reference centerpiece (core/corr.py:12-60):
 the volume is one big batched matmul (MXU-friendly), the pyramid is
 slice+reshape-mean 2x2 average pooling (NOT lax.reduce_window — see
-avg_pool_2x2), and the per-iteration lookup gathers a (2r+1)^2 bilinear
+avg_pool_2x2), and the per-iteration lookup takes a (2r+1)^2 bilinear
 window per pixel per level.
 
-Layouts: feature maps are (B, H, W, D); the flattened volume is
-(B*H*W, H_l, W_l, 1) per level — same flattening the reference uses so the
-lookup is a plain batched 2D sample.
+Layouts: feature maps are (B, H, W, D). A pyramid level is stored
+(B, H_l, W_l, H*W): the QUERY pixels on the minor axis, which the TPU
+spreads over the 128 lanes of a vector register, the target rows and
+columns as major axes. The reference flattens the other way round,
+(B*H*W, H_l, W_l, 1): there the minor
+pair (H_l, W_l) fills (8, 128) register tiles as (48, 128), (24, 128),
+(16, 128), (8, 128) at the chairs crop's 46x62, 23x31, 11x15, 5x7 —
+2.24 GB of HBM for 0.69 GB of values, read twice and its gradient sum
+read and written once in every training iteration. With the queries on
+the lanes 2852 pads to 2944 (1.03x); the chip's compiler puts the batch on
+the sublanes (`f32[16,46,62,2852]{3,0,2,1:T(8,128)}`: 0.710 GB), and the
+lookup is elementwise over whole registers (corr_lookup). docs/perf.md
+"Correlation memory & precision" has the chip's numbers.
 
 This module is the materialized path; the memory-efficient on-demand
 equivalent of the reference's alt_cuda_corr CUDA kernel
-(alt_cuda_corr/correlation_kernel.cu) is a separate op
-(see dexiraft_tpu.ops.local_corr once built).
+(alt_cuda_corr/correlation_kernel.cu) is dexiraft_tpu.ops.local_corr,
+whose transient per-chunk blocks keep the reference's slab form
+(all_pairs_correlation + interp_window below).
 """
 
 from __future__ import annotations
@@ -37,7 +48,7 @@ class CorrPyramid:
     lax.scan carries without tracer leakage into shape arithmetic.
     """
 
-    levels: tuple  # tuple of (B*H*W, H_l, W_l, 1) arrays (fp32/bf16/int8)
+    levels: tuple  # tuple of (B, H_l, W_l, H*W) arrays (fp32/bf16/int8)
     batch: int = flax.struct.field(pytree_node=False)
     ht: int = flax.struct.field(pytree_node=False)
     wd: int = flax.struct.field(pytree_node=False)
@@ -45,6 +56,11 @@ class CorrPyramid:
     # per-level fp32 scalar dequantization scales for int8 storage; None
     # for the scale-free dtypes (ops/quant.py). A pytree leaf tuple.
     scales: Optional[tuple] = None
+
+    @property
+    def level_shapes(self) -> tuple:
+        """((H_0, W_0), (H_1, W_1), ...): each level's target extent."""
+        return tuple(lvl.shape[1:3] for lvl in self.levels)
 
     def __call__(self, coords: jax.Array) -> jax.Array:
         return corr_lookup(self, coords)
@@ -54,7 +70,10 @@ def all_pairs_correlation(fmap1: jax.Array, fmap2: jax.Array) -> jax.Array:
     """corr[b, i, j, k, l] = <fmap1[b,i,j,:], fmap2[b,k,l,:]> / sqrt(D).
 
     fmap1, fmap2: (B, H, W, D). Returns (B*H*W, H, W, 1) in float32 —
-    the flattened layout the pyramid/lookup consume.
+    the reference's flattening, one slab per query. For a block that is
+    made and dropped inside one lookup (ops/local_corr.py's dense chunk,
+    parallel/context.py's ring); the stored pyramid's levels come from
+    _query_minor_volume.
     Reference: core/corr.py:52-60 (matmul + /sqrt(dim)), fp32 like
     core/raft.py:139-142.
     """
@@ -66,6 +85,21 @@ def all_pairs_correlation(fmap1: jax.Array, fmap2: jax.Array) -> jax.Array:
     corr = jnp.einsum("bnd,bmd->bnm", f1, f2, preferred_element_type=jnp.float32)
     corr = corr / jnp.sqrt(jnp.float32(d))
     return corr.reshape(b * h * w, h2, w2, 1)
+
+
+def _query_minor_volume(fmap1: jax.Array, fmap2: jax.Array) -> jax.Array:
+    """The same products as all_pairs_correlation, queries last:
+    vol[b, k, l, i*W + j] = <fmap1[b,i,j,:], fmap2[b,k,l,:]> / sqrt(D),
+    (B, H2, W2, H*W) float32. With fmap2 as the left operand the matmul
+    writes this form itself; no transpose follows it.
+    """
+    b, h, w, d = fmap1.shape
+    h2, w2 = fmap2.shape[1:3]  # differ from (h, w) under a sharded query axis
+    f1 = fmap1.reshape(b, h * w, d).astype(jnp.float32)
+    f2 = fmap2.reshape(b, h2 * w2, d).astype(jnp.float32)
+    corr = jnp.einsum("bmd,bnd->bmn", f2, f1, preferred_element_type=jnp.float32)
+    corr = corr / jnp.sqrt(jnp.float32(d))
+    return corr.reshape(b, h2, w2, h * w)
 
 
 def avg_pool_2x2(x: jax.Array) -> jax.Array:
@@ -94,7 +128,8 @@ def build_corr_pyramid(
     """Materialize the all-pairs volume and its average-pool pyramid.
 
     Reference: core/corr.py:13-27. Level i has shape
-    (B*H*W, H >> i, W >> i, 1) (floor division via VALID pooling).
+    (B, H >> i, W >> i, H*W) (floor division via VALID pooling; the
+    queries on the minor axis — module docstring).
 
     The reference pools the VOLUME; correlation is linear in fmap2, so
     avg-pooling the volume's target dims equals correlating against the
@@ -106,7 +141,7 @@ def build_corr_pyramid(
     ``dtype`` is the STORAGE precision of the pyramid ("fp32", "bf16",
     "int8" — ops/quant.py): correlation is always computed fp32, then
     each level is stored low-precision (per-level scale for int8) and
-    dequantized inside the lookup's matmuls. This halves/quarters the
+    dequantized inside the lookup's first contraction. This halves/quarters the
     HBM bytes every refinement iteration streams — the loop's bandwidth
     term (docs/perf.md "Correlation memory & precision").
     """
@@ -115,7 +150,7 @@ def build_corr_pyramid(
     levels: List[jax.Array] = []
     scales: List[Optional[jax.Array]] = []
     for _ in range(num_levels):
-        lvl, scale = store_corr(all_pairs_correlation(fmap1, f2), dtype)
+        lvl, scale = store_corr(_query_minor_volume(fmap1, f2), dtype)
         levels.append(lvl)
         scales.append(scale)
         f2 = avg_pool_2x2(f2.astype(jnp.float32))
@@ -161,32 +196,28 @@ def _axis_interp_matrix(center: jax.Array, radius: int, size: int,
     return jnp.maximum(0.0, 1.0 - jnp.abs(pos - t[..., None]))
 
 
-def interp_window(vol: jax.Array, centers: jax.Array, radius: int,
-                  scale: Optional[jax.Array] = None) -> jax.Array:
-    """Bilinear (2r+1)^2 window of each volume slab around its center.
+def interp_window(vol: jax.Array, centers: jax.Array,
+                  radius: int) -> jax.Array:
+    """Bilinear (2r+1)^2 window of each volume SLAB around its center.
 
     vol (N, Hl, Wl), centers (N, 2) in level pixels -> (N, (2r+1)^2).
 
-    ``vol`` may be stored below fp32 (bf16/int8 pyramid, ops/quant.py):
-    the upcast happens inside the einsum's operand read (XLA fuses the
-    convert into the matmul, so the fp32 values never round-trip HBM),
-    and ``scale`` — the int8 dequantization factor — multiplies the
-    window afterwards, which is exact because the whole lookup is linear
-    in the volume.
+    The slab-form helper: for a transient block in the reference's
+    flattening (all_pairs_correlation), one slab per query — the dense
+    chunk of ops/local_corr.py. The stored pyramid's lookup is corr_lookup
+    below; the mathematics is the same.
 
-    TPU formulation: the taps sit at INTEGER offsets from one real-valued
-    center per slab, so every tap shares the slab's fractional part and
-    the 2-D bilinear interpolation separates into per-axis 1-D stencils.
-    The whole windowed gather then collapses into batched matmuls
-    against per-pixel one-hot interpolation matrices,
+    The taps sit at INTEGER offsets from one real-valued center per slab,
+    so every tap shares the slab's fractional part and the 2-D bilinear
+    interpolation separates into per-axis 1-D stencils,
 
-        window[n] = A_x[n] · vol[n]ᵀ · A_y[n]ᵀ   — MXU work, no gather,
+        window[n] = A_x[n] · vol[n]ᵀ · A_y[n]ᵀ,
 
-    which XLA schedules as streaming passes over the volume (HBM-bandwidth
-    bound) instead of the scalar-gather HLO that advanced indexing lowers
-    to. Expressed as ONE three-operand einsum so XLA picks the
-    contraction path itself (scripts/lookup_ab.py --variant 2 compares
-    the hand-split pairs; not measured on today's code).
+    batched matmuls against per-pixel hat matrices (_axis_interp_matrix),
+    written as one three-operand einsum. On the chip its operands pad: a
+    (9, W_l) or (H_l, W_l) minor pair fills a fraction of an (8, 128)
+    tile, which is why the stored pyramid is not in this form (timings
+    in corr_lookup's docstring).
 
     The window axis order matches _window_delta: x offset on the SLOW
     axis — the reference's transposed window (core/corr.py:37-43).
@@ -195,14 +226,20 @@ def interp_window(vol: jax.Array, centers: jax.Array, radius: int,
     hl, wl = vol.shape[1], vol.shape[2]
     ax = _axis_interp_matrix(centers[:, 0], radius, wl)  # (N, win, Wl)
     ay = _axis_interp_matrix(centers[:, 1], radius, hl)  # (N, win, Hl)
-    # upcast in the operand read (fuses into the matmul; TPU's default
-    # matmul precision truncates fp32 inputs to bf16 internally anyway —
-    # lookup_ab3's finding — so the storage dtype only changes HBM bytes)
-    window = jnp.einsum("nby,nyx,nax->nab", ay, vol.astype(jnp.float32), ax,
+    # TPU's default matmul precision truncates fp32 inputs to bf16
+    window = jnp.einsum("nby,nyx,nax->nab", ay, vol, ax,
                         preferred_element_type=jnp.float32)
-    if scale is not None:
-        window = window * scale
     return window.reshape(vol.shape[0], win * win)
+
+
+def _tap_hats(center: jax.Array, radius: int, size: int) -> jax.Array:
+    """_axis_interp_matrix with the queries last: center (B, Q) ->
+    (B, 2r+1, size, Q), hats[b, j, p, q] = relu(1 - |p - (c[b,q] + j - r)|).
+    """
+    t = center[:, None, None, :] + jnp.arange(
+        -radius, radius + 1, dtype=jnp.float32)[:, None, None]
+    pos = jnp.arange(size, dtype=jnp.float32)[:, None]
+    return jnp.maximum(0.0, 1.0 - jnp.abs(pos - t))
 
 
 @jax.named_scope("corr_lookup")
@@ -211,16 +248,43 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
 
     coords: (B, H, W, 2) current correspondence estimates in level-0 pixels.
     Returns (B, H, W, num_levels * (2r+1)^2) float32 correlation features.
-    Reference: core/corr.py:29-50; windowing via interp_window.
+    Reference: core/corr.py:29-50.
+
+    The same separable hat window as interp_window, A_y · vol · A_xᵀ, zero
+    outside the frame, on the stored form (B, H_l, W_l, Q): every operand
+    has the queries on its minor axis, so both contractions run over MAJOR
+    axes — multiply-adds between whole registers, in fp32 on the vector
+    unit (the einsum form rounds its fp32 operands to bf16 on the chip; this
+    one does not). The x hat goes first: the level is read once, 9·H_l·W_l
+    multiply-adds a query, and what is left for the y hat is (B, 9, H_l, Q).
+    Autodiff's gradient with respect to the level is the mirror image, a
+    sum over the nine x taps that is written in the level's own form, so the
+    sum the training scan carries over its iterations is an add over full
+    registers. Timed alone at N = 45,632 (my chip run, PR 32): 3.63 ms a
+    lookup, 10.94 ms with its gradient; y first 4.70 | 12.21; the slab
+    einsum 12.97 | 25.98.
+
+    ``levels`` below fp32 are upcast in the first contraction's operand
+    read; an int8 level's scale multiplies its window, exact because the
+    lookup is linear in the volume.
     """
     r = pyramid.radius
     b, h, w = pyramid.batch, pyramid.ht, pyramid.wd
     win = 2 * r + 1
 
-    flat = coords.reshape(b * h * w, 2).astype(jnp.float32)
+    flat = coords.reshape(b, h * w, 2).astype(jnp.float32)
+    cx, cy = flat[..., 0], flat[..., 1]  # (B, Q)
     out = []
-    for i, corr in enumerate(pyramid.levels):
-        scale = pyramid.scales[i] if pyramid.scales is not None else None
-        window = interp_window(corr[..., 0], flat / (2.0**i), r, scale=scale)
-        out.append(window.reshape(b, h, w, win * win))
-    return jnp.concatenate(out, axis=-1).astype(jnp.float32)
+    for i, vol in enumerate(pyramid.levels):
+        hl, wl = vol.shape[1:3]
+        ax = _tap_hats(cx / (2.0**i), r, wl)  # (B, win, Wl, Q)
+        ay = _tap_hats(cy / (2.0**i), r, hl)  # (B, win, Hl, Q)
+        rows = jnp.sum(ax[:, :, None] * vol.astype(jnp.float32)[:, None],
+                       axis=3)  # (B, win_x, Hl, Q)
+        window = jnp.sum(ay[:, None] * rows[:, :, None], axis=3)
+        if pyramid.scales is not None:
+            window = window * pyramid.scales[i]
+        # (B, win_x, win_y, Q): x offset on the slow axis (_window_delta)
+        out.append(window.reshape(b, win * win, h * w))
+    out = jnp.concatenate(out, axis=1)  # (B, L*win^2, Q)
+    return jnp.swapaxes(out, 1, 2).reshape(b, h, w, -1)

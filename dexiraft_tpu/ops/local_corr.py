@@ -9,12 +9,16 @@ instead of the materialized volume's O((HW)^2) (SURVEY.md §2.2).
 TPU-native reformulation — flash-attention-style, all MXU matmuls:
 per chunk of query rows, the partial all-pairs block
 vol = f1_chunk · f2ᵀ (ops.corr.all_pairs_correlation) is materialized,
-windowed with the separable one-hot interpolation matmuls of
+windowed with the separable hat-matrix matmuls of
 ops.corr.interp_window, and discarded. Transient memory is
 O(chunk · W · H2 · W2) per level (`row_chunk` bounds it; lax.map keeps
 chunks sequential), never the full volume, and there are zero gather
-HLOs — the gather formulations lost to recomputing the dots on the MXU
-when this was written (not measured on today's code).
+HLOs. The block keeps the reference's flattening, one (H2, W2) slab per
+query, which the STORED pyramid left in PR 32 (ops/corr.py): on the chip
+the slab form's minor pairs pad (12.97 ms a four-level lookup at
+N = 45,632 against 3.63 with the queries on the lanes; my chip run,
+PR 32). No benchmark cell runs this path (`auto` is the flash kernel on
+a TPU, training takes allpairs), so it has no chip time of its own.
 
 Like the reference's AlternateCorrBlock (core/corr.py:63-91), the pyramid
 pools FMAP2 (not the correlation volume) — since build_corr_pyramid now
@@ -57,8 +61,8 @@ def local_corr_level(
 
     Flash-attention-style formulation: per query-row chunk, the partial
     all-pairs block vol = f1_chunk · f2ᵀ (MXU matmul) is materialized,
-    windowed via the separable one-hot interpolation matmuls of
-    ops.corr.corr_lookup, and discarded — O(chunk·H2·W2) transient memory,
+    windowed via the separable hat-matrix matmuls of
+    ops.corr.interp_window, and discarded — O(chunk·H2·W2) transient memory,
     never the full O((HW)²) volume, and zero gather HLOs.
     """
     b, h, w, c = fmap1.shape

@@ -221,3 +221,80 @@ def test_convex_upsample_is_lane_dense_for_v5e(chip):
         if len(dims) >= 4 and max(dims) >= 46 and dims[int(m.group(2))] in (2, 8, 9):
             starved.add(m.group(0))
     assert not starved, sorted(starved)
+
+
+# ---- the all-pairs pyramid (ops/corr.py) ----------------------------------
+
+_ITEM = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "s8": 1, "u8": 1, "pred": 1}
+
+
+def _tiled_arrays(text):
+    """Every array type in a compiled module as (printed type, dims, lane
+    dimension, physical bytes / value bytes): the two minor-most
+    dimensions of the layout fill (sublane, 128) tiles as `T(s,128)` says."""
+    seen = {}
+    for m in re.finditer(
+            r"(\w+)\[([\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\)[^}]*\}", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        order = [int(o) for o in m.group(3).split(",")]
+        if m.group(1) not in _ITEM or len(order) < 2 or 0 in dims:
+            continue
+        lane, sub = dims[order[0]], dims[order[1]]
+        ts, tl = int(m.group(4)), int(m.group(5))
+        pad = (-(-lane // tl) * tl / lane) * (-(-sub // ts) * ts / sub)
+        nbytes = _ITEM[m.group(1)]
+        for d in dims:
+            nbytes *= d
+        seen[m.group(0)] = (dims, lane, pad, nbytes)
+    return seen
+
+
+def test_corr_pyramid_is_lane_dense_for_v5e(chip):
+    """`build_corr_pyramid`, twelve lookups under remat and the gradient
+    with respect to the feature maps at `v5-train-chairs`' shapes (both
+    streams' batch of 16, 368x496 / 8, 256 features): what the train
+    step's scan does with `consts["pyr"]`. Until PR 32 a level was
+    `f32[45632,46,62,1]` and the scan's carried gradient sum likewise
+    (2.24 GB each for 0.69 GB of values), the lookup's hat products
+    `bf16[45632,9,46]`; the compiler counted 4.17 GB of temporaries for
+    this function. With the queries on the lanes every level, its
+    gradient and the carried sum are within 1.15x of their values (read:
+    1.03x, `f32[16,46,62,2852]{3,0,2,1:T(8,128)}`, the batch on the
+    sublanes), and the temporaries read 1.73 GB: the four levels (0.71),
+    the carried sum (0.71), and the hats, the nine rows a tap and the
+    build's operands for the rest. The limit is 1.25 x that reading."""
+    from dexiraft_tpu.ops.corr import build_corr_pyramid
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    b, h, w, d = 16, 46, 62, 256
+
+    def loss(f1, f2, coords, weight):
+        pyr = build_corr_pyramid(f1, f2, LEVELS, RADIUS)
+
+        def body(shift, _):
+            out = jax.checkpoint(lambda p, c: p(c))(pyr, coords + shift)
+            step = 0.01 * jax.lax.stop_gradient(jnp.mean(out))
+            return shift + step, jnp.sum(out * weight)
+
+        return jnp.sum(jax.lax.scan(body, jnp.float32(0), None, length=12)[1])
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+        sds((b, h, w, d)), sds((b, h, w, d)), sds((b, h, w, 2)),
+        sds((b, h, w, LEVELS * (2 * RADIUS + 1) ** 2))).compile()
+    arrays = _tiled_arrays(compiled.as_text())
+
+    # no large array with a lone 1, the nine taps or an unpacked level
+    # width on the lanes: `f32[45632,46,62,1]`, `bf16[45632,9,46]`, ...
+    widths = {1, 2 * RADIUS + 1} | {w >> i for i in range(LEVELS)}
+    starved = sorted(k for k, (dims, lane, pad, nbytes) in arrays.items()
+                     if nbytes > 64e6 and lane in widths)
+    assert not starved, starved
+    level_dims = [[b, h >> i, w >> i, h * w] for i in range(LEVELS)]
+    levels = {k: v for k, v in arrays.items() if v[0] in level_dims}
+    assert {tuple(v[0]) for v in levels.values()} == {
+        tuple(s) for s in level_dims}, sorted(levels)
+    padded = {k: round(v[2], 2) for k, v in levels.items() if v[2] > 1.15}
+    assert not padded, padded
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 1.73e9

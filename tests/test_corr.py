@@ -91,8 +91,10 @@ def test_pyramid_shapes_floor_division():
     rng = np.random.RandomState(1)
     f = rng.randn(1, 55, 13, 4).astype(np.float32)
     pyr = build_corr_pyramid(f, f, num_levels=4, radius=4)
-    shapes = [lvl.shape[1:3] for lvl in pyr.levels]
-    assert shapes == [(55, 13), (27, 6), (13, 3), (6, 1)]
+    assert pyr.level_shapes == ((55, 13), (27, 6), (13, 3), (6, 1))
+    # the stored form: target rows and columns major, the queries last
+    assert [lvl.shape for lvl in pyr.levels] == [
+        (1, hl, wl, 55 * 13) for hl, wl in pyr.level_shapes]
 
 
 def test_lookup_finite_at_one_pixel_levels():
@@ -108,7 +110,7 @@ def test_lookup_finite_at_one_pixel_levels():
     rng = np.random.RandomState(3)
     f = rng.randn(1, 13, 17, 8).astype(np.float32)  # 104x136 at 1/8
     pyr = build_corr_pyramid(f, f, num_levels=4, radius=4)
-    assert pyr.levels[-1].shape[1:3] == (1, 2)  # degenerate level hit
+    assert pyr.level_shapes[-1] == (1, 2)  # degenerate level hit
     out = corr_lookup(pyr, coords_grid(1, 13, 17))
     assert np.isfinite(np.asarray(out)).all()
 
@@ -124,3 +126,155 @@ def test_corr_pyramid_is_jit_safe_pytree():
     pyr = build_corr_pyramid(f, f)
     out = jax.jit(corr_lookup)(pyr, coords_grid(1, 16, 16))
     assert out.shape == (1, 16, 16, 324)
+
+
+# --- the stored pyramid against an oracle that shares no code with ops/corr.py
+
+
+def _oracle_volumes(f1, f2, num_levels):
+    """The reference's way round (core/corr.py:13-27): one all-pairs
+    product in true fp32, one slab per query, and the VOLUME is pooled
+    (ops/corr.py pools fmap2 and multiplies once per level)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, w, d = f1.shape
+    corr = jnp.einsum("bijd,bkld->bijkl", f1, f2,
+                      precision=jax.lax.Precision.HIGHEST) / np.sqrt(d)
+    vols = [corr.reshape(b * h * w, h, w)]
+    for _ in range(num_levels - 1):
+        v = vols[-1]
+        n, hh, ww = v.shape
+        v = v[:, :hh // 2 * 2, :ww // 2 * 2]
+        vols.append(v.reshape(n, hh // 2, 2, ww // 2, 2).mean((2, 4)))
+    return vols
+
+
+def _oracle_lookup(vols, coords, radius):
+    """Naive bilinear sampling, tap by tap: floor, the four neighbours
+    gathered, zero outside the frame (F.grid_sample's zeros padding with
+    absolute coordinates). x offset on the slow window axis."""
+    import jax.numpy as jnp
+
+    b, h, w, _ = coords.shape
+    flat = coords.reshape(-1, 2)
+    d = jnp.arange(-radius, radius + 1, dtype=jnp.float32)
+    win = 2 * radius + 1
+    out = []
+    for i, v in enumerate(vols):
+        n, hl, wl = v.shape
+        if hl == 0 or wl == 0:  # a level pooled away: nothing inside
+            out.append(jnp.zeros((b, h, w, win * win), jnp.float32))
+            continue
+        x = jnp.broadcast_to(flat[:, 0, None, None] / 2**i + d[:, None],
+                             (n, win, win))
+        y = jnp.broadcast_to(flat[:, 1, None, None] / 2**i + d[None, :],
+                             (n, win, win))
+        x0, y0 = jnp.floor(x), jnp.floor(y)
+        fx, fy = x - x0, y - y0
+
+        def tap(yi, xi):
+            inside = (yi >= 0) & (yi < hl) & (xi >= 0) & (xi < wl)
+            val = v[jnp.arange(n)[:, None, None],
+                    jnp.clip(yi, 0, hl - 1).astype(jnp.int32),
+                    jnp.clip(xi, 0, wl - 1).astype(jnp.int32)]
+            return jnp.where(inside, val, 0.0)
+
+        window = ((1 - fy) * (1 - fx) * tap(y0, x0)
+                  + (1 - fy) * fx * tap(y0, x0 + 1)
+                  + fy * (1 - fx) * tap(y0 + 1, x0)
+                  + fy * fx * tap(y0 + 1, x0 + 1))
+        out.append(window.reshape(b, h, w, win * win))
+    return jnp.concatenate(out, axis=-1)
+
+
+def _probe_coords(rng, b, h, w):
+    """Centres inside the frame, exactly on its border pixels, between
+    the last pixel and the frame's edge, and wholly outside (every tap of
+    every level misses)."""
+    coords = np.stack(np.meshgrid(np.arange(w), np.arange(h)), -1)[None]
+    coords = coords.repeat(b, 0).astype(np.float32)
+    coords += rng.uniform(-3, 3, coords.shape).astype(np.float32)
+    flat = coords.reshape(-1, 2)
+    special = np.array([
+        [0, 0], [w - 1, h - 1], [w - 1, 0], [0, h - 1],        # border pixels
+        [w - 0.5, h - 0.5], [-0.5, -0.25], [w - 1 + 4, 2.0],   # fading out
+        [-1000, -1000], [w + 900, h + 700], [3.0, -500],       # outside
+        [2.0, 3.0], [1.5, 2.5]], np.float32)                   # integer, half
+    assert len(flat) >= 2 * len(special)
+    flat[np.arange(len(special)) * (len(flat) // len(special))] = special
+    return flat.reshape(b, h, w, 2)
+
+
+@pytest.mark.parametrize("corr_dtype", ["fp32", "bf16", "int8"])
+@pytest.mark.parametrize("shape", [(1, 5, 7), (2, 6, 9), (1, 46, 62)])
+def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype):
+    """4 levels, radius 4: (1, 46, 62) is the chairs crop's 46x62, 23x31,
+    11x15, 5x7; (2, 6, 9) ends in the 1x2 level and a 0x1 one, (1, 5, 7)
+    in 1x1 and 0x0. fp32: lookup and jax.grad with respect to BOTH
+    feature maps against the oracle. bf16/int8: the lookup against the
+    oracle on the STORED values (the lookup itself adds no rounding), and
+    for bf16 the gradient, whose cotangent passes through the bf16 cast
+    (int8's round has none: models/raft.py refuses to train with it)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, w = shape
+    d = 16
+    rng = np.random.RandomState(b * 100 + h)
+    f1 = jnp.asarray(rng.randn(b, h, w, d).astype(np.float32))
+    f2 = jnp.asarray(rng.randn(b, h, w, d).astype(np.float32))
+    coords = jnp.asarray(_probe_coords(rng, b, h, w))
+    weight = jnp.asarray(rng.randn(b, h, w, 4 * 81).astype(np.float32))
+
+    @jax.jit
+    def ours(f1, f2):
+        pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=4,
+                                 dtype=corr_dtype)
+        return corr_lookup(pyr, coords)
+
+    @jax.jit
+    def oracle(f1, f2):
+        return _oracle_lookup(_oracle_volumes(f1, f2, 4), coords, 4)
+
+    # one pyramid for the lookup and for the stored values read below: a
+    # second build may round a product at a bf16 boundary the other way
+    pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=4, dtype=corr_dtype)
+    assert pyr.level_shapes == tuple((h >> i, w >> i) for i in range(4))
+    got = np.asarray(jax.jit(corr_lookup)(pyr, coords))
+    assert got.shape == (b, h, w, 4 * 81) and got.dtype == np.float32
+
+    if corr_dtype == "fp32":
+        want = np.asarray(oracle(f1, f2))
+    else:  # the stored values, relaid to the oracle's one slab per query
+        stored = []
+        for i, (lvl, (hl, wl)) in enumerate(zip(pyr.levels, pyr.level_shapes)):
+            v = np.asarray(lvl).astype(np.float32)
+            if pyr.scales is not None:
+                v = v * np.float32(pyr.scales[i])
+            stored.append(jnp.asarray(
+                np.moveaxis(v, -1, 1).reshape(b * h * w, hl, wl)))
+        want = np.asarray(jax.jit(
+            lambda vols: _oracle_lookup(vols, coords, 4))(stored))
+        # and the stored values are the oracle's, rounded once
+        full = np.asarray(_oracle_volumes(f1, f2, 1)[0])
+        step = {"bf16": 2.0**-8 * np.abs(full).max(),
+                "int8": np.abs(full).max() / 127 * 0.51}[corr_dtype]
+        assert np.abs(np.asarray(stored[0]) - full).max() <= step + 1e-5
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-5)
+    assert np.abs(want).max() > 0.1  # the probe reads something
+
+    if corr_dtype == "int8":
+        return
+    grad = jax.jit(jax.grad(
+        lambda a, c: jnp.sum(ours(a, c) * weight), (0, 1)))
+    grad_oracle = jax.jit(jax.grad(
+        lambda a, c: jnp.sum(oracle(a, c) * weight), (0, 1)))
+    for g, want_g in zip(grad(f1, f2), grad_oracle(f1, f2)):
+        g, want_g = np.asarray(g), np.asarray(want_g)
+        scale = np.abs(want_g).max()
+        assert scale > 0.1
+        # fp32: sums of up to 4 x 81 x H*W products in another order;
+        # bf16: each level's cotangent is rounded to bf16 on its way back
+        tol = 1e-5 if corr_dtype == "fp32" else 2.0**-7
+        np.testing.assert_allclose(g, want_g, rtol=0, atol=tol * scale)

@@ -72,7 +72,7 @@ def test_manifest_names_the_cell_and_every_metric_of_section_6(manifest):
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kanana-2-30b-a3b-share8", "train-pack8k", 1)
-    assert len(manifest["workloads"]) == 6
+    assert manifest["workloads"].index(cell) == 4  # later cells follow it
     by_name = {m["name"]: m for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + NEW + ["train_samples_per_s"]:
         assert CELL in by_name[name]["workloads"], name
@@ -91,11 +91,13 @@ def test_manifest_names_the_second_cell_and_what_it_reports(manifest):
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL2)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "trinity-mini-share8", "train-pack32k", 1)
-    assert manifest["workloads"][-1] == cell  # entries are added at the end
+    assert manifest["workloads"][5] == cell  # entries are added at the end
     by_name = {m["name"]: m
                for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + SHARED2 + ["train_samples_per_s"]:
-        assert by_name[name]["workloads"][-2:] == [CELL, CELL2], name
+        cells = by_name[name]["workloads"]
+        at = cells.index(CELL)
+        assert cells[at:at + 2] == [CELL, CELL2], name
     assert by_name["lm_attn_device_ms"]["workloads"] == [CELL]
     for name in NEW2:
         assert by_name[name]["workloads"] == [CELL2]
