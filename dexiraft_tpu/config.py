@@ -430,6 +430,74 @@ class AfmoeConfig:
     v_head_dim = property(lambda self: self.head_dim)
 
 
+@dataclasses.dataclass(frozen=True)
+class EvaByteConfig:
+    """An EvaByte decoder (`model_type: evabyte`): a dense byte-level
+    model whose mixer is EVA chunked linear attention (one softmax over
+    the exact keys of the query's own `window_size` window and the
+    `chunk_size` summaries of every chunk before it), with
+    `num_pred_heads` heads that predict the next bytes at once, norms
+    with a unit offset and every layer a SwiGLU (models/lm/, docs/lm.md).
+    Keys and defaults are EvaByte's published `config.json`.
+
+    The share is the heads held (`heads_held`, of keys and values too:
+    `num_key_value_heads` equals `num_attention_heads`); the SwiGLU, the
+    norms, the embedding and the 320-row vocabulary are whole on every
+    chip. The model has no experts: the properties at the end answer
+    `LMConfig`'s names for what the shared modules and the benchmark's
+    runner read, truthfully (none held of none, every layer dense).
+    """
+
+    vocab_size: int = 320
+    hidden_size: int = 4096
+    num_hidden_layers: int = 32
+    intermediate_size: int = 11_008
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    window_size: int = 2048
+    chunk_size: int = 16
+    num_pred_heads: int = 8
+    rope_theta: float = 1e5
+    rms_norm_eps: float = 1e-5
+    norm_add_unit_offset: bool = True
+    fp32_skip_add: bool = True
+    init_std: float = 0.01275
+    seq_len: int = 32_768
+    heads_held: Optional[Tuple[int, int]] = None
+    mixed_precision: bool = False
+    remat: bool = False
+    attn_block: int = 1024
+
+    def __post_init__(self):
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError(
+                f"{self.num_key_value_heads} key/value heads for "
+                f"{self.num_attention_heads} query heads: EVA's summaries "
+                "are a head's own")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError(f"hidden_size {self.hidden_size} does not "
+                             f"divide over {self.num_attention_heads} heads")
+        if self.chunk_size < 1 or self.window_size % self.chunk_size:
+            raise ValueError(f"a window of {self.window_size} positions is "
+                             f"not whole chunks of {self.chunk_size}")
+        if self.num_pred_heads < 1:
+            raise ValueError("num_pred_heads counts from 1")
+        _hold(self, "heads_held", self.num_attention_heads)
+        _whole_attention_blocks(self)
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    # ---- LMConfig's names for what the shared modules read ----
+    qk_head_dim = property(lambda self: self.head_dim)
+    v_head_dim = property(lambda self: self.head_dim)
+    first_k_dense_replace = property(lambda self: self.num_hidden_layers)
+    n_routed_experts = property(lambda self: 0)
+    experts_held = property(lambda self: (0, 0))
+    moe_intermediate_size = property(lambda self: 0)
+
+
 def _hold(cfg, name: str, whole: int) -> None:
     """A `(first, count)` share of `whole`, or None for all of it."""
     held = getattr(cfg, name)
@@ -473,15 +541,35 @@ def trinity_mini_toy(**kw) -> AfmoeConfig:
     return AfmoeConfig(**{**base, **kw})
 
 
+def evabyte(**kw) -> EvaByteConfig:
+    """EvaByte 6.5B as published; `heads_held` and `num_hidden_layers`
+    cut it to a chip's share
+    (benchmarks/configs/evabyte-6.5b-share4.json)."""
+    return EvaByteConfig(**kw)
+
+
+def evabyte_toy(**kw) -> EvaByteConfig:
+    """The CPU tests' size: every mechanism, toy widths. 8 heads of 8, a
+    row of four windows of 32 positions, chunks of 4 (so the tests'
+    documents start inside a chunk and inside a window), 3 bytes
+    predicted at once."""
+    base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=4,
+                intermediate_size=96, num_attention_heads=8,
+                num_key_value_heads=8, window_size=32, chunk_size=4,
+                num_pred_heads=3, seq_len=128, attn_block=32)
+    return EvaByteConfig(**{**base, **kw})
+
+
 # the configurations of models/lm: what `family_of` and `train` take for a
 # language model
-LM_CONFIGS = (LMConfig, AfmoeConfig)
+LM_CONFIGS = (LMConfig, AfmoeConfig, EvaByteConfig)
 
 # language models `train --variant` takes beside VARIANTS. Not in
 # VARIANTS: eval, serve and video have no path for them (ROADMAP.md).
 LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
                "trinity-mini": trinity_mini,
-               "trinity-mini-toy": trinity_mini_toy}
+               "trinity-mini-toy": trinity_mini_toy,
+               "evabyte": evabyte, "evabyte-toy": evabyte_toy}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,7 +1,7 @@
 """The plain reference of the language models: forward, loss and gradients
 in `jax.numpy`, float32, under `jax.default_matmul_precision("highest")`.
 
-Two architectures, picked by the configuration's type. An `LMConfig`:
+Three architectures, picked by the configuration's type. An `LMConfig`:
 written from the published `config.json` of
 kanana-2-30b-a3b-instruct-2601 (`model_type: deepseek_v3`). An
 `AfmoeConfig`: from Trinity-Mini's (`model_type: afmoe`) and, for what
@@ -9,7 +9,11 @@ the config does not carry (the four norms and where they sit, the gate,
 the QK-norm, rotary embedding on sliding layers only, the embedding's
 scale), from the model's published modelling code (`transformers`
 `models/afmoe`; docs/lm.md has both sets of equations and what is
-`assumed`). Independent of models/lm: no Flax module, no kernel, no
+`assumed`). An `EvaByteConfig`: from EvaByte's (`model_type: evabyte`)
+and the layer as docs/lm.md writes it down (`eva_attention` below: one
+head at a time, the pooling as a dense `[chunks, positions]` matrix,
+one dense row of scores over every key and every summary with both
+masks written out). Independent of models/lm: no Flax module, no kernel, no
 table, no sorting, no recomputation. Every held expert is applied to
 every token and weighted by `w_i` where the router chose it and by 0
 elsewhere; attention makes the dense `[heads, query rows, all keys]`
@@ -56,7 +60,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dexiraft_tpu.config import AfmoeConfig
+from dexiraft_tpu.config import AfmoeConfig, EvaByteConfig
 
 Share = Optional[Tuple[int, int]]
 
@@ -171,6 +175,79 @@ def gated_attention(p, x, positions, segment_ids, cfg, heads: int,
     return (out * jax.nn.sigmoid(x @ p["wg"])) @ p["wo"]
 
 
+def eva_attention(p, x, positions, segment_ids, cfg, heads: int,
+                  block: Optional[int] = None):
+    """One sequence of EvaByte's mixer: x [S, D]; `p` holds `heads`
+    heads' columns, `phi` and `mu_k` their rows. Windows and chunks are
+    cut by the row's positions t. Per head:
+
+        chunk c:  D(c) = the document of the chunk's last non-pad position
+                  a = softmax over the chunk's positions of document D(c)
+                      of (k . phi) / sqrt(d);  kk_c = a k + mu, vv_c = a v
+        query n:  one softmax over the keys m <= n of n's document in n's
+                  window, and the summaries of the chunks c of n's
+                  document (D(c) = d(n)) that lie in earlier windows
+
+    which is what the document gives alone at the same row offset with
+    every other position pad (tests/test_zz_lm_eva.py). A head at a
+    time, one after another, each recomputed in the backward: a head's
+    `[block rows, S + chunks]` scores are all that is held."""
+    hd, s = cfg.head_dim, x.shape[0]
+    chunk, window = cfg.chunk_size, cfg.window_size
+    q = _rope_half((x @ p["wq"]).reshape(s, heads, hd), positions,
+                   cfg.rope_theta)
+    k = _rope_half((x @ p["wk"]).reshape(s, heads, hd), positions,
+                   cfg.rope_theta)
+    v = (x @ p["wv"]).reshape(s, heads, hd)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, x.dtype))
+    t = jnp.arange(s)
+    c = jnp.arange(-(-s // chunk))
+    in_chunk = t[None, :] // chunk == c[:, None]              # [chunks, S]
+    last = jnp.max(jnp.where(in_chunk & (segment_ids > 0)[None], t[None],
+                             -1), axis=1)
+    chunk_doc = jnp.where(last >= 0, segment_ids[jnp.maximum(last, 0)], 0)
+    pooled = in_chunk & (segment_ids[None, :] == chunk_doc[:, None])
+    chunk_window = c * chunk // window
+
+    def head(args):
+        q_h, k_h, v_h, phi, mu = args
+        a = jax.nn.softmax(jnp.where(pooled, (k_h @ phi * scale)[None, :],
+                                     -jnp.inf), axis=1)
+        kk, vv = a @ k_h + mu, a @ v_h
+
+        def rows(q_rows, at, seg_rows):
+            exact = ((seg_rows[:, None] == segment_ids[None, :])
+                     & (at[:, None] // window == t[None, :] // window)
+                     & (t[None, :] <= at[:, None]))
+            summary = ((chunk_doc[None, :] == seg_rows[:, None])
+                       & (chunk_window[None, :] < at[:, None] // window))
+            scores = jnp.concatenate(
+                [jnp.where(exact, q_rows @ k_h.T * scale, -jnp.inf),
+                 jnp.where(summary, q_rows @ kk.T * scale, -jnp.inf)],
+                axis=1)
+            return jax.nn.softmax(scores, axis=1) @ jnp.concatenate(
+                [v_h, vv], axis=0)
+
+        return _by_blocks(rows, block, q_h, t, segment_ids)
+
+    per_head = (jnp.swapaxes(q, 0, 1), jnp.swapaxes(k, 0, 1),
+                jnp.swapaxes(v, 0, 1), p["phi"], p["mu_k"])
+    out = jax.lax.map(jax.checkpoint(head), per_head)         # [heads, S, d]
+    return jnp.swapaxes(out, 0, 1).reshape(s, heads * hd) @ p["wo"]
+
+
+def eva_layer(p, x, positions, segment_ids, cfg, heads_held: Share = None,
+              block: Optional[int] = None):
+    """h = x + Attn(N1(x)); x' = h + SwiGLU(N2(h)); one sequence. The
+    norms' gains are `1 + g` under `norm_add_unit_offset`."""
+    eps = cfg.rms_norm_eps
+    h = x + eva_attention(
+        p["attn"], _rms_norm(x, _gain(p["attn_norm"], cfg), eps), positions,
+        segment_ids, cfg, (heads_held or cfg.heads_held)[1], block)
+    normed = _rms_norm(h, _gain(p["ffn_norm"], cfg), eps)
+    return h + _by_blocks(lambda rows: _swiglu(rows, p["mlp"]), block, normed)
+
+
 def routing(p, x, cfg, bias=None):
     """(chosen expert ids [S, k], their weights [S, k]) over ALL experts."""
     scores = jax.nn.sigmoid(x @ p["router"])
@@ -234,6 +311,9 @@ def afmoe_layer(p, x, positions, segment_ids, cfg, index: int,
 
 def _layer_of(cfg, i: int, block: Optional[int] = None, **share):
     """Layer `i` as `f(p, x, positions, segment_ids)`."""
+    if isinstance(cfg, EvaByteConfig):
+        return lambda p, x, pos, seg: eva_layer(p, x, pos, seg, cfg,
+                                                block=block, **share)
     if isinstance(cfg, AfmoeConfig):
         return lambda p, x, pos, seg: afmoe_layer(p, x, pos, seg, cfg, i,
                                                   block=block, **share)
@@ -253,29 +333,45 @@ def _embed_scale(cfg, dtype):
     return jnp.asarray(cfg.hidden_size ** 0.5 if scaled else 1.0, dtype)
 
 
-def _targets(tokens, segment_ids):
-    """Position t predicts token t+1 where both are in one document."""
-    valid = (segment_ids[:-1] == segment_ids[1:]) & (segment_ids[:-1] > 0)
-    return tokens[1:], valid
+def _pred_heads(cfg) -> int:
+    """Tokens a position predicts at once: the head's vocabularies."""
+    return getattr(cfg, "num_pred_heads", 1)
+
+
+def _gain(g, cfg):
+    """A norm's gain from its parameter: `1 + g` under
+    `norm_add_unit_offset`."""
+    return 1.0 + g if getattr(cfg, "norm_add_unit_offset", False) else g
+
+
+def _targets(tokens, segment_ids, ahead: int = 1):
+    """(targets, valid), each [S, ahead]: position t predicts, with its
+    head j, token t+1+j where that lies in the row and in t's document;
+    pad predicts nothing."""
+    s = tokens.shape[0]
+    t = jnp.arange(s)[:, None] + 1 + jnp.arange(ahead)[None, :]
+    inside = t < s
+    t = jnp.minimum(t, s - 1)
+    valid = (inside & (segment_ids[t] == segment_ids[:, None])
+             & (segment_ids[:, None] > 0))
+    return tokens[t], valid
 
 
 def head_loss_sum(p, x, tokens, segment_ids, cfg,
                   block: Optional[int] = None):
     """Sum of the cross-entropies of one sequence's targets."""
-    targets, valid = _targets(tokens, segment_ids)
+    ahead = _pred_heads(cfg)
+    targets, valid = _targets(tokens, segment_ids, ahead)
 
     def rows(x_rows, targets, valid):
-        logits = (_rms_norm(x_rows, p["final_norm"], cfg.rms_norm_eps)
-                  @ p["head"])
+        logits = (_rms_norm(x_rows, _gain(p["final_norm"], cfg), cfg.rms_norm_eps)
+                  @ p["head"]).reshape(x_rows.shape[0], ahead, -1)
         logp = jax.nn.log_softmax(logits, axis=-1)
-        picked = jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
+        picked = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
         return jnp.where(valid, picked, 0.0)
 
-    # the last position, which predicts nothing, rides along
-    last = jnp.zeros((1,), targets.dtype)
-    return -jnp.sum(_by_blocks(rows, block, x,
-                               jnp.concatenate([targets, last]),
-                               jnp.concatenate([valid, last > 0])))
+    return -jnp.sum(_by_blocks(rows, block, x, targets, valid))
 
 
 def _cast(tree, dtype):
@@ -304,14 +400,14 @@ def logits(params, batch: Dict[str, Any], cfg, dtype=jnp.float32, **share):
             x = hidden_states(params, batch["tokens"][b],
                               batch["positions"][b], batch["segment_ids"][b],
                               cfg, **share)
-            rows.append(_rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-                        @ params["head"])
+            rows.append(_rms_norm(x, _gain(params["final_norm"], cfg),
+                                  cfg.rms_norm_eps) @ params["head"])
         return jnp.stack(rows)
 
 
-def n_targets(batch) -> jax.Array:
-    seg = batch["segment_ids"]
-    return jnp.sum((seg[:, :-1] == seg[:, 1:]) & (seg[:, :-1] > 0))
+def n_targets(batch, ahead: int = 1) -> jax.Array:
+    return sum(jnp.sum(_targets(tok, seg, ahead)[1]) for tok, seg in
+               zip(batch["tokens"], batch["segment_ids"]))
 
 
 def loss(params, batch: Dict[str, Any], cfg, dtype=jnp.float32, **share):
@@ -324,7 +420,8 @@ def loss(params, batch: Dict[str, Any], cfg, dtype=jnp.float32, **share):
                              ("tokens", "positions", "segment_ids"))
             x = hidden_states(params, tok, pos, seg, cfg, **share)
             total = total + head_loss_sum(params, x, tok, seg, cfg)
-        return (total / jnp.maximum(n_targets(batch), 1)).astype(jnp.float32)
+        return (total / jnp.maximum(n_targets(batch, _pred_heads(cfg)), 1)
+                ).astype(jnp.float32)
 
 
 def loss_and_grads(params, batch, cfg, dtype=jnp.float32, **share):
@@ -341,7 +438,7 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
     docstring)."""
     params = _cast(params, dtype)
     n_layers = cfg.num_hidden_layers
-    denom = jnp.maximum(n_targets(batch), 1).astype(dtype)
+    denom = jnp.maximum(n_targets(batch, _pred_heads(cfg)), 1).astype(dtype)
     scale = _embed_scale(cfg, dtype)
 
     def vjp_of(run):
@@ -391,21 +488,24 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
 
 
 def take_share(params, cfg, heads_held: Tuple[int, int],
-               experts_held: Tuple[int, int], kv_heads_held: Share = None):
+               experts_held: Share = None, kv_heads_held: Share = None):
     """From the parameters of a model that holds everything, the tree of
     the chip that holds `heads_held` and `experts_held`: the held heads'
     columns of `wq` and `wkvb` (afmoe: of `wq` and `wg`, and the held
-    key/value heads' columns of `wk` and `wv`), their rows of `wo`, the
-    held experts' matrices. The router, the latent projection, the
+    key/value heads' columns of `wk` and `wv`; evabyte: of `wq`, `wk`
+    and `wv`, and the held heads' rows of `phi` and `mu_k`), their rows
+    of `wo`, the held experts' matrices. The router, the latent projection, the
     norms, the shared experts, the embedding and the head are whole on
     every chip."""
-    e0, en = experts_held
-
     def heads(mat, per_head, axis, held=heads_held):
         lo, hi = held[0] * per_head, (held[0] + held[1]) * per_head
         return mat[:, lo:hi] if axis == 1 else mat[lo:hi]
 
-    if isinstance(cfg, AfmoeConfig):
+    if isinstance(cfg, EvaByteConfig):
+        hd = cfg.head_dim
+        cut = {"wq": (hd, 1), "wk": (hd, 1), "wv": (hd, 1), "wo": (hd, 0),
+               "phi": (1, 0), "mu_k": (1, 0)}
+    elif isinstance(cfg, AfmoeConfig):
         hd = cfg.head_dim
         cut = {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
                "wk": (hd, 1, kv_heads_held), "wv": (hd, 1, kv_heads_held)}
@@ -420,6 +520,7 @@ def take_share(params, cfg, heads_held: Tuple[int, int],
         lp["attn"] = dict(lp["attn"], **{
             k: heads(lp["attn"][k], *how) for k, how in cut.items()})
         if "moe" in lp:
+            e0, en = experts_held
             experts = dict(lp["moe"]["experts"])
             for k in ("w_gate", "w_up", "w_down"):
                 experts[k] = experts[k][e0:e0 + en]

@@ -1,5 +1,5 @@
-"""The two mixers, for the heads this chip holds: `mixer_of` picks by the
-configuration's type.
+"""The three mixers, for the heads this chip holds: `mixer_of` picks by
+the configuration's type.
 
 **`LatentAttention`** (`LMConfig`): multi-head latent attention without a
 query LoRA (`q_lora_rank: null`).
@@ -29,6 +29,18 @@ sigmoid gate on its output and an RMSNorm on every query and key head.
 and `W_v` the columns of the key/value heads those read
 (`cfg.kv_heads_held`): a key/value head serves several query heads, so
 the chips that hold its query heads each hold a copy of it.
+
+**`EvaAttention`** (`EvaByteConfig`): EVA chunked linear attention
+(ops/lm_eva.py has the equations).
+
+    q, k = rope(W_q x), rope(W_k x);  v = W_v x     (half rotation, over all d)
+    per head: phi, mu in R^d, learned (`adaptive_phi`, `adaptive_mu_k`)
+    o = one softmax over the exact keys of the query's own window and
+        the phi-pooled, mu-shifted summaries of its document's chunks in
+        the windows before;   out = W_o concat_heads(o)
+
+`W_q`, `W_k`, `W_v` hold the held heads' columns, `W_o` their rows, `phi`
+and `mu_k` their rows: a head's summaries are its own.
 """
 
 from __future__ import annotations
@@ -39,10 +51,11 @@ import jax.numpy as jnp
 
 from typing import Optional
 
-from dexiraft_tpu.config import AfmoeConfig, LMConfig
+from dexiraft_tpu.config import AfmoeConfig, EvaByteConfig, LMConfig
 from dexiraft_tpu.models.lm.layers import (Weights, rms_norm, rope_half,
                                            rope_interleaved)
 from dexiraft_tpu.ops.lm_attention import document_attention
+from dexiraft_tpu.ops.lm_eva import eva_attention
 
 
 class LatentAttention(Weights):
@@ -118,8 +131,34 @@ class GatedAttention(Weights):
             return gated @ self.w("wo", (heads * hd, d))
 
 
+class EvaAttention(Weights):
+    cfg: EvaByteConfig = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array, positions: jax.Array,
+                 segment_ids: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        b, s, d = x.shape
+        heads, hd = cfg.heads_held[1], cfg.head_dim
+        with jax.named_scope("lm/eva/proj"):
+            q, k, v = ((x @ self.w(name, (d, heads * hd))
+                        ).reshape(b, s, heads, hd)
+                       for name in ("wq", "wk", "wv"))
+            q = rope_half(q, positions, cfg.rope_theta)
+            k = rope_half(k, positions, cfg.rope_theta)
+            phi, mu = self.w("phi", (heads, hd)), self.w("mu_k", (heads, hd))
+        out = eva_attention(q, k, v, phi, mu, segment_ids,
+                            window=cfg.window_size, chunk=cfg.chunk_size,
+                            scale=hd ** -0.5, block=cfg.attn_block)
+        with jax.named_scope("lm/eva/proj"):
+            return out.reshape(b, s, heads * hd) @ self.w(
+                "wo", (heads * hd, d))
+
+
 def mixer_of(cfg, layer: int, **kw) -> nn.Module:
     """Layer `layer`'s attention module (named `attn`)."""
+    if isinstance(cfg, EvaByteConfig):
+        return EvaAttention(cfg=cfg, name="attn", **kw)
     if isinstance(cfg, AfmoeConfig):
         return GatedAttention(cfg=cfg, window=cfg.layer_window(layer),
                               name="attn", **kw)

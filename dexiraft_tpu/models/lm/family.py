@@ -53,7 +53,8 @@ class LMFamily:
     def loss_fn(self, params: Any, batch_stats: Any,
                 batch: Dict[str, jax.Array], rng: jax.Array):
         tokens, seg = batch["tokens"], batch["segment_ids"]
-        targets, weight = next_token_targets(tokens, seg)
+        targets, weight = next_token_targets(
+            tokens, seg, getattr(self.cfg, "num_pred_heads", 1))
         total, counters = self.model.apply(
             {"params": params, "batch_stats": batch_stats},
             tokens, batch["positions"], seg, targets=(targets, weight))

@@ -66,14 +66,43 @@ class Weights(nn.Module):
         return self.param(name, init, shape, jnp.float32).astype(self.dtype)
 
 
+# rows of one block of a SwiGLU whose `[rows, width]` intermediates pass
+# `_WHOLE_BYTES` each: `head_loss`'s block
+_ROW_BLOCK = 8192
+_WHOLE_BYTES = 512 * 1024 * 1024
+
+
 class SwiGLU(Weights):
-    """W_down(silu(W_gate x) * W_up x)."""
+    """W_down(silu(W_gate x) * W_up x).
+
+    Gate, up and their product are `[rows, width]` each, and the
+    backward holds their three cotangents beside them. Where one of them
+    passes `_WHOLE_BYTES` (32,768 rows of 11,008 in bf16 are 688 MiB, six
+    of them 4.3 GB; 32,768 rows of 6,144 are 384 MiB and stay whole) and
+    the rows are whole blocks of `_ROW_BLOCK`, the rows are walked a
+    block at a time, each a `jax.checkpoint`: a block's intermediates
+    exist for that block only, forward and backward. Chosen from the
+    shapes; the result is the same."""
 
     width: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         d = x.shape[-1]
-        gate = x @ self.w("w_gate", (d, self.width))
-        up = x @ self.w("w_up", (d, self.width))
-        return (jax.nn.silu(gate) * up) @ self.w("w_down", (self.width, d))
+        n = x.size // d
+        if (n * self.width * x.dtype.itemsize <= _WHOLE_BYTES
+                or n % _ROW_BLOCK):
+            gate = x @ self.w("w_gate", (d, self.width))
+            up = x @ self.w("w_up", (d, self.width))
+            return (jax.nn.silu(gate) * up) @ self.w("w_down",
+                                                     (self.width, d))
+        w_gate, w_up = (self.w(name, (d, self.width))
+                        for name in ("w_gate", "w_up"))
+        w_down = self.w("w_down", (self.width, d))
+
+        @jax.checkpoint
+        def rows(h):
+            return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+
+        return jax.lax.map(rows, x.reshape(-1, _ROW_BLOCK, d)
+                           ).reshape(x.shape)

@@ -51,6 +51,12 @@ out, the blocks between that hold no visible pair are computed and
 masked. Each block is a `jax.checkpoint`. Keys and values are repeated
 to the query heads.
 
+Either path also gives, asked with `return_lse`, the log of each row's
+sum of `exp(score)` beside the normalised output, differentiable like it:
+what a caller needs to carry the same softmax on over further keys
+(ops/lm_eva.py). `d lse / d s = p`, so its cotangent adds `dlse * p` to
+`dS`: the backward kernels are handed `delta - dlse` and do not change.
+
 Both: scores, the mask and the softmax statistics are fp32 whatever the
 inputs are; the probabilities are cast to `v`'s dtype for the second
 matmul; pad positions share id 0 and see each other.
@@ -79,10 +85,12 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 # ---- the XLA path ----------------------------------------------------------
 
 
-def _block(q, k, v, seg_q, seg_k, first_row, first_key, scale, window):
+def _block(q, k, v, seg_q, seg_k, first_row, first_key, scale, window,
+           with_lse=False):
     """q [G, Q, D], k [G, K, D], v [G, K, Dv], G = batch x heads; seg_q
     [G, Q], seg_k [G, K]. The block's first query is row `first_row` of
-    the sequence, its first key row `first_key`."""
+    the sequence, its first key row `first_key`. `with_lse`: also the
+    rows' log-sum-exp `[G, Q]`, fp32."""
     s = jnp.einsum("gqd,gkd->gqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     rows = (first_row + jnp.arange(q.shape[1]))[:, None]
@@ -99,8 +107,11 @@ def _block(q, k, v, seg_q, seg_k, first_row, first_key, scale, window):
     top = jax.lax.optimization_barrier(
         jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
     e = jnp.exp(s - top)
-    p = e / jnp.sum(e, axis=-1, keepdims=True)
-    return jnp.einsum("gqk,gkd->gqd", p.astype(v.dtype), v)
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    out = jnp.einsum("gqk,gkd->gqd", (e / total).astype(v.dtype), v)
+    if with_lse:
+        return out, (top + jnp.log(total))[..., 0]
+    return out
 
 
 def _fold(x):
@@ -116,8 +127,8 @@ def _unfold(x, b):
 
 
 def xla_document_attention(q, k, v, segment_ids, *, scale: float,
-                           block: int, window: Optional[int] = None
-                           ) -> jax.Array:
+                           block: int, window: Optional[int] = None,
+                           return_lse: bool = False):
     """`document_attention`'s XLA path (module docstring)."""
     b, seq, heads, _ = q.shape
     block = min(block, seq)
@@ -128,7 +139,7 @@ def xla_document_attention(q, k, v, segment_ids, *, scale: float,
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     q, k, v = _fold(q), _fold(k), _fold(v)
     seg = jnp.repeat(segment_ids, heads, axis=0)
-    run = jax.checkpoint(_block, static_argnums=(5, 6, 7, 8))
+    run = jax.checkpoint(_block, static_argnums=(5, 6, 7, 8, 9))
     out = []
     for first in range(0, seq, block):
         end = first + block
@@ -136,8 +147,17 @@ def xla_document_attention(q, k, v, segment_ids, *, scale: float,
               else max(0, first - window + 1) // block * block)
         out.append(run(q[:, first:end], k[:, lo:end], v[:, lo:end],
                        seg[:, first:end], seg[:, lo:end], first, lo, scale,
-                       window))
-    return _unfold(jnp.concatenate(out, axis=1), b)
+                       window, return_lse))
+    if not return_lse:
+        return _unfold(jnp.concatenate(out, axis=1), b)
+    return (_unfold(jnp.concatenate([o for o, _ in out], axis=1), b),
+            _unfold_rows(jnp.concatenate([l for _, l in out], axis=1), b))
+
+
+def _unfold_rows(x, b):
+    """A row statistic `[B*H, S]` -> `[B, S, H]`."""
+    g, seq = x.shape
+    return jnp.swapaxes(x.reshape(b, g // b, seq), 1, 2)
 
 
 def _group(heads: int, kv_heads: int) -> int:
@@ -529,13 +549,18 @@ def _forward(st: _Static, q, k, v, segment_ids, table: BlockTable):
 
 @functools.partial(jax.jit, static_argnums=0)
 def _backward(st: _Static, q, k, v, segment_ids, table: BlockTable, o, lse,
-              do):
+              do, dlse=None):
+    """`dlse`: the cotangent of the row log-sum-exp where that is an
+    output too. d lse / d s = p, so it adds `dlse * p` to `dS = p * (dP -
+    delta)`: the kernels are handed `delta - dlse` and are as they were."""
     g, seq, d_qk = q.shape
     d_v = v.shape[-1]
     hb, bq, bk = st.hb, st.bq, st.bk
     steps_a_row = st.heads // hb
     nq, nk = seq // bq, seq // bk
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    if dlse is not None:
+        delta = delta - dlse
     seg_col, seg_row = _lanes(segment_ids), segment_ids[:, None, :]
 
     sp = _query_major_specs(st, nq, d_qk, d_v)
@@ -606,9 +631,32 @@ def _flash_bwd(st, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_lse(st: _Static, q, k, v, segment_ids, table: BlockTable):
+    """`_flash` with the row log-sum-exp `[G, S]` as a second output,
+    for a caller that goes on with the softmax over further keys."""
+    return _forward(st, q, k, v, segment_ids, table)
+
+
+def _flash_lse_fwd(st, q, k, v, segment_ids, table):
+    o, lse = _forward(st, q, k, v, segment_ids, table)
+    return (o, lse), (q, k, v, segment_ids, table, o, lse)
+
+
+def _flash_lse_bwd(st, res, cotangents):
+    q, k, v, segment_ids, table, o, lse = res
+    do, dlse = cotangents
+    dq, dk, dv = _backward(st, q, k, v, segment_ids, table, o, lse, do, dlse)
+    return dq, dk, dv, None, None
+
+
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
 def flash_document_attention(q, k, v, segment_ids, *, scale: float,
                              window: Optional[int] = None,
-                             interpret: bool = False) -> jax.Array:
+                             interpret: bool = False,
+                             return_lse: bool = False):
     """`document_attention`'s kernel path (module docstring), for shapes
     `kernel_blocks` takes. `interpret=True` runs the kernels in Pallas's
     interpreter: the tests' way to them without a chip."""
@@ -618,15 +666,18 @@ def flash_document_attention(q, k, v, segment_ids, *, scale: float,
         raise ValueError(f"no kernel for {seq} positions of widths {d_qk} "
                          f"and {v.shape[-1]}")
     st = _Static(heads, float(scale), *blocks, interpret, k.shape[2], window)
-    out = _flash(st, _fold(q), _fold(k), _fold(v), segment_ids,
-                 block_table(segment_ids, *blocks, window))
-    return _unfold(out, b)
+    args = (st, _fold(q), _fold(k), _fold(v), segment_ids,
+            block_table(segment_ids, *blocks, window))
+    if not return_lse:
+        return _unfold(_flash(*args), b)
+    out, lse = _flash_lse(*args)
+    return _unfold(out, b), _unfold_rows(lse, b)
 
 
 def document_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        segment_ids: jax.Array, *, scale: float,
-                       block: int, window: Optional[int] = None
-                       ) -> jax.Array:
+                       block: int, window: Optional[int] = None,
+                       return_lse: bool = False):
     """softmax(q k^T * scale) v over the earlier tokens of the same
     document, all of them or those fewer than `window` positions back.
     q `[B, S, H, D]`, k `[B, S, H_kv, D]`, v `[B, S, H_kv, Dv]` with
@@ -634,12 +685,16 @@ def document_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     segment_ids `[B, S]` (pad positions share id 0 and see each other:
     their output is never read). Returns `[B, S, H, Dv]` in v's dtype.
     `block` is the XLA path's block of query rows; the kernel's blocks
-    are its own."""
+    are its own. `return_lse`: also the log of each row's sum of
+    `exp(score)` over its visible keys, `[B, S, H]` fp32, through which
+    the gradient flows too: what a caller needs to go on with the same
+    softmax over further keys (ops/lm_eva.py)."""
     if window is not None and window < 1:
         raise ValueError(f"a window of {window} positions holds no key")
     if (jax.default_backend() == "tpu"
             and kernel_blocks(q.shape[1], q.shape[-1], v.shape[-1])):
         return flash_document_attention(q, k, v, segment_ids, scale=scale,
-                                        window=window)
+                                        window=window, return_lse=return_lse)
     return xla_document_attention(q, k, v, segment_ids, scale=scale,
-                                  block=block, window=window)
+                                  block=block, window=window,
+                                  return_lse=return_lse)

@@ -16,6 +16,8 @@ one stage; presets supply the per-stage hyperparameters:
       sliding_attention sliding_attention sliding_attention full_attention \
       --heads_held 0 4 --experts_held 0 16 --vocab_size 25024 \
       --batch_size 1 --precision bf16 --remat
+  python -m dexiraft_tpu train --variant evabyte --tokens bytes.npz \
+      --layers 4 --heads_held 0 8 --batch_size 1 --precision bf16 --remat
 
 The loop is the reference's (train.py:163-215) re-shaped for TPU: one
 jitted sharded step (forward + loss + backward + optimizer), batches
@@ -75,9 +77,9 @@ def build_parser() -> argparse.ArgumentParser:
                    default="none", help="stage hyperparameter preset")
     p.add_argument("--variant", default="v1",
                    choices=sorted(VARIANTS) + sorted(LM_VARIANTS),
-                   help="v1..v5: RAFT; kanana2, trinity-mini: the language "
-                        "models of models/lm (docs/lm.md), trained on "
-                        "--tokens")
+                   help="v1..v5: RAFT; kanana2, trinity-mini, evabyte: the "
+                        "language models of models/lm (docs/lm.md), "
+                        "trained on --tokens")
     # the language model's own flags (refused for the RAFT variants)
     p.add_argument("--tokens", default=None,
                    help="language models: token file (.npz of `tokens` and "
@@ -85,7 +87,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "into rows of --seq_len")
     p.add_argument("--seq_len", type=int, default=None,
                    help="language models: positions a row (default: "
-                        "kanana2 8192, trinity-mini 32768)")
+                        "kanana2 8192, trinity-mini and evabyte 32768)")
     p.add_argument("--layers", type=int, default=None,
                    help="language models: decoder layers held (default: "
                         "all)")
@@ -109,8 +111,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "(default: those its query heads read)")
     p.add_argument("--experts_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="language models: the routed experts this chip holds of "
-                        "an expert-parallel group (default: all)")
+                   help="kanana2, trinity-mini: the routed experts this chip "
+                        "holds of an expert-parallel group (default: all)")
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="allpairs",
@@ -332,11 +334,12 @@ _RAFT_ONLY = ("stage", "preset", "small", "mixed_precision", "corr_impl",
               "fsdp", "elastic", "join")
 _LM_ONLY = ("tokens", "seq_len", "layers", "dense_layers", "layer_types",
             "vocab_size", "heads_held", "kv_heads_held", "experts_held")
-# of them, the flags one architecture has and the other has not, by the
+# of them, the flags one architecture has and another has not, by the
 # configuration's field
 _LM_FIELDS = {"dense_layers": ("first_k_dense_replace", "num_dense_layers"),
               "layer_types": ("layer_types",),
-              "kv_heads_held": ("kv_heads_held",)}
+              "kv_heads_held": ("kv_heads_held",),
+              "experts_held": ("experts_held",)}
 
 
 def _refuse_given(args, names, why: str) -> None:
@@ -361,8 +364,8 @@ def resolve_lm_configs(args) -> "tuple[Any, TrainConfig]":
     fields = {f.name for f in dataclasses.fields(make())}
     model = {k: v for k, v in (
         ("seq_len", args.seq_len), ("num_hidden_layers", args.layers),
-        ("vocab_size", args.vocab_size), ("heads_held", args.heads_held),
-        ("experts_held", args.experts_held)) if v is not None}
+        ("vocab_size", args.vocab_size), ("heads_held", args.heads_held))
+        if v is not None}
     for flag, names in _LM_FIELDS.items():
         value = getattr(args, flag)
         if value is None:
@@ -391,7 +394,7 @@ def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
     if args.variant in LM_VARIANTS:
         return resolve_lm_configs(args)
     _refuse_given(args, _LM_ONLY, "belong(s) to the language models "
-                  "(--variant kanana2, trinity-mini)")
+                  "(--variant kanana2, trinity-mini, evabyte)")
     if args.stage is None:
         raise SystemExit("train: --stage is required for --variant "
                          f"{args.variant}")
@@ -532,9 +535,9 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
     if is_lm:
         device_banner("train", model=args.variant, mesh=dict(mesh.shape),
                       heads_held=cfg.heads_held,
-                      experts_held=cfg.experts_held,
-                      **({"kv_heads_held": cfg.kv_heads_held}
-                         if hasattr(cfg, "kv_heads_held") else {}))
+                      **{f.name: getattr(cfg, f.name)
+                         for f in dataclasses.fields(cfg)
+                         if f.name in ("kv_heads_held", "experts_held")})
     else:
         device_banner("train", corr_impl=cfg.corr_impl,
                       fused_update=cfg.fused_update, mesh=dict(mesh.shape),

@@ -1,11 +1,13 @@
 """Shared by the tests/test_zz_lm_*.py files: the toy configurations of
-both architectures, a packed batch and seeded weights of O(1) scale."""
+the three architectures, a packed batch and seeded weights of O(1)
+scale."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dexiraft_tpu.config import TrainConfig, kanana2_toy, trinity_mini_toy
+from dexiraft_tpu.config import (TrainConfig, evabyte_toy, kanana2_toy,
+                                 trinity_mini_toy)
 from dexiraft_tpu.train.family import family_of
 
 
@@ -30,9 +32,11 @@ def packed_batch(cfg, rows=2, seed=0):
 
 def seeded(cfg, precision="fp32", remat="none", seed=1, scale=5.0):
     """(family, params, batch_stats): matrices scaled up from the 0.02
-    init so that every path carries signal at toy widths."""
+    init so that every path carries signal at toy widths (evabyte's
+    0.01275 to the same size)."""
     family = family_of(cfg, TrainConfig(precision=precision, remat=remat))
     params, stats = family.init(jax.random.PRNGKey(seed))
+    scale = scale * 0.02 / cfg.init_std
     params = jax.tree.map(lambda p: p * scale if p.ndim > 1 else p, params)
     return family, params, stats
 
@@ -42,12 +46,39 @@ def rel(a, b):
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-ARCHS = {"kanana2": kanana2_toy, "trinity": trinity_mini_toy}
+ARCHS = {"kanana2": kanana2_toy, "trinity": trinity_mini_toy,
+         "evabyte": evabyte_toy}
 # a share of each toy: experts 2-5; kanana's heads 1-2, trinity's query
-# heads 2-3, which read key/value head 1
+# heads 2-3, which read key/value head 1; evabyte's heads 2-3 (it has no
+# experts). The packed batch's documents start at 50 and 90: inside a
+# chunk of 4 and inside a window of 32
 SHARES = {"kanana2": dict(experts_held=(2, 4), heads_held=(1, 2)),
-          "trinity": dict(experts_held=(2, 4), heads_held=(2, 2))}
+          "trinity": dict(experts_held=(2, 4), heads_held=(2, 2)),
+          "evabyte": dict(heads_held=(2, 2))}
 
 
 def toy(arch="kanana2", **kw):
     return ARCHS[arch](**kw)
+
+
+def brute_force_eva_pairs(seg, window, chunk):
+    """{"local", "remote"} of one row of segment ids by EVA's definition,
+    pair by pair: (query, key) pairs in one document and window with the
+    key not after the query; (query, summary) pairs with the chunk's
+    document (that of its last non-pad position) the query's and the
+    chunk's window an earlier one."""
+    seg = [int(d) for d in seg]
+    n = len(seg)
+    chunk_doc = []
+    for c in range(-(-n // chunk)):
+        ids = [d for d in seg[c * chunk:(c + 1) * chunk] if d > 0]
+        chunk_doc.append(ids[-1] if ids else 0)
+    local = remote = 0
+    for q in range(n):
+        if seg[q] == 0:
+            continue
+        local += sum(1 for m in range(q + 1)
+                     if seg[m] == seg[q] and m // window == q // window)
+        remote += sum(1 for c, d in enumerate(chunk_doc)
+                      if d == seg[q] and c * chunk // window < q // window)
+    return {"local": local, "remote": remote}

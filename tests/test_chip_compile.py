@@ -192,6 +192,100 @@ def test_lm_attention_kernel_with_shared_heads_compiles_for_v5e(chip, which,
     assert "tpu_custom_call" in text and name in text
 
 
+# `evabyte-train-bytes32k`: 1 row x 8 heads of 128, 32,768 positions in
+# windows of 2,048 and chunks of 16
+EVA_HEADS, EVA_S, EVA_D, EVA_WINDOW, EVA_CHUNK = 8, 32768, 128, 2048, 16
+
+
+def _eva_on_the_chip(q, k, v, seg, *, scale, block, window=None,
+                     return_lse=False):
+    """`document_attention` as it resolves on a TPU; the backend here is
+    the CPU."""
+    from dexiraft_tpu.ops import lm_attention as la
+
+    return la.flash_document_attention(q, k, v, seg, scale=scale,
+                                       window=window, return_lse=return_lse)
+
+
+def test_eva_mixer_compiles_for_v5e(chip, monkeypatch):
+    """EVA's mixer at the third language cell's shapes, forward and the
+    gradients of q, k, v, phi and mu: the exact part on the three kernels
+    (the forward's log-sum-exp an output, its cotangent folded into the
+    backward's row sums), the pooling, the fifteen prefix products over
+    summaries and the merge as XLA gives them."""
+    from dexiraft_tpu.ops import lm_eva
+
+    monkeypatch.setattr(lm_eva, "document_attention", _eva_on_the_chip)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    row = sds((1, EVA_S, EVA_HEADS, EVA_D))
+    vec = sds((EVA_HEADS, EVA_D))
+
+    def loss(q, k, v, phi, mu, seg):
+        out = lm_eva.eva_attention(
+            q, k, v, phi, mu, seg, window=EVA_WINDOW, chunk=EVA_CHUNK,
+            scale=EVA_D ** -0.5, block=1024)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                          row, row, row, vec, vec,
+                          sds((1, EVA_S), jnp.int32))
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv"):
+        assert name in text
+    # no summary of 2,048 chunks against every one of 32,768 queries: the
+    # prefixes are static, the longest 1,920
+    assert f"{EVA_S},{EVA_S // EVA_CHUNK}]" not in text
+    assert f",{EVA_WINDOW},1920]" in text
+
+
+def test_evabyte_step_compiles_for_v5e_and_fits_the_chip(chip, monkeypatch):
+    """The third language cell's whole train step at its real size (4
+    layers, 8 of 32 heads, the SwiGLU whole, 620 M parameters, one row of
+    32,768 bytes, bf16, every layer recomputed) with the kernel path it
+    takes on the chip: `benchmarks/compile_check.py` compiles this cell
+    on the XLA path, its stand-in being the other mixers' entry point.
+    Arguments (masters and AdamW's moments) and temporaries fit 15.75 GB."""
+    import os.path as osp
+    import sys
+
+    import numpy as np
+
+    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+    from benchmarks import harness
+    from dexiraft_tpu.ops import lm_eva
+    from dexiraft_tpu.parallel import layout
+    from dexiraft_tpu.train.state import create_state
+    from dexiraft_tpu.train.step import make_train_step
+
+    cell = harness.load_cell("evabyte-train-bytes32k")
+    cfg, tc = harness.load_runner("lm_train_packed")._configs(cell, 0)
+    devices = list(chip.device_set)
+    mesh = layout.make_train_mesh(tc.batch_size, devices=devices)
+    repl = layout.replicated_sharding(mesh)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
+        jax.eval_shape(lambda: create_state(jax.random.PRNGKey(0), cfg, tc)))
+    batch = {k: jax.ShapeDtypeStruct(
+        (tc.batch_size, cfg.seq_len), np.int32,
+        sharding=layout.batch_input_sharding(mesh))
+        for k in ("tokens", "positions", "segment_ids")}
+
+    monkeypatch.setattr(lm_eva, "document_attention", _eva_on_the_chip)
+    with mesh:
+        compiled = make_train_step(cfg, tc, mesh=mesh).lower(
+            state, batch).compile()
+    text = compiled.as_text()
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv"):
+        assert name in text
+    memory = compiled.memory_analysis()
+    # fp32 masters and AdamW's two moments: 12 B a parameter
+    assert memory.argument_size_in_bytes > 12 * 620_015_616
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+
+
 # ---- the convex upsample (ops/upsample.py) --------------------------------
 
 def test_convex_upsample_is_lane_dense_for_v5e(chip):

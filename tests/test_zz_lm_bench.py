@@ -51,14 +51,29 @@ SHARED2 = [m for m in NEW if m != "lm_attn_device_ms"]
 NEEDS_A_CHIP2 = NEEDS_A_CHIP | {
     "lm_gqa_device_ms", "lm_gqa_window_kernel_device_ms",
     "lm_gqa_full_kernel_device_ms", "lm_gqa_kernel_roofline_pct"}
+# the third language cell (PR 33): a dense stack, so none of the expert
+# layer's metrics; its own mixer's six
+CELL3 = "evabyte-train-bytes32k"
+NEW3 = ["lm_eva_device_ms", "lm_eva_local_kernel_device_ms",
+        "lm_eva_remote_device_ms", "lm_eva_roofline_pct",
+        "lm_eva_local_blocks_visited_pct", "lm_mlp_device_ms"]
+SHARED3 = ["lm_head_loss_device_ms", "lm_optimizer_device_ms",
+           "lm_pack_fill_pct"]
+NEEDS_A_CHIP3 = (NEEDS_A_CHIP | set(NEW3)) - {
+    "lm_eva_local_blocks_visited_pct"}
 CELLS = {
     CELL: dict(config="kanana-2-30b-a3b-share8", traffic="train-pack8k",
-               model="kanana-2-30b-a3b-instruct-2601", bias="e_score_correction_bias",
+               model="kanana-2-30b-a3b-instruct-2601", shares=8,
+               assumes="e_score_correction_bias",
                reports=FED + NEW + SETUP, needs_a_chip=NEEDS_A_CHIP),
     CELL2: dict(config="trinity-mini-share8", traffic="train-pack32k",
-                model="Trinity-Mini", bias="expert_bias",
+                model="Trinity-Mini", shares=8, assumes="expert_bias",
                 reports=FED + SHARED2 + NEW2 + SETUP,
                 needs_a_chip=NEEDS_A_CHIP2),
+    CELL3: dict(config="evabyte-6.5b-share4", traffic="train-bytes32k",
+                model="EvaByte", shares=4, assumes="adaptive_mu_k",
+                reports=FED + SHARED3 + NEW3 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP3),
 }
 
 
@@ -105,7 +120,30 @@ def test_manifest_names_the_second_cell_and_what_it_reports(manifest):
         assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
                                    name + ".py"))
-    assert [m["name"] for m in manifest["per_layer"][-5:]] == NEW2
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[names.index(NEW2[0]):][:5] == NEW2
+
+
+def test_manifest_names_the_third_cell_and_what_it_reports(manifest):
+    cell = manifest["workloads"][-1]  # entries are added at the end
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        CELL3, "evabyte-6.5b-share4", "train-bytes32k", 1)
+    assert len(manifest["workloads"]) == 8 and len(cell["why"]) <= 200
+    assert manifest["configs"][-1]["name"] == "evabyte-6.5b-share4"
+    by_name = {m["name"]: m
+               for m in manifest["per_layer"] + manifest["end_to_end"]}
+    for name in FED + SHARED3 + ["train_samples_per_s"]:
+        assert by_name[name]["workloads"][-1] == CELL3, name
+    for name in NEW3:
+        assert by_name[name]["workloads"] == [CELL3]
+        assert by_name[name]["moves"] == "train_samples_per_s"
+        assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
+        assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
+                                   name + ".py"))
+    assert [m["name"] for m in manifest["per_layer"][-6:]] == NEW3
+    for name, m in by_name.items():  # a dense stack has no expert layer
+        if name.startswith(("lm_moe_", "lm_gqa_", "lm_attn_")):
+            assert CELL3 not in m["workloads"], name
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
@@ -129,8 +167,9 @@ def test_configuration_file_keeps_every_published_width(manifest, cell):
             assert not key.endswith(("_dim", "_rank")) and key not in WIDTHS
         else:
             assert cfg[key] == value, key
-    assert cfg["deployment"]["chips_sharing_a_layer"] == 8
-    assert any(CELLS[cell]["bias"] in a for a in cfg["assumed"])
+    assert (cfg["deployment"]["chips_sharing_a_layer"]
+            == CELLS[cell]["shares"])
+    assert any(CELLS[cell]["assumes"] in a for a in cfg["assumed"])
     assert osp.exists(osp.join(REPO, cfg["plain_reference"].split(":")[0]))
 
 
@@ -161,6 +200,12 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report(
     if cell == CELL2:  # both kinds of layer, counted apart
         assert counters["attn_block_pairs_visited_window"] > 0
         assert counters["attn_block_pairs_visited_full"] > 0
+    if cell == CELL3:  # a dense stack; both kinds of pair a traced row needs
+        assert counters["moe_slots_held"] == 0
+        assert counters["attn_block_pairs_visited_local"] > 0
+        assert counters["traced_pairs_local"] > 0
+        assert counters["traced_pairs_remote"] > 0
+        assert counters["attn_layers_local"] == 4
 
 
 def test_the_second_cells_configuration_states_its_cut_and_builds():
@@ -191,6 +236,58 @@ def test_the_second_cells_configuration_states_its_cut_and_builds():
     # the cell times what the program selects: no tuned size of its own
     assert cfg.moe_chunk is None
     assert set(cell.traffic["model_flags"]) == {"seq_len", "remat"}
+
+
+def test_the_third_cells_configuration_states_its_cut_and_builds():
+    """`parameters_held` is the program's own count and the issue's
+    arithmetic, the share is the deployment's, and the runner answers
+    every read of an expert layer truthfully: there is none."""
+    import dataclasses
+
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+    from dexiraft_tpu.config import EvaByteConfig, TrainConfig
+    from dexiraft_tpu.train.family import family_of
+    from dexiraft_tpu.train.state import param_count
+
+    cell = harness.load_cell(CELL3)
+    cfg, tc = harness.load_runner("lm_train_packed")._configs(cell, 0)
+    assert isinstance(cfg, EvaByteConfig)
+    held = cell.config["parameters_held"]
+    params, _ = jax.eval_shape(family_of(cfg, TrainConfig()).init,
+                               jax.random.PRNGKey(0))
+    assert param_count(params) == held["total"] == 620_015_616
+    assert held["total"] == 4 * held["layer"] + held[
+        "embedding_head_and_final_norm"]
+    assert held["layer"] == (held["attention_a_layer"]
+                             + held["swiglu_a_layer"] + 2 * 4096)
+    assert held["state_bytes_at_16_a_parameter"] == 16 * held["total"]
+    assert (cfg.heads_held, cfg.num_attention_heads, cfg.num_hidden_layers
+            ) == ((0, 8), 32, 4)
+    assert (cfg.window_size, cfg.chunk_size, cfg.num_pred_heads,
+            cfg.vocab_size, cfg.rope_theta, cfg.init_std) == (
+                2048, 16, 8, 320, 100000, 0.01275)
+    assert (cfg.seq_len, tc.batch_size, cfg.remat) == (32768, 1, True)
+    assert set(cell.traffic["model_flags"]) == {"seq_len", "remat"}
+    # what the runner reads of an expert layer
+    assert (cfg.experts_held, cfg.n_routed_experts, cfg.moe_intermediate_size,
+            cfg.first_k_dense_replace) == ((0, 0), 0, 0, 4)
+    docs = cell.traffic["documents"]
+    assert (docs["median"], docs["sigma"], docs["shortest"], docs["longest"],
+            docs["count"]) == (6144, 1.2, 256, 32768, 512)
+    assert (cell.traffic["batch"], cell.traffic["traced_steps"],
+            cell.traffic["check"]["reference_block"]) == (1, 4, 2048)
+    # controls: published keys the reference is given another value of
+    controls = cell.traffic["check"]["controls"]
+    assert {k for fault in controls.values() for k in fault} == {
+        "window_size", "chunk_size", "rope_theta", "norm_add_unit_offset"}
+    for fault in controls.values():
+        assert dataclasses.replace(cfg, **fault) != cfg
+    tol = cell.traffic["check"]["tolerances"]
+    assert set(tol) == {"loss", "grad_norm"} | {
+        "/".join(map(str, leaf)) for leaf in cell.traffic["check"]["leaves"]}
 
 
 def test_a_control_goes_through_the_checks_own_comparison():
@@ -260,12 +357,15 @@ def test_train_cli_refuses_the_language_models_flags_for_raft():
 
 
 @pytest.mark.parametrize("variant,share", [
-    ("kanana2-toy", ("--heads_held", "0", "2")),
+    ("kanana2-toy", ("--heads_held", "0", "2", "--experts_held", "0", "4")),
     # a dense sliding layer, an expert sliding layer, the full layer
     ("trinity-mini-toy", ("--heads_held", "2", "2", "--kv_heads_held", "1",
                           "1", "--layers", "3", "--dense_layers", "1",
                           "--layer_types", "sliding_attention",
-                          "sliding_attention", "full_attention"))])
+                          "sliding_attention", "full_attention",
+                          "--experts_held", "0", "4")),
+    # two of four shares' heads, two layers
+    ("evabyte-toy", ("--heads_held", "2", "4", "--layers", "2"))])
 def test_train_cli_trains_the_toy_model_through_the_normal_path(
         tmp_path, variant, share):
     import numpy as np
@@ -279,8 +379,7 @@ def test_train_cli_trains_the_toy_model_through_the_normal_path(
     write_token_file(tokens, rng.integers(0, 256, lengths.sum()), lengths)
     proc = _train("--variant", variant, "--tokens", tokens,
                   "--batch_size", "2", "--num_steps", "4", "--val_freq", "2",
-                  "--sum_freq", "2", "--precision", "bf16", "--remat",
-                  "--experts_held", "0", "4", *share,
+                  "--sum_freq", "2", "--precision", "bf16", "--remat", *share,
                   "--num_workers", "2", "--output", str(tmp_path / "ck"),
                   "--log_dir", str(tmp_path / "runs"))
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -299,3 +398,14 @@ def test_train_cli_refuses_a_share_that_splits_a_key_value_head(tmp_path):
     proc = _train("--variant", "kanana2-toy", "--tokens", "x.npz",
                   "--kv_heads_held", "0", "1")
     assert proc.returncode != 0 and "--kv_heads_held" in proc.stderr
+
+
+@pytest.mark.parametrize("flags,named", [
+    (["--experts_held", "0", "4"], "--experts_held"),
+    (["--dense_layers", "1"], "--dense_layers"),
+    (["--layer_types", "full_attention"], "--layer_types"),
+])
+def test_train_cli_refuses_for_evabyte_what_a_dense_stack_has_not(flags,
+                                                                  named):
+    proc = _train("--variant", "evabyte-toy", "--tokens", "x.npz", *flags)
+    assert proc.returncode != 0 and named in proc.stderr, proc.stderr[-500:]

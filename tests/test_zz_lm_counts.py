@@ -19,11 +19,13 @@ import pytest
 
 sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
 
-from benchmarks import flops, lm_counts, lm_counts_afmoe, lm_scopes  # noqa: E402
-from dexiraft_tpu.config import kanana2, trinity_mini  # noqa: E402
+from benchmarks import (flops, lm_counts, lm_counts_afmoe,  # noqa: E402
+                        lm_counts_eva, lm_scopes)
+from dexiraft_tpu.config import evabyte, kanana2, trinity_mini  # noqa: E402
 from dexiraft_tpu.interop import lm_reference as ref  # noqa: E402
 
-from _lm_common import SHARES, packed_batch, seeded, toy  # noqa: E402
+from _lm_common import (SHARES, brute_force_eva_pairs,  # noqa: E402
+                        packed_batch, seeded, toy)
 
 
 def test_dense_parts_equal_the_walk_of_the_reference():
@@ -269,4 +271,123 @@ def test_gqa_layer_metrics_read_their_counters_and_give_nothing_without():
                      "lm_gqa_full_kernel_device_ms",
                      "lm_gqa_kernel_roofline_pct",
                      "lm_gqa_window_blocks_visited_pct"):
+            assert read(name, obs(c)) is None, name
+
+
+# ---- the third architecture's counts (benchmarks/lm_counts_eva.py) ---------
+
+
+def test_eva_dense_parts_equal_the_walk_of_the_reference():
+    """The reference makes, a head, one `[S, S + chunks]` row of scores
+    and the `[chunks, S]` pooling matrix over every position (both kinds
+    of pair are masks there); the rest of its products are the analytic
+    per-token parts, the pooling's logit among them."""
+    cfg = toy("evabyte", **SHARES["evabyte"])
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg)
+    rows, s = batch["tokens"].shape
+    walked = flops.count(lambda p: ref.loss(p, batch, cfg), params)
+    parts = lm_counts_eva.per_token_forward(cfg)
+    heads, hd = cfg.heads_held[1], cfg.head_dim
+    chunks = s // cfg.chunk_size
+    # the analytic pooling counts a token's own chunk: a logit and two
+    # weighted sums; the reference multiplies whole matrices instead
+    dense = sum(v for k, v in parts.items() if k != "pooling")
+    layer = heads * (2 * s * hd                      # k . phi
+                     + 2 * 2 * chunks * s * hd       # a @ k, a @ v
+                     + 2 * 2 * s * (s + chunks) * hd)  # scores, values
+    assert walked == dense * rows * s + rows * cfg.num_hidden_layers * layer
+    assert parts["pooling"] == cfg.num_hidden_layers * heads * 3 * 2 * hd
+
+
+@pytest.mark.parametrize("window,chunk", [(8, 2), (32, 4), (16, 16), (64, 1)])
+@pytest.mark.parametrize("lengths", [(41, 59, 20), (3, 1, 100), (128,), (),
+                                     (7, 1, 1, 1, 30, 88)])
+def test_eva_pairs_equal_a_brute_force_count(lengths, window, chunk):
+    seg = np.zeros(128, np.int64)
+    at = 0
+    for i, n in enumerate(lengths, start=1):
+        seg[at:at + n] = i
+        at += n
+    want = brute_force_eva_pairs(seg, window, chunk)
+    assert lm_counts_eva.pairs_in_row(seg, window, chunk) == want
+    cfg = toy("evabyte", window_size=window, chunk_size=chunk)
+    assert lm_counts_eva.pairs_by_kind(cfg, np.stack([seg, seg])) == {
+        k: 2.0 * v for k, v in want.items()}
+    # and what the step itself reports (ops/lm_eva.py `pair_counts`)
+    from dexiraft_tpu.ops.lm_eva import pair_counts
+    got = pair_counts(np.asarray(seg[None], np.int32), window=window,
+                      chunk=chunk)
+    assert {"local": int(got[0]), "remote": int(got[1])} == want
+
+
+def test_eva_step_flops_at_the_cells_share_by_hand():
+    cfg = evabyte(num_hidden_layers=4, heads_held=(0, 8))
+    assert lm_counts_eva.layers_by_kind(cfg) == {"local": 4, "remote": 4}
+    per_token = lm_counts_eva.per_token_forward(cfg)
+    assert per_token["mlp"] == 4 * 3 * 2 * 4096 * 11008
+    assert per_token["projections"] == 4 * 4 * 2 * 4096 * 1024
+    assert per_token["head"] == 2 * 4096 * 8 * 320
+    parts = lm_counts_eva.step_flops(
+        cfg, tokens_real=32000, slots_held=0.0,
+        pairs={"local": 30e6, "remote": 25e6})
+    assert parts["attention"] == 3 * 8 * 2 * 256 * 4 * 55e6
+    assert parts["total"] == pytest.approx(sum(
+        v for k, v in parts.items() if k != "total"))
+    # forward + backward without the recomputed pass: the SwiGLU 104 of
+    # 123 TFLOP (the issue's 142 of 167 counts four passes, not three)
+    assert parts["mlp"] == pytest.approx(1.039e14, rel=0.01)
+    assert 1.20e14 < parts["total"] < 1.26e14
+
+
+def test_eva_roofline_is_over_the_needed_pairs_of_both_kinds():
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    least = lm_counts_eva.attention_roofline_seconds(
+        55e6, 4, 32768, 8, 128, True, peaks)
+    assert least["flops"] == 4 * 55e6 * 8 * 11 * 2 * 128
+    assert least["bytes"] == 4 * 4 * 32768 * 128 * 2 * 5 * 8
+    assert least["seconds"] == pytest.approx(least["flops"] / 197e12)
+
+
+def test_eva_layer_metrics_read_their_counters_and_give_nothing_without():
+    from benchmarks import harness
+
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    counters = {"scope_s:lm/eva/proj": 0.060,
+                "scope_s:lm/eva/local/kernel": 0.030,
+                "scope_s:lm/eva/remote": 0.050, "scope_s:lm/eva/pool": 0.004,
+                "scope_s:lm/eva/merge": 0.006, "scope_s:lm/mlp": 1.0,
+                "scope_s:lm/head_loss": 0.02,
+                "traced_pairs_local": 30e6, "traced_pairs_remote": 25e6,
+                "attn_layers_local": 4, "attn_layers_remote": 4,
+                "attn_heads_held": 8, "attn_kv_heads_held": 8,
+                "attn_head_dim": 128, "remat": 1.0, "batch": 1,
+                "seq_len": 32768,
+                "attn_block_pairs_visited_local": 4 * 160.0,
+                "attn_block_pairs_causal": 4 * 2080.0}
+
+    def obs(c):
+        return harness.Observation(
+            spans={}, counters=c, end_to_end={}, trace={"busy_s": 1.0},
+            peaks=peaks, chips=1, memory_peak_bytes=0)
+
+    read = lambda name, o: harness.load_metric(name).read(o)  # noqa: E731
+    full = obs(counters)
+    assert read("lm_eva_device_ms", full) == pytest.approx(150.0)
+    assert read("lm_eva_local_kernel_device_ms", full) == pytest.approx(30.0)
+    assert read("lm_eva_remote_device_ms", full) == pytest.approx(60.0)
+    assert read("lm_mlp_device_ms", full) == pytest.approx(1000.0)
+    least = 4 * 55e6 * 8 * 11 * 256 / 197e12
+    share = read("lm_eva_roofline_pct", full)
+    assert share == pytest.approx(least / 0.090 * 100)
+    assert 0 < share < 100
+    assert read("lm_eva_local_blocks_visited_pct", full) == pytest.approx(
+        160 / 2080 * 100)
+    # the parent's program, or another architecture's: nothing, no raise
+    for c in ({}, {"scope_s:lm/gqa/proj": 0.03, "batch": 1, "seq_len": 32768,
+                   "attn_block_pairs_visited_window": 500.0,
+                   "attn_block_pairs_causal": 2080.0}):
+        for name in ("lm_eva_device_ms", "lm_eva_local_kernel_device_ms",
+                     "lm_eva_remote_device_ms", "lm_eva_roofline_pct",
+                     "lm_eva_local_blocks_visited_pct", "lm_mlp_device_ms"):
             assert read(name, obs(c)) is None, name

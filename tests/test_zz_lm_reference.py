@@ -13,7 +13,9 @@ of mantissa (2^-8 = 0.4 % an operation) through three layers of width
 leaf of a few thousand entries by whole percents: seen 0.04-0.21 a leaf
 and 2e-4 on the loss; the limits are 0.35 and 2e-3. The trinity toy
 carries them through five layers, each of which norms what it adds
-(gains and a router read 0.26-0.35): its limit is 0.45. A bf16 run that
+(gains and a router read 0.26-0.35): its limit is 0.45. The evabyte toy
+has no router to flip: its leaves read 0.006-0.049 (a phi of 16
+entries the largest) and its loss 1.5e-4; its limit is 0.15. A bf16 run that
 dropped a term (a missing head, expert or rope half) is off by 0.5-1.
 """
 
@@ -127,7 +129,8 @@ def test_bf16_policy_stays_near_the_reference(arch):
     worst = max(rel(g, r) for g, r in zip(jax.tree.leaves(grads),
                                           jax.tree.leaves(ref_grads)))
     # not fp32 by accident, not broken
-    assert 1e-4 < worst < {"kanana2": 0.35, "trinity": 0.45}[arch], worst
+    assert 1e-4 < worst < {"kanana2": 0.35, "trinity": 0.45,
+                           "evabyte": 0.15}[arch], worst
     assert all(g.dtype == jnp.float32 for g in jax.tree.leaves(grads))
 
 

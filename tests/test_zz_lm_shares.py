@@ -4,7 +4,8 @@ what every chip computes alike (the shared experts) counted once, add up
 to what the uncut reference gives for the whole layer. For both
 architectures: kanana2's latent attention first, then trinity's gated
 grouped-query attention, where two chips hold copies of one key/value
-head.
+head, then evabyte's chunked linear attention over 4 chips, whose dense
+layer every chip holds whole.
 
 Each share runs the SYSTEM's modules (models/lm) on its slice of the
 whole model's weights; the whole is the plain reference holding every
@@ -240,3 +241,83 @@ def test_afmoe_layer_outputs_of_the_shares_add_up_to_the_whole_layer(
     # every slot of every token lands on exactly one chip
     assert sum(int(c["moe_slots_held"]) for _, c in parts) == (
         cfg.seq_len * cfg.num_experts_per_tok)
+
+
+# ---- the third architecture: EVA chunked linear attention ------------------
+#
+# One of 4 chips that share each layer: 8 heads, 2 a share, with their own
+# rows of phi and mu_k (a head's summaries are its own). The SwiGLU is
+# whole on every chip and counted once.
+
+EVA_SHARES = 4
+
+
+@pytest.fixture(scope="module")
+def eva_whole():
+    cfg = toy("evabyte")
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg, rows=1)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, cfg.seq_len,
+                                                  cfg.hidden_size))
+    return cfg, params, batch, x
+
+
+def _eva_share(i, whole):
+    cfg, params, _, _ = whole
+    share = toy("evabyte", heads_held=(2 * i, 2))
+    return share, ref.take_share(params, cfg, share.heads_held)
+
+
+def _eva_part(i, whole, x):
+    from dexiraft_tpu.models.lm.attention import mixer_of
+
+    _, _, batch, _ = whole
+    share, p = _eva_share(i, whole)
+    return mixer_of(share, 1).apply(
+        {"params": p["layers_1"]["attn"]}, x, batch["positions"],
+        batch["segment_ids"])[0]
+
+
+@pytest.mark.parametrize("i", range(EVA_SHARES))
+def test_an_eva_share_equals_the_reference_given_that_share(i, eva_whole):
+    cfg, _, batch, x = eva_whole
+    share, p = _eva_share(i, eva_whole)
+    assert p["layers_1"]["attn"]["phi"].shape == (2, cfg.head_dim)
+    want = ref.eva_attention(p["layers_1"]["attn"], x[0],
+                             batch["positions"][0], batch["segment_ids"][0],
+                             cfg, 2)
+    assert rel(_eva_part(i, eva_whole, x), want) < 2e-5
+
+
+def test_eva_attention_parts_of_all_shares_add_up_to_the_whole(eva_whole):
+    cfg, params, batch, x = eva_whole
+    total = sum(_eva_part(i, eva_whole, x) for i in range(EVA_SHARES))
+    want = ref.eva_attention(params["layers_1"]["attn"], x[0],
+                             batch["positions"][0], batch["segment_ids"][0],
+                             cfg, cfg.num_attention_heads)
+    assert rel(total, want) < 2e-5
+
+
+def test_eva_layer_outputs_of_the_shares_add_up_to_the_whole_layer(eva_whole):
+    """x + the four shares' attention parts = h; h + the SwiGLU, which
+    every chip holds whole, counted once = the uncut reference's layer
+    output (the norms' gains are 1 + g)."""
+    cfg, params, batch, x = eva_whole
+    lp = params["layers_1"]
+    want = ref.eva_layer(lp, x[0], batch["positions"][0],
+                         batch["segment_ids"][0], cfg)
+    normed = ref._rms_norm(x[0], 1.0 + lp["attn_norm"], cfg.rms_norm_eps)
+    h = x[0] + sum(_eva_part(i, eva_whole, normed[None])
+                   for i in range(EVA_SHARES))
+    ffn_in = ref._rms_norm(h, 1.0 + lp["ffn_norm"], cfg.rms_norm_eps)
+    mlp = SwiGLU(width=cfg.intermediate_size).apply({"params": lp["mlp"]},
+                                                    ffn_in)
+    assert rel(h + mlp, want) < 2e-5
+    # the whole model's tree cut to a share is the share's own tree
+    share, p = _eva_share(1, eva_whole)
+    from dexiraft_tpu.config import TrainConfig
+    from dexiraft_tpu.train.family import family_of
+    shapes, _ = jax.eval_shape(family_of(share, TrainConfig()).init,
+                               jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda a: a.shape, p) == jax.tree.map(
+        lambda a: a.shape, shapes)
