@@ -16,9 +16,12 @@ pair (H_l, W_l) fills (8, 128) register tiles as (48, 128), (24, 128),
 2.24 GB of HBM for 0.69 GB of values, read twice and its gradient sum
 read and written once in every training iteration. With the queries on
 the lanes 2852 pads to 2944 (1.03x); the chip's compiler puts the batch on
-the sublanes (`f32[16,46,62,2852]{3,0,2,1:T(8,128)}`: 0.710 GB), and the
-lookup is elementwise over whole registers (corr_lookup). docs/perf.md
-"Correlation memory & precision" has the chip's numbers.
+the sublanes (`f32[16,46,62,2852]{3,0,2,1:T(8,128)}`: 0.710 GB), so a
+target position is a whole register and the lookup ALIGNS each query's
+window by selects between registers, then weights neighbours by one lerp
+(corr_lookup, _axis_window; on a TPU the Pallas kernels of
+ops/pallas_window.py). docs/perf.md "Correlation memory & precision" has
+the chip's numbers.
 
 This module is the materialized path; the memory-efficient on-demand
 equivalent of the reference's alt_cuda_corr CUDA kernel
@@ -29,6 +32,7 @@ whose transient per-chunk blocks keep the reference's slab form
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional
 
 import flax.struct
@@ -232,14 +236,171 @@ def interp_window(vol: jax.Array, centers: jax.Array,
     return window.reshape(vol.shape[0], win * win)
 
 
-def _tap_hats(center: jax.Array, radius: int, size: int) -> jax.Array:
-    """_axis_interp_matrix with the queries last: center (B, Q) ->
-    (B, 2r+1, size, Q), hats[b, j, p, q] = relu(1 - |p - (c[b,q] + j - r)|).
+def _window_geometry(center: jax.Array, radius: int, size: int):
+    """What a lookup reads of one target axis of length ``size``.
+
+    A window is the n = 2r + 2 positions from i0 = floor(c) - r on; i0 is
+    clamped to [-n, size], at either end a window wholly outside the frame.
+    center (B, Q) -> start = i0 + n (int32, the window's first position on
+    the axis padded by n zeros in front) and frac = c - floor(c), the weight
+    of each position's right neighbour.
     """
-    t = center[:, None, None, :] + jnp.arange(
-        -radius, radius + 1, dtype=jnp.float32)[:, None, None]
-    pos = jnp.arange(size, dtype=jnp.float32)[:, None]
-    return jnp.maximum(0.0, 1.0 - jnp.abs(pos - t))
+    n = 2 * radius + 2
+    whole = jnp.floor(center)
+    start = (jnp.clip(whole - radius, -n, size) + n).astype(jnp.int32)
+    return start, center - whole
+
+
+def _digits(start: jax.Array, top: int) -> list:
+    """The masks of start's binary digits up to ``top``, lowest first."""
+    return [(start & (1 << k)) != 0 for k in range(max(top, 1).bit_length())]
+
+
+def _padded_length(n: int, size: int) -> int:
+    """Positions of the zero-filled axis the shifter's first stage reads."""
+    return n + (1 << max(size + n, 1).bit_length()) - 1
+
+
+def _shift_in(padded, digits, n: int, axis: int):
+    """padded[start + j], j < n, along ``axis``: a log-step shifter. The
+    positions are a major axis and the masks cover what is minor to it, so
+    a shift is a static slice and a stage one select; highest digit first,
+    stage k keeps the n + 2**k - 1 positions the lower digits still reach.
+    """
+    x = padded
+    for k in reversed(range(len(digits))):
+        step, width = 1 << k, n + (1 << k) - 1
+        x = jnp.where(digits[k],
+                      jax.lax.slice_in_dim(x, step, step + width, axis=axis),
+                      jax.lax.slice_in_dim(x, 0, width, axis=axis))
+    return x
+
+
+def _shift_out(taps, digits, limit: int, axis: int):
+    """_shift_in's transpose: tap j to position start + j of an axis of
+    ``limit`` positions, zero elsewhere; lowest digit first."""
+    def fill(like, count):
+        shape = list(like.shape)
+        shape[axis] = count
+        return jnp.zeros(shape, like.dtype)
+
+    y = taps
+    for k in range(len(digits)):
+        step = 1 << k
+        width = min(y.shape[axis] + step, limit)
+        gap = fill(y, step)
+        y = jnp.where(
+            digits[k],
+            jax.lax.slice_in_dim(jnp.concatenate([gap, y], axis), 0, width,
+                                 axis=axis),
+            jax.lax.slice_in_dim(jnp.concatenate([y, gap], axis), 0, width,
+                                 axis=axis))
+    if y.shape[axis] < limit:
+        y = jnp.concatenate([y, fill(y, limit - y.shape[axis])], axis)
+    return y
+
+
+def _lerp(taps, frac, axis):
+    n = taps.shape[axis]
+    lo = jax.lax.slice_in_dim(taps, 0, n - 1, axis=axis)
+    hi = jax.lax.slice_in_dim(taps, 1, n, axis=axis)
+    return (1.0 - frac) * lo + frac * hi
+
+
+def _lerp_transposed(g, frac, axis):
+    """The cotangent of the n - 1 lerped values on the n taps."""
+    edge = list(g.shape)
+    edge[axis] = 1
+    edge = jnp.zeros(edge, g.dtype)
+    return (jnp.concatenate([(1.0 - frac) * g, edge], axis)
+            + jnp.concatenate([edge, frac * g], axis))
+
+
+def _kernel_interpret() -> Optional[bool]:
+    """None: the plain form. Else the Pallas kernels (ops/pallas_window.py),
+    compiled on a TPU and interpreted under DEXIRAFT_PALLAS_INTERPRET."""
+    from dexiraft_tpu.ops.pallas_corr import _interpret_default
+
+    if jax.default_backend() == "tpu":
+        return False
+    return True if _interpret_default() else None
+
+
+def _axis_taps(vol, start, n, axis):
+    """The n positions of each query's window, zero outside the frame."""
+    size = vol.shape[axis]
+    pads = [(0, 0, 0)] * vol.ndim
+    pads[axis] = (n, _padded_length(n, size) - n - size, 0)
+    padded = jax.lax.pad(vol.astype(jnp.float32), jnp.float32(0), pads)
+    digits = [d[:, None, None, :] for d in _digits(start, size + n)]
+    return _shift_in(padded, digits, n, axis)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _axis_window(vol: jax.Array, center: jax.Array, radius: int,
+                 axis: int) -> jax.Array:
+    """The 2r+1 bilinear taps around ``center`` along one target axis.
+
+    vol (B, S1, S2, Q) as stored (fp32, bf16, int8: upcast where it is
+    read) with ``axis`` (1 or 2) the target axis, center (B, Q) in that
+    axis' pixels -> the same array in float32 with 2r+1 on ``axis``:
+    out[j] = (1 - f) * vol[i0 + j] + f * vol[i0 + j + 1], zero outside
+    the frame, i0 = floor(c) - r and f = c - floor(c) per (b, q).
+    The taps sit at integer offsets from one centre, so they share f and
+    are 2r+2 CONSECUTIVE positions: the window is aligned by selects
+    between whole registers (a log-step shifter, _shift_in: one select a
+    binary digit of the window's start) and weighted by one lerp. On a
+    TPU the stages run in VMEM (ops/pallas_window.py); elsewhere they are
+    plain `where` on the level.
+    """
+    n = 2 * radius + 2
+    start, frac = _window_geometry(center, radius, vol.shape[axis])
+    interpret = _kernel_interpret()
+    if interpret is None or vol.size == 0:
+        return _lerp(_axis_taps(vol, start, n, axis),
+                     frac[:, None, None, :], axis)
+    from dexiraft_tpu.ops.pallas_window import align_axis
+
+    return align_axis(vol, start, frac, n, axis, interpret)
+
+
+def _axis_window_fwd(vol, center, radius, axis):
+    return _axis_window(vol, center, radius, axis), (vol, center)
+
+
+def _axis_window_bwd(radius, axis, res, g):
+    """The mirror image: the cotangent's taps placed back under the same
+    masks, so the level's gradient is written once, dense, in the level's
+    own form (B, S1, S2, Q)."""
+    vol, center = res
+    n, size = 2 * radius + 2, vol.shape[axis]
+    start, frac = _window_geometry(center, radius, size)
+
+    # d/d center: the lerp's slope (floor has none), summed per query
+    taps = _axis_taps(vol, start, n, axis)
+    slope = (jax.lax.slice_in_dim(taps, 1, n, axis=axis)
+             - jax.lax.slice_in_dim(taps, 0, n - 1, axis=axis))
+    d_center = jnp.sum(g * slope, axis=(1, 2))
+
+    interpret = _kernel_interpret()
+    if vol.size == 0:
+        d_vol = jnp.zeros(vol.shape, jnp.float32)
+    elif interpret is None:
+        digits = [d[:, None, None, :] for d in _digits(start, size + n)]
+        d_taps = _lerp_transposed(g, frac[:, None, None, :], axis)
+        d_vol = jax.lax.slice_in_dim(
+            _shift_out(d_taps, digits, n + size, axis), n, n + size,
+            axis=axis)
+    else:
+        from dexiraft_tpu.ops.pallas_window import place_axis
+
+        d_vol = place_axis(g, start, frac, n, size, axis, interpret)
+    if not jnp.issubdtype(vol.dtype, jnp.floating):  # int8: no tangent space
+        return None, d_center
+    return d_vol.astype(vol.dtype), d_center
+
+
+_axis_window.defvjp(_axis_window_fwd, _axis_window_bwd)
 
 
 @jax.named_scope("corr_lookup")
@@ -250,23 +411,30 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
     Returns (B, H, W, num_levels * (2r+1)^2) float32 correlation features.
     Reference: core/corr.py:29-50.
 
-    The same separable hat window as interp_window, A_y · vol · A_xᵀ, zero
-    outside the frame, on the stored form (B, H_l, W_l, Q): every operand
-    has the queries on its minor axis, so both contractions run over MAJOR
-    axes — multiply-adds between whole registers, in fp32 on the vector
-    unit (the einsum form rounds its fp32 operands to bf16 on the chip; this
-    one does not). The x hat goes first: the level is read once, 9·H_l·W_l
-    multiply-adds a query, and what is left for the y hat is (B, 9, H_l, Q).
-    Autodiff's gradient with respect to the level is the mirror image, a
-    sum over the nine x taps that is written in the level's own form, so the
-    sum the training scan carries over its iterations is an add over full
-    registers. Timed alone at N = 45,632 (my chip run, PR 32): 3.63 ms a
-    lookup, 10.94 ms with its gradient; y first 4.70 | 12.21; the slab
-    einsum 12.97 | 25.98.
+    The same bilinear window as interp_window, zero outside the frame, on
+    the stored form (B, H_l, W_l, Q), without the hats: the taps sit at
+    integer offsets from one centre, so along an axis they are 2r+2
+    CONSECUTIVE positions from i0 = floor(c) - r sharing one fractional
+    part, and the queries are on the minor axis, so a position is a whole
+    register. Each axis in turn (_axis_window; x first: the level is read
+    once and what is left for y is (B, H_l, 2r+1, Q)) takes those positions
+    by selects under per-(b, q) masks and weights neighbours by one lerp,
+    in fp32; nothing is multiplied by a zero. The gradient with respect to
+    the level is the mirror image (a custom_vjp: the cotangent's taps
+    placed back under the same masks), written once in the level's own
+    form, so the sum the training scan carries over its iterations is an
+    add over full registers. One algorithm for every shape and backend
+    (a log-step shifter, _shift_in); on a TPU its stages run in VMEM
+    (ops/pallas_window.py), elsewhere as plain `where`. Timed alone at
+    N = 45,632, forward | the level's gradient | build + twelve lookups +
+    gradient, ms (my chip run, PR 34): the kernels 1.34 | 1.76 | 84.2;
+    the dense hats this replaced 3.56 | 5.89 | 209.6; a two-stage select
+    chain left to XLA 4.08 | 6.51 | 188.8 (it fetches every static slice
+    of a chain from HBM on its own).
 
-    ``levels`` below fp32 are upcast in the first contraction's operand
-    read; an int8 level's scale multiplies its window, exact because the
-    lookup is linear in the volume.
+    ``levels`` below fp32 are upcast where they are read; an int8 level's
+    scale multiplies its window, exact because the lookup is linear in the
+    volume.
     """
     r = pyramid.radius
     b, h, w = pyramid.batch, pyramid.ht, pyramid.wd
@@ -276,15 +444,11 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
     cx, cy = flat[..., 0], flat[..., 1]  # (B, Q)
     out = []
     for i, vol in enumerate(pyramid.levels):
-        hl, wl = vol.shape[1:3]
-        ax = _tap_hats(cx / (2.0**i), r, wl)  # (B, win, Wl, Q)
-        ay = _tap_hats(cy / (2.0**i), r, hl)  # (B, win, Hl, Q)
-        rows = jnp.sum(ax[:, :, None] * vol.astype(jnp.float32)[:, None],
-                       axis=3)  # (B, win_x, Hl, Q)
-        window = jnp.sum(ay[:, None] * rows[:, :, None], axis=3)
+        rows = _axis_window(vol, cx / (2.0**i), r, 2)
+        window = _axis_window(rows, cy / (2.0**i), r, 1)  # (B, y, x, Q)
         if pyramid.scales is not None:
             window = window * pyramid.scales[i]
         # (B, win_x, win_y, Q): x offset on the slow axis (_window_delta)
-        out.append(window.reshape(b, win * win, h * w))
+        out.append(jnp.swapaxes(window, 1, 2).reshape(b, win * win, h * w))
     out = jnp.concatenate(out, axis=1)  # (B, L*win^2, Q)
     return jnp.swapaxes(out, 1, 2).reshape(b, h, w, -1)

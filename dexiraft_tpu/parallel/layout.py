@@ -63,7 +63,7 @@ import numpy as np
 # module's whole job is constructing jax.sharding objects. Callers that
 # must stay jax-free (data/__init__, loaders) already import lazily.
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,6 +208,28 @@ class SpecLayout:
         replicating them at pod batch sizes would still be a layout
         bug the size tripwire must catch."""
         return self.batch_for(mesh)
+
+    def corr_window(self, mesh, shape) -> Optional[
+            Tuple[PartitionSpec, PartitionSpec]]:
+        """shard_map specs of the lookup's alignment kernels
+        (ops/pallas_window.py): for an array in the order the chip stores
+        a level, (S1, S2, B, H*W), and for its per-query (B, H*W)
+        operands. The batch over 'data' and the queries (whole image
+        rows) over 'seq', each where the mesh has the axis partitioned
+        automatically and it divides the extent; the two target axes stay
+        whole. None where nothing is left to split: no mesh, one chip, or
+        inside a shard_map that already did."""
+        auto = {name: size for name, size, kind in zip(
+            mesh.axis_names, mesh.axis_sizes, mesh.axis_types)
+            if kind == AxisType.Auto and size > 1}
+        entry = [None, None]
+        for axis, extent in ((self.data_axis, shape[2]),
+                             (self.seq_axis, shape[3])):
+            ways = auto.get(axis)
+            entry.append(axis if ways and extent % ways == 0 else None)
+        if not any(entry):
+            return None
+        return PartitionSpec(*entry), PartitionSpec(*entry[2:])
 
     # ---- mesh shape queries -------------------------------------------
 

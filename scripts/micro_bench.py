@@ -67,8 +67,9 @@ def timeit(name, fn, *args, reps=3, strict=False):
 def corr_bytes_per_lookup(batch: int, h8: int, w8: int, num_levels: int,
                           corr_dtype: str) -> int:
     """Estimated bytes ONE all-pairs corr_lookup streams from HBM: every
-    pyramid level is read once per lookup by the x hat's contraction
-    (corr_lookup is volume-streaming by construction — docs/perf.md).
+    pyramid level is read once per lookup, by the x axis' alignment (the
+    kernel fetches a block of the level into VMEM once; corr_lookup is
+    volume-streaming by construction — docs/perf.md).
     Level dims floor-halve exactly like build_corr_pyramid's VALID pool."""
     from dexiraft_tpu.ops.quant import corr_dtype_bytes
 

@@ -36,14 +36,13 @@ DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding on one described v5e chip; skipped where the topology
-    cannot be described. The persistent compilation cache is off around
-    these compiles: an entry written for a described chip cannot be read
-    back without one, and the next run would warn."""
+def topo():
+    """The described v5e:2x2; skipped where it cannot be described. The
+    persistent compilation cache is off around these compiles: an entry
+    written for a described chip cannot be read back without one, and the
+    next run would warn."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -53,9 +52,17 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Sharding on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shapes(chip, dtype, h=H, w=W, b=B):
@@ -343,7 +350,31 @@ def _tiled_arrays(text):
     return seen
 
 
-def test_corr_pyramid_is_lane_dense_for_v5e(chip):
+def _twelve_lookups(f1, f2, coords, weight):
+    """What the train step's scan does with `consts["pyr"]`: the build,
+    twelve lookups under remat, a weighted sum."""
+    from dexiraft_tpu.ops.corr import build_corr_pyramid
+
+    pyr = build_corr_pyramid(f1, f2, LEVELS, RADIUS)
+
+    def body(shift, _):
+        out = jax.checkpoint(lambda p, c: p(c))(pyr, coords + shift)
+        step = 0.01 * jax.lax.stop_gradient(jnp.mean(out))
+        return shift + step, jnp.sum(out * weight)
+
+    return jnp.sum(jax.lax.scan(body, jnp.float32(0), None, length=12)[1])
+
+
+@pytest.fixture
+def lookup_on_the_chip(monkeypatch):
+    """`corr_lookup` asks the backend, which is the CPU here: hand it the
+    answer a TPU gives (the Pallas kernels, compiled)."""
+    from dexiraft_tpu.ops import corr
+
+    monkeypatch.setattr(corr, "_kernel_interpret", lambda: False)
+
+
+def test_corr_pyramid_is_lane_dense_for_v5e(chip, lookup_on_the_chip):
     """`build_corr_pyramid`, twelve lookups under remat and the gradient
     with respect to the feature maps at `v5-train-chairs`' shapes (both
     streams' batch of 16, 368x496 / 8, 256 features): what the train
@@ -354,30 +385,21 @@ def test_corr_pyramid_is_lane_dense_for_v5e(chip):
     this function. With the queries on the lanes every level, its
     gradient and the carried sum are within 1.15x of their values (read:
     1.03x, `f32[16,46,62,2852]{3,0,2,1:T(8,128)}`, the batch on the
-    sublanes), and the temporaries read 1.73 GB: the four levels (0.71),
-    the carried sum (0.71), and the hats, the nine rows a tap and the
-    build's operands for the rest. The limit is 1.25 x that reading."""
-    from dexiraft_tpu.ops.corr import build_corr_pyramid
-
+    sublanes). Since PR 34 the lookup is the alignment kernels
+    (ops/pallas_window.py), which take a level as `f32[46,62,16,2852]` in
+    the default layout: the same bytes in the same order, so no copy of a
+    level stands between the two, and the temporaries read 1.51 GB (the
+    four levels 0.71, the carried sum 0.71, the build's operands; with the
+    dense hats 1.73). The limit is 1.25 x that reading."""
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
     b, h, w, d = 16, 46, 62, 256
-
-    def loss(f1, f2, coords, weight):
-        pyr = build_corr_pyramid(f1, f2, LEVELS, RADIUS)
-
-        def body(shift, _):
-            out = jax.checkpoint(lambda p, c: p(c))(pyr, coords + shift)
-            step = 0.01 * jax.lax.stop_gradient(jnp.mean(out))
-            return shift + step, jnp.sum(out * weight)
-
-        return jnp.sum(jax.lax.scan(body, jnp.float32(0), None, length=12)[1])
-
-    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+    compiled = jax.jit(jax.grad(_twelve_lookups, (0, 1))).lower(
         sds((b, h, w, d)), sds((b, h, w, d)), sds((b, h, w, 2)),
         sds((b, h, w, LEVELS * (2 * RADIUS + 1) ** 2))).compile()
-    arrays = _tiled_arrays(compiled.as_text())
+    text = compiled.as_text()
+    arrays = _tiled_arrays(text)
 
     # no large array with a lone 1, the nine taps or an unpacked level
     # width on the lanes: `f32[45632,46,62,1]`, `bf16[45632,9,46]`, ...
@@ -391,4 +413,112 @@ def test_corr_pyramid_is_lane_dense_for_v5e(chip):
         tuple(s) for s in level_dims}, sorted(levels)
     padded = {k: round(v[2], 2) for k, v in levels.items() if v[2] > 1.15}
     assert not padded, padded
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 1.73e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 1.51e9
+    # the forward scan's x and y alignment of four levels, and their
+    # mirror images in the backward scan (whose forward is dead here: the
+    # lookup is linear in the level)
+    assert text.count("tpu_custom_call") == 16
+    # a level reaches its kernel as a bitcast: no copy the size of level 0
+    copies = [m for m in re.findall(r"= f32\[([\d,]+)\]\S* copy\(", text)
+              if sorted(int(d) for d in m.split(",")) == sorted(level_dims[0])]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("axis", [2, 1], ids=["x", "y"])
+@pytest.mark.parametrize("level", [(46, 62), (5, 7)],
+                         ids=["level0", "level3"])
+def test_lookup_window_kernels_compile_for_v5e(chip, lookup_on_the_chip,
+                                               level, axis):
+    """The alignment kernel and its mirror image alone, at the chairs
+    crop's level 0 `[16,46,62,2852]` and level 3 `[16,5,7,2852]` (a target
+    axis shorter than the window: every stage of the shifter meets the
+    zero fill), along x on the level and along y on the nine rows it
+    leaves: two Mosaic calls a case, each within the default scoped VMEM."""
+    from dexiraft_tpu.ops.corr import _axis_window
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    hl, wl = level
+    vol = (16, hl, wl, 2852) if axis == 2 else (16, hl, 2 * RADIUS + 1, 2852)
+    out = list(vol)
+    out[axis] = 2 * RADIUS + 1
+
+    def weighted(vol, center, weight):
+        return jnp.sum(_axis_window(vol, center, RADIUS, axis) * weight)
+
+    text = jax.jit(jax.value_and_grad(weighted)).lower(
+        sds(vol), sds((16, 2852)), sds(tuple(out))).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_lookup_kernels_stay_on_their_chip_under_a_data_mesh(
+        topo, lookup_on_the_chip):
+    """`v5-train-chairs-dp4`'s share of the same function: a global batch
+    of 64 (both streams of 32 pairs) over four chips. The partitioner
+    cannot split a kernel; the call names its own axes (`_per_chip`), so
+    every chip aligns its 16 rows and no level is gathered."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from dexiraft_tpu.parallel.layout import LAYOUT
+
+    mesh = Mesh(np.array(topo.devices), (LAYOUT.data_axis,))
+    data = NamedSharding(mesh, LAYOUT.batch())
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=data)
+
+    b, h, w, d = 64, 46, 62, 256
+    text = jax.jit(jax.grad(_twelve_lookups, (0, 1))).lower(
+        sds((b, h, w, d)), sds((b, h, w, d)), sds((b, h, w, 2)),
+        sds((b, h, w, LEVELS * (2 * RADIUS + 1) ** 2))).compile().as_text()
+    assert text.count("tpu_custom_call") == 16
+    assert "all-gather" not in text
+    assert f"f32[{h},{w},16,{h * w}]" in text      # a chip's rows of level 0
+    assert f"f32[{h},{w},64,{h * w}]" not in text
+
+
+def test_v5_train_step_compiles_with_the_lookup_kernels_and_fits_the_chip(
+        topo, lookup_on_the_chip):
+    """`v5-train-chairs`' whole step at its real size (batch 8, 368x496,
+    12 iterations, bf16, every iteration recomputed, `corr_impl=allpairs`)
+    with the lookup it takes on the chip; `benchmarks/compile_check.py`
+    sees the CPU backend and compiles the plain form. Per level the x and
+    the y alignment, forward, recomputed and mirrored: 24 Mosaic calls.
+    The compiler counts 8.74 GB of temporaries beside 0.62 GB of
+    arguments (with the dense hats 8.94; the chip reads a peak of 9.67)."""
+    import os.path as osp
+    import sys
+
+    import numpy as np
+
+    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+    from benchmarks import harness
+    from dexiraft_tpu.parallel import layout
+    from dexiraft_tpu.train.state import create_state
+    from dexiraft_tpu.train.step import make_train_step
+
+    cell = harness.load_cell("v5-train-chairs")
+    runner = harness.load_runner(cell.traffic["kind"])
+    cfg = harness.build_config(cell.config, cell.traffic["model_flags"], "tpu")
+    tc = runner._train_config(cell.traffic, 0)
+    mesh = layout.make_train_mesh(tc.batch_size, devices=topo.devices[:1])
+    repl = layout.replicated_sharding(mesh)
+    data = layout.batch_input_sharding(mesh)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
+        jax.eval_shape(lambda: create_state(jax.random.PRNGKey(0), cfg, tc)))
+    (h, w), b = tc.image_size, tc.batch_size
+    batch = {k: jax.ShapeDtypeStruct(shape, np.float32, sharding=data)
+             for k, shape in {"image1": (b, h, w, 3), "image2": (b, h, w, 3),
+                              "flow": (b, h, w, 2), "valid": (b, h, w)}.items()}
+    with mesh:
+        compiled = make_train_step(cfg, tc, mesh=mesh).lower(
+            state, batch).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 24
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert memory.temp_size_in_bytes < 1.1 * 8.74e9

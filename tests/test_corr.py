@@ -206,43 +206,136 @@ def _probe_coords(rng, b, h, w):
     return flat.reshape(b, h, w, 2)
 
 
-@pytest.mark.parametrize("corr_dtype", ["fp32", "bf16", "int8"])
-@pytest.mark.parametrize("shape", [(1, 5, 7), (2, 6, 9), (1, 46, 62)])
-def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype):
-    """4 levels, radius 4: (1, 46, 62) is the chairs crop's 46x62, 23x31,
-    11x15, 5x7; (2, 6, 9) ends in the 1x2 level and a 0x1 one, (1, 5, 7)
-    in 1x1 and 0x0. fp32: lookup and jax.grad with respect to BOTH
-    feature maps against the oracle. bf16/int8: the lookup against the
-    oracle on the STORED values (the lookup itself adds no rounding), and
-    for bf16 the gradient, whose cotangent passes through the bf16 cast
-    (int8's round has none: models/raft.py refuses to train with it)."""
+def _boundary_centres(size, radius, levels):
+    """Where aligning a window can break and weighting the axis cannot:
+    at every level, each whole position from below -(2r+2) - r (the
+    window's first position clamps there) to above size + r (it clamps at
+    size), so every fine shift, every coarse stride and both clamps, with
+    an epsilon either side of it, exactly on it, and half a pixel on."""
+    n = 2 * radius + 2
+    out = []
+    for i in range(levels):
+        whole = np.arange(-n - radius - 2, (size >> i) + radius + 3)
+        for off in (-2.0**-10, 0.0, 2.0**-10, 0.5):
+            out.append((whole + off) * 2.0**i)
+    return np.concatenate(out).astype(np.float32)
+
+
+def _boundary_coords(rng, h, w, radius, levels):
+    """(b, h, w, 2): every boundary centre of the x axis beside one of the
+    y axis (each list shuffled, the shorter cycled); b is what holds them."""
+    xs = rng.permutation(_boundary_centres(w, radius, levels))
+    ys = rng.permutation(_boundary_centres(h, radius, levels))
+    count = max(len(xs), len(ys))
+    b = -(-count // (h * w))
+    idx = np.arange(b * h * w)
+    coords = np.stack([xs[idx % len(xs)], ys[idx % len(ys)]], -1)
+    return coords.reshape(b, h, w, 2)
+
+
+def _tap_hats(center, radius, size):
+    """The form the lookup had until PR 34, as a second oracle: the axis
+    weighted by dense hats, hats[b, j, p, q] = relu(1 - |p - (c + j - r)|)."""
+    import jax.numpy as jnp
+
+    t = center[:, None, None, :] + jnp.arange(
+        -radius, radius + 1, dtype=jnp.float32)[:, None, None]
+    pos = jnp.arange(size, dtype=jnp.float32)[:, None]
+    return jnp.maximum(0.0, 1.0 - jnp.abs(pos - t))
+
+
+def _hat_lookup(pyr, coords):
+    import jax.numpy as jnp
+
+    r, b, q = pyr.radius, pyr.batch, pyr.ht * pyr.wd
+    flat = coords.reshape(b, q, 2).astype(jnp.float32)
+    out = []
+    for i, vol in enumerate(pyr.levels):
+        hl, wl = vol.shape[1:3]
+        ax = _tap_hats(flat[..., 0] / 2.0**i, r, wl)
+        ay = _tap_hats(flat[..., 1] / 2.0**i, r, hl)
+        rows = jnp.sum(ax[:, :, None] * vol.astype(jnp.float32)[:, None], 3)
+        out.append(jnp.sum(ay[:, None] * rows[:, :, None], 3).reshape(b, -1, q))
+    out = jnp.concatenate(out, axis=1)
+    return jnp.swapaxes(out, 1, 2).reshape(b, pyr.ht, pyr.wd, -1)
+
+
+# level extents either side of the window (2r+2 = 10) and of each stage
+# boundary (the coarse block is 17 wide, its stride 8), as height and as width
+_EDGE_SHAPES = [(9, 25), (10, 24), (16, 18), (17, 17), (18, 16), (24, 10),
+                (25, 9)]
+_ORACLE_CASES = (
+    [pytest.param(shape, dt, 4, "plain", id=f"{shape[1]}x{shape[2]}-{dt}")
+     for shape in [(1, 5, 7), (2, 6, 9), (1, 46, 62)]
+     for dt in ("fp32", "bf16", "int8")]
+    + [pytest.param((0, h, w), dt, 4, "plain", id=f"edges-{h}x{w}-{dt}")
+       for h, w in _EDGE_SHAPES for dt in ("fp32", "bf16", "int8")]
+    + [pytest.param((0, h, w), dt, r, "plain", id=f"edges-{h}x{w}-r{r}-{dt}")
+       for h, w in [(16, 18), (9, 25)] for r in (3, 2)
+       for dt in ("fp32", "bf16", "int8")]
+    # the same through the Pallas kernels, interpreted (ops/pallas_window.py)
+    + [pytest.param(shape, "fp32", 4, "kernel",
+                    id=f"kernel-{shape[1]}x{shape[2]}")
+       for shape in [(1, 5, 7), (2, 6, 9), (1, 46, 62)]]
+    + [pytest.param((0, h, w), "fp32", 4, "kernel", id=f"kernel-edges-{h}x{w}")
+       for h, w in _EDGE_SHAPES]
+    + [pytest.param((0, 16, 18), dt, r, "kernel",
+                    id=f"kernel-edges-16x18-r{r}-{dt}")
+       for r, dt in [(3, "fp32"), (2, "fp32"), (3, "bf16"), (3, "int8")]])
+
+
+@pytest.mark.parametrize("shape,corr_dtype,radius,path", _ORACLE_CASES)
+def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype, radius,
+                                                     path, monkeypatch):
+    """4 levels: (1, 46, 62) is the chairs crop's 46x62, 23x31, 11x15,
+    5x7; (2, 6, 9) ends in the 1x2 level and a 0x1 one, (1, 5, 7) in 1x1
+    and 0x0. The `edges` cases (batch 0: as many as hold the centres) put
+    level extents and centres where a window ALIGNED by selects can break
+    (_boundary_centres), at radius 4, 3 and 2: the stage widths follow
+    the radius. `kernel`: the Pallas kernels a TPU runs, interpreted.
+    fp32: lookup and jax.grad with respect to BOTH feature maps against
+    the oracle. bf16/int8: the lookup against the oracle on the STORED
+    values (the lookup itself adds no rounding), and for bf16 the
+    gradient, whose cotangent passes through the bf16 cast (int8's round
+    has none: models/raft.py refuses to train with it)."""
     import jax
     import jax.numpy as jnp
 
+    from dexiraft_tpu.ops import corr as corr_mod
+
+    monkeypatch.setattr(corr_mod, "_kernel_interpret",
+                        lambda: True if path == "kernel" else None)
     b, h, w = shape
     d = 16
+    win2 = (2 * radius + 1) ** 2
     rng = np.random.RandomState(b * 100 + h)
+    if b:
+        coords = _probe_coords(rng, b, h, w)
+    else:
+        coords = _boundary_coords(rng, h, w, radius, 4)
+        b = coords.shape[0]
+    coords = jnp.asarray(coords)
     f1 = jnp.asarray(rng.randn(b, h, w, d).astype(np.float32))
     f2 = jnp.asarray(rng.randn(b, h, w, d).astype(np.float32))
-    coords = jnp.asarray(_probe_coords(rng, b, h, w))
-    weight = jnp.asarray(rng.randn(b, h, w, 4 * 81).astype(np.float32))
+    weight = jnp.asarray(rng.randn(b, h, w, 4 * win2).astype(np.float32))
 
     @jax.jit
     def ours(f1, f2):
-        pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=4,
+        pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=radius,
                                  dtype=corr_dtype)
         return corr_lookup(pyr, coords)
 
     @jax.jit
     def oracle(f1, f2):
-        return _oracle_lookup(_oracle_volumes(f1, f2, 4), coords, 4)
+        return _oracle_lookup(_oracle_volumes(f1, f2, 4), coords, radius)
 
     # one pyramid for the lookup and for the stored values read below: a
     # second build may round a product at a bf16 boundary the other way
-    pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=4, dtype=corr_dtype)
+    pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=radius,
+                             dtype=corr_dtype)
     assert pyr.level_shapes == tuple((h >> i, w >> i) for i in range(4))
     got = np.asarray(jax.jit(corr_lookup)(pyr, coords))
-    assert got.shape == (b, h, w, 4 * 81) and got.dtype == np.float32
+    assert got.shape == (b, h, w, 4 * win2) and got.dtype == np.float32
 
     if corr_dtype == "fp32":
         want = np.asarray(oracle(f1, f2))
@@ -255,7 +348,7 @@ def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype):
             stored.append(jnp.asarray(
                 np.moveaxis(v, -1, 1).reshape(b * h * w, hl, wl)))
         want = np.asarray(jax.jit(
-            lambda vols: _oracle_lookup(vols, coords, 4))(stored))
+            lambda vols: _oracle_lookup(vols, coords, radius))(stored))
         # and the stored values are the oracle's, rounded once
         full = np.asarray(_oracle_volumes(f1, f2, 1)[0])
         step = {"bf16": 2.0**-8 * np.abs(full).max(),
@@ -278,3 +371,87 @@ def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype):
         # bf16: each level's cotangent is rounded to bf16 on its way back
         tol = 1e-5 if corr_dtype == "fp32" else 2.0**-7
         np.testing.assert_allclose(g, want_g, rtol=0, atol=tol * scale)
+
+
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+@pytest.mark.parametrize("shape,radius", [((2, 6, 9), 4), ((1, 16, 18), 3),
+                                          ((1, 46, 62), 4)])
+def test_lookup_matches_the_hat_form_and_its_coordinate_gradient(
+        shape, radius, path, monkeypatch):
+    """Against the dense-hat form the lookup had until PR 34 (a second
+    oracle, now the tests' own): the window, and d/d coords, which the hats
+    give as the slope of the interpolation and the aligned form has to
+    state itself (floor has no gradient). The model stops that gradient
+    (models/raft.py), so nothing else reads it."""
+    import jax
+    import jax.numpy as jnp
+
+    from dexiraft_tpu.ops import corr as corr_mod
+
+    monkeypatch.setattr(corr_mod, "_kernel_interpret",
+                        lambda: True if path == "kernel" else None)
+    b, h, w = shape
+    rng = np.random.RandomState(h)
+    f1 = jnp.asarray(rng.randn(b, h, w, 16).astype(np.float32))
+    f2 = jnp.asarray(rng.randn(b, h, w, 16).astype(np.float32))
+    pyr = build_corr_pyramid(f1, f2, num_levels=4, radius=radius)
+    # random centres: the slope is not defined ON a whole position
+    coords = jnp.asarray(_probe_coords(rng, b, h, w)
+                         + rng.uniform(0.05, 0.45, (b, h, w, 2))
+                         .astype(np.float32))
+    weight = jnp.asarray(rng.randn(
+        b, h, w, 4 * (2 * radius + 1) ** 2).astype(np.float32))
+
+    got = jax.jit(corr_lookup)(pyr, coords)
+    want = jax.jit(_hat_lookup)(pyr, coords)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=2e-5)
+    g = jax.jit(jax.grad(lambda c: jnp.sum(corr_lookup(pyr, c) * weight)))
+    g_hat = jax.jit(jax.grad(lambda c: jnp.sum(_hat_lookup(pyr, c) * weight)))
+    want_g = np.asarray(g_hat(coords))
+    assert np.abs(want_g).max() > 0.1
+    np.testing.assert_allclose(np.asarray(g(coords)), want_g, rtol=0,
+                               atol=2e-5 * np.abs(want_g).max())
+
+
+def test_kernel_path_runs_shard_by_shard_on_a_data_mesh(monkeypatch):
+    """The partitioner cannot split a Pallas call: alone it would gather a
+    batch-sharded level onto every chip (`v5-train-chairs-dp4`). The call
+    wraps itself in a shard_map over the axes the layout names
+    (ops/pallas_window.py `_per_chip`): same values and gradients as the
+    plain form on one device, and no all-gather in the program."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from dexiraft_tpu.ops import corr as corr_mod
+    from dexiraft_tpu.parallel.layout import LAYOUT
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    b, h, w, d = 4, 8, 12, 16
+    rng = np.random.RandomState(5)
+    f1 = jnp.asarray(rng.randn(b, h, w, d).astype(np.float32))
+    f2 = jnp.asarray(rng.randn(b, h, w, d).astype(np.float32))
+    coords = jnp.asarray(_probe_coords(rng, b, h, w))
+    weight = jnp.asarray(rng.randn(b, h, w, 3 * 49).astype(np.float32))
+
+    def loss(f1, f2, coords):
+        pyr = build_corr_pyramid(f1, f2, num_levels=3, radius=3)
+        return jnp.sum(corr_lookup(pyr, coords) * weight)
+
+    grad = jax.grad(loss, (0, 1))
+    monkeypatch.setattr(corr_mod, "_kernel_interpret", lambda: None)
+    want = jax.jit(grad)(f1, f2, coords)
+
+    monkeypatch.setattr(corr_mod, "_kernel_interpret", lambda: True)
+    mesh = Mesh(np.array(devices[:4]), (LAYOUT.data_axis,))
+    data = NamedSharding(mesh, LAYOUT.batch())
+    fn = jax.jit(grad, in_shardings=(data,) * 3, out_shardings=(data,) * 2)
+    args = [jax.device_put(a, data) for a in (f1, f2, coords)]
+    for g, want_g in zip(fn(*args), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want_g),
+                                   rtol=0, atol=1e-5 * np.abs(want_g).max())
+    text = fn.lower(*args).compile().as_text()
+    assert "all-gather" not in text

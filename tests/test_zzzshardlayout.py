@@ -254,7 +254,7 @@ class TestSpecLayout:
                     "param_leaf_spec", "batch", "batch_spatial",
                     "batch_spatial_compute", "carry",
                     "corr_query_rows", "batch_for", "corr_volume",
-                    "corr_fmaps", "data_size", "has_seq", "has_fsdp",
+                    "corr_fmaps", "corr_window", "data_size", "has_seq", "has_fsdp",
                     "fsdp_size", "seq_size"}
         public = {n for n in dir(SpecLayout) if not n.startswith("_")
                   and callable(getattr(SpecLayout, n))}
@@ -274,6 +274,19 @@ class TestSpecLayout:
         assert spec_str(LAYOUT.batch_for(m2)) == "P('data', 'seq')"
         assert spec_str(LAYOUT.corr_volume(m2)) == "P('data', 'seq')"
         assert spec_str(LAYOUT.corr_fmaps(m2)) == "P('data', 'seq')"
+        # the lookup's kernels, (S1, S2, B, H*W): an axis only where it
+        # divides; nothing to split on one device or with no mesh axis left
+        whole, per_query = LAYOUT.corr_window(m2.abstract_mesh, (46, 62, 8, 64))
+        assert spec_str(whole) == "P(None, None, 'data', 'seq')"
+        assert spec_str(per_query) == "P('data', 'seq')"
+        whole, per_query = LAYOUT.corr_window(m2.abstract_mesh, (46, 62, 6, 64))
+        assert spec_str(whole) == "P(None, None, None, 'seq')"
+        assert spec_str(per_query) == "P(None, 'seq')"
+        assert LAYOUT.corr_window(m2.abstract_mesh, (46, 62, 6, 63)) is None
+        import jax
+
+        one = make_mesh(jax.devices()[:1]).abstract_mesh
+        assert LAYOUT.corr_window(one, (46, 62, 8, 64)) is None
         assert LAYOUT.data_size(m2) == 4
         assert LAYOUT.has_seq(m2) and not LAYOUT.has_seq(m1)
         assert LAYOUT.seq_size(m2) == 2 and LAYOUT.seq_size(m1) == 1
