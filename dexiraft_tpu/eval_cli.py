@@ -273,6 +273,20 @@ def _make_engine(args, eval_fn, mesh, mode, warm_start=False, watch=None):
     return engine
 
 
+_setup_reported = False
+
+
+def _report_setup() -> None:
+    """The `[setup]` line (what JAX spent before warm, by jitted
+    program), once a process: at the first geometry's mark_warm."""
+    global _setup_reported
+    if not _setup_reported:
+        from dexiraft_tpu.analysis import guards
+
+        _setup_reported = True
+        print(guards.setup_line(), flush=True)
+
+
 def _strict_wrap(eval_fn, watch):
     """Per-geometry compile absorption for the per-image eval loops.
 
@@ -294,6 +308,7 @@ def _strict_wrap(eval_fn, watch):
         else:
             seen.add(sig)
             watch.mark_warm()
+            _report_setup()
         return out
 
     return wrapped

@@ -441,22 +441,30 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
     denom = jnp.maximum(n_targets(batch, _pred_heads(cfg)), 1).astype(dtype)
     scale = _embed_scale(cfg, dtype)
 
+    def jit_as(name, f):
+        # the name a trace, a compile log and the set-up table
+        # (`analysis.guards.setup_report`) give the program
+        f.__name__ = f.__qualname__ = name
+        return jax.jit(f)
+
     def vjp_of(run):
         def f(p, x, pos, seg, dy):
             _, pull = jax.vjp(lambda p, x: run(p, x, pos, seg), p, x)
             return pull(dy)
-        return jax.jit(f)
+        return jit_as("reference_layer_vjp", f)
+
+    def head_loss(p, x, tok, seg):
+        return head_loss_sum(p, x, tok, seg, cfg, block) / denom
 
     kinds = [_kind(cfg, i) for i in range(n_layers)]
     runs = {k: _layer_of(cfg, kinds.index(k), block, **share)
             for k in set(kinds)}
-    fwd = {k: jax.jit(run) for k, run in runs.items()}
+    fwd = {k: jit_as("reference_layer", run) for k, run in runs.items()}
     bwd = {k: vjp_of(run) for k, run in runs.items()}
     top = {k: params[k] for k in ("final_norm", "head")}
-    head = jax.jit(jax.value_and_grad(
-        lambda p, x, tok, seg: head_loss_sum(p, x, tok, seg, cfg, block)
-        / denom, argnums=(0, 1)))
-    embed_grad = jax.jit(lambda tok, dx: jnp.zeros_like(
+    head = jit_as("reference_head_loss_and_grad",
+                  jax.value_and_grad(head_loss, argnums=(0, 1)))
+    embed_grad = jit_as("reference_embed_grad", lambda tok, dx: jnp.zeros_like(
         params["embed"]).at[tok].add(dx * scale))
 
     add = lambda acc, g: g if acc is None else jax.tree.map(jnp.add, acc, g)

@@ -266,8 +266,8 @@ _spans_lock = threading.Lock()
 
 def add(name: str, seconds: float) -> None:
     """One more duration under `name`: what `span` does on exit, for a
-    duration measured elsewhere (a sum over a batch's rows, a
-    jax.monitoring event)."""
+    duration measured elsewhere (a sum over a batch's rows, what one
+    jitted program's compile-path events came to)."""
     with _spans_lock:
         rec = _spans.get(name)
         if rec is None:

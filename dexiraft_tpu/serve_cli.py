@@ -317,6 +317,10 @@ def _warmup(engine, geometries, carry_fn=None, video=None) -> None:
                     "contract (make_eval_step(adaptive=True))")
     if video is not None:
         video.warmup(geometries)
+    from dexiraft_tpu.analysis import guards
+
+    # every named bucket has marked warm: what JAX spent on them
+    print(guards.setup_line(), flush=True)
     engine.reset_stats()  # warmup is not traffic
 
 

@@ -878,6 +878,7 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
                     # steps, not compiles)
                     watch = jaxguards.RecompileWatch(f"train[{tc.name}]")
                     watch.mark_warm()
+                    print(jaxguards.setup_line(), flush=True)
                     batch_devices = len(
                         jax.tree.leaves(batch)[0].sharding.device_set)
                     if args.strict:

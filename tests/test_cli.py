@@ -41,7 +41,7 @@ def _train_args(tmp_path, steps, extra=()):
     ]
 
 
-def test_train_resume_eval_roundtrip(chairs_env):
+def test_train_resume_eval_roundtrip(chairs_env, capsys):
     import jax
 
     from dexiraft_tpu.train_cli import main as train_main
@@ -51,6 +51,11 @@ def test_train_resume_eval_roundtrip(chairs_env):
     train_main(_train_args(tmp, 3))
     ckpt_dir = str(tmp / "ckpts" / "t")
     assert ckpt.latest_step(ckpt_dir) == 3
+    # what JAX spent before the first step was done, by jitted program:
+    # one line, at the first mark_warm and not at the later ones
+    (setup,) = [line for line in capsys.readouterr().out.splitlines()
+                if line.startswith("[setup] ")]
+    assert "program(s)" in setup and "uncached" in setup
     assert (tmp / "runs" / "t" / "metrics.jsonl").exists()
 
     # resume continues the step counter (full-state restore)

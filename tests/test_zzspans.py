@@ -306,24 +306,246 @@ def test_listener_keeps_what_a_fresh_jit_costs_and_mark_warm_freezes_it():
     assert guards.jax_at_warm() == frozen == got
 
 
-def test_self_seconds_takes_out_what_ran_inside():
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+CACHE = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _play(script):
+    """JAX's begin ("b") and end ("e", with its seconds) events as the
+    listeners are handed them, on this thread's stack."""
     from dexiraft_tpu.analysis import guards
 
-    # own thread: the listener's record of what it has seen is per thread
-    out = {}
+    for kind, event, fun_name, *seconds in script:
+        kw = {"fun_name": fun_name} if fun_name else {}
+        if kind == "b":
+            guards._on_begin(event, time.time(), **kw)
+        else:
+            guards._on_end(event, seconds[0], **kw)
 
-    def run():
-        time.sleep(0.02)
-        a = guards._self_seconds(0.01)    # a child: the last 10 ms
-        time.sleep(0.02)
-        b = guards._self_seconds(0.015)   # a second child
-        c = guards._self_seconds(0.045)   # their parent, just ended
-        d = guards._self_seconds(0.001)   # a sibling after it
-        out.update(a=a, b=b, c=c, d=d)
 
-    t = threading.Thread(target=run)
-    t.start()
-    t.join(30)
-    assert not t.is_alive()
-    assert out["a"] == 0.01 and out["b"] == 0.015 and out["d"] == 0.001
-    assert out["c"] == pytest.approx(0.045 - 0.01 - 0.015)
+def _played(*scripts):
+    """Each script on a thread of its own (the stack is per thread), all
+    at once; the `jax:` table afterwards as {name: (seconds, count)}."""
+    profiling.reset("jax:")
+    go = threading.Barrier(len(scripts))
+
+    def run(script):
+        go.wait(10)
+        _play(script)
+
+    pool = [threading.Thread(target=run, args=(s,)) for s in scripts]
+    for t in pool:
+        t.start()
+    for t in pool:
+        t.join(30)
+    assert not any(t.is_alive() for t in pool)
+    return {k: (pytest.approx(v["seconds"]), v["count"])
+            for k, v in profiling.snapshot("jax:").items()}
+
+
+STACK_CASES = {
+    # an inner jit's trace inside a trace, inside the outer's lowering:
+    # each event's seconds leave out what ended inside it, and all of
+    # it is the root's
+    "nested": (
+        [[("b", LOWER, "jit(step)"), ("b", TRACE, "f"), ("b", TRACE, "g"),
+          ("e", TRACE, "g", 0.1), ("e", TRACE, "f", 0.5),
+          ("e", LOWER, "jit(step)", 2.0)]],
+        {"jax:trace": (0.5, 1), "jax:trace/step": (0.5, 1),
+         "jax:lower": (1.5, 1), "jax:lower/step": (1.5, 1)}),
+    # one call's trace, lowering and compile are three roots of one
+    # name; another program's are its own
+    "siblings": (
+        [[("b", TRACE, "step"), ("b", TRACE, "inner"),
+          ("e", TRACE, "inner", 0.25), ("b", TRACE, "inner"),
+          ("e", TRACE, "inner", 0.25), ("e", TRACE, "step", 1.0),
+          ("b", LOWER, "jit(step)"), ("e", LOWER, "jit(step)", 0.5),
+          ("b", TRACE, "loss"), ("e", TRACE, "loss", 2.0),
+          ("b", LOWER, "pmap(loss)"), ("e", LOWER, "pmap(loss)", 0.125)]],
+        {"jax:trace": (3.0, 2), "jax:trace/step": (1.0, 1),
+         "jax:trace/loss": (2.0, 1), "jax:lower": (0.625, 2),
+         "jax:lower/step": (0.5, 1), "jax:lower/loss": (0.125, 1)}),
+    # the cache read has no begin: a leaf of the compile that is open,
+    # which is then no miss; a compile with no read inside is one
+    "cache_read": (
+        [[("b", COMPILE, "jit(step)"), ("e", CACHE, "", 4.0),
+          ("e", COMPILE, "jit(step)", 5.0),
+          ("b", COMPILE, "jit(init)"), ("e", COMPILE, "jit(init)", 2.0)]],
+        {"jax:backend_compile": (3.0, 2), "jax:cache_load": (4.0, 1),
+         "jax:backend_compile/step": (1.0, 1),
+         "jax:cache_load/step": (4.0, 1),
+         "jax:backend_compile/init": (2.0, 1),
+         "jax:uncached/init": (2.0, 1)}),
+    # a begin whose end JAX skipped goes when its parent ends, and what
+    # ended inside it ended inside the parent; an end with no begin and
+    # nothing open is a root of its own; the stack is whole afterwards
+    "unmatched": (
+        [[("b", TRACE, "step"), ("b", TRACE, "lost"), ("b", TRACE, "inner"),
+          ("e", TRACE, "inner", 0.125), ("e", TRACE, "step", 1.0),
+          ("e", LOWER, "jit(ghost)", 0.5), ("e", CACHE, "", 0.25),
+          ("b", TRACE, "after"), ("e", TRACE, "after", 0.75)]],
+        {"jax:trace": (1.75, 2), "jax:trace/step": (1.0, 1),
+         "jax:trace/after": (0.75, 1), "jax:lower": (0.5, 1),
+         "jax:lower/ghost": (0.5, 1), "jax:cache_load": (0.25, 1),
+         "jax:cache_load/?": (0.25, 1)}),
+    # a thread's open frames are its own
+    "two_threads": (
+        [[("b", TRACE, "a"), ("b", TRACE, "inner"),
+          ("e", TRACE, "inner", 0.5), ("e", TRACE, "a", 1.0)],
+         [("b", LOWER, "jit(b)"), ("b", TRACE, "inner"),
+          ("e", TRACE, "inner", 0.25), ("e", LOWER, "jit(b)", 2.0)]],
+        {"jax:trace": (1.25, 2), "jax:trace/a": (1.0, 1),
+         "jax:trace/b": (0.25, 1), "jax:lower": (1.75, 1),
+         "jax:lower/b": (1.75, 1)}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACK_CASES))
+def test_stack_books_self_seconds_to_the_root_that_caused_them(case):
+    scripts, want = STACK_CASES[case]
+    assert _played(*scripts) == want
+
+
+def _fresh_programs():
+    """A jitted function that calls an inner jit, under names no other
+    test compiles."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def spans_inner(x):
+        return jnp.sin(x) * 2.0
+
+    @jax.jit
+    def spans_outer(x):
+        return spans_inner(x) + spans_inner(x * 2.0)
+
+    return spans_outer
+
+
+PHASES = ("jax:trace", "jax:lower", "jax:backend_compile", "jax:cache_load")
+
+
+def test_a_fresh_jit_books_one_root_with_its_inner_jit_inside():
+    import jax
+    import jax.numpy as jnp
+
+    outer = _fresh_programs()
+    x = jax.block_until_ready(jnp.ones((7, 3)))  # eager programs: before
+    profiling.reset("jax:")
+    jax.block_until_ready(outer(x))
+    got = profiling.snapshot("jax:")
+    roots = {name.partition("/")[2] for name in got} - {""}
+    assert roots == {"spans_outer"}     # the inner's seconds are inside
+    for phase in ("jax:trace", "jax:lower", "jax:backend_compile"):
+        # one add a root frame, not one an event
+        assert got[phase + "/spans_outer"]["count"] == 1
+    jax.block_until_ready(outer(jnp.ones((2, 2))))  # eager roots beside it
+    got = profiling.snapshot("jax:")
+    assert got["jax:lower/spans_outer"]["count"] == 2
+    for phase in PHASES:
+        under = [v for k, v in got.items() if k.startswith(phase + "/")]
+        if phase in got:
+            assert got[phase]["seconds"] == pytest.approx(
+                sum(v["seconds"] for v in under))
+            assert got[phase]["count"] == sum(v["count"] for v in under)
+        else:
+            assert not under
+
+
+def test_drift_warning_names_the_function_that_recompiled():
+    import io
+
+    import jax
+    import jax.numpy as jnp
+
+    from dexiraft_tpu.analysis import guards
+
+    outer = _fresh_programs()
+    a, b = jnp.ones((7, 3)), jnp.ones((5, 2))
+    jax.block_until_ready(outer(a))
+    watch = guards.RecompileWatch("spans-test")
+    watch.mark_warm()
+    assert watch.recompiled() == []
+    jax.block_until_ready(outer(b))   # a second signature: drift
+    with watch.sanctioned():          # a planned compile keeps no name
+        jax.block_until_ready(outer(jnp.ones((4, 4))))
+    jax.block_until_ready(outer(b * 1.0))
+    jax.block_until_ready(outer(jnp.ones((3, 9))))
+    assert watch.recompiled().count("spans_outer") == 2 <= watch.drift
+    buf = io.StringIO()
+    assert watch.warn_if_drifted(file=buf)
+    assert "recompile(s) after warmup: " in buf.getvalue()
+    assert "spans_outer x2" in buf.getvalue()
+    with pytest.raises(guards.RecompileBudgetExceeded, match="spans_outer x2"):
+        watch.check()
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """The persistent compilation cache in a directory of the test's."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    compilation_cache.reset_cache()
+    yield tmp_path
+    for k, v in was.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_a_root_compiled_with_the_cache_off_counts_as_uncached():
+    import jax
+    import jax.numpy as jnp
+
+    assert not jax.config.jax_compilation_cache_dir
+    outer = _fresh_programs()
+    x = jax.block_until_ready(jnp.ones((7, 3)))
+    profiling.reset("jax:")
+    jax.block_until_ready(outer(x))
+    got = profiling.snapshot("jax:")
+    assert got["jax:uncached/spans_outer"]["count"] == 1
+    assert "jax:cache_load" not in got
+
+
+def test_a_root_that_read_its_cache_entry_is_not_uncached(persistent_cache):
+    import jax
+    import jax.numpy as jnp
+
+    from dexiraft_tpu.analysis import guards
+
+    x = jax.block_until_ready(jnp.ones((7, 3)))
+    profiling.reset("jax:")
+    jax.block_until_ready(_fresh_programs()(x))   # a miss: written
+    cold = profiling.snapshot("jax:")
+    assert cold["jax:uncached/spans_outer"]["count"] == 1
+    assert "jax:cache_load/spans_outer" not in cold
+
+    profiling.reset("jax:")
+    jax.block_until_ready(_fresh_programs()(x))   # a new jit: read back
+    guards.RecompileWatch("spans-test").mark_warm()
+    warm = guards.jax_at_warm()
+    assert "jax:uncached/spans_outer" not in warm
+    assert warm["jax:cache_load/spans_outer"]["count"] == 1
+    assert warm["jax:cache_load/spans_outer"]["seconds"] > 0.0
+    (row,) = guards.setup_report(floor_s=0.0)
+    assert row["root"] == "spans_outer"
+    assert (row["programs"], row["uncached"]) == (1, 0)
+    assert row["seconds"] == pytest.approx(
+        row["trace_s"] + row["lower_s"] + row["backend_compile_s"]
+        + row["cache_load_s"])
+    assert row["seconds"] == pytest.approx(sum(
+        warm[p]["seconds"] for p in PHASES if p in warm))
+    assert guards.setup_line().startswith("[setup] jax spent ")
+    (folded,) = guards.setup_report(floor_s=1e9)
+    assert folded == {**row, "root": "other"}
