@@ -36,7 +36,7 @@ from dexiraft_tpu.models.extractor import BasicEncoder, SmallEncoder
 from dexiraft_tpu.models.update import BasicUpdateBlock, RefineFlow, SmallUpdateBlock
 from dexiraft_tpu.ops.corr import build_corr_pyramid
 from dexiraft_tpu.ops.local_corr import build_local_corr
-from dexiraft_tpu.ops.grid import coords_grid, upflow8
+from dexiraft_tpu.ops.grid import as_planes, coords_grid, upflow8
 from dexiraft_tpu.ops.upsample import upsample_flow_convex
 
 
@@ -79,8 +79,7 @@ class RAFTStep(nn.Module):
         coords0 = coords_grid(b, pyr.ht, pyr.wd)
 
         coords1 = jax.lax.stop_gradient(carry["coords1"])  # (2B or B, h, w, 2)
-        flow = coords1 - jnp.concatenate([coords0, coords0], 0) if dual \
-            else coords1 - coords0
+        flow = coords1 - coords0[:1]  # the grid broadcasts over both streams
         if cfg.fused_update:
             # fused step (config.fused_update): the lookup and the motion
             # encoder's 1x1 corr conv run in ONE Pallas kernel inside the
@@ -122,7 +121,9 @@ class RAFTStep(nn.Module):
         else:
             coords1 = coords1 + delta
 
-        carry = {**carry, "coords1": coords1, "net": net}
+        # the carry keeps the queries on the lanes: the update above and
+        # every reader of coords1 (the lookup, the flow) run on planes
+        carry = {**carry, "coords1": as_planes(coords1), "net": net}
 
         if not self.emit:
             # test mode: keep only what the post-scan upsample needs

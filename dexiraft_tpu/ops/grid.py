@@ -13,6 +13,19 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
+
+
+def as_planes(field: jax.Array) -> jax.Array:
+    """Hold a (B, H, W, 2) field — coordinates, a flow — physically as
+    its two (H, W) planes, (B, 2, H, W); logically nothing changes. A TPU
+    tiles an array's two minor dimensions into (8, 128) registers: with
+    the 2 components as the lane axis the array is 64 times its size in
+    tiles, and every copy and elementwise pass over it pays for that.
+    Left to itself the chip's compiler gives a loop's carry or a scan's
+    stacked output that form, whatever the producer computed in, even
+    one that is logically (2, B, H, W) (PERF.md section 6, PR 29, PR 36)."""
+    return with_layout_constraint(field, Layout(major_to_minor=(0, 3, 1, 2)))
 
 
 def coords_grid(batch: int, ht: int, wd: int, dtype=jnp.float32) -> jax.Array:

@@ -103,15 +103,25 @@ class LocalCorr:
     """On-demand correlation pyramid: same lookup interface as CorrPyramid.
 
     Holds fmap1 and the avg-pooled fmap2 pyramid (core/corr.py:64-72);
-    correlation is computed per lookup instead of materialized.
+    correlation is computed per lookup instead of materialized. Under
+    kernel="flash" both are held in the form the kernel reads
+    (ops/pallas_corr.py pad_flash_operands), made once in
+    build_local_corr: a lookup hands the kernel only its coordinates.
     """
 
-    fmap1: jax.Array  # (B, H, W, C), fp32
-    fmap2_pyramid: tuple  # tuple of (B, H>>i, W>>i, C) in the storage dtype
+    # kernel="xla": (B, H, W, C) fp32. kernel="flash": (B, Np, C) fp32,
+    # the queries flattened and zero-padded to a pixel-block multiple
+    fmap1: jax.Array
+    # one (B, H>>i, W>>i, C) level per pyramid index, in the storage
+    # dtype; under kernel="flash" zero-padded to the kernel's row-block
+    # and lane multiples (a degenerate 0-row tail level stays empty)
+    fmap2_pyramid: tuple
     batch: int = flax.struct.field(pytree_node=False)
     ht: int = flax.struct.field(pytree_node=False)
     wd: int = flax.struct.field(pytree_node=False)
     radius: int = flax.struct.field(pytree_node=False)
+    # the true (H>>i, W>>i) of every level, whatever fmap2_pyramid pads
+    level_shapes: tuple = flax.struct.field(pytree_node=False)
     row_chunk: Optional[int] = flax.struct.field(pytree_node=False, default=None)
     # lookup implementation: "xla" (local_corr_level matmuls) or "flash"
     # (blocked HBM-streaming kernel — ops/pallas_corr.py)
@@ -139,8 +149,8 @@ class LocalCorr:
                 # whole-model paths exercisable off-chip
                 # (tests/test_zzzflashcorr.py)
                 corr = flash_local_corr_level(
-                    self.fmap1, f2, coords_i, self.radius, None,
-                    self.row_chunk)
+                    self.fmap1, f2, coords_i, self.radius,
+                    self.level_shapes[i], None, self.row_chunk)
             else:
                 corr = local_corr_level(
                     self.fmap1, f2, coords_i, self.radius, self.row_chunk)
@@ -159,8 +169,8 @@ class LocalCorr:
         from dexiraft_tpu.ops.pallas_corr import flash_fused_step
 
         return flash_fused_step(self.fmap1, self.fmap2_pyramid, coords,
-                                weight, bias, self.radius, None,
-                                self.row_chunk)
+                                weight, bias, self.radius, self.level_shapes,
+                                None, self.row_chunk)
 
 
 def build_local_corr(
@@ -180,7 +190,8 @@ def build_local_corr(
     level is then stored bf16/int8 with a per-level scale (ops/quant.py)
     and the lookup dequantizes in-register.
 
-    ``kernel`` picks the lookup implementation ("xla" | "flash").
+    ``kernel`` picks the lookup implementation ("xla" | "flash"); "flash"
+    stores the operands as its kernel reads them, padded here, once.
     """
     if kernel not in ("xla", "flash"):
         raise ValueError(f"unknown local-corr kernel {kernel!r}; "
@@ -191,8 +202,14 @@ def build_local_corr(
     for _ in range(num_levels - 1):
         pooled.append(avg_pool_2x2(pooled[-1]))
     stored = [store_corr(lvl, dtype) for lvl in pooled]
+    levels = tuple(s[0] for s in stored)
+    level_shapes = tuple(tuple(lvl.shape[1:3]) for lvl in levels)
+    if kernel == "flash":
+        from dexiraft_tpu.ops.pallas_corr import pad_flash_operands
+
+        f1, levels = pad_flash_operands(f1, levels)
     return LocalCorr(
-        fmap1=f1, fmap2_pyramid=tuple(s[0] for s in stored),
-        batch=b, ht=h, wd=w,
-        radius=radius, row_chunk=row_chunk, kernel=kernel,
+        fmap1=f1, fmap2_pyramid=levels,
+        batch=b, ht=h, wd=w, radius=radius, level_shapes=level_shapes,
+        row_chunk=row_chunk, kernel=kernel,
         scales=(tuple(s[1] for s in stored) if dtype == "int8" else None))

@@ -19,7 +19,8 @@ step's first matmul.
 
 Consequences: VMEM use is O(pixel_block) at ANY geometry, HBM holds only
 the fmaps (never a volume — levels are padded only to a row-block and
-lane multiple), and there is ONE kernel per refinement iteration. Two
+lane multiple, once, where the pyramid is built: pad_flash_operands),
+and there is ONE kernel per refinement iteration. Two
 entry points share it (corr_impl="flash", through ops/local_corr.py's
 LocalCorr): flash_fused_step contracts each level's window against the
 motion encoder's 1x1 conv weight slice in-kernel, so only the
@@ -32,8 +33,9 @@ without fused_update. Levels are read in their storage dtype
 (fp32/bf16/int8) and upcast in-register.
 
 Gradients: forward-only kernel wrapped in jax.custom_vjp; the VJPs
-recompute through the XLA formulation (local_corr_level /
-fused_reference): fmap gradients and zero coords gradient, the CUDA
+slice the padded operands back to their true extents and recompute
+through the XLA formulation (local_corr_level / fused_reference): fmap
+gradients (zero in the padding) and zero coords gradient, the CUDA
 backward's semantics (correlation_kernel.cu:307).
 
 tests/test_chip_compile.py compiles both entry points for v5e. (An
@@ -92,26 +94,39 @@ def fused_reference(fmap1, fmap2_levels, coords, weight, bias, radius,
             + bias.astype(jnp.float32))
 
 
-def _level_bwd_xla(radius, interpret, row_chunk, res, g):
-    """flash_local_corr_level's VJP: recompute through local_corr_level."""
-    fmap1, fmap2, coords = res
+def _unpad_f1(f1, hw):
+    """The kernel's (B, Np, C) queries back as the (B, H, W, C) fmap."""
+    h, w = hw
+    return f1[:, :h * w].reshape(f1.shape[0], h, w, f1.shape[2])
+
+
+def _level_bwd_xla(radius, level_shape, interpret, row_chunk, res, g):
+    """flash_local_corr_level's VJP: recompute through local_corr_level
+    on the operands' true extents (the slices sit inside the function
+    jax.vjp walks, so the cotangents come back in the padded form)."""
+    f1, level, coords = res
+    h2, w2 = level_shape
     # row-chunked recompute: bounds the backward's transient patch buffer
     # the same way the forward XLA path does
     _, vjp = jax.vjp(
-        lambda f1, f2: local_corr_level(f1, f2, coords, radius,
-                                        row_chunk=row_chunk),
-        fmap1, fmap2)
+        lambda f1_, lv: local_corr_level(
+            _unpad_f1(f1_, coords.shape[1:3]), lv[:, :h2, :w2], coords,
+            radius, row_chunk=row_chunk),
+        f1, level)
     g1, g2 = vjp(g)
     return g1, g2, jnp.zeros_like(coords)
 
 
-def _fused_bwd(radius, interpret, row_chunk, res, g):
-    """flash_fused_step's VJP: recompute through fused_reference."""
-    fmap1, fmap2_levels, coords, weight, bias = res
+def _fused_bwd(radius, level_shapes, interpret, row_chunk, res, g):
+    """flash_fused_step's VJP: recompute through fused_reference, sliced
+    as in _level_bwd_xla."""
+    f1, levels, coords, weight, bias = res
     _, vjp = jax.vjp(
-        lambda f1, f2s, w_, b_: fused_reference(
-            f1, f2s, coords, w_, b_, radius, row_chunk=row_chunk),
-        fmap1, fmap2_levels, weight, bias)
+        lambda f1_, lvs, w_, b_: fused_reference(
+            _unpad_f1(f1_, coords.shape[1:3]),
+            tuple(lv[:, :h2, :w2] for lv, (h2, w2) in zip(lvs, level_shapes)),
+            coords, w_, b_, radius, row_chunk=row_chunk),
+        f1, levels, weight, bias)
     g1, g2s, gw, gb = vjp(g)
     return g1, g2s, jnp.zeros_like(coords), gw, gb
 
@@ -142,10 +157,37 @@ def _fused_bwd(radius, interpret, row_chunk, res, g):
 # queries per grid step / fmap2 rows per DMA block, read at trace time
 # (tests set toy tiles on the module attribute): a resident set of ~5 MB
 # at C=256 and W2=128 (f1 block 256 KB + the two (8, W2, C) row-block
-# slots, 1 MiB each at fp32 + the (P, rows*W2) dots transient).
+# slots, 1 MiB each at fp32 + the (P, rows*W2) dots transient; the
+# (2, P) coordinate block is one 8 KB tile pair).
 _FLASH_PIXEL_BLOCK = 256
 _FLASH_ROWS = 8
 _LANES = 128
+
+
+def pad_flash_operands(fmap1: jax.Array, fmap2_levels) -> tuple:
+    """The kernel's operands in the form it reads, made ONCE where the
+    pyramid is built (ops/local_corr.py build_local_corr) and not once a
+    lookup: a refinement loop hands the kernel nothing but coordinates.
+
+    fmap1 (B, H, W, C) -> fp32 (B, Np, C): the queries flattened and
+    zero-padded to a pixel-block multiple. Each level -> its rows padded
+    to the DMA block size and its columns to the lane width, in the
+    STORAGE dtype (fp32/bf16/int8 — the quantized bytes are what stream
+    HBM->VMEM); zero rows/columns read as out-of-frame. The column pad is
+    what Mosaic needs: the kernel splits the (P, rows*w2) dots into
+    (P, rows, w2), which it only lays out when w2 is a whole number of
+    128-lane tiles. A degenerate 0-row/0-col tail level (a 1x1 level
+    pools to nothing) stays as it is: it never enters the kernel.
+    Returns (f1, levels); the VJPs slice them back to the levels' true
+    extents, which the caller keeps (LocalCorr.level_shapes)."""
+    b, h, w, c = fmap1.shape
+    f1 = jnp.pad(fmap1.astype(jnp.float32).reshape(b, h * w, c),
+                 ((0, 0), (0, (-h * w) % _FLASH_PIXEL_BLOCK), (0, 0)))
+    levels = tuple(
+        jnp.pad(f2, ((0, 0), (0, (-f2.shape[1]) % _FLASH_ROWS),
+                     (0, (-f2.shape[2]) % _LANES), (0, 0)))
+        if f2.shape[1] and f2.shape[2] else f2 for f2 in fmap2_levels)
+    return f1, levels
 
 
 def _hat(taps_center, length, offset, radius, p_block):
@@ -171,6 +213,7 @@ def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
     scratch: the two f2 row-block slots, window accumulator, [out
     accumulator], one DMA semaphore a slot.
 
+    ``level_shapes`` are the staged levels' PADDED extents (the refs');
     ``level_ids`` are the ORIGINAL pyramid indices of the staged levels
     (degenerate 0-row tail levels are filtered out on the XLA side —
     their windows are identically zero); ``num_levels_total`` sizes the
@@ -192,6 +235,10 @@ def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
     p_block = f1_ref.shape[1]
     c = f1_ref.shape[2]
     bi = pl.program_id(0)
+    # the (2, P) coordinate block, queries on the lanes, turned into
+    # per-query columns once a grid step
+    cols = coords_ref[0].astype(jnp.float32).T  # (P, 2)
+    cx, cy = cols[:, 0], cols[:, 1]
 
     # fold the 1/sqrt(C) normalization into the query block once — every
     # dots matmul below then carries it (linear; the caller never folds
@@ -218,7 +265,7 @@ def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
     tys, first, end = [], [], []
     for f2_ref, lvl in zip(lvl_refs, level_ids):
         n_blocks = f2_ref.shape[1] // rows
-        ty = coords_ref[0, :, 1].astype(jnp.float32) * (1.0 / (2.0 ** lvl))
+        ty = cy * (1.0 / (2.0 ** lvl))
         lo = jnp.min(jnp.ceil((ty - (r + 1) - (rows - 1)) / rows))
         hi = jnp.max(jnp.floor((ty + (r + 1)) / rows))
         tys.append(ty)
@@ -249,7 +296,7 @@ def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
 
     for i, (lvl, (h2, w2)) in enumerate(zip(level_ids, level_shapes)):
         inv = 1.0 / (2.0 ** lvl)
-        tx = coords_ref[0, :, 0].astype(jnp.float32) * inv  # (P,)
+        tx = cx * inv  # (P,)
         ty = tys[i]
         # x hats cover the whole level width (a row of queries spans it);
         # y hats are built per row block inside the loop
@@ -295,26 +342,28 @@ def _flash_kernel(*refs, radius: int, level_ids: tuple, level_shapes: tuple,
         out_ref[0] = acc_ref[...]
 
 
-def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
+def _flash_forward(f1: jax.Array, levels: tuple, coords: jax.Array,
                    weight, bias, radius: int, interpret=None) -> jax.Array:
-    """Shared XLA-side prep for the fused (weight/bias given) and lookup
-    (weight=bias=None) flash kernels. fmap2 levels are padded only to a
-    row-block multiple (zero rows read as out-of-frame) and enter the
+    """Shared XLA-side call of the fused (weight/bias given) and lookup
+    (weight=bias=None) flash kernels on operands pad_flash_operands made:
+    nothing is padded here but the coordinates, which reach the kernel as
+    (B, 2, Np) planes, the queries on the lanes. The levels enter the
     kernel in HBM; everything else is pixel-blocked into VMEM."""
     if interpret is None:
         interpret = _interpret_default()
-    b, h, w, c = fmap1.shape
+    b, h, w, _ = coords.shape
+    np_tot, c = f1.shape[1:]
     r = radius
     win = 2 * r + 1
-    num_levels = len(fmap2_levels)
+    num_levels = len(levels)
     fused = weight is not None
     rows = _FLASH_ROWS
     pixel_block = _FLASH_PIXEL_BLOCK
 
-    # degenerate 0-row/0-col tail levels (a 1x1 level pools to nothing)
-    # never enter the kernel: their windows are identically zero, and a
-    # zero-size operand cannot flow through pallas_call
-    level_ids = tuple(i for i, f2 in enumerate(fmap2_levels)
+    # degenerate 0-row/0-col tail levels never enter the kernel: their
+    # windows are identically zero, and a zero-size operand cannot flow
+    # through pallas_call
+    level_ids = tuple(i for i, f2 in enumerate(levels)
                       if f2.shape[1] > 0 and f2.shape[2] > 0)
     if not level_ids:
         # every staged level is degenerate (single-level call on a
@@ -324,36 +373,23 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
             return jnp.broadcast_to(bias.astype(jnp.float32),
                                     (b, h, w, weight.shape[1]))
         return jnp.zeros((b, h, w, num_levels * win * win), jnp.float32)
-    kept = [fmap2_levels[i] for i in level_ids]
-
-    # pad each level's rows to the DMA block size and its columns to the
-    # lane width, in the STORAGE dtype (fp32/bf16/int8 — the quantized
-    # bytes are what stream HBM->VMEM). Zero rows/columns read as
-    # out-of-frame. The column pad is what Mosaic needs: the kernel
-    # splits the (P, rows*w2) dots into (P, rows, w2), which it only
-    # lays out when w2 is a whole number of 128-lane tiles
-    f2p = [jnp.pad(f2, ((0, 0), (0, (-f2.shape[1]) % rows),
-                        (0, (-f2.shape[2]) % _LANES), (0, 0)))
-           for f2 in kept]
-    level_shapes = tuple(f2.shape[1:3] for f2 in f2p)
-    w2_max = max(s[1] for s in level_shapes)
+    f2p = [levels[i] for i in level_ids]
+    padded_shapes = tuple(f2.shape[1:3] for f2 in f2p)
+    w2_max = max(s[1] for s in padded_shapes)
 
     n = h * w
-    n_pad = (-n) % pixel_block
-    np_tot = n + n_pad
-    flat = lambda a: jnp.pad(  # noqa: E731
-        a.reshape(b, n, a.shape[3]), ((0, 0), (0, n_pad), (0, 0)))
-    f1_flat = flat(fmap1.astype(jnp.float32))
     # padded tail queries carry coords 0 — they force row block 0 of each
     # level to be fetched, compute a real window, and are sliced away
-    co_flat = flat(coords.astype(jnp.float32))
+    co = jnp.pad(
+        jnp.moveaxis(coords.astype(jnp.float32), 3, 1).reshape(b, 2, n),
+        ((0, 0), (0, 0), (0, np_tot - n)))
 
     grid = (b, np_tot // pixel_block)
     f1_spec = pl.BlockSpec((1, pixel_block, c), lambda bi, ti: (bi, ti, 0),
                            memory_space=pltpu.VMEM)
-    co_spec = pl.BlockSpec((1, pixel_block, 2), lambda bi, ti: (bi, ti, 0),
+    co_spec = pl.BlockSpec((1, 2, pixel_block), lambda bi, ti: (bi, 0, ti),
                            memory_space=pltpu.VMEM)
-    inputs = [f1_flat, co_flat]
+    inputs = [f1, co]
     in_specs = [f1_spec, co_spec]
     if fused:
         feat = weight.shape[1]
@@ -381,7 +417,7 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
 
     kernel = functools.partial(_flash_kernel, radius=r,
                                level_ids=level_ids,
-                               level_shapes=level_shapes,
+                               level_shapes=padded_shapes,
                                num_levels_total=num_levels,
                                rows=rows, fused=fused)
     out = pl.pallas_call(
@@ -401,49 +437,52 @@ def _flash_forward(fmap1: jax.Array, fmap2_levels: tuple, coords: jax.Array,
     return out[:, :n].reshape(b, h, w, out_ch)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_local_corr_level(fmap1, fmap2, coords, radius: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_local_corr_level(f1, level, coords, radius: int, level_shape,
                            interpret=None, row_chunk=8):
-    """(B,H,W,C) x (B,H2,W2,C) x (B,H,W,2 level coords) -> (B,H,W,(2r+1)^2):
+    """(B,Np,C) queries x one (B,H2p,W2p,C) level, both as
+    pad_flash_operands made them (``level_shape`` the level's true
+    extent), x (B,H,W,2 level coords) -> (B,H,W,(2r+1)^2):
     local_corr_level's semantics (coords in LEVEL pixels, zero coords
     grad; the VJP recomputes through it). interpret=None defers to
     DEXIRAFT_PALLAS_INTERPRET (off-chip debug switch, resolved at trace
     time). row_chunk only bounds the backward recompute's transient
     buffer: pass the model's corr_row_chunk."""
-    return _flash_forward(fmap1, (fmap2,), coords, None, None, radius,
-                          interpret)
+    return _flash_forward(f1, (level,), coords, None, None, radius, interpret)
 
 
-def _flash_level_fwd(fmap1, fmap2, coords, radius, interpret, row_chunk):
-    return (_flash_forward(fmap1, (fmap2,), coords, None, None, radius,
+def _flash_level_fwd(f1, level, coords, radius, level_shape, interpret,
+                     row_chunk):
+    return (_flash_forward(f1, (level,), coords, None, None, radius,
                            interpret),
-            (fmap1, fmap2, coords))
+            (f1, level, coords))
 
 
 flash_local_corr_level.defvjp(_flash_level_fwd, _level_bwd_xla)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def flash_fused_step(fmap1, fmap2_levels, coords, weight, bias,
-                     radius: int, interpret=None, row_chunk=8):
-    """Fused lookup+update-entry: (B,H,W,C) x L levels x level-0 coords
-    x (L*(2r+1)^2, F) weight x (F,) bias -> (B,H,W,F), one kernel per
-    refinement iteration.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def flash_fused_step(f1, levels, coords, weight, bias, radius: int,
+                     level_shapes, interpret=None, row_chunk=8):
+    """Fused lookup+update-entry: (B,Np,C) queries x L levels, as
+    pad_flash_operands made them (``level_shapes`` their true extents),
+    x (B,H,W,2) level-0 coords x (L*(2r+1)^2, F) weight x (F,) bias ->
+    (B,H,W,F), one kernel per refinement iteration.
 
-    Gradients flow to fmap1, float-dtype fmap2 levels, weight and bias
+    Gradients flow to the queries, float-dtype levels, weight and bias
     by recomputing through fused_reference; coords get zero gradient.
     int8-stored levels are non-differentiable by construction (their
     float0 cotangent falls out of jax.vjp) — the model layer refuses to
     train int8 pyramids rather than training with dead fmap2 gradients."""
-    return _flash_forward(fmap1, tuple(fmap2_levels), coords, weight, bias,
-                          radius, interpret)
+    return _flash_forward(f1, tuple(levels), coords, weight, bias, radius,
+                          interpret)
 
 
-def _flash_fused_fwd(fmap1, fmap2_levels, coords, weight, bias, radius,
+def _flash_fused_fwd(f1, levels, coords, weight, bias, radius, level_shapes,
                      interpret, row_chunk):
-    out = _flash_forward(fmap1, tuple(fmap2_levels), coords, weight, bias,
-                         radius, interpret)
-    return out, (fmap1, tuple(fmap2_levels), coords, weight, bias)
+    out = _flash_forward(f1, tuple(levels), coords, weight, bias, radius,
+                         interpret)
+    return out, (f1, tuple(levels), coords, weight, bias)
 
 
 flash_fused_step.defvjp(_flash_fused_fwd, _fused_bwd)

@@ -29,7 +29,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Layout, with_layout_constraint
+
+from dexiraft_tpu.ops.grid import as_planes
 
 _EXACT = jax.lax.Precision.HIGHEST  # fp32 through a 0/1 matrix: no rounding
 
@@ -82,7 +83,7 @@ def convex_combine(flow_padded: jax.Array, mask: jax.Array) -> jax.Array:
     # stays (B, 2, 8H, 8W), which is what it was computed as: left to
     # itself the chip's compiler gave the scan's stacked predictions the
     # 2 as their lane axis (64x padding, 8.4 GB at the chairs crop).
-    return with_layout_constraint(out, Layout(major_to_minor=(0, 3, 1, 2)))
+    return as_planes(out)
 
 
 @jax.named_scope("upsample_flow_convex")

@@ -23,6 +23,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from dexiraft_tpu.config import resolve_corr_impl
@@ -66,15 +67,20 @@ def chip(topo):
 
 
 def _shapes(chip, dtype, h=H, w=W, b=B):
+    """The kernel's operands as `build_local_corr(kernel="flash")` hands
+    them over: queries and levels already padded (`pad_flash_operands`),
+    `level_shapes` the levels' true extents."""
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
-    levels = tuple(sds((b, h >> i, w >> i, C), DTYPES[dtype])
-                   for i in range(LEVELS))
+    raw = tuple(sds((b, h >> i, w >> i, C), DTYPES[dtype])
+                for i in range(LEVELS))
+    f1, levels = jax.eval_shape(pc.pad_flash_operands, sds((b, h, w, C)), raw)
     win2 = (2 * RADIUS + 1) ** 2
-    return dict(f1=sds((b, h, w, C)), coords=sds((b, h, w, 2)),
-                levels=levels, weight=sds((LEVELS * win2, FEAT)),
-                bias=sds((FEAT,)))
+    return dict(f1=sds(f1.shape), coords=sds((b, h, w, 2)),
+                levels=tuple(sds(lv.shape, lv.dtype) for lv in levels),
+                level_shapes=tuple(lv.shape[1:3] for lv in raw),
+                weight=sds((LEVELS * win2, FEAT)), bias=sds((FEAT,)))
 
 
 def _compiled_text(fn, *args) -> str:
@@ -96,7 +102,7 @@ def test_flash_fused_step_compiles_for_v5e(chip, dtype, hw):
     s = _shapes(chip, dtype, *hw)
     text = _compiled_text(
         lambda f1, lv, co, w, b: pc.flash_fused_step(
-            f1, lv, co, w, b, RADIUS, False),
+            f1, lv, co, w, b, RADIUS, s["level_shapes"], False),
         s["f1"], s["levels"], s["coords"], s["weight"], s["bias"])
     assert "tpu_custom_call" in text
 
@@ -109,9 +115,96 @@ def test_flash_lookup_compiles_for_v5e(chip, dtype, level):
     s = _shapes(chip, dtype)
     text = _compiled_text(
         lambda f1, f2, co: pc.flash_local_corr_level(
-            f1, f2, co, RADIUS, False),
+            f1, f2, co, RADIUS, s["level_shapes"][level], False),
         s["f1"], s["levels"][level], s["coords"])
     assert "tpu_custom_call" in text
+
+
+# ---- the eval loop around the kernel (models/raft.py RAFTStep) ------------
+
+@pytest.fixture(scope="module", params=["v1", "v5"])
+def eval_loop(chip, request):
+    """An eval cell's forward (flash + fused, 440x1024, 32 iterations,
+    bf16, the cell's batch of 32) compiled for the described chip, 20 s
+    (v1) and 55 s (v5): the batch its loop carries (v5's two streams
+    ride one batch of 64) and the instructions of its `while` body as
+    (name, result type, opcode)."""
+    import os.path as osp
+    import sys
+
+    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+    from benchmarks import harness
+    from dexiraft_tpu.models.raft import RAFT
+    from dexiraft_tpu.train.step import make_eval_step
+
+    cell = harness.load_cell(f"{request.param}-eval-sintel")
+    tr = cell.traffic
+    cfg = harness.build_config(cell.config, tr["model_flags"], "tpu")
+    dummy = np.zeros((1, 64, 64, 3), np.float32)
+    variables = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda: RAFT(cfg).init(
+            jax.random.PRNGKey(0), dummy, dummy, iters=1, train=False)))
+    image = jax.ShapeDtypeStruct((tr["batch"], 440, 1024, 3), np.float32,
+                                 sharding=chip)
+    text = make_eval_step(cfg, iters=tr["iters"]).lower(
+        variables, image, image).compile().as_text()
+    body, = re.findall(r" while\(.*?body=%?([\w.\-]+)", text)
+    block = text.split(f"\n%{body} (")[1].split("\n}\n")[0]
+    loop = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", block,
+                      re.M)
+    assert len(loop) > 100 and any(
+        op == "custom-call" and name.startswith("flash_fused_step")
+        for name, _, op in loop)
+    return tr["batch"] * (2 if cfg.has_edge_stream else 1), loop
+
+
+def _elements(kind):
+    return int(np.prod([int(d) for d in
+                        re.search(r"\[([\d,]+)\]", kind).group(1).split(",")]))
+
+
+def test_eval_loop_pads_no_kernel_operand_for_v5e(eval_loop):
+    """The kernel's operands are padded where the pyramid is built, once
+    a pair: the loop's only `pad` is the coordinates' own (`f32[32,2,
+    7168]` in v1). The parent's v1 program fails this on `pad.458
+    f32[32,7168,256]` (the queries), `pad.460 f32[32,56,128,256]` and
+    `pad.461`-`pad.463` (the levels), 32 times a batch: XLA does not move
+    a loop-invariant pad out of a loop, because it grows its operand."""
+    nb, loop = eval_loop
+    pads = [f"{name} {kind}" for name, kind, op in loop if op == "pad"]
+    large = [p for p in pads
+             if _elements(p) >= nb * 8 * 128 * C]  # the smallest level, padded
+    assert pads and not large, pads
+
+
+def test_eval_loop_keeps_the_queries_on_the_lanes_for_v5e(eval_loop):
+    """No instruction of the loop holds an array of B x 55 x 128 or more
+    positions whose lane dimension is the 2 coordinate components, but a
+    convolution's own operand: the 7x7 flow convolution reads
+    `bf16[B,55,128,2]{3,0,2,1}`, which one `copy` makes from the planes
+    (and the memory-space moves of it). The parent's v1 program fails
+    this on `copy.264 f32[32,55,128,2]{3,2,1,0}` and `pad.459
+    f32[32,7168,2]` (the kernel's coordinate operand), `copy.265` and the
+    carry itself, `f32[32,55,128,2]{3,0,2,1}` (64 times its size in
+    tiles), and `subtract_convert_fusion.2`; v5's failed it again with
+    the carry in planes, while the flow was taken against a concatenated
+    grid (`copy.1203`, `copy.1204 f32[64,55,128,2]{3,0,2,1}`)."""
+    nb, loop = eval_loop
+    starved = set()
+    for name, kind, op in loop:
+        for dims, order in re.findall(r"\w+\[([\d,]+)\]\{([\d,]+)", kind):
+            dims = [int(d) for d in dims.split(",")]
+            if (dims[int(order.split(",")[0])] == 2
+                    and np.prod(dims) >= nb * H * W * 2):
+                starved.add(f"{name} {kind} {op}")
+    own = {s for s in starved if re.search(  # the operand, made and moved
+        rf"bf16\[{nb},55,128,2\]\{{3,0,2,1[^ ]* (copy|copy-done|slice-done|"
+        r"custom-call)$", s)}
+    assert not starved - own, sorted(starved - own)
+    # the carry: the two planes of each pair, (8, 128) tiles over (h, w)
+    assert any(kind.startswith(f"f32[{nb},55,128,2]{{2,1,3,0:T(8,128)")
+               for _, kind, _ in loop)
 
 
 def test_interpret_switch_is_an_error_on_a_tpu_backend(monkeypatch):

@@ -560,3 +560,44 @@ def test_streaming_feature_reuse_matches_chained_pairs():
                            features2=feats[2], flow_init=low_s)
     assert float(jnp.max(jnp.abs(up_a1 - up_b1))) <= 1e-4
     assert float(jnp.max(jnp.abs(up_a2 - up_b2))) <= 1e-4
+
+
+def test_warm_start_through_the_flash_kernel(monkeypatch):
+    """`flow_init` enters the carry above the loop, and the loop holds
+    its coordinates as planes down to the kernel's `(B, 2, Np)` operand
+    (ISSUE 36): a streamed warm start through flash + fused (what `auto`
+    serves on a TPU; interpreted here) is the all-pairs path's on the
+    same parameters, and differs from the cold start."""
+    import jax
+    import jax.numpy as jnp
+
+    from dexiraft_tpu.config import raft_v1
+    from dexiraft_tpu.models.raft import RAFT
+
+    monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
+    k1, k2 = jax.random.split(jax.random.PRNGKey(11))
+    im1 = jax.random.uniform(k1, (2, 48, 64, 3), jnp.float32, 0, 255)
+    im2 = jax.random.uniform(k2, (2, 48, 64, 3), jnp.float32, 0, 255)
+    plain = RAFT(raft_v1(small=True))
+    flash = RAFT(raft_v1(small=True, corr_impl="flash", fused_update=True))
+    variables = plain.init(jax.random.PRNGKey(0), im1, im2, iters=1,
+                           train=False)
+    # per-item warm starts: a warm row beside a cold (zero) row
+    fi = jnp.stack([jax.random.uniform(jax.random.PRNGKey(3), (6, 8, 2),
+                                       jnp.float32, -2, 2),
+                    jnp.zeros((6, 8, 2))])
+
+    def streamed(model, flow_init):
+        f1 = model.apply(variables, im1, mode="encode")
+        f2 = model.apply(variables, im2, mode="encode")
+        return jax.jit(lambda v, a, b, fi_: model.apply(
+            v, None, iters=3, test_mode=True, mode="step", features1=a,
+            features2=b, flow_init=fi_))(variables, f1, f2, flow_init)
+
+    low_p, up_p = streamed(plain, fi)
+    low_f, up_f = streamed(flash, fi)
+    assert float(jnp.max(jnp.abs(low_p - low_f))) <= 1e-4
+    assert float(jnp.max(jnp.abs(up_p - up_f))) <= 1e-4
+    low_c, _ = streamed(flash, None)
+    assert float(jnp.max(jnp.abs(low_c[0] - low_f[0]))) > 1e-2  # warm row
+    assert float(jnp.max(jnp.abs(low_c[1] - low_f[1]))) <= 1e-6  # cold row

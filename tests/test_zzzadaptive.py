@@ -167,6 +167,45 @@ class TestAdaptiveRefine:
             np.testing.assert_allclose(_get(up_b)[row], _get(up_s)[0],
                                        rtol=0, atol=1e-4)
 
+    def test_flash_kernel_under_the_while_loop(self, setup, monkeypatch):
+        """The adaptive driver reads `coords1` differences and freezes
+        carry rows outside `RAFTStep`, on a carry the step holds as
+        planes down to the kernel's coordinate operand (ISSUE 36): with
+        flash + fused (what `auto` serves on a TPU; interpreted here) a
+        full budget at tol 0 is the scan bit for bit, the all-pairs path
+        to rounding, and rows still freeze on their own."""
+        from dexiraft_tpu.train.step import make_eval_step
+
+        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
+        cfg = dataclasses.replace(setup["cfg"], corr_impl="flash",
+                                  fused_update=True)
+        a = np.stack([setup["frame"](1), setup["frame"](3)])
+        b = np.stack([setup["frame"](2), setup["frame"](4)])
+        low_f, up_f = make_eval_step(cfg, iters=ITERS)(
+            setup["variables"], a, b)
+        low_a, up_a, iu, _ = make_eval_step(
+            dataclasses.replace(cfg, converge_tol=0.0), iters=ITERS,
+            adaptive=True)(setup["variables"], a, b,
+                           iter_budget=np.int32(ITERS))
+        assert np.array_equal(_get(up_f), _get(up_a))
+        assert np.array_equal(_get(low_f), _get(low_a))
+        assert _get(iu).tolist() == [ITERS, ITERS]
+        _, up_plain = setup["fixed"](setup["variables"], a, b)
+        np.testing.assert_allclose(_get(up_f), _get(up_plain), rtol=0,
+                                   atol=1e-3)
+        # the gate: the damped model's rows stop early, each where its
+        # solo all-pairs run stops
+        _, up_d, iu_d, _ = make_eval_step(cfg, iters=ITERS, adaptive=True)(
+            setup["damped"], a, b, iter_budget=np.int32(ITERS))
+        assert int(_get(iu_d).max()) < ITERS
+        for row in range(2):
+            _, up_s, iu_s, _ = setup["adapt"](
+                setup["damped"], a[row][None], b[row][None],
+                iter_budget=np.int32(ITERS))
+            assert int(_get(iu_d)[row]) == int(_get(iu_s)[0])
+            np.testing.assert_allclose(_get(up_d)[row], _get(up_s)[0],
+                                       rtol=0, atol=1e-4)
+
     def test_config_rejects_negative_tol(self):
         from dataclasses import replace
 

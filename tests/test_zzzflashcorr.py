@@ -27,13 +27,28 @@ import pytest
 from dexiraft_tpu.ops.corr import build_corr_pyramid, corr_lookup
 from dexiraft_tpu.ops import pallas_corr
 from dexiraft_tpu.ops.local_corr import build_local_corr, local_corr_level
-from dexiraft_tpu.ops.pallas_corr import (
-    flash_fused_step,
-    flash_local_corr_level,
-    fused_reference,
-)
+from dexiraft_tpu.ops.pallas_corr import fused_reference, pad_flash_operands
 
 REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
+
+
+def flash_fused_step(fmap1, levels, coords, weight, bias, radius,
+                     interpret=None):
+    """The kernel's fused entry point on RAW arrays: the operands padded
+    as build_local_corr(kernel="flash") pads them, inside whatever
+    differentiates this — a gradient w.r.t. the raw arrays runs the
+    VJP's slices of the padded operands and the pad's own transpose."""
+    f1, padded = pad_flash_operands(fmap1, tuple(levels))
+    return pallas_corr.flash_fused_step(
+        f1, padded, coords, weight, bias, radius,
+        tuple(tuple(lv.shape[1:3]) for lv in levels), interpret)
+
+
+def flash_local_corr_level(fmap1, fmap2, coords, radius, interpret=None):
+    """The kernel's lookup entry point on RAW arrays, as above."""
+    f1, (level,) = pad_flash_operands(fmap1, (fmap2,))
+    return pallas_corr.flash_local_corr_level(
+        f1, level, coords, radius, tuple(fmap2.shape[1:3]), interpret)
 
 
 @pytest.fixture(autouse=True)
@@ -195,6 +210,127 @@ class TestFlashKernelParity:
                                 w8, bias, radius, True)
         bound = 0.05 * float(jnp.max(jnp.abs(ref)))
         assert float(jnp.max(jnp.abs(out8 - ref))) <= max(bound, 1e-3)
+
+
+# -- operands made once, where the pyramid is built (ISSUE 36) ---------------
+
+# (h, w, levels): level widths 64 / 32 / 16 under the lane width; odd
+# widths 13 / 6 / 3 / 1 with h*w = 65 not a multiple of the pixel block
+# and a degenerate 0-row tail level
+PREPARED = {"w64_32_16": (4, 64, 3), "odd_tail": (5, 13, 4)}
+
+
+def _prepared_case(name, dtype, radius=2):
+    """-> (flash pyramid, xla pyramid, coords, weight with any int8
+    scales folded in, bias)."""
+    h, w, levels = PREPARED[name]
+    f1, f2, coords, weight, bias = _setup(
+        jax.random.PRNGKey(sum(map(ord, name + dtype))), b=2, h=h, w=w,
+        c=16, levels=levels, radius=radius)
+    lc = build_local_corr(f1, f2, levels, radius, dtype=dtype, kernel="flash")
+    lx = build_local_corr(f1, f2, levels, radius, dtype=dtype)
+    if lx.scales is not None:
+        ww = (2 * radius + 1) ** 2
+        weight = jnp.concatenate([weight[i * ww:(i + 1) * ww] * lx.scales[i]
+                                  for i in range(levels)])
+    return lc, lx, coords, weight, bias
+
+
+class TestPreparedOperands:
+    """build_local_corr(kernel="flash") hands the kernel its operands as
+    it reads them; a lookup pads nothing but the coordinates."""
+
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8"])
+    @pytest.mark.parametrize("name", list(PREPARED))
+    def test_fused_matches_reference(self, name, dtype):
+        lc, lx, coords, weight, bias = _prepared_case(name, dtype)
+        out = pallas_corr.flash_fused_step(
+            lc.fmap1, lc.fmap2_pyramid, coords, weight, bias, lc.radius,
+            lc.level_shapes, True)
+        ref = fused_reference(lx.fmap1, lx.fmap2_pyramid, coords, weight,
+                              bias, lx.radius)
+        assert out.shape == ref.shape
+        assert float(jnp.max(jnp.abs(out - ref))) <= 1e-3 * max(
+            1.0, float(jnp.max(jnp.abs(ref))))
+
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8"])
+    @pytest.mark.parametrize("name", list(PREPARED))
+    def test_lookup_matches_local_corr_level(self, name, dtype, monkeypatch):
+        """The pyramid's own lookup: a kernel call a level (the tail
+        level of `odd_tail` none: its windows are zero), scales after."""
+        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
+        lc, lx, coords, _, _ = _prepared_case(name, dtype)
+        out, ref = lc(coords), lx(coords)
+        assert out.shape == ref.shape
+        assert float(jnp.max(jnp.abs(out - ref))) <= 1e-3 * max(
+            1.0, float(jnp.max(jnp.abs(ref))))
+        if name == "odd_tail":
+            assert lc.level_shapes[-1][0] == 0
+            np.testing.assert_array_equal(np.asarray(out[..., -25:]), 0.0)
+
+    @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+    @pytest.mark.parametrize("name", list(PREPARED))
+    def test_fused_vjp_slices_the_padded_operands(self, name, dtype):
+        """jax.grad through flash_fused_step w.r.t. the PADDED operands:
+        inside the true extents the reference's gradients, in the
+        padding exactly zero."""
+        lc, lx, coords, weight, bias = _prepared_case(name, dtype)
+        h, w = lc.ht, lc.wd
+
+        def loss_flash(f1_, lv_, w_, b_):
+            return jnp.sum(pallas_corr.flash_fused_step(
+                f1_, lv_, coords, w_, b_, lc.radius, lc.level_shapes,
+                True) ** 2)
+
+        def loss_ref(f1_, lv_, w_, b_):
+            return jnp.sum(fused_reference(f1_, lv_, coords, w_, b_,
+                                           lx.radius) ** 2)
+
+        gf = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(
+            lc.fmap1, lc.fmap2_pyramid, weight, bias)
+        gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(
+            lx.fmap1, lx.fmap2_pyramid, weight, bias)
+        tol = dict(rtol=1e-3, atol=1e-3) if dtype == "fp32" else dict(
+            rtol=1e-2, atol=1e-2)
+        f32 = lambda a: np.asarray(a, dtype=np.float32)  # noqa: E731
+        assert gf[0].shape == lc.fmap1.shape
+        np.testing.assert_allclose(
+            f32(gf[0][:, :h * w]).reshape(gr[0].shape), f32(gr[0]), **tol)
+        np.testing.assert_array_equal(f32(gf[0][:, h * w:]), 0.0)
+        for g, ref, (h2, w2) in zip(gf[1], gr[1], lc.level_shapes):
+            assert g.shape[1:3] != (h2, w2) or not h2  # it was padded
+            np.testing.assert_allclose(f32(g[:, :h2, :w2]), f32(ref), **tol)
+            np.testing.assert_array_equal(f32(g[:, h2:]), 0.0)
+            np.testing.assert_array_equal(f32(g[:, :, w2:]), 0.0)
+        for g, ref in zip(gf[2:], gr[2:]):
+            np.testing.assert_allclose(f32(g), f32(ref), **tol)
+        assert float(jnp.abs(gr[0]).max()) > 0
+
+    @pytest.mark.parametrize("name", list(PREPARED))
+    def test_lookup_vjp_slices_the_padded_operands(self, name):
+        """The same through flash_local_corr_level, at level 1."""
+        lc, lx, coords, _, _ = _prepared_case(name, "fp32")
+        (h2, w2), co = lc.level_shapes[1], coords / 2.0
+
+        def grads(fn, f1_, lv_):
+            return jax.grad(lambda a, b_, c_: jnp.sum(fn(a, b_, c_) ** 2),
+                            argnums=(0, 1, 2))(f1_, lv_, co)
+
+        gf = grads(lambda a, b_, c_: pallas_corr.flash_local_corr_level(
+            a, b_, c_, 2, (h2, w2), True), lc.fmap1, lc.fmap2_pyramid[1])
+        gr = grads(lambda a, b_, c_: local_corr_level(a, b_, c_, 2),
+                   lx.fmap1, lx.fmap2_pyramid[1])
+        n = lc.ht * lc.wd
+        np.testing.assert_allclose(
+            np.asarray(gf[0][:, :n]).reshape(gr[0].shape), np.asarray(gr[0]),
+            rtol=1e-3, atol=1e-3)
+        np.testing.assert_array_equal(np.asarray(gf[0][:, n:]), 0.0)
+        np.testing.assert_allclose(np.asarray(gf[1][:, :h2, :w2]),
+                                   np.asarray(gr[1]), rtol=1e-3, atol=1e-3)
+        np.testing.assert_array_equal(np.asarray(gf[1][:, h2:]), 0.0)
+        np.testing.assert_array_equal(np.asarray(gf[1][:, :, w2:]), 0.0)
+        np.testing.assert_array_equal(np.asarray(gf[2]), 0.0)
+        assert float(jnp.abs(gr[1]).max()) > 0
 
 
 # -- the model's own width and radii, and the frame's edges -----------------
@@ -742,8 +878,9 @@ class TestMemoryFootprint:
 
         def flash(f1_, f2_, co_):
             lc = build_local_corr(f1_, f2_, levels, radius, kernel="flash")
-            return flash_fused_step(lc.fmap1, lc.fmap2_pyramid, co_,
-                                    weight, bias, radius, True)
+            return pallas_corr.flash_fused_step(
+                lc.fmap1, lc.fmap2_pyramid, co_, weight, bias, radius,
+                lc.level_shapes, True)
 
         def allpairs(f1_, f2_, co_):
             pyr = build_corr_pyramid(f1_, f2_, levels, radius)
