@@ -239,6 +239,12 @@ VARIANTS = {
 }
 
 
+# what a router's normalisation adds to the chosen scores' sum (a
+# configuration's `route_eps`) where the published code adds nothing
+# that fp32 sees
+ROUTE_EPS = 1e-20
+
+
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
     """A DeepSeek-V3-style decoder (`model_type: deepseek_v3`): latent
@@ -300,6 +306,12 @@ class LMConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def mixer(self, i: int) -> str:
+        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
+        return "mla"
+
+    route_eps = ROUTE_EPS
 
 
 def kanana2(**kw) -> LMConfig:
@@ -377,48 +389,26 @@ class AfmoeConfig:
     moe_chunk: Optional[int] = None
 
     def __post_init__(self):
-        heads, kv = self.num_attention_heads, self.num_key_value_heads
-        if heads % kv:
-            raise ValueError(f"{heads} query heads do not divide over "
-                             f"{kv} key/value heads")
-        group = heads // kv
-        _hold(self, "heads_held", heads)
+        _hold_grouped_heads(self)
         _hold(self, "experts_held", self.num_experts)
-        first, count = self.heads_held
-        if self.kv_heads_held is None:
-            lo, hi = first // group, (first + count - 1) // group
-            object.__setattr__(self, "kv_heads_held", (lo, hi - lo + 1))
-        _hold(self, "kv_heads_held", kv)
-        kv_first, kv_count = self.kv_heads_held
-        # the op's rule, in local numbers: held query head i reads held
-        # key/value head i // (count // kv_count). True of query heads
-        # inside one key/value head, and of whole groups from a group's
-        # first head
-        per = max(count // kv_count, 1)
-        if count % kv_count or any(
-                (first + i) // group - kv_first != i // per
-                for i in range(count)):
-            raise ValueError(
-                f"heads_held={self.heads_held} with kv_heads_held="
-                f"{self.kv_heads_held}: the query heads a chip holds "
-                f"divide evenly, in order, over the key/value heads it "
-                f"holds ({group} query heads read one key/value head)")
-        kinds = self.layer_types
-        if kinds is None:
-            kinds = tuple(_LAYER_KINDS[(i + 1) % 4 == 0]
-                          for i in range(self.num_hidden_layers))
-        kinds = tuple(kinds)
-        if (len(kinds) != self.num_hidden_layers
-                or any(k not in _LAYER_KINDS for k in kinds)):
-            raise ValueError(f"layer_types={kinds!r}: one of {_LAYER_KINDS} "
-                             f"for each of {self.num_hidden_layers} layers")
-        object.__setattr__(self, "layer_types", kinds)
+        _hold_layer_types(self, _LAYER_KINDS, tuple(
+            _LAYER_KINDS[(i + 1) % 4 == 0]
+            for i in range(self.num_hidden_layers)))
         _whole_attention_blocks(self)
 
     def layer_window(self, i: int) -> Optional[int]:
         """Layer `i`'s window, None for a full layer."""
         return (self.sliding_window
                 if self.layer_types[i] == "sliding_attention" else None)
+
+    def mixer(self, i: int) -> str:
+        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
+        return "gqa"
+
+    # what `GatedAttention` does beside the grouped-query attention
+    attention_gate = True
+    rope_full_layers = False
+    route_eps = ROUTE_EPS
 
     # ---- LMConfig's names for what the shared modules read ----
     n_routed_experts = property(lambda self: self.num_experts)
@@ -489,6 +479,10 @@ class EvaByteConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
 
+    def mixer(self, i: int) -> str:
+        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
+        return "eva"
+
     # ---- LMConfig's names for what the shared modules read ----
     qk_head_dim = property(lambda self: self.head_dim)
     v_head_dim = property(lambda self: self.head_dim)
@@ -496,6 +490,99 @@ class EvaByteConfig:
     n_routed_experts = property(lambda self: 0)
     experts_held = property(lambda self: (0, 0))
     moe_intermediate_size = property(lambda self: 0)
+
+
+# `layer_types` of LFM2-8B-A1B as published: 18 convolution layers and 6
+# attention layers
+_LFM2_LAYER_KINDS = ("conv", "full_attention")
+_LFM2_LAYER_TYPES = tuple(
+    _LFM2_LAYER_KINDS[i in (2, 6, 10, 14, 18, 21)] for i in range(24))
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """An LFM2 mixture-of-experts decoder (`model_type: lfm2_moe`): a
+    layer's mixer is a doubly gated short causal convolution (`conv`,
+    ops/lm_conv.py) or grouped-query attention with QK-norm and a rotary
+    embedding (`full_attention`), picked by `layer_types`; two pre-norms
+    a layer; leading dense layers, then sigmoid-routed expert layers
+    without a shared expert; the head is the embedding (models/lm/,
+    docs/lm.md). Keys and defaults are LFM2-8B-A1B's published
+    `config.json`.
+
+    The share is `AfmoeConfig`'s (query heads, the key/value heads they
+    read, routed experts, vocabulary rows). The convolution mixer has no
+    heads: its channels are the hidden width, and it is whole on every
+    chip. The properties at the end answer `LMConfig`'s names and say
+    what the shared modules do for this architecture.
+    """
+
+    vocab_size: int = 65_536
+    hidden_size: int = 2048
+    num_hidden_layers: int = 24
+    num_dense_layers: int = 2
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    conv_L_cache: int = 3
+    # one of _LFM2_LAYER_KINDS a layer; None: the published 24
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    # assumed (the catalog row does not give it): `initializer_range`
+    init_std: float = 0.02
+    seq_len: int = 32_768
+    heads_held: Optional[Tuple[int, int]] = None
+    kv_heads_held: Optional[Tuple[int, int]] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    mixed_precision: bool = False
+    remat: bool = False
+    attn_block: int = 1024
+    moe_chunk: Optional[int] = None
+
+    def __post_init__(self):
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError(f"hidden_size {self.hidden_size} does not "
+                             f"divide over {self.num_attention_heads} heads")
+        if self.conv_L_cache < 1:
+            raise ValueError("conv_L_cache counts the taps from 1")
+        _hold_grouped_heads(self)
+        _hold(self, "experts_held", self.num_experts)
+        # the published pattern has no period: another depth names its own
+        _hold_layer_types(self, _LFM2_LAYER_KINDS, _LFM2_LAYER_TYPES)
+        _whole_attention_blocks(self)
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """An attention layer sees the whole document."""
+        return None
+
+    def mixer(self, i: int) -> str:
+        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
+        return "conv" if self.layer_types[i] == "conv" else "gqa"
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    # what `GatedAttention` does beside the grouped-query attention
+    attention_gate = False
+    rope_full_layers = True
+    # logits = E . norm(x): no `head` parameter
+    tie_embedding = True
+    route_eps = 1e-6
+
+    # ---- LMConfig's names for what the shared modules read ----
+    n_routed_experts = property(lambda self: self.num_experts)
+    n_shared_experts = property(lambda self: 0)
+    first_k_dense_replace = property(lambda self: self.num_dense_layers)
+    rms_norm_eps = property(lambda self: self.norm_eps)
+    qk_head_dim = property(lambda self: self.head_dim)
+    v_head_dim = property(lambda self: self.head_dim)
 
 
 def _hold(cfg, name: str, whole: int) -> None:
@@ -509,6 +596,48 @@ def _hold(cfg, name: str, whole: int) -> None:
         raise ValueError(f"{name}={held!r} is not a range of "
                          f"the {whole} the model has")
     object.__setattr__(cfg, name, (first, count))
+
+
+def _hold_layer_types(cfg, allowed, published) -> None:
+    """`layer_types` as a tuple of one of `allowed` a held layer; None:
+    `published`."""
+    kinds = tuple(published if cfg.layer_types is None else cfg.layer_types)
+    if (len(kinds) != cfg.num_hidden_layers
+            or any(k not in allowed for k in kinds)):
+        raise ValueError(f"layer_types={kinds!r}: one of {allowed} "
+                         f"for each of {cfg.num_hidden_layers} layers")
+    object.__setattr__(cfg, "layer_types", kinds)
+
+
+def _hold_grouped_heads(cfg) -> None:
+    """`heads_held` of `num_attention_heads` query heads and the
+    `kv_heads_held` of `num_key_value_heads` they read (default: exactly
+    those)."""
+    heads, kv = cfg.num_attention_heads, cfg.num_key_value_heads
+    if heads % kv:
+        raise ValueError(f"{heads} query heads do not divide over "
+                         f"{kv} key/value heads")
+    group = heads // kv
+    _hold(cfg, "heads_held", heads)
+    first, count = cfg.heads_held
+    if cfg.kv_heads_held is None:
+        lo, hi = first // group, (first + count - 1) // group
+        object.__setattr__(cfg, "kv_heads_held", (lo, hi - lo + 1))
+    _hold(cfg, "kv_heads_held", kv)
+    kv_first, kv_count = cfg.kv_heads_held
+    # the op's rule, in local numbers: held query head i reads held
+    # key/value head i // (count // kv_count). True of query heads
+    # inside one key/value head, and of whole groups from a group's
+    # first head
+    per = max(count // kv_count, 1)
+    if count % kv_count or any(
+            (first + i) // group - kv_first != i // per
+            for i in range(count)):
+        raise ValueError(
+            f"heads_held={cfg.heads_held} with kv_heads_held="
+            f"{cfg.kv_heads_held}: the query heads a chip holds "
+            f"divide evenly, in order, over the key/value heads it "
+            f"holds ({group} query heads read one key/value head)")
 
 
 def _whole_attention_blocks(cfg) -> None:
@@ -560,16 +689,41 @@ def evabyte_toy(**kw) -> EvaByteConfig:
     return EvaByteConfig(**{**base, **kw})
 
 
+def lfm2_8b_a1b(**kw) -> Lfm2MoeConfig:
+    """LFM2-8B-A1B as published; `heads_held`, `kv_heads_held`,
+    `experts_held`, `vocab_size`, `num_hidden_layers`, `num_dense_layers`
+    and `layer_types` cut it to a chip's share
+    (benchmarks/configs/lfm2-8b-a1b-share4.json)."""
+    return Lfm2MoeConfig(**kw)
+
+
+def lfm2_8b_a1b_toy(**kw) -> Lfm2MoeConfig:
+    """The CPU tests' size: every mechanism, toy widths. 8 query heads
+    of 8 over 2 key/value heads (4 query heads read one), 16 experts; a
+    dense convolution layer, then one period of expert layers, the
+    attention layer first."""
+    base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=5,
+                num_dense_layers=1, intermediate_size=96,
+                moe_intermediate_size=32, num_experts=16,
+                num_experts_per_tok=2, num_attention_heads=8,
+                num_key_value_heads=2,
+                layer_types=("conv", "full_attention") + ("conv",) * 3,
+                seq_len=128, attn_block=32, moe_chunk=64)
+    return Lfm2MoeConfig(**{**base, **kw})
+
+
 # the configurations of models/lm: what `family_of` and `train` take for a
 # language model
-LM_CONFIGS = (LMConfig, AfmoeConfig, EvaByteConfig)
+LM_CONFIGS = (LMConfig, AfmoeConfig, EvaByteConfig, Lfm2MoeConfig)
 
 # language models `train --variant` takes beside VARIANTS. Not in
 # VARIANTS: eval, serve and video have no path for them (ROADMAP.md).
 LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
                "trinity-mini": trinity_mini,
                "trinity-mini-toy": trinity_mini_toy,
-               "evabyte": evabyte, "evabyte-toy": evabyte_toy}
+               "evabyte": evabyte, "evabyte-toy": evabyte_toy,
+               "lfm2-8b-a1b": lfm2_8b_a1b,
+               "lfm2-8b-a1b-toy": lfm2_8b_a1b_toy}
 
 
 @dataclasses.dataclass(frozen=True)

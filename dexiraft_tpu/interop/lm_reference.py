@@ -1,7 +1,7 @@
 """The plain reference of the language models: forward, loss and gradients
 in `jax.numpy`, float32, under `jax.default_matmul_precision("highest")`.
 
-Three architectures, picked by the configuration's type. An `LMConfig`:
+Four architectures, picked by the configuration's type. An `LMConfig`:
 written from the published `config.json` of
 kanana-2-30b-a3b-instruct-2601 (`model_type: deepseek_v3`). An
 `AfmoeConfig`: from Trinity-Mini's (`model_type: afmoe`) and, for what
@@ -13,7 +13,13 @@ scale), from the model's published modelling code (`transformers`
 and the layer as docs/lm.md writes it down (`eva_attention` below: one
 head at a time, the pooling as a dense `[chunks, positions]` matrix,
 one dense row of scores over every key and every summary with both
-masks written out). Independent of models/lm: no Flax module, no kernel, no
+masks written out). An `Lfm2MoeConfig`: from LFM2-8B-A1B's
+(`model_type: lfm2_moe`) and the layer as docs/lm.md writes it down
+(`short_conv` below: the taps as explicit shifted sums, each source
+position looked up with its document id beside it; `lfm2_layer`: the
+mixer of a layer a convolution or `gated_attention` without its gate
+and with the rotary embedding; the head is the embedding's transpose).
+Independent of models/lm: no Flax module, no kernel, no
 table, no sorting, no recomputation. Every held expert is applied to
 every token and weighted by `w_i` where the router chose it and by 0
 elsewhere; attention makes the dense `[heads, query rows, all keys]`
@@ -33,9 +39,9 @@ Departures from the published model, each also in docs/lm.md:
     left out, here as in the system. The vocabulary slice is a smaller
     vocabulary: the embedding and the head have the rows that are held
     and the loss is over them.
-  * afmoe's `expert_bias` is the same buffer under another name, held
-    at 0 alike; its `load_balance_coeff` belongs to the update rule that
-    is not run.
+  * afmoe's and lfm2_moe's `expert_bias` is the same buffer under
+    another name, held at 0 alike; afmoe's `load_balance_coeff` belongs
+    to the update rule that is not run.
 
 `blocked_loss_and_grads` is the same mathematics walked a sequence and
 a layer at a time (`jax.vjp` of one layer, inputs kept, layers revisited
@@ -60,7 +66,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dexiraft_tpu.config import AfmoeConfig, EvaByteConfig
+from dexiraft_tpu.config import AfmoeConfig, EvaByteConfig, Lfm2MoeConfig
 
 Share = Optional[Tuple[int, int]]
 
@@ -145,17 +151,19 @@ def attention(p, x, positions, segment_ids, cfg, heads: int):
 
 def gated_attention(p, x, positions, segment_ids, cfg, heads: int,
                     kv_heads: int, window: Optional[int],
-                    block: Optional[int] = None):
+                    block: Optional[int] = None, gate: bool = True,
+                    rope: Optional[bool] = None):
     """One sequence of afmoe's mixer: x [S, D]. `p` holds `heads` query
     heads' columns and the `kv_heads` key/value heads they read, each
     serving `heads // kv_heads` of them in order. `window` None: a full
-    layer, without a positional embedding."""
+    layer, without a positional embedding unless `rope` says otherwise
+    (lfm2_moe's attention layers: the rotary embedding, and no `gate`)."""
     hd, eps = cfg.head_dim, cfg.rms_norm_eps
     s = x.shape[0]
     q = _rms_norm((x @ p["wq"]).reshape(s, heads, hd), p["q_norm"], eps)
     k = _rms_norm((x @ p["wk"]).reshape(s, kv_heads, hd), p["k_norm"], eps)
     v = (x @ p["wv"]).reshape(s, kv_heads, hd)
-    if window is not None:
+    if (window is not None) if rope is None else rope:
         q = _rope_half(q, positions, cfg.rope_theta)
         k = _rope_half(k, positions, cfg.rope_theta)
     k, v = (jnp.repeat(t, heads // kv_heads, axis=1) for t in (k, v))
@@ -172,7 +180,32 @@ def gated_attention(p, x, positions, segment_ids, cfg, heads: int,
         return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, axis=-1), v)
 
     out = _by_blocks(rows, block, q, t, segment_ids).reshape(s, heads * hd)
-    return (out * jax.nn.sigmoid(x @ p["wg"])) @ p["wo"]
+    if gate:
+        out = out * jax.nn.sigmoid(x @ p["wg"])
+    return out @ p["wo"]
+
+
+def short_conv(p, x, segment_ids, cfg):
+    """One sequence of lfm2_moe's convolution mixer: x [S, D].
+
+        [B; C; z] = W_in x;   a_n = B_n * z_n
+        c_n = sum_j k[:, j] a_m * [m >= 0 and d(m) = d(n)],  m = n-(L-1)+j
+        out = W_out (C * c)
+
+    Each tap looks its source position up (`a[m]`, `d[m]`), so a tap
+    that would cross a document's first token reads zero as one before
+    position 0 does: the document's outputs are what it gives alone."""
+    s, taps = x.shape[0], cfg.conv_L_cache
+    b, c, z = jnp.split(x @ p["w_in"], 3, axis=-1)
+    a = b * z
+    n = jnp.arange(s)
+    total = jnp.zeros_like(a)
+    for j in range(taps):
+        m = n - (taps - 1) + j
+        inside = (m >= 0) & (segment_ids[jnp.maximum(m, 0)] == segment_ids)
+        total = total + p["taps"][:, j] * jnp.where(
+            inside[:, None], a[jnp.maximum(m, 0)], 0.0)
+    return (c * total) @ p["w_out"]
 
 
 def eva_attention(p, x, positions, segment_ids, cfg, heads: int,
@@ -255,16 +288,18 @@ def routing(p, x, cfg, bias=None):
     _, chosen = jax.lax.top_k(for_choice, cfg.num_experts_per_tok)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.route_eps)
     return chosen, w * cfg.routed_scaling_factor
 
 
 def moe(p, x, cfg, experts_held: Tuple[int, int], bias=None):
     """One sequence: the held experts' part of the routed sum, plus the
-    shared experts. Expert `first + j` has row j of `p["experts"]`."""
+    shared experts where the model has them. Expert `first + j` has row j
+    of `p["experts"]`."""
     first, count = experts_held
     chosen, w = routing(p["experts"], x, cfg, bias)
-    out = _swiglu(x, p["shared"])
+    out = (_swiglu(x, p["shared"]) if cfg.n_shared_experts
+           else jnp.zeros_like(x))
     for j in range(count):
         w_j = jnp.sum(jnp.where(chosen == first + j, w, 0.0), axis=-1)
         expert = {k: p["experts"][k][j] for k in ("w_gate", "w_up", "w_down")}
@@ -309,8 +344,37 @@ def afmoe_layer(p, x, positions, segment_ids, cfg, index: int,
     return h + _rms_norm(f, p["ffn_post_norm"], eps)
 
 
+def lfm2_layer(p, x, positions, segment_ids, cfg, index: int,
+               heads_held: Share = None, kv_heads_held: Share = None,
+               experts_held: Share = None, bias=None,
+               block: Optional[int] = None):
+    """h = x + Mixer_l(N1(x)); x' = h + FFN(N2(h)); one sequence, layer
+    `index` of the layers held: a convolution (whole whatever the share)
+    or an attention over the heads held."""
+    eps = cfg.norm_eps
+    u = _rms_norm(x, p["attn_norm"], eps)
+    if cfg.layer_types[index] == "conv":
+        h = x + short_conv(p["conv"], u, segment_ids, cfg)
+    else:
+        h = x + gated_attention(
+            p["attn"], u, positions, segment_ids, cfg,
+            (heads_held or cfg.heads_held)[1],
+            (kv_heads_held or cfg.kv_heads_held)[1], None, block,
+            gate=False, rope=True)
+    normed = _rms_norm(h, p["ffn_norm"], eps)
+    if index < cfg.num_dense_layers:
+        return h + _by_blocks(lambda rows: _swiglu(rows, p["mlp"]), block,
+                              normed)
+    return h + _by_blocks(lambda rows: moe(p["moe"], rows, cfg,
+                                           experts_held or cfg.experts_held,
+                                           bias), block, normed)
+
+
 def _layer_of(cfg, i: int, block: Optional[int] = None, **share):
     """Layer `i` as `f(p, x, positions, segment_ids)`."""
+    if isinstance(cfg, Lfm2MoeConfig):
+        return lambda p, x, pos, seg: lfm2_layer(p, x, pos, seg, cfg, i,
+                                                 block=block, **share)
     if isinstance(cfg, EvaByteConfig):
         return lambda p, x, pos, seg: eva_layer(p, x, pos, seg, cfg,
                                                 block=block, **share)
@@ -322,9 +386,11 @@ def _layer_of(cfg, i: int, block: Optional[int] = None, **share):
 
 
 def _kind(cfg, i: int):
-    """What tells layer `i`'s program from another layer's."""
-    window = (cfg.layer_window(i) if isinstance(cfg, AfmoeConfig) else None)
-    return _is_dense(cfg, i), window
+    """What tells layer `i`'s program from another layer's: dense or
+    sparse, and its entry of `layer_types` where the configuration has
+    them (a window or none; a convolution or an attention)."""
+    kinds = getattr(cfg, "layer_types", None)
+    return _is_dense(cfg, i), kinds[i] if kinds else None
 
 
 def _embed_scale(cfg, dtype):
@@ -336,6 +402,12 @@ def _embed_scale(cfg, dtype):
 def _pred_heads(cfg) -> int:
     """Tokens a position predicts at once: the head's vocabularies."""
     return getattr(cfg, "num_pred_heads", 1)
+
+
+def _head(p, cfg):
+    """The head's matrix [D, vocab]: under `tie_embedding` the
+    embedding's own rows."""
+    return p["embed"].T if getattr(cfg, "tie_embedding", False) else p["head"]
 
 
 def _gain(g, cfg):
@@ -365,7 +437,7 @@ def head_loss_sum(p, x, tokens, segment_ids, cfg,
 
     def rows(x_rows, targets, valid):
         logits = (_rms_norm(x_rows, _gain(p["final_norm"], cfg), cfg.rms_norm_eps)
-                  @ p["head"]).reshape(x_rows.shape[0], ahead, -1)
+                  @ _head(p, cfg)).reshape(x_rows.shape[0], ahead, -1)
         logp = jax.nn.log_softmax(logits, axis=-1)
         picked = jnp.take_along_axis(logp, targets[..., None],
                                      axis=-1)[..., 0]
@@ -401,7 +473,7 @@ def logits(params, batch: Dict[str, Any], cfg, dtype=jnp.float32, **share):
                               batch["positions"][b], batch["segment_ids"][b],
                               cfg, **share)
             rows.append(_rms_norm(x, _gain(params["final_norm"], cfg),
-                                  cfg.rms_norm_eps) @ params["head"])
+                                  cfg.rms_norm_eps) @ _head(params, cfg))
         return jnp.stack(rows)
 
 
@@ -461,7 +533,10 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
             for k in set(kinds)}
     fwd = {k: jit_as("reference_layer", run) for k, run in runs.items()}
     bwd = {k: vjp_of(run) for k, run in runs.items()}
-    top = {k: params[k] for k in ("final_norm", "head")}
+    # a tied head is the embedding: its gradient from the loss joins the
+    # gather's below, under the one name
+    tied = getattr(cfg, "tie_embedding", False)
+    top = {k: params[k] for k in ("final_norm", "embed" if tied else "head")}
     head = jit_as("reference_head_loss_and_grad",
                   jax.value_and_grad(head_loss, argnums=(0, 1)))
     embed_grad = jit_as("reference_embed_grad", lambda tok, dx: jnp.zeros_like(
@@ -513,7 +588,7 @@ def take_share(params, cfg, heads_held: Tuple[int, int],
         hd = cfg.head_dim
         cut = {"wq": (hd, 1), "wk": (hd, 1), "wv": (hd, 1), "wo": (hd, 0),
                "phi": (1, 0), "mu_k": (1, 0)}
-    elif isinstance(cfg, AfmoeConfig):
+    elif hasattr(cfg, "kv_heads_held"):
         hd = cfg.head_dim
         cut = {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
                "wk": (hd, 1, kv_heads_held), "wv": (hd, 1, kv_heads_held)}
@@ -525,8 +600,10 @@ def take_share(params, cfg, heads_held: Tuple[int, int],
     out = dict(params)
     for i in range(cfg.num_hidden_layers):
         lp = dict(params[f"layers_{i}"])
-        lp["attn"] = dict(lp["attn"], **{
-            k: heads(lp["attn"][k], *how) for k, how in cut.items()})
+        if "attn" in lp:  # a convolution mixer is whole on every chip
+            lp["attn"] = dict(lp["attn"], **{
+                k: heads(lp["attn"][k], *how) for k, how in cut.items()
+                if k in lp["attn"]})
         if "moe" in lp:
             e0, en = experts_held
             experts = dict(lp["moe"]["experts"])

@@ -1,5 +1,5 @@
-"""The three mixers, for the heads this chip holds: `mixer_of` picks by
-the configuration's type.
+"""The four mixers, for the heads this chip holds: `mixer_of` picks by
+the configuration's `mixer(layer)`.
 
 **`LatentAttention`** (`LMConfig`): multi-head latent attention without a
 query LoRA (`q_lora_rank: null`).
@@ -30,6 +30,20 @@ and `W_v` the columns of the key/value heads those read
 (`cfg.kv_heads_held`): a key/value head serves several query heads, so
 the chips that hold its query heads each hold a copy of it.
 
+An `Lfm2MoeConfig`'s `full_attention` layer is the same module with what
+its configuration says: no gate and no `W_g` (`attention_gate`), the
+rotary embedding on full layers too (`rope_full_layers`).
+
+**`ShortConv`** (`Lfm2MoeConfig`, `conv` layers): the doubly gated short
+causal convolution (ops/lm_conv.py has the equations).
+
+    [B; C; z] = W_in x           in this order
+    out = W_out (C * conv_L(B * z))    depthwise, causal, within documents
+
+It has no heads: the channels are the hidden width, and the module is
+whole on every chip (in a deployment a tensor-parallel chip would hold
+its share of the channels).
+
 **`EvaAttention`** (`EvaByteConfig`): EVA chunked linear attention
 (ops/lm_eva.py has the equations).
 
@@ -49,12 +63,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from typing import Optional
+from typing import Any, Optional
 
-from dexiraft_tpu.config import AfmoeConfig, EvaByteConfig, LMConfig
+from dexiraft_tpu.config import EvaByteConfig, LMConfig
 from dexiraft_tpu.models.lm.layers import (Weights, rms_norm, rope_half,
                                            rope_interleaved)
 from dexiraft_tpu.ops.lm_attention import document_attention
+from dexiraft_tpu.ops.lm_conv import gated_short_conv
 from dexiraft_tpu.ops.lm_eva import eva_attention
 
 
@@ -99,7 +114,7 @@ class LatentAttention(Weights):
 
 
 class GatedAttention(Weights):
-    cfg: AfmoeConfig = None
+    cfg: Any = None  # an AfmoeConfig or an Lfm2MoeConfig
     window: Optional[int] = None  # None: a full layer
 
     @nn.compact
@@ -116,19 +131,39 @@ class GatedAttention(Weights):
                  ).reshape(b, s, kv_heads, hd)
             v = (x @ self.w("wv", (d, kv_heads * hd))
                  ).reshape(b, s, kv_heads, hd)
-            gate = x @ self.w("wg", (d, heads * hd))
+            if cfg.attention_gate:
+                gate = x @ self.w("wg", (d, heads * hd))
             q, k = (rms_norm(t, self.param(name, nn.initializers.ones, (hd,),
                                            jnp.float32), cfg.rms_norm_eps)
                     for t, name in ((q, "q_norm"), (k, "k_norm")))
-            if self.window is not None:
+            if self.window is not None or cfg.rope_full_layers:
                 q = rope_half(q, positions, cfg.rope_theta)
                 k = rope_half(k, positions, cfg.rope_theta)
         with jax.named_scope(f"lm/gqa/{kind}/kernel"):
             out = document_attention(q, k, v, segment_ids, scale=hd ** -0.5,
                                      block=cfg.attn_block, window=self.window)
         with jax.named_scope("lm/gqa/proj"):
-            gated = out.reshape(b, s, heads * hd) * jax.nn.sigmoid(gate)
-            return gated @ self.w("wo", (heads * hd, d))
+            out = out.reshape(b, s, heads * hd)
+            if cfg.attention_gate:
+                out = out * jax.nn.sigmoid(gate)
+            return out @ self.w("wo", (heads * hd, d))
+
+
+class ShortConv(Weights):
+    cfg: Any = None  # an Lfm2MoeConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, positions: jax.Array,
+                 segment_ids: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        with jax.named_scope("lm/conv/in"):
+            b, c, z = jnp.split(x @ self.w("w_in", (d, 3 * d)), 3, axis=-1)
+        with jax.named_scope("lm/conv/gate"):
+            y = gated_short_conv(
+                b, c, z, self.w("taps", (d, self.cfg.conv_L_cache)),
+                segment_ids)
+        with jax.named_scope("lm/conv/out"):
+            return y @ self.w("w_out", (d, d))
 
 
 class EvaAttention(Weights):
@@ -156,10 +191,14 @@ class EvaAttention(Weights):
 
 
 def mixer_of(cfg, layer: int, **kw) -> nn.Module:
-    """Layer `layer`'s attention module (named `attn`)."""
-    if isinstance(cfg, EvaByteConfig):
-        return EvaAttention(cfg=cfg, name="attn", **kw)
-    if isinstance(cfg, AfmoeConfig):
+    """Layer `layer`'s mixer: an attention module (named `attn`) or the
+    convolution (named `conv`)."""
+    kind = cfg.mixer(layer)
+    if kind == "conv":
+        return ShortConv(cfg=cfg, name="conv", **kw)
+    if kind == "gqa":
         return GatedAttention(cfg=cfg, window=cfg.layer_window(layer),
                               name="attn", **kw)
+    if kind == "eva":
+        return EvaAttention(cfg=cfg, name="attn", **kw)
     return LatentAttention(cfg=cfg, name="attn", **kw)

@@ -8,16 +8,22 @@
     EvaByteConfig:  x0 = E[byte];  LMConfig's layer with every FFN dense,
                     the norms' gains `1 + g` (norm_add_unit_offset) and
                     the two sums in fp32 (fp32_skip_add)
+    Lfm2MoeConfig:  x0 = E[id];  LMConfig's layer, the mixer of layer l
+                    a short convolution or an attention (`layer_types`)
     logits = W_head RMSNorm(x_last)           (untied; an EvaByteConfig's
                                                head has `num_pred_heads`
-                                               vocabularies of columns)
+                                               vocabularies of columns;
+                                               under `tie_embedding`
+                                               W_head is E's transpose and
+                                               no parameter of its own)
 
 `Attn` is the configuration's mixer (models/lm/attention.py `mixer_of`;
 an `AfmoeConfig`'s `layer_types` make layer `l`'s a sliding-window or a
-full one). FFN is a SwiGLU of `intermediate_size` in the first
-`first_k_dense_replace` layers and the expert layer after them. Under
-`cfg.remat` every layer is a `jax.checkpoint` that keeps nothing: the
-backward holds one layer's activations at a time.
+full one, an `Lfm2MoeConfig`'s a convolution or an attention). FFN is a
+SwiGLU of `intermediate_size` in the first `first_k_dense_replace`
+layers and the expert layer after them. Under `cfg.remat` every layer is
+a `jax.checkpoint` that keeps nothing: the backward holds one layer's
+activations at a time.
 
 The loss is the mean cross-entropy over next-token targets that lie in
 the same document as their input (a packed row holds several; pad has
@@ -43,17 +49,20 @@ from dexiraft_tpu.models.lm.attention import mixer_of
 from dexiraft_tpu.models.lm.layers import SwiGLU, Weights, rms_norm
 from dexiraft_tpu.models.lm.moe import MoE
 from dexiraft_tpu.ops.lm_attention import block_pair_counts, kernel_blocks
+from dexiraft_tpu.ops.lm_conv import taps_masked
 from dexiraft_tpu.ops.lm_eva import local_ids, pair_counts
 
 # an `LMConfig`'s layers are of one kind (`attn_block_pairs_visited`, of a
 # layer); an `AfmoeConfig`'s are of two, each summed over its layers; an
 # `EvaByteConfig`'s are summed over its layers too, and carry the pairs
-# the batch needs beside the block pairs the kernel visits
+# the batch needs beside the block pairs the kernel visits; an
+# `Lfm2MoeConfig`'s attention layers are full ones, and its convolution
+# layers count the taps their mask zeroes
 COUNTERS = ("moe_slots_held", "moe_load_max", "moe_load_mean",
             "moe_dropped_slots", "attn_block_pairs_visited",
             "attn_block_pairs_visited_window", "attn_block_pairs_visited_full",
             "attn_block_pairs_visited_local", "attn_block_pairs_causal",
-            "eva_pairs_local", "eva_pairs_remote")
+            "eva_pairs_local", "eva_pairs_remote", "conv_taps_masked")
 # positions a block of `head_loss`; a row that is not whole blocks is one
 HEAD_BLOCK = 8192
 
@@ -141,11 +150,14 @@ class LM(nn.Module):
         with jax.named_scope("lm/norm"):
             x = rms_norm(x, _gain(self, cfg, "final_norm", cfg.hidden_size),
                          cfg.rms_norm_eps)
-        head = self.param(
-            "head", nn.initializers.normal(cfg.init_std),
-            (cfg.hidden_size,
-             cfg.vocab_size * getattr(cfg, "num_pred_heads", 1)),
-            jnp.float32).astype(dtype)
+        if getattr(cfg, "tie_embedding", False):
+            head = embed.astype(dtype).T
+        else:
+            head = self.param(
+                "head", nn.initializers.normal(cfg.init_std),
+                (cfg.hidden_size,
+                 cfg.vocab_size * getattr(cfg, "num_pred_heads", 1)),
+                jnp.float32).astype(dtype)
         counters = dict(_reduce_counters(per_layer),
                         **_attention_counters(cfg, segment_ids))
         if logits:
@@ -174,8 +186,11 @@ def _attention_counters(cfg, segment_ids: jax.Array) -> Dict[str, jax.Array]:
     """The block pairs the attention kernel's grid computes for this
     batch and those of a layer's causal triangle, from the table the
     kernel is handed (every layer sees the same documents). An
-    `LMConfig`: of a layer. An `AfmoeConfig`: by the layers' kind, each
-    summed over the layers of the kind. An `EvaByteConfig`: the exact
+    `LMConfig`: of a layer. A configuration with `layer_types`: by the
+    layers' kind, each summed over the layers of the kind (the window
+    kind where the configuration has a `sliding_window`; the taps the
+    convolution layers' mask zeroes where it has such layers). An
+    `EvaByteConfig`: the exact
     part's, on the ids that separate document and window, summed over
     the layers, beside the (query, key) and (query, summary) pairs the
     batch needs, exactly. Where the kernel does not take the shapes, the
@@ -194,15 +209,23 @@ def _attention_counters(cfg, segment_ids: jax.Array) -> Dict[str, jax.Array]:
                 "eva_pairs_local": local * layers,
                 "eva_pairs_remote": remote * layers}
     visited, causal = block_pair_counts(segment_ids, *blocks)
-    if not isinstance(cfg, AfmoeConfig):
+    kinds = getattr(cfg, "layer_types", None)
+    if kinds is None:
         return {"attn_block_pairs_visited": visited,
                 "attn_block_pairs_causal": causal}
-    sliding = sum(k == "sliding_attention" for k in cfg.layer_types)
-    windowed, _ = block_pair_counts(segment_ids, *blocks, cfg.sliding_window)
-    return {"attn_block_pairs_visited_window": windowed * sliding,
-            "attn_block_pairs_visited_full":
-                visited * (len(cfg.layer_types) - sliding),
-            "attn_block_pairs_causal": causal}
+    out = {}
+    if hasattr(cfg, "sliding_window"):
+        windowed, _ = block_pair_counts(segment_ids, *blocks,
+                                        cfg.sliding_window)
+        out["attn_block_pairs_visited_window"] = (
+            windowed * kinds.count("sliding_attention"))
+    out.update(attn_block_pairs_visited_full=(
+        visited * kinds.count("full_attention")),
+        attn_block_pairs_causal=causal)
+    if "conv" in kinds:
+        out["conv_taps_masked"] = (taps_masked(segment_ids, cfg.conv_L_cache)
+                                   * kinds.count("conv"))
+    return out
 
 
 def next_token_targets(tokens: jax.Array, segment_ids: jax.Array,

@@ -3,12 +3,15 @@
 Routing is the whole model's (`topk_method: noaux_tc`, one group):
 `s = sigmoid(W_r x)` in fp32 over all `n_routed_experts`, the top
 `num_experts_per_tok` of `s + b`, weights `s` of the chosen (without
-`b`) normalised to sum 1 and scaled by `routed_scaling_factor`. This
-chip then computes `sum_i w_i Expert_i(x)` over the chosen experts it
-holds (`cfg.experts_held`), plus the shared experts, which every chip of
-the group computes alike. What the absent experts would add is left
-out: in a deployment it arrives with the expert-parallel sum. No code
-stands in for that exchange.
+`b`) normalised to sum 1 (over their sum and the configuration's
+`route_eps`) and scaled by
+`routed_scaling_factor`. This chip then computes `sum_i w_i Expert_i(x)`
+over the chosen experts it holds (`cfg.experts_held`), plus the shared
+experts, which every chip of the group computes alike (a configuration
+with `n_shared_experts == 0` has none, and nothing is built for them).
+What the absent experts would add is left out: in a deployment it
+arrives with the expert-parallel sum. No code stands in for that
+exchange.
 
 Dropless, in one program shape. A (token, choice) pair is a slot;
 `T * top_k` slots exist and any number of them, up to all, may fall on
@@ -50,13 +53,13 @@ from dexiraft_tpu.ops.grouped import grouped_matmul
 
 
 def route(scores: jax.Array, bias: jax.Array, top_k: int, scale: float,
-          normalise: bool) -> Tuple[jax.Array, jax.Array]:
+          normalise: bool, eps: float) -> Tuple[jax.Array, jax.Array]:
     """(expert ids `[T, k]`, weights `[T, k]` fp32) from sigmoid scores
     `[T, E]` fp32."""
     _, chosen = jax.lax.top_k(scores + bias, top_k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalise:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return chosen, weights * scale
 
 
@@ -101,7 +104,7 @@ class RoutedExperts(Weights):
                 precision=jax.lax.Precision.HIGHEST))
             chosen, weights = route(scores, bias, top_k,
                                     cfg.routed_scaling_factor,
-                                    cfg.norm_topk_prob)
+                                    cfg.norm_topk_prob, cfg.route_eps)
 
         with jax.named_scope("lm/moe/dispatch"):
             local = chosen.reshape(-1) - first
@@ -200,6 +203,8 @@ class MoE(Weights):
         routed, counters = RoutedExperts(
             cfg=cfg, dtype=self.dtype, init_std=self.init_std,
             name="experts")(flat)
+        if not cfg.n_shared_experts:
+            return routed.reshape(x.shape), counters
         with jax.named_scope("lm/moe/shared"):
             shared = SwiGLU(
                 width=cfg.n_shared_experts * cfg.moe_intermediate_size,

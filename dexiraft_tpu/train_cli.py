@@ -18,6 +18,10 @@ one stage; presets supply the per-stage hyperparameters:
       --batch_size 1 --precision bf16 --remat
   python -m dexiraft_tpu train --variant evabyte --tokens bytes.npz \
       --layers 4 --heads_held 0 8 --batch_size 1 --precision bf16 --remat
+  python -m dexiraft_tpu train --variant lfm2-8b-a1b --tokens docs.npz \
+      --layers 5 --dense_layers 1 --layer_types conv full_attention conv \
+      conv conv --heads_held 0 8 --experts_held 0 8 --vocab_size 16384 \
+      --batch_size 1 --precision bf16 --remat
 
 The loop is the reference's (train.py:163-215) re-shaped for TPU: one
 jitted sharded step (forward + loss + backward + optimizer), batches
@@ -77,7 +81,7 @@ def build_parser() -> argparse.ArgumentParser:
                    default="none", help="stage hyperparameter preset")
     p.add_argument("--variant", default="v1",
                    choices=sorted(VARIANTS) + sorted(LM_VARIANTS),
-                   help="v1..v5: RAFT; kanana2, trinity-mini, evabyte: the "
+                   help="v1..v5: RAFT; kanana2, trinity-mini, evabyte, lfm2-8b-a1b: the "
                         "language models of models/lm (docs/lm.md), "
                         "trained on --tokens")
     # the language model's own flags (refused for the RAFT variants)
@@ -87,7 +91,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "into rows of --seq_len")
     p.add_argument("--seq_len", type=int, default=None,
                    help="language models: positions a row (default: "
-                        "kanana2 8192, trinity-mini and evabyte 32768)")
+                        "kanana2 8192, the others 32768)")
     p.add_argument("--layers", type=int, default=None,
                    help="language models: decoder layers held (default: "
                         "all)")
@@ -95,9 +99,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="language models: of them, the leading dense "
                         "layers (default: as published)")
     p.add_argument("--layer_types", nargs="+", default=None,
-                   choices=["sliding_attention", "full_attention"],
+                   choices=["sliding_attention", "full_attention", "conv"],
                    help="trinity-mini: each held layer's attention "
-                        "(default: three sliding, one full, repeated)")
+                        "(default: three sliding, one full, repeated); "
+                        "lfm2-8b-a1b: each held layer's mixer, conv or "
+                        "full_attention (default: the published 24)")
     p.add_argument("--vocab_size", type=int, default=None,
                    help="language models: rows of the vocabulary held")
     p.add_argument("--heads_held", type=int, nargs=2, default=None,
@@ -107,12 +113,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "all)")
     p.add_argument("--kv_heads_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="trinity-mini: the key/value heads this chip holds "
+                   help="trinity-mini, lfm2-8b-a1b: the key/value heads this "
+                        "chip holds "
                         "(default: those its query heads read)")
     p.add_argument("--experts_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="kanana2, trinity-mini: the routed experts this chip "
-                        "holds of an expert-parallel group (default: all)")
+                   help="kanana2, trinity-mini, lfm2-8b-a1b: the routed "
+                        "experts this chip holds of an expert-parallel "
+                        "group (default: all)")
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="allpairs",
@@ -394,7 +402,7 @@ def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
     if args.variant in LM_VARIANTS:
         return resolve_lm_configs(args)
     _refuse_given(args, _LM_ONLY, "belong(s) to the language models "
-                  "(--variant kanana2, trinity-mini, evabyte)")
+                  "(--variant kanana2, trinity-mini, evabyte, lfm2-8b-a1b)")
     if args.stage is None:
         raise SystemExit("train: --stage is required for --variant "
                          f"{args.variant}")

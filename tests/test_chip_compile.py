@@ -386,6 +386,75 @@ def test_evabyte_step_compiles_for_v5e_and_fits_the_chip(chip, monkeypatch):
             < 15.75 * 2 ** 30)
 
 
+# `lfm2-train-pack32k`: 1 row x 8 query heads over 2 key/value heads,
+# 32,768 positions, width 64: the narrowest heads the kernel runs, and
+# two whole groups of four a row
+LF_H, LF_KV, LF_S, LF_D = 8, 2, 32768, 64
+
+
+@pytest.mark.parametrize("which", ["forward", "dq", "dkv"])
+def test_lm_attention_kernel_at_heads_of_64_compiles_for_v5e(chip, which):
+    """The three kernels at the fourth cell's shapes: blocks whose last
+    dimension is the whole 64-wide head, a step of 4 query heads on one
+    K/V block, two steps a row."""
+    from dexiraft_tpu.ops import lm_attention as la
+
+    bq, bk = la.kernel_blocks(LF_S, LF_D, LF_D)
+    st = la._Static(LF_H, LF_D ** -0.5, bq, bk, False, LF_KV, None)
+    assert (st.hb, st.rep, st.hkv, st.heads // st.hb) == (4, 4, 1, 2)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    q, kv = sds((LF_H, LF_S, LF_D)), sds((LF_KV, LF_S, LF_D))
+    seg = sds((1, LF_S), jnp.int32)
+    table = lambda s: la.block_table(s, bq, bk)  # noqa: E731
+    if which == "forward":
+        text = _compiled_text(
+            lambda q, k, v, s: la._forward(st, q, k, v, s, table(s)),
+            q, kv, kv, seg)
+    else:
+        text = _compiled_text(
+            lambda q, k, v, s, o, lse, do: la._backward(
+                st, q, k, v, s, table(s), o, lse, do),
+            q, kv, kv, seg, q, sds((LF_H, LF_S), jnp.float32), q)
+    name = {"forward": "lm_attention_fwd", "dq": "lm_attention_dq",
+            "dkv": "lm_attention_dkv"}[which]
+    assert "tpu_custom_call" in text and name in text
+
+
+def test_lfm2_step_compiles_for_v5e_and_fits_the_chip(topo):
+    """The fourth language cell's whole train step at its real size (5
+    layers c f c c c, 8 of 32 experts and heads, 500 M parameters with a
+    tied head, one row of 32,768 positions, bf16, every layer recomputed)
+    as `benchmarks/compile_check.py` lowers it, the attention layer on
+    the kernel path it takes on the chip. Arguments (masters and AdamW's
+    moments) and temporaries fit 15.75 GB."""
+    import os.path as osp
+    import sys
+
+    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+    from benchmarks import harness
+
+    cell = harness.load_cell("lfm2-train-pack32k")
+    lowered = []
+    harness.load_runner("lm_train_packed").compile_for(
+        cell, topo, lambda label, program: lowered.append(program))
+    compiled = lowered[0].compile()  # the step; the check's program is
+    text = compiled.as_text()        # compile_check.py's to compile
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv"):
+        assert name in text
+    for scope in ("lm/conv/in", "lm/conv/gate", "lm/conv/out",
+                  "lm/gqa/full/kernel", "lm/moe/experts"):
+        assert scope in text, scope
+    assert "lm/moe/shared" not in text
+    memory = compiled.memory_analysis()
+    # fp32 masters and AdamW's two moments: 12 B a parameter
+    assert memory.argument_size_in_bytes > 12 * 499_955_840
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+
+
 # ---- the convex upsample (ops/upsample.py) --------------------------------
 
 def test_convex_upsample_is_lane_dense_for_v5e(chip):

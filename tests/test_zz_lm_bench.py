@@ -61,6 +61,17 @@ SHARED3 = ["lm_head_loss_device_ms", "lm_optimizer_device_ms",
            "lm_pack_fill_pct"]
 NEEDS_A_CHIP3 = (NEEDS_A_CHIP | set(NEW3)) - {
     "lm_eva_local_blocks_visited_pct"}
+# the fourth language cell (PR 38): two kinds of mixer, an expert layer
+# without a shared expert; what it shares with Trinity's (the attention
+# layer is a `full` one under `lm/gqa/`), with EvaByte's (`lm_mlp`), its
+# own four (the program's `conv_taps_masked` has no reader: the runner
+# does not carry it, PERF.md section 7)
+CELL4 = "lfm2-train-pack32k"
+NEW4 = ["lm_conv_device_ms", "lm_conv_gate_device_ms", "lm_conv_roofline_pct",
+        "lm_gqa_full_kernel_roofline_pct"]
+SHARED4 = SHARED2 + ["lm_gqa_device_ms", "lm_gqa_full_kernel_device_ms",
+                     "lm_mlp_device_ms"]
+NEEDS_A_CHIP4 = NEEDS_A_CHIP2 | set(NEW4) | {"lm_mlp_device_ms"}
 CELLS = {
     CELL: dict(config="kanana-2-30b-a3b-share8", traffic="train-pack8k",
                model="kanana-2-30b-a3b-instruct-2601", shares=8,
@@ -74,6 +85,10 @@ CELLS = {
                 model="EvaByte", shares=4, assumes="adaptive_mu_k",
                 reports=FED + SHARED3 + NEW3 + SETUP,
                 needs_a_chip=NEEDS_A_CHIP3),
+    CELL4: dict(config="lfm2-8b-a1b-share4", traffic="train-pack32k-docs2k",
+                model="LFM2-8B-A1B", shares=4, assumes="expert_bias",
+                reports=FED + SHARED4 + NEW4 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP4),
 }
 
 
@@ -115,7 +130,7 @@ def test_manifest_names_the_second_cell_and_what_it_reports(manifest):
         assert cells[at:at + 2] == [CELL, CELL2], name
     assert by_name["lm_attn_device_ms"]["workloads"] == [CELL]
     for name in NEW2:
-        assert by_name[name]["workloads"] == [CELL2]
+        assert by_name[name]["workloads"][0] == CELL2
         assert by_name[name]["moves"] == "train_samples_per_s"
         assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
@@ -125,17 +140,18 @@ def test_manifest_names_the_second_cell_and_what_it_reports(manifest):
 
 
 def test_manifest_names_the_third_cell_and_what_it_reports(manifest):
-    cell = manifest["workloads"][-1]  # entries are added at the end
+    cell = manifest["workloads"][7]  # entries are added at the end
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL3, "evabyte-6.5b-share4", "train-bytes32k", 1)
-    assert len(manifest["workloads"]) == 8 and len(cell["why"]) <= 200
-    assert manifest["configs"][-1]["name"] == "evabyte-6.5b-share4"
+    assert len(cell["why"]) <= 200
+    assert manifest["configs"][4]["name"] == "evabyte-6.5b-share4"
     by_name = {m["name"]: m
                for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + SHARED3 + ["train_samples_per_s"]:
-        assert by_name[name]["workloads"][-1] == CELL3, name
+        cells = by_name[name]["workloads"]
+        assert cells.index(CELL3) == len(cells) - 2, name  # CELL4 follows
     for name in NEW3:
-        assert by_name[name]["workloads"] == [CELL3]
+        assert by_name[name]["workloads"][0] == CELL3
         assert by_name[name]["moves"] == "train_samples_per_s"
         assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
@@ -145,6 +161,32 @@ def test_manifest_names_the_third_cell_and_what_it_reports(manifest):
     for name, m in by_name.items():  # a dense stack has no expert layer
         if name.startswith(("lm_moe_", "lm_gqa_", "lm_attn_")):
             assert CELL3 not in m["workloads"], name
+
+
+def test_manifest_names_the_fourth_cell_and_what_it_reports(manifest):
+    cell = manifest["workloads"][-1]  # entries are added at the end
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        CELL4, "lfm2-8b-a1b-share4", "train-pack32k-docs2k", 1)
+    assert len(manifest["workloads"]) == 9 and len(cell["why"]) <= 200
+    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
+    assert manifest["configs"][-1]["name"] == "lfm2-8b-a1b-share4"
+    by_name = {m["name"]: m
+               for m in manifest["per_layer"] + manifest["end_to_end"]}
+    for name in FED + SHARED4 + ["train_samples_per_s"]:
+        assert by_name[name]["workloads"][-1] == CELL4, name
+    for name in NEW4:
+        assert by_name[name]["workloads"] == [CELL4]
+        assert by_name[name]["moves"] == "train_samples_per_s"
+        assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
+        assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
+                                   name + ".py"))
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(NEW4):] == NEW4
+    # what reads a `window` kind, latent attention or EVA stays the others'
+    for name, m in by_name.items():
+        if (name.startswith(("lm_eva_", "lm_attn_", "lm_gqa_window_"))
+                or name == "lm_gqa_kernel_roofline_pct"):
+            assert CELL4 not in m["workloads"], name
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
@@ -207,6 +249,14 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report(
         assert counters["traced_pairs_local"] > 0
         assert counters["traced_pairs_remote"] > 0
         assert counters["attn_layers_local"] == 4
+    if cell == CELL4:  # one attention layer's table beside four mixers
+        assert counters["attn_block_pairs_visited_full"] > 0
+        assert "attn_block_pairs_visited_window" not in counters
+        assert (counters["attn_layers_conv"], counters["attn_layers_full"]
+                ) == (4, 1)
+        assert set(k for k in counters if k.startswith("traced_pairs_")
+                   ) == {"traced_pairs_full"}
+        assert counters["moe_slots_held"] > 0
 
 
 def test_the_second_cells_configuration_states_its_cut_and_builds():
@@ -291,6 +341,89 @@ def test_the_third_cells_configuration_states_its_cut_and_builds():
         "/".join(map(str, leaf)) for leaf in cell.traffic["check"]["leaves"]}
 
 
+def test_the_fourth_cells_configuration_states_its_cut_and_builds():
+    """`parameters_held` is the program's own count and the issue's
+    arithmetic, the share is the deployment's, the traffic is the
+    issue's letter for letter, and every read of the runner is met."""
+    import dataclasses
+
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+    from dexiraft_tpu.config import Lfm2MoeConfig, TrainConfig
+    from dexiraft_tpu.models.lm.moe import dispatch_chunk
+    from dexiraft_tpu.train.family import family_of
+    from dexiraft_tpu.train.state import param_count
+
+    cell = harness.load_cell(CELL4)
+    cfg, tc = harness.load_runner("lm_train_packed")._configs(cell, 0)
+    assert isinstance(cfg, Lfm2MoeConfig)
+    held = cell.config["parameters_held"]
+    params, _ = jax.eval_shape(family_of(cfg, TrainConfig()).init,
+                               jax.random.PRNGKey(0))
+    assert param_count(params) == held["total"] == 499_955_840
+    assert "head" not in params  # tied: one matrix
+    assert held["total"] == (
+        held["dense_layer"] + held["expert_layer_attention"]
+        + 3 * held["expert_layer_conv"] + held["embedding_and_final_norm"])
+    assert held["dense_layer"] == held["conv_mixer"] + 3 * 2048 * 7168 + 4096
+    assert held["expert_layer_conv"] == (
+        held["conv_mixer"] + held["experts_and_router_a_layer"] + 4096)
+    assert held["expert_layer_attention"] == (
+        held["attention_mixer"] + held["experts_and_router_a_layer"] + 4096)
+    assert held["state_bytes_at_16_a_parameter"] == 16 * held["total"]
+    assert (cfg.heads_held, cfg.kv_heads_held, cfg.experts_held) == (
+        (0, 8), (0, 2), (0, 8))
+    assert (cfg.num_experts, cfg.num_attention_heads,
+            cfg.num_key_value_heads, cfg.head_dim) == (32, 32, 8, 64)
+    assert cfg.layer_types == ("conv", "full_attention", "conv", "conv",
+                               "conv")
+    assert (cfg.num_dense_layers, cfg.vocab_size, cfg.conv_L_cache,
+            cfg.rope_theta, cfg.norm_eps) == (1, 16384, 3, 1e6, 1e-5)
+    assert (cfg.seq_len, tc.batch_size, cfg.remat) == (32768, 1, True)
+    assert cfg.moe_chunk is None
+    assert dispatch_chunk(32768 * 4, 8, 32) == 49152
+    assert set(cell.traffic["model_flags"]) == {"seq_len", "remat"}
+    # what the runner reads of a configuration
+    assert (cfg.n_routed_experts, cfg.first_k_dense_replace,
+            cfg.moe_intermediate_size, cfg.qk_head_dim, cfg.hidden_size) == (
+                32, 1, 1792, 64, 2048)
+    docs = cell.traffic["documents"]
+    assert (docs["median"], docs["sigma"], docs["shortest"], docs["longest"],
+            docs["count"]) == (2048, 1.2, 64, 16384, 1024)
+    assert (cell.traffic["batch"], cell.traffic["warm_steps"],
+            cell.traffic["traced_steps"], cell.traffic["loader_drain_s"],
+            cell.traffic["num_workers"], cell.traffic["prefetch_depth"],
+            cell.traffic["lr"], cell.traffic["wdecay"],
+            cell.traffic["check"]["reference_block"]) == (
+                1, 3, 6, 3.0, 8, 2, 3e-4, 0.1, 2048)
+    # controls: published keys the reference is given another value of
+    controls = cell.traffic["check"]["controls"]
+    assert {k: v for fault in controls.values() for k, v in fault.items()
+            } == {"rope_theta": 10000.0, "norm_topk_prob": False,
+                  "routed_scaling_factor": 2.0}
+    for fault in controls.values():
+        assert dataclasses.replace(cfg, **fault) != cfg
+    leaves = ["/".join(map(str, leaf))
+              for leaf in cell.traffic["check"]["leaves"]]
+    assert leaves == ["layers_2/conv/w_in", "layers_2/conv/taps",
+                      "layers_1/attn/wk", "layers_4/moe/experts/router",
+                      "layers_2/moe/experts/w_down/0", "layers_3/conv/w_out",
+                      "layers_0/mlp/w_down", "embed"]
+    # every layer held carries a checked leaf: a layer the program lacked
+    # would fail by its own
+    assert {leaf.split("/")[0] for leaf in leaves} == {"embed"} | {
+        f"layers_{i}" for i in range(cfg.num_hidden_layers)}
+    assert set(cell.traffic["check"]["tolerances"]) == {
+        "loss", "grad_norm"} | set(leaves)
+    assert cell.config["program"]["scopes"].count("lm/moe/shared") == 0
+    # the other three still build from their files
+    for other in (CELL2, CELL3):
+        harness.load_runner("lm_train_packed")._configs(
+            harness.load_cell(other), 0)
+
+
 def test_a_control_goes_through_the_checks_own_comparison():
     """The builder's other readings (`LM_CHECK_SECOND_READING`): each
     control is a fault the configuration can express, and its readings
@@ -366,7 +499,13 @@ def test_train_cli_refuses_the_language_models_flags_for_raft():
                           "sliding_attention", "full_attention",
                           "--experts_held", "0", "4")),
     # two of four shares' heads, two layers
-    ("evabyte-toy", ("--heads_held", "2", "4", "--layers", "2"))])
+    ("evabyte-toy", ("--heads_held", "2", "4", "--layers", "2")),
+    # a dense convolution layer and the attention expert layer; one
+    # key/value head's whole group
+    ("lfm2-8b-a1b-toy", ("--heads_held", "4", "4", "--kv_heads_held", "1",
+                         "1", "--layers", "2", "--dense_layers", "1",
+                         "--layer_types", "conv", "full_attention",
+                         "--experts_held", "0", "4"))])
 def test_train_cli_trains_the_toy_model_through_the_normal_path(
         tmp_path, variant, share):
     import numpy as np

@@ -20,8 +20,9 @@ import pytest
 sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
 
 from benchmarks import (flops, lm_counts, lm_counts_afmoe,  # noqa: E402
-                        lm_counts_eva, lm_scopes)
-from dexiraft_tpu.config import evabyte, kanana2, trinity_mini  # noqa: E402
+                        lm_counts_eva, lm_counts_lfm2, lm_scopes)
+from dexiraft_tpu.config import (evabyte, kanana2, lfm2_8b_a1b,  # noqa: E402
+                                 trinity_mini)
 from dexiraft_tpu.interop import lm_reference as ref  # noqa: E402
 
 from _lm_common import (SHARES, brute_force_eva_pairs,  # noqa: E402
@@ -390,4 +391,143 @@ def test_eva_layer_metrics_read_their_counters_and_give_nothing_without():
         for name in ("lm_eva_device_ms", "lm_eva_local_kernel_device_ms",
                      "lm_eva_remote_device_ms", "lm_eva_roofline_pct",
                      "lm_eva_local_blocks_visited_pct", "lm_mlp_device_ms"):
+            assert read(name, obs(c)) is None, name
+
+
+# ---- the fourth architecture's counts (benchmarks/lm_counts_lfm2.py) -------
+
+
+def test_lfm2_dense_parts_equal_the_walk_of_the_reference():
+    """The reference makes whole `[S, S]` score matrices in its one
+    attention layer and applies every held expert to every token; the
+    gate is elementwise, which the walk does not count; the tied head is
+    one product, counted once."""
+    cfg = toy("lfm2", **SHARES["lfm2"])
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg)
+    rows, s = batch["tokens"].shape
+    walked = flops.count(lambda p: ref.loss(p, batch, cfg), params)
+    parts = lm_counts_lfm2.per_token_forward(cfg)
+    dense = sum(v for k, v in parts.items() if k != "conv_gate")
+    kinds = lm_counts_lfm2.layers_by_kind(cfg)
+    assert kinds == {"conv": 4, "full": 1}
+    scores = rows * kinds["full"] * cfg.heads_held[1] * s * s * 2 * (
+        2 * cfg.head_dim)
+    experts = (rows * s * (cfg.num_hidden_layers - cfg.num_dense_layers)
+               * cfg.experts_held[1] * lm_counts_lfm2.per_slot_forward(cfg))
+    assert walked == dense * rows * s + scores + experts
+    assert parts["conv_gate"] == 4 * cfg.hidden_size * 7  # 2 L + 1, L = 3
+
+
+@pytest.mark.parametrize("length", [1, 2, 3, 4])
+@pytest.mark.parametrize("lengths", [(41, 59, 20), (3, 1, 2, 100), (128,), (),
+                                     (1,) * 128])
+def test_lfm2_pairs_and_taps_equal_a_brute_force_count(lengths, length):
+    """By the definitions, position by position: the benchmark's pairs,
+    and the taps of the program's own counter (ops/lm_conv.py
+    `taps_masked`), which nothing else counts."""
+    import jax.numpy as jnp
+
+    from dexiraft_tpu.ops.lm_conv import taps_masked
+
+    seg = np.zeros(128, np.int32)
+    at = 0
+    for i, n in enumerate(lengths, start=1):
+        seg[at:at + n] = i
+        at += n
+    masked = pairs = 0
+    for n, d in enumerate(seg):
+        if d == 0:
+            continue
+        pairs += sum(1 for m in range(n + 1) if seg[m] == d)
+        masked += sum(1 for back in range(1, length)
+                      if n - back < 0 or seg[n - back] != d)
+    cfg = toy("lfm2", conv_L_cache=length)
+    got = lm_counts_lfm2.pairs_by_kind(cfg, np.stack([seg, seg]))
+    assert got == {"full": 2.0 * pairs}
+    assert int(taps_masked(jnp.asarray(seg[None]), length)) == masked
+
+
+def test_lfm2_step_flops_at_the_cells_share_by_hand():
+    """The issue's arithmetic: 3.85e13 FLOP a step at an even balance."""
+    cfg = lfm2_8b_a1b(
+        num_hidden_layers=5, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+        heads_held=(0, 8), experts_held=(0, 8), vocab_size=16384)
+    per_token = lm_counts_lfm2.per_token_forward(cfg)
+    assert per_token["conv_projections"] == 4 * 2 * 4 * 2048 * 2048
+    assert per_token["attention_projections"] == 2 * 2048 * 64 * 20
+    assert per_token["dense_mlp"] == 3 * 2 * 2048 * 7168
+    assert per_token["router"] == 4 * 2 * 2048 * 32
+    assert per_token["head"] == 2 * 2048 * 16384
+    assert lm_counts_lfm2.per_slot_forward(cfg) == 3 * 2 * 2048 * 1792
+    parts = lm_counts_lfm2.step_flops(
+        cfg, tokens_real=32768, slots_held=4 * 32768,
+        pairs={"full": 130e6})
+    assert parts["attention"] == 3 * 8 * 2 * 128 * 130e6
+    assert parts["conv_projections"] == pytest.approx(1.32e13, rel=0.01)
+    assert parts["routed"] == pytest.approx(8.66e12, rel=0.01)
+    assert parts["head"] == pytest.approx(6.6e12, rel=0.01)
+    assert parts["total"] == pytest.approx(sum(
+        v for k, v in parts.items() if k != "total"))
+    assert parts["total"] == pytest.approx(3.85e13, rel=0.01)
+
+
+def test_conv_roofline_is_four_products_and_fifteen_arrays_under_remat():
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    least = lm_counts_lfm2.conv_roofline_seconds(32768, 2048, 4, True, peaks)
+    assert least["flops"] == 4 * 32768 * 32 * 2048 * 2048
+    assert least["bytes"] == 4 * 32768 * 15 * 2048 * 2
+    assert least["seconds"] == pytest.approx(
+        least["flops"] / 197e12 + least["bytes"] / 819e9)
+    assert least["seconds"] == pytest.approx(0.0992, rel=0.01)
+    plain = lm_counts_lfm2.conv_roofline_seconds(32768, 2048, 4, False, peaks)
+    assert (plain["flops"], plain["bytes"]) == (
+        least["flops"] * 3 / 4, least["bytes"] * 11 / 15)
+    assert (lm_counts_lfm2.attention_roofline_seconds
+            is lm_counts_afmoe.attention_roofline_seconds)
+
+
+def test_lfm2_layer_metrics_read_their_counters_and_give_nothing_without():
+    from benchmarks import harness
+
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    counters = {"scope_s:lm/conv/in": 0.090, "scope_s:lm/conv/gate": 0.030,
+                "scope_s:lm/conv/out": 0.030,
+                "scope_s:lm/gqa/proj": 0.010,
+                "scope_s:lm/gqa/full/kernel": 0.008,
+                "scope_s:lm/mlp": 0.06, "scope_s:lm/moe/experts": 0.1,
+                "traced_pairs_full": 130e6,
+                "attn_layers_conv": 4, "attn_layers_full": 1,
+                "attn_heads_held": 8, "attn_kv_heads_held": 2,
+                "attn_head_dim": 64, "hidden_size": 2048, "remat": 1.0,
+                "batch": 1, "seq_len": 32768, "tokens_real": 32600.0}
+
+    def obs(c):
+        return harness.Observation(
+            spans={}, counters=c, end_to_end={}, trace={"busy_s": 1.0},
+            peaks=peaks, chips=1, memory_peak_bytes=0)
+
+    read = lambda name, o: harness.load_metric(name).read(o)  # noqa: E731
+    full = obs(counters)
+    assert read("lm_conv_device_ms", full) == pytest.approx(150.0)
+    assert read("lm_conv_gate_device_ms", full) == pytest.approx(30.0)
+    assert read("lm_gqa_device_ms", full) == pytest.approx(18.0)
+    assert read("lm_gqa_full_kernel_device_ms", full) == pytest.approx(8.0)
+    least = lm_counts_lfm2.conv_roofline_seconds(32600.0, 2048, 4, True,
+                                                 peaks)["seconds"]
+    share = read("lm_conv_roofline_pct", full)
+    assert share == pytest.approx(least / 0.150 * 100)
+    assert 0 < share < 100
+    kernel = lm_counts_afmoe.attention_roofline_seconds(
+        130e6, 1, 32768, 8, 2, 64, True, peaks)["seconds"]
+    assert kernel == pytest.approx(130e6 * 8 * 11 * 128 / 197e12)
+    assert read("lm_gqa_full_kernel_roofline_pct", full) == pytest.approx(
+        kernel / 0.008 * 100)
+    # the parent's program, or another architecture's: nothing, no raise
+    for c in ({}, {"scope_s:lm/eva/proj": 0.03, "batch": 1, "seq_len": 32768,
+                   "traced_pairs_local": 3e7, "attn_layers_local": 4}):
+        for name in ("lm_conv_device_ms", "lm_conv_gate_device_ms",
+                     "lm_conv_roofline_pct",
+                     "lm_gqa_full_kernel_roofline_pct"):
             assert read(name, obs(c)) is None, name

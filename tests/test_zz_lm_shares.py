@@ -5,7 +5,8 @@ to what the uncut reference gives for the whole layer. For both
 architectures: kanana2's latent attention first, then trinity's gated
 grouped-query attention, where two chips hold copies of one key/value
 head, then evabyte's chunked linear attention over 4 chips, whose dense
-layer every chip holds whole.
+layer every chip holds whole, then lfm2's two kinds of layer over 4
+chips, whose convolution mixer every chip holds whole.
 
 Each share runs the SYSTEM's modules (models/lm) on its slice of the
 whole model's weights; the whole is the plain reference holding every
@@ -315,6 +316,129 @@ def test_eva_layer_outputs_of_the_shares_add_up_to_the_whole_layer(eva_whole):
     assert rel(h + mlp, want) < 2e-5
     # the whole model's tree cut to a share is the share's own tree
     share, p = _eva_share(1, eva_whole)
+    from dexiraft_tpu.config import TrainConfig
+    from dexiraft_tpu.train.family import family_of
+    shapes, _ = jax.eval_shape(family_of(share, TrainConfig()).init,
+                               jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda a: a.shape, p) == jax.tree.map(
+        lambda a: a.shape, shapes)
+
+
+# ---- the fourth architecture: convolution and attention mixers -------------
+#
+# One of 4 chips that share each layer, as the cell's deployment: 16 query
+# heads of 8 over 4 key/value heads, so share i holds the whole group of
+# key/value head i (no copies) and experts 4i..4i+3. Three layers: after
+# the dense one, layer 1 is the attention expert layer and layer 2 a
+# convolution expert layer, whose mixer
+# has no heads: every chip holds it whole and it is counted once. No
+# shared expert.
+
+LFM2_SHARES = 4
+LFM2_LAYERS = {"attention": "layers_1", "conv": "layers_2"}
+_LFM2_SIZE = dict(hidden_size=128, num_attention_heads=16,
+                  num_key_value_heads=4, num_hidden_layers=3,
+                  layer_types=("conv", "full_attention", "conv"))
+
+
+@pytest.fixture(scope="module")
+def lfm2_whole():
+    cfg = toy("lfm2", **_LFM2_SIZE)
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg, rows=1)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, cfg.seq_len,
+                                                  cfg.hidden_size))
+    return cfg, params, batch, x
+
+
+def _lfm2_share(i, whole):
+    cfg, params, _, _ = whole
+    share = toy("lfm2", heads_held=(4 * i, 4), experts_held=(4 * i, 4),
+                **_LFM2_SIZE)
+    assert share.kv_heads_held == (i, 1)
+    return share, ref.take_share(params, cfg, share.heads_held,
+                                 share.experts_held, share.kv_heads_held)
+
+
+def _lfm2_mixer_part(i, whole, layer, x):
+    from dexiraft_tpu.models.lm.attention import mixer_of
+
+    _, _, batch, _ = whole
+    share, p = _lfm2_share(i, whole)
+    name = "conv" if layer == LFM2_LAYERS["conv"] else "attn"
+    return mixer_of(share, int(layer.split("_")[1])).apply(
+        {"params": p[layer][name]}, x, batch["positions"],
+        batch["segment_ids"])[0]
+
+
+def _lfm2_routed_part(i, whole, layer, rows):
+    share, p = _lfm2_share(i, whole)
+    out, counters = RoutedExperts(cfg=share).apply(
+        {"params": p[layer]["moe"]["experts"]}, rows,
+        mutable=["batch_stats"])[0]
+    assert int(counters["moe_dropped_slots"]) == 0
+    return out, counters
+
+
+@pytest.mark.parametrize("i", range(LFM2_SHARES))
+def test_an_lfm2_share_equals_the_reference_given_that_share(i, lfm2_whole):
+    cfg, params, batch, x = lfm2_whole
+    share, p = _lfm2_share(i, lfm2_whole)
+    pos, seg = batch["positions"][0], batch["segment_ids"][0]
+    want = ref.gated_attention(p["layers_1"]["attn"], x[0], pos, seg, cfg,
+                               4, 1, None, gate=False, rope=True)
+    assert rel(_lfm2_mixer_part(i, lfm2_whole, "layers_1", x), want) < 2e-5
+    # the convolution mixer is the whole model's, whatever the share
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: a is b, p["layers_2"]["conv"],
+        params["layers_2"]["conv"]))
+    assert rel(_lfm2_mixer_part(i, lfm2_whole, "layers_2", x),
+               ref.short_conv(params["layers_2"]["conv"], x[0], seg, cfg)
+               ) < 2e-5
+    assert "shared" not in p["layers_2"]["moe"]
+    routed, _ = _lfm2_routed_part(i, lfm2_whole, "layers_2", x[0])
+    assert rel(routed, ref.moe(p["layers_2"]["moe"], x[0], cfg,
+                               share.experts_held)) < 2e-5
+
+
+def test_lfm2_attention_parts_of_all_shares_add_up_to_the_whole(lfm2_whole):
+    cfg, params, batch, x = lfm2_whole
+    total = sum(_lfm2_mixer_part(i, lfm2_whole, "layers_1", x)
+                for i in range(LFM2_SHARES))
+    want = ref.gated_attention(
+        params["layers_1"]["attn"], x[0], batch["positions"][0],
+        batch["segment_ids"][0], cfg, cfg.num_attention_heads,
+        cfg.num_key_value_heads, None, gate=False, rope=True)
+    assert rel(total, want) < 2e-5
+
+
+@pytest.mark.parametrize("kind", list(LFM2_LAYERS))
+def test_lfm2_layer_outputs_of_the_shares_add_up_to_the_whole_layer(
+        kind, lfm2_whole):
+    """x + the mixer (the four attention parts summed, or the convolution
+    counted once) = h; h + the four routed parts, and no shared expert =
+    the uncut reference's layer output, for both kinds of expert layer."""
+    cfg, params, batch, x = lfm2_whole
+    layer = LFM2_LAYERS[kind]
+    lp = params[layer]
+    want = ref.lfm2_layer(lp, x[0], batch["positions"][0],
+                          batch["segment_ids"][0], cfg,
+                          int(layer.split("_")[1]))
+    normed = ref._rms_norm(x[0], lp["attn_norm"], cfg.norm_eps)[None]
+    if kind == "conv":
+        h = x[0] + _lfm2_mixer_part(0, lfm2_whole, layer, normed)
+    else:
+        h = x[0] + sum(_lfm2_mixer_part(i, lfm2_whole, layer, normed)
+                       for i in range(LFM2_SHARES))
+    ffn_in = ref._rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
+    parts = [_lfm2_routed_part(i, lfm2_whole, layer, ffn_in)
+             for i in range(LFM2_SHARES)]
+    assert rel(h + sum(out for out, _ in parts), want) < 2e-5
+    # every slot of every token lands on exactly one chip
+    assert sum(int(c["moe_slots_held"]) for _, c in parts) == (
+        cfg.seq_len * cfg.num_experts_per_tok)
+    # the whole model's tree cut to a share is the share's own tree
+    share, p = _lfm2_share(1, lfm2_whole)
     from dexiraft_tpu.config import TrainConfig
     from dexiraft_tpu.train.family import family_of
     shapes, _ = jax.eval_shape(family_of(share, TrainConfig()).init,
