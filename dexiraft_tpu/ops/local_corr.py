@@ -113,8 +113,9 @@ class LocalCorr:
     # the queries flattened and zero-padded to a pixel-block multiple
     fmap1: jax.Array
     # one (B, H>>i, W>>i, C) level per pyramid index, in the storage
-    # dtype; under kernel="flash" zero-padded to the kernel's row-block
-    # and lane multiples (a degenerate 0-row tail level stays empty)
+    # dtype; under kernel="flash" stored x-major, (B, W>>i, rows, C) with
+    # the rows zero-padded to the kernel's row-block multiple (a
+    # degenerate 0-row tail level stays empty)
     fmap2_pyramid: tuple
     batch: int = flax.struct.field(pytree_node=False)
     ht: int = flax.struct.field(pytree_node=False)
@@ -191,7 +192,7 @@ def build_local_corr(
     and the lookup dequantizes in-register.
 
     ``kernel`` picks the lookup implementation ("xla" | "flash"); "flash"
-    stores the operands as its kernel reads them, padded here, once.
+    stores the operands as its kernel reads them, laid out here, once.
     """
     if kernel not in ("xla", "flash"):
         raise ValueError(f"unknown local-corr kernel {kernel!r}; "

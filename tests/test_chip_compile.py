@@ -96,9 +96,11 @@ def test_auto_on_tpu_names_what_is_compiled_here():
                                       ("fp32", (136, 240))])
 def test_flash_fused_step_compiles_for_v5e(chip, dtype, hw):
     """What `auto` serves: one kernel per refinement iteration. The two
-    row-block slots and their semaphore pair are what Mosaic has to
-    accept and what has to fit: 1088x1920 (136x240, level 0 padded to 256
-    columns) has the largest pair any entry point reaches."""
+    row-block slots with their semaphore pair, the zero-filled x axis
+    and the taps are what Mosaic has to accept and what has to fit under
+    its default scoped limit: 1088x1920 (136x240: slots of 240 x 8 x 256,
+    9.66 MiB of scratch) is the largest any entry point reaches. A bf16 or
+    int8 level is copied and read in 8-row blocks like an fp32 one."""
     s = _shapes(chip, dtype, *hw)
     text = _compiled_text(
         lambda f1, lv, co, w, b: pc.flash_fused_step(
@@ -111,7 +113,7 @@ def test_flash_fused_step_compiles_for_v5e(chip, dtype, hw):
                                          ("fp32", 3), ("bf16", 3)])
 def test_flash_lookup_compiles_for_v5e(chip, dtype, level):
     """corr_impl="flash" without fused_update, at the widest level and
-    at the narrowest (16 columns, padded to the lane width)."""
+    at the narrowest (16 columns as they are: no lane pad)."""
     s = _shapes(chip, dtype)
     text = _compiled_text(
         lambda f1, f2, co: pc.flash_local_corr_level(
@@ -205,6 +207,31 @@ def test_eval_loop_keeps_the_queries_on_the_lanes_for_v5e(eval_loop):
     # the carry: the two planes of each pair, (8, 128) tiles over (h, w)
     assert any(kind.startswith(f"f32[{nb},55,128,2]{{2,1,3,0:T(8,128)")
                for _, kind, _ in loop)
+
+
+def test_eval_loop_relays_nothing_of_the_kernel_for_v5e(eval_loop):
+    """The whole `while` body read, not its ten longest: the kernel takes
+    its levels as they are stored (x-major, `pad_flash_operands`), its
+    queries and weights as the loop carries them, and its `(B, Np, F)`
+    result goes to the motion encoder as it is. So the body's every
+    `pad`, `transpose` and `copy` is one the hat-form kernel's loop had
+    too (PR 38's program, read the same way): the coordinates' own pad,
+    and the 7x7 flow convolution's operand made from the planes. A level
+    relaid, a weight transposed or a result turned for its consumer
+    inside the loop would show here."""
+    nb, loop = eval_loop
+    moved = {f"{kind.split('{')[0]} {op}" for _, kind, op in loop
+             if op in ("pad", "transpose", "copy")}
+    assert moved == {f"f32[{nb},2,7168] pad", f"bf16[{nb},55,128,2] copy",
+                     "f32[55,128,2] copy"}, sorted(moved)
+    # the levels reach the call in their stored form: x major, 8-row
+    # blocks second-minor, no lane pad (level 3 is 16 columns, not 128)
+    levels = [kind.split("{")[0] for _, kind, op in loop
+              if op == "get-tuple-element" and kind.endswith("256]{3,2,1,0:T(8,128)}")
+              and kind.startswith(f"f32[{nb},")]
+    assert sorted(levels) == sorted(
+        f"f32[{nb},{W >> i},{-(-(H >> i) // 8) * 8},{C}]"
+        for i in range(LEVELS)), levels
 
 
 def test_interpret_switch_is_an_error_on_a_tpu_backend(monkeypatch):

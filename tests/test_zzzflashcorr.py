@@ -216,8 +216,18 @@ class TestFlashKernelParity:
 
 # (h, w, levels): level widths 64 / 32 / 16 under the lane width; odd
 # widths 13 / 6 / 3 / 1 with h*w = 65 not a multiple of the pixel block
-# and a degenerate 0-row tail level
-PREPARED = {"w64_32_16": (4, 64, 3), "odd_tail": (5, 13, 4)}
+# and a degenerate 0-row tail level; widths 27 / 13 / 6, no multiple of
+# a sublane tile (240 columns: LEVEL_WIDTHS below, and the stored form
+# in tests/test_zzzfused_corr.py)
+PREPARED = {"w64_32_16": (4, 64, 3), "odd_tail": (5, 13, 4),
+            "w27_13_6": (6, 27, 3)}
+
+
+def _stored_back(g, h2):
+    """A level's cotangent in the stored form (B, W2, H2p, C) -> the
+    (B, H2, W2, C) part of it and the padding rows."""
+    g = jnp.swapaxes(g, 1, 2)
+    return g[:, :h2], g[:, h2:]
 
 
 def _prepared_case(name, dtype, radius=2):
@@ -271,9 +281,10 @@ class TestPreparedOperands:
     @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
     @pytest.mark.parametrize("name", list(PREPARED))
     def test_fused_vjp_slices_the_padded_operands(self, name, dtype):
-        """jax.grad through flash_fused_step w.r.t. the PADDED operands:
-        inside the true extents the reference's gradients, in the
-        padding exactly zero."""
+        """jax.grad through flash_fused_step w.r.t. the operands AS
+        STORED (queries padded, levels x-major with padded rows): on the
+        true extents the reference's gradients, in the padding exactly
+        zero."""
         lc, lx, coords, weight, bias = _prepared_case(name, dtype)
         h, w = lc.ht, lc.wd
 
@@ -297,11 +308,17 @@ class TestPreparedOperands:
         np.testing.assert_allclose(
             f32(gf[0][:, :h * w]).reshape(gr[0].shape), f32(gr[0]), **tol)
         np.testing.assert_array_equal(f32(gf[0][:, h * w:]), 0.0)
-        for g, ref, (h2, w2) in zip(gf[1], gr[1], lc.level_shapes):
-            assert g.shape[1:3] != (h2, w2) or not h2  # it was padded
-            np.testing.assert_allclose(f32(g[:, :h2, :w2]), f32(ref), **tol)
-            np.testing.assert_array_equal(f32(g[:, h2:]), 0.0)
-            np.testing.assert_array_equal(f32(g[:, :, w2:]), 0.0)
+        for g, lv, ref, (h2, w2) in zip(gf[1], lc.fmap2_pyramid, gr[1],
+                                        lc.level_shapes):
+            assert g.shape == lv.shape and g.dtype == lv.dtype
+            if not h2:  # the empty tail level: stored as it is
+                assert g.size == 0
+                continue
+            # x a major axis and unpadded, the rows to a row-block multiple
+            assert g.shape[1] == w2 and g.shape[2] % pallas_corr._FLASH_ROWS == 0
+            inside, padding = _stored_back(g, h2)
+            np.testing.assert_allclose(f32(inside), f32(ref), **tol)
+            np.testing.assert_array_equal(f32(padding), 0.0)
         for g, ref in zip(gf[2:], gr[2:]):
             np.testing.assert_allclose(f32(g), f32(ref), **tol)
         assert float(jnp.abs(gr[0]).max()) > 0
@@ -325,10 +342,12 @@ class TestPreparedOperands:
             np.asarray(gf[0][:, :n]).reshape(gr[0].shape), np.asarray(gr[0]),
             rtol=1e-3, atol=1e-3)
         np.testing.assert_array_equal(np.asarray(gf[0][:, n:]), 0.0)
-        np.testing.assert_allclose(np.asarray(gf[1][:, :h2, :w2]),
-                                   np.asarray(gr[1]), rtol=1e-3, atol=1e-3)
-        np.testing.assert_array_equal(np.asarray(gf[1][:, h2:]), 0.0)
-        np.testing.assert_array_equal(np.asarray(gf[1][:, :, w2:]), 0.0)
+        assert gf[1].shape == lc.fmap2_pyramid[1].shape
+        inside, padding = _stored_back(gf[1], h2)
+        assert inside.shape[2] == w2
+        np.testing.assert_allclose(np.asarray(inside), np.asarray(gr[1]),
+                                   rtol=1e-3, atol=1e-3)
+        np.testing.assert_array_equal(np.asarray(padding), 0.0)
         np.testing.assert_array_equal(np.asarray(gf[2]), 0.0)
         assert float(jnp.abs(gr[1]).max()) > 0
 
@@ -349,6 +368,15 @@ def _geometry_case(name):
                                                 jnp.float32, -3.0, 3.0)
     if name in ("radius3", "radius4"):
         return f1, f2, noisy, int(name[-1])
+    if name == "integer_centres":  # every fraction exactly zero
+        return f1, f2, jnp.round(noisy), 4
+    if name in LEVEL_WIDTHS:  # a level as wide as no tile: x takes no pad
+        rows2, w2 = LEVEL_WIDTHS[name]
+        f2 = jax.random.normal(k2, (b, rows2, w2, c), jnp.float32)
+        scale = jnp.asarray([w2 / w, rows2 / h], jnp.float32)
+        return f1, f2, noisy * scale, 4
+    if name.startswith("edge_"):
+        return _edge_case(name, f1, f2, noisy)
     if name == "frame_edge":  # every window straddles two frame edges
         edge = jnp.stack([jnp.full((b, h, w), -0.4),
                           jnp.full((b, h, w), h - 0.6)], axis=-1)
@@ -363,18 +391,47 @@ def _geometry_case(name):
     return f1, f2, noisy / 2.0, 3
 
 
+# level (rows, columns): 27, 13 and 6 columns are no multiple of 8, 240
+# (the 1088x1920 bucket's level 0) none of 128
+LEVEL_WIDTHS = {"w27": (8, 27), "w13": (8, 13), "w6": (4, 6),
+                "w240": (4, 240)}
+# each edge of the frame x the window wholly or partly beyond it x an
+# integer or a fractional centre
+EDGE_CASES = [f"edge_{edge}_{how}_{centre}"
+              for edge in ("left", "right", "top", "bottom")
+              for how in ("out", "part") for centre in ("int", "frac")]
+
+
+def _edge_case(name, f1, f2, noisy):
+    """Every query's window beyond one edge of a 4x8 level at radius 2
+    (a corner of the fixture, 32 channels: the edges need no width):
+    wholly (the nearest tap's support ends before the frame: all zeros)
+    or partly (some taps inside); the other coordinate stays the noisy
+    grid's."""
+    _, edge, how, centre = name.split("_")
+    radius = 2
+    f1, f2, noisy = f1[:, :4, :8, :32], f2[:, :4, :8, :32], noisy[:, :4, :8]
+    axis = 0 if edge in ("left", "right") else 1
+    size = f2.shape[2 - axis]
+    beyond = (radius + 2.0) if how == "out" else 1.0
+    frac = 0.0 if centre == "int" else 0.4
+    at = (-beyond - frac) if edge in ("left", "top") else (
+        size - 1 + beyond + frac)
+    return f1, f2, noisy.at[..., axis].set(at), radius
+
+
 class TestModelWidthGeometry:
     CASES = ["radius3", "radius4", "frame_edge", "far_out_both_signs",
-             "half_size_level"]
+             "half_size_level", "integer_centres", *LEVEL_WIDTHS]
 
-    @pytest.mark.parametrize("name", CASES)
+    @pytest.mark.parametrize("name", CASES + EDGE_CASES)
     def test_lookup_matches_reference(self, name):
         f1, f2, coords, radius = _geometry_case(name)
         out = flash_local_corr_level(f1, f2, coords, radius, True)
         ref = local_corr_level(f1, f2, coords, radius)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
-        if name == "far_out_both_signs":
+        if name == "far_out_both_signs" or "_out_" in name:
             np.testing.assert_array_equal(np.asarray(out), 0.0)
         else:
             assert float(jnp.abs(ref).max()) > 0.1
@@ -454,6 +511,8 @@ def _pipeline_case(name):
     key = jax.random.PRNGKey(sum(map(ord, name)))
     if name == "batch2_tail":
         b, h, w = 2, 7, 6  # 42 queries: the third block is 10 real + 6 pad
+    elif name == "unaligned_query_block":
+        h, w = 8, 6  # a block is 2 2/3 query rows: it spans row blocks
     elif name == "degenerate_tail":
         h = 3  # levels of 3, 1 and 0 rows
     elif name in ("bf16", "int8"):
@@ -511,6 +570,11 @@ def _pipeline_case(name):
         def check(v):
             for (f, e), nb in zip(v, n_blocks):
                 assert (f == 0).all() and (e == nb).all()
+    elif name == "unaligned_query_block":
+        coords = grid + 0.1 * (coords - grid)
+
+        def check(v):  # its own rows lie in two row blocks, the reach adds
+            assert _n(v)[0].max() >= 4
     elif name == "degenerate_tail":
         def check(v):
             assert [lv.shape[1] for lv in levels] == [3, 1, 0]
@@ -533,7 +597,7 @@ class TestVisitedRangePipeline:
 
     CASES = ["small", "level0_empty", "bottom_empty", "all_empty",
              "one_visit", "clipped_both", "degenerate_tail", "batch2_tail",
-             "bf16", "int8"]
+             "unaligned_query_block", "bf16", "int8"]
 
     @staticmethod
     def _case(name):
@@ -611,6 +675,23 @@ class TestBlockedTilingEquivalence:
                                 weight, bias, radius, True)
         assert float(jnp.max(jnp.abs(one - many))) <= 1e-4
 
+    @pytest.mark.parametrize("rows,pixel_block", [(1, 16), (3, 48), (4, 32),
+                                                  (8, 128)])
+    def test_toy_tiles_match_reference(self, rows, pixel_block, monkeypatch):
+        """Any row block and any query block: the sizes of the scratch,
+        the shifter's axis and the strided row sum follow them."""
+        radius = 2
+        f1, f2, coords, weight, bias = _setup(jax.random.PRNGKey(8),
+                                              radius=radius)
+        lc = build_local_corr(f1, f2, num_levels=3, radius=radius)
+        monkeypatch.setattr(pallas_corr, "_FLASH_ROWS", rows)
+        monkeypatch.setattr(pallas_corr, "_FLASH_PIXEL_BLOCK", pixel_block)
+        out = flash_fused_step(lc.fmap1, lc.fmap2_pyramid, coords, weight,
+                               bias, radius, True)
+        ref = fused_reference(lc.fmap1, lc.fmap2_pyramid, coords, weight,
+                              bias, radius)
+        assert float(jnp.max(jnp.abs(out - ref))) <= 1e-4
+
     def test_pixel_block_override_identical(self, monkeypatch):
         radius = 2
         f1, f2, coords, _, _ = _setup(jax.random.PRNGKey(9), radius=radius)
@@ -619,6 +700,63 @@ class TestBlockedTilingEquivalence:
         b = flash_local_corr_level(f1, f2, coords, radius, True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6)
+
+
+def _kernel_equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (the kernel inside `pallas_call`, loop and branch bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_equations(sub)
+
+
+class TestWindowingMechanism:
+    """What a visit does, read from the kernel's jaxpr: one product with
+    the queries on the lanes, x aligned by selects between registers, no
+    per-query matmul and no hat over a level's width."""
+
+    @pytest.mark.parametrize("fused", [True, False])
+    def test_no_batched_products_and_no_hat_over_the_width(self, fused):
+        radius, w2, c = 2, 24, 32  # a width no other size of the call has
+        f1, f2, coords, weight, bias = _setup(
+            jax.random.PRNGKey(11), h=4, w=w2, c=c, levels=1, radius=radius)
+        if fused:
+            fn = lambda a, b_, co: flash_fused_step(  # noqa: E731
+                a, (b_,), co, weight, bias, radius, True)
+        else:
+            fn = lambda a, b_, co: flash_local_corr_level(  # noqa: E731
+                a, b_, co, radius, True)
+        call, = [e for e in _kernel_equations(
+            jax.make_jaxpr(fn)(f1, f2, coords).jaxpr)
+            if e.primitive.name == "pallas_call"]
+        eqns = list(_kernel_equations(call.params["jaxpr"]))
+        dots = [e for e in eqns if e.primitive.name == "dot_general"]
+        # the correlation itself (and the fused weight product): plain 2-D
+        # products, none batched over the queries
+        assert len(dots) == (2 if fused else 1)
+        for e in dots:
+            (_, _), (lhs_batch, rhs_batch) = e.params["dimension_numbers"]
+            assert not lhs_batch and not rhs_batch
+            assert all(v.aval.ndim == 2 for v in e.invars)
+        p_block, rows = pallas_corr._FLASH_PIXEL_BLOCK, pallas_corr._FLASH_ROWS
+        dots_t, = [e.outvars[0].aval.shape for e in dots
+                   if e.invars[0].aval.shape == (w2 * rows, c)]
+        assert dots_t == (w2 * rows, p_block)  # positions x queries
+        # nothing holds the level's width on its minor axis: no
+        # (P, 2r+1, w2) hat, no (P, rows, w2) relayout of the product
+        assert not hasattr(pallas_corr, "_hat")
+        minor = [e for e in eqns for v in e.outvars
+                 if getattr(v.aval, "shape", ()) and v.aval.shape[-1] == w2]
+        assert not minor, minor
+        # x by the shifter: one select a binary digit of the window's
+        # start on the zero-filled axis
+        n = 2 * radius + 2
+        selects = [e for e in eqns if e.primitive.name == "select_n"
+                   and e.outvars[0].aval.ndim == 3]
+        assert len(selects) == (w2 + n).bit_length()
+        assert all(e.outvars[0].aval.shape[1:] == (rows, p_block)
+                   for e in selects)
 
 
 def _assert_flash_fused_matches_allpairs(make, mixed, variables, im1, im2):
@@ -858,10 +996,9 @@ class TestMemoryFootprint:
     def test_flash_temp_is_o_fmaps_not_o_volume(self):
         # big enough that N^2 >> N*C, small enough to trace fast:
         # N = 5120 queries, C = 64 -> level-0 volume 105 MB vs fmaps
-        # 2.6 MB. The flash kernel pads every level to 128 columns (the
-        # chip's lane width), so its per-level transients are a constant
-        # ~2.5 MB each at the default pixel block — the geometry has to
-        # be large enough that this constant sits well under 8x fmaps
+        # 2.6 MB. The flash kernel's scratch is a constant few MB at the
+        # default pixel block — the geometry has to be large enough that
+        # this constant sits well under 8x fmaps
         h8, w8, c, radius, levels = 40, 128, 64, 4, 4
         n = h8 * w8
         f1 = jax.random.normal(jax.random.PRNGKey(0), (1, h8, w8, c),

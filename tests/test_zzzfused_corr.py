@@ -127,14 +127,16 @@ class TestQuantizedPyramid:
 class TestFlashOperandsMadeOnce:
     """build_local_corr(kernel="flash") stores the kernel's operands in
     the form the kernel reads (ops/pallas_corr.py pad_flash_operands):
-    what they hold is the xla build's arrays, zeros around them. The
-    kernel's parity on them is tests/test_zzzflashcorr.py's."""
+    what they hold is the xla build's arrays, a level with x as its
+    major axis and zero rows after its own. The kernel's parity on them
+    is tests/test_zzzflashcorr.py's."""
 
     # (h, w, levels): level widths 64 / 32 / 16; odd widths 13 / 6 / 3 / 1
-    # with 65 queries and a degenerate 0-row tail level
+    # with 65 queries and a degenerate 0-row tail level; 240 / 120 columns
     @pytest.mark.parametrize("dtype", ["fp32", "bf16", "int8"])
-    @pytest.mark.parametrize("h,w,levels", [(4, 64, 3), (5, 13, 4)])
-    def test_padded_once_in_the_storage_dtype(self, h, w, levels, dtype):
+    @pytest.mark.parametrize("h,w,levels", [(4, 64, 3), (5, 13, 4),
+                                            (3, 240, 2)])
+    def test_stored_once_in_the_storage_dtype(self, h, w, levels, dtype):
         from dexiraft_tpu.ops import pallas_corr as pc
 
         f1, f2, coords, _, _ = _setup(jax.random.PRNGKey(h * w), b=2, h=h,
@@ -153,20 +155,21 @@ class TestFlashOperandsMadeOnce:
             np.asarray(lc.fmap1[:, :n]).reshape(lx.fmap1.shape),
             np.asarray(lx.fmap1))
         np.testing.assert_array_equal(np.asarray(lc.fmap1[:, n:]), 0.0)
-        # the levels: the stored bytes, zeros to the row-block and lane
-        # multiples; a level with no rows stays empty
+        # the levels: the stored bytes as (B, W2, H2p, C), x a major axis
+        # with no pad, zero rows up to a row-block multiple; a level with
+        # no rows stays empty
         for lv, raw, (h2, w2) in zip(lc.fmap2_pyramid, lx.fmap2_pyramid,
                                      lc.level_shapes):
             assert lv.dtype == raw.dtype
             if not h2 or not w2:
                 assert lv.shape == raw.shape and lv.size == 0
                 continue
-            assert lv.shape[1] % pc._FLASH_ROWS == 0 and lv.shape[1] >= h2
-            assert lv.shape[2] % pc._LANES == 0 and lv.shape[2] >= w2
+            assert lv.shape == (2, w2, -(-h2 // pc._FLASH_ROWS)
+                                * pc._FLASH_ROWS, 8)
             as_np = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
-            np.testing.assert_array_equal(as_np(lv[:, :h2, :w2]), as_np(raw))
-            np.testing.assert_array_equal(as_np(lv[:, h2:]), 0.0)
-            np.testing.assert_array_equal(as_np(lv[:, :, w2:]), 0.0)
+            np.testing.assert_array_equal(
+                as_np(lv[:, :, :h2]), as_np(raw).swapaxes(1, 2))
+            np.testing.assert_array_equal(as_np(lv[:, :, h2:]), 0.0)
         if dtype == "int8":
             for a, b in zip(lc.scales, lx.scales):
                 assert float(a) == float(b)
