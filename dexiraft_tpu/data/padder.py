@@ -69,6 +69,33 @@ class InputPadder:
         width = [(0, 0)] * (inputs[0].ndim - 3) + [(t, b), (l, r), (0, 0)]
         return tuple(np.pad(x, width, mode="edge") for x in inputs)
 
+    def pad_into(self, out: np.ndarray, x: np.ndarray) -> None:
+        """Write `x` edge-padded into `out` (..., padded H, padded W, C)
+        in one pass over its bytes: the assignment casts to `out.dtype`
+        as it places the frame, then the edges are replicated in place
+        (columns of the frame's own rows first, then whole rows, so a
+        corner holds the corner pixel). Bit for bit
+        `pad(np.asarray(x, out.dtype))`, with no array of the padded
+        size beside `out` — the serving engines fill their batch
+        buffers with it."""
+        l, r, t, b = self._pad
+        if tuple(out.shape[-3:-1]) != self.padded_shape:
+            raise ValueError(
+                f"pad_into: out is {out.shape[-3]}x{out.shape[-2]}, the "
+                f"padded shape is {self.padded_shape[0]}x"
+                f"{self.padded_shape[1]}")
+        y1, x1 = t + self.ht, l + self.wd  # the frame's far corner in `out`
+        rows = out[..., t:y1, :, :]
+        rows[..., l:x1, :] = x
+        if l:
+            rows[..., :l, :] = rows[..., l:l + 1, :]
+        if r:
+            rows[..., x1:, :] = rows[..., x1 - 1:x1, :]
+        if t:
+            out[..., :t, :, :] = out[..., t:t + 1, :, :]
+        if b:
+            out[..., y1:, :, :] = out[..., y1 - 1:y1, :, :]
+
     def unpad(self, x: np.ndarray) -> np.ndarray:
         l, r, t, b = self._pad
         ht, wd = x.shape[-3], x.shape[-2]

@@ -138,7 +138,7 @@ class ServeStats:
                         wait for the device (`engine:wait`, the host's
                         slack) plus the device-to-host copy
                         (`engine:copy_out`)
-      * dispatch_s    — host-side pad/stack/put/enqueue time (never
+      * dispatch_s    — host-side assemble/put/enqueue time (never
                         blocks on device compute): `engine:assemble` +
                         `engine:put` + `engine:enqueue`
       * batch_latency — per-batch dispatch→fetch-complete wall time;

@@ -14,11 +14,15 @@ the same treatment PR 2 gave training:
     like training batches do. The tail batch of a bucket is padded back
     up to `batch_size` by replicating its last item — shape stability
     keeps the one-executable-per-bucket contract — and the filler
-    results are masked out, so metrics cover exactly the dataset.
+    results are masked out, so metrics cover exactly the dataset. A
+    batch is assembled in ONE pass over its bytes: each frame is cast
+    to float32, placed and edge-padded straight into its row of a host
+    buffer the engine owns and reuses (InputPadder.pad_into;
+    _batch_buffers has the ownership rule).
   * async in-flight dispatch: eval_fn only ENQUEUES device work (jax
     async dispatch) and the host->device put is async too, so holding
     `inflight` dispatched tickets before fetching overlaps device
-    compute with host pad/stack/encode work. ServeStats (profiling.py)
+    compute with host assemble/encode work. ServeStats (profiling.py)
     accounts the residual honestly: fetch_s is the compute the window
     failed to hide.
   * data-parallel serving: with a mesh, each batch device_puts sharded
@@ -60,6 +64,10 @@ from dexiraft_tpu.profiling import ServeStats, span
 from dexiraft_tpu.serve.buckets import BucketRegistry
 
 EvalFn = Callable[..., Tuple[Any, Any]]
+
+# host bytes the batch-buffer rings of one engine may pin (_batch_buffers):
+# the Sintel eval bucket at batch 32 and inflight 2 holds 1.04 GB of it
+_RING_BYTES = 2 << 30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,6 +233,9 @@ class InferenceEngine:
         # (batch_size, row shape) signature, compiled inside the
         # bucket's expected first-dispatch window
         self._zero_rows: Dict[Tuple[int, ...], Any] = {}
+        # host batch buffers, a ring per bucket in order of last use
+        # (_batch_buffers)
+        self._rings: Dict[Tuple[int, int], collections.deque] = {}
         self._stack_fn = None
 
     # ---- input validation ----------------------------------------------
@@ -304,17 +315,18 @@ class InferenceEngine:
             padders = [InputPadder(it["image1"].shape, mode=mode,
                                    stride=cfg.stride, target=bucket)
                        for _, it in group]
-            im1 = [p.pad(np.asarray(it["image1"], np.float32))[0]
-                   for p, (_, it) in zip(padders, group)]
-            im2 = [p.pad(np.asarray(it["image2"], np.float32))[0]
-                   for p, (_, it) in zip(padders, group)]
-            fill = cfg.batch_size - len(group)
-            if fill:  # tail: replicate the last item up to the batch shape
-                im1 += [im1[-1]] * fill
-                im2 += [im2[-1]] * fill
-                self.stats.pad_frames += fill
-            im1 = np.stack(im1)
-            im2 = np.stack(im2)
+            # one pass over the batch's bytes: each frame is cast, placed
+            # and edge-padded straight into its row of the batch buffer
+            n = len(group)
+            im1, im2 = self._batch_buffers(bucket)
+            for row, (p, (_, it)) in enumerate(zip(padders, group)):
+                p.pad_into(im1[row], it["image1"])
+                p.pad_into(im2[row], it["image2"])
+            if n < cfg.batch_size:
+                # tail: replicate the last item up to the batch shape
+                im1[n:] = im1[n - 1]
+                im2[n:] = im2[n - 1]
+                self.stats.pad_frames += cfg.batch_size - n
 
         inits = [it.get("flow_init") for _, it in group]
         will_fi = cfg.warm_start or any(x is not None for x in inits)
@@ -336,7 +348,7 @@ class InferenceEngine:
             # synchronously before enqueueing — its seconds go to
             # compile_s ONLY (a plain clock read, no span), so
             # dispatch_s and engine:enqueue stay what ServeStats
-            # documents (host pad/stack/put/enqueue time)
+            # documents (host assemble/put/enqueue time)
             t1 = time.perf_counter()
             with (contextlib.nullcontext() if fresh
                   else span("engine:enqueue")) as enqueue:
@@ -383,6 +395,42 @@ class InferenceEngine:
             final_delta=final_delta))
         self.stats.peak_inflight = max(self.stats.peak_inflight,
                                        len(self._inflight))
+
+    def _batch_buffers(self, bucket: Tuple[int, int]):
+        """This batch's two (batch_size, H, W, 3) float32 host buffers.
+
+        The engine owns them: a bucket keeps a ring of `inflight + 1`
+        pairs and a dispatch takes the oldest. `device_put` may still be
+        reading a buffer after it returns (on a TPU the copy is
+        asynchronous; the CPU backend may alias a large aligned array
+        instead of copying it), so a pair is written again only once
+        `inflight` younger batches have been dispatched — and stream()
+        fetches a ticket before it dispatches the `inflight`-th batch
+        after it, run_batch before it returns — by when its own batch's
+        results are on the host and nothing reads its inputs. Reuse is
+        the point: a fresh 170 MB array costs more in page faults than
+        the pass that fills it (PERF.md section 6, PR 41).
+
+        What the rings pin is bounded by `_RING_BYTES` over all buckets:
+        past it the least recently used buckets' rings go (their
+        buffers live on until their transfers are done: the runtime
+        holds them), so a bucket too large for the budget, or one seen
+        rarely among many, gets fresh buffers, which is always safe.
+        """
+        cfg = self.config
+        ring = self._rings.pop(bucket, None) or collections.deque()
+        if len(ring) > cfg.inflight:
+            pair = ring.popleft()
+        else:
+            shape = (cfg.batch_size,) + bucket + (3,)
+            pair = (np.empty(shape, np.float32), np.empty(shape, np.float32))
+        ring.append(pair)
+        self._rings[bucket] = ring  # most recently used last
+        held = sum(2 * len(r) * r[0][0].nbytes for r in self._rings.values())
+        while held > _RING_BYTES:
+            old = self._rings.pop(next(iter(self._rings)))
+            held -= 2 * len(old) * old[0][0].nbytes
+        return pair
 
     def _assemble_fi(self, bucket: Tuple[int, int], inits: List[Any]):
         """The dispatch group's (batch_size, h/8, w/8, 2) flow_init.

@@ -284,8 +284,11 @@ class VideoEngine:
             with win:
                 for i in range(t_frames):
                     t0 = time.perf_counter()
-                    padded = padder.pad(
-                        np.asarray(frames[i], np.float32))[0][None]
+                    # cast, placed and edge-padded in one pass (the pair
+                    # engine's way); a buffer of the frame's own, since
+                    # the put may still read it after it returns
+                    padded = np.empty((1,) + bucket + (3,), np.float32)
+                    padder.pad_into(padded[0], frames[i])
                     feats = self.encode_fn(self.put(padded))
                     if feats_prev is not None:
                         if flow_init is None:

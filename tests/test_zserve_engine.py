@@ -73,6 +73,221 @@ class TestBuckets:
             InputPadder((40, 56, 3), target=(44, 56))    # not stride-aligned
 
 
+def _edge_pad_reference(x, padder):
+    """What the engine fed the model before `pad_into`: the frame as
+    float32, then numpy's own edge pad at the padder's widths."""
+    l, r, t, b = padder._pad
+    return np.pad(np.asarray(x, np.float32), [(t, b), (l, r), (0, 0)],
+                  mode="edge")
+
+
+# (frame, target): no pad; the stride pad; a bucket larger than the next
+# stride multiple, pads on all four sides in sintel mode; a pad one pixel
+# wide (right column and bottom row alone)
+_PAD_CASES = {
+    "no_pad": ((40, 56), None),
+    "stride_pad": ((37, 53), None),
+    "bucket_target": ((37, 53), (48, 72)),
+    "one_pixel": ((39, 55), None),
+}
+
+
+class TestPadInto:
+    @pytest.mark.parametrize("dtype", [np.uint8, np.int32, np.float32,
+                                       np.float64])
+    @pytest.mark.parametrize("case", sorted(_PAD_CASES))
+    @pytest.mark.parametrize("mode", ["sintel", "kitti"])
+    def test_matches_numpy_edge_pad_bit_for_bit(self, mode, case, dtype):
+        hw, target = _PAD_CASES[case]
+        rng = np.random.default_rng(7)
+        x = rng.uniform(0, 255, hw + (3,))
+        if dtype is np.int32:
+            x = x * 1e5  # past float32's 24 bits: the cast must round alike
+        x = x.astype(dtype)
+        p = InputPadder(x.shape, mode=mode, target=target)
+        ref = _edge_pad_reference(x, p)
+        out = np.full(p.padded_shape + (3,), np.nan, np.float32)
+        p.pad_into(out, x)
+        assert out.shape == ref.shape
+        assert out.tobytes() == ref.tobytes()
+        if case == "bucket_target" and mode == "sintel":
+            assert all(p._pad)  # the case covers all four sides
+
+    def test_leading_axes_and_other_dtypes(self):
+        # a batch of frames at once, into a float64 buffer: the
+        # assignment casts to whatever `out` holds
+        x = np.random.default_rng(8).integers(0, 255, (2, 37, 53, 3),
+                                              dtype=np.uint8)
+        p = InputPadder(x.shape, mode="sintel", target=(48, 64))
+        out = np.full((2, 48, 64, 3), np.nan, np.float64)
+        p.pad_into(out, x)
+        np.testing.assert_array_equal(out, p.pad(x.astype(np.float64))[0])
+
+    def test_rejects_a_buffer_of_another_shape(self):
+        p = InputPadder((37, 53, 3), target=(48, 64))
+        with pytest.raises(ValueError, match="padded shape"):
+            p.pad_into(np.empty((40, 56, 3), np.float32),
+                       np.zeros((37, 53, 3), np.uint8))
+
+
+class _Keeps:
+    """An eval_fn that records the batches it is handed and answers
+    with a flow made of its inputs (channel 0 of each frame), so a
+    Result says whose frames were in its row."""
+
+    def __init__(self):
+        self.seen = []
+
+    def flow(self, im1, im2):
+        up = np.stack([im1[..., 0], im2[..., 0]], axis=-1)
+        return up[:, ::8, ::8].copy(), up
+
+    def __call__(self, im1, im2, flow_init=None):
+        self.seen.append((im1, im2))
+        return self.flow(im1, im2)
+
+
+class _AtFetch:
+    """A device future's stand-in: nothing is read before the fetch."""
+
+    def __init__(self, read):
+        self.read = read
+
+    def __array__(self, dtype=None, copy=None):
+        return self.read()
+
+
+class _KeepsUntilFetch(_Keeps):
+    """Keeps its inputs and reads them only when the engine fetches the
+    ticket: what a device does whose host-to-device copy is still
+    reading the buffer after `device_put` returned. A buffer written to
+    between the hand-over and the fetch fails the read."""
+
+    def __call__(self, im1, im2, flow_init=None):
+        self.seen.append((im1, im2))
+        handed = im1.tobytes(), im2.tobytes()
+
+        def read(part):
+            assert (im1.tobytes(), im2.tobytes()) == handed
+            return self.flow(im1, im2)[part]
+
+        return _AtFetch(lambda: read(0)), _AtFetch(lambda: read(1))
+
+
+def _own_flow(item):
+    return np.stack([np.asarray(item["image1"], np.float32)[..., 0],
+                     np.asarray(item["image2"], np.float32)[..., 0]], -1)
+
+
+class TestAssemble:
+    @pytest.mark.parametrize("n", [1, 3])
+    def test_group_shorter_than_the_batch(self, n):
+        # tail rows hold the last item, and count as pad frames
+        items = _items([(37, 53)] * n, seed=3)
+        fn = _Keeps()
+        eng = InferenceEngine(fn, ServeConfig(batch_size=4),
+                              put=lambda batch: batch)
+        out = eng.run_batch([dict(it) for it in items])
+        assert len(out) == n and eng.stats.pad_frames == 4 - n
+        (im1, im2), = fn.seen
+        assert im1.shape == im2.shape == (4, 40, 56, 3)
+        assert im1.dtype == im2.dtype == np.float32
+        p = InputPadder((37, 53, 3), mode="sintel", target=(40, 56))
+        for row in range(4):
+            it = items[min(row, n - 1)]
+            assert (im1[row].tobytes()
+                    == _edge_pad_reference(it["image1"], p).tobytes())
+            assert (im2[row].tobytes()
+                    == _edge_pad_reference(it["image2"], p).tobytes())
+
+    @pytest.mark.parametrize("mode", ["sintel", "kitti"])
+    def test_mixed_frame_sizes_and_dtypes_in_one_bucket(self, mode):
+        geoms = [(40, 56), (44, 60), (36, 52), (48, 64)]
+        items = _items(geoms, seed=4)
+        for it, dt in zip(items, (np.uint8, np.float64, np.int32,
+                                  np.float32)):
+            it["image1"] = it["image1"].astype(dt)
+            it["image2"] = it["image2"].astype(dt)
+        fn = _Keeps()
+        eng = InferenceEngine(fn, ServeConfig(
+            batch_size=4, bucket_multiple=16, mode=mode),
+            put=lambda batch: batch)
+        out = eng.run_batch([dict(it) for it in items])
+        (im1, im2), = fn.seen
+        assert eng.stats.pad_frames == 0
+        for row, it in enumerate(items):
+            p = InputPadder(it["image1"].shape, mode=mode, target=(48, 64))
+            assert (im1[row].tobytes()
+                    == _edge_pad_reference(it["image1"], p).tobytes())
+            assert (im2[row].tobytes()
+                    == _edge_pad_reference(it["image2"], p).tobytes())
+            np.testing.assert_array_equal(out[row].flow_up, _own_flow(it))
+
+    # what the rings may pin: nothing (every batch gets fresh buffers),
+    # one of the two buckets' rings (they evict each other), everything
+    @pytest.mark.parametrize("ring_bytes", [0, 4 * 2 * 2 * 40 * 56 * 12,
+                                            None])
+    @pytest.mark.parametrize("inflight", [1, 2, 3])
+    def test_a_batch_keeps_its_frames_until_it_is_fetched(
+            self, inflight, ring_bytes, monkeypatch):
+        # the buffer-reuse hazard: batch k is read at its fetch, after
+        # the batches behind it in the window were assembled into the
+        # bucket's other buffers. `put` hands the engine's own buffers
+        # through, as a backend that aliases host memory would
+        from dexiraft_tpu.serve import engine as engine_module
+
+        if ring_bytes is not None:
+            monkeypatch.setattr(engine_module, "_RING_BYTES", ring_bytes)
+        geoms = [(37, 53), (30, 41)] * 9  # two buckets, interleaved
+        items = _items(geoms, seed=5)
+        fn = _KeepsUntilFetch()
+        eng = InferenceEngine(fn, ServeConfig(batch_size=2,
+                                              inflight=inflight),
+                              put=lambda batch: batch)
+        got = {r.index: r.flow_up
+               for r in eng.stream(dict(it) for it in items)}
+        assert sorted(got) == list(range(len(items)))
+        assert eng.stats.peak_inflight == inflight
+        for i, it in enumerate(items):
+            np.testing.assert_array_equal(got[i], _own_flow(it))
+        assert len(fn.seen) == eng.stats.batches == 10
+        held = sum(a.nbytes + b.nbytes for ring in eng._rings.values()
+                   for a, b in ring)
+        assert held <= engine_module._RING_BYTES
+        buffers = {id(im1) for im1, _ in fn.seen}
+        if ring_bytes is None:
+            # five batches a bucket through inflight + 1 buffers each
+            assert len(buffers) == 2 * min(5, inflight + 1)
+        elif ring_bytes == 0:
+            assert len(buffers) == 10 and not eng._rings
+
+    def test_assembly_allocates_no_second_batch(self):
+        # one pass: the two batch buffers and nothing else of their size
+        import tracemalloc
+
+        b, hw = 4, (250, 317)
+        rng = np.random.default_rng(6)
+        items = [{"image1": rng.integers(0, 255, hw + (3,), dtype=np.uint8),
+                  "image2": rng.integers(0, 255, hw + (3,), dtype=np.uint8)}
+                 for _ in range(b)]
+
+        def fn(im1, im2, flow_init=None):  # answers without allocating
+            bh, bw = im1.shape[1:3]
+            return (np.broadcast_to(np.float32(0), (b, bh // 8, bw // 8, 2)),
+                    np.broadcast_to(np.float32(0), (b, bh, bw, 2)))
+
+        eng = InferenceEngine(fn, ServeConfig(batch_size=b),
+                              put=lambda batch: batch)
+        buffers = 2 * b * 256 * 320 * 3 * 4
+        tracemalloc.start()
+        try:
+            eng.run_batch(items)
+            peak = tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+        assert buffers <= peak < 1.25 * buffers
+
+
 class TestEngineStream:
     def test_partial_batch_tail_masked(self):
         # 5 frames over 2 buckets at batch 2: tails pad up to the batch
