@@ -16,8 +16,11 @@ TPU-first design choices (vs. the reference's Python loop over CUDA calls):
     iterations compile into ONE on-device graph; `iters` is static.
   * NHWC layouts; under mixed_precision encoders/update run in bf16 while
     the correlation volume stays fp32 (mirrors core/raft.py:134-148).
-  * the correlation pyramid is a pytree threaded through the scan carry —
-    XLA hoists it as loop-invariant.
+  * the correlation pyramid is a pytree the scan reads as a broadcast
+    constant; where a gradient is taken through an all-pairs pyramid the
+    loop hands back its iterations' window cotangents and the levels'
+    gradient is placed once, after the backward loop (ops/corr.py
+    place_once): the scan carries no level-sized sum.
   * coords are stop_gradient'ed at each iteration start, matching the
     reference's per-iteration detach (core/raft.py:170-171).
 """
@@ -34,7 +37,8 @@ from dexiraft_tpu.config import RAFTConfig
 from dexiraft_tpu.models.dexined import DexiNed, stack_edge_maps
 from dexiraft_tpu.models.extractor import BasicEncoder, SmallEncoder
 from dexiraft_tpu.models.update import BasicUpdateBlock, RefineFlow, SmallUpdateBlock
-from dexiraft_tpu.ops.corr import build_corr_pyramid
+from dexiraft_tpu.ops.corr import (CorrPyramid, build_corr_pyramid,
+                                   lookup_centres, place_once)
 from dexiraft_tpu.ops.local_corr import build_local_corr
 from dexiraft_tpu.ops.grid import as_planes, coords_grid, upflow8
 from dexiraft_tpu.ops.upsample import upsample_flow_convex
@@ -59,6 +63,10 @@ class RAFTStep(nn.Module):
     in test mode — the final flow is upsampled ONCE after the scan from
     the carried mask (test_mode returns only the last prediction,
     core/raft.py:194-197).
+
+    ``probe``, the scan's per-iteration input, is None or this iteration's
+    zeros for the lookup's windows (ops/corr.py place_once); with it the
+    step also emits the coordinates its lookup read.
     """
 
     cfg: RAFTConfig
@@ -66,7 +74,7 @@ class RAFTStep(nn.Module):
     emit: bool = True
 
     @nn.compact
-    def __call__(self, carry: Dict[str, Any], _, consts: Dict[str, Any]):
+    def __call__(self, carry: Dict[str, Any], probe, consts: Dict[str, Any]):
         cfg = self.cfg
         if cfg.small:
             update_block = SmallUpdateBlock(hidden_dim=cfg.hidden_dim, dtype=self.dtype)
@@ -78,7 +86,8 @@ class RAFTStep(nn.Module):
         b = pyr.batch // 2 if dual else pyr.batch
         coords0 = coords_grid(b, pyr.ht, pyr.wd)
 
-        coords1 = jax.lax.stop_gradient(carry["coords1"])  # (2B or B, h, w, 2)
+        # (2B or B, h, w, 2)
+        coords1 = looked_up = jax.lax.stop_gradient(carry["coords1"])
         flow = coords1 - coords0[:1]  # the grid broadcasts over both streams
         if cfg.fused_update:
             # fused step (config.fused_update): the lookup and the motion
@@ -90,6 +99,8 @@ class RAFTStep(nn.Module):
                 carry["net"], consts["inp"], None, flow,
                 pyr=pyr, coords=coords1)
         else:
+            # a probe goes with an all-pairs pyramid only (place_once)
+            at = (coords1,) if probe is None else (coords1, probe)
             if cfg.remat_lookup and not cfg.remat:
                 # recompute the lookup in backward instead of storing its
                 # intermediates (the per-iteration hat matrices dominate
@@ -98,10 +109,10 @@ class RAFTStep(nn.Module):
                 # normally; prevent_cse=False matches the full-remat
                 # scan convention (the scan already rules out the CSE
                 # hazard)
-                corr = jax.checkpoint(lambda p, c: p(c),
-                                      prevent_cse=False)(pyr, coords1)
+                corr = jax.checkpoint(lambda p, *at: p(*at),
+                                      prevent_cse=False)(pyr, *at)
             else:
-                corr = pyr(coords1)
+                corr = pyr(*at)
             net, up_mask, delta = update_block(carry["net"], consts["inp"],
                                                corr, flow)
         delta = delta.astype(jnp.float32)
@@ -131,7 +142,9 @@ class RAFTStep(nn.Module):
             return carry, None
 
         prediction = self._predict(cfg, coords1, coords0, up_mask, b)
-        return carry, prediction
+        if probe is None:
+            return carry, prediction
+        return carry, (prediction, lookup_centres(looked_up))
 
     def _predict(self, cfg, coords1, coords0, up_mask, b):
         if cfg.has_edge_stream:
@@ -491,8 +504,22 @@ class RAFT(nn.Module):
         )
         # pin the module name so parameter paths (and thus checkpoints and
         # interop name maps) are identical with and without remat
-        carry, predictions = scan(cfg=cfg, dtype=dtype, emit=emit,
-                                  name="ScanRAFTStep_0")(carry, None, consts)
+        name = "ScanRAFTStep_0"
+        if (test_mode or self.is_initializing()
+                or not isinstance(pyr, CorrPyramid)):
+            carry, predictions = scan(cfg=cfg, dtype=dtype, emit=emit,
+                                      name=name)(carry, None, consts)
+        else:
+            # the train path over an all-pairs pyramid: the same scan as a
+            # function of its parameters, so that a gradient taken through
+            # it places the levels' gradient once (place_once)
+            def refine(pyr, probe, params, carry, inp):
+                _, ys = scan(cfg=cfg, dtype=dtype, emit=emit, parent=None).apply(
+                    {"params": params}, carry, probe, {"pyr": pyr, "inp": inp})
+                return ys if probe is not None else (ys, None)
+
+            return place_once(refine, pyr, self.variables["params"][name],
+                              carry, inp, iters=iters)
 
         if test_mode:
             flow_low = carry["coords1"][:b] - coords0

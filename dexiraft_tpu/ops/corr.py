@@ -13,15 +13,18 @@ columns as major axes. The reference flattens the other way round,
 (B*H*W, H_l, W_l, 1): there the minor
 pair (H_l, W_l) fills (8, 128) register tiles as (48, 128), (24, 128),
 (16, 128), (8, 128) at the chairs crop's 46x62, 23x31, 11x15, 5x7 —
-2.24 GB of HBM for 0.69 GB of values, read twice and its gradient sum
-read and written once in every training iteration. With the queries on
+2.24 GB of HBM for 0.69 GB of values, read twice in every training
+iteration. With the queries on
 the lanes 2852 pads to 2944 (1.03x); the chip's compiler puts the batch on
 the sublanes (`f32[16,46,62,2852]{3,0,2,1:T(8,128)}`: 0.710 GB), so a
 target position is a whole register and the lookup ALIGNS each query's
 window by selects between registers, then weights neighbours by one lerp
 (corr_lookup, _axis_window; on a TPU the Pallas kernels of
-ops/pallas_window.py). docs/perf.md "Correlation memory & precision" has
-the chip's numbers.
+ops/pallas_window.py). A loop of lookups that is differentiated places
+the levels' gradient once, after its backward loop (place_once): the
+loop's iterations hand back window cotangents, 59 MB each, and no
+level-sized array is written, read back or added inside it.
+docs/perf.md "Correlation memory & precision" has the chip's numbers.
 
 This module is the materialized path; the memory-efficient on-demand
 equivalent of the reference's alt_cuda_corr CUDA kernel
@@ -33,7 +36,7 @@ whose transient per-chunk blocks keep the reference's slab form
 from __future__ import annotations
 
 import functools
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 import flax.struct
 import jax
@@ -66,8 +69,9 @@ class CorrPyramid:
         """((H_0, W_0), (H_1, W_1), ...): each level's target extent."""
         return tuple(lvl.shape[1:3] for lvl in self.levels)
 
-    def __call__(self, coords: jax.Array) -> jax.Array:
-        return corr_lookup(self, coords)
+    def __call__(self, coords: jax.Array,
+                 probe: Optional[tuple] = None) -> jax.Array:
+        return corr_lookup(self, coords, probe)
 
 
 def all_pairs_correlation(fmap1: jax.Array, fmap2: jax.Array) -> jax.Array:
@@ -368,6 +372,15 @@ def _axis_window_fwd(vol, center, radius, axis):
     return _axis_window(vol, center, radius, axis), (vol, center)
 
 
+def _axis_placed(g, start, frac, n, size, axis):
+    """The plain form of _axis_window's transpose in vol: g (B, S1, S2, Q)
+    with n - 1 taps on ``axis`` -> ``size`` positions there, float32."""
+    digits = [d[:, None, None, :] for d in _digits(start, size + n)]
+    d_taps = _lerp_transposed(g, frac[:, None, None, :], axis)
+    return jax.lax.slice_in_dim(
+        _shift_out(d_taps, digits, n + size, axis), n, n + size, axis=axis)
+
+
 def _axis_window_bwd(radius, axis, res, g):
     """The mirror image: the cotangent's taps placed back under the same
     masks, so the level's gradient is written once, dense, in the level's
@@ -386,11 +399,7 @@ def _axis_window_bwd(radius, axis, res, g):
     if vol.size == 0:
         d_vol = jnp.zeros(vol.shape, jnp.float32)
     elif interpret is None:
-        digits = [d[:, None, None, :] for d in _digits(start, size + n)]
-        d_taps = _lerp_transposed(g, frac[:, None, None, :], axis)
-        d_vol = jax.lax.slice_in_dim(
-            _shift_out(d_taps, digits, n + size, axis), n, n + size,
-            axis=axis)
+        d_vol = _axis_placed(g, start, frac, n, size, axis)
     else:
         from dexiraft_tpu.ops.pallas_window import place_axis
 
@@ -403,13 +412,24 @@ def _axis_window_bwd(radius, axis, res, g):
 _axis_window.defvjp(_axis_window_fwd, _axis_window_bwd)
 
 
+def lookup_centres(coords: jax.Array) -> jax.Array:
+    """(B, H, W, 2) coordinates as a lookup reads them: (B, 2, H*W)
+    float32, x then y, the queries on the lanes."""
+    b, h, w, _ = coords.shape
+    return jnp.swapaxes(coords.reshape(b, h * w, 2).astype(jnp.float32), 1, 2)
+
+
 @jax.named_scope("corr_lookup")
-def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
+def corr_lookup(pyramid: CorrPyramid, coords: jax.Array,
+                probe: Optional[tuple] = None) -> jax.Array:
     """Sample a (2r+1)^2 window around ``coords / 2^i`` at every level.
 
     coords: (B, H, W, 2) current correspondence estimates in level-0 pixels.
     Returns (B, H, W, num_levels * (2r+1)^2) float32 correlation features.
-    Reference: core/corr.py:29-50.
+    Reference: core/corr.py:29-50. ``probe``, an array (B, 2r+1, 2r+1, H*W)
+    a level, is added to the level's window (y, x, the queries on the
+    lanes) as the kernels leave it: a zero whose cotangent is the window's,
+    in the form the placing kernels take (place_once).
 
     The same bilinear window as interp_window, zero outside the frame, on
     the stored form (B, H_l, W_l, Q), without the hats: the taps sit at
@@ -422,8 +442,9 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
     in fp32; nothing is multiplied by a zero. The gradient with respect to
     the level is the mirror image (a custom_vjp: the cotangent's taps
     placed back under the same masks), written once in the level's own
-    form, so the sum the training scan carries over its iterations is an
-    add over full registers. One algorithm for every shape and backend
+    form. That is a single lookup's gradient; a loop of lookups under
+    place_once leaves this rule unused and places all its iterations'
+    cotangents at once. One algorithm for every shape and backend
     (a log-step shifter, _shift_in); on a TPU its stages run in VMEM
     (ops/pallas_window.py), elsewhere as plain `where`. Timed alone at
     N = 45,632, forward | the level's gradient | build + twelve lookups +
@@ -440,15 +461,110 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array) -> jax.Array:
     b, h, w = pyramid.batch, pyramid.ht, pyramid.wd
     win = 2 * r + 1
 
-    flat = coords.reshape(b, h * w, 2).astype(jnp.float32)
-    cx, cy = flat[..., 0], flat[..., 1]  # (B, Q)
+    centres = lookup_centres(coords.reshape(b, h, w, 2))
+    cx, cy = centres[:, 0], centres[:, 1]  # (B, Q)
     out = []
     for i, vol in enumerate(pyramid.levels):
         rows = _axis_window(vol, cx / (2.0**i), r, 2)
         window = _axis_window(rows, cy / (2.0**i), r, 1)  # (B, y, x, Q)
         if pyramid.scales is not None:
             window = window * pyramid.scales[i]
+        if probe is not None:
+            window = window + probe[i]
         # (B, win_x, win_y, Q): x offset on the slow axis (_window_delta)
         out.append(jnp.swapaxes(window, 1, 2).reshape(b, win * win, h * w))
     out = jnp.concatenate(out, axis=1)  # (B, L*win^2, Q)
     return jnp.swapaxes(out, 1, 2).reshape(b, h, w, -1)
+
+
+def _placed_once(g, cx, cy, radius: int, hl: int, wl: int, mesh):
+    """sum_t of lookup t's gradient with respect to one level: g
+    (T, B, 2r+1, 2r+1, Q) the window cotangents (y, x), cx and cy (T, B, Q)
+    the centres in the level's pixels -> (B, hl, wl, Q) float32. y first,
+    on the nine columns of a window; then x, summed over the stack: on a
+    TPU one kernel that writes each block of the level's gradient once
+    (ops/pallas_window.py place_axis_sum), elsewhere a scan of plain
+    `where`. ``mesh`` is the level's: g, the cotangent of a zero, may not
+    say where it lives."""
+    n = 2 * radius + 2
+    zero = jnp.zeros((g.shape[1], hl, wl, g.shape[-1]), jnp.float32)
+    if zero.size == 0:
+        return zero
+    sy, fy = _window_geometry(cy, radius, hl)
+    sx, fx = _window_geometry(cx, radius, wl)
+    interpret = _kernel_interpret()
+    if interpret is None:
+        def add(total, lookup):
+            g, sy, fy, sx, fx = lookup
+            rows = _axis_placed(g, sy, fy, n, hl, 1)
+            return total + _axis_placed(rows, sx, fx, n, wl, 2), None
+
+        return jax.lax.scan(add, zero, (g, sy, fy, sx, fx))[0]
+    from dexiraft_tpu.ops.pallas_window import place_axis, place_axis_sum
+
+    rows = place_axis(g, sy, fy, n, hl, 1, interpret, mesh)
+    return place_axis_sum(rows, sx, fx, n, wl, 2, interpret, mesh)
+
+
+def place_once(loop: Callable, pyramid, *args, iters: int):
+    """``loop``, ``iters`` lookups into ``pyramid``, with the levels'
+    gradient placed once, after the backward loop.
+
+    loop(pyramid, probe, *args) -> (out, centres). ``probe`` is None or,
+    a level, zeros (iters, B, 2r+1, 2r+1, Q); lookup t takes every level's
+    slice t (corr_lookup's ``probe``: a scan's per-iteration input), and
+    centres (iters, B, 2, Q) are the coordinates it read (lookup_centres),
+    carrying no gradient. Everything the loop differentiates is in
+    ``args``. Returns out.
+
+    Reverse mode sums the cotangent of what a loop only reads over its
+    iterations: with the pyramid a constant of a scan, every backward
+    iteration writes each level's gradient whole (mostly zeros: a query
+    touches 10 of level 0's 62 columns), and the scan's transpose reads it
+    back and adds it to the sum it carries, four level-sized passes an
+    iteration. The placing is linear in the window's cotangent and the
+    coordinates carry no gradient, so d level = sum_t place(g_t; centres_t)
+    can wait: here the loop reads the levels as constants, the probe's
+    cotangent collects g_t, 59 MB a lookup at the chairs crop where level 0
+    alone is 521 MB, and _placed_once places each level's stack as the
+    loop left it. What decides is what can be seen: a CorrPyramid with
+    floating levels, and a gradient being taken (the rule below runs only
+    then; without one the loop runs as it is written). The other arguments'
+    gradients are autodiff's own; remat inside the loop keeps its meaning.
+    A bf16 level's gradient is summed in fp32 and cast once.
+    """
+    if not (isinstance(pyramid, CorrPyramid) and pyramid.scales is None
+            and all(jnp.issubdtype(lvl.dtype, jnp.floating)
+                    for lvl in pyramid.levels)):
+        return loop(pyramid, None, *args)[0]
+    like = jax.tree.structure(pyramid)
+    levels = [(lvl.shape, lvl.dtype, jax.typeof(lvl).sharding.mesh)
+              for lvl in pyramid.levels]
+    radius = pyramid.radius
+
+    @jax.custom_vjp
+    def run(pyramid, *args):
+        return loop(pyramid, None, *args)[0]
+
+    def run_fwd(pyramid, *args):
+        (b, _, _, q), win = levels[0][0], 2 * radius + 1
+        probe = tuple(jnp.zeros((iters, b, win, win, q), jnp.float32)
+                      for _ in levels)
+        out, pullback, centres = jax.vjp(
+            lambda probe, *args: loop(pyramid, probe, *args), probe, *args,
+            has_aux=True)
+        return out, (pullback, centres)
+
+    def run_bwd(res, g):
+        pullback, centres = res
+        d_windows, *d_args = pullback(g)
+        d_levels = []
+        for i, ((_, hl, wl, _), dtype, mesh) in enumerate(levels):
+            d = _placed_once(d_windows[i], centres[:, :, 0] / (2.0**i),
+                             centres[:, :, 1] / (2.0**i), radius, hl, wl,
+                             mesh)
+            d_levels.append(d.astype(dtype))
+        return (jax.tree.unflatten(like, d_levels), *d_args)
+
+    run.defvjp(run_fwd, run_bwd)
+    return run(pyramid, *args)

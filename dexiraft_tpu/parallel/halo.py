@@ -70,7 +70,8 @@ from dexiraft_tpu.models.update import (
     MOTION_ENCODER_CHAIN,
     SEP_CONV_GRU_CHAIN,
 )
-from dexiraft_tpu.ops.corr import build_corr_pyramid, corr_lookup
+from dexiraft_tpu.ops.corr import (build_corr_pyramid, corr_lookup,
+                                   lookup_centres, place_once)
 from dexiraft_tpu.ops.grid import _resize_matrix, coords_grid
 from dexiraft_tpu.ops.losses import MAX_FLOW
 from dexiraft_tpu.ops.upsample import convex_combine
@@ -641,19 +642,21 @@ def _halo_forward(cfg: RAFTConfig, params, batch_stats, im1, im2, *,
     coords0 = _coords_grid_sharded(b_loc, l8, w8, n_seq)
     coords1 = coords0 if flow_init is None else coords0 + flow_init
 
-    def scan_block(up_params, net, coords1, inp, pyr, coords0):
-        def step(carry, _):
+    def refine(pyr, probe, up_params, net, coords1, inp, coords0):
+        # probe: None, or a zero for each lookup's windows, with which a
+        # step also emits the coordinates it looked up (place_once)
+        def step(carry, probe):
             net, coords1 = carry
-            coords1 = jax.lax.stop_gradient(coords1)
+            coords1 = looked_up = jax.lax.stop_gradient(coords1)
             flow = coords1 - coords0
-            corr = corr_lookup(pyr, coords1)
+            corr = corr_lookup(pyr, coords1, probe)
             net, up_mask, delta = update_fwd(up_params, net, inp, corr,
                                              flow, n_seq)
             coords1 = coords1 + delta.astype(jnp.float32)
-            if not emit:
-                return (net, coords1), up_mask
-            flow_up = _upsample_halo(coords1 - coords0, up_mask, n_seq)
-            return (net, coords1), flow_up
+            ys = (_upsample_halo(coords1 - coords0, up_mask, n_seq) if emit
+                  else up_mask)
+            return (net, coords1), (
+                ys, None if probe is None else lookup_centres(looked_up))
 
         if remat_mode == "per_iter":
             step = jax.checkpoint(step, prevent_cse=False)
@@ -661,10 +664,15 @@ def _halo_forward(cfg: RAFTConfig, params, batch_stats, im1, im2, *,
             step = jax.checkpoint(
                 step, prevent_cse=False,
                 policy=jax.checkpoint_policies.dots_saveable)
-        (net, coords1), ys = jax.lax.scan(
-            step, (net, coords1), None, length=iters,
+        (net, coords1), (ys, centres) = jax.lax.scan(
+            step, (net, coords1), probe, length=iters,
             unroll=max(1, min(unroll, iters)))
-        return coords1, ys
+        return (coords1, ys), centres
+
+    def scan_block(up_params, net, coords1, inp, pyr, coords0):
+        # a gradient taken through the loop places the levels' once
+        return place_once(refine, pyr, up_params, net, coords1, inp, coords0,
+                          iters=iters)
 
     coords1, ys = _run_block(scan_block, params["ScanRAFTStep_0"],
                              param_dims["ScanRAFTStep_0"], n_fsdp,

@@ -214,22 +214,26 @@ class SpecLayout:
         """shard_map specs of the lookup's alignment kernels
         (ops/pallas_window.py): for an array in the order the chip stores
         a level, (S1, S2, B, H*W), and for its per-query (B, H*W)
-        operands. The batch over 'data' and the queries (whole image
-        rows) over 'seq', each where the mesh has the axis partitioned
-        automatically and it divides the extent; the two target axes stay
-        whole. None where nothing is left to split: no mesh, one chip, or
-        inside a shard_map that already did."""
+        operands; a stack of lookups leads both with its own axis,
+        (T, S1, S2, B, H*W) and (T, B, H*W). The batch over 'data' and
+        the queries (whole image rows) over 'seq', each where the mesh
+        has the axis partitioned automatically and it divides the extent;
+        the two target axes and the stack stay whole. None where nothing
+        is left to split: no mesh, one chip, or inside a shard_map that
+        already did."""
         auto = {name: size for name, size, kind in zip(
             mesh.axis_names, mesh.axis_sizes, mesh.axis_types)
             if kind == AxisType.Auto and size > 1}
-        entry = [None, None]
-        for axis, extent in ((self.data_axis, shape[2]),
-                             (self.seq_axis, shape[3])):
+        split = []
+        for axis, extent in ((self.data_axis, shape[-2]),
+                             (self.seq_axis, shape[-1])):
             ways = auto.get(axis)
-            entry.append(axis if ways and extent % ways == 0 else None)
-        if not any(entry):
+            split.append(axis if ways and extent % ways == 0 else None)
+        if not any(split):
             return None
-        return PartitionSpec(*entry), PartitionSpec(*entry[2:])
+        stack = [None] * (len(shape) - 4)
+        return (PartitionSpec(*stack, None, None, *split),
+                PartitionSpec(*stack, *split))
 
     # ---- mesh shape queries -------------------------------------------
 

@@ -541,17 +541,57 @@ def _tiled_arrays(text):
 
 def _twelve_lookups(f1, f2, coords, weight):
     """What the train step's scan does with `consts["pyr"]`: the build,
-    twelve lookups under remat, a weighted sum."""
-    from dexiraft_tpu.ops.corr import build_corr_pyramid
+    twelve lookups under remat, a weighted sum; like the model's, the loop
+    runs under `place_once`."""
+    from dexiraft_tpu.ops.corr import (build_corr_pyramid, lookup_centres,
+                                       place_once)
+
+    def loop(pyr, probe, coords, weight):
+        def body(shift, probe):
+            at = coords + shift
+            out = jax.checkpoint(lambda p, c, z: p(c, z))(pyr, at, probe)
+            step = 0.01 * jax.lax.stop_gradient(jnp.mean(out))
+            return shift + step, (jnp.sum(out * weight), lookup_centres(at))
+
+        return jax.lax.scan(body, jnp.float32(0), probe, length=12)[1]
 
     pyr = build_corr_pyramid(f1, f2, LEVELS, RADIUS)
+    return jnp.sum(place_once(loop, pyr, coords, weight, iters=12))
 
-    def body(shift, _):
-        out = jax.checkpoint(lambda p, c: p(c))(pyr, coords + shift)
-        step = 0.01 * jax.lax.stop_gradient(jnp.mean(out))
-        return shift + step, jnp.sum(out * weight)
 
-    return jnp.sum(jax.lax.scan(body, jnp.float32(0), None, length=12)[1])
+def _while_bodies(text):
+    """Each `while` body of a compiled module as (the body's text, its
+    instructions as (name, result type, opcode)), in the module's order."""
+    out = []
+    for body in re.findall(r" while\(.*?body=%?([\w.\-]+)", text):
+        block = text.split(f"\n%{body} (")[1].split("\n}\n")[0]
+        out.append((block, re.findall(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\(", block, re.M)))
+    return out
+
+
+def _arrays_of(kind, dims):
+    """The array types in a result type whose extents are ``dims`` in any
+    order (a kernel takes a level as `[46,62,16,2852]`)."""
+    return [m for m in re.findall(r"\w+\[([\d,]+)\]", kind)
+            if sorted(int(d) for d in m.split(",") if int(d) > 1)
+            == sorted(dims)]
+
+
+def _backward_loop_holds_only_the_level(text, level0):
+    """The backward `while` body (the one whose instructions are transposes
+    of the forward's) holds no array of level 0's extent but the level
+    itself, read from the loop's state and bitcast for the kernel: no
+    gradient of it is written, read back or added inside the loop."""
+    backward = [loop for block, loop in _while_bodies(text)
+                if "transpose(jvp" in block]
+    assert len(backward) == 1, len(backward)
+    made = [f"{name} {kind} {op}" for name, kind, op in backward[0]
+            if _arrays_of(kind, level0)
+            and op not in ("parameter", "get-tuple-element", "bitcast",
+                           "tuple")]  # the loop's state, in and out
+    assert not made, made
+    return backward[0]
 
 
 @pytest.fixture
@@ -577,9 +617,14 @@ def test_corr_pyramid_is_lane_dense_for_v5e(chip, lookup_on_the_chip):
     sublanes). Since PR 34 the lookup is the alignment kernels
     (ops/pallas_window.py), which take a level as `f32[46,62,16,2852]` in
     the default layout: the same bytes in the same order, so no copy of a
-    level stands between the two, and the temporaries read 1.51 GB (the
-    four levels 0.71, the carried sum 0.71, the build's operands; with the
-    dense hats 1.73). The limit is 1.25 x that reading."""
+    level stands between the two. Since PR 42 the loop runs under
+    `place_once`: the backward scan hands back twelve window cotangents a
+    level (`f32[12,16,9,9,2852]` four times, 0.71 GB: what the carried sum
+    was) and holds no level-sized array but the level; after it a level's
+    gradient is two kernels on its stack as the loop left it, the second
+    writing each block once. The temporaries read 1.58 GB (with the
+    carried sum 1.51, with the dense hats 1.73). The limit is 1.25 x that
+    reading."""
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
@@ -602,11 +647,14 @@ def test_corr_pyramid_is_lane_dense_for_v5e(chip, lookup_on_the_chip):
         tuple(s) for s in level_dims}, sorted(levels)
     padded = {k: round(v[2], 2) for k, v in levels.items() if v[2] > 1.15}
     assert not padded, padded
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 1.51e9
-    # the forward scan's x and y alignment of four levels, and their
-    # mirror images in the backward scan (whose forward is dead here: the
-    # lookup is linear in the level)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 1.58e9
+    # the forward scan's x and y alignment of four levels, and after the
+    # backward scan (whose forward is dead here: the lookup is linear in
+    # the level) the stack placed along y and summed along x, a level each
     assert text.count("tpu_custom_call") == 16
+    assert len(re.findall(r"%corr_window_place_sum[\w.]* = ", text)) == LEVELS
+    loop = _backward_loop_holds_only_the_level(text, level_dims[0])
+    assert not [name for name, _, op in loop if op == "custom-call"]
     # a level reaches its kernel as a bitcast: no copy the size of level 0
     copies = [m for m in re.findall(r"= f32\[([\d,]+)\]\S* copy\(", text)
               if sorted(int(d) for d in m.split(",")) == sorted(level_dims[0])]
@@ -646,7 +694,9 @@ def test_lookup_kernels_stay_on_their_chip_under_a_data_mesh(
     """`v5-train-chairs-dp4`'s share of the same function: a global batch
     of 64 (both streams of 32 pairs) over four chips. The partitioner
     cannot split a kernel; the call names its own axes (`_per_chip`), so
-    every chip aligns its 16 rows and no level is gathered."""
+    every chip aligns its 16 rows and no level is gathered; the stack of
+    window cotangents stays split by its batch with the iterations whole
+    on every chip, so placing it moves nothing between chips."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
 
@@ -666,6 +716,18 @@ def test_lookup_kernels_stay_on_their_chip_under_a_data_mesh(
     assert "all-gather" not in text
     assert f"f32[{h},{w},16,{h * w}]" in text      # a chip's rows of level 0
     assert f"f32[{h},{w},64,{h * w}]" not in text
+    assert f"f32[12,16,9,9,{h * w}]" in text       # and of a level's stack
+    assert f"f32[12,64,9,9,{h * w}]" not in text
+    # what crosses chips is the loop's scalar mean; nothing the size of a
+    # level's window cotangent (16 x 81 x 2852), let alone of a stack or a
+    # level
+    moved = [line.strip()[:160] for line in text.splitlines() if re.search(
+        r" (all-reduce|all-to-all|collective-permute|reduce-scatter)"
+        r"(-start)?\(", line) and any(
+            _elements(kind) >= 16 * 81 * h * w
+            for kind in re.findall(r"\w+\[[\d,]+\]", line.split("(")[0]))]
+    assert not moved, moved
+    _backward_loop_holds_only_the_level(text, [16, h, w, h * w])
 
 
 def test_v5_train_step_compiles_with_the_lookup_kernels_and_fits_the_chip(
@@ -674,9 +736,11 @@ def test_v5_train_step_compiles_with_the_lookup_kernels_and_fits_the_chip(
     12 iterations, bf16, every iteration recomputed, `corr_impl=allpairs`)
     with the lookup it takes on the chip; `benchmarks/compile_check.py`
     sees the CPU backend and compiles the plain form. Per level the x and
-    the y alignment, forward, recomputed and mirrored: 24 Mosaic calls.
-    The compiler counts 8.74 GB of temporaries beside 0.62 GB of
-    arguments (with the dense hats 8.94; the chip reads a peak of 9.67)."""
+    the y alignment, forward and recomputed, and after the backward loop
+    the stack placed along y and summed along x: 24 Mosaic calls, and no
+    level-sized array in the backward loop but the levels. The compiler
+    counts 9.41 GB of temporaries beside 0.62 GB of arguments (8.74
+    with the gradient summed inside the loop, with the dense hats 8.94)."""
     import os.path as osp
     import sys
 
@@ -707,7 +771,13 @@ def test_v5_train_step_compiles_with_the_lookup_kernels_and_fits_the_chip(
             state, batch).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 24
+    assert len(re.findall(r"%corr_window_place_sum[\w.]* = ", text)) == 4
+    loop = _backward_loop_holds_only_the_level(text, [16, 46, 62, 46 * 62])
+    assert sum(op == "custom-call" and name.startswith("corr_window_align")
+               for name, _, op in loop) == 8
+    assert not [name for name, _, _ in loop
+                if name.startswith("corr_window_place")]
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
-    assert memory.temp_size_in_bytes < 1.1 * 8.74e9
+    assert memory.temp_size_in_bytes < 1.1 * 9.41e9
