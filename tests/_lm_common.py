@@ -2,13 +2,18 @@
 the four architectures, a packed batch and seeded weights of O(1)
 scale."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from dexiraft_tpu.config import (TrainConfig, evabyte_toy, kanana2_toy,
                                  lfm2_8b_a1b_toy, trinity_mini_toy)
+from dexiraft_tpu.interop import lm_reference as ref
 from dexiraft_tpu.train.family import family_of
+
+from _models import as_one_program
 
 
 def packed_batch(cfg, rows=2, seed=0):
@@ -30,15 +35,22 @@ def packed_batch(cfg, rows=2, seed=0):
             (("tokens", tokens), ("positions", pos), ("segment_ids", seg))}
 
 
+@functools.lru_cache(maxsize=None)
 def seeded(cfg, precision="fp32", remat="none", seed=1, scale=5.0):
     """(family, params, batch_stats): matrices scaled up from the 0.02
     init so that every path carries signal at toy widths (evabyte's
-    0.01275 to the same size)."""
+    0.01275 to the same size). Kept on its arguments: do not write into
+    the trees."""
     family = family_of(cfg, TrainConfig(precision=precision, remat=remat))
-    params, stats = family.init(jax.random.PRNGKey(seed))
+    params, stats = jax.jit(family.init)(jax.random.PRNGKey(seed))
     scale = scale * 0.02 / cfg.init_std
     params = jax.tree.map(lambda p: p * scale if p.ndim > 1 else p, params)
     return family, params, stats
+
+
+# (params, batch, cfg, **share): eager it is a few thousand dispatches,
+# each with a compile of its own
+reference_loss_and_grads = as_one_program(ref.loss_and_grads)
 
 
 def rel(a, b):

@@ -37,8 +37,8 @@ class TestContextParallelCorr:
     def test_matches_unsharded(self):
         f1, f2, coords = _fmaps(jax.random.PRNGKey(0))
         mesh = make_mesh_2d(2, 4)
-        out = context_parallel_corr(f1, f2, coords, mesh,
-                                    num_levels=2, radius=3)
+        out = jax.jit(lambda a, b, c: context_parallel_corr(
+            a, b, c, mesh, num_levels=2, radius=3))(f1, f2, coords)
         pyr = build_corr_pyramid(f1, f2, num_levels=2, radius=3)
         ref = corr_lookup(pyr, coords)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -63,8 +63,8 @@ class TestRingCorrLookup:
         partition across blocks."""
         f1, f2, coords = _fmaps(jax.random.PRNGKey(2))
         mesh = make_mesh_2d(2, 4)  # H=16 over 4 ring chips -> blocks of 4
-        out = ring_corr_lookup(f1, f2, coords, mesh,
-                               num_levels=3, radius=3)
+        out = jax.jit(lambda a, b, c: ring_corr_lookup(
+            a, b, c, mesh, num_levels=3, radius=3))(f1, f2, coords)
         pyr = build_corr_pyramid(f1, f2, num_levels=3, radius=3)
         ref = corr_lookup(pyr, coords)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

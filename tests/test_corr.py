@@ -2,10 +2,16 @@
 CorrBlock semantics (core/corr.py:12-60), re-implemented here in torch.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
-from dexiraft_tpu.ops import build_corr_pyramid, corr_lookup
+from _models import as_one_program
+from dexiraft_tpu import ops
+from dexiraft_tpu.ops import corr_lookup
+
+build_corr_pyramid = as_one_program(ops.build_corr_pyramid)
 
 torch = pytest.importorskip("torch")
 import torch.nn.functional as F  # noqa: E402
@@ -188,6 +194,27 @@ def _oracle_lookup(vols, coords, radius):
     return jnp.concatenate(out, axis=-1)
 
 
+@functools.lru_cache(maxsize=None)
+def _oracle_programs(radius):
+    """(lookup from the feature maps, lookup from stored volumes, the
+    gradient of the first under a weight) of the oracle, jitted once a
+    radius: the cases of one shape (a dtype and a path each) share the
+    compiled programs."""
+    import jax
+    import jax.numpy as jnp
+
+    def lookup(f1, f2, coords):
+        return _oracle_lookup(_oracle_volumes(f1, f2, 4), coords, radius)
+
+    def stored(vols, coords):
+        return _oracle_lookup(vols, coords, radius)
+
+    def weighted(f1, f2, coords, weight):
+        return jnp.sum(lookup(f1, f2, coords) * weight)
+
+    return jax.jit(lookup), jax.jit(stored), jax.jit(jax.grad(weighted, (0, 1)))
+
+
 def _probe_coords(rng, b, h, w):
     """Centres inside the frame, exactly on its border pixels, between
     the last pixel and the frame's edge, and wholly outside (every tap of
@@ -325,9 +352,7 @@ def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype, radius,
                                  dtype=corr_dtype)
         return corr_lookup(pyr, coords)
 
-    @jax.jit
-    def oracle(f1, f2):
-        return _oracle_lookup(_oracle_volumes(f1, f2, 4), coords, radius)
+    oracle, oracle_stored, grad_oracle = _oracle_programs(radius)
 
     # one pyramid for the lookup and for the stored values read below: a
     # second build may round a product at a bf16 boundary the other way
@@ -338,7 +363,7 @@ def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype, radius,
     assert got.shape == (b, h, w, 4 * win2) and got.dtype == np.float32
 
     if corr_dtype == "fp32":
-        want = np.asarray(oracle(f1, f2))
+        want = np.asarray(oracle(f1, f2, coords))
     else:  # the stored values, relaid to the oracle's one slab per query
         stored = []
         for i, (lvl, (hl, wl)) in enumerate(zip(pyr.levels, pyr.level_shapes)):
@@ -347,10 +372,9 @@ def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype, radius,
                 v = v * np.float32(pyr.scales[i])
             stored.append(jnp.asarray(
                 np.moveaxis(v, -1, 1).reshape(b * h * w, hl, wl)))
-        want = np.asarray(jax.jit(
-            lambda vols: _oracle_lookup(vols, coords, radius))(stored))
+        want = np.asarray(oracle_stored(stored, coords))
         # and the stored values are the oracle's, rounded once
-        full = np.asarray(_oracle_volumes(f1, f2, 1)[0])
+        full = np.asarray(as_one_program(_oracle_volumes)(f1, f2, 1)[0])
         step = {"bf16": 2.0**-8 * np.abs(full).max(),
                 "int8": np.abs(full).max() / 127 * 0.51}[corr_dtype]
         assert np.abs(np.asarray(stored[0]) - full).max() <= step + 1e-5
@@ -361,9 +385,7 @@ def test_stored_pyramid_lookup_and_grad_match_oracle(shape, corr_dtype, radius,
         return
     grad = jax.jit(jax.grad(
         lambda a, c: jnp.sum(ours(a, c) * weight), (0, 1)))
-    grad_oracle = jax.jit(jax.grad(
-        lambda a, c: jnp.sum(oracle(a, c) * weight), (0, 1)))
-    for g, want_g in zip(grad(f1, f2), grad_oracle(f1, f2)):
+    for g, want_g in zip(grad(f1, f2), grad_oracle(f1, f2, coords, weight)):
         g, want_g = np.asarray(g), np.asarray(want_g)
         scale = np.abs(want_g).max()
         assert scale > 0.1

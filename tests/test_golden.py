@@ -8,13 +8,12 @@ while still catching any real change to the forward semantics (a wrong
 window ordering, a dropped stream, a changed update rule all shift
 these sums by orders more than 1e-2)."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _models import init_raft, jit_apply
 from dexiraft_tpu.config import raft_v1, raft_v2, raft_v5
-from dexiraft_tpu.models.raft import RAFT
 
 GOLDEN = {
     # name: (|flow_up| sum, |flow_low| sum) at iters=4, 48x64 ramp input.
@@ -33,16 +32,13 @@ GOLDEN = {
 
 
 def _forward(cfg, with_edges):
-    model = RAFT(cfg)
+    model, v = init_raft(cfg, 48, 64, with_edges)
     img = jnp.asarray(
         np.linspace(0, 255, 1 * 48 * 64 * 3, dtype=np.float32)
         .reshape(1, 48, 64, 3))
     img2 = img[:, :, ::-1, :]
     kw = dict(edges1=img / 2, edges2=img2 / 2) if with_edges else {}
-    v = model.init(jax.random.PRNGKey(0), img, img2, iters=1,
-                   train=False, **kw)
-    low, up = model.apply(v, img, img2, iters=4, train=False,
-                          test_mode=True, **kw)
+    low, up = jit_apply(model)(v, img, img2, iters=4, test_mode=True, **kw)
     return float(jnp.sum(jnp.abs(up))), float(jnp.sum(jnp.abs(low)))
 
 

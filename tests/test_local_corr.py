@@ -101,15 +101,12 @@ class TestGradients:
 
 class TestRAFTIntegration:
     def test_raft_local_forward(self):
+        from _models import init_raft, jit_apply
         from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
 
-        cfg = raft_v1(small=True, corr_impl="local")
-        model = RAFT(cfg)
-        img = jnp.zeros((1, 64, 64, 3), jnp.float32)
-        variables = model.init(jax.random.PRNGKey(0), img, img, iters=1, train=False)
+        model, variables = init_raft(raft_v1(small=True, corr_impl="local"))
         rng = jax.random.PRNGKey(1)
         im1 = jax.random.uniform(rng, (1, 64, 64, 3), jnp.float32, 0, 255)
-        preds = model.apply(variables, im1, im1, iters=2, train=False)
+        preds = jit_apply(model)(variables, im1, im1, iters=2)
         assert preds.shape == (2, 1, 64, 64, 2)
         assert np.isfinite(np.asarray(preds)).all()

@@ -15,24 +15,24 @@ class TestRemat:
         import jax
         import jax.numpy as jnp
 
+        from _models import init_raft
         from dexiraft_tpu.config import raft_v1
         from dexiraft_tpu.models.raft import RAFT
 
         img = jax.random.uniform(jax.random.PRNGKey(1), (1, 64, 64, 3),
                                  jnp.float32, 0, 255)
+        # the flag chooses the backward program, not the tree
+        _, variables = init_raft(raft_v1(small=True))
         outs = {}
         for flag in (False, True):
-            cfg = raft_v1(small=True, **{kwarg: flag})
-            model = RAFT(cfg)
-            variables = model.init(jax.random.PRNGKey(0), img, img,
-                                   iters=1, train=False)
+            model = RAFT(raft_v1(small=True, **{kwarg: flag}))
 
             def loss(v):
                 preds = model.apply(v, img, img, iters=3, train=False)
                 return jnp.sum(preds ** 2)
 
-            outs[flag] = (float(loss(variables)),
-                          jax.tree.leaves(jax.grad(loss)(variables)))
+            value, grads = jax.jit(jax.value_and_grad(loss))(variables)
+            outs[flag] = (float(value), jax.tree.leaves(grads))
         np.testing.assert_allclose(outs[True][0], outs[False][0], rtol=1e-5)
         # recompute reorders fp32 fusions; conv biases directly followed
         # by InstanceNorm have a TRUE gradient of zero (the norm subtracts
@@ -53,21 +53,20 @@ class TestFreezeBN:
         import jax
         import jax.numpy as jnp
 
+        from _models import init_raft, jit_apply
         from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
 
-        cfg = raft_v1()  # full model: cnet uses batch norm
-        model = RAFT(cfg)
+        # full model: cnet uses batch norm
+        model, variables = init_raft(raft_v1())
+        forward = jit_apply(model)
         img = jax.random.uniform(jax.random.PRNGKey(0), (1, 64, 64, 3),
                                  jnp.float32, 0, 255)
-        variables = model.init(jax.random.PRNGKey(1), img, img,
-                               iters=1, train=False)
         stats0 = variables["batch_stats"]
 
         def run(freeze):
-            _, mut = model.apply(
+            _, mut = forward(
                 variables, img, img, iters=1, train=True, freeze_bn=freeze,
-                mutable=["batch_stats"])
+                mutable=("batch_stats",))
             return mut["batch_stats"]
 
         frozen = run(True)

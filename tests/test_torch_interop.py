@@ -107,9 +107,9 @@ def _raft_parity_case(torch_model, cfg, *, small=False, seed=1, tol=5e-3):
     frames large enough that the level-3 volume is >= 2x2; at 1x1 the
     REFERENCE's grid_sample normalization divides by zero
     (core/utils/utils.py:64-65) and emits NaN."""
-    import jax
     import jax.numpy as jnp
 
+    from _models import jit_apply, raft_shapes
     from dexiraft_tpu.interop.torch_convert import (
         convert_raft_state_dict,
         verify_against,
@@ -121,11 +121,7 @@ def _raft_parity_case(torch_model, cfg, *, small=False, seed=1, tol=5e-3):
 
     variables = convert_raft_state_dict(torch_model.state_dict(), small=small)
     jm = RAFT(cfg)
-    template = jax.eval_shape(
-        lambda: jm.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 128, 160, 3)),
-                        jnp.zeros((1, 128, 160, 3)), iters=1, train=False))
-    verify_against(template, variables)
+    verify_against(raft_shapes(cfg, 128, 160), variables)
 
     rng = np.random.default_rng(seed)
     im1 = rng.uniform(0, 255, (1, 128, 160, 3)).astype(np.float32)
@@ -136,8 +132,8 @@ def _raft_parity_case(torch_model, cfg, *, small=False, seed=1, tol=5e-3):
             torch.from_numpy(im1.transpose(0, 3, 1, 2)),
             torch.from_numpy(im2.transpose(0, 3, 1, 2)),
             iters=4, test_mode=True)
-    j_low, j_up = jm.apply(variables, jnp.asarray(im1), jnp.asarray(im2),
-                           iters=4, train=False, test_mode=True)
+    j_low, j_up = jit_apply(jm)(variables, jnp.asarray(im1), jnp.asarray(im2),
+                                iters=4, test_mode=True)
 
     np.testing.assert_allclose(
         np.asarray(j_low), t_low.numpy().transpose(0, 2, 3, 1),

@@ -1,6 +1,8 @@
 """Training layer: schedule parity vs torch, step convergence, DP sharding,
 checkpoint round-trip and curriculum partial restore."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,23 @@ from dexiraft_tpu.train.state import param_count
 
 SMALL = raft_v1(small=True)
 TC = TrainConfig(num_steps=200, batch_size=2, iters=2, image_size=(64, 64), lr=1e-4)
+
+
+@functools.lru_cache(maxsize=None)
+def plain_step():
+    """SMALL's step under TC, compiled once for the tests that take it."""
+    return make_train_step(SMALL, TC)
+
+
+@functools.lru_cache(maxsize=None)
+def _kept_state(tc):
+    return create_state(jax.random.key(0), SMALL, tc)
+
+
+def fresh_state(tc=TC):
+    """`create_state(key(0), SMALL, tc)`, one init a configuration: a
+    copy, since a step donates the state it is given."""
+    return jax.tree.map(jnp.copy, _kept_state(tc))
 
 
 def synthetic_batch(rng, batch=2, size=(64, 64)):
@@ -58,8 +77,8 @@ class TestOneCycle:
 
 class TestTrainStep:
     def test_loss_decreases(self):
-        state = create_state(jax.random.key(0), SMALL, TC)
-        step = make_train_step(SMALL, TC)
+        state = fresh_state()
+        step = plain_step()
         batch = synthetic_batch(np.random.default_rng(0))
         losses = []
         for _ in range(8):
@@ -70,15 +89,15 @@ class TestTrainStep:
         assert int(state.step) == 8
 
     def test_metrics_keys_and_lr(self):
-        state = create_state(jax.random.key(0), SMALL, TC)
-        step = make_train_step(SMALL, TC)
+        state = fresh_state()
+        step = plain_step()
         _, metrics = step(state, synthetic_batch(np.random.default_rng(1)))
         for k in ("epe", "1px", "3px", "5px", "loss", "lr"):
             assert k in metrics
         assert float(metrics["lr"]) == pytest.approx(float(onecycle_lr(TC.lr, TC.num_steps + 100)(0)))
 
     def test_param_count_nonzero(self):
-        state = create_state(jax.random.key(0), SMALL, TC)
+        state = fresh_state()
         assert param_count(state.params) > 900_000  # small RAFT ~1M params
 
 
@@ -89,11 +108,11 @@ class TestShardedStep:
         tc = TrainConfig(num_steps=200, batch_size=8, iters=2, image_size=(64, 64), lr=1e-4)
         batch = synthetic_batch(np.random.default_rng(2), batch=8)
 
-        state_a = create_state(jax.random.key(0), SMALL, tc)
+        state_a = fresh_state(tc)
         step_single = make_train_step(SMALL, tc)
         state_a, m_single = step_single(state_a, batch)
 
-        state_b = create_state(jax.random.key(0), SMALL, tc)
+        state_b = fresh_state(tc)
         step_dp = make_train_step(SMALL, tc, mesh=mesh)
         state_b, m_dp = step_dp(state_b, shard_batch(batch, mesh))
 
@@ -116,8 +135,8 @@ class TestCheckpoint:
             save_checkpoint,
         )
 
-        state = create_state(jax.random.key(0), SMALL, TC)
-        step = make_train_step(SMALL, TC)
+        state = fresh_state()
+        step = plain_step()
         state, _ = step(state, synthetic_batch(np.random.default_rng(3)))
         save_checkpoint(str(tmp_path / "ck"), state)
 
@@ -143,8 +162,8 @@ class TestStateFiniteSignal:
     state_finite is computed on the new state inside the step."""
 
     def test_healthy_step_reports_finite(self):
-        state = create_state(jax.random.key(0), SMALL, TC)
-        step = make_train_step(SMALL, TC)
+        state = fresh_state()
+        step = plain_step()
         _, metrics = step(state, synthetic_batch(np.random.default_rng(0)))
         assert "state_finite" in metrics
         assert bool(metrics["state_finite"])
@@ -153,8 +172,8 @@ class TestStateFiniteSignal:
         """Inf in the optimizer's moments: the loss (pre-update params)
         stays finite, but the update poisons params — exactly the blind
         spot a loss-only guard has."""
-        state = create_state(jax.random.key(0), SMALL, TC)
-        step = make_train_step(SMALL, TC)
+        state = fresh_state()
+        step = plain_step()
         state, _ = step(state, synthetic_batch(np.random.default_rng(0)))
 
         poisoned_opt = jax.tree.map(
@@ -185,37 +204,27 @@ class TestEdgeSumFusion:
         image pair and the edge-image pair are summed before the loss."""
         import dataclasses
 
-        from dexiraft_tpu.train.state import create_state
-        from dexiraft_tpu.train.step import make_train_step
-
         tc = dataclasses.replace(TC, edge_sum_fusion=True)
         rng = np.random.default_rng(0)
         batch = synthetic_batch(rng)
         batch["edges1"] = batch["image1"] * 0.5
         batch["edges2"] = batch["image2"] * 0.5
 
-        state = create_state(jax.random.key(0), SMALL, tc)
+        state = fresh_state(tc)
         step = make_train_step(SMALL, tc)
         state2, m = step(state, batch)
         assert np.isfinite(float(m["loss"]))
 
-        plain_step = make_train_step(SMALL, TC)
-        plain_state = create_state(jax.random.key(0), SMALL, TC)
-        _, m_plain = plain_step(plain_state, {k: v for k, v in batch.items()
-                                              if not k.startswith("edges")})
+        _, m_plain = plain_step()(fresh_state(), {
+            k: v for k, v in batch.items() if not k.startswith("edges")})
         # summed fusion must actually change the loss
         assert abs(float(m["loss"]) - float(m_plain["loss"])) > 1e-6
 
     def test_missing_edges_raises(self):
         import dataclasses
 
-        import pytest
-
-        from dexiraft_tpu.train.state import create_state
-        from dexiraft_tpu.train.step import make_train_step
-
         tc = dataclasses.replace(TC, edge_sum_fusion=True)
-        state = create_state(jax.random.key(0), SMALL, tc)
+        state = fresh_state(tc)
         step = make_train_step(SMALL, tc)
         with pytest.raises(ValueError, match="edge_sum_fusion"):
             step(state, synthetic_batch(np.random.default_rng(1)))

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _models import init_module
 from dexiraft_tpu.ops import lm_attention as la
 
 S, HEADS, D_QK, D_V = 512, 2, 192, 128
@@ -82,10 +83,9 @@ def _rel(a, b):
 
 
 def _out_and_grads(fn, q, k, v, w):
-    out = fn(q, k, v)
-    grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
-                     argnums=(0, 1, 2))(q, k, v)
-    return (out,) + grads
+    return jax.jit(lambda *a: (fn(*a),) + jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+        argnums=(0, 1, 2))(*a))(q, k, v)
 
 
 def _assert_kernel_matches_the_xla_path(layout, dtype):
@@ -142,7 +142,7 @@ def test_latent_attention_on_the_kernel_matches_the_reference(
     w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
     module = attention.LatentAttention(cfg=cfg, dtype=jnp.float32,
                                        init_std=0.2)
-    params = module.init(jax.random.PRNGKey(0), x, pos, seg)["params"]
+    params = init_module(module, x, pos, seg)["params"]
 
     def ours(p, x):
         return jnp.sum(module.apply({"params": p}, x, pos, seg) * w)
@@ -152,8 +152,8 @@ def test_latent_attention_on_the_kernel_matches_the_reference(
             return jnp.sum(ref.attention(p, x[0], pos[0], seg[0], cfg,
                                          HEADS) * w[0])
 
-    got = jax.value_and_grad(ours, argnums=(0, 1))(params, x)
-    want = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
+    got = jax.jit(jax.value_and_grad(ours, argnums=(0, 1)))(params, x)
+    want = jax.jit(jax.value_and_grad(plain, argnums=(0, 1)))(params, x)
     assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got[1])[0],
                             jax.tree.leaves(want[1])):
@@ -190,7 +190,7 @@ def test_gated_attention_on_the_kernel_matches_the_reference(
     w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
     module = attention.GatedAttention(cfg=cfg, window=window,
                                       dtype=jnp.float32, init_std=0.2)
-    params = module.init(jax.random.PRNGKey(0), x, pos, seg)["params"]
+    params = init_module(module, x, pos, seg)["params"]
 
     def ours(p, x):
         return jnp.sum(module.apply({"params": p}, x, pos, seg) * w)
@@ -200,8 +200,8 @@ def test_gated_attention_on_the_kernel_matches_the_reference(
             return jnp.sum(ref.gated_attention(p, x[0], pos[0], seg[0], cfg,
                                                4, 1, window) * w[0])
 
-    got = jax.value_and_grad(ours, argnums=(0, 1))(params, x)
-    want = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
+    got = jax.jit(jax.value_and_grad(ours, argnums=(0, 1)))(params, x)
+    want = jax.jit(jax.value_and_grad(plain, argnums=(0, 1)))(params, x)
     assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got[1])[0],
                             jax.tree.leaves(want[1])):

@@ -31,6 +31,7 @@ from dexiraft_tpu.ops import lm_attention as la
 from dexiraft_tpu.ops.lm_conv import gated_short_conv, taps_masked
 
 from _lm_common import packed_batch, rel, seeded, toy
+from _models import init_module, jit_apply
 
 H, L, T = 16, 3, 24
 
@@ -250,7 +251,7 @@ def _assert_attention_mixer_matches(cfg, tol):
     seg, pos, x, w = _mixer_case(cfg, cfg.seq_len)
     module = attention.mixer_of(cfg, 1, dtype=jnp.float32, init_std=0.2)
     assert isinstance(module, attention.GatedAttention)
-    params = module.init(jax.random.PRNGKey(0), x, pos, seg)["params"]
+    params = init_module(module, x, pos, seg)["params"]
     assert set(params) == {"wq", "wk", "wv", "wo", "q_norm", "k_norm"}
     heads, kv_heads = cfg.heads_held[1], cfg.kv_heads_held[1]
 
@@ -326,9 +327,10 @@ def test_an_expert_layer_without_a_shared_expert_matches_the_reference():
     assert cfg.n_shared_experts == 0
     x = jax.random.normal(jax.random.PRNGKey(7), (1, 96, cfg.hidden_size))
     module = MoE(cfg=cfg, init_std=0.1)
-    variables = module.init(jax.random.PRNGKey(0), x)
+    variables = init_module(module, x)
     assert set(variables["params"]) == {"experts"}  # nothing built for it
-    (got, counters), _ = module.apply(variables, x, mutable=["batch_stats"])
+    (got, counters), _ = jit_apply(module)(variables, x,
+                                           mutable=("batch_stats",))
     with jax.default_matmul_precision("highest"):
         want = ref.moe(variables["params"], x[0], cfg, cfg.experts_held)
     assert rel(got[0], want) < 2e-5

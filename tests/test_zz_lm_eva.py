@@ -36,6 +36,7 @@ from dexiraft_tpu.ops import lm_attention as la
 from dexiraft_tpu.ops import lm_eva
 
 from _lm_common import brute_force_eva_pairs, rel
+from _models import init_module
 
 S, HEADS, HD = 512, 2, 64
 DOCS = (41, 59, 201, 83, 87)
@@ -101,7 +102,7 @@ def test_mixer_matches_the_reference(path, sizes, kernel_blocks_of_128,
                     jnp.float32)
     w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
     module = EvaAttention(cfg=cfg, dtype=jnp.float32, init_std=0.2)
-    params = module.init(jax.random.PRNGKey(0), x, pos, seg)["params"]
+    params = init_module(module, x, pos, seg)["params"]
     real = (seg > 0)[..., None]
 
     def ours(p, x):
@@ -170,10 +171,10 @@ def test_the_log_sum_exp_is_an_output_with_its_own_gradient(
         out, lse = _dense(q[0], k[0], v[0], seg[0], scale, window)
         return jnp.sum(out * w[0]) + jnp.sum(lse * u[0]), (out, lse)
 
-    (_, (out, lse)), grads = jax.value_and_grad(
-        ours, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-    (_, (want_out, want_lse)), want = jax.value_and_grad(
-        plain, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, (out, lse)), grads = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, (want_out, want_lse)), want = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     assert lse.shape == (1, S, HEADS) and lse.dtype == jnp.float32
     assert rel(out[0], want_out) < 1e-5 and rel(lse[0], want_lse) < 1e-5
     for name, a, b in zip("qkv", grads, want):
@@ -231,8 +232,7 @@ def test_a_document_reads_what_it_reads_alone_at_the_same_offset(sizes):
     x = jnp.asarray(rng.normal(size=(cfg.seq_len, cfg.hidden_size)),
                     jnp.float32)
     module = EvaAttention(cfg=cfg, dtype=jnp.float32, init_std=0.2)
-    params = module.init(jax.random.PRNGKey(0), x[None], pos[None],
-                         seg[None])["params"]
+    params = init_module(module, x[None], pos[None], seg[None])["params"]
     plain = jax.jit(lambda pos, seg: ref.eva_attention(params, x, pos, seg,
                                                        cfg, HEADS))
     ours = jax.jit(lambda pos, seg: module.apply(

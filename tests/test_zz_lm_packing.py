@@ -10,10 +10,10 @@ import pytest
 
 from dexiraft_tpu.data.loader import Loader
 from dexiraft_tpu.data.tokens import PackedTokens, first_fit, write_token_file
-from dexiraft_tpu.interop import lm_reference as ref
 from dexiraft_tpu.models.lm import LM, next_token_targets
 
-from _lm_common import packed_batch, rel, seeded, toy
+from _lm_common import (packed_batch, reference_loss_and_grads, rel, seeded,
+                        toy)
 
 
 @pytest.fixture(scope="module")
@@ -85,6 +85,7 @@ def test_a_token_attends_to_nothing_outside_its_document():
     batch = packed_batch(cfg, rows=1)
     model = LM(cfg)
 
+    @jax.jit
     def logits(tokens):
         return model.apply({"params": params, "batch_stats": stats}, tokens,
                            batch["positions"], batch["segment_ids"],
@@ -109,9 +110,9 @@ def test_a_document_reads_the_same_wherever_it_lies_in_the_row():
     _, params, stats = seeded(cfg)
     batch = packed_batch(cfg, rows=1)
     model = LM(cfg)
-    run = lambda b: model.apply({"params": params, "batch_stats": stats},
-                                b["tokens"], b["positions"],
-                                b["segment_ids"], logits=True)[0]
+    run = jax.jit(lambda b: model.apply(
+        {"params": params, "batch_stats": stats}, b["tokens"], b["positions"],
+        b["segment_ids"], logits=True)[0])
     base = run(batch)
     alone = {k: jnp.zeros_like(v) for k, v in batch.items()}
     for k in batch:
@@ -136,11 +137,10 @@ def test_dropless_with_every_token_forced_onto_one_held_expert():
     assert int(metrics["moe_load_max"]) == tokens
     assert int(metrics["moe_slots_held"]) >= 2 * tokens  # two expert layers
     assert int(metrics["moe_dropped_slots"]) == 0
-    want = ref.loss(params, batch, cfg, bias=bias)
+    want, want_grads = reference_loss_and_grads(params, batch, cfg, bias=bias)
     assert abs(float(loss) - float(want)) < 2e-5 * float(want)
     grads = jax.jit(jax.grad(lambda p: family.loss_fn(
         p, stats, batch, jax.random.PRNGKey(0))[0]))(params)
-    want_grads = jax.grad(lambda p: ref.loss(p, batch, cfg, bias=bias))(params)
     for path in (("layers_1", "moe", "experts", "w_down"),
                  ("layers_2", "moe", "experts", "router")):
         g, w = grads, want_grads

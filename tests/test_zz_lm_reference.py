@@ -33,7 +33,9 @@ import pytest
 from dexiraft_tpu.interop import lm_reference as ref
 from dexiraft_tpu.models.lm import LM
 
-from _lm_common import ARCHS, SHARES, packed_batch, rel, seeded, toy
+from _lm_common import (ARCHS, SHARES, packed_batch,
+                        reference_loss_and_grads, rel, seeded, toy)
+from _models import as_one_program
 
 
 @functools.lru_cache(maxsize=None)
@@ -46,7 +48,7 @@ def _fp32(arch):
     (loss, (metrics, _)), grads = jax.jit(jax.value_and_grad(
         family.loss_fn, has_aux=True))(params, stats, batch,
                                        jax.random.PRNGKey(0))
-    ref_loss, ref_grads = ref.loss_and_grads(params, batch, cfg)
+    ref_loss, ref_grads = reference_loss_and_grads(params, batch, cfg)
     return dict(arch=arch, cfg=cfg, params=params, stats=stats,
                 batch=batch, loss=loss,
                 metrics=metrics, grads=grads, ref_loss=ref_loss,
@@ -73,10 +75,10 @@ def _leaf_names(arch):
 def test_logits_match_the_reference(fp32):
     cfg = fp32["cfg"]
     b = fp32["batch"]
-    got, _ = LM(cfg).apply(
-        {"params": fp32["params"], "batch_stats": fp32["stats"]},
-        b["tokens"], b["positions"], b["segment_ids"], logits=True)
-    want = ref.logits(fp32["params"], b, cfg)
+    got, _ = jax.jit(lambda v, b: LM(cfg).apply(
+        v, b["tokens"], b["positions"], b["segment_ids"], logits=True))(
+        {"params": fp32["params"], "batch_stats": fp32["stats"]}, b)
+    want = as_one_program(ref.logits)(fp32["params"], b, cfg)
     real = np.asarray(b["segment_ids"]) > 0
     assert rel(np.asarray(got)[real], np.asarray(want)[real]) < 2e-5
 
@@ -128,7 +130,7 @@ def test_bf16_policy_stays_near_the_reference(arch):
     (loss, _), grads = jax.jit(jax.value_and_grad(
         family.loss_fn, has_aux=True))(params, stats, batch,
                                        jax.random.PRNGKey(0))
-    ref_loss, ref_grads = ref.loss_and_grads(params, batch, cfg)
+    ref_loss, ref_grads = reference_loss_and_grads(params, batch, cfg)
     assert abs(float(loss) - float(ref_loss)) < 2e-3 * float(ref_loss)
     worst = max(rel(g, r) for g, r in zip(jax.tree.leaves(grads),
                                           jax.tree.leaves(ref_grads)))

@@ -487,40 +487,36 @@ def test_split_encoder_parity_with_monolithic(variant):
     import jax
     import jax.numpy as jnp
 
+    from _models import init_raft, jit_apply
     from dexiraft_tpu.config import VARIANTS
-    from dexiraft_tpu.models.raft import RAFT
 
-    cfg = VARIANTS[variant](small=True)
-    model = RAFT(cfg)
+    model, variables = init_raft(VARIANTS[variant](small=True), 48, 64)
+    forward = jit_apply(model)
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
     im1 = jax.random.uniform(k1, (1, 48, 64, 3), jnp.float32, 0, 255)
     im2 = jax.random.uniform(k2, (1, 48, 64, 3), jnp.float32, 0, 255)
-    variables = model.init(jax.random.PRNGKey(0), im1, im2, iters=1,
-                           train=False)
 
-    low_m, up_m = model.apply(variables, im1, im2, iters=2,
-                              test_mode=True)
-    f1 = model.apply(variables, im1, mode="encode")
-    f2 = model.apply(variables, im2, mode="encode")
-    low_s, up_s = model.apply(variables, None, iters=2, test_mode=True,
-                              mode="step", features1=f1, features2=f2)
+    low_m, up_m = forward(variables, im1, im2, iters=2, test_mode=True)
+    f1 = forward(variables, im1, mode="encode")
+    f2 = forward(variables, im2, mode="encode")
+    low_s, up_s = forward(variables, None, iters=2, test_mode=True,
+                          mode="step", features1=f1, features2=f2)
     assert float(jnp.max(jnp.abs(low_m - low_s))) <= 1e-4
     assert float(jnp.max(jnp.abs(up_m - up_s))) <= 1e-4
 
     # warm start rides the same contract (flow_init enters in "step")
     fi = jax.random.uniform(jax.random.PRNGKey(3), (1, 6, 8, 2),
                             jnp.float32, -1, 1)
-    _, up_mw = model.apply(variables, im1, im2, iters=2, test_mode=True,
-                           flow_init=fi)
-    _, up_sw = model.apply(variables, None, iters=2, test_mode=True,
-                           flow_init=fi, mode="step", features1=f1,
-                           features2=f2)
+    _, up_mw = forward(variables, im1, im2, iters=2, test_mode=True,
+                       flow_init=fi)
+    _, up_sw = forward(variables, None, iters=2, test_mode=True,
+                       flow_init=fi, mode="step", features1=f1, features2=f2)
     assert float(jnp.max(jnp.abs(up_mw - up_sw))) <= 1e-4
 
     # a forgotten frame fails loudly, not as a NoneType deep crash
     # (images became Optional for the split modes)
     with pytest.raises(ValueError, match="mode='pair' needs"):
-        model.apply(variables, im1, iters=1, test_mode=True)
+        forward(variables, im1, iters=1, test_mode=True)
 
 
 def test_streaming_feature_reuse_matches_chained_pairs():
@@ -532,32 +528,30 @@ def test_streaming_feature_reuse_matches_chained_pairs():
     import jax
     import jax.numpy as jnp
 
+    from _models import init_raft, jit_apply
     from dexiraft_tpu.config import raft_v1
-    from dexiraft_tpu.models.raft import RAFT
 
-    cfg = raft_v1(small=True)
-    model = RAFT(cfg)
+    model, variables = init_raft(raft_v1(small=True), 48, 64)
+    forward = jit_apply(model)
     key = jax.random.PRNGKey(5)
     frames = [jax.random.uniform(jax.random.fold_in(key, i),
                                  (1, 48, 64, 3), jnp.float32, 0, 255)
               for i in range(3)]
-    variables = model.init(jax.random.PRNGKey(0), frames[0], frames[1],
-                           iters=1, train=False)
 
     # chained monolithic pairs with flow carry
-    low, up_a1 = model.apply(variables, frames[0], frames[1], iters=2,
-                             test_mode=True)
-    _, up_a2 = model.apply(variables, frames[1], frames[2], iters=2,
-                           test_mode=True, flow_init=low)
+    low, up_a1 = forward(variables, frames[0], frames[1], iters=2,
+                         test_mode=True)
+    _, up_a2 = forward(variables, frames[1], frames[2], iters=2,
+                       test_mode=True, flow_init=low)
 
     # streamed: each frame encoded once
-    feats = [model.apply(variables, f, mode="encode") for f in frames]
-    low_s, up_b1 = model.apply(variables, None, iters=2, test_mode=True,
-                               mode="step", features1=feats[0],
-                               features2=feats[1])
-    _, up_b2 = model.apply(variables, None, iters=2, test_mode=True,
-                           mode="step", features1=feats[1],
-                           features2=feats[2], flow_init=low_s)
+    feats = [forward(variables, f, mode="encode") for f in frames]
+    low_s, up_b1 = forward(variables, None, iters=2, test_mode=True,
+                           mode="step", features1=feats[0],
+                           features2=feats[1])
+    _, up_b2 = forward(variables, None, iters=2, test_mode=True,
+                       mode="step", features1=feats[1], features2=feats[2],
+                       flow_init=low_s)
     assert float(jnp.max(jnp.abs(up_a1 - up_b1))) <= 1e-4
     assert float(jnp.max(jnp.abs(up_a2 - up_b2))) <= 1e-4
 
@@ -571,6 +565,7 @@ def test_warm_start_through_the_flash_kernel(monkeypatch):
     import jax
     import jax.numpy as jnp
 
+    from _models import init_raft, jit_apply
     from dexiraft_tpu.config import raft_v1
     from dexiraft_tpu.models.raft import RAFT
 
@@ -578,21 +573,20 @@ def test_warm_start_through_the_flash_kernel(monkeypatch):
     k1, k2 = jax.random.split(jax.random.PRNGKey(11))
     im1 = jax.random.uniform(k1, (2, 48, 64, 3), jnp.float32, 0, 255)
     im2 = jax.random.uniform(k2, (2, 48, 64, 3), jnp.float32, 0, 255)
-    plain = RAFT(raft_v1(small=True))
-    flash = RAFT(raft_v1(small=True, corr_impl="flash", fused_update=True))
-    variables = plain.init(jax.random.PRNGKey(0), im1, im2, iters=1,
-                           train=False)
+    plain, variables = init_raft(raft_v1(small=True), 48, 64)
+    plain = jit_apply(plain)
+    flash = jit_apply(
+        RAFT(raft_v1(small=True, corr_impl="flash", fused_update=True)))
     # per-item warm starts: a warm row beside a cold (zero) row
     fi = jnp.stack([jax.random.uniform(jax.random.PRNGKey(3), (6, 8, 2),
                                        jnp.float32, -2, 2),
                     jnp.zeros((6, 8, 2))])
 
-    def streamed(model, flow_init):
-        f1 = model.apply(variables, im1, mode="encode")
-        f2 = model.apply(variables, im2, mode="encode")
-        return jax.jit(lambda v, a, b, fi_: model.apply(
-            v, None, iters=3, test_mode=True, mode="step", features1=a,
-            features2=b, flow_init=fi_))(variables, f1, f2, flow_init)
+    def streamed(forward, flow_init):
+        f1 = forward(variables, im1, mode="encode")
+        f2 = forward(variables, im2, mode="encode")
+        return forward(variables, None, iters=3, test_mode=True, mode="step",
+                       features1=f1, features2=f2, flow_init=flow_init)
 
     low_p, up_p = streamed(plain, fi)
     low_f, up_f = streamed(flash, fi)

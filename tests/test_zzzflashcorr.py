@@ -4,8 +4,8 @@ Interpret-mode parity of flash_fused_step / flash_local_corr_level
 against the unfused XLA references (forward AND gradients, including
 through bf16/int8-quantized levels, at the published width and radii
 and at the frame's edges), blocked-tiling vs single-block equivalence,
-the whole-model flash path on shared parameters (v1 and v5, as the eval
-cells run them), config-time refusals, the one list of --corr_impl
+config-time refusals (the whole model on the flash path:
+tests/test_zzzflashmodel.py), the one list of --corr_impl
 choices, and the compile-time memory_analysis pin that the flash executable's temp footprint is
 O(fmaps) — not O(volume) — at a geometry where the all-pairs volume
 dominates.
@@ -24,14 +24,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dexiraft_tpu.ops.corr import build_corr_pyramid, corr_lookup
-from dexiraft_tpu.ops import pallas_corr
-from dexiraft_tpu.ops.local_corr import build_local_corr, local_corr_level
-from dexiraft_tpu.ops.pallas_corr import fused_reference, pad_flash_operands
+from _models import as_one_program
+from dexiraft_tpu.ops import corr, local_corr, pallas_corr
+from dexiraft_tpu.ops.pallas_corr import pad_flash_operands
+
+build_corr_pyramid = as_one_program(corr.build_corr_pyramid)
+corr_lookup = as_one_program(corr.corr_lookup)
+build_local_corr = as_one_program(local_corr.build_local_corr)
+local_corr_level = as_one_program(local_corr.local_corr_level)
+fused_reference = as_one_program(pallas_corr.fused_reference)
 
 REPO = osp.dirname(osp.dirname(osp.abspath(__file__)))
 
 
+@as_one_program
 def flash_fused_step(fmap1, levels, coords, weight, bias, radius,
                      interpret=None):
     """The kernel's fused entry point on RAW arrays: the operands padded
@@ -44,6 +50,7 @@ def flash_fused_step(fmap1, levels, coords, weight, bias, radius,
         tuple(tuple(lv.shape[1:3]) for lv in levels), interpret)
 
 
+@as_one_program
 def flash_local_corr_level(fmap1, fmap2, coords, radius, interpret=None):
     """The kernel's lookup entry point on RAW arrays, as above."""
     f1, (level,) = pad_flash_operands(fmap1, (fmap2,))
@@ -129,9 +136,9 @@ class TestFlashKernelParity:
             return jnp.sum(
                 fused_reference(f1_, f2s_, co_, w_, b_, radius) ** 2)
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2, 3, 4))(
+        gf = as_one_program(jax.grad(loss_flash, argnums=(0, 1, 2, 3, 4)))(
             lc.fmap1, lc.fmap2_pyramid, coords, weight, bias)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3, 4))(
+        gr = as_one_program(jax.grad(loss_ref, argnums=(0, 1, 2, 3, 4)))(
             lc.fmap1, lc.fmap2_pyramid, coords, weight, bias)
         for a, b_ in zip(jax.tree_util.tree_leaves(gf),
                          jax.tree_util.tree_leaves(gr)):
@@ -156,9 +163,9 @@ class TestFlashKernelParity:
             return jnp.sum(fused_reference(f1_, f2s_, coords, w_, b_,
                                            radius) ** 2)
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(
+        gf = as_one_program(jax.grad(loss_flash, argnums=(0, 1, 2, 3)))(
             lc.fmap1, lc.fmap2_pyramid, weight, bias)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(
+        gr = as_one_program(jax.grad(loss_ref, argnums=(0, 1, 2, 3)))(
             lc.fmap1, lc.fmap2_pyramid, weight, bias)
         for a, b_ in zip(jax.tree_util.tree_leaves(gf),
                          jax.tree_util.tree_leaves(gr)):
@@ -184,8 +191,8 @@ class TestFlashKernelParity:
             return jnp.sum(fused_reference(
                 f1_, lc8.fmap2_pyramid, coords, w_, b_, radius) ** 2)
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(lc8.fmap1, weight, bias)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(lc8.fmap1, weight, bias)
+        gf = as_one_program(jax.grad(loss_flash, argnums=(0, 1, 2)))(lc8.fmap1, weight, bias)
+        gr = as_one_program(jax.grad(loss_ref, argnums=(0, 1, 2)))(lc8.fmap1, weight, bias)
         for a, b_ in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=1e-3, atol=1e-3)
@@ -254,7 +261,7 @@ class TestPreparedOperands:
     @pytest.mark.parametrize("name", list(PREPARED))
     def test_fused_matches_reference(self, name, dtype):
         lc, lx, coords, weight, bias = _prepared_case(name, dtype)
-        out = pallas_corr.flash_fused_step(
+        out = as_one_program(pallas_corr.flash_fused_step)(
             lc.fmap1, lc.fmap2_pyramid, coords, weight, bias, lc.radius,
             lc.level_shapes, True)
         ref = fused_reference(lx.fmap1, lx.fmap2_pyramid, coords, weight,
@@ -270,7 +277,8 @@ class TestPreparedOperands:
         level of `odd_tail` none: its windows are zero), scales after."""
         monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
         lc, lx, coords, _, _ = _prepared_case(name, dtype)
-        out, ref = lc(coords), lx(coords)
+        lookup = as_one_program(lambda pyramid, at: pyramid(at))
+        out, ref = lookup(lc, coords), lookup(lx, coords)
         assert out.shape == ref.shape
         assert float(jnp.max(jnp.abs(out - ref))) <= 1e-3 * max(
             1.0, float(jnp.max(jnp.abs(ref))))
@@ -297,9 +305,9 @@ class TestPreparedOperands:
             return jnp.sum(fused_reference(f1_, lv_, coords, w_, b_,
                                            lx.radius) ** 2)
 
-        gf = jax.grad(loss_flash, argnums=(0, 1, 2, 3))(
+        gf = as_one_program(jax.grad(loss_flash, argnums=(0, 1, 2, 3)))(
             lc.fmap1, lc.fmap2_pyramid, weight, bias)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(
+        gr = as_one_program(jax.grad(loss_ref, argnums=(0, 1, 2, 3)))(
             lx.fmap1, lx.fmap2_pyramid, weight, bias)
         tol = dict(rtol=1e-3, atol=1e-3) if dtype == "fp32" else dict(
             rtol=1e-2, atol=1e-2)
@@ -330,8 +338,9 @@ class TestPreparedOperands:
         (h2, w2), co = lc.level_shapes[1], coords / 2.0
 
         def grads(fn, f1_, lv_):
-            return jax.grad(lambda a, b_, c_: jnp.sum(fn(a, b_, c_) ** 2),
-                            argnums=(0, 1, 2))(f1_, lv_, co)
+            return as_one_program(jax.grad(
+                lambda a, b_, c_: jnp.sum(fn(a, b_, c_) ** 2),
+                argnums=(0, 1, 2)))(f1_, lv_, co)
 
         gf = grads(lambda a, b_, c_: pallas_corr.flash_local_corr_level(
             a, b_, c_, 2, (h2, w2), True), lc.fmap1, lc.fmap2_pyramid[1])
@@ -460,8 +469,9 @@ class TestModelWidthGeometry:
         f1, f2, coords = f1[:, :4, :8], f2[:, :4, :8], coords[:, :4, :8]
 
         def grads(level):
-            return jax.grad(lambda a, b_, c_: jnp.sum(level(a, b_, c_) ** 2),
-                            argnums=(0, 1, 2))(f1, f2, coords)
+            return as_one_program(jax.grad(
+                lambda a, b_, c_: jnp.sum(level(a, b_, c_) ** 2),
+                argnums=(0, 1, 2)))(f1, f2, coords)
 
         gf = grads(lambda a, b_, c_: flash_local_corr_level(a, b_, c_, 2,
                                                             True))
@@ -757,159 +767,6 @@ class TestWindowingMechanism:
         assert len(selects) == (w2 + n).bit_length()
         assert all(e.outvars[0].aval.shape[1:] == (rows, p_block)
                    for e in selects)
-
-
-def _assert_flash_fused_matches_allpairs(make, mixed, variables, im1, im2):
-    """The whole model as the eval cells run it (flash + fused, which
-    `auto` resolves to on a TPU) against allpairs on the same
-    parameters."""
-    from dexiraft_tpu.models.raft import RAFT
-
-    def flow(**corr):  # one program each: eager, hundreds of compiles
-        cfg = make(small=True, mixed_precision=mixed, **corr)
-        return jax.jit(lambda v, a, b: RAFT(cfg).apply(
-            v, a, b, iters=2, train=False))(variables, im1, im2)
-
-    ref = flow()
-    out = flow(corr_impl="flash", fused_update=True)
-    scale = float(jnp.abs(ref).max())
-    assert out.shape == ref.shape and scale > 1.0  # px: a flow to compare
-    # fp32: reassociation noise. bf16 compute: a last-bit difference in a
-    # window feature can round an activation the other way (measured
-    # 0.6-0.8 % of the largest flow after two iterations)
-    tol = 0.02 * scale if mixed else 1e-4
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=0, atol=tol)
-
-
-class TestFlashModel:
-    """Whole-model flash vs the unfused path, SAME parameters — the
-    checkpoint-interchange contract of FusedCorrEncoder extends to the
-    flash kernel unchanged."""
-
-    @pytest.fixture(scope="class")
-    def fixture(self):
-        from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
-
-        img = jnp.zeros((1, 32, 32, 3), jnp.float32)
-        im1 = jax.random.uniform(jax.random.PRNGKey(1), (1, 32, 32, 3),
-                                 jnp.float32, 0, 255)
-        im2 = jax.random.uniform(jax.random.PRNGKey(2), (1, 32, 32, 3),
-                                 jnp.float32, 0, 255)
-        cfg_l = raft_v1(small=True, corr_impl="local")
-        variables = RAFT(cfg_l).init(jax.random.PRNGKey(0), img, img,
-                                     iters=1, train=False)
-        ref = RAFT(cfg_l).apply(variables, im1, im2, iters=2, train=False)
-        return im1, im2, variables, ref
-
-    def test_param_tree_identical(self, fixture, monkeypatch):
-        from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        img = jnp.zeros((1, 32, 32, 3), jnp.float32)
-        _, _, variables, _ = fixture
-        cfg_f = raft_v1(small=True, corr_impl="flash", fused_update=True)
-        v_f = RAFT(cfg_f).init(jax.random.PRNGKey(0), img, img,
-                               iters=1, train=False)
-        assert (jax.tree_util.tree_structure(v_f)
-                == jax.tree_util.tree_structure(variables))
-        assert (jax.tree_util.tree_map(lambda x: x.shape, v_f)
-                == jax.tree_util.tree_map(lambda x: x.shape, variables))
-
-    def test_flash_fused_matches_unfused_same_params(self, fixture,
-                                                     monkeypatch):
-        from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        im1, im2, variables, ref = fixture
-        cfg_f = raft_v1(small=True, corr_impl="flash", fused_update=True)
-        out = RAFT(cfg_f).apply(variables, im1, im2, iters=2, train=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
-
-    def test_flash_unfused_lookup_matches(self, fixture, monkeypatch):
-        from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        im1, im2, variables, ref = fixture
-        cfg_u = raft_v1(small=True, corr_impl="flash")
-        out = RAFT(cfg_u).apply(variables, im1, im2, iters=2, train=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
-
-    def test_flash_fused_matches_allpairs_mixed_precision(self, fixture,
-                                                          monkeypatch):
-        from dexiraft_tpu.config import raft_v1
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        im1, im2, variables, _ = fixture
-        _assert_flash_fused_matches_allpairs(raft_v1, True, variables,
-                                             im1, im2)
-
-    def test_flash_trains(self, fixture, monkeypatch):
-        """flash is trainable (what licenses train_cli --corr_impl
-        flash): whole-model param grads through the scanned fused step
-        match the unfused path's grads — the VJP recomputes through
-        fused_reference, so this is the same backward graph."""
-        from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        im1, im2, variables, _ = fixture
-
-        def loss(cfg):
-            def f(params):
-                out = RAFT(cfg).apply(
-                    {**variables, "params": params}, im1, im2, iters=1,
-                    train=False)
-                return jnp.mean(out ** 2)
-            return f
-
-        g_flash = jax.grad(loss(raft_v1(small=True, corr_impl="flash",
-                                        fused_update=True)))(
-            variables["params"])
-        g_ref = jax.grad(loss(raft_v1(small=True, corr_impl="local")))(
-            variables["params"])
-        flat_f = jax.tree_util.tree_leaves(g_flash)
-        flat_r = jax.tree_util.tree_leaves(g_ref)
-        for a, b in zip(flat_f, flat_r):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=5e-3, atol=5e-3)
-        # and they are not trivially zero
-        assert max(float(jnp.abs(a).max()) for a in flat_f) > 0
-
-
-class TestEvalCellModelV5:
-    """v5 small: the dual stream's 2B-batch pyramid through the fused
-    step, behind the embedded DexiNed."""
-
-    @pytest.fixture(scope="class")
-    def fixture(self):
-        from dexiraft_tpu.config import raft_v5
-        from dexiraft_tpu.models.raft import RAFT
-
-        img = jnp.zeros((1, 32, 32, 3), jnp.float32)
-        im1 = jax.random.uniform(jax.random.PRNGKey(1), (1, 32, 32, 3),
-                                 jnp.float32, 0, 255)
-        im2 = jax.random.uniform(jax.random.PRNGKey(2), (1, 32, 32, 3),
-                                 jnp.float32, 0, 255)
-        # one program: eager, v5's init is a thousand small compiles
-        variables = jax.jit(lambda k: RAFT(raft_v5(small=True)).init(
-            k, img, img, iters=1, train=False))(jax.random.PRNGKey(0))
-        return im1, im2, variables
-
-    @pytest.mark.parametrize("mixed", [False, True])
-    def test_flash_fused_matches_allpairs(self, fixture, mixed, monkeypatch):
-        from dexiraft_tpu.config import raft_v5
-
-        monkeypatch.setenv("DEXIRAFT_PALLAS_INTERPRET", "1")
-        im1, im2, variables = fixture
-        _assert_flash_fused_matches_allpairs(raft_v5, mixed, variables,
-                                             im1, im2)
 
 
 class TestConfigTimeRefusals:

@@ -183,18 +183,16 @@ class TestModelQuantizedPath:
 
     @pytest.fixture(scope="class")
     def fixture(self):
+        from _models import init_raft, jit_apply
         from dexiraft_tpu.config import raft_v1
-        from dexiraft_tpu.models.raft import RAFT
 
-        img = jnp.zeros((1, 32, 32, 3), jnp.float32)
         im1 = jax.random.uniform(jax.random.PRNGKey(1), (1, 32, 32, 3),
                                  jnp.float32, 0, 255)
         im2 = jax.random.uniform(jax.random.PRNGKey(2), (1, 32, 32, 3),
                                  jnp.float32, 0, 255)
-        cfg_l = raft_v1(small=True, corr_impl="local")
-        variables = RAFT(cfg_l).init(jax.random.PRNGKey(0), img, img,
-                                     iters=1, train=False)
-        ref = RAFT(cfg_l).apply(variables, im1, im2, iters=2, train=False)
+        model, variables = init_raft(raft_v1(small=True, corr_impl="local"),
+                                     32, 32)
+        ref = jit_apply(model)(variables, im1, im2, iters=2)
         return im1, im2, variables, ref
 
     @pytest.mark.parametrize("dtype,px_bound", [("bf16", 0.05),
@@ -205,12 +203,13 @@ class TestModelQuantizedPath:
         Measured: bf16 ~0.016 px max, int8 ~0.041 px max at 2 iters;
         bounds leave headroom for rng/platform wiggle without ever
         letting a broken dequant (errors >> 1 px) pass."""
+        from _models import jit_apply
         from dexiraft_tpu.config import raft_v1
         from dexiraft_tpu.models.raft import RAFT
 
         im1, im2, variables, ref = fixture
         cfg_q = raft_v1(small=True, corr_dtype=dtype)
-        out = RAFT(cfg_q).apply(variables, im1, im2, iters=2, train=False)
+        out = jit_apply(RAFT(cfg_q))(variables, im1, im2, iters=2)
         drift = float(jnp.max(jnp.abs(out - ref)))
         assert drift <= px_bound, f"{dtype} flow drift {drift} px"
 

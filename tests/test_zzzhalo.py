@@ -428,20 +428,11 @@ class TestParamBlockSchedule:
         module key; every param leaf must belong to exactly one block
         (a new top-level module automatically becomes its own block —
         the schedule can't silently skip one)."""
-        import jax
-        import jax.numpy as jnp
-
-        from dexiraft_tpu.models.raft import RAFT
+        from _models import raft_shapes
         from dexiraft_tpu.parallel.layout import param_block_names
 
         cfg, _ = _ok_setup()
-        model = RAFT(cfg)
-        abstract = jax.eval_shape(
-            lambda: model.init(jax.random.PRNGKey(0),
-                               jnp.zeros((1, 48, 64, 3), jnp.float32),
-                               jnp.zeros((1, 48, 64, 3), jnp.float32),
-                               iters=1, train=False))
-        params = abstract["params"]
+        params = raft_shapes(cfg, 48, 64)["params"]
         blocks = param_block_names(params)
         assert set(blocks) == {"fnet", "cnet", "ScanRAFTStep_0"}
         assert blocks == tuple(params), "schedule must follow tree order"
