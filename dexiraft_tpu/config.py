@@ -245,8 +245,68 @@ VARIANTS = {
 ROUTE_EPS = 1e-20
 
 
+class DecoderConfig:
+    """What models/lm and `train` read of a language model's
+    configuration, each name with the answer of a decoder that has
+    nothing special: every layer grouped-query attention over the whole
+    document with a rotary embedding and a dense SwiGLU, two pre-norms a
+    layer, an untied head of one vocabulary.
+
+    An architecture is a frozen dataclass that derives from this, holds
+    its published keys, its share and the row's length, validates them,
+    and overrides the answers that differ (docs/lm.md, "Adding an
+    architecture", lists the fields). The shared modules read these
+    names and never a configuration's class.
+    """
+
+    # the published `model_type`: the row of interop/lm_reference.py's
+    # table, which reads none of the answers below
+    model_type = ""
+    # what `layer_types` (and `train --layer_types`) may hold
+    layer_kinds = ()
+    # RMSNorms on what each half of a layer adds, beside the two in front
+    post_norms = False
+    # what the embedding is multiplied by
+    embed_scale = 1.0
+    # logits = E . norm(x): no `head` parameter
+    tie_embedding = False
+    # tokens a position predicts at once: the head's vocabularies
+    num_pred_heads = 1
+    # a norm's parameter is its gain's distance from 1
+    norm_add_unit_offset = False
+    # the two sums of a layer in fp32
+    fp32_skip_add = False
+    # what `GatedAttention` does beside the grouped-query attention: a
+    # sigmoid gate on its output; the rotary embedding on layers without
+    # a window too
+    attention_gate = False
+    rope_full_layers = True
+    # the expert layer: none, every layer dense
+    n_routed_experts = 0
+    n_shared_experts = 0
+    experts_held = (0, 0)
+    moe_intermediate_size = 0
+    routed_scaling_factor = 1.0
+    norm_topk_prob = True
+    route_eps = ROUTE_EPS
+    first_k_dense_replace = property(lambda self: self.num_hidden_layers)
+    # a head's width, and the attention kernel's
+    head_dim = property(
+        lambda self: self.hidden_size // self.num_attention_heads)
+    qk_head_dim = property(lambda self: self.head_dim)
+    v_head_dim = property(lambda self: self.head_dim)
+
+    def mixer(self, i: int) -> str:
+        """Layer `i`'s mixer: a key of models/lm/attention.py `MIXERS`."""
+        return "gqa"
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """Layer `i`'s window, None where it sees the whole document."""
+        return None
+
+
 @dataclasses.dataclass(frozen=True)
-class LMConfig:
+class LMConfig(DecoderConfig):
     """A DeepSeek-V3-style decoder (`model_type: deepseek_v3`): latent
     attention without a query LoRA, one or more leading dense layers,
     then sigmoid-routed expert layers with shared experts
@@ -303,15 +363,14 @@ class LMConfig:
         _hold(self, "experts_held", self.n_routed_experts)
         _whole_attention_blocks(self)
 
+    model_type = "deepseek_v3"
+
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     def mixer(self, i: int) -> str:
-        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
         return "mla"
-
-    route_eps = ROUTE_EPS
 
 
 def kanana2(**kw) -> LMConfig:
@@ -333,11 +392,8 @@ def kanana2_toy(**kw) -> LMConfig:
     return LMConfig(**{**base, **kw})
 
 
-_LAYER_KINDS = ("sliding_attention", "full_attention")
-
-
 @dataclasses.dataclass(frozen=True)
-class AfmoeConfig:
+class AfmoeConfig(DecoderConfig):
     """An AFMoE decoder (`model_type: afmoe`): gated grouped-query
     attention with QK-norm, sliding-window layers (rotary embedding) and
     full layers (none) mixed by `layer_types`, four RMSNorms a layer,
@@ -349,10 +405,6 @@ class AfmoeConfig:
     key/value head serves `num_attention_heads // num_key_value_heads`
     query heads, so the chips that hold its query heads each hold a copy
     of it. `kv_heads_held` defaults to the heads that `heads_held` reads.
-
-    The modules both architectures run (the stack, the expert layer)
-    read one vocabulary, `LMConfig`'s: the properties at the end give
-    this configuration's keys under those names.
     """
 
     vocab_size: int = 200_192
@@ -370,7 +422,7 @@ class AfmoeConfig:
     num_key_value_heads: int = 4
     head_dim: int = 128
     sliding_window: int = 2048
-    # one of _LAYER_KINDS a layer; None: the published pattern, three
+    # one of `layer_kinds` a layer; None: the published pattern, three
     # sliding layers and a full one (`global_attn_every_n_layers: 4`)
     layer_types: Optional[Tuple[str, ...]] = None
     rope_theta: float = 1e4
@@ -391,37 +443,32 @@ class AfmoeConfig:
     def __post_init__(self):
         _hold_grouped_heads(self)
         _hold(self, "experts_held", self.num_experts)
-        _hold_layer_types(self, _LAYER_KINDS, tuple(
-            _LAYER_KINDS[(i + 1) % 4 == 0]
+        _hold_layer_types(self, tuple(
+            self.layer_kinds[(i + 1) % 4 == 0]
             for i in range(self.num_hidden_layers)))
         _whole_attention_blocks(self)
 
-    def layer_window(self, i: int) -> Optional[int]:
-        """Layer `i`'s window, None for a full layer."""
-        return (self.sliding_window
-                if self.layer_types[i] == "sliding_attention" else None)
-
-    def mixer(self, i: int) -> str:
-        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
-        return "gqa"
-
-    # what `GatedAttention` does beside the grouped-query attention
+    model_type = "afmoe"
+    layer_kinds = ("sliding_attention", "full_attention")
+    post_norms = True
     attention_gate = True
     rope_full_layers = False
-    route_eps = ROUTE_EPS
-
-    # ---- LMConfig's names for what the shared modules read ----
+    embed_scale = property(lambda self: self.hidden_size ** 0.5
+                           if self.mup_enabled else 1.0)
+    # this architecture's published names for the expert layer's keys
     n_routed_experts = property(lambda self: self.num_experts)
     n_shared_experts = property(lambda self: self.num_shared_experts)
     routed_scaling_factor = property(lambda self: self.route_scale)
     norm_topk_prob = property(lambda self: self.route_norm)
     first_k_dense_replace = property(lambda self: self.num_dense_layers)
-    qk_head_dim = property(lambda self: self.head_dim)
-    v_head_dim = property(lambda self: self.head_dim)
+
+    def layer_window(self, i: int) -> Optional[int]:
+        return (self.sliding_window
+                if self.layer_types[i] == "sliding_attention" else None)
 
 
 @dataclasses.dataclass(frozen=True)
-class EvaByteConfig:
+class EvaByteConfig(DecoderConfig):
     """An EvaByte decoder (`model_type: evabyte`): a dense byte-level
     model whose mixer is EVA chunked linear attention (one softmax over
     the exact keys of the query's own `window_size` window and the
@@ -433,9 +480,7 @@ class EvaByteConfig:
     The share is the heads held (`heads_held`, of keys and values too:
     `num_key_value_heads` equals `num_attention_heads`); the SwiGLU, the
     norms, the embedding and the 320-row vocabulary are whole on every
-    chip. The model has no experts: the properties at the end answer
-    `LMConfig`'s names for what the shared modules and the benchmark's
-    runner read, truthfully (none held of none, every layer dense).
+    chip. The model has no experts: none held of none, every layer dense.
     """
 
     vocab_size: int = 320
@@ -475,32 +520,21 @@ class EvaByteConfig:
         _hold(self, "heads_held", self.num_attention_heads)
         _whole_attention_blocks(self)
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    model_type = "evabyte"
 
     def mixer(self, i: int) -> str:
-        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
         return "eva"
-
-    # ---- LMConfig's names for what the shared modules read ----
-    qk_head_dim = property(lambda self: self.head_dim)
-    v_head_dim = property(lambda self: self.head_dim)
-    first_k_dense_replace = property(lambda self: self.num_hidden_layers)
-    n_routed_experts = property(lambda self: 0)
-    experts_held = property(lambda self: (0, 0))
-    moe_intermediate_size = property(lambda self: 0)
 
 
 # `layer_types` of LFM2-8B-A1B as published: 18 convolution layers and 6
 # attention layers
-_LFM2_LAYER_KINDS = ("conv", "full_attention")
 _LFM2_LAYER_TYPES = tuple(
-    _LFM2_LAYER_KINDS[i in (2, 6, 10, 14, 18, 21)] for i in range(24))
+    ("conv", "full_attention")[i in (2, 6, 10, 14, 18, 21)]
+    for i in range(24))
 
 
 @dataclasses.dataclass(frozen=True)
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(DecoderConfig):
     """An LFM2 mixture-of-experts decoder (`model_type: lfm2_moe`): a
     layer's mixer is a doubly gated short causal convolution (`conv`,
     ops/lm_conv.py) or grouped-query attention with QK-norm and a rotary
@@ -513,8 +547,7 @@ class Lfm2MoeConfig:
     The share is `AfmoeConfig`'s (query heads, the key/value heads they
     read, routed experts, vocabulary rows). The convolution mixer has no
     heads: its channels are the hidden width, and it is whole on every
-    chip. The properties at the end answer `LMConfig`'s names and say
-    what the shared modules do for this architecture.
+    chip.
     """
 
     vocab_size: int = 65_536
@@ -530,7 +563,7 @@ class Lfm2MoeConfig:
     num_attention_heads: int = 32
     num_key_value_heads: int = 8
     conv_L_cache: int = 3
-    # one of _LFM2_LAYER_KINDS a layer; None: the published 24
+    # one of `layer_kinds` a layer; None: the published 24
     layer_types: Optional[Tuple[str, ...]] = None
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
@@ -554,35 +587,20 @@ class Lfm2MoeConfig:
         _hold_grouped_heads(self)
         _hold(self, "experts_held", self.num_experts)
         # the published pattern has no period: another depth names its own
-        _hold_layer_types(self, _LFM2_LAYER_KINDS, _LFM2_LAYER_TYPES)
+        _hold_layer_types(self, _LFM2_LAYER_TYPES)
         _whole_attention_blocks(self)
 
-    def layer_window(self, i: int) -> Optional[int]:
-        """An attention layer sees the whole document."""
-        return None
-
-    def mixer(self, i: int) -> str:
-        """Layer `i`'s mixer module (models/lm/attention.py `mixer_of`)."""
-        return "conv" if self.layer_types[i] == "conv" else "gqa"
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
-
-    # what `GatedAttention` does beside the grouped-query attention
-    attention_gate = False
-    rope_full_layers = True
-    # logits = E . norm(x): no `head` parameter
+    model_type = "lfm2_moe"
+    layer_kinds = ("conv", "full_attention")
     tie_embedding = True
     route_eps = 1e-6
-
-    # ---- LMConfig's names for what the shared modules read ----
+    # this architecture's published names for the shared modules' keys
     n_routed_experts = property(lambda self: self.num_experts)
-    n_shared_experts = property(lambda self: 0)
     first_k_dense_replace = property(lambda self: self.num_dense_layers)
     rms_norm_eps = property(lambda self: self.norm_eps)
-    qk_head_dim = property(lambda self: self.head_dim)
-    v_head_dim = property(lambda self: self.head_dim)
+
+    def mixer(self, i: int) -> str:
+        return "conv" if self.layer_types[i] == "conv" else "gqa"
 
 
 def _hold(cfg, name: str, whole: int) -> None:
@@ -598,13 +616,13 @@ def _hold(cfg, name: str, whole: int) -> None:
     object.__setattr__(cfg, name, (first, count))
 
 
-def _hold_layer_types(cfg, allowed, published) -> None:
-    """`layer_types` as a tuple of one of `allowed` a held layer; None:
-    `published`."""
+def _hold_layer_types(cfg, published) -> None:
+    """`layer_types` as a tuple of one of `cfg.layer_kinds` a held layer;
+    None: `published`."""
     kinds = tuple(published if cfg.layer_types is None else cfg.layer_types)
     if (len(kinds) != cfg.num_hidden_layers
-            or any(k not in allowed for k in kinds)):
-        raise ValueError(f"layer_types={kinds!r}: one of {allowed} "
+            or any(k not in cfg.layer_kinds for k in kinds)):
+        raise ValueError(f"layer_types={kinds!r}: one of {cfg.layer_kinds} "
                          f"for each of {cfg.num_hidden_layers} layers")
     object.__setattr__(cfg, "layer_types", kinds)
 
@@ -711,10 +729,6 @@ def lfm2_8b_a1b_toy(**kw) -> Lfm2MoeConfig:
                 seq_len=128, attn_block=32, moe_chunk=64)
     return Lfm2MoeConfig(**{**base, **kw})
 
-
-# the configurations of models/lm: what `family_of` and `train` take for a
-# language model
-LM_CONFIGS = (LMConfig, AfmoeConfig, EvaByteConfig, Lfm2MoeConfig)
 
 # language models `train --variant` takes beside VARIANTS. Not in
 # VARIANTS: eval, serve and video have no path for them (ROADMAP.md).

@@ -1,24 +1,26 @@
 """The plain reference of the language models: forward, loss and gradients
 in `jax.numpy`, float32, under `jax.default_matmul_precision("highest")`.
 
-Four architectures, picked by the configuration's type. An `LMConfig`:
-written from the published `config.json` of
-kanana-2-30b-a3b-instruct-2601 (`model_type: deepseek_v3`). An
-`AfmoeConfig`: from Trinity-Mini's (`model_type: afmoe`) and, for what
-the config does not carry (the four norms and where they sit, the gate,
-the QK-norm, rotary embedding on sliding layers only, the embedding's
-scale), from the model's published modelling code (`transformers`
-`models/afmoe`; docs/lm.md has both sets of equations and what is
-`assumed`). An `EvaByteConfig`: from EvaByte's (`model_type: evabyte`)
-and the layer as docs/lm.md writes it down (`eva_attention` below: one
-head at a time, the pooling as a dense `[chunks, positions]` matrix,
-one dense row of scores over every key and every summary with both
-masks written out). An `Lfm2MoeConfig`: from LFM2-8B-A1B's
-(`model_type: lfm2_moe`) and the layer as docs/lm.md writes it down
-(`short_conv` below: the taps as explicit shifted sums, each source
+Four architectures, picked by the configuration's published `model_type`
+from `_ARCHS`, the table further down. `deepseek_v3`: written from the
+published `config.json` of kanana-2-30b-a3b-instruct-2601. `afmoe`: from
+Trinity-Mini's and, for what the config does not carry (the four norms
+and where they sit, the gate, the QK-norm, rotary embedding on sliding
+layers only, the embedding's scale), from the model's published
+modelling code (`transformers` `models/afmoe`; docs/lm.md has both sets
+of equations and what is `assumed`). `evabyte`: from EvaByte's and the
+layer as docs/lm.md writes it down (`eva_attention` below: one head at a
+time, the pooling as a dense `[chunks, positions]` matrix, one dense row
+of scores over every key and every summary with both masks written out).
+`lfm2_moe`: from LFM2-8B-A1B's and the layer as docs/lm.md writes it
+down (`short_conv` below: the taps as explicit shifted sums, each source
 position looked up with its document id beside it; `lfm2_layer`: the
 mixer of a layer a convolution or `gated_attention` without its gate
 and with the rotary embedding; the head is the embedding's transpose).
+A row says what its architecture does by itself, from the published
+keys: it reads none of the answers `config.DecoderConfig` derives for
+models/lm (`post_norms`, `embed_scale`, `tie_embedding`, ...), so a wrong
+one there still fails the comparison.
 Independent of models/lm: no Flax module, no kernel, no
 table, no sorting, no recomputation. Every held expert is applied to
 every token and weighted by `w_i` where the router chose it and by 0
@@ -61,12 +63,10 @@ tell from it (PERF.md).
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from dexiraft_tpu.config import AfmoeConfig, EvaByteConfig, Lfm2MoeConfig
 
 Share = Optional[Tuple[int, int]]
 
@@ -370,50 +370,82 @@ def lfm2_layer(p, x, positions, segment_ids, cfg, index: int,
                                            bias), block, normed)
 
 
+def _gqa_cut(cfg, kv_heads_held: Share):
+    hd = cfg.head_dim
+    return {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
+            "wk": (hd, 1, kv_heads_held), "wv": (hd, 1, kv_heads_held)}
+
+
+def _typed(cfg, i: int):
+    """A layer's program where `layer_types` says what its mixer is."""
+    return i < cfg.num_dense_layers, cfg.layer_types[i]
+
+
+class _Arch(NamedTuple):
+    """What a `model_type` is."""
+    # (p, x, positions, segment_ids, cfg, i, block=, **share): layer `i`
+    layer: Callable
+    # (cfg, kv_heads_held): a mixer's matrices that `take_share` cuts, as
+    # (width a head, axis[, the heads held if not the query heads])
+    cut: Callable
+    # (cfg, i): what tells layer `i`'s program from another layer's
+    kind: Callable = lambda cfg, i: None
+    embed_scale: Callable = lambda cfg: 1.0
+    # tokens a position predicts at once: the head's vocabularies
+    pred_heads: Callable = lambda cfg: 1
+    # a norm's gain is 1 + its parameter
+    unit_offset: Callable = lambda cfg: False
+    # the head is the embedding's own rows
+    tied: bool = False
+
+
+_ARCHS = {
+    "deepseek_v3": _Arch(
+        lambda p, x, pos, seg, cfg, i, block=None, **share: layer(
+            p, x, pos, seg, cfg, i < cfg.first_k_dense_replace, **share),
+        lambda cfg, kv: {
+            "wq": (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, 1),
+            "wkvb": (cfg.qk_nope_head_dim + cfg.v_head_dim, 1),
+            "wo": (cfg.v_head_dim, 0)},
+        lambda cfg, i: i < cfg.first_k_dense_replace),
+    # `mup_enabled`: the embedding times sqrt(hidden_size)
+    "afmoe": _Arch(afmoe_layer, _gqa_cut, _typed, embed_scale=lambda cfg: (
+        cfg.hidden_size ** 0.5 if cfg.mup_enabled else 1.0)),
+    "evabyte": _Arch(
+        lambda p, x, pos, seg, cfg, i, **share: eva_layer(
+            p, x, pos, seg, cfg, **share),
+        lambda cfg, kv: dict(
+            {k: (cfg.head_dim, 1) for k in ("wq", "wk", "wv")},
+            wo=(cfg.head_dim, 0), phi=(1, 0), mu_k=(1, 0)),
+        pred_heads=lambda cfg: cfg.num_pred_heads,
+        unit_offset=lambda cfg: cfg.norm_add_unit_offset),
+    "lfm2_moe": _Arch(lfm2_layer, _gqa_cut, _typed, tied=True),
+}
+
+
 def _layer_of(cfg, i: int, block: Optional[int] = None, **share):
     """Layer `i` as `f(p, x, positions, segment_ids)`."""
-    if isinstance(cfg, Lfm2MoeConfig):
-        return lambda p, x, pos, seg: lfm2_layer(p, x, pos, seg, cfg, i,
-                                                 block=block, **share)
-    if isinstance(cfg, EvaByteConfig):
-        return lambda p, x, pos, seg: eva_layer(p, x, pos, seg, cfg,
-                                                block=block, **share)
-    if isinstance(cfg, AfmoeConfig):
-        return lambda p, x, pos, seg: afmoe_layer(p, x, pos, seg, cfg, i,
-                                                  block=block, **share)
-    return lambda p, x, pos, seg: layer(p, x, pos, seg, cfg,
-                                        _is_dense(cfg, i), **share)
-
-
-def _kind(cfg, i: int):
-    """What tells layer `i`'s program from another layer's: dense or
-    sparse, and its entry of `layer_types` where the configuration has
-    them (a window or none; a convolution or an attention)."""
-    kinds = getattr(cfg, "layer_types", None)
-    return _is_dense(cfg, i), kinds[i] if kinds else None
+    run = _ARCHS[cfg.model_type].layer
+    return lambda p, x, pos, seg: run(p, x, pos, seg, cfg, i, block=block,
+                                      **share)
 
 
 def _embed_scale(cfg, dtype):
-    """afmoe's `mup_enabled`: the embedding times sqrt(hidden_size)."""
-    scaled = isinstance(cfg, AfmoeConfig) and cfg.mup_enabled
-    return jnp.asarray(cfg.hidden_size ** 0.5 if scaled else 1.0, dtype)
+    return jnp.asarray(_ARCHS[cfg.model_type].embed_scale(cfg), dtype)
 
 
 def _pred_heads(cfg) -> int:
-    """Tokens a position predicts at once: the head's vocabularies."""
-    return getattr(cfg, "num_pred_heads", 1)
+    return _ARCHS[cfg.model_type].pred_heads(cfg)
 
 
 def _head(p, cfg):
-    """The head's matrix [D, vocab]: under `tie_embedding` the
-    embedding's own rows."""
-    return p["embed"].T if getattr(cfg, "tie_embedding", False) else p["head"]
+    """The head's matrix [D, vocab]."""
+    return p["embed"].T if _ARCHS[cfg.model_type].tied else p["head"]
 
 
 def _gain(g, cfg):
-    """A norm's gain from its parameter: `1 + g` under
-    `norm_add_unit_offset`."""
-    return 1.0 + g if getattr(cfg, "norm_add_unit_offset", False) else g
+    """A norm's gain from its parameter."""
+    return 1.0 + g if _ARCHS[cfg.model_type].unit_offset(cfg) else g
 
 
 def _targets(tokens, segment_ids, ahead: int = 1):
@@ -448,10 +480,6 @@ def head_loss_sum(p, x, tokens, segment_ids, cfg,
 
 def _cast(tree, dtype):
     return jax.tree.map(lambda a: jnp.asarray(a, dtype), tree)
-
-
-def _is_dense(cfg, i):
-    return i < cfg.first_k_dense_replace
 
 
 def hidden_states(params, tokens, positions, segment_ids, cfg, **share):
@@ -528,14 +556,14 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
     def head_loss(p, x, tok, seg):
         return head_loss_sum(p, x, tok, seg, cfg, block) / denom
 
-    kinds = [_kind(cfg, i) for i in range(n_layers)]
+    kinds = [_ARCHS[cfg.model_type].kind(cfg, i) for i in range(n_layers)]
     runs = {k: _layer_of(cfg, kinds.index(k), block, **share)
             for k in set(kinds)}
     fwd = {k: jit_as("reference_layer", run) for k, run in runs.items()}
     bwd = {k: vjp_of(run) for k, run in runs.items()}
     # a tied head is the embedding: its gradient from the loss joins the
     # gather's below, under the one name
-    tied = getattr(cfg, "tie_embedding", False)
+    tied = _ARCHS[cfg.model_type].tied
     top = {k: params[k] for k in ("final_norm", "embed" if tied else "head")}
     head = jit_as("reference_head_loss_and_grad",
                   jax.value_and_grad(head_loss, argnums=(0, 1)))
@@ -584,18 +612,7 @@ def take_share(params, cfg, heads_held: Tuple[int, int],
         lo, hi = held[0] * per_head, (held[0] + held[1]) * per_head
         return mat[:, lo:hi] if axis == 1 else mat[lo:hi]
 
-    if isinstance(cfg, EvaByteConfig):
-        hd = cfg.head_dim
-        cut = {"wq": (hd, 1), "wk": (hd, 1), "wv": (hd, 1), "wo": (hd, 0),
-               "phi": (1, 0), "mu_k": (1, 0)}
-    elif hasattr(cfg, "kv_heads_held"):
-        hd = cfg.head_dim
-        cut = {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
-               "wk": (hd, 1, kv_heads_held), "wv": (hd, 1, kv_heads_held)}
-    else:
-        nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                          cfg.v_head_dim)
-        cut = {"wq": (nope + rope, 1), "wkvb": (nope + dv, 1), "wo": (dv, 0)}
+    cut = _ARCHS[cfg.model_type].cut(cfg, kv_heads_held)
 
     out = dict(params)
     for i in range(cfg.num_hidden_layers):

@@ -1,7 +1,11 @@
-"""The four mixers, for the heads this chip holds: `mixer_of` picks by
-the configuration's `mixer(layer)`.
+"""The mixers, for the heads this chip holds. `MIXERS` is the one table,
+kind -> module; `mixer_of` picks from it by the configuration's
+`mixer(layer)`. A mixer reads the configuration's keys it names below
+and says which counters a batch gives its layers (`counters`, read by
+models/lm/model.py `_attention_counters`): a new one is a module here,
+its row in the table and its op.
 
-**`LatentAttention`** (`LMConfig`): multi-head latent attention without a
+**`LatentAttention`** (`mla`): multi-head latent attention without a
 query LoRA (`q_lora_rank: null`).
 
     q            = W_q x            -> per head [q_nope; q_rope]
@@ -15,8 +19,9 @@ the latent projection `W_kva` and its norm are whole on every chip of
 the group. What the absent heads would add to `out` is left out: in a
 deployment it arrives with the tensor-parallel sum.
 
-**`GatedAttention`** (`AfmoeConfig`): grouped-query attention with a
-sigmoid gate on its output and an RMSNorm on every query and key head.
+**`GatedAttention`** (`gqa`): grouped-query attention with an RMSNorm on
+every query and key head and, under `attention_gate`, a sigmoid gate on
+its output. As afmoe runs it:
 
     q = W_q x -> heads x d;  k = W_k x, v = W_v x -> kv heads x d;  g = W_g x
     q = RMSNorm_d(q), k = RMSNorm_d(k)     one gain each, shared by the heads
@@ -30,12 +35,12 @@ and `W_v` the columns of the key/value heads those read
 (`cfg.kv_heads_held`): a key/value head serves several query heads, so
 the chips that hold its query heads each hold a copy of it.
 
-An `Lfm2MoeConfig`'s `full_attention` layer is the same module with what
-its configuration says: no gate and no `W_g` (`attention_gate`), the
-rotary embedding on full layers too (`rope_full_layers`).
+lfm2_moe's `full_attention` layer is the same module with what its
+configuration says: no gate and no `W_g` (`attention_gate`), the rotary
+embedding on full layers too (`rope_full_layers`).
 
-**`ShortConv`** (`Lfm2MoeConfig`, `conv` layers): the doubly gated short
-causal convolution (ops/lm_conv.py has the equations).
+**`ShortConv`** (`conv`): the doubly gated short causal convolution
+(ops/lm_conv.py has the equations).
 
     [B; C; z] = W_in x           in this order
     out = W_out (C * conv_L(B * z))    depthwise, causal, within documents
@@ -44,7 +49,7 @@ It has no heads: the channels are the hidden width, and the module is
 whole on every chip (in a deployment a tensor-parallel chip would hold
 its share of the channels).
 
-**`EvaAttention`** (`EvaByteConfig`): EVA chunked linear attention
+**`EvaAttention`** (`eva`): EVA chunked linear attention
 (ops/lm_eva.py has the equations).
 
     q, k = rope(W_q x), rope(W_k x);  v = W_v x     (half rotation, over all d)
@@ -65,16 +70,47 @@ import jax.numpy as jnp
 
 from typing import Any, Optional
 
-from dexiraft_tpu.config import EvaByteConfig, LMConfig
 from dexiraft_tpu.models.lm.layers import (Weights, rms_norm, rope_half,
                                            rope_interleaved)
-from dexiraft_tpu.ops.lm_attention import document_attention
-from dexiraft_tpu.ops.lm_conv import gated_short_conv
-from dexiraft_tpu.ops.lm_eva import eva_attention
+from dexiraft_tpu.ops.lm_attention import (block_pair_counts,
+                                           document_attention, kernel_blocks)
+from dexiraft_tpu.ops.lm_conv import gated_short_conv, taps_masked
+from dexiraft_tpu.ops.lm_eva import eva_attention, local_ids, pair_counts
 
 
-class LatentAttention(Weights):
-    cfg: LMConfig = None
+class Mixer(Weights):
+    """A layer's mixer: `(x, positions, segment_ids) -> [B, S, D]`. Beside
+    it a mixer has `COUNTERS`, every name its `counters` may give, and
+
+        counters(cfg, segment_ids, layers) -> {name: int32 scalar}
+
+    a static method: what a batch gives the held layers of this kind,
+    `layers` their number by window in the order of the stack."""
+
+    cfg: Any = None  # a config.DecoderConfig
+    window: Optional[int] = None  # the layer's `cfg.layer_window`
+    # the layer's sub-tree of parameters
+    TREE = "attn"
+
+
+def _block_pairs(cfg, ids: jax.Array, window: Optional[int] = None):
+    """(visited, causal): the block pairs the attention kernel's grid
+    computes for this batch and those of a layer's causal triangle, from
+    the table the kernel is handed; where the kernel does not take the
+    shapes, of the one block the XLA path's mask covers."""
+    seq = ids.shape[1]
+    blocks = kernel_blocks(seq, cfg.qk_head_dim, cfg.v_head_dim) or (seq, seq)
+    return block_pair_counts(ids, *blocks, window)
+
+
+class LatentAttention(Mixer):
+    # `_block_pairs` of one layer: every layer's are the same
+    COUNTERS = ("attn_block_pairs_visited", "attn_block_pairs_causal")
+
+    @staticmethod
+    def counters(cfg, segment_ids, layers):
+        return dict(zip(LatentAttention.COUNTERS,
+                        _block_pairs(cfg, segment_ids)))
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -113,9 +149,23 @@ class LatentAttention(Weights):
                 "wo", (heads * dv, d))
 
 
-class GatedAttention(Weights):
-    cfg: Any = None  # an AfmoeConfig or an Lfm2MoeConfig
-    window: Optional[int] = None  # None: a full layer
+class GatedAttention(Mixer):
+    # the visited pairs by the layers' kind (under a window, or full),
+    # each summed over the layers of the kind; the triangle of a layer
+    COUNTERS = ("attn_block_pairs_visited_window",
+                "attn_block_pairs_visited_full", "attn_block_pairs_causal")
+
+    @staticmethod
+    def counters(cfg, segment_ids, layers):
+        visited, causal = _block_pairs(cfg, segment_ids)
+        out = {"attn_block_pairs_causal": causal}
+        for window, n in layers.items():
+            if window is None:
+                out["attn_block_pairs_visited_full"] = visited * n
+            else:
+                out["attn_block_pairs_visited_window"] = _block_pairs(
+                    cfg, segment_ids, window)[0] * n
+        return out
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -149,8 +199,16 @@ class GatedAttention(Weights):
             return out @ self.w("wo", (heads * hd, d))
 
 
-class ShortConv(Weights):
-    cfg: Any = None  # an Lfm2MoeConfig
+class ShortConv(Mixer):
+    TREE = "conv"
+    # the taps the mask zeroes, summed over the layers
+    COUNTERS = ("conv_taps_masked",)
+
+    @staticmethod
+    def counters(cfg, segment_ids, layers):
+        return {"conv_taps_masked": (taps_masked(segment_ids,
+                                                 cfg.conv_L_cache)
+                                     * sum(layers.values()))}
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -166,8 +224,21 @@ class ShortConv(Weights):
             return y @ self.w("w_out", (d, d))
 
 
-class EvaAttention(Weights):
-    cfg: EvaByteConfig = None
+class EvaAttention(Mixer):
+    # the exact part's block pairs, on the ids that separate document and
+    # window, beside the (query, key) and (query, summary) pairs the batch
+    # needs, exactly: each summed over the layers
+    COUNTERS = ("attn_block_pairs_visited_local", "attn_block_pairs_causal",
+                "eva_pairs_local", "eva_pairs_remote")
+
+    @staticmethod
+    def counters(cfg, segment_ids, layers):
+        counts = _block_pairs(
+            cfg, local_ids(segment_ids, cfg.window_size), cfg.window_size
+        ) + pair_counts(segment_ids, window=cfg.window_size,
+                        chunk=cfg.chunk_size)
+        return {name: count * sum(layers.values())
+                for name, count in zip(EvaAttention.COUNTERS, counts)}
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -190,15 +261,14 @@ class EvaAttention(Weights):
                 "wo", (heads * hd, d))
 
 
+# a configuration's `mixer(i)` -> the module
+MIXERS = {"mla": LatentAttention, "gqa": GatedAttention,
+          "eva": EvaAttention, "conv": ShortConv}
+
+
 def mixer_of(cfg, layer: int, **kw) -> nn.Module:
-    """Layer `layer`'s mixer: an attention module (named `attn`) or the
-    convolution (named `conv`)."""
-    kind = cfg.mixer(layer)
-    if kind == "conv":
-        return ShortConv(cfg=cfg, name="conv", **kw)
-    if kind == "gqa":
-        return GatedAttention(cfg=cfg, window=cfg.layer_window(layer),
-                              name="attn", **kw)
-    if kind == "eva":
-        return EvaAttention(cfg=cfg, name="attn", **kw)
-    return LatentAttention(cfg=cfg, name="attn", **kw)
+    """Layer `layer`'s mixer, named as its sub-tree (`attn`, or `conv`
+    for the convolution)."""
+    module = MIXERS[cfg.mixer(layer)]
+    return module(cfg=cfg, window=cfg.layer_window(layer), name=module.TREE,
+                  **kw)

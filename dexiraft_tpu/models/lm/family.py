@@ -19,7 +19,7 @@ from dexiraft_tpu.models.lm.model import LM, next_token_targets
 
 class LMFamily:
     def __init__(self, cfg: Any, tc: TrainConfig):
-        """cfg: one of config.LM_CONFIGS."""
+        """cfg: a config.DecoderConfig."""
         if tc.remat == "dots_saveable":
             raise ValueError(
                 "remat='dots_saveable' is a policy of RAFT's refinement "
@@ -53,8 +53,8 @@ class LMFamily:
     def loss_fn(self, params: Any, batch_stats: Any,
                 batch: Dict[str, jax.Array], rng: jax.Array):
         tokens, seg = batch["tokens"], batch["segment_ids"]
-        targets, weight = next_token_targets(
-            tokens, seg, getattr(self.cfg, "num_pred_heads", 1))
+        targets, weight = next_token_targets(tokens, seg,
+                                             self.cfg.num_pred_heads)
         total, counters = self.model.apply(
             {"params": params, "batch_stats": batch_stats},
             tokens, batch["positions"], seg, targets=(targets, weight))

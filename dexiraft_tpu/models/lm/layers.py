@@ -50,10 +50,6 @@ def rope_half(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
                            axis=-1).astype(x.dtype)
 
 
-def normal_init(std: float):
-    return nn.initializers.normal(stddev=std)
-
-
 class Weights(nn.Module):
     """Base of the LM modules: parameters live in fp32 (the masters the
     optimizer updates) and `w(...)` hands a copy in the compute dtype."""
@@ -61,9 +57,9 @@ class Weights(nn.Module):
     dtype: Any = jnp.float32
     init_std: float = 0.02
 
-    def w(self, name: str, shape, init=None) -> jax.Array:
-        init = init or normal_init(self.init_std)
-        return self.param(name, init, shape, jnp.float32).astype(self.dtype)
+    def w(self, name: str, shape) -> jax.Array:
+        return self.param(name, nn.initializers.normal(self.init_std), shape,
+                          jnp.float32).astype(self.dtype)
 
 
 # rows of one block of a SwiGLU whose `[rows, width]` intermediates pass

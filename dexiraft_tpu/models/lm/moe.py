@@ -63,6 +63,11 @@ def route(scores: jax.Array, bias: jax.Array, top_k: int, scale: float,
     return chosen, weights * scale
 
 
+# what an expert layer counts of a batch, and how `reduce_counters`
+# takes each over a stack's expert layers
+COUNTERS = {"moe_slots_held": jnp.sum, "moe_load_max": jnp.max,
+            "moe_load_mean": jnp.mean, "moe_dropped_slots": jnp.sum}
+
 # a dispatch chunk is a whole number of these rows
 _CHUNK_ROWS = 8192
 
@@ -82,7 +87,7 @@ def dispatch_chunk(slots: int, held: int, experts: int) -> int:
 
 
 class RoutedExperts(Weights):
-    cfg: Any = None  # one of config.LM_CONFIGS
+    cfg: Any = None  # a config.DecoderConfig
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -194,7 +199,7 @@ class MoE(Weights):
     """Routed experts held here + the shared experts (one SwiGLU of
     `n_shared_experts * moe_intermediate_size`)."""
 
-    cfg: Any = None  # one of config.LM_CONFIGS
+    cfg: Any = None  # a config.DecoderConfig
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -210,3 +215,14 @@ class MoE(Weights):
                 width=cfg.n_shared_experts * cfg.moe_intermediate_size,
                 dtype=self.dtype, init_std=self.init_std, name="shared")(flat)
         return (routed + shared).reshape(x.shape), counters
+
+
+def reduce_counters(per_layer) -> Dict[str, jax.Array]:
+    """Over the expert layers: slots and drops summed, the fullest
+    expert's load, the mean load. A stack without one holds no slot and
+    drops none."""
+    if not per_layer:
+        return {"moe_slots_held": jnp.zeros((), jnp.int32),
+                "moe_dropped_slots": jnp.zeros((), jnp.int32)}
+    stack = {k: jnp.stack([c[k] for c in per_layer]) for k in per_layer[0]}
+    return {k: over(stack[k]) for k, over in COUNTERS.items()}

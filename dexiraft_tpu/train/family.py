@@ -12,7 +12,7 @@ jitted init with the optimizer state, value-and-grad, microbatch
 accumulation, clip + AdamW on the OneCycle schedule, fp32 masters under
 the bf16 policy, the `all_finite` verdict, donation and the shardings.
 `family_of(cfg, tc)` picks by the config's type: a `RAFTConfig` gives
-RAFT v1-v5 (below), one of `LM_CONFIGS` the language model
+RAFT v1-v5 (below), a `DecoderConfig` the language model
 (models/lm/family.py, imported only then).
 """
 
@@ -24,13 +24,13 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from dexiraft_tpu.config import LM_CONFIGS, RAFTConfig, TrainConfig
+from dexiraft_tpu.config import DecoderConfig, RAFTConfig, TrainConfig
 
 Batch = Dict[str, jax.Array]
 
 
 def family_of(cfg: Any, tc: TrainConfig):
-    if isinstance(cfg, LM_CONFIGS):
+    if isinstance(cfg, DecoderConfig):
         from dexiraft_tpu.models.lm.family import LMFamily
 
         return LMFamily(cfg, tc)

@@ -10,18 +10,8 @@ one stage; presets supply the per-stage hyperparameters:
       --restore_ckpt checkpoints/raft-chairs
   python -m dexiraft_tpu train --variant kanana2 --tokens docs.npz \
       --layers 6 --heads_held 0 4 --experts_held 0 16 --vocab_size 16032 \
-      --batch_size 4 --precision bf16 --remat        (docs/lm.md)
-  python -m dexiraft_tpu train --variant trinity-mini --tokens docs.npz \
-      --layers 5 --dense_layers 1 --layer_types sliding_attention \
-      sliding_attention sliding_attention sliding_attention full_attention \
-      --heads_held 0 4 --experts_held 0 16 --vocab_size 25024 \
-      --batch_size 1 --precision bf16 --remat
-  python -m dexiraft_tpu train --variant evabyte --tokens bytes.npz \
-      --layers 4 --heads_held 0 8 --batch_size 1 --precision bf16 --remat
-  python -m dexiraft_tpu train --variant lfm2-8b-a1b --tokens docs.npz \
-      --layers 5 --dense_layers 1 --layer_types conv full_attention conv \
-      conv conv --heads_held 0 8 --experts_held 0 8 --vocab_size 16384 \
-      --batch_size 1 --precision bf16 --remat
+      --batch_size 4 --precision bf16 --remat
+          (a language model; docs/lm.md has a line for each of them)
 
 The loop is the reference's (train.py:163-215) re-shaped for TPU: one
 jitted sharded step (forward + loss + backward + optimizer), batches
@@ -44,7 +34,7 @@ from dexiraft_tpu.config import (
     CORR_IMPLS,
     LM_VARIANTS,
     VARIANTS,
-    LM_CONFIGS,
+    DecoderConfig,
     RAFTConfig,
     TrainConfig,
 )
@@ -81,8 +71,8 @@ def build_parser() -> argparse.ArgumentParser:
                    default="none", help="stage hyperparameter preset")
     p.add_argument("--variant", default="v1",
                    choices=sorted(VARIANTS) + sorted(LM_VARIANTS),
-                   help="v1..v5: RAFT; kanana2, trinity-mini, evabyte, lfm2-8b-a1b: the "
-                        "language models of models/lm (docs/lm.md), "
+                   help=f"v1..v5: RAFT; {', '.join(sorted(LM_VARIANTS))}: "
+                        "the language models of models/lm (docs/lm.md), "
                         "trained on --tokens")
     # the language model's own flags (refused for the RAFT variants)
     p.add_argument("--tokens", default=None,
@@ -91,7 +81,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "into rows of --seq_len")
     p.add_argument("--seq_len", type=int, default=None,
                    help="language models: positions a row (default: "
-                        "kanana2 8192, the others 32768)")
+                        "the configuration's)")
     p.add_argument("--layers", type=int, default=None,
                    help="language models: decoder layers held (default: "
                         "all)")
@@ -99,11 +89,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="language models: of them, the leading dense "
                         "layers (default: as published)")
     p.add_argument("--layer_types", nargs="+", default=None,
-                   choices=["sliding_attention", "full_attention", "conv"],
-                   help="trinity-mini: each held layer's attention "
-                        "(default: three sliding, one full, repeated); "
-                        "lfm2-8b-a1b: each held layer's mixer, conv or "
-                        "full_attention (default: the published 24)")
+                   choices=list(dict.fromkeys(
+                       kind for make in LM_VARIANTS.values()
+                       for kind in make().layer_kinds)),
+                   help="language models whose layers differ: each held "
+                        "layer's kind, of those the configuration takes "
+                        "(default: the published pattern)")
     p.add_argument("--vocab_size", type=int, default=None,
                    help="language models: rows of the vocabulary held")
     p.add_argument("--heads_held", type=int, nargs=2, default=None,
@@ -113,12 +104,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "all)")
     p.add_argument("--kv_heads_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="trinity-mini, lfm2-8b-a1b: the key/value heads this "
-                        "chip holds "
+                   help="language models with grouped key/value heads: "
+                        "those this chip holds "
                         "(default: those its query heads read)")
     p.add_argument("--experts_held", type=int, nargs=2, default=None,
                    metavar=("FIRST", "COUNT"),
-                   help="kanana2, trinity-mini, lfm2-8b-a1b: the routed "
+                   help="language models with an expert layer: the routed "
                         "experts this chip holds of an expert-parallel "
                         "group (default: all)")
     p.add_argument("--small", action="store_true")
@@ -362,10 +353,8 @@ def resolve_lm_configs(args) -> "tuple[Any, TrainConfig]":
     _refuse_given(args, _RAFT_ONLY,
                   f"belong(s) to the RAFT variants; --variant "
                   f"{args.variant} is a language model (docs/lm.md: "
-                  "--tokens, --seq_len, --layers, --dense_layers, "
-                  "--layer_types, --vocab_size, --heads_held, "
-                  "--kv_heads_held, --experts_held, --remat for whole "
-                  "layers)")
+                  f"{', '.join('--' + n for n in _LM_ONLY)}, --remat for "
+                  "whole layers)")
     if not args.tokens:
         raise SystemExit(f"train: --variant {args.variant} needs --tokens")
     make = LM_VARIANTS[args.variant]
@@ -402,7 +391,7 @@ def resolve_configs(args) -> "tuple[RAFTConfig, TrainConfig]":
     if args.variant in LM_VARIANTS:
         return resolve_lm_configs(args)
     _refuse_given(args, _LM_ONLY, "belong(s) to the language models "
-                  "(--variant kanana2, trinity-mini, evabyte, lfm2-8b-a1b)")
+                  f"(--variant {', '.join(sorted(LM_VARIANTS))})")
     if args.stage is None:
         raise SystemExit("train: --stage is required for --variant "
                          f"{args.variant}")
@@ -539,7 +528,7 @@ def train(cfg: RAFTConfig, tc: TrainConfig, args, elastic=None,
 
     if args.compile_cache:
         enable_persistent_cache()
-    is_lm = isinstance(cfg, LM_CONFIGS)
+    is_lm = isinstance(cfg, DecoderConfig)
     if is_lm:
         device_banner("train", model=args.variant, mesh=dict(mesh.shape),
                       heads_held=cfg.heads_held,

@@ -16,6 +16,7 @@ at 1e-4 as tests/test_zz_lm_attention_kernel.py holds the other mixers.
 """
 
 import functools
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -347,12 +348,11 @@ def test_the_head_is_the_embedding_and_an_untied_one_fails():
     assert "head" not in params and cfg.tie_embedding
     batch, ((loss, _), grads) = _toy_step()
 
-    class Untied(type(cfg)):
-        tie_embedding = False
-
-    untied = Untied(**{f: getattr(cfg, f) for f in cfg.__dataclass_fields__})
-    loss_u, parts = jax.jit(lambda p: ref.loss_and_grads(p, batch, untied))(
-        dict(params, head=params["embed"].T))
+    # the reference goes by its own row, not by `cfg.tie_embedding`
+    untied = ref._ARCHS[cfg.model_type]._replace(tied=False)
+    with mock.patch.dict(ref._ARCHS, {cfg.model_type: untied}):
+        loss_u, parts = jax.jit(lambda p: ref.loss_and_grads(p, batch, cfg))(
+            dict(params, head=params["embed"].T))
     assert abs(float(loss_u) - float(loss)) < 2e-5 * float(loss)
     assert rel(grads["embed"], parts["embed"] + parts["head"].T) < 2e-5
     assert rel(grads["embed"], parts["embed"]) > 0.1

@@ -23,6 +23,7 @@ every other leaf under 0.32; its limit is 0.55. A bf16 run that
 dropped a term (a missing head, expert or rope half) is off by 0.5-1.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -150,3 +151,24 @@ def test_reference_runs_in_the_precision_below(fp32):
     assert abs(float(low) - float(fp32["ref_loss"])) < 0.01 * float(
         fp32["ref_loss"])
     assert all(g.dtype == jnp.bfloat16 for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("arch,wrong", [
+    ("trinity", dict(embed_scale=1.0)),
+    ("trinity", dict(rope_full_layers=True)),
+    ("lfm2", dict(tie_embedding=False))])
+def test_a_wrong_answer_of_the_configuration_fails_the_comparison(arch, wrong):
+    """The reference goes by `model_type` and the published keys, not by
+    what `config.DecoderConfig` answers the stack: a configuration that
+    answers wrongly moves the system and leaves the reference where it
+    was, by 10x the loss's limit at the least (the full layer's rotary
+    embedding alone: 7e-4)."""
+    right = toy(arch)
+    cfg = type("Wrong", (type(right),), wrong)(**{
+        f.name: getattr(right, f.name) for f in dataclasses.fields(right)})
+    family, params, stats = seeded(cfg)
+    batch = packed_batch(cfg)
+    loss, _ = jax.jit(family.loss_fn)(params, stats, batch,
+                                      jax.random.PRNGKey(0))
+    want = as_one_program(ref.loss)(params, batch, cfg)
+    assert abs(float(loss) - float(want)) > 2e-4 * float(want)
