@@ -268,6 +268,8 @@ class DecoderConfig:
     post_norms = False
     # what the embedding is multiplied by
     embed_scale = 1.0
+    # the deviation the embedding's rows are drawn at
+    embed_init_std = property(lambda self: self.init_std)
     # logits = E . norm(x): no `head` parameter
     tie_embedding = False
     # tokens a position predicts at once: the head's vocabularies
@@ -277,10 +279,9 @@ class DecoderConfig:
     # the two sums of a layer in fp32
     fp32_skip_add = False
     # what `GatedAttention` does beside the grouped-query attention: a
-    # sigmoid gate on its output; the rotary embedding on layers without
-    # a window too
+    # sigmoid gate on its output; an RMSNorm on every query and key head
     attention_gate = False
-    rope_full_layers = True
+    qk_norm = True
     # the expert layer: none, every layer dense
     n_routed_experts = 0
     n_shared_experts = 0
@@ -290,6 +291,15 @@ class DecoderConfig:
     norm_topk_prob = True
     route_eps = ROUTE_EPS
     first_k_dense_replace = property(lambda self: self.num_hidden_layers)
+    # what the router reads: "ffn", the normed tensor its experts read,
+    # or "layer", the layer's input ahead of the mixer
+    router_reads = "ffn"
+    # how the logits become weights: "sigmoid" of all, a bias buffer in
+    # the choice, the chosen scores normalised; or "softmax" over the
+    # chosen logits, no buffer
+    route_score = "sigmoid"
+    # an expert's gate function: a key of models/lm/moe.py `ACTS`
+    expert_act = "silu"
     # a head's width, and the attention kernel's
     head_dim = property(
         lambda self: self.hidden_size // self.num_attention_heads)
@@ -303,6 +313,11 @@ class DecoderConfig:
     def layer_window(self, i: int) -> Optional[int]:
         """Layer `i`'s window, None where it sees the whole document."""
         return None
+
+    def layer_rope(self, i: int) -> bool:
+        """Whether layer `i`'s `GatedAttention` rotates its queries and
+        keys; False: no positional embedding."""
+        return True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -452,7 +467,6 @@ class AfmoeConfig(DecoderConfig):
     layer_kinds = ("sliding_attention", "full_attention")
     post_norms = True
     attention_gate = True
-    rope_full_layers = False
     embed_scale = property(lambda self: self.hidden_size ** 0.5
                            if self.mup_enabled else 1.0)
     # this architecture's published names for the expert layer's keys
@@ -465,6 +479,9 @@ class AfmoeConfig(DecoderConfig):
     def layer_window(self, i: int) -> Optional[int]:
         return (self.sliding_window
                 if self.layer_types[i] == "sliding_attention" else None)
+
+    def layer_rope(self, i: int) -> bool:
+        return self.layer_types[i] == "sliding_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -603,6 +620,109 @@ class Lfm2MoeConfig(DecoderConfig):
         return "conv" if self.layer_types[i] == "conv" else "gqa"
 
 
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerConfig(DecoderConfig):
+    """A SmallThinker decoder (`model_type: smallthinker`): grouped-query
+    attention without QK-norm or gate, 7 query heads a key/value head,
+    sliding-window layers with a rotary embedding and full layers without
+    a positional embedding, published layer by layer
+    (`sliding_window_layout`, `rope_layout`); every layer an expert layer
+    whose router reads the layer's input ahead of attention, takes the
+    top `moe_num_active_primary_experts` logits and a softmax over them,
+    and whose experts are ReLU-gated, with no shared expert
+    (models/lm/, docs/lm.md). Keys and defaults are
+    SmallThinker-21BA3B-Instruct's published `config.json`.
+
+    The share is `AfmoeConfig`'s, in whole groups: a chip holds all 7
+    query heads of each key/value head it holds, so nothing is copied.
+    """
+
+    vocab_size: int = 151_936
+    hidden_size: int = 2560
+    num_hidden_layers: int = 52
+    moe_ffn_hidden_size: int = 768
+    moe_num_primary_experts: int = 64
+    moe_num_active_primary_experts: int = 6
+    moe_primary_router_apply_softmax: bool = True
+    norm_topk_prob: bool = True
+    num_attention_heads: int = 28
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window_size: int = 4096
+    # 0 or 1 a held layer; None: the published period, a full layer
+    # without a rotary embedding and three sliding layers with one
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
+    rope_theta: float = 1.5e6
+    rms_norm_eps: float = 1e-6
+    # assumed (the catalog row has no key for them; docs/lm.md): the
+    # router ahead of attention on the layer's input (`described_as`),
+    # the experts' ReLU gate ("sparse ReGLU"). The reference alone reads
+    # these two and `moe_primary_router_apply_softmax`, so that a control
+    # of the check can hand it another mechanism; the program builds the
+    # published one whatever they say (`router_reads`, `route_score`,
+    # `expert_act` below)
+    router_before_attention: bool = True
+    hidden_act: str = "relu"
+    # assumed: `initializer_range`
+    init_std: float = 0.02
+    seq_len: int = 16_384
+    heads_held: Optional[Tuple[int, int]] = None
+    kv_heads_held: Optional[Tuple[int, int]] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    mixed_precision: bool = False
+    remat: bool = False
+    attn_block: int = 1024
+    moe_chunk: Optional[int] = None
+
+    def __post_init__(self):
+        for name in ("sliding_window_layout", "rope_layout"):
+            given = getattr(self, name)
+            layout = tuple(int(i % 4 != 0)
+                           for i in range(self.num_hidden_layers)
+                           ) if given is None else tuple(given)
+            if (len(layout) != self.num_hidden_layers
+                    or any(v not in (0, 1) for v in layout)):
+                raise ValueError(f"{name}={layout!r}: 0 or 1 for each of "
+                                 f"{self.num_hidden_layers} layers")
+            object.__setattr__(self, name, layout)
+        _hold_grouped_heads(self)
+        group = self.num_attention_heads // self.num_key_value_heads
+        if self.heads_held[0] % group or self.heads_held[1] % group:
+            raise ValueError(
+                f"heads_held={self.heads_held} splits a group of {group} "
+                "query heads: a chip holds a key/value head's query heads "
+                "whole")
+        _hold(self, "experts_held", self.moe_num_primary_experts)
+        _whole_attention_blocks(self)
+
+    model_type = "smallthinker"
+    qk_norm = False
+    first_k_dense_replace = 0
+    router_reads = "layer"
+    route_score = "softmax"
+    expert_act = "relu"
+    # the stand-in for trained weights: a token's own row outweighs what
+    # a layer adds to the stream. At `init_std` attention's output, a
+    # running mean over the document, outweighs it 30 times, the router
+    # (which reads the stream un-normed) sends a document's tokens to the
+    # same experts and a chip's load is a lottery of the seed (PERF.md,
+    # PR 45: load max / mean 5.6-6.4 against 1.2)
+    embed_init_std = 1.0
+    # this architecture's published names for the expert layer's keys
+    n_routed_experts = property(lambda self: self.moe_num_primary_experts)
+    num_experts_per_tok = property(
+        lambda self: self.moe_num_active_primary_experts)
+    moe_intermediate_size = property(lambda self: self.moe_ffn_hidden_size)
+
+    def layer_window(self, i: int) -> Optional[int]:
+        return (self.sliding_window_size if self.sliding_window_layout[i]
+                else None)
+
+    def layer_rope(self, i: int) -> bool:
+        return bool(self.rope_layout[i])
+
+
 def _hold(cfg, name: str, whole: int) -> None:
     """A `(first, count)` share of `whole`, or None for all of it."""
     held = getattr(cfg, name)
@@ -730,6 +850,26 @@ def lfm2_8b_a1b_toy(**kw) -> Lfm2MoeConfig:
     return Lfm2MoeConfig(**{**base, **kw})
 
 
+def smallthinker_21b(**kw) -> SmallThinkerConfig:
+    """SmallThinker-21BA3B-Instruct as published; `heads_held`,
+    `kv_heads_held`, `experts_held`, `vocab_size`, `num_hidden_layers`
+    and the two layouts cut it to a chip's share
+    (benchmarks/configs/smallthinker-21b-a3b-share4.json)."""
+    return SmallThinkerConfig(**kw)
+
+
+def smallthinker_21b_toy(**kw) -> SmallThinkerConfig:
+    """The CPU tests' size: every mechanism, toy widths. 14 query heads
+    of 8 over 2 key/value heads (a group is 7, as published), 8 experts
+    top 2, one period f s s s with a window shorter than the row."""
+    base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=4,
+                moe_ffn_hidden_size=32, moe_num_primary_experts=8,
+                moe_num_active_primary_experts=2, num_attention_heads=14,
+                num_key_value_heads=2, head_dim=8, sliding_window_size=32,
+                seq_len=128, attn_block=32, moe_chunk=64)
+    return SmallThinkerConfig(**{**base, **kw})
+
+
 # language models `train --variant` takes beside VARIANTS. Not in
 # VARIANTS: eval, serve and video have no path for them (ROADMAP.md).
 LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
@@ -737,7 +877,9 @@ LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
                "trinity-mini-toy": trinity_mini_toy,
                "evabyte": evabyte, "evabyte-toy": evabyte_toy,
                "lfm2-8b-a1b": lfm2_8b_a1b,
-               "lfm2-8b-a1b-toy": lfm2_8b_a1b_toy}
+               "lfm2-8b-a1b-toy": lfm2_8b_a1b_toy,
+               "smallthinker-21b": smallthinker_21b,
+               "smallthinker-21b-toy": smallthinker_21b_toy}
 
 
 @dataclasses.dataclass(frozen=True)

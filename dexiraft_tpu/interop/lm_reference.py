@@ -1,7 +1,7 @@
 """The plain reference of the language models: forward, loss and gradients
 in `jax.numpy`, float32, under `jax.default_matmul_precision("highest")`.
 
-Four architectures, picked by the configuration's published `model_type`
+Five architectures, picked by the configuration's published `model_type`
 from `_ARCHS`, the table further down. `deepseek_v3`: written from the
 published `config.json` of kanana-2-30b-a3b-instruct-2601. `afmoe`: from
 Trinity-Mini's and, for what the config does not carry (the four norms
@@ -17,6 +17,12 @@ down (`short_conv` below: the taps as explicit shifted sums, each source
 position looked up with its document id beside it; `lfm2_layer`: the
 mixer of a layer a convolution or `gated_attention` without its gate
 and with the rotary embedding; the head is the embedding's transpose).
+`smallthinker`: from SmallThinker-21BA3B-Instruct's and the layer as
+docs/lm.md writes it down (`smallthinker_layer`: the router's logits
+from the layer's input ahead of attention, a softmax over all experts,
+the top few and their sum; `gated_attention` without gate or QK-norm,
+window and rotary embedding by the two published layouts; ReLU-gated
+experts, none shared).
 A row says what its architecture does by itself, from the published
 keys: it reads none of the answers `config.DecoderConfig` derives for
 models/lm (`post_norms`, `embed_scale`, `tie_embedding`, ...), so a wrong
@@ -108,8 +114,10 @@ def _rope_half(x, positions, theta):
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], -1)
 
 
-def _swiglu(x, p):
-    return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+def _swiglu(x, p, act=jax.nn.silu):
+    """W_down(act(W_gate x) * W_up x): a SwiGLU, or with `jax.nn.relu`
+    smallthinker's ReGLU."""
+    return (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
 
 
 def _by_blocks(f, block: Optional[int], *rows):
@@ -152,16 +160,21 @@ def attention(p, x, positions, segment_ids, cfg, heads: int):
 def gated_attention(p, x, positions, segment_ids, cfg, heads: int,
                     kv_heads: int, window: Optional[int],
                     block: Optional[int] = None, gate: bool = True,
-                    rope: Optional[bool] = None):
+                    rope: Optional[bool] = None, qk_norm: bool = True):
     """One sequence of afmoe's mixer: x [S, D]. `p` holds `heads` query
     heads' columns and the `kv_heads` key/value heads they read, each
     serving `heads // kv_heads` of them in order. `window` None: a full
     layer, without a positional embedding unless `rope` says otherwise
-    (lfm2_moe's attention layers: the rotary embedding, and no `gate`)."""
+    (lfm2_moe's attention layers: the rotary embedding, and no `gate`;
+    smallthinker's: neither `gate` nor `qk_norm`)."""
     hd, eps = cfg.head_dim, cfg.rms_norm_eps
     s = x.shape[0]
-    q = _rms_norm((x @ p["wq"]).reshape(s, heads, hd), p["q_norm"], eps)
-    k = _rms_norm((x @ p["wk"]).reshape(s, kv_heads, hd), p["k_norm"], eps)
+    q = (x @ p["wq"]).reshape(s, heads, hd)
+    if qk_norm:
+        q = _rms_norm(q, p["q_norm"], eps)
+    k = (x @ p["wk"]).reshape(s, kv_heads, hd)
+    if qk_norm:
+        k = _rms_norm(k, p["k_norm"], eps)
     v = (x @ p["wv"]).reshape(s, kv_heads, hd)
     if (window is not None) if rope is None else rope:
         q = _rope_half(q, positions, cfg.rope_theta)
@@ -296,14 +309,22 @@ def moe(p, x, cfg, experts_held: Tuple[int, int], bias=None):
     """One sequence: the held experts' part of the routed sum, plus the
     shared experts where the model has them. Expert `first + j` has row j
     of `p["experts"]`."""
-    first, count = experts_held
     chosen, w = routing(p["experts"], x, cfg, bias)
     out = (_swiglu(x, p["shared"]) if cfg.n_shared_experts
            else jnp.zeros_like(x))
+    return _add_held_experts(out, p["experts"], x, chosen, w, experts_held)
+
+
+def _add_held_experts(out, p, x, chosen, w, experts_held: Tuple[int, int],
+                      act=jax.nn.silu):
+    """`out` + every held expert applied to every token of `x`, weighted
+    by `w` where the router chose it and by 0 elsewhere. Expert
+    `first + j` has row j of `p`."""
+    first, count = experts_held
     for j in range(count):
         w_j = jnp.sum(jnp.where(chosen == first + j, w, 0.0), axis=-1)
-        expert = {k: p["experts"][k][j] for k in ("w_gate", "w_up", "w_down")}
-        out = out + w_j[:, None] * _swiglu(x, expert)
+        expert = {k: p[k][j] for k in ("w_gate", "w_up", "w_down")}
+        out = out + w_j[:, None] * _swiglu(x, expert, act)
     return out
 
 
@@ -370,6 +391,50 @@ def lfm2_layer(p, x, positions, segment_ids, cfg, index: int,
                                            bias), block, normed)
 
 
+def smallthinker_moe(p, x, u, cfg, experts_held: Tuple[int, int]):
+    """One sequence of smallthinker's expert layer: the router reads `x`
+    (assumed A1: the layer's input, `router_before_attention`; else `u`),
+    the experts read `u`.
+
+        r = W_r x;   s = softmax(r) over all experts (or sigmoid(r))
+        chosen = the top few of s;   w = s[chosen] / sum s[chosen]
+        out = sum_chosen w_e W_down,e (relu(W_gate,e u) * W_up,e u)
+
+    A softmax's chosen over their sum is the softmax over the chosen
+    logits, which `norm_topk_prob` leaves as it is; sigmoid scores are
+    divided by their sum only under it. Every held expert is applied to
+    every token."""
+    r = (x if cfg.router_before_attention else u) @ p["experts"]["router"]
+    soft = cfg.moe_primary_router_apply_softmax
+    scores = jax.nn.softmax(r, axis=-1) if soft else jax.nn.sigmoid(r)
+    w, chosen = jax.lax.top_k(scores, cfg.moe_num_active_primary_experts)
+    if soft or cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return _add_held_experts(
+        jnp.zeros_like(u), p["experts"], u, chosen, w, experts_held,
+        {"relu": jax.nn.relu, "silu": jax.nn.silu}[cfg.hidden_act])
+
+
+def smallthinker_layer(p, x, positions, segment_ids, cfg, index: int,
+                       heads_held: Share = None, kv_heads_held: Share = None,
+                       experts_held: Share = None,
+                       block: Optional[int] = None):
+    """h = x + Attn(N1(x)); x' = h + Experts(N2(h); routed on x); one
+    sequence, layer `index` of the layers held: its window and its rotary
+    embedding by the two published layouts."""
+    eps = cfg.rms_norm_eps
+    h = x + gated_attention(
+        p["attn"], _rms_norm(x, p["attn_norm"], eps), positions, segment_ids,
+        cfg, (heads_held or cfg.heads_held)[1],
+        (kv_heads_held or cfg.kv_heads_held)[1],
+        cfg.sliding_window_size if cfg.sliding_window_layout[index] else None,
+        block, gate=False, rope=bool(cfg.rope_layout[index]), qk_norm=False)
+    return h + _by_blocks(
+        lambda x_rows, u_rows: smallthinker_moe(
+            p["moe"], x_rows, u_rows, cfg, experts_held or cfg.experts_held),
+        block, x, _rms_norm(h, p["ffn_norm"], eps))
+
+
 def _gqa_cut(cfg, kv_heads_held: Share):
     hd = cfg.head_dim
     return {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
@@ -420,6 +485,9 @@ _ARCHS = {
         pred_heads=lambda cfg: cfg.num_pred_heads,
         unit_offset=lambda cfg: cfg.norm_add_unit_offset),
     "lfm2_moe": _Arch(lfm2_layer, _gqa_cut, _typed, tied=True),
+    "smallthinker": _Arch(
+        smallthinker_layer, _gqa_cut,
+        lambda cfg, i: (cfg.sliding_window_layout[i], cfg.rope_layout[i])),
 }
 
 

@@ -1,6 +1,9 @@
-"""A DeepSeek-V3-style language model on the training path (docs/lm.md):
-latent attention, a sigmoid-routed expert layer with shared experts that
-is told which experts it holds, the decoder stack and its loss.
+"""The language models on the training path (docs/lm.md): one decoder
+stack and its loss for every `config.DecoderConfig` (kanana-2, Trinity,
+EvaByte, LFM2, SmallThinker), the mixers a configuration may name
+(latent, grouped-query, EVA and short-convolution: `attention.MIXERS`),
+and one expert layer that is told which experts it holds, what its
+router reads and how it scores, and its experts' gate (`moe`).
 
 Imported only by the paths that run it: `import dexiraft_tpu` and every
 RAFT entry point leave this package alone.

@@ -19,9 +19,10 @@ the latent projection `W_kva` and its norm are whole on every chip of
 the group. What the absent heads would add to `out` is left out: in a
 deployment it arrives with the tensor-parallel sum.
 
-**`GatedAttention`** (`gqa`): grouped-query attention with an RMSNorm on
-every query and key head and, under `attention_gate`, a sigmoid gate on
-its output. As afmoe runs it:
+**`GatedAttention`** (`gqa`): grouped-query attention with, under
+`qk_norm`, an RMSNorm on every query and key head, under
+`attention_gate` a sigmoid gate on its output, and a rotary embedding on
+the layers whose `layer_rope` says so. As afmoe runs it:
 
     q = W_q x -> heads x d;  k = W_k x, v = W_v x -> kv heads x d;  g = W_g x
     q = RMSNorm_d(q), k = RMSNorm_d(k)     one gain each, shared by the heads
@@ -37,7 +38,10 @@ the chips that hold its query heads each hold a copy of it.
 
 lfm2_moe's `full_attention` layer is the same module with what its
 configuration says: no gate and no `W_g` (`attention_gate`), the rotary
-embedding on full layers too (`rope_full_layers`).
+embedding on full layers too (`layer_rope`). smallthinker's layers are
+it with neither gate nor QK-norm (no `q_norm`, `k_norm` parameters), 7
+query heads a key/value head, the window and the rotary embedding by the
+published layouts.
 
 **`ShortConv`** (`conv`): the doubly gated short causal convolution
 (ops/lm_conv.py has the equations).
@@ -89,6 +93,7 @@ class Mixer(Weights):
 
     cfg: Any = None  # a config.DecoderConfig
     window: Optional[int] = None  # the layer's `cfg.layer_window`
+    rope: bool = True  # the layer's `cfg.layer_rope`
     # the layer's sub-tree of parameters
     TREE = "attn"
 
@@ -183,10 +188,12 @@ class GatedAttention(Mixer):
                  ).reshape(b, s, kv_heads, hd)
             if cfg.attention_gate:
                 gate = x @ self.w("wg", (d, heads * hd))
-            q, k = (rms_norm(t, self.param(name, nn.initializers.ones, (hd,),
-                                           jnp.float32), cfg.rms_norm_eps)
-                    for t, name in ((q, "q_norm"), (k, "k_norm")))
-            if self.window is not None or cfg.rope_full_layers:
+            if cfg.qk_norm:
+                q, k = (rms_norm(t, self.param(name, nn.initializers.ones,
+                                               (hd,), jnp.float32),
+                                 cfg.rms_norm_eps)
+                        for t, name in ((q, "q_norm"), (k, "k_norm")))
+            if self.rope:
                 q = rope_half(q, positions, cfg.rope_theta)
                 k = rope_half(k, positions, cfg.rope_theta)
         with jax.named_scope(f"lm/gqa/{kind}/kernel"):
@@ -270,5 +277,5 @@ def mixer_of(cfg, layer: int, **kw) -> nn.Module:
     """Layer `layer`'s mixer, named as its sub-tree (`attn`, or `conv`
     for the convolution)."""
     module = MIXERS[cfg.mixer(layer)]
-    return module(cfg=cfg, window=cfg.layer_window(layer), name=module.TREE,
-                  **kw)
+    return module(cfg=cfg, window=cfg.layer_window(layer),
+                  rope=cfg.layer_rope(layer), name=module.TREE, **kw)

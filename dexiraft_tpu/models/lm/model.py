@@ -12,9 +12,14 @@ the two sums are in the stream's dtype, or in fp32 [fp32_skip_add];
 E's transpose and no parameter of its own [tie_embedding].
 
 `Attn_l` is layer `l`'s mixer (models/lm/attention.py `mixer_of`, by
-the configuration's `mixer(l)` and `layer_window(l)`). FFN is a
-SwiGLU of `intermediate_size` in the first `first_k_dense_replace`
-layers and the expert layer after them. Under `cfg.remat` every layer is
+the configuration's `mixer(l)`, `layer_window(l)` and `layer_rope(l)`).
+FFN is a SwiGLU of `intermediate_size` in the first
+`first_k_dense_replace` layers and the expert layer after them
+(models/lm/moe.py), whose router reads what its experts read, `N2(h)`,
+or the layer's input `x` itself [router_reads]: then the routing and the
+dispatch table are made ahead of the mixer, since nothing they read
+waits for it, and the router's gradient flows into `x` and not into
+`h`. Under `cfg.remat` every layer is
 a `jax.checkpoint` that keeps nothing: the backward holds one layer's
 activations at a time.
 
@@ -82,18 +87,23 @@ class DecoderLayer(Weights):
                 return rms_norm(t, _gain(self, cfg, name, t.shape[-1]),
                                 cfg.rms_norm_eps)
 
+        experts = plan = None
+        if self.index >= cfg.first_k_dense_replace:
+            experts = moe.MoE(cfg=cfg, name="moe", **kw)
+            if cfg.router_reads == "layer":
+                plan = experts.plan(x)
         out = mixer_of(cfg, self.index, **kw)(
             norm("attn_norm", x), positions, segment_ids)
         h = _skip_add(cfg, x, norm("attn_post_norm", out)
                       if cfg.post_norms else out)
         normed = norm("ffn_norm", h)
-        if self.index < cfg.first_k_dense_replace:
+        if experts is None:
             with jax.named_scope("lm/mlp"):
                 out = SwiGLU(width=cfg.intermediate_size, name="mlp",
                              **kw)(normed)
             counters = {}
         else:
-            out, counters = moe.MoE(cfg=cfg, name="moe", **kw)(normed)
+            out, counters = experts(normed, plan)
         return _skip_add(cfg, h, norm("ffn_post_norm", out)
                          if cfg.post_norms else out), counters
 
@@ -114,7 +124,8 @@ class LM(nn.Module):
         cfg = self.cfg
         dtype = jnp.bfloat16 if cfg.mixed_precision else jnp.float32
         kw = dict(dtype=dtype, init_std=cfg.init_std)
-        embed = self.param("embed", nn.initializers.normal(cfg.init_std),
+        embed = self.param("embed",
+                           nn.initializers.normal(cfg.embed_init_std),
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         with jax.named_scope("lm/embed"):
             x = embed.astype(dtype)[tokens]

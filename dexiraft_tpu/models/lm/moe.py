@@ -1,23 +1,41 @@
 """The routed expert layer, told which experts it holds.
 
-Routing is the whole model's (`topk_method: noaux_tc`, one group):
-`s = sigmoid(W_r x)` in fp32 over all `n_routed_experts`, the top
-`num_experts_per_tok` of `s + b`, weights `s` of the chosen (without
-`b`) normalised to sum 1 (over their sum and the configuration's
-`route_eps`) and scaled by
-`routed_scaling_factor`. This chip then computes `sum_i w_i Expert_i(x)`
-over the chosen experts it holds (`cfg.experts_held`), plus the shared
-experts, which every chip of the group computes alike (a configuration
-with `n_shared_experts == 0` has none, and nothing is built for them).
-What the absent experts would add is left out: in a deployment it
-arrives with the expert-parallel sum. No code stands in for that
-exchange.
+Routing is the whole model's, over all `n_routed_experts`, in fp32, by
+the configuration's `route_score`:
+
+  * `"sigmoid"` (`topk_method: noaux_tc`, one group: kanana, Trinity,
+    LFM2): `s = sigmoid(W_r x)`, the top `num_experts_per_tok` of
+    `s + b`, weights `s` of the chosen (without `b`) normalised to sum 1
+    (over their sum and the configuration's `route_eps`) and scaled by
+    `routed_scaling_factor`. `b` (`e_score_correction_bias`) is a buffer
+    outside the gradient whose update rule the published configs do not
+    give; it is carried in the `batch_stats` collection and held at zero
+    (docs/lm.md, `assumed`).
+  * `"softmax"` (SmallThinker): the top `num_experts_per_tok` of the
+    logits `W_r x` and a softmax over the chosen, which sums to 1 by
+    itself; no buffer is built.
+
+What the router reads is the configuration's too (`router_reads`): the
+tensor its experts read, inside `__call__`, or the layer's input ahead
+of the mixer, through `plan` (models/lm/model.py `DecoderLayer`). Either
+way `plan(x)` is the router and the dispatch table, everything that
+depends on the routing and not on the experts' input, and
+`__call__(x, plan)` the experts' part.
+
+This chip then computes `sum_i w_i Expert_i(x)` over the chosen experts
+it holds (`cfg.experts_held`), an expert
+`W_down(act(W_gate x) * W_up x)` with the configuration's `expert_act`,
+plus the shared experts, which every chip of the group computes alike (a
+configuration with `n_shared_experts == 0` has none, and nothing is
+built for them). What the absent experts would add is left out: in a
+deployment it arrives with the expert-parallel sum. No code stands in
+for that exchange.
 
 Dropless, in one program shape. A (token, choice) pair is a slot;
 `T * top_k` slots exist and any number of them, up to all, may fall on
 held experts. Slots are sorted by held expert (the others last), and
 the sorted order is cut into chunks of `dispatch_chunk` rows. A chunk
-gathers its tokens, runs the three grouped products of a SwiGLU with
+gathers its tokens, runs the three grouped products of a gated MLP with
 the chunk's own group sizes, and scatter-adds the weighted rows into the
 output. Chunk 0 always runs; the later chunks are a scan under one
 `lax.cond` that is taken only if held slots pass chunk 0, each of them
@@ -34,15 +52,11 @@ the expected load with room; every row of room costs its gather and its
 scatter-add whether a slot fills it or not. `cfg.moe_chunk` overrides
 the size (the tests' several chunks at toy sizes; a smaller chunk to
 save memory).
-
-`b` (`e_score_correction_bias`) is a buffer outside the gradient whose
-update rule the published config does not give; it is carried in the
-`batch_stats` collection and held at zero (docs/lm.md, `assumed`).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -62,6 +76,17 @@ def route(scores: jax.Array, bias: jax.Array, top_k: int, scale: float,
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return chosen, weights * scale
 
+
+def route_softmax(logits: jax.Array, top_k: int,
+                  scale: float) -> Tuple[jax.Array, jax.Array]:
+    """(expert ids `[T, k]`, weights `[T, k]` fp32) from logits `[T, E]`
+    fp32: the top `top_k` and a softmax over them."""
+    top, chosen = jax.lax.top_k(logits, top_k)
+    return chosen, jax.nn.softmax(top, axis=-1) * scale
+
+
+# a configuration's `expert_act` -> the gate function
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 # what an expert layer counts of a batch, and how `reduce_counters`
 # takes each over a stack's expert layers
@@ -86,30 +111,56 @@ def dispatch_chunk(slots: int, held: int, experts: int) -> int:
     return min(slots, -(-room // _CHUNK_ROWS) * _CHUNK_ROWS)
 
 
+class Plan(NamedTuple):
+    """A batch's routing as the experts' part needs it: the slots sorted
+    by held expert and cut into chunks, `[chunks, chunk]` each (a slot's
+    token and its weight), and the held experts' loads."""
+    slot_token: jax.Array   # int32
+    slot_weight: jax.Array  # fp32
+    counts: jax.Array       # [held] int32: slots at each held expert
+    starts: jax.Array       # [held]: where each one's run starts
+    ends: jax.Array
+    n_held: jax.Array       # their sum
+
+
 class RoutedExperts(Weights):
     cfg: Any = None  # a config.DecoderConfig
 
-    @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """x `[T, D]` -> (this chip's part of the layer's output `[T, D]`,
-        counters)."""
+    def setup(self):
         cfg = self.cfg
-        t, d = x.shape
-        top_k, width = cfg.num_experts_per_tok, cfg.moe_intermediate_size
+        d, width = cfg.hidden_size, cfg.moe_intermediate_size
+        held = cfg.experts_held[1]
+        normal = nn.initializers.normal
+        self.router = self.param("router", normal(cfg.init_std),
+                                 (d, cfg.n_routed_experts), jnp.float32)
+        if cfg.route_score == "sigmoid":
+            self.bias = self.variable(
+                "batch_stats", "e_score_correction_bias",
+                lambda: jnp.zeros((cfg.n_routed_experts,), jnp.float32))
+        self.w_gate, self.w_up = (
+            self.param(name, normal(self.init_std), (held, d, width),
+                       jnp.float32) for name in ("w_gate", "w_up"))
+        self.w_down = self.param("w_down", normal(self.init_std),
+                                 (held, width, d), jnp.float32)
+
+    def plan(self, x: jax.Array) -> Plan:
+        """x `[T, D]`, the tensor the router reads -> the routing."""
+        cfg = self.cfg
+        t = x.shape[0]
+        top_k = cfg.num_experts_per_tok
         first, held = cfg.experts_held
 
         with jax.named_scope("lm/moe/router"):
-            w_r = self.param("router", nn.initializers.normal(cfg.init_std),
-                             (d, cfg.n_routed_experts), jnp.float32)
-            bias = self.variable(
-                "batch_stats", "e_score_correction_bias",
-                lambda: jnp.zeros((cfg.n_routed_experts,), jnp.float32)).value
-            scores = jax.nn.sigmoid(jnp.matmul(
-                x.astype(jnp.float32), w_r,
-                precision=jax.lax.Precision.HIGHEST))
-            chosen, weights = route(scores, bias, top_k,
-                                    cfg.routed_scaling_factor,
-                                    cfg.norm_topk_prob, cfg.route_eps)
+            logits = jnp.matmul(x.astype(jnp.float32), self.router,
+                                precision=jax.lax.Precision.HIGHEST)
+            if cfg.route_score == "softmax":
+                chosen, weights = route_softmax(logits, top_k,
+                                                cfg.routed_scaling_factor)
+            else:
+                chosen, weights = route(
+                    jax.nn.sigmoid(logits), self.bias.value, top_k,
+                    cfg.routed_scaling_factor, cfg.norm_topk_prob,
+                    cfg.route_eps)
 
         with jax.named_scope("lm/moe/dispatch"):
             local = chosen.reshape(-1) - first
@@ -128,10 +179,20 @@ class RoutedExperts(Weights):
                 n_chunks, chunk)
             slot_weight = jnp.pad(weights.reshape(-1)[order], (0, pad)
                                   ).reshape(n_chunks, chunk)
+        return Plan(slot_token, slot_weight, counts, starts, ends, n_held)
 
-        w_gate = self.w("w_gate", (held, d, width))
-        w_up = self.w("w_up", (held, d, width))
-        w_down = self.w("w_down", (held, width, d))
+    def __call__(self, x: jax.Array, plan: Optional[Plan] = None
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """x `[T, D]`, with its routing (None: routed on `x` itself) ->
+        (this chip's part of the layer's output `[T, D]`, counters)."""
+        t, d = x.shape
+        slot_token, slot_weight, counts, starts, ends, n_held = (
+            self.plan(x) if plan is None else plan)
+        n_chunks, chunk = slot_token.shape
+        act = ACTS[self.cfg.expert_act]
+
+        w_gate, w_up, w_down = (w.astype(self.dtype) for w in
+                                (self.w_gate, self.w_up, self.w_down))
 
         def run_chunk(y, c, tokens, wts):
             lo = c * chunk
@@ -150,8 +211,7 @@ class RoutedExperts(Weights):
             with jax.named_scope("lm/moe/experts"):
                 gate = keep(grouped_matmul(rows, w_gate, sizes))
                 up = keep(grouped_matmul(rows, w_up, sizes))
-                out = keep(grouped_matmul(jax.nn.silu(gate) * up, w_down,
-                                          sizes))
+                out = keep(grouped_matmul(act(gate) * up, w_down, sizes))
             with jax.named_scope("lm/moe/combine"):
                 y = y.at[tokens].add(out.astype(jnp.float32) * wts[:, None])
             return y, jnp.sum(sizes)
@@ -197,23 +257,31 @@ class RoutedExperts(Weights):
 
 class MoE(Weights):
     """Routed experts held here + the shared experts (one SwiGLU of
-    `n_shared_experts * moe_intermediate_size`)."""
+    `n_shared_experts * moe_intermediate_size`). `plan(x)` routes on a
+    tensor of the caller's choosing, `[..., D]`; `__call__(x, plan)`
+    takes that routing, or routes on `x` itself."""
 
     cfg: Any = None  # a config.DecoderConfig
 
-    @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    def setup(self):
         cfg = self.cfg
+        kw = dict(dtype=self.dtype, init_std=self.init_std)
+        self.experts = RoutedExperts(cfg=cfg, **kw)
+        if cfg.n_shared_experts:
+            self.shared = SwiGLU(
+                width=cfg.n_shared_experts * cfg.moe_intermediate_size, **kw)
+
+    def plan(self, x: jax.Array) -> Plan:
+        return self.experts.plan(x.reshape(-1, x.shape[-1]))
+
+    def __call__(self, x: jax.Array, plan: Optional[Plan] = None
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         flat = x.reshape(-1, x.shape[-1])
-        routed, counters = RoutedExperts(
-            cfg=cfg, dtype=self.dtype, init_std=self.init_std,
-            name="experts")(flat)
-        if not cfg.n_shared_experts:
+        routed, counters = self.experts(flat, plan)
+        if not self.cfg.n_shared_experts:
             return routed.reshape(x.shape), counters
         with jax.named_scope("lm/moe/shared"):
-            shared = SwiGLU(
-                width=cfg.n_shared_experts * cfg.moe_intermediate_size,
-                dtype=self.dtype, init_std=self.init_std, name="shared")(flat)
+            shared = self.shared(flat)
         return (routed + shared).reshape(x.shape), counters
 
 

@@ -17,7 +17,8 @@ by a flag:
 
 **On a TPU, whole lane-aligned blocks: a Pallas flash kernel**
 (`flash_document_attention`). Grid `(rows x groups of up to 4 query
-heads, query blocks, key blocks)`, key blocks innermost. A `(bq, bk)`
+heads, or one whole group of up to 8 that no smaller number divides,
+query blocks, key blocks)`, key blocks innermost. A `(bq, bk)`
 tile of scores lives in VMEM in fp32 and nowhere else; the running
 maximum, the running sum and the output accumulator are fp32 VMEM
 scratch. The backward is two more kernels (`dq`; `dk` and `dv`) that
@@ -430,6 +431,10 @@ def _dkv_kernel(last_ref, full_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 # ---- their calls -----------------------------------------------------------
 
 _HEADS_A_STEP = 4  # at most; VMEM holds their blocks twice over
+# a group that no such number divides is held whole up to this many heads
+# (7 heads' q, o, do blocks of 512 x 128 bf16 are 0.9 MB each, their fp32
+# statistics and accumulators 1.8 MB each: inside `_VMEM_LIMIT`)
+_GROUP_A_STEP = 8
 # of a v5e's 128 MiB: the backward's blocks for 4 heads pass the 16 MiB a
 # kernel is given unasked
 _VMEM_LIMIT = 64 * 1024 * 1024
@@ -453,10 +458,18 @@ class _Static(NamedTuple):
     def hb(self) -> int:
         """Query heads a grid step holds: the largest divisor of `heads`
         up to `_HEADS_A_STEP` that holds whole groups or lies inside
-        one."""
-        return max(n for n in range(1, _HEADS_A_STEP + 1)
-                   if self.heads % n == 0
-                   and (n % self.group == 0 or self.group % n == 0))
+        one; where only 1 is (a group of 7), the whole group, up to
+        `_GROUP_A_STEP`: one K/V fetch, one mask and one step's fixed
+        cost for its heads, and `dk`/`dv` summed in the step's scratch.
+        At 7 heads on 1 of 128 over rows of 16,384 that is 7.9 against
+        13.3 ms a window layer's forward and backward and 10.7 against
+        16.0 a full layer's (my chip run, PR 45)."""
+        few = max(n for n in range(1, _HEADS_A_STEP + 1)
+                  if self.heads % n == 0
+                  and (n % self.group == 0 or self.group % n == 0))
+        if few == 1 and 1 < self.group <= _GROUP_A_STEP:
+            return self.group
+        return few
 
     @property
     def rep(self) -> int:

@@ -1,5 +1,5 @@
 """Shared by the tests/test_zz_lm_*.py files: the toy configurations of
-the four architectures, a packed batch and seeded weights of O(1)
+the five architectures, a packed batch and seeded weights of O(1)
 scale."""
 
 import functools
@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dexiraft_tpu.config import (TrainConfig, evabyte_toy, kanana2_toy,
-                                 lfm2_8b_a1b_toy, trinity_mini_toy)
+                                 lfm2_8b_a1b_toy, smallthinker_21b_toy,
+                                 trinity_mini_toy)
 from dexiraft_tpu.interop import lm_reference as ref
 from dexiraft_tpu.train.family import family_of
 
@@ -59,16 +60,19 @@ def rel(a, b):
 
 
 ARCHS = {"kanana2": kanana2_toy, "trinity": trinity_mini_toy,
-         "evabyte": evabyte_toy, "lfm2": lfm2_8b_a1b_toy}
+         "evabyte": evabyte_toy, "lfm2": lfm2_8b_a1b_toy,
+         "smallthinker": smallthinker_21b_toy}
 # a share of each toy: experts 2-5; kanana's heads 1-2, trinity's query
 # heads 2-3, which read key/value head 1; evabyte's heads 2-3 (it has no
-# experts); lfm2's query heads 4-7, the whole group of key/value head 1.
+# experts); lfm2's query heads 4-7, the whole group of key/value head 1;
+# smallthinker's query heads 7-13, the whole group of 7 of key/value head 1.
 # The packed batch's documents start at 50 and 90: inside a chunk of 4
 # and inside a window of 32
 SHARES = {"kanana2": dict(experts_held=(2, 4), heads_held=(1, 2)),
           "trinity": dict(experts_held=(2, 4), heads_held=(2, 2)),
           "evabyte": dict(heads_held=(2, 2)),
-          "lfm2": dict(experts_held=(2, 4), heads_held=(4, 4))}
+          "lfm2": dict(experts_held=(2, 4), heads_held=(4, 4)),
+          "smallthinker": dict(experts_held=(2, 4), heads_held=(7, 7))}
 
 
 def toy(arch="kanana2", **kw):

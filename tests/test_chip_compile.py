@@ -323,6 +323,45 @@ def test_lm_attention_kernel_with_shared_heads_compiles_for_v5e(chip, which,
     assert "tpu_custom_call" in text and name in text
 
 
+# `smallthinker-train-pack16k`: 1 row x 7 query heads over 1 key/value
+# head, 16,384 positions, width 128; a sliding-window layer and the full one
+ST_G, ST_KV, ST_S, ST_D, ST_WINDOW = 7, 1, 16384, 128, 4096
+
+
+@pytest.mark.parametrize("window", [ST_WINDOW, None], ids=["window", "full"])
+@pytest.mark.parametrize("which", ["forward", "dq", "dkv"])
+def test_lm_attention_kernel_with_a_group_of_seven_compiles_for_v5e(
+        chip, which, window):
+    """The same three kernels at the fifth cell's shapes: a grid step
+    holds the whole group of 7 query heads on one K/V block (7 x 512 x 128
+    blocks of q, o, do and their fp32 statistics inside the VMEM limit),
+    `dk`/`dv` sum over the 7 in scratch."""
+    from dexiraft_tpu.ops import lm_attention as la
+
+    bq, bk = la.kernel_blocks(ST_S, ST_D, ST_D)
+    st = la._Static(ST_G, ST_D ** -0.5, bq, bk, False, ST_KV, window)
+    assert (st.hb, st.rep, st.hkv) == (7, 7, 1)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    q, kv = sds((ST_G, ST_S, ST_D)), sds((ST_KV, ST_S, ST_D))
+    seg = sds((1, ST_S), jnp.int32)
+    table = lambda s: la.block_table(s, bq, bk, window)  # noqa: E731
+    if which == "forward":
+        text = _compiled_text(
+            lambda q, k, v, s: la._forward(st, q, k, v, s, table(s)),
+            q, kv, kv, seg)
+    else:
+        text = _compiled_text(
+            lambda q, k, v, s, o, lse, do: la._backward(
+                st, q, k, v, s, table(s), o, lse, do),
+            q, kv, kv, seg, q, sds((ST_G, ST_S), jnp.float32), q)
+    name = {"forward": "lm_attention_fwd", "dq": "lm_attention_dq",
+            "dkv": "lm_attention_dkv"}[which]
+    assert "tpu_custom_call" in text and name in text
+
+
 # `evabyte-train-bytes32k`: 1 row x 8 heads of 128, 32,768 positions in
 # windows of 2,048 and chunks of 16
 EVA_HEADS, EVA_S, EVA_D, EVA_WINDOW, EVA_CHUNK = 8, 32768, 128, 2048, 16
@@ -484,6 +523,41 @@ def test_lfm2_step_compiles_for_v5e_and_fits_the_chip(topo):
     memory = compiled.memory_analysis()
     # fp32 masters and AdamW's two moments: 12 B a parameter
     assert memory.argument_size_in_bytes > 12 * 499_955_840
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+
+
+@pytest.mark.slow
+def test_smallthinker_step_compiles_for_v5e_and_fits_the_chip(topo):
+    """The fifth language cell's whole train step at its real size (4
+    layers f s s s, 16 of 64 experts, 7 of 28 query heads on 1 key/value
+    head, 594 M parameters, one row of 16,384 positions, bf16, every
+    layer recomputed) as `benchmarks/compile_check.py` lowers it, the
+    attention on the kernel path it takes on the chip. The routing opens
+    ahead of attention under the same scopes; nothing is built for a
+    shared expert or a dense layer. Arguments and temporaries fit
+    15.75 GB."""
+    import os.path as osp
+    import sys
+
+    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+    from benchmarks import harness
+
+    cell = harness.load_cell("smallthinker-train-pack16k")
+    lowered = []
+    harness.load_runner("lm_train_packed").compile_for(
+        cell, topo, lambda label, program: lowered.append(program))
+    compiled = lowered[0].compile()  # the step; the check's program is
+    text = compiled.as_text()        # compile_check.py's to compile
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv"):
+        assert name in text
+    for scope in ("lm/gqa/window/kernel", "lm/gqa/full/kernel",
+                  "lm/moe/router", "lm/moe/dispatch", "lm/moe/experts"):
+        assert scope in text, scope
+    assert "lm/moe/shared" not in text and "lm/mlp" not in text
+    memory = compiled.memory_analysis()
+    # fp32 masters and AdamW's two moments: 12 B a parameter
+    assert memory.argument_size_in_bytes > 12 * 593_615_360
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
 

@@ -189,6 +189,7 @@ def test_gated_attention_on_the_kernel_matches_the_reference(
     x = jnp.asarray(rng.normal(size=(1, S, cfg.hidden_size)), jnp.float32)
     w = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
     module = attention.GatedAttention(cfg=cfg, window=window,
+                                      rope=window is not None,
                                       dtype=jnp.float32, init_std=0.2)
     params = init_module(module, x, pos, seg)["params"]
 
@@ -285,6 +286,48 @@ def test_kernel_steps_hold_whole_groups_or_lie_inside_one(blocks, heads,
                                        (2, 1): (2, 2, 1)}[heads, kv_heads]
     _assert_matches_the_dense_oracle("kernel", 200, heads, kv_heads,
                                      layout="block_aligned_two_rows")
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("heads,kv_heads", [(7, 1), (14, 2)])
+def test_groups_of_seven_match_the_dense_oracle(blocks, heads, kv_heads,
+                                                window):
+    """SmallThinker's share: a key/value head serves 7 query heads, which
+    is no power of two and no divisor's multiple up to `_HEADS_A_STEP`.
+    Forward, dq, and dk, dv summed over the 7 heads, with and without a
+    window, on two rows: with the whole group a grid step (what the
+    rule picks: the faster on the chip, PERF.md section 6), and with one
+    head a step, dk and dv of the 7 steps summed outside the kernel."""
+    st = la._Static(heads, 1.0, 128, 128, True, kv_heads, window)
+    assert (st.group, st.hb, st.rep, st.hkv) == (7, 7, 7, 1)
+    _assert_matches_the_dense_oracle("kernel", window, heads, kv_heads,
+                                     layout="block_aligned_two_rows")
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_a_group_of_seven_over_seven_steps_matches_the_dense_oracle(
+        blocks, monkeypatch, window):
+    monkeypatch.setattr(la, "_GROUP_A_STEP", 1)  # the rule before PR 45
+    st = la._Static(7, 1.0, 128, 128, True, 1, window)
+    assert (st.hb, st.rep, st.hkv) == (1, 1, 1) and st.group > st.hb
+    _assert_matches_the_dense_oracle("kernel", window, 7, 1,
+                                     layout="block_aligned_two_rows")
+
+
+@pytest.mark.parametrize("heads,kv_heads,want", [
+    (4, 4, (4, 1, 4)), (4, 1, (4, 4, 1)), (8, 2, (4, 4, 1)),
+    (8, 8, (4, 1, 4)), (32, 32, (4, 1, 4)), (2, 1, (2, 2, 1)),
+    (8, 1, (4, 4, 1)), (7, 1, (7, 7, 1)), (14, 2, (7, 7, 1)),
+    (5, 1, (5, 5, 1)), (9, 1, (3, 3, 1)), (11, 1, (1, 1, 1))])
+def test_heads_a_step_of_the_accepted_cells_stand(heads, kv_heads, want):
+    """(hb, rep, hkv) for the (query, key/value) heads the benchmark's
+    cells run: kanana's 4 on 4, Trinity's 4 on 1, LFM2's 8 on 2,
+    EvaByte's 8 on 8 (and the whole EvaByte's 32): what they were before
+    a group of 7 came; then the tests' 2 on 1 and 8 on 1, SmallThinker's
+    share and its toy, and what the rule gives other odd groups (a group
+    past `_GROUP_A_STEP` stays a head a step)."""
+    st = la._Static(heads, 1.0, 512, 512, False, kv_heads, None)
+    assert (st.hb, st.rep, st.hkv) == want
 
 
 def test_heads_that_do_not_divide_are_refused():

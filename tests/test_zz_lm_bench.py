@@ -72,6 +72,13 @@ NEW4 = ["lm_conv_device_ms", "lm_conv_gate_device_ms", "lm_conv_roofline_pct",
 SHARED4 = SHARED2 + ["lm_gqa_device_ms", "lm_gqa_full_kernel_device_ms",
                      "lm_mlp_device_ms"]
 NEEDS_A_CHIP4 = NEEDS_A_CHIP2 | set(NEW4) | {"lm_mlp_device_ms"}
+# the fifth language cell (PR 45): window and full layers as Trinity's,
+# so every metric of Trinity's cell, and one of its own (the router's
+# time ahead of attention)
+CELL5 = "smallthinker-train-pack16k"
+NEW5 = ["lm_moe_router_device_ms"]
+SHARED5 = SHARED2 + NEW2
+NEEDS_A_CHIP5 = NEEDS_A_CHIP2 | {"lm_moe_router_device_ms"}
 CELLS = {
     CELL: dict(config="kanana-2-30b-a3b-share8", traffic="train-pack8k",
                model="kanana-2-30b-a3b-instruct-2601", shares=8,
@@ -89,6 +96,12 @@ CELLS = {
                 model="LFM2-8B-A1B", shares=4, assumes="expert_bias",
                 reports=FED + SHARED4 + NEW4 + SETUP,
                 needs_a_chip=NEEDS_A_CHIP4),
+    CELL5: dict(config="smallthinker-21b-a3b-share4",
+                traffic="train-pack16k",
+                model="SmallThinker-21BA3B-Instruct", shares=4,
+                assumes="router_before_attention",
+                reports=FED + SHARED5 + NEW5 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP5),
 }
 
 
@@ -149,7 +162,7 @@ def test_manifest_names_the_third_cell_and_what_it_reports(manifest):
                for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + SHARED3 + ["train_samples_per_s"]:
         cells = by_name[name]["workloads"]
-        assert cells.index(CELL3) == len(cells) - 2, name  # CELL4 follows
+        assert cells[cells.index(CELL3) + 1] == CELL4, name  # it follows
     for name in NEW3:
         assert by_name[name]["workloads"][0] == CELL3
         assert by_name[name]["moves"] == "train_samples_per_s"
@@ -164,16 +177,17 @@ def test_manifest_names_the_third_cell_and_what_it_reports(manifest):
 
 
 def test_manifest_names_the_fourth_cell_and_what_it_reports(manifest):
-    cell = manifest["workloads"][-1]  # entries are added at the end
+    cell = manifest["workloads"][8]  # entries are added at the end
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL4, "lfm2-8b-a1b-share4", "train-pack32k-docs2k", 1)
-    assert len(manifest["workloads"]) == 9 and len(cell["why"]) <= 200
+    assert len(cell["why"]) <= 200
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
-    assert manifest["configs"][-1]["name"] == "lfm2-8b-a1b-share4"
+    assert manifest["configs"][5]["name"] == "lfm2-8b-a1b-share4"
     by_name = {m["name"]: m
                for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + SHARED4 + ["train_samples_per_s"]:
-        assert by_name[name]["workloads"][-1] == CELL4, name
+        cells = by_name[name]["workloads"]  # last, or CELL5 follows
+        assert cells[cells.index(CELL4) + 1:] in ([], [CELL5]), name
     for name in NEW4:
         assert by_name[name]["workloads"] == [CELL4]
         assert by_name[name]["moves"] == "train_samples_per_s"
@@ -181,12 +195,44 @@ def test_manifest_names_the_fourth_cell_and_what_it_reports(manifest):
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
                                    name + ".py"))
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(NEW4):] == NEW4
+    assert names[names.index(NEW4[0]):][:len(NEW4)] == NEW4
     # what reads a `window` kind, latent attention or EVA stays the others'
     for name, m in by_name.items():
         if (name.startswith(("lm_eva_", "lm_attn_", "lm_gqa_window_"))
                 or name == "lm_gqa_kernel_roofline_pct"):
             assert CELL4 not in m["workloads"], name
+
+
+def test_manifest_names_the_fifth_cell_and_what_it_reports(manifest):
+    cell = manifest["workloads"][-1]  # entries are added at the end
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        CELL5, "smallthinker-21b-a3b-share4", "train-pack16k", 1)
+    assert len(manifest["workloads"]) == 10 and len(cell["why"]) <= 200
+    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
+    entry = manifest["configs"][-1]
+    assert entry["name"] == "smallthinker-21b-a3b-share4"
+    assert len(entry["why"]) <= 200 and len(manifest["configs"]) == 7
+    by_name = {m["name"]: m
+               for m in manifest["per_layer"] + manifest["end_to_end"]}
+    # every list Trinity's cell is in, and no other
+    for name, m in by_name.items():
+        if name in NEW5:
+            continue
+        cells = m.get("workloads", [])
+        assert (CELL5 in cells) == (CELL2 in cells), name
+        if CELL5 in cells:
+            assert cells[-1] == CELL5, name
+    for name in FED + SHARED5 + ["train_samples_per_s"]:
+        assert by_name[name]["workloads"][-1] == CELL5, name
+    for name in NEW5:
+        assert by_name[name]["workloads"] == [CELL5]
+        assert by_name[name]["moves"] == "train_samples_per_s"
+        assert by_name[name]["layer"] == by_name["lm_moe_device_ms"]["layer"]
+        assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
+                                   name + ".py"))
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(NEW5):] == NEW5
+    assert by_name["lm_moe_router_device_ms"]["source"] == "device_trace"
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
@@ -256,6 +302,14 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report(
                 ) == (4, 1)
         assert set(k for k in counters if k.startswith("traced_pairs_")
                    ) == {"traced_pairs_full"}
+        assert counters["moe_slots_held"] > 0
+    if cell == CELL5:  # both kinds of layer, a group of 7
+        assert counters["attn_block_pairs_visited_window"] > 0
+        assert counters["attn_block_pairs_visited_full"] > 0
+        assert (counters["attn_layers_window"], counters["attn_layers_full"]
+                ) == (3, 1)
+        assert (counters["attn_heads_held"], counters["attn_kv_heads_held"]
+                ) == (7, 1)
         assert counters["moe_slots_held"] > 0
 
 
@@ -461,6 +515,79 @@ def test_a_control_goes_through_the_checks_own_comparison():
     assert cell.config["deployment"]["chips_sharing_a_layer"] == 8
 
 
+def test_the_fifth_cells_configuration_states_its_cut_and_builds():
+    """`parameters_held` is the program's own count and the issue's
+    arithmetic, the share is the deployment's, the traffic is the
+    issue's letter for letter, and every control is a field of the
+    configuration that the reference reads."""
+    import dataclasses
+
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+    from dexiraft_tpu.config import TrainConfig
+    from dexiraft_tpu.train.family import family_of
+    from dexiraft_tpu.train.state import param_count
+
+    cell = harness.load_cell(CELL5)
+    cfg, tc = harness.load_runner("lm_train_packed")._configs(cell, 0)
+    held = cell.config["parameters_held"]
+    params, stats = jax.eval_shape(family_of(cfg, TrainConfig()).init,
+                                   jax.random.PRNGKey(0))
+    assert param_count(params) == held["total"] == 593_615_360
+    assert not jax.tree.leaves(stats)  # no bias buffer under a softmax
+    assert held["total"] == 4 * held["layer"] + held[
+        "embedding_and_head"] + held["final_norm"]
+    assert held["layer"] == (held["attention_a_layer"]
+                             + held["experts_a_layer"]
+                             + held["router_a_layer"] + held["norms_a_layer"])
+    assert held["state_bytes_at_16_a_parameter"] == 16 * held["total"]
+    assert "q_norm" not in params["layers_0"]["attn"]
+    assert (cfg.heads_held, cfg.kv_heads_held, cfg.experts_held) == (
+        (0, 7), (0, 1), (0, 16))
+    assert (cfg.moe_num_primary_experts, cfg.num_attention_heads,
+            cfg.num_key_value_heads) == (64, 28, 4)  # the router's width
+    assert cfg.sliding_window_layout == cfg.rope_layout == (0, 1, 1, 1)
+    assert (cfg.hidden_size, cfg.head_dim, cfg.moe_ffn_hidden_size,
+            cfg.moe_num_active_primary_experts, cfg.sliding_window_size,
+            cfg.rope_theta, cfg.rms_norm_eps, cfg.vocab_size) == (
+                2560, 128, 768, 6, 4096, 1.5e6, 1e-6, 37_984)
+    assert (cfg.seq_len, tc.batch_size, cfg.remat, tc.precision, tc.lr,
+            tc.wdecay) == (16384, 1, True, "bf16", 3e-4, 0.1)
+    assert cfg.moe_chunk is None  # what the program selects: 32,768 rows
+    from dexiraft_tpu.models.lm.moe import dispatch_chunk
+    assert dispatch_chunk(16384 * 6, 16, 64) == 32768
+    assert set(cell.traffic["model_flags"]) == {"seq_len", "remat"}
+    docs = cell.traffic["documents"]
+    assert (docs["median"], docs["sigma"], docs["shortest"], docs["longest"],
+            docs["count"]) == (6144, 1.0, 1024, 16384, 512)
+    assert (cell.traffic["num_workers"], cell.traffic["prefetch_depth"],
+            cell.traffic["warm_steps"], cell.traffic["traced_steps"],
+            cell.traffic["check"]["reference_block"]) == (8, 2, 3, 6, 2048)
+    assert cell.config["deployment"]["chips_sharing_a_layer"] == 4
+    # controls: fields of the configuration the reference is given
+    # another value of; each changes what the reference reads
+    controls = cell.traffic["check"]["controls"]
+    assert set(controls) == {"router_after_attention", "no_window",
+                             "rope_on_full_layers", "silu_experts",
+                             "sigmoid_router"}
+    for fault in controls.values():
+        assert dataclasses.replace(cfg, **fault) != cfg
+    assert dataclasses.replace(cfg, **controls["no_window"]).layer_window(
+        1) == cfg.seq_len
+    assert dataclasses.replace(
+        cfg, **controls["rope_on_full_layers"]).layer_rope(0)
+    leaves = ["/".join(map(str, leaf))
+              for leaf in cell.traffic["check"]["leaves"]]
+    assert set(cell.traffic["check"]["tolerances"]) == {
+        "loss", "grad_norm"} | set(leaves)
+    assert any("router" in leaf for leaf in leaves)
+    # a window layer's W_k and the full layer's
+    assert {"layers_1/attn/wk", "layers_0/attn/wk"} <= set(leaves)
+    assert [cfg.layer_window(i) for i in (0, 1)] == [None, 4096]
+
+
 def _train(*flags):
     return subprocess.run(
         [sys.executable, "-m", "dexiraft_tpu", "train", *flags], cwd=REPO,
@@ -505,7 +632,11 @@ def test_train_cli_refuses_the_language_models_flags_for_raft():
     ("lfm2-8b-a1b-toy", ("--heads_held", "4", "4", "--kv_heads_held", "1",
                          "1", "--layers", "2", "--dense_layers", "1",
                          "--layer_types", "conv", "full_attention",
-                         "--experts_held", "0", "4"))])
+                         "--experts_held", "0", "4")),
+    # the full layer and a sliding one; one key/value head's group of 7
+    ("smallthinker-21b-toy", ("--heads_held", "7", "7", "--kv_heads_held",
+                              "1", "1", "--layers", "2", "--experts_held",
+                              "0", "4"))])
 def test_train_cli_trains_the_toy_model_through_the_normal_path(
         tmp_path, variant, share):
     import numpy as np

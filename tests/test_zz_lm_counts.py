@@ -20,9 +20,10 @@ import pytest
 sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
 
 from benchmarks import (flops, lm_counts, lm_counts_afmoe,  # noqa: E402
-                        lm_counts_eva, lm_counts_lfm2, lm_scopes)
+                        lm_counts_eva, lm_counts_lfm2,
+                        lm_counts_smallthinker, lm_scopes)
 from dexiraft_tpu.config import (evabyte, kanana2, lfm2_8b_a1b,  # noqa: E402
-                                 trinity_mini)
+                                 smallthinker_21b, trinity_mini)
 from dexiraft_tpu.interop import lm_reference as ref  # noqa: E402
 
 from _lm_common import (SHARES, brute_force_eva_pairs,  # noqa: E402
@@ -531,3 +532,124 @@ def test_lfm2_layer_metrics_read_their_counters_and_give_nothing_without():
                      "lm_conv_roofline_pct",
                      "lm_gqa_full_kernel_roofline_pct"):
             assert read(name, obs(c)) is None, name
+
+
+# ---- the fifth architecture's counts (benchmarks/lm_counts_smallthinker.py)
+
+
+def test_smallthinker_dense_parts_equal_the_walk_of_the_reference():
+    """The reference makes whole `[S, S]` score matrices in every layer
+    of either kind (the window is a mask there), applies each held expert
+    to every token, and its router is one product over all experts in
+    every layer; no layer is dense, no expert shared, no projection a
+    gate's."""
+    cfg = toy("smallthinker", **SHARES["smallthinker"])
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg)
+    rows, s = batch["tokens"].shape
+    walked = flops.count(lambda p: ref.loss(p, batch, cfg), params)
+    parts = lm_counts_smallthinker.per_token_forward(cfg)
+    assert set(parts) == {"projections", "router", "head"}
+    assert lm_counts_smallthinker.layers_by_kind(cfg) == {"window": 3,
+                                                          "full": 1}
+    scores = (rows * cfg.num_hidden_layers * s * s
+              * lm_counts_smallthinker.per_pair_forward(cfg))
+    experts = (rows * s * cfg.num_hidden_layers * cfg.experts_held[1]
+               * lm_counts_smallthinker.per_slot_forward(cfg))
+    assert walked == sum(parts.values()) * rows * s + scores + experts
+
+
+def test_smallthinker_pairs_are_the_window_and_the_document():
+    cfg = toy("smallthinker")
+    seg = np.asarray(packed_batch(cfg, rows=1)["segment_ids"][0])
+    at = np.arange(len(seg))
+    back = at[:, None] - at[None, :]
+    same = (seg[:, None] == seg[None, :]) & (seg[:, None] > 0) & (back >= 0)
+    assert lm_counts_smallthinker.pairs_by_kind(cfg, np.stack([seg, seg])) == {
+        "full": 2.0 * same.sum(),
+        "window": 2.0 * (same & (back < cfg.sliding_window_size)).sum()}
+
+
+def test_smallthinker_step_flops_at_the_cells_share_by_hand():
+    """The issue's arithmetic: the head and loss over a quarter of the
+    vocabulary are 63 % of the matrix FLOPs a token, 194 M against
+    4 x 28.5 M in the layers at an even balance."""
+    cfg = smallthinker_21b(
+        num_hidden_layers=4, heads_held=(0, 7), experts_held=(0, 16),
+        vocab_size=37_984)
+    per_token = lm_counts_smallthinker.per_token_forward(cfg)
+    assert per_token["projections"] == 4 * 2 * 2560 * 128 * (14 + 2)
+    assert per_token["router"] == 4 * 2 * 2560 * 64
+    assert per_token["head"] == 2 * 2560 * 37_984
+    assert lm_counts_smallthinker.per_slot_forward(cfg) == 3 * 2 * 2560 * 768
+    assert lm_counts_smallthinker.per_pair_forward(cfg) == 7 * 2 * 256
+    # a token's 6 slots fall on the 16 held of 64 experts 1.5 times a layer
+    a_layer = (per_token["projections"] + per_token["router"]) / 4 + 1.5 * (
+        lm_counts_smallthinker.per_slot_forward(cfg))
+    assert a_layer == pytest.approx(28.5e6, rel=0.01)
+    assert per_token["head"] / (per_token["head"] + 4 * a_layer
+                                ) == pytest.approx(0.63, abs=0.005)
+    parts = lm_counts_smallthinker.step_flops(
+        cfg, tokens_real=16384, slots_held=4 * 24576,
+        pairs={"full": 134e6, "window": 58.7e6})
+    assert parts["attention"] == 3 * 7 * 2 * 256 * (134e6 + 3 * 58.7e6)
+    assert parts["routed"] == 3 * 3 * 2 * 2560 * 768 * 4 * 24576
+    assert parts["total"] == pytest.approx(sum(
+        v for k, v in parts.items() if k != "total"))
+    # a 16,384-token document: the window layer's pairs and the full one's
+    row = np.ones(16384, np.int32)
+    assert lm_counts_smallthinker.pairs_by_kind(cfg, row[None]) == {
+        "full": 16384 * 16385 / 2,
+        "window": 4096 * 4097 / 2 + (16384 - 4096) * 4096}
+
+
+def test_smallthinker_layer_metric_reads_its_scope_and_gives_nothing_without():
+    from benchmarks import harness
+
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    counters = {"scope_s:lm/moe/router": 0.004,
+                "scope_s:lm/moe/dispatch": 0.040,
+                "scope_s:lm/moe/experts": 0.070,
+                "scope_s:lm/moe/combine": 0.030,
+                "scope_s:lm/gqa/proj": 0.010,
+                "scope_s:lm/gqa/window/kernel": 0.030,
+                "scope_s:lm/gqa/full/kernel": 0.020,
+                "moe_slots_held": 90000.0,
+                "moe_intermediate_size": 768, "hidden_size": 2560,
+                "experts_held": 16, "experts_layers": 4, "remat": 1.0,
+                "traced_slots_held": 90000.0,
+                "traced_pairs_window": 30e6, "traced_pairs_full": 50e6,
+                "attn_layers_window": 3, "attn_layers_full": 1,
+                "attn_heads_held": 7, "attn_kv_heads_held": 1,
+                "attn_head_dim": 128, "batch": 1, "seq_len": 16384}
+
+    def obs(c):
+        return harness.Observation(
+            spans={}, counters=c, end_to_end={}, trace={"busy_s": 1.0},
+            peaks=peaks, chips=1, memory_peak_bytes=0)
+
+    read = lambda name, o: harness.load_metric(name).read(o)  # noqa: E731
+    full = obs(counters)
+    assert read("lm_moe_router_device_ms", full) == pytest.approx(4.0)
+    assert read("lm_moe_device_ms", full) == pytest.approx(144.0)
+    # the accepted shares on this width and these heads: the same work
+    # whatever `hb` implements it, and under 100 %
+    kernel = sum(lm_counts_afmoe.attention_roofline_seconds(
+        pairs, layers, 16384, 7, 1, 128, True, peaks)["seconds"]
+        for pairs, layers in ((30e6, 3), (50e6, 1)))
+    assert kernel == pytest.approx((3 * 30e6 + 50e6) * 7 * 11 * 256 / 197e12)
+    assert read("lm_gqa_kernel_roofline_pct", full) == pytest.approx(
+        kernel / 0.050 * 100)
+    grouped = lm_counts.grouped_roofline_seconds(2560, 768, 16, 90000.0, 4,
+                                                 True, peaks)
+    assert grouped["bound"] == "compute"
+    assert read("lm_moe_experts_roofline_pct", full) == pytest.approx(
+        grouped["seconds"] / 0.070 * 100)
+    assert 0 < read("lm_moe_experts_roofline_pct", full) < 100
+    # the parent's program (no such scope), an untraced run: nothing,
+    # and no raise
+    for c in ({}, {"scope_s:lm/mla": 0.15, "batch": 4, "seq_len": 8192}):
+        assert read("lm_moe_router_device_ms", obs(c)) is None
+    untraced = obs(counters)
+    untraced.trace = None
+    assert read("lm_moe_router_device_ms", untraced) is None

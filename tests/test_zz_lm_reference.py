@@ -137,7 +137,8 @@ def test_bf16_policy_stays_near_the_reference(arch):
                                           jax.tree.leaves(ref_grads)))
     # not fp32 by accident, not broken
     assert 1e-4 < worst < {"kanana2": 0.35, "trinity": 0.45,
-                           "evabyte": 0.15, "lfm2": 0.55}[arch], worst
+                           "evabyte": 0.15, "lfm2": 0.55,
+                           "smallthinker": 0.55}[arch], worst
     assert all(g.dtype == jnp.float32 for g in jax.tree.leaves(grads))
 
 
@@ -155,7 +156,7 @@ def test_reference_runs_in_the_precision_below(fp32):
 
 @pytest.mark.parametrize("arch,wrong", [
     ("trinity", dict(embed_scale=1.0)),
-    ("trinity", dict(rope_full_layers=True)),
+    ("trinity", dict(layer_rope=lambda self, i: True)),
     ("lfm2", dict(tie_embedding=False))])
 def test_a_wrong_answer_of_the_configuration_fails_the_comparison(arch, wrong):
     """The reference goes by `model_type` and the published keys, not by

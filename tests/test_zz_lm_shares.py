@@ -6,7 +6,9 @@ architectures: kanana2's latent attention first, then trinity's gated
 grouped-query attention, where two chips hold copies of one key/value
 head, then evabyte's chunked linear attention over 4 chips, whose dense
 layer every chip holds whole, then lfm2's two kinds of layer over 4
-chips, whose convolution mixer every chip holds whole.
+chips, whose convolution mixer every chip holds whole, then
+smallthinker's two kinds of layer over 4 chips, each a whole group of 7
+query heads, the routing made on the layer's input.
 
 Each share runs the SYSTEM's modules (models/lm) on its slice of the
 whole model's weights; the whole is the plain reference holding every
@@ -439,6 +441,110 @@ def test_lfm2_layer_outputs_of_the_shares_add_up_to_the_whole_layer(
         cfg.seq_len * cfg.num_experts_per_tok)
     # the whole model's tree cut to a share is the share's own tree
     share, p = _lfm2_share(1, lfm2_whole)
+    from dexiraft_tpu.config import TrainConfig
+    from dexiraft_tpu.train.family import family_of
+    shapes, _ = jax.eval_shape(family_of(share, TrainConfig()).init,
+                               jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda a: a.shape, p) == jax.tree.map(
+        lambda a: a.shape, shapes)
+
+
+# ---- the fifth architecture: groups of seven, the router ahead of attention
+#
+# One of 4 chips that share each layer, as the cell's deployment: 28 query
+# heads of 8 over 4 key/value heads, so share i holds the whole group of 7
+# of key/value head i (no copies) and experts 4i..4i+3 of 16. Layer 0 is
+# the full layer (no positional embedding), layer 1 a sliding one (rotary
+# embedding). The router reads the layer's input, the experts the norm of
+# what follows attention; no shared expert.
+
+ST_SHARES = 4
+ST_LAYERS = {"full": "layers_0", "sliding": "layers_1"}
+_ST_SIZE = dict(num_attention_heads=28, num_key_value_heads=4,
+                moe_num_primary_experts=16, num_hidden_layers=2)
+
+
+@pytest.fixture(scope="module")
+def st_whole():
+    cfg = toy("smallthinker", **_ST_SIZE)
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg, rows=1)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, cfg.seq_len,
+                                                  cfg.hidden_size))
+    return cfg, params, batch, x
+
+
+def _st_share(i, whole):
+    cfg, params, _, _ = whole
+    share = toy("smallthinker", heads_held=(7 * i, 7),
+                experts_held=(4 * i, 4), **_ST_SIZE)
+    assert share.kv_heads_held == (i, 1)
+    return share, ref.take_share(params, cfg, share.heads_held,
+                                 share.experts_held, share.kv_heads_held)
+
+
+def _st_attention_part(i, whole, layer, x):
+    from dexiraft_tpu.models.lm.attention import mixer_of
+
+    _, _, batch, _ = whole
+    share, p = _st_share(i, whole)
+    module = mixer_of(share, int(layer.split("_")[1]))
+    return jax.jit(module.apply)({"params": p[layer]["attn"]}, x,
+                                 batch["positions"], batch["segment_ids"])[0]
+
+
+def _st_routed_part(i, whole, layer, x, u):
+    """The share's experts on `u`, routed on `x`."""
+    share, p = _st_share(i, whole)
+    module = RoutedExperts(cfg=share)
+
+    def run(v, x, u):
+        return module.apply(v, u, module.apply(v, x, method="plan"))
+
+    out, counters = jax.jit(run)({"params": p[layer]["moe"]["experts"]},
+                                 x, u)
+    assert int(counters["moe_dropped_slots"]) == 0
+    return out, counters
+
+
+@pytest.mark.parametrize("kind", list(ST_LAYERS))
+def test_smallthinker_layer_outputs_of_the_shares_add_up_to_the_whole_layer(
+        kind, st_whole):
+    """x + the four attention parts = h; h + the four routed parts (each
+    routed on x, fed N2(h)), and no shared expert = the uncut reference's
+    layer output, for the full and for a sliding layer; each share's
+    parts equal the reference given that share."""
+    cfg, params, batch, x = st_whole
+    layer = ST_LAYERS[kind]
+    index = int(layer.split("_")[1])
+    lp = params[layer]
+    pos, seg = batch["positions"][0], batch["segment_ids"][0]
+    want = ref.smallthinker_layer(lp, x[0], pos, seg, cfg, index)
+    normed = ref._rms_norm(x[0], lp["attn_norm"], cfg.rms_norm_eps)[None]
+    parts = [_st_attention_part(i, st_whole, layer, normed)
+             for i in range(ST_SHARES)]
+    for i, part in enumerate(parts):
+        share, p = _st_share(i, st_whole)
+        assert "q_norm" not in p[layer]["attn"]
+        assert rel(part, ref.gated_attention(
+            p[layer]["attn"], normed[0], pos, seg, cfg, 7, 1,
+            cfg.layer_window(index), gate=False, rope=cfg.layer_rope(index),
+            qk_norm=False)) < 2e-5
+    h = x[0] + sum(parts)
+    ffn_in = ref._rms_norm(h, lp["ffn_norm"], cfg.rms_norm_eps)
+    routed = [_st_routed_part(i, st_whole, layer, x[0], ffn_in)
+              for i in range(ST_SHARES)]
+    for i, (out, _) in enumerate(routed):
+        share, p = _st_share(i, st_whole)
+        assert set(p[layer]["moe"]) == {"experts"}
+        assert rel(out, ref.smallthinker_moe(
+            p[layer]["moe"], x[0], ffn_in, cfg, share.experts_held)) < 2e-5
+    assert rel(h + sum(out for out, _ in routed), want) < 2e-5
+    # every slot of every token lands on exactly one chip
+    assert sum(int(c["moe_slots_held"]) for _, c in routed) == (
+        cfg.seq_len * cfg.moe_num_active_primary_experts)
+    # the whole model's tree cut to a share is the share's own tree
+    share, p = _st_share(1, st_whole)
     from dexiraft_tpu.config import TrainConfig
     from dexiraft_tpu.train.family import family_of
     shapes, _ = jax.eval_shape(family_of(share, TrainConfig()).init,
