@@ -42,6 +42,18 @@ LEVELS, RADIUS, FEAT = 4, 4, 256
 DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
+@pytest.fixture(scope="module", autouse=True)
+def full_optimisation():
+    """This file's subject is the program the chip's compiler makes, so it
+    compiles at the full level (tests/conftest.py sets the lowest for the
+    rest of tier 1). The flag is read at each compile, and every case
+    here compiles anew."""
+    was = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", was)
+
+
 @pytest.fixture(scope="module")
 def topo():
     """The described v5e:2x2; skipped where it cannot be described. The
@@ -95,6 +107,11 @@ def _compiled_text(fn, *args) -> str:
 
 def test_auto_on_tpu_names_what_is_compiled_here():
     assert resolve_corr_impl("auto", "tpu") == ("flash", True)
+
+
+def test_this_file_compiles_at_the_full_level():
+    """What the cases below pin is the optimised program for the chip."""
+    assert jax.config.read("jax_disable_most_optimizations") is False
 
 
 @pytest.mark.parametrize("dtype,hw", [("fp32", (H, W)), ("bf16", (H, W)),

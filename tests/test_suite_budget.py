@@ -56,15 +56,51 @@ def test_six_workers_need_the_largest_file_at_least(tmp_path, capsys):
     assert sum(c[2] for c in cases) / slowest.WORKERS < slowest.BUDGET_S
     assert slowest.main(["", _junit(tmp_path / "t.xml", cases)]) == 1
     out = capsys.readouterr().out
-    assert "need 1200.0 s at best" in out and "tests/test_c.py" in out
+    assert "need 1200.0 s as `loadfile`" in out
+    assert ("the run ends with tests/test_c.py, 1200.0 s, started at 0.0 s"
+            in out)
+    assert "; 1200.0 s at best" in out
     assert "OVER the budget by 100.0 s" in out
 
 
 def test_a_sum_over_the_budget_exits_1(tmp_path, capsys):
-    cases = [(f"tests.test_f{i}", "test_it", 340.0) for i in range(20)]
+    """Twenty files of one test: a worker holds two, so two of the six
+    run four and the others three."""
+    cases = [(f"tests.test_f{i:02}", "test_it", 340.0) for i in range(20)]
     assert max(c[2] for c in cases) < slowest.BUDGET_S
     assert slowest.main(["", _junit(tmp_path / "t.xml", cases)]) == 1
-    assert "need 1133.3 s at best" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "need 1360.0 s as `loadfile`" in out
+    assert "; 1133.3 s at best" in out
+
+
+def test_a_file_of_few_long_tests_starts_last_and_ends_the_run(tmp_path,
+                                                               capsys):
+    """The files are handed out by their number of tests, most first:
+    sixty files of ten one-second tests keep six workers for 100 s, and
+    only then does the file of two 300 s tests start, on one worker. The
+    best case (its 600 s) knows nothing of that tail; the makespan does."""
+    cases = [(f"tests.test_f{i:02}", f"test_{j}", 1.0)
+             for i in range(60) for j in range(10)]
+    cases += [("tests.test_long", f"test_{j}", 300.0) for j in range(2)]
+    assert slowest.main(["", _junit(tmp_path / "t.xml", cases)]) == 0
+    out = capsys.readouterr().out
+    assert "need 700.0 s as `loadfile`" in out
+    assert ("the run ends with tests/test_long.py, 600.0 s, started at "
+            "100.0 s" in out)
+    assert "; 600.0 s at best" in out
+
+
+def test_a_worker_takes_the_next_file_with_two_tests_left():
+    """xdist hands a worker its next file when it has two tests or fewer
+    left, not when it is free: the worker whose last two tests are long
+    takes the file another worker would have reached sooner."""
+    files = {"a.py": [1.0, 100.0, 100.0], "b.py": [1.0, 1.0, 1.0, 5.0, 5.0],
+             **{f"c{i}.py": [2.0] * 4 for i in range(4)},
+             "late.py": [50.0]}
+    makespan, last, started = slowest.play(files)
+    # b.py's worker has two left at 3 s and a.py's at 1 s: late.py is a.py's
+    assert (makespan, last, started) == (251.0, "late.py", 201.0)
 
 
 @pytest.mark.parametrize("content", [None, "", "<testsuites><testsuite>"
