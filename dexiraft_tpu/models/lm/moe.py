@@ -36,8 +36,8 @@ Dropless, in one program shape. A (token, choice) pair is a slot;
 held experts. Slots are sorted by held expert (the others last), and
 the sorted order is cut into chunks of `dispatch_chunk` rows. A chunk
 gathers its tokens, runs the three grouped products of a gated MLP with
-the chunk's own group sizes, and scatter-adds the weighted rows into the
-output. Chunk 0 always runs; the later chunks are a scan under one
+the chunk's own group sizes, and adds the weighted rows back to their
+tokens (below). Chunk 0 always runs; the later chunks are a scan under one
 `lax.cond` that is taken only if held slots pass chunk 0, each of them
 under a `lax.cond` of its own and a `jax.checkpoint`, so chunks that
 seldom run keep nothing for the backward and cost nothing when skipped. At the published balance
@@ -48,10 +48,22 @@ held slots less the rows the chunks took, and stays 0.
 
 The chunk is sized from the shapes (`dispatch_chunk`): a load that
 reaches chunk 1 pays for a whole second chunk, so chunk 0 has to clear
-the expected load with room; every row of room costs its gather and its
-scatter-add whether a slot fills it or not. `cfg.moe_chunk` overrides
-the size (the tests' several chunks at toy sizes; a smaller chunk to
-save memory).
+the expected load with room; every row of room costs its gather
+whether a slot fills it or not. `cfg.moe_chunk` overrides the size (the
+tests' several chunks at toy sizes; a smaller chunk to save memory).
+
+How rows come back. The sort is stable, so inside a held expert's run
+the tokens ascend: for a block of `rows.block_tokens` tokens and one
+expert, the rows that belong to the block are one contiguous range of
+the order. `plan` counts each expert's slots by token block (the loads
+are that table's column sums, so it costs no further pass), and the
+table of where each range starts, cut to a chunk as the group sizes are,
+goes with the chunk's rows to `ops/rows.py`: `gather_rows` takes them
+out (`x[tokens]`) and `segment_add` adds them back with their weights,
+each the other's transpose, the sum a blocked segment sum that writes
+every `[T, D]` tile once and reads no row past the held slots. The
+counters `moe_rows_covered` / `moe_rows_live` say how much of what that
+sum fetches, in whole windows, is rows of a range.
 """
 
 from __future__ import annotations
@@ -63,6 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from dexiraft_tpu.models.lm.layers import SwiGLU, Weights
+from dexiraft_tpu.ops import rows as row_ops
 from dexiraft_tpu.ops.grouped import grouped_matmul
 
 
@@ -91,7 +104,8 @@ ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 # what an expert layer counts of a batch, and how `reduce_counters`
 # takes each over a stack's expert layers
 COUNTERS = {"moe_slots_held": jnp.sum, "moe_load_max": jnp.max,
-            "moe_load_mean": jnp.mean, "moe_dropped_slots": jnp.sum}
+            "moe_load_mean": jnp.mean, "moe_dropped_slots": jnp.sum,
+            "moe_rows_covered": jnp.sum, "moe_rows_live": jnp.sum}
 
 # a dispatch chunk is a whole number of these rows
 _CHUNK_ROWS = 8192
@@ -114,13 +128,15 @@ def dispatch_chunk(slots: int, held: int, experts: int) -> int:
 class Plan(NamedTuple):
     """A batch's routing as the experts' part needs it: the slots sorted
     by held expert and cut into chunks, `[chunks, chunk]` each (a slot's
-    token and its weight), and the held experts' loads."""
+    token and its weight), the held experts' loads, and where in the
+    order each expert's rows of each block of tokens start."""
     slot_token: jax.Array   # int32
     slot_weight: jax.Array  # fp32
     counts: jax.Array       # [held] int32: slots at each held expert
     starts: jax.Array       # [held]: where each one's run starts
     ends: jax.Array
     n_held: jax.Array       # their sum
+    block_lo: jax.Array     # [held, token blocks + 1] int32
 
 
 class RoutedExperts(Weights):
@@ -166,11 +182,19 @@ class RoutedExperts(Weights):
             local = chosen.reshape(-1) - first
             key = jnp.where((local >= 0) & (local < held), local, held)
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
-                             axis=0, dtype=jnp.int32)           # [held]
+            # each held expert's slots by block of tokens; its load is
+            # the sum over the blocks
+            blocks = t // row_ops.block_tokens(t)
+            by_block = jnp.sum(
+                key.reshape(blocks, -1, 1) == jnp.arange(held),
+                axis=1, dtype=jnp.int32)                  # [blocks, held]
+            counts = jnp.sum(by_block, axis=0)                  # [held]
             n_held = jnp.sum(counts)
             ends = jnp.cumsum(counts)
             starts = ends - counts
+            block_lo = jnp.concatenate(
+                [starts[:, None], starts[:, None] + jnp.cumsum(by_block, 0).T],
+                axis=1)
             chunk = min(cfg.moe_chunk or dispatch_chunk(
                 t * top_k, held, cfg.n_routed_experts), t * top_k)
             n_chunks = -(-t * top_k // chunk)
@@ -179,14 +203,15 @@ class RoutedExperts(Weights):
                 n_chunks, chunk)
             slot_weight = jnp.pad(weights.reshape(-1)[order], (0, pad)
                                   ).reshape(n_chunks, chunk)
-        return Plan(slot_token, slot_weight, counts, starts, ends, n_held)
+        return Plan(slot_token, slot_weight, counts, starts, ends, n_held,
+                    block_lo)
 
     def __call__(self, x: jax.Array, plan: Optional[Plan] = None
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """x `[T, D]`, with its routing (None: routed on `x` itself) ->
         (this chip's part of the layer's output `[T, D]`, counters)."""
         t, d = x.shape
-        slot_token, slot_weight, counts, starts, ends, n_held = (
+        slot_token, slot_weight, counts, starts, ends, n_held, block_lo = (
             self.plan(x) if plan is None else plan)
         n_chunks, chunk = slot_token.shape
         act = ACTS[self.cfg.expert_act]
@@ -194,10 +219,17 @@ class RoutedExperts(Weights):
         w_gate, w_up, w_down = (w.astype(self.dtype) for w in
                                 (self.w_gate, self.w_up, self.w_down))
 
-        def run_chunk(y, c, tokens, wts):
+        def chunk_lo(c):
+            """The table for chunk `c`'s rows: cut to it as `sizes` is."""
+            return jnp.clip(block_lo, c * chunk, (c + 1) * chunk) - c * chunk
+
+        def run_chunk(c, tokens, wts):
+            """Chunk `c`'s part of the output `[T, D]` fp32, and the
+            rows it took."""
             lo = c * chunk
             sizes = (jnp.clip(ends, lo, lo + chunk)
                      - jnp.clip(starts, lo, lo + chunk))
+            table = chunk_lo(c)
             # rows past the held slots are in no group, and the grouped
             # product leaves such rows unspecified (on the chip: whatever
             # the buffer held, NaN included). They are zeroed on the way
@@ -207,26 +239,29 @@ class RoutedExperts(Weights):
             live = (lo + jnp.arange(chunk) < n_held)[:, None]
             keep = lambda a: jnp.where(live, a, jnp.zeros((), a.dtype))
             with jax.named_scope("lm/moe/dispatch"):
-                rows = keep(x[tokens])
+                rows = keep(row_ops.gather_rows(x, tokens, table))
             with jax.named_scope("lm/moe/experts"):
                 gate = keep(grouped_matmul(rows, w_gate, sizes))
                 up = keep(grouped_matmul(rows, w_up, sizes))
                 out = keep(grouped_matmul(act(gate) * up, w_down, sizes))
             with jax.named_scope("lm/moe/combine"):
-                y = y.at[tokens].add(out.astype(jnp.float32) * wts[:, None])
-            return y, jnp.sum(sizes)
+                part = row_ops.segment_add(out, tokens, wts, table, t)
+            return part, jnp.sum(sizes)
 
-        y = jnp.zeros((t, d), jnp.float32)
-        y, taken = run_chunk(y, 0, slot_token[0], slot_weight[0])
+        y, taken = run_chunk(0, slot_token[0], slot_weight[0])
         if n_chunks > 1:
             later = jax.checkpoint(run_chunk)
 
             def body(carry, xs):
                 y, taken = carry
                 c, tokens, wts = xs
+
+                def add(y):
+                    part, n = later(c, tokens, wts)
+                    return y + part, n
+
                 y, n = jax.lax.cond(
-                    c * chunk < n_held,
-                    lambda y: later(y, c, tokens, wts),
+                    c * chunk < n_held, add,
                     lambda y: (y, jnp.zeros((), jnp.int32)), y)
                 return (y, taken + n), None
 
@@ -246,11 +281,17 @@ class RoutedExperts(Weights):
                 lambda y: (y, jnp.zeros((), jnp.int32)), y)
             taken = taken + more
 
+        # what the segment sums fetch, in whole windows, and the rows of
+        # a range among it (every held slot, once)
+        every = jax.vmap(chunk_lo)(jnp.arange(n_chunks))
         counters = {
             "moe_slots_held": n_held,
             "moe_load_max": jnp.max(counts),
             "moe_load_mean": jnp.mean(counts.astype(jnp.float32)),
             "moe_dropped_slots": n_held - taken,
+            "moe_rows_covered": row_ops.WINDOW * jnp.sum(
+                row_ops.windows(every[..., :-1], every[..., 1:])),
+            "moe_rows_live": jnp.sum(every[..., 1:] - every[..., :-1]),
         }
         return y.astype(x.dtype), counters
 

@@ -511,28 +511,92 @@ def test_lm_attention_kernel_at_heads_of_64_compiles_for_v5e(chip, which):
     assert "tpu_custom_call" in text and name in text
 
 
+# (tokens, hidden, rows of a dispatch chunk, experts held) of a step
+ROWS_CELLS = {"kanana2": (32768, 2048, 32768, 16),
+              "trinity": (32768, 2048, 49152, 16),
+              "lfm2": (32768, 2048, 49152, 8),
+              "smallthinker": (16384, 2560, 32768, 16)}
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted_fp32", "plain_bf16"])
+@pytest.mark.parametrize("cell", ROWS_CELLS)
+def test_rows_segment_sum_compiles_for_v5e(chip, cell, weighted):
+    """The expert layer's segment sum (ops/rows.py) at the four sparse
+    cells' real shapes, as combine's forward calls it (bf16 rows, fp32
+    weights, an fp32 sum) and as dispatch's backward does (bf16 in and
+    out). The call asks for no VMEM limit, so Mosaic holds it to the one
+    a kernel is given unasked."""
+    from dexiraft_tpu.ops import rows
+
+    tokens, hidden, chunk, held = ROWS_CELLS[cell]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    args = [sds((chunk, hidden), jnp.bfloat16), sds((chunk,), jnp.int32),
+            sds((held, tokens // rows.block_tokens(tokens) + 1), jnp.int32)]
+    if weighted:
+        args.append(sds((chunk,), jnp.float32))
+    text = _compiled_text(
+        lambda r, t, lo, w=None: rows.kernel_segment_sum(
+            r, t, lo, tokens, w, jnp.float32 if weighted else jnp.bfloat16),
+        *args)
+    assert "tpu_custom_call" in text and "rows_segment_sum" in text
+
+
+def _row_scatters_under_the_moves(text, tokens, hidden):
+    """The compiled step's `scatter` instructions with a `[tokens,
+    hidden]` result under `lm/moe/dispatch` or `lm/moe/combine`: what
+    the expert layer's row moves were before ops/rows.py (16 of them in
+    SmallThinker's step on the plain path, none on the kernel's)."""
+    found = []
+    for line in text.splitlines():
+        if " scatter(" not in line or f"[{tokens},{hidden}]" not in (
+                line.split(" scatter(")[0]):
+            continue
+        if "lm/moe/dispatch" in line or "lm/moe/combine" in line:
+            found.append(line.strip()[:200])
+    return found
+
+
+def _step_on_the_chips_paths(cell_name, topo):
+    """The cell's step as `benchmarks/compile_check.py` lowers it, the
+    expert layer's row moves on the kernel too (`ops/rows.py` picks its
+    path from the backend, which here is the CPU), compiled."""
+    import os.path as osp
+    import sys
+    from unittest import mock
+
+    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
+    from benchmarks import harness
+    from dexiraft_tpu.ops import rows
+
+    cell = harness.load_cell(cell_name)
+    lowered = []
+    with mock.patch.object(rows, "_on_tpu", lambda: True):
+        harness.load_runner("lm_train_packed").compile_for(
+            cell, topo, lambda label, program: lowered.append(program))
+    return lowered[0].compile()  # the step; the check's program is
+    #                              compile_check.py's to compile
+
+
 @pytest.mark.slow
 def test_lfm2_step_compiles_for_v5e_and_fits_the_chip(topo):
     """The fourth language cell's whole train step at its real size (5
     layers c f c c c, 8 of 32 experts and heads, 500 M parameters with a
     tied head, one row of 32,768 positions, bf16, every layer recomputed)
     as `benchmarks/compile_check.py` lowers it, the attention layer on
-    the kernel path it takes on the chip. Arguments (masters and AdamW's
-    moments) and temporaries fit 15.75 GB."""
-    import os.path as osp
-    import sys
-
-    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
-    from benchmarks import harness
-
-    cell = harness.load_cell("lfm2-train-pack32k")
-    lowered = []
-    harness.load_runner("lm_train_packed").compile_for(
-        cell, topo, lambda label, program: lowered.append(program))
-    compiled = lowered[0].compile()  # the step; the check's program is
-    text = compiled.as_text()        # compile_check.py's to compile
-    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv"):
+    the kernel path it takes on the chip and the expert layers' rows
+    added back by `rows_segment_sum`, no `[T, D]` scatter left under
+    dispatch or combine. Arguments (masters and AdamW's moments) and
+    temporaries fit 15.75 GB."""
+    compiled = _step_on_the_chips_paths("lfm2-train-pack32k", topo)
+    text = compiled.as_text()
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv",
+                 "rows_segment_sum"):
         assert name in text
+    assert not _row_scatters_under_the_moves(text, 32768, 2048)
     for scope in ("lm/conv/in", "lm/conv/gate", "lm/conv/out",
                   "lm/gqa/full/kernel", "lm/moe/experts"):
         assert scope in text, scope
@@ -550,26 +614,21 @@ def test_smallthinker_step_compiles_for_v5e_and_fits_the_chip(topo):
     layers f s s s, 16 of 64 experts, 7 of 28 query heads on 1 key/value
     head, 594 M parameters, one row of 16,384 positions, bf16, every
     layer recomputed) as `benchmarks/compile_check.py` lowers it, the
-    attention on the kernel path it takes on the chip. The routing opens
-    ahead of attention under the same scopes; nothing is built for a
-    shared expert or a dense layer. Arguments and temporaries fit
-    15.75 GB."""
-    import os.path as osp
-    import sys
-
-    sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
-    from benchmarks import harness
-
-    cell = harness.load_cell("smallthinker-train-pack16k")
-    lowered = []
-    harness.load_runner("lm_train_packed").compile_for(
-        cell, topo, lambda label, program: lowered.append(program))
-    compiled = lowered[0].compile()  # the step; the check's program is
-    text = compiled.as_text()        # compile_check.py's to compile
-    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv"):
+    attention on the kernel path it takes on the chip and the expert
+    layers' rows added back by `rows_segment_sum`, no `[T, D]` scatter
+    left under dispatch or combine (the router's `take_along_axis` and
+    `weights[order]` keep their small ones). The routing opens ahead of
+    attention under the same scopes; nothing is built for a shared
+    expert or a dense layer. Arguments and temporaries fit 15.75 GB."""
+    compiled = _step_on_the_chips_paths("smallthinker-train-pack16k", topo)
+    text = compiled.as_text()
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv",
+                 "rows_segment_sum"):
         assert name in text
+    assert not _row_scatters_under_the_moves(text, 16384, 2560)
     for scope in ("lm/gqa/window/kernel", "lm/gqa/full/kernel",
-                  "lm/moe/router", "lm/moe/dispatch", "lm/moe/experts"):
+                  "lm/moe/router", "lm/moe/dispatch", "lm/moe/experts",
+                  "lm/moe/combine"):
         assert scope in text, scope
     assert "lm/moe/shared" not in text and "lm/mlp" not in text
     memory = compiled.memory_analysis()

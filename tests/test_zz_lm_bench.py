@@ -79,29 +79,37 @@ CELL5 = "smallthinker-train-pack16k"
 NEW5 = ["lm_moe_router_device_ms"]
 SHARED5 = SHARED2 + NEW2
 NEEDS_A_CHIP5 = NEEDS_A_CHIP2 | {"lm_moe_router_device_ms"}
+# PR 47: the expert layer's two row moves timed apart in the four sparse
+# cells, and the share of the segment sums' fetched rows that are rows of
+# a range (the first cell's runner carries four counters by name and not
+# this one: PERF.md section 7)
+NEW47 = ["lm_moe_dispatch_device_ms", "lm_moe_combine_device_ms",
+         "lm_moe_rows_live_pct"]
+SPARSE = [CELL, CELL2, CELL4, CELL5]
 CELLS = {
     CELL: dict(config="kanana-2-30b-a3b-share8", traffic="train-pack8k",
                model="kanana-2-30b-a3b-instruct-2601", shares=8,
                assumes="e_score_correction_bias",
-               reports=FED + NEW + SETUP, needs_a_chip=NEEDS_A_CHIP),
+               reports=FED + NEW + NEW47[:2] + SETUP,
+               needs_a_chip=NEEDS_A_CHIP | set(NEW47[:2])),
     CELL2: dict(config="trinity-mini-share8", traffic="train-pack32k",
                 model="Trinity-Mini", shares=8, assumes="expert_bias",
-                reports=FED + SHARED2 + NEW2 + SETUP,
-                needs_a_chip=NEEDS_A_CHIP2),
+                reports=FED + SHARED2 + NEW2 + NEW47 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP2 | set(NEW47[:2])),
     CELL3: dict(config="evabyte-6.5b-share4", traffic="train-bytes32k",
                 model="EvaByte", shares=4, assumes="adaptive_mu_k",
                 reports=FED + SHARED3 + NEW3 + SETUP,
                 needs_a_chip=NEEDS_A_CHIP3),
     CELL4: dict(config="lfm2-8b-a1b-share4", traffic="train-pack32k-docs2k",
                 model="LFM2-8B-A1B", shares=4, assumes="expert_bias",
-                reports=FED + SHARED4 + NEW4 + SETUP,
-                needs_a_chip=NEEDS_A_CHIP4),
+                reports=FED + SHARED4 + NEW4 + NEW47 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP4 | set(NEW47[:2])),
     CELL5: dict(config="smallthinker-21b-a3b-share4",
                 traffic="train-pack16k",
                 model="SmallThinker-21BA3B-Instruct", shares=4,
                 assumes="router_before_attention",
-                reports=FED + SHARED5 + NEW5 + SETUP,
-                needs_a_chip=NEEDS_A_CHIP5),
+                reports=FED + SHARED5 + NEW5 + NEW47 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP5 | set(NEW47[:2])),
 }
 
 
@@ -231,8 +239,27 @@ def test_manifest_names_the_fifth_cell_and_what_it_reports(manifest):
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
                                    name + ".py"))
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(NEW5):] == NEW5
+    assert names[-len(NEW5 + NEW47):-len(NEW47)] == NEW5  # PR 47's follow
     assert by_name["lm_moe_router_device_ms"]["source"] == "device_trace"
+
+
+def test_manifest_names_the_row_moves_metrics_in_the_sparse_cells(manifest):
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert [m["name"] for m in manifest["per_layer"]][-3:] == NEW47
+    for name in NEW47:
+        m = by_name[name]
+        assert m["layer"] == by_name["lm_moe_device_ms"]["layer"]
+        assert m["moves"] == "train_samples_per_s"
+        assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
+                                   name + ".py"))
+    for name in NEW47[:2]:
+        assert by_name[name]["workloads"] == SPARSE
+        assert (by_name[name]["source"], by_name[name]["better"]) == (
+            "device_trace", "lower")
+    assert by_name["lm_moe_rows_live_pct"]["workloads"] == SPARSE[1:]
+    assert (by_name["lm_moe_rows_live_pct"]["source"],
+            by_name["lm_moe_rows_live_pct"]["better"]) == (
+        "program_counter", "higher")
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
@@ -286,6 +313,9 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report(
     assert counters["moe_dropped_slots"] == 0
     assert counters["flops_per_unit"] > 0
     assert "against their limits" in proc.stdout
+    if cell in SPARSE[1:]:  # the held slots, each in a range once
+        assert counters["moe_rows_live"] == counters["moe_slots_held"]
+        assert counters["moe_rows_covered"] >= counters["moe_rows_live"]
     if cell == CELL2:  # both kinds of layer, counted apart
         assert counters["attn_block_pairs_visited_window"] > 0
         assert counters["attn_block_pairs_visited_full"] > 0
