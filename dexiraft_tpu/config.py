@@ -256,7 +256,11 @@ class DecoderConfig:
     its published keys, its share and the row's length, validates them,
     and overrides the answers that differ (docs/lm.md, "Adding an
     architecture", lists the fields). The shared modules read these
-    names and never a configuration's class.
+    names and never a configuration's class. A stack whose layers hold a
+    mixer or a feed-forward part alone says so layer by layer
+    (`layer_parts`); experts of two matrices, a latent space around the
+    routed path and a share of the shared expert's columns are
+    `expert_gate`, `moe_latent_size` and `shared_width`.
     """
 
     # the published `model_type`: the row of interop/lm_reference.py's
@@ -300,14 +304,33 @@ class DecoderConfig:
     route_score = "sigmoid"
     # an expert's gate function: a key of models/lm/moe.py `ACTS`
     expert_act = "silu"
+    # an expert is `W_down(act(W_gate u) * W_up u)`, three matrices; or,
+    # False, `W_down act(W_up u)`, two and no `w_gate`; the shared expert
+    # alike
+    expert_gate = True
+    # the width the routed experts read and write: 0, the hidden width;
+    # else a latent space, `hidden -> moe_latent_size` ahead of the
+    # dispatch and back after the combine (the router and the shared
+    # expert stay on the hidden width)
+    moe_latent_size = 0
+    # the shared expert's columns held here
+    shared_width = property(
+        lambda self: self.n_shared_experts * self.moe_intermediate_size)
     # a head's width, and the attention kernel's
     head_dim = property(
         lambda self: self.hidden_size // self.num_attention_heads)
     qk_head_dim = property(lambda self: self.head_dim)
     v_head_dim = property(lambda self: self.head_dim)
 
+    def layer_parts(self, i: int) -> Tuple[str, ...]:
+        """What layer `i` holds, each part with one norm in front and
+        its own sum into the stream: "mixer", "ffn", or both in this
+        order."""
+        return ("mixer", "ffn")
+
     def mixer(self, i: int) -> str:
-        """Layer `i`'s mixer: a key of models/lm/attention.py `MIXERS`."""
+        """Layer `i`'s mixer: a key of models/lm/attention.py `MIXERS`
+        (asked of a layer that has one)."""
         return "gqa"
 
     def layer_window(self, i: int) -> Optional[int]:
@@ -723,6 +746,175 @@ class SmallThinkerConfig(DecoderConfig):
         return bool(self.rope_layout[i])
 
 
+# `hybrid_override_pattern` of NVIDIA-Nemotron-3-Super-120B-A12B as
+# published: 40 Mamba-2 layers (M), 40 expert layers (E), 8 attention
+# layers (*)
+_NEMOTRON_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig(DecoderConfig):
+    """A Nemotron-H hybrid decoder (`model_type: nemotron_h`): every
+    layer is ONE block behind one norm, `x + Block(N(x))`, by the
+    letters of `hybrid_override_pattern`: `M` a Mamba-2 mixer (a
+    selective state-space scan in chunks of `chunk_size`, the state
+    carried from chunk to chunk and reset at a document's first token,
+    behind a causal depthwise convolution of `conv_kernel` taps with a
+    bias and a SiLU), `*` grouped-query attention without positional
+    embedding, QK-norm or gate, `E` sigmoid-routed experts of two
+    matrices with a squared ReLU in a latent space of `moe_latent_size`,
+    beside a shared expert on the hidden width (models/lm/, docs/lm.md).
+    Keys and defaults are NVIDIA-Nemotron-3-Super-120B-A12B's published
+    `config.json`.
+
+    The share: `ssm_heads_held` of the `mamba_num_heads` heads in whole
+    B/C groups (`ssm_groups_held`, derived: head h reads group
+    `h // (mamba_num_heads // n_groups)`, and the gated norm's
+    statistics are a group's own), `heads_held` query heads with the
+    `kv_heads_held` they read, `experts_held`, `shared_columns_held` of
+    the shared expert's `moe_shared_expert_intermediate_size` columns,
+    and a `vocab_size`-row slice. The router, the latent projections
+    and the norms are whole. `None` holds everything.
+    """
+
+    vocab_size: int = 131_072
+    hidden_size: int = 4096
+    num_hidden_layers: int = 88
+    # one letter of `layer_kinds` a held layer; None: the published 88
+    hybrid_override_pattern: Optional[str] = None
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    rope_theta: float = 1e4
+    n_routed_experts: int = 512
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 22
+    moe_intermediate_size: int = 2688
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    routed_scaling_factor: float = 5.0
+    norm_topk_prob: bool = True
+    layer_norm_epsilon: float = 1e-5
+    # assumed (docs/lm.md, A1-A6, and the cell's controls): the plain
+    # reference alone reads these, so that a control of the check can
+    # hand it another mechanism; the program builds the published one
+    # whatever they say
+    state_carry: bool = True        # False: the state zeroed at every chunk
+    document_reset: bool = True     # A1. False: state and taps cross documents
+    gate_before_norm: bool = True   # A2. False: norm(y) * silu(z)
+    attention_rope: bool = False    # A3. True: a rotary embedding
+    router_reads_latent: bool = False   # A4. True: the router's rows of z
+    mlp_hidden_act: str = "relu2"   # "relu": not its square
+    gated_experts: bool = False     # A6. True: silu(W_up z) * W_up z
+    d_skip: bool = True             # False: no D * x
+    without_layer: Optional[int] = None  # this held layer adds nothing
+    # assumed: `initializer_range`
+    init_std: float = 0.02
+    seq_len: int = 32_768
+    ssm_heads_held: Optional[Tuple[int, int]] = None
+    ssm_groups_held: Optional[Tuple[int, int]] = None
+    heads_held: Optional[Tuple[int, int]] = None
+    kv_heads_held: Optional[Tuple[int, int]] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    shared_columns_held: Optional[Tuple[int, int]] = None
+    mixed_precision: bool = False
+    remat: bool = False
+    attn_block: int = 1024
+    moe_chunk: Optional[int] = None
+
+    def __post_init__(self):
+        pattern = self.hybrid_override_pattern
+        if pattern is None and self.num_hidden_layers == len(
+                _NEMOTRON_PATTERN):
+            pattern = _NEMOTRON_PATTERN
+        if (pattern is None or len(pattern) != self.num_hidden_layers
+                or set(pattern) - set(self.layer_kinds)):
+            raise ValueError(
+                f"hybrid_override_pattern={pattern!r}: one letter of "
+                f"{self.layer_kinds} for each of {self.num_hidden_layers} "
+                "layers (only the published depth has a default)")
+        object.__setattr__(self, "hybrid_override_pattern", pattern)
+        heads, groups = self.mamba_num_heads, self.n_groups
+        if heads % groups or self.chunk_size < 1 or self.conv_kernel < 1:
+            raise ValueError(
+                f"{heads} Mamba heads over {groups} groups, chunks of "
+                f"{self.chunk_size}, {self.conv_kernel} taps")
+        if self.seq_len % self.chunk_size:
+            raise ValueError(f"seq_len {self.seq_len} is not whole chunks "
+                             f"of {self.chunk_size}")
+        _hold(self, "ssm_heads_held", heads)
+        per = heads // groups
+        first, count = self.ssm_heads_held
+        if first % per or count % per:
+            raise ValueError(
+                f"ssm_heads_held={self.ssm_heads_held} splits a group of "
+                f"{per} heads: a chip holds a B/C group's heads whole (the "
+                "gated norm's statistics are the group's)")
+        if self.ssm_groups_held is None:
+            object.__setattr__(self, "ssm_groups_held",
+                               (first // per, count // per))
+        if tuple(self.ssm_groups_held) != (first // per, count // per):
+            raise ValueError(
+                f"ssm_groups_held={self.ssm_groups_held} are not the groups "
+                f"of ssm_heads_held={self.ssm_heads_held}")
+        _hold(self, "ssm_groups_held", groups)
+        _hold_grouped_heads(self)
+        _hold(self, "experts_held", self.n_routed_experts)
+        _hold(self, "shared_columns_held",
+              self.moe_shared_expert_intermediate_size)
+        _whole_attention_blocks(self)
+
+    model_type = "nemotron_h"
+    layer_kinds = ("M", "*", "E")
+    qk_norm = False
+    first_k_dense_replace = 0
+    expert_act = "relu2"
+    expert_gate = False
+    # the stand-in for a trained stream, as SmallThinker's: a token's own
+    # row outweighs what the layers add to it. A squared ReLU has a mean,
+    # so every expert layer adds the same vector to every token (the
+    # shared expert's alone has a deviation of 0.4 an entry), and the
+    # router of the next one, which reads the normed stream, then prefers
+    # the same experts for every token: with the rows at deviation 1 the
+    # fullest held expert of the deepest layer took 4.1 times the mean
+    # and the overflow chunks ran (PERF.md, PR 48, call 1); at 16 the
+    # deviation of the 512 loads over their mean is 0.10-0.14 by layer
+    # where 0.09 is the draw's own noise (CPU, real widths, 4,096
+    # positions: 0.56-0.94 at 1, 0.23-0.43 at 4, 0.14-0.23 at 8). What
+    # 16 stands for, measured there too: the eleven layers are published
+    # layers 27-37, which meet a stream 27 blocks built, so a block adds
+    # between 1/27 and 1/sqrt(27) of it, 4-19 %; here a block adds 4-6.5
+    # % of the stream it meets at 16 (8-13 % at 8, 14-26 % at 4, 24-88 %
+    # at 1) and the vector all tokens share is 3-6 % of a token's own
+    # part at an expert layer's input (27-39 % at 1). A trained router
+    # is balanced by its bias buffer, whose update rule the config does
+    # not give
+    embed_init_std = 16.0
+    rms_norm_eps = property(lambda self: self.layer_norm_epsilon)
+    shared_width = property(lambda self: self.shared_columns_held[1])
+
+    def layer_parts(self, i: int) -> Tuple[str, ...]:
+        return ("ffn",) if self.hybrid_override_pattern[i] == "E" else (
+            "mixer",)
+
+    def mixer(self, i: int) -> str:
+        return "mamba2" if self.hybrid_override_pattern[i] == "M" else "gqa"
+
+    def layer_rope(self, i: int) -> bool:
+        return False
+
+
 def _hold(cfg, name: str, whole: int) -> None:
     """A `(first, count)` share of `whole`, or None for all of it."""
     held = getattr(cfg, name)
@@ -870,6 +1062,32 @@ def smallthinker_21b_toy(**kw) -> SmallThinkerConfig:
     return SmallThinkerConfig(**{**base, **kw})
 
 
+def nemotron_h(**kw) -> NemotronHConfig:
+    """NVIDIA-Nemotron-3-Super-120B-A12B as published; the shares
+    (`ssm_heads_held`, `heads_held`, `kv_heads_held`, `experts_held`,
+    `shared_columns_held`), `vocab_size`, `num_hidden_layers` and
+    `hybrid_override_pattern` cut it to a chip's share
+    (benchmarks/configs/nemotron-3-super-120b-a12b-share64.json)."""
+    return NemotronHConfig(**kw)
+
+
+def nemotron_h_toy(**kw) -> NemotronHConfig:
+    """The CPU tests' size: every mechanism, toy widths. 8 Mamba heads
+    of 8 with a state of 16 over 4 groups (2 heads a group), chunks of
+    16 (the tests' documents start inside a chunk); 8 query heads of 8
+    on 2 key/value heads; 16 experts top 3 of width 24 in a latent space
+    of 32, a shared expert of 48 columns; M E M * E."""
+    base = dict(vocab_size=256, hidden_size=64, num_hidden_layers=5,
+                hybrid_override_pattern="MEM*E", mamba_num_heads=8,
+                mamba_head_dim=8, ssm_state_size=16, n_groups=4,
+                chunk_size=16, num_attention_heads=8,
+                num_key_value_heads=2, head_dim=8, n_routed_experts=16,
+                num_experts_per_tok=3, moe_intermediate_size=24,
+                moe_latent_size=32, moe_shared_expert_intermediate_size=48,
+                seq_len=128, attn_block=32, moe_chunk=64)
+    return NemotronHConfig(**{**base, **kw})
+
+
 # language models `train --variant` takes beside VARIANTS. Not in
 # VARIANTS: eval, serve and video have no path for them (ROADMAP.md).
 LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
@@ -879,7 +1097,8 @@ LM_VARIANTS = {"kanana2": kanana2, "kanana2-toy": kanana2_toy,
                "lfm2-8b-a1b": lfm2_8b_a1b,
                "lfm2-8b-a1b-toy": lfm2_8b_a1b_toy,
                "smallthinker-21b": smallthinker_21b,
-               "smallthinker-21b-toy": smallthinker_21b_toy}
+               "smallthinker-21b-toy": smallthinker_21b_toy,
+               "nemotron-h": nemotron_h, "nemotron-h-toy": nemotron_h_toy}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,7 +1,7 @@
 """The plain reference of the language models: forward, loss and gradients
 in `jax.numpy`, float32, under `jax.default_matmul_precision("highest")`.
 
-Five architectures, picked by the configuration's published `model_type`
+Six architectures, picked by the configuration's published `model_type`
 from `_ARCHS`, the table further down. `deepseek_v3`: written from the
 published `config.json` of kanana-2-30b-a3b-instruct-2601. `afmoe`: from
 Trinity-Mini's and, for what the config does not carry (the four norms
@@ -23,6 +23,19 @@ from the layer's input ahead of attention, a softmax over all experts,
 the top few and their sum; `gated_attention` without gate or QK-norm,
 window and rotary embedding by the two published layouts; ReLU-gated
 experts, none shared).
+`nemotron_h`: from NVIDIA-Nemotron-3-Super-120B-A12B's and the layers as
+docs/lm.md writes them down (`nemotron_layer`: one norm and one block a
+layer; `mamba2`: the state-space layer as the recurrence itself, token
+by token, a `lax.scan` over the positions nested and checkpointed in
+blocks, which shares nothing with the chunked form of ops/lm_ssm.py, the
+convolution's taps as looked-up shifted sums; `gated_attention` without
+gate, QK-norm or rotary embedding; `nemotron_moe`: the router on the
+hidden width, two-matrix experts with a squared ReLU in the latent
+space, the shared expert on the hidden width). Its configuration carries
+the fields a control of the check changes (`state_carry`,
+`document_reset`, `gate_before_norm`, `attention_rope`,
+`router_reads_latent`, `mlp_hidden_act`, `gated_experts`, `d_skip`,
+`without_layer`): this file alone reads them.
 A row says what its architecture does by itself, from the published
 keys: it reads none of the answers `config.DecoderConfig` derives for
 models/lm (`post_norms`, `embed_scale`, `tie_embedding`, ...), so a wrong
@@ -198,6 +211,24 @@ def gated_attention(p, x, positions, segment_ids, cfg, heads: int,
     return out @ p["wo"]
 
 
+def _looked_up_taps(a, taps, segment_ids, within_documents: bool = True):
+    """sum_j taps[:, j] a[m] [m >= 0 and d(m) = d(n)], m = n - (L-1) + j,
+    for a [S, H] and taps [H, L]: each tap looks its source position up
+    with its document id beside it (`within_documents` False: the row's
+    start alone cuts a tap)."""
+    n = jnp.arange(a.shape[0])
+    length = taps.shape[1]
+    total = jnp.zeros_like(a)
+    for j in range(length):
+        m = n - (length - 1) + j
+        inside = m >= 0
+        if within_documents:
+            inside &= segment_ids[jnp.maximum(m, 0)] == segment_ids
+        total = total + taps[:, j] * jnp.where(
+            inside[:, None], a[jnp.maximum(m, 0)], 0.0)
+    return total
+
+
 def short_conv(p, x, segment_ids, cfg):
     """One sequence of lfm2_moe's convolution mixer: x [S, D].
 
@@ -208,16 +239,8 @@ def short_conv(p, x, segment_ids, cfg):
     Each tap looks its source position up (`a[m]`, `d[m]`), so a tap
     that would cross a document's first token reads zero as one before
     position 0 does: the document's outputs are what it gives alone."""
-    s, taps = x.shape[0], cfg.conv_L_cache
     b, c, z = jnp.split(x @ p["w_in"], 3, axis=-1)
-    a = b * z
-    n = jnp.arange(s)
-    total = jnp.zeros_like(a)
-    for j in range(taps):
-        m = n - (taps - 1) + j
-        inside = (m >= 0) & (segment_ids[jnp.maximum(m, 0)] == segment_ids)
-        total = total + p["taps"][:, j] * jnp.where(
-            inside[:, None], a[jnp.maximum(m, 0)], 0.0)
+    total = _looked_up_taps(b * z, p["taps"], segment_ids)
     return (c * total) @ p["w_out"]
 
 
@@ -435,6 +458,131 @@ def smallthinker_layer(p, x, positions, segment_ids, cfg, index: int,
         block, x, _rms_norm(h, p["ffn_norm"], eps))
 
 
+# positions of one checkpointed block of `mamba2`'s recurrence
+_SCAN_BLOCK = 256
+
+
+def mamba2(p, u, segment_ids, cfg, heads: int, groups: int):
+    """One sequence of nemotron_h's Mamba-2 mixer: u [S, D]; `p` holds
+    `heads` heads' columns and the `groups` B/C groups they read, head h
+    reading group `h // (heads // groups)`.
+
+        [z; xBC; dt] = W_in u;  xBC = silu(conv(xBC) + b)  (taps looked up)
+        dt = softplus(dt + dt_bias);  a = exp(dt A),  A = -exp(A_log)
+        h_t = r_t a_t h_{t-1} + dt_t x_t (x) B_t;   y_t = h_t C_t + D x_t
+        out = W_out RMSNorm_group(y * silu(z))
+
+    with r_t = 0 at a document's first token. The recurrence is run as
+    written, a position at a time; blocks of `_SCAN_BLOCK` positions are
+    recomputed in the backward, so a row of 32,768 keeps 128 states and
+    not every one."""
+    s = u.shape[0]
+    hp, n = cfg.mamba_head_dim, cfg.ssm_state_size
+    inner, bc = heads * hp, groups * n
+    proj = u @ p["in_proj"]
+    z, xbc, dt = (proj[:, :inner], proj[:, inner:2 * inner + 2 * bc],
+                  proj[:, 2 * inner + 2 * bc:])
+    at = jnp.arange(s)
+    total = _looked_up_taps(xbc, p["taps"], segment_ids, cfg.document_reset)
+    xbc = jax.nn.silu(total + p["conv_bias"])
+    x = xbc[:, :inner].reshape(s, heads, hp)
+    b = jnp.repeat(xbc[:, inner:inner + bc].reshape(s, groups, n),
+                   heads // groups, axis=1)
+    c = jnp.repeat(xbc[:, inner + bc:].reshape(s, groups, n),
+                   heads // groups, axis=1)
+    dt = jax.nn.softplus(dt + p["dt_bias"])
+    decay = jnp.exp(dt * -jnp.exp(p["A_log"]))                  # [S, heads]
+    carried = jnp.ones((s,), bool).at[0].set(False)
+    if cfg.document_reset:
+        carried &= segment_ids == jnp.roll(segment_ids, 1)
+    if not cfg.state_carry:
+        carried &= at % cfg.chunk_size != 0
+    decay = decay * carried[:, None].astype(decay.dtype)
+
+    def token(h, xs):
+        x_t, b_t, c_t, dt_t, a_t = xs
+        h = (a_t[:, None, None] * h
+             + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :])
+        return h, jnp.einsum("hpn,hn->hp", h, c_t)
+
+    def rows(h, xs):
+        return jax.lax.scan(token, h, xs)
+
+    xs = (x, b, c, dt, decay)
+    h0 = jnp.zeros((heads, hp, n), u.dtype)
+    if s % _SCAN_BLOCK == 0 and s > _SCAN_BLOCK:
+        cut = tuple(v.reshape((-1, _SCAN_BLOCK) + v.shape[1:]) for v in xs)
+        _, y = jax.lax.scan(jax.checkpoint(rows), h0, cut)
+        y = y.reshape(s, heads, hp)
+    else:
+        _, y = rows(h0, xs)
+    if cfg.d_skip:
+        y = y + p["D"][:, None] * x
+    y, gate = y.reshape(s, groups, -1), jax.nn.silu(z).reshape(s, groups, -1)
+    gain = p["norm"].reshape(groups, -1)
+    eps = cfg.layer_norm_epsilon
+    y = (_rms_norm(y * gate, gain, eps) if cfg.gate_before_norm
+         else _rms_norm(y, gain, eps) * gate)
+    return y.reshape(s, inner) @ p["out_proj"]
+
+
+def nemotron_moe(p, u, cfg, experts_held: Tuple[int, int], bias=None):
+    """One sequence of nemotron_h's expert layer: u [S, D], the layer's
+    normed input.
+
+        s = sigmoid(W_r u) over all experts (assumed A4: u, not z);
+        the top few of s + b, their s over their sum, times the scale
+        z = W_lat_down u;   Expert_i(z) = W_down,i relu(W_up,i z)^2
+        out = W_lat_up sum_chosen w_i Expert_i(z) + W_sd relu(W_su u)^2
+
+    Every held expert is applied to every token."""
+    act = {"relu2": lambda v: jnp.square(jax.nn.relu(v)),
+           "relu": jax.nn.relu}[cfg.mlp_hidden_act]
+    z = u @ p["latent_down"]
+    router, read = p["experts"]["router"], u
+    if cfg.router_reads_latent:
+        router, read = router[:z.shape[1]], z
+    chosen, w = routing({"router": router}, read, cfg, bias)
+    first, count = experts_held
+    routed = jnp.zeros_like(z)
+    for j in range(count):
+        w_j = jnp.sum(jnp.where(chosen == first + j, w, 0.0), axis=-1)
+        up = z @ p["experts"]["w_up"][j]
+        mid = jax.nn.silu(up) * up if cfg.gated_experts else act(up)
+        routed = routed + w_j[:, None] * (mid @ p["experts"]["w_down"][j])
+    return (routed @ p["latent_up"]
+            + act(u @ p["shared"]["w_up"]) @ p["shared"]["w_down"])
+
+
+def nemotron_layer(p, x, positions, segment_ids, cfg, index: int,
+                   ssm_heads_held: Share = None, heads_held: Share = None,
+                   kv_heads_held: Share = None, experts_held: Share = None,
+                   bias=None, block: Optional[int] = None):
+    """x' = x + Block(N(x)); one sequence, layer `index` of the layers
+    held: the block its letter of `hybrid_override_pattern` names."""
+    kind = cfg.hybrid_override_pattern[index]
+    eps = cfg.layer_norm_epsilon
+    if index == cfg.without_layer:
+        return x
+    if kind == "E":
+        return x + _by_blocks(
+            lambda rows: nemotron_moe(p["moe"], rows, cfg,
+                                      experts_held or cfg.experts_held, bias),
+            block, _rms_norm(x, p["ffn_norm"], eps))
+    u = _rms_norm(x, p["attn_norm"], eps)
+    if kind == "M":
+        heads = (ssm_heads_held or cfg.ssm_heads_held)[1]
+        return x + mamba2(
+            p["ssm"], u, segment_ids, cfg, heads,
+            heads // (cfg.mamba_num_heads // cfg.n_groups))
+    return x + gated_attention(
+        p["attn"], u, positions, segment_ids, cfg,
+        (heads_held or cfg.heads_held)[1],
+        (kv_heads_held or cfg.kv_heads_held)[1], None, block, gate=False,
+        rope=cfg.attention_rope, qk_norm=False)
+
+
+
 def _gqa_cut(cfg, kv_heads_held: Share):
     hd = cfg.head_dim
     return {"wq": (hd, 1), "wg": (hd, 1), "wo": (hd, 0),
@@ -488,6 +636,9 @@ _ARCHS = {
     "smallthinker": _Arch(
         smallthinker_layer, _gqa_cut,
         lambda cfg, i: (cfg.sliding_window_layout[i], cfg.rope_layout[i])),
+    "nemotron_h": _Arch(nemotron_layer, _gqa_cut,
+                        lambda cfg, i: (cfg.hybrid_override_pattern[i],
+                                        i == cfg.without_layer)),
 }
 
 
@@ -667,7 +818,9 @@ def blocked_loss_and_grads(params, batch, cfg, dtype=jnp.float32,
 
 
 def take_share(params, cfg, heads_held: Tuple[int, int],
-               experts_held: Share = None, kv_heads_held: Share = None):
+               experts_held: Share = None, kv_heads_held: Share = None,
+               ssm_heads_held: Share = None,
+               shared_columns_held: Share = None):
     """From the parameters of a model that holds everything, the tree of
     the chip that holds `heads_held` and `experts_held`: the held heads'
     columns of `wq` and `wkvb` (afmoe: of `wq` and `wg`, and the held
@@ -675,7 +828,9 @@ def take_share(params, cfg, heads_held: Tuple[int, int],
     and `wv`, and the held heads' rows of `phi` and `mu_k`), their rows
     of `wo`, the held experts' matrices. The router, the latent projection, the
     norms, the shared experts, the embedding and the head are whole on
-    every chip."""
+    every chip. nemotron_h: also the `ssm_heads_held` Mamba heads with
+    their B/C groups (`_ssm_share`) and the `shared_columns_held`
+    columns of the shared expert."""
     def heads(mat, per_head, axis, held=heads_held):
         lo, hi = held[0] * per_head, (held[0] + held[1]) * per_head
         return mat[:, lo:hi] if axis == 1 else mat[lo:hi]
@@ -689,11 +844,47 @@ def take_share(params, cfg, heads_held: Tuple[int, int],
             lp["attn"] = dict(lp["attn"], **{
                 k: heads(lp["attn"][k], *how) for k, how in cut.items()
                 if k in lp["attn"]})
+        if "ssm" in lp:
+            lp["ssm"] = _ssm_share(lp["ssm"], cfg, ssm_heads_held)
         if "moe" in lp:
             e0, en = experts_held
             experts = dict(lp["moe"]["experts"])
-            for k in ("w_gate", "w_up", "w_down"):
+            for k in experts.keys() & {"w_gate", "w_up", "w_down"}:
                 experts[k] = experts[k][e0:e0 + en]
             lp["moe"] = dict(lp["moe"], experts=experts)
+            if shared_columns_held is not None:
+                c0, cn = shared_columns_held
+                shared = lp["moe"]["shared"]
+                lp["moe"]["shared"] = {
+                    "w_up": shared["w_up"][:, c0:c0 + cn],
+                    "w_down": shared["w_down"][c0:c0 + cn]}
         out[f"layers_{i}"] = lp
     return out
+
+
+def _ssm_share(p, cfg, heads_held: Tuple[int, int]):
+    """The Mamba-2 mixer's tree for the chip that holds `heads_held` of
+    `cfg.mamba_num_heads` heads (whole groups): `in_proj`'s columns are
+    [z; x; B; C; dt] and the convolution's channels [x; B; C], each cut
+    to the held heads or to the groups they read."""
+    h0, hn = heads_held
+    per = cfg.mamba_num_heads // cfg.n_groups
+    hp, n = cfg.mamba_head_dim, cfg.ssm_state_size
+    inner, bc = cfg.mamba_num_heads * hp, cfg.n_groups * n
+    heads = slice(h0 * hp, (h0 + hn) * hp)
+    groups = slice(h0 // per * n, (h0 + hn) // per * n)
+
+    def parts(mat, axis, pieces):
+        mat = jnp.moveaxis(mat, axis, 0)
+        return jnp.moveaxis(jnp.concatenate(
+            [mat[at:at + size][cut] for at, size, cut in pieces]), 0, axis)
+
+    conv = [(0, inner, heads), (inner, bc, groups), (inner + bc, bc, groups)]
+    return dict(
+        p, in_proj=parts(p["in_proj"], 1, [(0, inner, heads)] + [
+            (at + inner, size, cut) for at, size, cut in conv] + [
+            (2 * inner + 2 * bc, cfg.mamba_num_heads, slice(h0, h0 + hn))]),
+        taps=parts(p["taps"], 0, conv),
+        conv_bias=parts(p["conv_bias"], 0, conv),
+        norm=p["norm"][heads], out_proj=p["out_proj"][heads],
+        **{k: p[k][h0:h0 + hn] for k in ("dt_bias", "A_log", "D")})

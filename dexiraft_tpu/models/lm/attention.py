@@ -64,6 +64,25 @@ its share of the channels).
 
 `W_q`, `W_k`, `W_v` hold the held heads' columns, `W_o` their rows, `phi`
 and `mu_k` their rows: a head's summaries are its own.
+
+**`Mamba2`** (`mamba2`): the selective state-space mixer (ops/lm_ssm.py
+has the scan's equations, ops/lm_conv.py the convolution's).
+
+    [z; x; B; C; dt] = W_in u        H P, H P, G N, G N, H columns
+    [x; B; C] = silu(conv_L([x; B; C]) + b)   depthwise, causal, within documents
+    dt = softplus(dt + dt_bias);  A = -exp(A_log)        fp32, a head each
+    y  = scan(x, dt, A, B, C) + D x   the state reset at a document's start
+    out = W_out RMSNorm_group(y * silu(z))    the statistics a group's own
+
+`in_proj` holds the held heads' columns of z, x and dt and the held
+groups' of B and C, `taps` and `conv_bias` the same channels of
+[x; B; C], `dt_bias`, `A_log`, `D` the held heads' entries, `norm` and
+`out_proj` their channels' gains and rows. A chip holds whole groups
+(`cfg.ssm_heads_held`, `cfg.ssm_groups_held`), so nothing of the norm
+crosses chips. `A_log` starts at `log(1 + h mod 16)` of the model's own
+head number h, `D` at 1, `dt_bias` at the inverse softplus of a step
+log-uniform in [`time_step_min`, `time_step_max`] floored at
+`time_step_floor`.
 """
 
 from __future__ import annotations
@@ -78,8 +97,10 @@ from dexiraft_tpu.models.lm.layers import (Weights, rms_norm, rope_half,
                                            rope_interleaved)
 from dexiraft_tpu.ops.lm_attention import (block_pair_counts,
                                            document_attention, kernel_blocks)
-from dexiraft_tpu.ops.lm_conv import gated_short_conv, taps_masked
+from dexiraft_tpu.ops.lm_conv import (causal_conv, gated_short_conv,
+                                      taps_masked)
 from dexiraft_tpu.ops.lm_eva import eva_attention, local_ids, pair_counts
+from dexiraft_tpu.ops.lm_ssm import doc_counts, ssm_scan
 
 
 class Mixer(Weights):
@@ -268,14 +289,78 @@ class EvaAttention(Mixer):
                 "wo", (heads * hd, d))
 
 
+class Mamba2(Mixer):
+    TREE = "ssm"
+    # the resets the layers apply to real tokens and the chunks that
+    # hold one, each summed over the layers
+    COUNTERS = ("ssm_doc_starts", "ssm_chunks_reset")
+
+    @staticmethod
+    def counters(cfg, segment_ids, layers):
+        counts = doc_counts(segment_ids, cfg.chunk_size)
+        return {name: count * sum(layers.values())
+                for name, count in zip(Mamba2.COUNTERS, counts)}
+
+    @nn.compact
+    def __call__(self, x: jax.Array, positions: jax.Array,
+                 segment_ids: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        b, s, d = x.shape
+        (first, heads), groups = cfg.ssm_heads_held, cfg.ssm_groups_held[1]
+        p, n = cfg.mamba_head_dim, cfg.ssm_state_size
+        inner, bc = heads * p, groups * n
+        fp32 = lambda name, init: self.param(  # noqa: E731
+            name, init, (heads,), jnp.float32)
+
+        def dt_bias_init(key, shape, dtype):
+            lo, hi = jnp.log(cfg.time_step_min), jnp.log(cfg.time_step_max)
+            step = jnp.maximum(jnp.exp(
+                jax.random.uniform(key, shape, dtype) * (hi - lo) + lo),
+                cfg.time_step_floor)
+            return step + jnp.log(-jnp.expm1(-step))
+
+        with jax.named_scope("lm/ssm/in"):
+            z, xbc, dt = jnp.split(
+                x @ self.w("in_proj", (d, 2 * inner + 2 * bc + heads)),
+                (inner, 2 * inner + 2 * bc), axis=-1)
+        with jax.named_scope("lm/ssm/conv"):
+            xbc = causal_conv(
+                xbc, self.w("taps", (inner + 2 * bc, cfg.conv_kernel)),
+                self.param("conv_bias", nn.initializers.zeros,
+                           (inner + 2 * bc,), jnp.float32), segment_ids)
+        with jax.named_scope("lm/ssm/scan"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + fp32("dt_bias", dt_bias_init))
+            # an fp32 array of its own type: a weakly typed leaf turns
+            # strong in the first step, and the step compiles twice
+            a = -jnp.exp(fp32("A_log", lambda *_: jnp.log(
+                (1 + (first + jnp.arange(heads)) % 16).astype(jnp.float32))))
+            y = ssm_scan(
+                xbc[..., :inner].reshape(b, s, heads, p), dt, a,
+                xbc[..., inner:inner + bc].reshape(b, s, groups, n),
+                xbc[..., inner + bc:].reshape(b, s, groups, n),
+                fp32("D", nn.initializers.ones), segment_ids,
+                cfg.chunk_size)
+        with jax.named_scope("lm/ssm/gate_norm"):
+            gated = (y.reshape(b, s, inner).astype(jnp.float32)
+                     * jax.nn.silu(z.astype(jnp.float32)))
+            gain = self.param("norm", nn.initializers.ones, (inner,),
+                              jnp.float32)
+            y = rms_norm(gated.reshape(b, s, groups, -1),
+                         gain.reshape(groups, -1), cfg.rms_norm_eps
+                         ).astype(x.dtype).reshape(b, s, inner)
+        with jax.named_scope("lm/ssm/out"):
+            return y @ self.w("out_proj", (inner, d))
+
+
 # a configuration's `mixer(i)` -> the module
 MIXERS = {"mla": LatentAttention, "gqa": GatedAttention,
-          "eva": EvaAttention, "conv": ShortConv}
+          "eva": EvaAttention, "conv": ShortConv, "mamba2": Mamba2}
 
 
 def mixer_of(cfg, layer: int, **kw) -> nn.Module:
-    """Layer `layer`'s mixer, named as its sub-tree (`attn`, or `conv`
-    for the convolution)."""
+    """Layer `layer`'s mixer, named as its sub-tree (`attn`, `conv` for
+    the convolution, `ssm` for the state-space mixer)."""
     module = MIXERS[cfg.mixer(layer)]
     return module(cfg=cfg, window=cfg.layer_window(layer),
                   rope=cfg.layer_rope(layer), name=module.TREE, **kw)

@@ -1,6 +1,6 @@
 """What every decoder layer shares: RMSNorm, the rotary embedding on
-interleaved pairs and on half-rotated ones, the SwiGLU MLP and the
-fp32-master parameter."""
+interleaved pairs and on half-rotated ones, the SwiGLU MLP, the MLP of
+two matrices and the fp32-master parameter."""
 
 from __future__ import annotations
 
@@ -102,3 +102,16 @@ class SwiGLU(Weights):
 
         return jax.lax.map(rows, x.reshape(-1, _ROW_BLOCK, d)
                            ).reshape(x.shape)
+
+
+class ActMLP(Weights):
+    """W_down act(W_up x): an MLP of two matrices, no gate."""
+
+    width: int = 0
+    act: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        return self.act(x @ self.w("w_up", (d, self.width))) @ self.w(
+            "w_down", (self.width, d))

@@ -4,7 +4,13 @@ the configuration answers is in brackets:
     x0 = E[id] * embed_scale
     h  = x + Attn_l(N1(x));       x' = h + FFN(N2(h))
     h  = x + N2(Attn_l(N1(x)));   x' = h + N4(FFN(N3(h)))      [post_norms]
+    x' = x + Attn_l(N1(x))   or   x' = x + FFN(N2(x))          [layer_parts]
     logits = W_head RMSNorm(x_last)
+
+A layer holds a mixer and a feed-forward part, each behind its norm and
+with its own sum into the stream, or one of the two alone
+[`layer_parts(l)`]: the half a layer does not have is not built, and its
+norm is not.
 
 The norms' gains are the parameters, or `1 + g` [norm_add_unit_offset];
 the two sums are in the stream's dtype, or in fp32 [fp32_skip_add];
@@ -12,8 +18,9 @@ the two sums are in the stream's dtype, or in fp32 [fp32_skip_add];
 E's transpose and no parameter of its own [tie_embedding].
 
 `Attn_l` is layer `l`'s mixer (models/lm/attention.py `mixer_of`, by
-the configuration's `mixer(l)`, `layer_window(l)` and `layer_rope(l)`).
-FFN is a SwiGLU of `intermediate_size` in the first
+the configuration's `mixer(l)`, `layer_window(l)` and `layer_rope(l)`):
+attention of four kinds, a gated short convolution or a state-space
+scan. FFN is a SwiGLU of `intermediate_size` in the first
 `first_k_dense_replace` layers and the expert layer after them
 (models/lm/moe.py), whose router reads what its experts read, `N2(h)`,
 or the layer's input `x` itself [router_reads]: then the routing and the
@@ -87,15 +94,20 @@ class DecoderLayer(Weights):
                 return rms_norm(t, _gain(self, cfg, name, t.shape[-1]),
                                 cfg.rms_norm_eps)
 
+        parts = cfg.layer_parts(self.index)
         experts = plan = None
-        if self.index >= cfg.first_k_dense_replace:
+        if "ffn" in parts and self.index >= cfg.first_k_dense_replace:
             experts = moe.MoE(cfg=cfg, name="moe", **kw)
             if cfg.router_reads == "layer":
                 plan = experts.plan(x)
-        out = mixer_of(cfg, self.index, **kw)(
-            norm("attn_norm", x), positions, segment_ids)
-        h = _skip_add(cfg, x, norm("attn_post_norm", out)
-                      if cfg.post_norms else out)
+        h = x
+        if "mixer" in parts:
+            out = mixer_of(cfg, self.index, **kw)(
+                norm("attn_norm", x), positions, segment_ids)
+            h = _skip_add(cfg, x, norm("attn_post_norm", out)
+                          if cfg.post_norms else out)
+        if "ffn" not in parts:
+            return h, {}
         normed = norm("ffn_norm", h)
         if experts is None:
             with jax.named_scope("lm/mlp"):
@@ -163,7 +175,8 @@ def _attention_counters(cfg, segment_ids: jax.Array) -> Dict[str, jax.Array]:
     table's order, with the number of its layers by window."""
     layers = collections.defaultdict(collections.Counter)
     for i in range(cfg.num_hidden_layers):
-        layers[cfg.mixer(i)][cfg.layer_window(i)] += 1
+        if "mixer" in cfg.layer_parts(i):
+            layers[cfg.mixer(i)][cfg.layer_window(i)] += 1
     out: Dict[str, jax.Array] = {}
     for kind, mixer in MIXERS.items():
         if kind in layers:

@@ -23,19 +23,29 @@ depends on the routing and not on the experts' input, and
 `__call__(x, plan)` the experts' part.
 
 This chip then computes `sum_i w_i Expert_i(x)` over the chosen experts
-it holds (`cfg.experts_held`), an expert
-`W_down(act(W_gate x) * W_up x)` with the configuration's `expert_act`,
-plus the shared experts, which every chip of the group computes alike (a
-configuration with `n_shared_experts == 0` has none, and nothing is
-built for them). What the absent experts would add is left out: in a
-deployment it arrives with the expert-parallel sum. No code stands in
-for that exchange.
+it holds (`cfg.experts_held`). An expert is three matrices,
+`W_down(act(W_gate x) * W_up x)`, or two, `W_down act(W_up x)`, with no
+`w_gate` in the tree (`cfg.expert_gate`), `act` the configuration's
+`expert_act`; the shared expert has the same form at `cfg.shared_width`
+columns (all of `n_shared_experts * moe_intermediate_size`, or the
+columns of it this chip holds: its activation is elementwise, so the
+chips' column shares add up), and a configuration with
+`n_shared_experts == 0` has none and nothing is built for it. Under
+`cfg.moe_latent_size` the routed path runs in a latent space: the rows
+are projected `hidden -> latent` ahead of the dispatch and the combined
+sum back `latent -> hidden` (two matmuls of `MoE`, scope
+`lm/moe/latent`, whole on every chip), so the rows that are gathered,
+multiplied and added back have the latent width; the router and the
+shared expert read the hidden width. What the absent experts and
+columns would add is left out: in a deployment it arrives with the
+expert-parallel sum. No code stands in for that exchange.
 
 Dropless, in one program shape. A (token, choice) pair is a slot;
 `T * top_k` slots exist and any number of them, up to all, may fall on
 held experts. Slots are sorted by held expert (the others last), and
 the sorted order is cut into chunks of `dispatch_chunk` rows. A chunk
-gathers its tokens, runs the three grouped products of a gated MLP with
+gathers its tokens, runs the grouped products of its experts (three of
+a gated MLP, two of an ungated one) with
 the chunk's own group sizes, and adds the weighted rows back to their
 tokens (below). Chunk 0 always runs; the later chunks are a scan under one
 `lax.cond` that is taken only if held slots pass chunk 0, each of them
@@ -51,6 +61,25 @@ reaches chunk 1 pays for a whole second chunk, so chunk 0 has to clear
 the expected load with room; every row of room costs its gather
 whether a slot fills it or not. `cfg.moe_chunk` overrides the size (the
 tests' several chunks at toy sizes; a smaller chunk to save memory).
+
+The later chunks' branch, around their checkpoint or inside it. A
+`lax.cond` around a chunk's checkpoint hands the scan, for each of its
+steps, a copy of what the checkpoint keeps, "the rows and the weights,
+or zeros": temporaries nobody reads, a GB or so with a handful of later
+chunks (kanana's 5 x 285 MB, Trinity's 5 x 335, LFM2's and
+SmallThinker's 2 x 300), 6.2 GB with the 43 of 22 slots a token over 8
+of 512 experts, where the step does not fit the chip. With the branch
+inside the checkpoint the scan keeps a chunk's three arguments only,
+and a chunk the slots do not reach adds a `[T, D]` of zeros. The second
+form alone would be simpler, and it was measured (PERF.md, PR 48, call
+5, parent | change on one seed): Trinity +0.06 %, LFM2 +0.005 %,
+SmallThinker inside its noise, but kanana 7.266 -> 7.190 rows/s, -1.05 %
+where the same program twice reads -0.02 %, 5.8 ms a step although no
+later chunk ran in either: the compiler lays the step out otherwise
+around a branch that never runs. So the branch stands around the
+checkpoint while the copies that costs stay under `_STACKED_BYTES`, and
+inside it past that; chosen from the shapes, and the result is the
+same.
 
 How rows come back. The sort is stable, so inside a held expert's run
 the tokens ascend: for a block of `rows.block_tokens` tokens and one
@@ -74,7 +103,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dexiraft_tpu.models.lm.layers import SwiGLU, Weights
+from dexiraft_tpu.models.lm.layers import ActMLP, SwiGLU, Weights
 from dexiraft_tpu.ops import rows as row_ops
 from dexiraft_tpu.ops.grouped import grouped_matmul
 
@@ -98,8 +127,10 @@ def route_softmax(logits: jax.Array, top_k: int,
     return chosen, jax.nn.softmax(top, axis=-1) * scale
 
 
-# a configuration's `expert_act` -> the gate function
-ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# a configuration's `expert_act` -> the gate function (of an expert
+# without a gate: its activation)
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+        "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 # what an expert layer counts of a batch, and how `reduce_counters`
 # takes each over a stack's expert layers
@@ -109,6 +140,11 @@ COUNTERS = {"moe_slots_held": jnp.sum, "moe_load_max": jnp.max,
 
 # a dispatch chunk is a whole number of these rows
 _CHUNK_ROWS = 8192
+# the later chunks' branch stands around their checkpoint while the
+# copies that costs stay under this (an eighth of a v5e's memory: the
+# accepted cells' 0.55-1.7 GB), and inside it past it (6.2 GB; module
+# docstring, "The later chunks' branch")
+_STACKED_BYTES = 2 * 1024 ** 3
 
 
 def dispatch_chunk(slots: int, held: int, experts: int) -> int:
@@ -144,18 +180,23 @@ class RoutedExperts(Weights):
 
     def setup(self):
         cfg = self.cfg
-        d, width = cfg.hidden_size, cfg.moe_intermediate_size
+        width = cfg.moe_intermediate_size
+        # the width the experts read and write
+        d = cfg.moe_latent_size or cfg.hidden_size
         held = cfg.experts_held[1]
         normal = nn.initializers.normal
-        self.router = self.param("router", normal(cfg.init_std),
-                                 (d, cfg.n_routed_experts), jnp.float32)
+        self.router = self.param(
+            "router", normal(cfg.init_std),
+            (cfg.hidden_size, cfg.n_routed_experts), jnp.float32)
         if cfg.route_score == "sigmoid":
             self.bias = self.variable(
                 "batch_stats", "e_score_correction_bias",
                 lambda: jnp.zeros((cfg.n_routed_experts,), jnp.float32))
-        self.w_gate, self.w_up = (
-            self.param(name, normal(self.init_std), (held, d, width),
-                       jnp.float32) for name in ("w_gate", "w_up"))
+        if cfg.expert_gate:
+            self.w_gate = self.param("w_gate", normal(self.init_std),
+                                     (held, d, width), jnp.float32)
+        self.w_up = self.param("w_up", normal(self.init_std),
+                               (held, d, width), jnp.float32)
         self.w_down = self.param("w_down", normal(self.init_std),
                                  (held, width, d), jnp.float32)
 
@@ -208,16 +249,20 @@ class RoutedExperts(Weights):
 
     def __call__(self, x: jax.Array, plan: Optional[Plan] = None
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """x `[T, D]`, with its routing (None: routed on `x` itself) ->
-        (this chip's part of the layer's output `[T, D]`, counters)."""
+        """x `[T, D]` at the experts' width, with its routing (None:
+        routed on `x` itself) -> (this chip's part of the layer's output
+        `[T, D]`, counters)."""
         t, d = x.shape
         slot_token, slot_weight, counts, starts, ends, n_held, block_lo = (
             self.plan(x) if plan is None else plan)
         n_chunks, chunk = slot_token.shape
         act = ACTS[self.cfg.expert_act]
 
-        w_gate, w_up, w_down = (w.astype(self.dtype) for w in
-                                (self.w_gate, self.w_up, self.w_down))
+        gated = self.cfg.expert_gate
+        if gated:
+            w_gate = self.w_gate.astype(self.dtype)
+        w_up, w_down = (w.astype(self.dtype) for w in
+                        (self.w_up, self.w_down))
 
         def chunk_lo(c):
             """The table for chunk `c`'s rows: cut to it as `sizes` is."""
@@ -241,20 +286,38 @@ class RoutedExperts(Weights):
             with jax.named_scope("lm/moe/dispatch"):
                 rows = keep(row_ops.gather_rows(x, tokens, table))
             with jax.named_scope("lm/moe/experts"):
-                gate = keep(grouped_matmul(rows, w_gate, sizes))
-                up = keep(grouped_matmul(rows, w_up, sizes))
-                out = keep(grouped_matmul(act(gate) * up, w_down, sizes))
+                if gated:
+                    gate = keep(grouped_matmul(rows, w_gate, sizes))
+                    up = keep(grouped_matmul(rows, w_up, sizes))
+                    mid = act(gate) * up
+                else:
+                    mid = act(keep(grouped_matmul(rows, w_up, sizes)))
+                out = keep(grouped_matmul(mid, w_down, sizes))
             with jax.named_scope("lm/moe/combine"):
                 part = row_ops.segment_add(out, tokens, wts, table, t)
             return part, jnp.sum(sizes)
 
         y, taken = run_chunk(0, slot_token[0], slot_weight[0])
         if n_chunks > 1:
-            later = jax.checkpoint(run_chunk)
+            # what every later chunk reads alike: the rows and the weights
+            shared = sum(a.size * a.dtype.itemsize for a in
+                         (x, w_up, w_down) + ((w_gate,) if gated else ()))
+            inside = (n_chunks - 1) * shared > _STACKED_BYTES
+
+            def reached(c, tokens, wts):
+                return jax.lax.cond(
+                    c * chunk < n_held, lambda: run_chunk(c, tokens, wts),
+                    lambda: (jnp.zeros((t, d), jnp.float32),
+                             jnp.zeros((), jnp.int32)))
+
+            later = jax.checkpoint(reached if inside else run_chunk)
 
             def body(carry, xs):
                 y, taken = carry
                 c, tokens, wts = xs
+                if inside:
+                    part, n = later(c, tokens, wts)
+                    return (y + part, taken + n), None
 
                 def add(y):
                     part, n = later(c, tokens, wts)
@@ -297,10 +360,11 @@ class RoutedExperts(Weights):
 
 
 class MoE(Weights):
-    """Routed experts held here + the shared experts (one SwiGLU of
-    `n_shared_experts * moe_intermediate_size`). `plan(x)` routes on a
-    tensor of the caller's choosing, `[..., D]`; `__call__(x, plan)`
-    takes that routing, or routes on `x` itself."""
+    """Routed experts held here + the shared experts (one MLP of
+    `cfg.shared_width` columns, gated as the routed ones are) and, under
+    `cfg.moe_latent_size`, the two projections around the routed path.
+    `plan(x)` routes on a tensor of the caller's choosing, `[..., D]`;
+    `__call__(x, plan)` takes that routing, or routes on `x` itself."""
 
     cfg: Any = None  # a config.DecoderConfig
 
@@ -309,8 +373,17 @@ class MoE(Weights):
         kw = dict(dtype=self.dtype, init_std=self.init_std)
         self.experts = RoutedExperts(cfg=cfg, **kw)
         if cfg.n_shared_experts:
-            self.shared = SwiGLU(
-                width=cfg.n_shared_experts * cfg.moe_intermediate_size, **kw)
+            self.shared = (
+                SwiGLU(width=cfg.shared_width, **kw) if cfg.expert_gate
+                else ActMLP(width=cfg.shared_width,
+                            act=ACTS[cfg.expert_act], **kw))
+        if cfg.moe_latent_size:
+            shape = (cfg.hidden_size, cfg.moe_latent_size)
+            normal = nn.initializers.normal(self.init_std)
+            self.latent_down = self.param("latent_down", normal, shape,
+                                          jnp.float32)
+            self.latent_up = self.param("latent_up", normal, shape[::-1],
+                                        jnp.float32)
 
     def plan(self, x: jax.Array) -> Plan:
         return self.experts.plan(x.reshape(-1, x.shape[-1]))
@@ -318,7 +391,16 @@ class MoE(Weights):
     def __call__(self, x: jax.Array, plan: Optional[Plan] = None
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         flat = x.reshape(-1, x.shape[-1])
-        routed, counters = self.experts(flat, plan)
+        if self.cfg.moe_latent_size:
+            # the router reads the hidden width, the experts the latent
+            plan = self.experts.plan(flat) if plan is None else plan
+            with jax.named_scope("lm/moe/latent"):
+                rows = flat @ self.latent_down.astype(self.dtype)
+            routed, counters = self.experts(rows, plan)
+            with jax.named_scope("lm/moe/latent"):
+                routed = routed @ self.latent_up.astype(self.dtype)
+        else:
+            routed, counters = self.experts(flat, plan)
         if not self.cfg.n_shared_experts:
             return routed.reshape(x.shape), counters
         with jax.named_scope("lm/moe/shared"):

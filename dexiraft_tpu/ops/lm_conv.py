@@ -1,6 +1,9 @@
-"""The doubly gated short causal convolution of a packed row, within
-documents: the `conv` mixer of an `Lfm2MoeConfig` between its two
-projections (models/lm/attention.py `ShortConv`).
+"""Short causal depthwise convolutions of a packed row, within
+documents. Two mixers use them, with one mask and one way of shifting
+(`_taps_sum`): the doubly gated one of an `Lfm2MoeConfig` between its
+two projections (models/lm/attention.py `ShortConv`; the equations
+below), and Mamba-2's ahead of its scan, `silu(conv_L(x) + bias)` with
+no gate (`causal_conv`, models/lm/attention.py `Mamba2`).
 
 Row positions n = 0..T-1 with document ids d(n) (pad is 0); B, C, z the
 thirds of the in-projection, `[.., T, H]`; taps k `[H, L]`, depthwise,
@@ -37,20 +40,38 @@ def _shifted(x: jax.Array, by: int, fill) -> jax.Array:
     return jnp.pad(x, pad, constant_values=fill)[:, :x.shape[1]]
 
 
-def gated_short_conv(b: jax.Array, c: jax.Array, z: jax.Array,
-                     taps: jax.Array, segment_ids: jax.Array) -> jax.Array:
-    """b, c, z `[B, T, H]`, taps `[H, L]`, segment_ids `[B, T]` ->
-    `[B, T, H]` in b's dtype (module docstring). The products and the
-    sum over the taps are fp32."""
+def _taps_sum(a: jax.Array, taps: jax.Array,
+              segment_ids: jax.Array) -> jax.Array:
+    """c of the module docstring from a `[B, T, H]` fp32 and taps
+    `[H, L]` fp32: the taps' sum under the document mask."""
     length = taps.shape[1]
-    taps = taps.astype(jnp.float32)
-    a = b.astype(jnp.float32) * z.astype(jnp.float32)
     total = a * taps[:, length - 1]
     for back in range(1, length):
         same = _shifted(segment_ids, back, -1) == segment_ids
         total = total + jnp.where(same[..., None], _shifted(a, back, 0.0),
                                   0.0) * taps[:, length - 1 - back]
+    return total
+
+
+def gated_short_conv(b: jax.Array, c: jax.Array, z: jax.Array,
+                     taps: jax.Array, segment_ids: jax.Array) -> jax.Array:
+    """b, c, z `[B, T, H]`, taps `[H, L]`, segment_ids `[B, T]` ->
+    `[B, T, H]` in b's dtype (module docstring). The products and the
+    sum over the taps are fp32."""
+    taps = taps.astype(jnp.float32)
+    total = _taps_sum(b.astype(jnp.float32) * z.astype(jnp.float32), taps,
+                      segment_ids)
     return (c.astype(jnp.float32) * total).astype(b.dtype)
+
+
+def causal_conv(x: jax.Array, taps: jax.Array, bias: jax.Array,
+                segment_ids: jax.Array) -> jax.Array:
+    """silu(c + bias), c the taps' sum of x `[B, T, H]` under the same
+    mask (taps `[H, L]`, bias `[H]`): `[B, T, H]` in x's dtype, the sum,
+    the bias and the SiLU in fp32."""
+    total = _taps_sum(x.astype(jnp.float32), taps.astype(jnp.float32),
+                      segment_ids)
+    return jax.nn.silu(total + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 def taps_masked(segment_ids: jax.Array, length: int) -> jax.Array:

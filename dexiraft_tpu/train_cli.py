@@ -112,6 +112,15 @@ def build_parser() -> argparse.ArgumentParser:
                    help="language models with an expert layer: the routed "
                         "experts this chip holds of an expert-parallel "
                         "group (default: all)")
+    p.add_argument("--ssm_heads_held", type=int, nargs=2, default=None,
+                   metavar=("FIRST", "COUNT"),
+                   help="language models with a state-space mixer: the "
+                        "Mamba-2 heads this chip holds, in whole B/C groups "
+                        "(default: all)")
+    p.add_argument("--shared_columns_held", type=int, nargs=2, default=None,
+                   metavar=("FIRST", "COUNT"),
+                   help="language models whose shared expert is divided: "
+                        "the columns of it this chip holds (default: all)")
     p.add_argument("--small", action="store_true")
     p.add_argument("--mixed_precision", action="store_true")
     p.add_argument("--corr_impl", default="allpairs",
@@ -332,13 +341,16 @@ _RAFT_ONLY = ("stage", "preset", "small", "mixed_precision", "corr_impl",
               "validation", "records_dir", "edge_root", "edge_sum_fusion",
               "fsdp", "elastic", "join")
 _LM_ONLY = ("tokens", "seq_len", "layers", "dense_layers", "layer_types",
-            "vocab_size", "heads_held", "kv_heads_held", "experts_held")
+            "vocab_size", "heads_held", "kv_heads_held", "experts_held",
+            "ssm_heads_held", "shared_columns_held")
 # of them, the flags one architecture has and another has not, by the
 # configuration's field
 _LM_FIELDS = {"dense_layers": ("first_k_dense_replace", "num_dense_layers"),
               "layer_types": ("layer_types",),
               "kv_heads_held": ("kv_heads_held",),
-              "experts_held": ("experts_held",)}
+              "experts_held": ("experts_held",),
+              "ssm_heads_held": ("ssm_heads_held",),
+              "shared_columns_held": ("shared_columns_held",)}
 
 
 def _refuse_given(args, names, why: str) -> None:

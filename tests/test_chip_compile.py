@@ -638,6 +638,43 @@ def test_smallthinker_step_compiles_for_v5e_and_fits_the_chip(topo):
             < 15.75 * 2 ** 30)
 
 
+@pytest.mark.slow
+def test_nemotron_step_compiles_for_v5e_and_fits_the_chip(topo):
+    """The sixth language cell's whole train step at its real size (11
+    layers M E M E M E M E M * E, each one block; 16 of 128 Mamba heads,
+    4 query heads on 1 key/value head, 8 of 512 experts top 22 in a
+    latent space of 1,024, 672 shared columns; 508 M parameters, one row
+    of 32,768 positions, bf16, every layer recomputed) as
+    `benchmarks/compile_check.py` lowers it, the attention layer on the
+    kernel path it takes on the chip and the expert layers' rows of 1,024
+    added back by `rows_segment_sum`, no `[T, D]` scatter left under
+    dispatch or combine at either width. The 43 overflow chunks keep no
+    copy of the rows or of the experts' matrices (`[43, 32768, 1024]`,
+    `[43, 8, 1024, 2688]`: 6.2 GB where each chunk's branch stood around
+    its checkpoint). Arguments and temporaries fit 15.75 GB."""
+    compiled = _step_on_the_chips_paths("nemotron3-train-pack32k", topo)
+    text = compiled.as_text()
+    for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv",
+                 "rows_segment_sum"):
+        assert name in text
+    assert not _row_scatters_under_the_moves(text, 32768, 1024)
+    assert not _row_scatters_under_the_moves(text, 32768, 4096)
+    for scope in ("lm/ssm/in", "lm/ssm/conv", "lm/ssm/scan",
+                  "lm/ssm/gate_norm", "lm/ssm/out", "lm/gqa/full/kernel",
+                  "lm/moe/latent", "lm/moe/router", "lm/moe/dispatch",
+                  "lm/moe/experts", "lm/moe/shared", "lm/moe/combine"):
+        assert scope in text, scope
+    assert "lm/mlp" not in text and "lm/gqa/window" not in text
+    assert "[43,32768,1024]" not in text and "[43,8,1024,2688]" not in text
+    memory = compiled.memory_analysis()
+    # fp32 masters and AdamW's two moments: 12 B a parameter
+    assert memory.argument_size_in_bytes > 12 * 508_187_120
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    print("nemotron step: temp", memory.temp_size_in_bytes / 1e9, "args",
+          memory.argument_size_in_bytes / 1e9)
+
+
 # ---- the convex upsample (ops/upsample.py) --------------------------------
 
 def test_convex_upsample_is_lane_dense_for_v5e(chip):

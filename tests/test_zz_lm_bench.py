@@ -86,6 +86,24 @@ NEEDS_A_CHIP5 = NEEDS_A_CHIP2 | {"lm_moe_router_device_ms"}
 NEW47 = ["lm_moe_dispatch_device_ms", "lm_moe_combine_device_ms",
          "lm_moe_rows_live_pct"]
 SPARSE = [CELL, CELL2, CELL4, CELL5]
+# the sixth language cell (PR 48): layers of one block, a state-space
+# mixer, an attention layer of the `full` kind as LFM2's, an expert layer
+# with a latent space, so the expert layer's metrics but the one whose
+# reader counts three products a slot at the hidden width; its own
+# three device times (the two roofline shares of `lm_counts_nemotron`
+# and the program's `ssm_doc_starts` / `ssm_chunks_reset` have no
+# reader: the runner carries neither the widths nor the counters,
+# PERF.md section 7)
+CELL6 = "nemotron3-train-pack32k"
+NEW6 = ["lm_ssm_device_ms", "lm_ssm_scan_device_ms",
+        "lm_moe_latent_device_ms"]
+SHARED6 = [m for m in SHARED2 if m != "lm_moe_experts_roofline_pct"] + [
+    "lm_moe_router_device_ms", "lm_gqa_device_ms",
+    "lm_gqa_full_kernel_device_ms", "lm_gqa_full_kernel_roofline_pct"
+] + NEW47
+NEEDS_A_CHIP6 = (NEEDS_A_CHIP | set(NEW6) | set(NEW47[:2]) | {
+    "lm_moe_router_device_ms", "lm_gqa_device_ms",
+    "lm_gqa_full_kernel_device_ms", "lm_gqa_full_kernel_roofline_pct"})
 CELLS = {
     CELL: dict(config="kanana-2-30b-a3b-share8", traffic="train-pack8k",
                model="kanana-2-30b-a3b-instruct-2601", shares=8,
@@ -110,6 +128,12 @@ CELLS = {
                 assumes="router_before_attention",
                 reports=FED + SHARED5 + NEW5 + NEW47 + SETUP,
                 needs_a_chip=NEEDS_A_CHIP5 | set(NEW47[:2])),
+    CELL6: dict(config="nemotron-3-super-120b-a12b-share64",
+                traffic="train-pack32k-docs4k",
+                model="NVIDIA-Nemotron-3-Super-120B-A12B-BF16", shares=64,
+                assumes="document reset",
+                reports=FED + SHARED6 + NEW6 + SETUP,
+                needs_a_chip=NEEDS_A_CHIP6),
 }
 
 
@@ -194,10 +218,12 @@ def test_manifest_names_the_fourth_cell_and_what_it_reports(manifest):
     by_name = {m["name"]: m
                for m in manifest["per_layer"] + manifest["end_to_end"]}
     for name in FED + SHARED4 + ["train_samples_per_s"]:
-        cells = by_name[name]["workloads"]  # last, or CELL5 follows
-        assert cells[cells.index(CELL4) + 1:] in ([], [CELL5]), name
+        cells = by_name[name]["workloads"]  # last, or later cells follow
+        assert cells[cells.index(CELL4) + 1:] in (
+            [], [CELL5], [CELL6], [CELL5, CELL6]), name
     for name in NEW4:
-        assert by_name[name]["workloads"] == [CELL4]
+        assert by_name[name]["workloads"][0] == CELL4
+        assert by_name[name]["workloads"][1:] in ([], [CELL6])
         assert by_name[name]["moves"] == "train_samples_per_s"
         assert by_name[name]["layer"] == by_name["lm_attn_device_ms"]["layer"]
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
@@ -212,14 +238,14 @@ def test_manifest_names_the_fourth_cell_and_what_it_reports(manifest):
 
 
 def test_manifest_names_the_fifth_cell_and_what_it_reports(manifest):
-    cell = manifest["workloads"][-1]  # entries are added at the end
+    cell = manifest["workloads"][9]  # entries are added at the end
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL5, "smallthinker-21b-a3b-share4", "train-pack16k", 1)
-    assert len(manifest["workloads"]) == 10 and len(cell["why"]) <= 200
+    assert len(cell["why"]) <= 200
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
-    entry = manifest["configs"][-1]
+    entry = manifest["configs"][6]
     assert entry["name"] == "smallthinker-21b-a3b-share4"
-    assert len(entry["why"]) <= 200 and len(manifest["configs"]) == 7
+    assert len(entry["why"]) <= 200
     by_name = {m["name"]: m
                for m in manifest["per_layer"] + manifest["end_to_end"]}
     # every list Trinity's cell is in, and no other
@@ -228,24 +254,57 @@ def test_manifest_names_the_fifth_cell_and_what_it_reports(manifest):
             continue
         cells = m.get("workloads", [])
         assert (CELL5 in cells) == (CELL2 in cells), name
-        if CELL5 in cells:
-            assert cells[-1] == CELL5, name
-    for name in FED + SHARED5 + ["train_samples_per_s"]:
-        assert by_name[name]["workloads"][-1] == CELL5, name
+        if CELL5 in cells:  # last, or the sixth cell follows
+            assert cells[cells.index(CELL5) + 1:] in ([], [CELL6]), name
     for name in NEW5:
-        assert by_name[name]["workloads"] == [CELL5]
+        assert by_name[name]["workloads"] in ([CELL5], [CELL5, CELL6])
         assert by_name[name]["moves"] == "train_samples_per_s"
         assert by_name[name]["layer"] == by_name["lm_moe_device_ms"]["layer"]
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
                                    name + ".py"))
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(NEW5 + NEW47):-len(NEW47)] == NEW5  # PR 47's follow
+    assert names[names.index(NEW47[0]) - 1] == NEW5[0]  # PR 47's follow
     assert by_name["lm_moe_router_device_ms"]["source"] == "device_trace"
+
+
+def test_manifest_names_the_sixth_cell_and_what_it_reports(manifest):
+    cell = manifest["workloads"][-1]  # entries are added at the end
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        CELL6, "nemotron-3-super-120b-a12b-share64", "train-pack32k-docs4k",
+        1)
+    assert len(manifest["workloads"]) == 11 and len(cell["why"]) <= 200
+    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
+    entry = manifest["configs"][-1]
+    assert entry["name"] == "nemotron-3-super-120b-a12b-share64"
+    assert len(entry["why"]) <= 200 and len(manifest["configs"]) == 8
+    by_name = {m["name"]: m
+               for m in manifest["per_layer"] + manifest["end_to_end"]}
+    for name in FED + SHARED6 + ["train_samples_per_s"]:
+        assert by_name[name]["workloads"][-1] == CELL6, name
+    listed = {name for name, m in by_name.items()
+              if CELL6 in m.get("workloads", [])}
+    assert listed == set(FED + SHARED6 + NEW6 + ["train_samples_per_s"])
+    # its reader counts 12 products a slot at the hidden width
+    assert CELL6 not in by_name["lm_moe_experts_roofline_pct"]["workloads"]
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[-len(NEW6):] == NEW6
+    for name in NEW6:
+        m = by_name[name]
+        assert m["workloads"] == [CELL6] and m["source"] == "device_trace"
+        assert m["moves"] == "train_samples_per_s"
+        assert m["layer"] == by_name[
+            "lm_moe_device_ms" if "moe" in name else "lm_attn_device_ms"][
+                "layer"]
+        assert (m["unit"], m["better"]) == (
+            ("%", "higher") if name.endswith("_pct") else ("ms", "lower"))
+        assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
+                                   name + ".py"))
 
 
 def test_manifest_names_the_row_moves_metrics_in_the_sparse_cells(manifest):
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    assert [m["name"] for m in manifest["per_layer"]][-3:] == NEW47
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names[names.index(NEW47[0]):][:3] == NEW47
     for name in NEW47:
         m = by_name[name]
         assert m["layer"] == by_name["lm_moe_device_ms"]["layer"]
@@ -253,10 +312,10 @@ def test_manifest_names_the_row_moves_metrics_in_the_sparse_cells(manifest):
         assert osp.exists(osp.join(REPO, "benchmarks", "layer_metrics",
                                    name + ".py"))
     for name in NEW47[:2]:
-        assert by_name[name]["workloads"] == SPARSE
+        assert by_name[name]["workloads"][:4] == SPARSE
         assert (by_name[name]["source"], by_name[name]["better"]) == (
             "device_trace", "lower")
-    assert by_name["lm_moe_rows_live_pct"]["workloads"] == SPARSE[1:]
+    assert by_name["lm_moe_rows_live_pct"]["workloads"][:3] == SPARSE[1:]
     assert (by_name["lm_moe_rows_live_pct"]["source"],
             by_name["lm_moe_rows_live_pct"]["better"]) == (
         "program_counter", "higher")
@@ -333,6 +392,15 @@ def test_rehearsal_runs_the_cell_end_to_end_and_lists_what_it_would_report(
         assert set(k for k in counters if k.startswith("traced_pairs_")
                    ) == {"traced_pairs_full"}
         assert counters["moe_slots_held"] > 0
+    if cell == CELL6:  # three kinds of layer, each of one block
+        assert (counters["attn_layers_mamba"], counters["attn_layers_full"],
+                counters["attn_layers_experts"]) == (2, 1, 2)
+        assert counters["attn_block_pairs_visited_full"] > 0
+        assert set(k for k in counters if k.startswith("traced_pairs_")
+                   ) == {"traced_pairs_full"}
+        assert counters["moe_slots_held"] > 0
+        assert counters["moe_rows_live"] == counters["moe_slots_held"]
+        assert not any(k.startswith("ssm_") for k in counters)
     if cell == CELL5:  # both kinds of layer, a group of 7
         assert counters["attn_block_pairs_visited_window"] > 0
         assert counters["attn_block_pairs_visited_full"] > 0
@@ -618,6 +686,111 @@ def test_the_fifth_cells_configuration_states_its_cut_and_builds():
     assert [cfg.layer_window(i) for i in (0, 1)] == [None, 4096]
 
 
+def test_the_sixth_cells_configuration_states_its_cut_and_builds():
+    """`parameters_held` is the program's own count and the issue's
+    arithmetic, the share is the deployment's, the traffic is the
+    issue's, and every control is a field of the configuration that the
+    reference reads."""
+    import dataclasses
+
+    import jax
+
+    sys.path.insert(0, REPO)
+    from benchmarks import harness, lm_counts_nemotron
+    from dexiraft_tpu.config import TrainConfig, nemotron_h_toy
+    from dexiraft_tpu.models.lm.moe import dispatch_chunk
+    from dexiraft_tpu.train.family import family_of
+    from dexiraft_tpu.train.state import param_count
+
+    cell = harness.load_cell(CELL6)
+    cfg, tc = harness.load_runner("lm_train_packed")._configs(cell, 0)
+    held = cell.config["parameters_held"]
+    params, stats = jax.eval_shape(family_of(cfg, TrainConfig()).init,
+                                   jax.random.PRNGKey(0))
+    assert param_count(params) == held["total"] == 508_187_120
+    assert held["total"] == (5 * held["mamba_layer"] + held["attention_layer"]
+                             + 5 * held["expert_layer"]
+                             + held["embedding_and_head"]
+                             + held["final_norm"])
+    assert held["state_bytes_at_16_a_parameter"] == 16 * held["total"]
+    count = lambda tree: sum(  # noqa: E731
+        int(a.size) for a in jax.tree.leaves(tree))
+    assert [count(params[f"layers_{i}"]) for i in (0, 9, 10)] == [
+        held["mamba_layer"], held["attention_layer"], held["expert_layer"]]
+    # the toy constructor's count by the same formulas at its widths
+    toy, _ = harness.load_runner("lm_train_packed")._configs(
+        harness.load_cell(CELL6, rehearsal=True), 0)
+    toy_params, _ = jax.eval_shape(family_of(toy, TrainConfig()).init,
+                                   jax.random.PRNGKey(0))
+    d, inner, bc = 64, 4 * 8, 2 * 16
+    mamba = (d + d * (2 * inner + 2 * bc + 4) + (inner + 2 * bc) * 5 + 12
+             + inner + inner * d)
+    attention = d + 2 * d * 32 + 2 * d * 8
+    expert = d + d * 16 + 2 * d * 32 + 4 * 2 * 32 * 24 + 2 * d * 24
+    assert param_count(toy_params) == (2 * mamba + attention + 2 * expert
+                                       + 2 * 256 * d + d)
+    assert toy == nemotron_h_toy(
+        ssm_heads_held=(0, 4), heads_held=(0, 4), experts_held=(0, 4),
+        shared_columns_held=(0, 24), seq_len=128, remat=True)
+    assert (cfg.ssm_heads_held, cfg.ssm_groups_held, cfg.heads_held,
+            cfg.kv_heads_held, cfg.experts_held, cfg.shared_columns_held
+            ) == ((0, 16), (0, 1), (0, 4), (0, 1), (0, 8), (0, 672))
+    assert cell.config["deployment"]["ssm_groups_held"] == [0, 1]
+    assert (cfg.mamba_num_heads, cfg.n_groups, cfg.num_attention_heads,
+            cfg.num_key_value_heads, cfg.n_routed_experts) == (
+                128, 8, 32, 2, 512)  # the whole counts
+    assert (cfg.hidden_size, cfg.mamba_head_dim, cfg.ssm_state_size,
+            cfg.conv_kernel, cfg.chunk_size, cfg.head_dim,
+            cfg.moe_latent_size, cfg.moe_intermediate_size,
+            cfg.moe_shared_expert_intermediate_size, cfg.num_experts_per_tok,
+            cfg.routed_scaling_factor, cfg.vocab_size,
+            cfg.hybrid_override_pattern) == (
+                4096, 64, 128, 4, 128, 128, 1024, 2688, 5376, 22, 5, 16_384,
+                "MEMEMEMEM*E")
+    assert (cfg.seq_len, tc.batch_size, cfg.remat, tc.precision, tc.lr,
+            tc.wdecay) == (32_768, 1, True, "bf16", 3e-4, 0.1)
+    # the names the runner reads of a configuration
+    assert (cfg.first_k_dense_replace, cfg.qk_head_dim) == (0, 128)
+    assert lm_counts_nemotron.layers_by_kind(cfg) == {
+        "mamba": 5, "full": 1, "experts": 5}
+    assert cfg.moe_chunk is None  # what the program selects: 16,384 rows
+    assert dispatch_chunk(32_768 * 22, 8, 512) == 16_384
+    docs = cell.traffic["documents"]
+    assert (docs["median"], docs["sigma"], docs["shortest"], docs["longest"],
+            docs["count"]) == (4096, 1.0, 256, 16_384, 768)
+    assert (cell.traffic["num_workers"], cell.traffic["prefetch_depth"],
+            cell.traffic["warm_steps"], cell.traffic["traced_steps"],
+            cell.traffic["check"]["reference_block"]) == (8, 2, 3, 4, 2048)
+    assert (cell.config["deployment"]["chips_sharing_a_layer"],
+            cell.config["deployment"]["pipeline_stages"]) == (64, 8)
+    assert set(cell.config["program"]["scopes"]) >= {
+        "lm/ssm/in", "lm/ssm/conv", "lm/ssm/scan", "lm/ssm/gate_norm",
+        "lm/ssm/out", "lm/moe/latent"}
+    controls = cell.traffic["check"]["controls"]
+    assert set(controls) == {
+        "no_state_carry", "state_across_documents", "norm_before_gate",
+        "rope_on_attention", "router_reads_latent", "relu_experts",
+        "gated_experts", "no_D_skip"} | {
+            f"without_layer_{i}" for i in range(11)}
+    # every held layer carries a leaf: one that adds nothing cannot pass
+    assert {leaf[0] for leaf in cell.traffic["check"]["leaves"]} == {
+        f"layers_{i}" for i in range(11)}
+    for fault in controls.values():
+        assert dataclasses.replace(cfg, **fault) != cfg
+    leaves = ["/".join(map(str, leaf))
+              for leaf in cell.traffic["check"]["leaves"]]
+    assert set(cell.traffic["check"]["tolerances"]) == {
+        "loss", "grad_norm"} | set(leaves)
+    tree = {"/".join(str(getattr(k, "key", k)) for k in path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]}
+    for leaf in leaves:  # every layer kind carries one
+        assert leaf.removesuffix("/0") in tree, leaf
+    for needle in ("ssm/in_proj", "ssm/taps", "ssm/A_log", "ssm/dt_bias",
+                   "ssm/out_proj", "attn/wk", "experts/router",
+                   "latent_down", "experts/w_down", "shared/w_up"):
+        assert any(needle in leaf for leaf in leaves), needle
+
+
 def _train(*flags):
     return subprocess.run(
         [sys.executable, "-m", "dexiraft_tpu", "train", *flags], cwd=REPO,
@@ -666,7 +839,12 @@ def test_train_cli_refuses_the_language_models_flags_for_raft():
     # the full layer and a sliding one; one key/value head's group of 7
     ("smallthinker-21b-toy", ("--heads_held", "7", "7", "--kv_heads_held",
                               "1", "1", "--layers", "2", "--experts_held",
-                              "0", "4"))])
+                              "0", "4")),
+    # M E M * E; one group of Mamba heads, a key/value head's second half,
+    # a quarter of the experts and of the shared expert's columns
+    ("nemotron-h-toy", ("--ssm_heads_held", "2", "2", "--heads_held", "2",
+                        "2", "--experts_held", "4", "4",
+                        "--shared_columns_held", "12", "12"))])
 def test_train_cli_trains_the_toy_model_through_the_normal_path(
         tmp_path, variant, share):
     import numpy as np

@@ -20,9 +20,10 @@ import pytest
 sys.path.insert(0, osp.dirname(osp.dirname(osp.abspath(__file__))))
 
 from benchmarks import (flops, lm_counts, lm_counts_afmoe,  # noqa: E402
-                        lm_counts_eva, lm_counts_lfm2,
+                        lm_counts_eva, lm_counts_lfm2, lm_counts_nemotron,
                         lm_counts_smallthinker, lm_scopes)
 from dexiraft_tpu.config import (evabyte, kanana2, lfm2_8b_a1b,  # noqa: E402
+                                 nemotron_h, nemotron_h_toy,
                                  smallthinker_21b, trinity_mini)
 from dexiraft_tpu.interop import lm_reference as ref  # noqa: E402
 
@@ -653,3 +654,139 @@ def test_smallthinker_layer_metric_reads_its_scope_and_gives_nothing_without():
     untraced = obs(counters)
     untraced.trace = None
     assert read("lm_moe_router_device_ms", untraced) is None
+
+
+# ---- Nemotron-H (benchmarks/lm_counts_nemotron.py) --------------------------
+
+_NEMOTRON_SHARE = dict(ssm_heads_held=(2, 4), heads_held=(4, 4),
+                       experts_held=(2, 6), shared_columns_held=(12, 24))
+
+
+def test_nemotron_dense_parts_equal_the_walk_of_the_reference():
+    """The reference makes a whole `[S, S]` score matrix in the attention
+    layer and applies each held expert to every token; its recurrence's
+    one matrix product a token is the state's reading `h C` (2 P N a
+    head: the update `(dt x) (x) B` is elementwise there and half of the
+    part), and its convolution is elementwise."""
+    cfg = nemotron_h_toy(**_NEMOTRON_SHARE)
+    _, params, _ = seeded(cfg)
+    batch = packed_batch(cfg)
+    rows, s = batch["tokens"].shape
+    walked = flops.count(lambda p: ref.loss(p, batch, cfg), params)
+    parts = lm_counts_nemotron.per_token_forward(cfg)
+    assert set(parts) == {"ssm_projections", "ssm_conv", "ssm_scan",
+                          "attention_projections", "router", "latent",
+                          "shared", "head"}
+    kinds = lm_counts_nemotron.layers_by_kind(cfg)
+    assert kinds == {"mamba": 2, "full": 1, "experts": 2}
+    assert parts["ssm_scan"] == 2 * 4 * 4 * 8 * 16
+    assert parts["ssm_conv"] == 2 * 2 * 4 * (4 * 8 + 2 * 2 * 16)
+    dense = sum(parts.values()) - parts["ssm_conv"] - parts["ssm_scan"] / 2
+    scores = rows * s * s * lm_counts_nemotron.per_pair_forward(cfg)
+    experts = (rows * s * kinds["experts"] * cfg.experts_held[1]
+               * lm_counts_nemotron.per_slot_forward(cfg))
+    assert walked == dense * rows * s + scores + experts
+
+
+def test_nemotron_step_flops_at_the_cells_share_by_hand():
+    cfg = nemotron_h(
+        num_hidden_layers=11, hybrid_override_pattern="MEMEMEMEM*E",
+        ssm_heads_held=(0, 16), heads_held=(0, 4), kv_heads_held=(0, 1),
+        experts_held=(0, 8), shared_columns_held=(0, 672), vocab_size=16_384)
+    per_token = lm_counts_nemotron.per_token_forward(cfg)
+    assert per_token["ssm_projections"] == 5 * 2 * 4096 * (2320 + 1024)
+    assert per_token["ssm_scan"] == 5 * 16 * 4 * 64 * 128
+    assert per_token["attention_projections"] == 2 * 4096 * 128 * (8 + 2)
+    assert per_token["router"] == 5 * 2 * 4096 * 512
+    assert per_token["latent"] == 5 * 2 * 2 * 4096 * 1024
+    assert per_token["shared"] == 5 * 2 * 2 * 4096 * 672
+    assert per_token["head"] == 2 * 4096 * 16_384
+    # two products a slot at the latent width: a ninth of what three at
+    # the hidden width would be credited
+    assert lm_counts_nemotron.per_slot_forward(cfg) == 2 * 2 * 1024 * 2688
+    assert lm_counts.per_slot_forward(cfg) == 6 * lm_counts_nemotron.per_slot_forward(cfg)
+    assert lm_counts_nemotron.per_pair_forward(cfg) == 4 * 2 * 256
+    parts = lm_counts_nemotron.step_flops(
+        cfg, tokens_real=32_000, slots_held=5 * 11_264, pairs={"full": 90e6})
+    assert parts["routed"] == 3 * 2 * 2 * 1024 * 2688 * 5 * 11_264
+    assert parts["attention"] == 3 * 4 * 2 * 256 * 90e6
+    assert parts["total"] == pytest.approx(sum(
+        v for k, v in parts.items() if k != "total"))
+    row = np.repeat(np.arange(1, 9, dtype=np.int32), 4096)
+    assert lm_counts_nemotron.pairs_by_kind(cfg, row[None]) == {
+        "full": 8 * 4096 * 4097 / 2}
+    assert lm_counts_nemotron.grouped_calls(True) == 8
+    assert lm_counts_nemotron.grouped_calls(False) == 6
+
+
+def test_nemotron_layer_metrics_read_their_scopes_and_stay_under_100():
+    """The three new readers on a synthetic observation of the cell's
+    size (the predicted times): device times are their scopes' sums; the
+    counts' two least times over them are shares under 100 % (they have
+    no reader: the runner carries no width of theirs), as the accepted
+    attention reader's is; the parent's program (no such scope) and an
+    untraced run read as nothing."""
+    from benchmarks import harness
+
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    counters = {"scope_s:lm/ssm/in": 0.060, "scope_s:lm/ssm/conv": 0.015,
+                "scope_s:lm/ssm/scan": 0.150,
+                "scope_s:lm/ssm/gate_norm": 0.010,
+                "scope_s:lm/ssm/out": 0.030, "scope_s:lm/moe/latent": 0.045,
+                "scope_s:lm/moe/router": 0.050,
+                "scope_s:lm/moe/dispatch": 0.040,
+                "scope_s:lm/moe/experts": 0.030,
+                "scope_s:lm/moe/shared": 0.030,
+                "scope_s:lm/moe/combine": 0.030,
+                "scope_s:lm/gqa/proj": 0.008,
+                "scope_s:lm/gqa/full/kernel": 0.020,
+                "tokens_real": 32_000.0, "moe_slots_held": 56_000.0,
+                "traced_slots_held": 56_000.0, "moe_intermediate_size": 2688,
+                "hidden_size": 4096, "experts_held": 8, "experts_layers": 11,
+                "remat": 1.0, "traced_pairs_full": 90e6,
+                "attn_layers_mamba": 5, "attn_layers_full": 1,
+                "attn_layers_experts": 5, "attn_heads_held": 4,
+                "attn_kv_heads_held": 1, "attn_head_dim": 128, "batch": 1,
+                "seq_len": 32_768}
+
+    def obs(c):
+        return harness.Observation(
+            spans={}, counters=c, end_to_end={}, trace={"busy_s": 1.0},
+            peaks=peaks, chips=1, memory_peak_bytes=0)
+
+    read = lambda name, o: harness.load_metric(name).read(o)  # noqa: E731
+    full = obs(counters)
+    assert read("lm_ssm_device_ms", full) == pytest.approx(265.0)
+    assert read("lm_ssm_scan_device_ms", full) == pytest.approx(150.0)
+    assert read("lm_moe_latent_device_ms", full) == pytest.approx(45.0)
+    assert read("lm_moe_device_ms", full) == pytest.approx(225.0)
+    scan = lm_counts_nemotron.scan_roofline_seconds(
+        32_000.0, 16, 64, 128, 1, 5, True, peaks)
+    assert scan["flops"] == 5 * 32_000 * 4 * 16 * 4 * 64 * 128
+    assert scan["bytes"] == 5 * 32_000 * 4 * (
+        2 * 1024 * 2 + 2 * 128 * 2 + 16 * 4)
+    assert scan["bound"] == "memory"
+    grouped = lm_counts_nemotron.grouped_roofline_seconds(
+        1024, 2688, 8, 56_000.0, 5, True, peaks)
+    assert grouped["flops"] == 56_000 * 8 * 2 * 1024 * 2688
+    assert grouped["bound"] == "compute"
+    # the accepted reader of the attention layer's kernel reads this
+    # cell's one `full` layer truly: 4 heads on 1 of 128
+    kernel = lm_counts_lfm2.attention_roofline_seconds(
+        90e6, 1, 32_768, 4, 1, 128, True, peaks)["seconds"]
+    assert read("lm_gqa_full_kernel_roofline_pct", full) == pytest.approx(
+        kernel / 0.020 * 100)
+    for share in (scan["seconds"] / 0.150, grouped["seconds"] / 0.030,
+                  read("lm_gqa_full_kernel_roofline_pct", full) / 100):
+        assert 0 < share < 1
+    # the reader fixed at 12 products a slot of the hidden width would
+    # credit six times the work: why the cell is not in its list
+    assert read("lm_moe_experts_roofline_pct", full) > 100
+    for c in ({}, {"scope_s:lm/mla": 0.15, "batch": 4, "seq_len": 8192}):
+        for name in ("lm_ssm_device_ms", "lm_ssm_scan_device_ms",
+                     "lm_moe_latent_device_ms"):
+            assert read(name, obs(c)) is None, name
+    untraced = obs(counters)
+    untraced.trace = None
+    assert read("lm_ssm_scan_device_ms", untraced) is None
+    assert read("lm_moe_latent_device_ms", untraced) is None

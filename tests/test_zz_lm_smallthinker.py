@@ -198,7 +198,8 @@ def test_the_published_configuration_and_its_refusals():
 
 
 @pytest.mark.parametrize("variant", sorted(
-    v for v in LM_VARIANTS if not v.startswith("smallthinker")))
+    v for v in LM_VARIANTS
+    if not v.startswith(("smallthinker", "nemotron"))))  # the four before it
 def test_the_new_answers_leave_the_other_architectures_as_they_were(variant):
     cfg = LM_VARIANTS[variant]()
     assert (cfg.router_reads, cfg.route_score, cfg.expert_act, cfg.qk_norm,
