@@ -27,8 +27,15 @@ or the layer's input `x` itself [router_reads]: then the routing and the
 dispatch table are made ahead of the mixer, since nothing they read
 waits for it, and the router's gradient flows into `x` and not into
 `h`. Under `cfg.remat` every layer is
-a `jax.checkpoint` that keeps nothing: the backward holds one layer's
-activations at a time.
+a `jax.checkpoint` that keeps one thing, the expert layer's routing
+(what `moe.plan` puts under the name `moe.ROUTING`: the chosen experts,
+their logits, the sorted order and the dispatch table, integers and
+floats `[T * top_k]`, five arrays of 4 B a slot a layer: 14 MB at 32,768
+tokens by 22): the backward holds one layer's activations at a time and
+recomputes them all but the router's fp32 product, the top-k, the sort
+and the table, which cost a ninth of the largest cell's step to make
+again (98.9 of 873.5 ms; PERF.md, PR 49) and a few MB to keep. A layer
+without experts carries no such name and keeps nothing.
 
 The loss is the mean cross-entropy over next-token targets that lie in
 the same document as their input (a packed row holds several; pad has
@@ -143,8 +150,10 @@ class LM(nn.Module):
             x = embed.astype(dtype)[tokens]
             if cfg.embed_scale != 1.0:
                 x = x * jnp.asarray(cfg.embed_scale, dtype)
-        layer_cls = (nn.remat(DecoderLayer, prevent_cse=True)
-                     if cfg.remat else DecoderLayer)
+        layer_cls = (nn.remat(
+            DecoderLayer, prevent_cse=True,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                moe.ROUTING)) if cfg.remat else DecoderLayer)
         per_layer = []
         for i in range(cfg.num_hidden_layers):
             x, counters = layer_cls(cfg=cfg, index=i, name=f"layers_{i}",

@@ -22,6 +22,24 @@ way `plan(x)` is the router and the dispatch table, everything that
 depends on the routing and not on the experts' input, and
 `__call__(x, plan)` the experts' part.
 
+The routing is made once a step. `plan` puts what the experts' part and
+the router's backward read of it under one name (`ROUTING`,
+`checkpoint_name`): the chosen ids and their logits `[T, top_k]`, the
+sorted order `[T * top_k]` and the `Plan` (a slot's token and weight,
+the loads, the table), and the layer's checkpoint keeps that name and
+nothing else (models/lm/model.py): five arrays of 4 B a slot and a few
+KB, 14.4 MB a layer at 32,768 tokens by 22 (0.8-5.2 MB in the other
+cells), where the fp32 logits `[T, experts]` of ONE recomputed product
+are 67 MB. The backward then recomputes neither the `highest` product
+`x W_r`, the top-k, the sort, the table nor `weights[order]`; what is
+left of the router there is the `[T, top_k]` cotangent through the
+score, its scatter into `[T, experts]` and the two products of the
+matmul's own transpose. For that the score is taken AFTER the selection
+(`_select`, `route`): the derivative of `sigmoid(take(logits))` reads
+the kept `[T, top_k]` logits, that of `take(sigmoid(logits))` the whole
+`[T, experts]` scores, which would keep the product alive; the values
+are the same, a sigmoid being elementwise.
+
 This chip then computes `sum_i w_i Expert_i(x)` over the chosen experts
 it holds (`cfg.experts_held`). An expert is three matrices,
 `W_down(act(W_gate x) * W_up x)`, or two, `W_down act(W_up x)`, with no
@@ -102,18 +120,40 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dexiraft_tpu.models.lm.layers import ActMLP, SwiGLU, Weights
 from dexiraft_tpu.ops import rows as row_ops
 from dexiraft_tpu.ops.grouped import grouped_matmul
 
 
-def route(scores: jax.Array, bias: jax.Array, top_k: int, scale: float,
+# the name a layer's checkpoint keeps (models/lm/model.py): the routing
+ROUTING = "lm_routing"
+
+
+def _kept(a: jax.Array) -> jax.Array:
+    """`a` under the name the layer's checkpoint keeps."""
+    return checkpoint_name(a, ROUTING)
+
+
+def _select(by: jax.Array, logits: jax.Array,
+            top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """(ids `[T, k]` of the top `top_k` of `by` `[T, E]`, their entries
+    of `logits`), both kept. The entries are taken with the kept ids, so
+    that their derivative, a scatter into `[T, E]`, reads nothing the
+    backward would have to select again."""
+    chosen = _kept(jax.lax.top_k(by, top_k)[1])
+    return chosen, _kept(jnp.take_along_axis(logits, chosen, axis=-1))
+
+
+def route(logits: jax.Array, bias: jax.Array, top_k: int, scale: float,
           normalise: bool, eps: float) -> Tuple[jax.Array, jax.Array]:
-    """(expert ids `[T, k]`, weights `[T, k]` fp32) from sigmoid scores
-    `[T, E]` fp32."""
-    _, chosen = jax.lax.top_k(scores + bias, top_k)
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    """(expert ids `[T, k]`, weights `[T, k]` fp32) from logits `[T, E]`
+    fp32: the top `top_k` of `sigmoid(logits) + bias`, weighted by the
+    sigmoids of their own logits, taken after the selection (module
+    docstring: the same numbers, and a derivative that reads `[T, k]`)."""
+    chosen, picked = _select(jax.nn.sigmoid(logits) + bias, logits, top_k)
+    weights = jax.nn.sigmoid(picked)
     if normalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return chosen, weights * scale
@@ -123,8 +163,8 @@ def route_softmax(logits: jax.Array, top_k: int,
                   scale: float) -> Tuple[jax.Array, jax.Array]:
     """(expert ids `[T, k]`, weights `[T, k]` fp32) from logits `[T, E]`
     fp32: the top `top_k` and a softmax over them."""
-    top, chosen = jax.lax.top_k(logits, top_k)
-    return chosen, jax.nn.softmax(top, axis=-1) * scale
+    chosen, picked = _select(logits, logits, top_k)
+    return chosen, jax.nn.softmax(picked, axis=-1) * scale
 
 
 # a configuration's `expert_act` -> the gate function (of an expert
@@ -201,7 +241,9 @@ class RoutedExperts(Weights):
                                  (held, width, d), jnp.float32)
 
     def plan(self, x: jax.Array) -> Plan:
-        """x `[T, D]`, the tensor the router reads -> the routing."""
+        """x `[T, D]`, the tensor the router reads -> the routing, every
+        array of it under `ROUTING` (and the ids, their logits and the
+        order with them): a checkpoint that keeps the name routes once."""
         cfg = self.cfg
         t = x.shape[0]
         top_k = cfg.num_experts_per_tok
@@ -215,14 +257,14 @@ class RoutedExperts(Weights):
                                                 cfg.routed_scaling_factor)
             else:
                 chosen, weights = route(
-                    jax.nn.sigmoid(logits), self.bias.value, top_k,
+                    logits, self.bias.value, top_k,
                     cfg.routed_scaling_factor, cfg.norm_topk_prob,
                     cfg.route_eps)
 
         with jax.named_scope("lm/moe/dispatch"):
             local = chosen.reshape(-1) - first
             key = jnp.where((local >= 0) & (local < held), local, held)
-            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            order = _kept(jnp.argsort(key, stable=True).astype(jnp.int32))
             # each held expert's slots by block of tokens; its load is
             # the sum over the blocks
             blocks = t // row_ops.block_tokens(t)
@@ -244,8 +286,8 @@ class RoutedExperts(Weights):
                 n_chunks, chunk)
             slot_weight = jnp.pad(weights.reshape(-1)[order], (0, pad)
                                   ).reshape(n_chunks, chunk)
-        return Plan(slot_token, slot_weight, counts, starts, ends, n_held,
-                    block_lo)
+        return Plan(*map(_kept, (slot_token, slot_weight, counts, starts,
+                                 ends, n_held, block_lo)))
 
     def __call__(self, x: jax.Array, plan: Optional[Plan] = None
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:

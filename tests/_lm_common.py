@@ -2,7 +2,9 @@
 the five architectures, a packed batch and seeded weights of O(1)
 scale."""
 
+import collections
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +59,37 @@ reference_loss_and_grads = as_one_program(ref.loss_and_grads)
 def rel(a, b):
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def routing_ops(text, tokens, experts, top_k):
+    """What a lowered step (`.lower(...).as_text()`) holds of a routing
+    of `tokens` tokens over `experts` experts, as `main` runs it (a
+    function called n times counts n times): the router's products (a
+    `dot_general` with `[tokens, experts]` fp32 as its result, the
+    forward one, or as an operand, the two of its transpose), the top-k
+    selections, the sorts of the `tokens * top_k` slots and the gathers
+    of a float a slot (`weights[order]`)."""
+    slots = tokens * top_k
+    patterns = {
+        "products": rf"stablehlo\.dot_general.*tensor<{tokens}x{experts}xf32>",
+        "top_k": r"chlo\.top_k",
+        "sorts": rf'"stablehlo\.sort"\((?:(?!stablehlo\.sort)[\s\S])*?'
+                 rf"\}}\) : \(tensor<{slots}xi32>",
+        "weight_gathers": rf"stablehlo\.gather.*\(tensor<{slots}xf32>,"}
+    at = [(m.group(1), m.start()) for m in re.finditer(
+        r"func\.func (?:public |private )?@([\w.\-]+)\(", text)]
+    bodies = {name: text[start:end] for (name, start), (_, end) in
+              zip(at, at[1:] + [(None, len(text))])}
+
+    @functools.lru_cache(maxsize=None)
+    def held(name):
+        found = collections.Counter(
+            {k: len(re.findall(p, bodies[name])) for k, p in patterns.items()})
+        for callee in re.findall(r"[ .]call @([\w.\-]+)\(", bodies[name]):
+            found.update(held(callee))
+        return found
+
+    return dict(held("main"))
 
 
 ARCHS = {"kanana2": kanana2_toy, "trinity": trinity_mini_toy,

@@ -561,9 +561,14 @@ def _row_scatters_under_the_moves(text, tokens, hidden):
 
 
 def _step_on_the_chips_paths(cell_name, topo):
+    """`_step_lowered`'s step, compiled."""
+    return _step_lowered(cell_name, topo).compile()
+
+
+def _step_lowered(cell_name, topo):
     """The cell's step as `benchmarks/compile_check.py` lowers it, the
     expert layer's row moves on the kernel too (`ops/rows.py` picks its
-    path from the backend, which here is the CPU), compiled."""
+    path from the backend, which here is the CPU)."""
     import os.path as osp
     import sys
     from unittest import mock
@@ -577,8 +582,8 @@ def _step_on_the_chips_paths(cell_name, topo):
     with mock.patch.object(rows, "_on_tpu", lambda: True):
         harness.load_runner("lm_train_packed").compile_for(
             cell, topo, lambda label, program: lowered.append(program))
-    return lowered[0].compile()  # the step; the check's program is
-    #                              compile_check.py's to compile
+    return lowered[0]  # the step; the check's program is
+    #                    compile_check.py's to compile
 
 
 @pytest.mark.slow
@@ -651,8 +656,19 @@ def test_nemotron_step_compiles_for_v5e_and_fits_the_chip(topo):
     dispatch or combine at either width. The 43 overflow chunks keep no
     copy of the rows or of the experts' matrices (`[43, 32768, 1024]`,
     `[43, 8, 1024, 2688]`: 6.2 GB where each chunk's branch stood around
-    its checkpoint). Arguments and temporaries fit 15.75 GB."""
-    compiled = _step_on_the_chips_paths("nemotron3-train-pack32k", topo)
+    its checkpoint). The routing is made once a layer: the lowered step
+    holds 15 router products (a layer's forward one `[32768, 512]` and
+    the two of its transpose), 5 top-k, 5 sorts of the 720,896 slots and
+    5 gathers of their weights (20, 10, 10 and 10 while a layer's
+    checkpoint kept nothing), for 72 MB kept. Arguments and
+    temporaries fit 15.75 GB (6.10 + 7.84; 7.81 before the routing was
+    kept)."""
+    from _lm_common import routing_ops
+
+    lowered = _step_lowered("nemotron3-train-pack32k", topo)
+    assert routing_ops(lowered.as_text(), 32768, 512, 22) == dict(
+        products=15, top_k=5, sorts=5, weight_gathers=5)
+    compiled = lowered.compile()
     text = compiled.as_text()
     for name in ("lm_attention_fwd", "lm_attention_dq", "lm_attention_dkv",
                  "rows_segment_sum"):

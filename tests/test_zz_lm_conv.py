@@ -308,9 +308,9 @@ def test_the_router_adds_its_epsilon_to_the_chosen_scores_sum(normalise):
     x = x.at[:, 0].set(1.0)
     router = jnp.asarray(rng.normal(size=(cfg.hidden_size, cfg.num_experts))
                          * 0.05, jnp.float32).at[0].add(-15.0)
-    scores = jax.nn.sigmoid(x @ router)
+    logits = x @ router
     zero = jnp.zeros((cfg.num_experts,))
-    chosen, got = route(scores, zero, cfg.num_experts_per_tok,
+    chosen, got = route(logits, zero, cfg.num_experts_per_tok,
                         cfg.routed_scaling_factor, normalise, cfg.route_eps)
     with jax.default_matmul_precision("highest"):
         want_chosen, want = ref.routing({"router": router}, x, cfg)
@@ -318,7 +318,7 @@ def test_the_router_adds_its_epsilon_to_the_chosen_scores_sum(normalise):
     assert rel(got, want) < 1e-5
     if normalise:
         assert 0.1 < float(jnp.max(jnp.sum(got, axis=-1))) < 0.9
-        untouched = route(scores, zero, cfg.num_experts_per_tok, 1.0, True,
+        untouched = route(logits, zero, cfg.num_experts_per_tok, 1.0, True,
                           ROUTE_EPS)[1]
         assert rel(untouched, want) > 0.1
 

@@ -245,5 +245,5 @@ def _chosen_of(module, variables, x, cfg):
         return np.asarray(moe.route_softmax(
             logits, cfg.num_experts_per_tok, 1.0)[0])
     bias = variables["batch_stats"]["experts"]["e_score_correction_bias"]
-    return np.asarray(moe.route(jax.nn.sigmoid(logits), bias,
-                                cfg.num_experts_per_tok, 1.0, False, 0.0)[0])
+    return np.asarray(moe.route(logits, bias, cfg.num_experts_per_tok, 1.0,
+                                False, 0.0)[0])
